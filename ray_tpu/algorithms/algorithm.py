@@ -97,8 +97,10 @@ class Algorithm(Trainable):
         # and DCN across (reference: torch.distributed init in
         # train/torch/config.py:83 / NCCL group setup).
         from ray_tpu.parallel import distributed as dist_lib
+        from ray_tpu.utils.platform import ensure_compile_cache
 
         dist_lib.initialize()
+        ensure_compile_cache()
 
         # learner mesh (driver-side policies): built through the
         # backend the config selects — the sharding runtime's

@@ -181,8 +181,8 @@ class AlgorithmConfig:
         # Defer the learner's stats readback by one call: learn
         # returns right after the SGD nest is dispatched and fetches
         # the PREVIOUS call's stats (long finished) instead of
-        # blocking on this one — amortizes per-dispatch latency
-        # (dominant on a tunneled/remote TPU). train() results lag
+        # blocking on this one — amortizes the per-dispatch host
+        # cost and readback. train() results lag
         # one learn step; host-side stat hooks (PPO kl adaptation)
         # see the lagged values.
         self.deferred_stats = False

@@ -202,7 +202,7 @@ class CQLJaxPolicy(SACJaxPolicy):
 
             (c_loss, (q1, td_loss, cql_pen)), c_grads = (
                 jax.value_and_grad(critic_loss, has_aux=True)(
-                    params["critic"]
+                    sharding_lib.varying(params["critic"], axis)
                 )
             )
             c_grads = jax.lax.pmean(c_grads, axis)
@@ -232,7 +232,7 @@ class CQLJaxPolicy(SACJaxPolicy):
 
             (a_loss, logp_pi), a_grads = jax.value_and_grad(
                 actor_loss, has_aux=True
-            )(params["actor"])
+            )(sharding_lib.varying(params["actor"], axis))
             a_grads = jax.lax.pmean(a_grads, axis)
             a_upd, a_opt = tx_a.update(
                 a_grads, opt_state["actor"], params["actor"]
@@ -247,7 +247,7 @@ class CQLJaxPolicy(SACJaxPolicy):
                 )
 
             al_loss, al_grad = jax.value_and_grad(alpha_loss)(
-                params["log_alpha"]
+                sharding_lib.varying(params["log_alpha"], axis)
             )
             al_grad = jax.lax.pmean(al_grad, axis)
             al_upd, al_opt = tx_al.update(

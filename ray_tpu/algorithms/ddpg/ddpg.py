@@ -372,7 +372,7 @@ class DDPGJaxPolicy(JaxPolicy):
 
             (c_loss, (q1, td_err)), c_grads = jax.value_and_grad(
                 critic_loss, has_aux=True
-            )(params["critic"])
+            )(sharding_lib.varying(params["critic"], axis))
             c_grads = jax.lax.pmean(c_grads, axis)
             c_upd, c_opt = tx_c.update(
                 c_grads, opt_state["critic"], params["critic"]
@@ -389,7 +389,7 @@ class DDPGJaxPolicy(JaxPolicy):
                 return loss
 
             a_loss, a_grads = jax.value_and_grad(actor_loss)(
-                params["actor"]
+                sharding_lib.varying(params["actor"], axis)
             )
             a_grads = jax.lax.pmean(a_grads, axis)
             a_upd, a_opt = tx_a.update(

@@ -469,13 +469,13 @@ class QMIX(Algorithm):
         # reduce globally (psum), so the update equals the single-
         # device one. Mirrors the JaxPolicy shard_map nest — QMIX owns
         # a bespoke episode-major program, so the wiring lives here.
+        from ray_tpu import sharding as sharding_lib
+
         mesh = self.config.get("_mesh")
         axis = None
         if mesh is not None and int(
             np.prod(list(mesh.shape.values()))
         ) > 1:
-            from ray_tpu import sharding as sharding_lib
-
             axis = sharding_lib.data_axis(mesh)
 
         def unroll_q(agent_params, obs):
@@ -539,9 +539,16 @@ class QMIX(Algorithm):
                 loss = jnp.sum(jnp.square(td)) / denom
                 return loss, (q_tot, td, denom)
 
+            # per-shard view of the replicated params, so the psum
+            # below is the one cross-shard reduction
+            # (sharding/specs.py "varying-axes typing")
             (loss, (q_tot, td, denom)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
-            )(params)
+            )(
+                params
+                if axis is None
+                else sharding_lib.varying(params, axis)
+            )
             if axis is not None:
                 grads = jax.lax.psum(grads, axis)
             updates, opt_state = tx.update(grads, opt_state, params)
@@ -556,8 +563,6 @@ class QMIX(Algorithm):
                     k: jax.lax.psum(v, axis) for k, v in stats.items()
                 }
             return params, opt_state, stats
-
-        from ray_tpu import sharding as sharding_lib
 
         if axis is None:
             return sharding_lib.sharded_jit(fn, label="learn[QMIX]")

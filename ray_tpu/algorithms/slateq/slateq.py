@@ -478,7 +478,9 @@ class SlateQJaxPolicy(JaxPolicy):
             (
                 (loss, (clicked_q, td, n, choice_loss)),
                 grads,
-            ) = jax.value_and_grad(loss_fn, has_aux=True)(params)
+            ) = jax.value_and_grad(loss_fn, has_aux=True)(
+                sharding_lib.varying(params, axis)
+            )
             grads = jax.lax.pmean(grads, axis)
             updates, opt_state = tx.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)

@@ -1579,16 +1579,11 @@ def cluster_resources() -> Dict[str, float]:
     rt = _require_runtime()
     res = {"CPU": float(rt.num_cpus)}
     res.update(rt.total_resources)
-    try:
-        import jax
+    import jax
 
-        tpus = len(
-            [d for d in jax.devices() if d.platform not in ("cpu",)]
-        )
-        if tpus:
-            res["TPU"] = float(tpus)
-    except Exception:
-        pass
+    tpus = len([d for d in jax.devices() if d.platform != "cpu"])
+    if tpus:
+        res["TPU"] = float(tpus)
     return res
 
 

@@ -13,9 +13,6 @@ import numpy as np
 
 
 def main(argv=None) -> int:
-    from ray_tpu.utils.platform import apply_platform_override
-
-    apply_platform_override()
     parser = argparse.ArgumentParser(description="ray_tpu evaluate CLI")
     parser.add_argument("checkpoint", type=str)
     parser.add_argument("--run", type=str, required=True)

@@ -366,7 +366,11 @@ class JaxRolloutEngine:
             # un-partitioned policies; per-leaf model-axis slices for
             # partitioned ones — the model inserts its own collectives)
             p_ps = getattr(policy, "param_pspecs", None)
-            p_ps = P() if p_ps is None else p_ps
+            p_ps = (
+                P()
+                if p_ps is None
+                else sharding_lib.manual_pspecs(self.mesh, p_ps)
+            )
             sharded = jax.shard_map(
                 program,
                 mesh=self.mesh,

@@ -8,8 +8,8 @@ reference's _MultiGPULoaderThread + tower-buffer protocol, collapsed to a
 double-buffered ``jax.device_put`` thread). Policies without the two-phase
 JaxPolicy learn API fall back to synchronous ``learn_on_batch``.
 
-Two further overlaps matter on a tunneled/remote TPU backend, where a
-single dispatch round trip can exceed the nest's compute:
+Two further overlaps matter wherever a dispatch's host cost plus its
+stats readback can exceed the nest's compute:
 
 - **Deferred stats.** For policies without host-side
   ``after_learn_on_batch`` hooks, ``learn_on_device_batch`` runs with

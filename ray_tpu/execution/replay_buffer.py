@@ -304,24 +304,11 @@ def resolve_device_resident(config: Dict, mesh=None) -> bool:
     if not mode:
         return False
     if mode == "auto":
-        try:
-            import jax
+        from ray_tpu.sharding.mesh import all_cpu, num_shards
 
-            devices = mesh.devices.flatten() if mesh is not None else (
-                jax.devices()
-            )
-            if all(d.platform == "cpu" for d in devices):
-                return False
-        except Exception:
+        if all_cpu(mesh):
             return False
-        shards = 1
-        if mesh is not None:
-            try:
-                from ray_tpu.sharding import num_shards
-
-                shards = num_shards(mesh)
-            except Exception:
-                shards = 1
+        shards = num_shards(mesh) if mesh is not None else 1
         if int(config.get("train_batch_size", 0)) % max(1, shards):
             return False
     return True
@@ -341,15 +328,9 @@ def resolve_device_tree(config: Dict, mesh=None) -> bool:
     if not resolve_device_resident(config, mesh):
         return False
     if mode == "auto":
-        try:
-            import jax
+        from ray_tpu.sharding.mesh import all_cpu
 
-            devices = mesh.devices.flatten() if mesh is not None else (
-                jax.devices()
-            )
-            if all(d.platform == "cpu" for d in devices):
-                return False
-        except Exception:
+        if all_cpu(mesh):
             return False
     return True
 
@@ -446,11 +427,11 @@ class DeviceReplayBuffer:
         self.mesh = mesh if mesh is not None else sharding_lib.get_mesh()
         self.memory_cap_bytes = memory_cap_bytes
         self.label = label
-        # None = auto: insert/sample row movement through the Pallas
-        # row-copy kernels (ops/framestack.py) where they lower —
-        # bitwise-identical data movement either way. Auto stays off on
-        # multi-device meshes (the kernels address the local ring, not
-        # a sharded one); a forced True is honored as-is (tests).
+        # handed to ops/framestack.py's row gather/scatter as-is: None
+        # = auto, which is the XLA path (Mosaic refuses the row-copy
+        # kernels — see _COMPILES_ON_TPU there) unless
+        # pallas_interpret asks for the interpreter; a forced True is
+        # honored (tests) — bitwise-identical data movement either way.
         self.use_pallas = use_pallas
         self.pallas_interpret = bool(pallas_interpret)
         self._store: Dict[str, Any] = {}  # name -> device ring array
@@ -590,18 +571,6 @@ class DeviceReplayBuffer:
         self._sample_fn = None
         return True
 
-    def _resolve_pallas(self):
-        """The per-program use_pallas value: explicit knob wins; auto
-        (None) passes through to the kernels' own lowering probes,
-        except on multi-device meshes where it resolves to False."""
-        if self.use_pallas is not None:
-            return bool(self.use_pallas)
-        if self.pallas_interpret:
-            return True
-        if int(self.mesh.devices.size) != 1:
-            return False
-        return None
-
     def _build_insert_fn(self):
         import jax
         import jax.numpy as jnp
@@ -610,7 +579,7 @@ class DeviceReplayBuffer:
         from ray_tpu.ops import framestack as framestack_lib
 
         meta = dict(self._meta)
-        up = self._resolve_pallas()
+        up = self.use_pallas
         interp = self.pallas_interpret
 
         def fn(store, rows, pos):
@@ -640,7 +609,7 @@ class DeviceReplayBuffer:
         from ray_tpu.ops import framestack as framestack_lib
 
         meta = dict(self._meta)
-        up = self._resolve_pallas()
+        up = self.use_pallas
         interp = self.pallas_interpret
 
         def fn(store, idx):
@@ -845,7 +814,7 @@ class DeviceReplayBuffer:
         if not isinstance(idx, jax.Array):
             idx = np.ascontiguousarray(idx, np.int32)
         meta = dict(self._meta)
-        up = self._resolve_pallas()
+        up = self.use_pallas
         interp = self.pallas_interpret
         from ray_tpu.ops import framestack as framestack_lib
 

@@ -38,7 +38,6 @@ and local shapes flow through the same code.
 
 from __future__ import annotations
 
-import functools
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -47,70 +46,40 @@ import numpy as np
 
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.sharding.mesh import MODEL_AXIS
+from ray_tpu.sharding.specs import varying
 
 
 def _bound_parallel_axis(name: Optional[str]) -> Optional[str]:
     """Trace-time probe: ``name`` if it is a bound mesh axis here
     (i.e. we are inside a shard_map over it) AND its size exceeds 1 —
-    else None. The discarded axis_index is dead code when bound;
-    unbound raises before building anything. A size-1 axis returns
-    None on purpose: its collectives would be exact no-ops, and
-    emitting none keeps the ``model_parallel=1`` program literally the
-    replicated program (the bitwise-parity geometry). ``axis_size``
-    folds to a static int at trace time (parallel/__init__ shim)."""
+    else None. A size-1 axis returns None on purpose: its collectives
+    would be exact no-ops, and emitting none keeps the
+    ``model_parallel=1`` program literally the replicated program (the
+    bitwise-parity geometry)."""
     if not name:
         return None
     try:
-        jax.lax.axis_index(name)
-    except Exception:
+        size = jax.lax.axis_size(name)
+    except NameError:  # unbound: not inside a shard_map over it
         return None
-    try:
-        if int(jax.lax.axis_size(name)) <= 1:
-            return None
-    except Exception:  # non-static size: keep the collectives (safe)
-        pass
-    return name
+    return name if size > 1 else None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def copy_to_model_shards(x, axis):
     """Megatron's *f* operator: identity forward into a tensor-parallel
     region, all-reduce backward — collects each model shard's partial
     gradient contribution to the (replicated) activations feeding a
-    column-parallel projection."""
-    return x
+    column-parallel projection. Under ``shard_map``'s varying-axes
+    typing that is exactly the cast of a replicated value to a
+    per-shard one: its transpose is the ``psum``."""
+    return varying(x, axis)
 
 
-def _copy_fwd(x, axis):
-    return x, None
-
-
-def _copy_bwd(axis, _res, g):
-    return (jax.lax.psum(g, axis),)
-
-
-copy_to_model_shards.defvjp(_copy_fwd, _copy_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def reduce_from_model_shards(x, axis):
     """Megatron's *g* operator: all-reduce forward out of a
-    row-parallel projection, identity backward. Spelled as a
-    custom_vjp rather than a bare ``lax.psum`` because under
-    ``check_rep=False`` (the jax<0.5 shard_map shim) psum transposes
-    to psum, which would double-reduce the cotangent."""
+    row-parallel projection, identity backward (``psum`` transposes to
+    the per-shard cast)."""
     return jax.lax.psum(x, axis)
-
-
-def _reduce_fwd(x, axis):
-    return jax.lax.psum(x, axis), None
-
-
-def _reduce_bwd(axis, _res, g):
-    return (g,)
-
-
-reduce_from_model_shards.defvjp(_reduce_fwd, _reduce_bwd)
 
 
 def _layer_norm(x, p, eps=1e-5):

@@ -31,16 +31,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU memory spaces; absent on CPU-only hosts is fine (interpret)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from ray_tpu.ops._pallas import kernel_selected
 
 _BLOCK_Q = 128
 _BLOCK_K = 128
+# compiled and matched the XLA reference on a TPU v5e at the GTrXL and
+# ring-attention shapes of tests/test_tpu_hardware.py (jax 0.9.0)
+_COMPILES_ON_TPU = True
 _NEG_INF = -1e30
 
 
@@ -159,11 +158,7 @@ def flash_block_attention_stats(q, k, v, offset, *, interpret=False):
     setup = _pallas_setup(q, k, v)
     n, t, d = q.shape
     bq, bk, qp, kp, vp, tp, grid, vmem = setup
-    smem = (
-        {}
-        if _VMEM is None
-        else {"memory_space": pltpu.SMEM}
-    )
+    smem = {"memory_space": pltpu.SMEM}
     acc, m, l = pl.pallas_call(
         functools.partial(
             _block_kernel, s_actual=k.shape[1], block_k=bk
@@ -208,7 +203,7 @@ def _pallas_setup(q, k, v):
     vp = _pad_to(v, 1, bk)
     tp = qp.shape[1]
     grid = (n, tp // bq)
-    vmem = {} if _VMEM is None else {"memory_space": _VMEM}
+    vmem = {"memory_space": pltpu.VMEM}
     return bq, bk, qp, kp, vp, tp, grid, vmem
 
 
@@ -252,25 +247,6 @@ def _flash_fwd_pallas(q, k, v, causal_offset, interpret):
     return out[:, :t]
 
 
-@functools.lru_cache(maxsize=None)
-def _pallas_lowers(t, s, d):
-    """One-time probe (cached per shape class): does the forward kernel
-    actually lower on this backend? Mosaic's supported-shape envelope
-    shifts between releases; when a shape class fails to lower we fall
-    back to the XLA reference path instead of crashing the hot loop.
-    The probe compiles n=1 (batch·head count never affects lowering —
-    it is only the leading grid dimension)."""
-    try:
-        zq = jnp.zeros((1, t, d), jnp.float32)
-        zk = jnp.zeros((1, s, d), jnp.float32)
-        jax.jit(
-            lambda a, b: _flash_fwd_pallas(a, b, b, 0, False)
-        ).lower(zq, zk).compile()
-        return True
-    except Exception:  # pragma: no cover - backend-dependent
-        return False
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash_attention(q, k, v, causal_offset, interpret):
     return _flash_fwd_pallas(q, k, v, causal_offset, interpret)
@@ -307,10 +283,9 @@ def flash_attention(
     (CPU testing of the real kernel)."""
     B, H, T, D = q.shape
     S = k.shape[2]
-    if use_pallas is None:
-        use_pallas = interpret or (
-            jax.default_backend() == "tpu" and _pallas_lowers(T, S, D)
-        )
+    use_pallas = kernel_selected(
+        use_pallas, interpret, compiles_on_tpu=_COMPILES_ON_TPU
+    )
     qf = q.reshape(B * H, T, D)
     kf = k.reshape(B * H, S, D)
     vf = v.reshape(B * H, S, D)

@@ -16,11 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # Pallas fragment-scan kernel; associative_scan stays the
-    # portable path and the golden reference
-    from jax.experimental import pallas as pl
-except Exception:  # pragma: no cover - minimal jax builds
-    pl = None
+from jax.experimental import pallas as pl
+
+from ray_tpu.ops._pallas import kernel_selected
 
 
 def discount_cumsum_np(x: np.ndarray, gamma: float) -> np.ndarray:
@@ -127,6 +125,16 @@ def compute_gae(
     return adv, value_targets
 
 
+# Mosaic (jax 0.9.0, TPU v5e) refuses the kernel's dynamic width-1
+# slice of the lane (time) axis: "Mosaic failed to compile TPU kernel:
+# cannot statically prove that index in dimension 1 is a multiple of
+# 128 ... vector.load ... memref<8x128xf32, #tpu.memory_space<vmem>>
+# -> vector<8x1xf32>". So ``use_pallas=None`` (auto) resolves to the
+# associative scan on every backend; the kernel runs only when forced
+# or through the interpreter.
+_COMPILES_ON_TPU = False
+
+
 def _gae_scan_kernel(deltas_ref, coeffs_ref, adv_ref, *, t):
     """Reverse first-order recurrence over the time axis for one row
     block: adv[t] = delta[t] + coeff[t] * adv[t+1]. Sequential in T
@@ -137,10 +145,10 @@ def _gae_scan_kernel(deltas_ref, coeffs_ref, adv_ref, *, t):
 
     def body(i, run):
         col = t - 1 - i
-        d = pl.load(deltas_ref, (slice(None), pl.ds(col, 1)))
-        c = pl.load(coeffs_ref, (slice(None), pl.ds(col, 1)))
+        d = deltas_ref[:, pl.ds(col, 1)]
+        c = coeffs_ref[:, pl.ds(col, 1)]
         run = d + c * run
-        pl.store(adv_ref, (slice(None), pl.ds(col, 1)), run)
+        adv_ref[:, pl.ds(col, 1)] = run
         return run
 
     jax.lax.fori_loop(
@@ -167,20 +175,6 @@ def _gae_scan_pallas(deltas, coeffs, interpret):
         interpret=interpret,
     )(deltas, coeffs)
     return out[:b] if pad else out
-
-
-@functools.lru_cache(maxsize=None)
-def _gae_lowers(b, t):  # pragma: no cover - backend-dependent
-    """One-time probe per (B, T) class: does the fragment-scan kernel
-    lower on this backend's Mosaic?"""
-    try:
-        x = jnp.zeros((b, t), jnp.float32)
-        jax.jit(
-            lambda d, c: _gae_scan_pallas(d, c, False)
-        ).lower(x, x).compile()
-        return True
-    except Exception:
-        return False
 
 
 def compute_gae_fragment(
@@ -217,7 +211,8 @@ def compute_gae_fragment(
 
     Returns (advantages, value_targets), both (B, T) float32.
 
-    ``use_pallas`` (None = auto, True/False forces) routes the reverse
+    ``use_pallas`` (True/False forces; None = auto, which is the
+    associative scan — see ``_COMPILES_ON_TPU``) routes the reverse
     recurrence through the Pallas fragment-scan kernel: sequential in
     T per row block — the mathematically exact evaluation order — vs
     the associative scan's log-depth reassociation, so the two paths
@@ -233,12 +228,9 @@ def compute_gae_fragment(
     deltas = rewards + gamma * next_values * not_term - values
     coeffs = gamma * lambda_ * not_done
 
-    if use_pallas is None:
-        use_pallas = interpret or (
-            jax.default_backend() == "tpu" and pl is not None
-            and _gae_lowers(*deltas.shape)
-        )
-    if use_pallas and pl is not None:
+    if kernel_selected(
+        use_pallas, interpret, compiles_on_tpu=_COMPILES_ON_TPU
+    ):
         adv = _gae_scan_pallas(deltas, coeffs, interpret)
         return adv, adv + values
 

@@ -11,6 +11,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from ray_tpu.ops._pallas import kernel_selected
+
 
 class SegmentTree:
     def __init__(self, capacity: int, operation, neutral_element: float):
@@ -164,11 +166,10 @@ def find_prefixsum_body(value, prefixsum, capacity: int):
 # VMEM-resident and each level is a vectorized gather + exact f64
 # compare/subtract — the identical op sequence to
 # ``find_prefixsum_body``, so draws stay bit-exact vs the host trees.
-# The tree is f64 (the determinism contract above), which Mosaic does
-# not lower on current TPU releases — so on this container the kernel
-# is interpreter-only (``use_pallas="auto"`` resolves to the XLA body
-# on TPU via the lowering probe; benchmarks/e2e/pallas_kernels.json
-# records the why-not) and exists as the parity-tested template for
+# The tree is f64 (the determinism contract above) and Mosaic has no
+# f64 vectors, so the kernel can never compile on a TPU: it is
+# interpreter-only, ``use_pallas=None`` (auto) always resolves to the
+# XLA body, and the kernel exists as the parity-tested template for
 # backends that grow f64 VMEM support.
 
 
@@ -181,7 +182,7 @@ def _descent_kernel(value_ref, p_ref, out_ref, *, levels, capacity):
     idx = jnp.ones(p.shape, jnp.int32)
     for _ in range(levels):
         left = 2 * idx
-        left_vals = pl.load(value_ref, (left,))
+        left_vals = value_ref[left]
         go_right = p > left_vals
         p = jnp.where(go_right, p - left_vals, p)
         idx = jnp.where(go_right, left + 1, left)
@@ -208,36 +209,6 @@ def find_prefixsum_pallas(value, prefixsum, capacity: int, *, interpret=False):
         interpret=interpret,
     )(value, prefixsum)
     return out.astype(jnp.int64)
-
-
-def _descent_lowers(capacity: int, n: int) -> bool:
-    """Probe: does the f64 descent lower on this backend? (It does not
-    on current TPU Mosaic — f64 vectors — which is exactly what the
-    auto knob needs to know.)"""
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu import sharding as sharding_lib
-
-    key = (capacity, n)
-    hit = _DESCENT_LOWERS.get(key)
-    if hit is not None:
-        return hit
-    try:
-        with sharding_lib.f64_scope():
-            v = jnp.zeros(2 * capacity, jnp.float64)
-            p = jnp.zeros(n, jnp.float64)
-            jax.jit(
-                lambda a, b: find_prefixsum_pallas(a, b, capacity)
-            ).lower(v, p).compile()
-        ok = True
-    except Exception:  # pragma: no cover - backend-dependent
-        ok = False
-    _DESCENT_LOWERS[key] = ok
-    return ok
-
-
-_DESCENT_LOWERS: dict = {}
 
 
 # ray-tpu: device-fn f64
@@ -336,9 +307,8 @@ class DeviceSumTree:
         self.capacity = int(capacity)
         self.mesh = mesh if mesh is not None else sharding_lib.get_mesh()
         self.label = label
-        # None = auto: Pallas descent where the f64 kernel lowers
-        # (probe-gated; interpreter always qualifies), XLA body
-        # elsewhere — today that means XLA on TPU, see the module
+        # None = auto: the XLA body, unless the interpreter was asked
+        # for — the f64 kernel cannot compile on a TPU, see the module
         # comment above find_prefixsum_pallas
         self.use_pallas = use_pallas
         self.pallas_interpret = bool(pallas_interpret)
@@ -427,13 +397,20 @@ class DeviceSumTree:
         fn = self._update_fns.get(key)
         if fn is None:
             fn = self._update_fns[key] = self._build_update_fn(u, bp)
+        rep = sharding_lib.replicated(self.mesh)
         with sharding_lib.f64_scope():
+            # jax types an argument by the mesh it lives on, so the
+            # host and the device spelling of one (u, bp) argument
+            # would each trace the program: host inputs go onto the
+            # tree's mesh first and every call sees one type
             idx_p = pad(idx_arr, 0)
             if not isinstance(idx_p, jax.Array):
-                idx_p = idx_p.astype(np_.int32)
+                idx_p = jax.device_put(idx_p.astype(np_.int32), rep)
             vals_p = pad(powered, 0.0)
             if not isinstance(vals_p, jax.Array):
-                vals_p = vals_p.astype(np_.float64)
+                vals_p = jax.device_put(
+                    vals_p.astype(np_.float64), rep
+                )
             self.sum_value, self.min_value = fn(
                 self.sum_value, self.min_value, idx_p, vals_p, mask
             )
@@ -456,10 +433,11 @@ class DeviceSumTree:
 
             cap = self.capacity
             interp = self.pallas_interpret
-            if self.use_pallas is None:
-                pallas = interp or _descent_lowers(cap, rand.shape[-1])
-            else:
-                pallas = bool(self.use_pallas)
+            # Mosaic has no f64: the descent kernel never compiles on
+            # a TPU, so auto is the XLA body (ops/_pallas.py)
+            pallas = kernel_selected(
+                self.use_pallas, interp, compiles_on_tpu=False
+            )
 
             # ray-tpu: f64
             def prog(sum_t, min_t, r, size_, beta_):

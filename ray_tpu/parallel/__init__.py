@@ -1,30 +1,7 @@
-"""Legacy parallel namespace — jax version shims plus an adapter over
-the sharding runtime (``ray_tpu.sharding``). The mesh helpers re-
-exported here keep the historical ``("data",)`` axis naming for the
-pmap-backend learn programs; new code targets ``ray_tpu.sharding``."""
-
-import functools
-
-import jax
-
-if not hasattr(jax, "shard_map"):
-    # Older jax exposes shard_map only under jax.experimental (and its
-    # replication checker predates the cond/scan patterns the learn
-    # programs use); alias the stable name so one codebase spans both.
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    @functools.wraps(_experimental_shard_map)
-    def _shard_map_compat(f, mesh=None, in_specs=None, out_specs=None, **kw):
-        kw.pop("check_vma", None)
-        kw.setdefault("check_rep", False)
-        return _experimental_shard_map(f, mesh, in_specs, out_specs, **kw)
-
-    jax.shard_map = _shard_map_compat
-
-if not hasattr(jax.lax, "axis_size"):
-    # same vintage gap; psum of the constant 1 folds to the static
-    # axis size at trace time on these versions
-    jax.lax.axis_size = lambda axis_name: jax.lax.psum(1, axis_name)
+"""Legacy parallel namespace — an adapter over the sharding runtime
+(``ray_tpu.sharding``). The mesh helpers re-exported here keep the
+historical ``("data",)`` axis naming for the pmap-backend learn
+programs; new code targets ``ray_tpu.sharding``."""
 
 from ray_tpu.parallel.mesh import (
     make_mesh,

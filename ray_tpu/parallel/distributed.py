@@ -72,13 +72,7 @@ def initialize(
         or os.environ.get("JAX_PLATFORMS", "")
     ).lower()
     if "cpu" in platforms:
-        try:
-            jax.config.update(
-                "jax_cpu_collectives_implementation", "gloo"
-            )
-        except Exception:
-            pass  # older/newer jax without the option (or gloo-less
-            # jaxlib): keep the default and let init surface errors
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     num_processes = int(
         num_processes
         if num_processes is not None

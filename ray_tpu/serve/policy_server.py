@@ -300,7 +300,9 @@ class BatchedPolicyServer:
         import jax
 
         from ray_tpu import sharding as sharding_lib
+        from ray_tpu.utils.platform import ensure_compile_cache
 
+        ensure_compile_cache()
         self._rep = sharding_lib.replicated(policy.mesh)
         # params enter the fused forward per their live placement tree
         # (replicated for ordinary policies; per-leaf model-axis

@@ -3,9 +3,9 @@
 Replaces the per-call pmap/shard-map shims with a first-class layer
 (docs/sharding.md):
 
-  - :mod:`~ray_tpu.sharding.mesh`    mesh construction (cached, CPU
-    fallback, simulated devices), ``("batch",)`` data mesh today with
-    the ``"model"`` axis name reserved;
+  - :mod:`~ray_tpu.sharding.mesh`    mesh construction (cached,
+    simulated devices), ``("batch",)`` data mesh today with the
+    ``"model"`` axis name reserved;
   - :mod:`~ray_tpu.sharding.specs`   NamedSharding builders: replicated
     param trees, row-sharded batch columns, per-leaf trees with the
     ragged-leading-dim fallback;
@@ -48,6 +48,7 @@ from ray_tpu.sharding.specs import (
     clear_sharding_caches,
     default_partition_rules,
     leaf_sharding,
+    manual_pspecs,
     mesh_spans_processes,
     named_tree,
     param_pspecs,
@@ -59,6 +60,9 @@ from ray_tpu.sharding.specs import (
     state_pspecs,
     tree_nbytes,
     tree_shard_nbytes,
+    varying,
+    vma_barrier,
+    vma_of,
 )
 from ray_tpu.sharding.registry import (
     ProgramRegistry,
@@ -133,6 +137,7 @@ __all__ = [
     "get_mesh",
     "global_devices",
     "leaf_sharding",
+    "manual_pspecs",
     "mesh_spans_processes",
     "model_axis",
     "model_shards",
@@ -152,4 +157,7 @@ __all__ = [
     "state_pspecs",
     "tree_nbytes",
     "tree_shard_nbytes",
+    "varying",
+    "vma_barrier",
+    "vma_of",
 ]

@@ -56,16 +56,6 @@ from ray_tpu.util import tracing
 FORMAT = 2
 
 
-def supported() -> bool:
-    """Whether this jax build can serialize compiled executables."""
-    try:
-        from jax.experimental import serialize_executable  # noqa: F401
-
-        return True
-    except Exception:
-        return False
-
-
 def fingerprint() -> Dict[str, Any]:
     """The validity domain of a serialized executable: the toolchain
     that compiled it and the device topology it was compiled for. Any

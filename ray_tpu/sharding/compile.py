@@ -183,8 +183,8 @@ class ShardedFunction:
         as this function's one trace), installs the result, and queues
         the serialized executable for the cache writer so the NEXT
         replica hits. Returns ``"hit"`` / ``"compiled"`` /
-        ``"disabled"`` (no cache, or a jax build that can't serialize
-        executables — the caller falls back to plain jit warmup).
+        ``"disabled"`` (no cache — the caller falls back to plain jit
+        warmup).
 
         The cache signature carries the MESH GEOMETRY of the program's
         shardings on top of the ledger's shape/dtype signature: the
@@ -196,7 +196,7 @@ class ShardedFunction:
         from ray_tpu.sharding import aot as aot_lib
 
         cache = aot_lib.resolve_cache(cache)
-        if cache is None or not aot_lib.supported():
+        if cache is None:
             return "disabled"
         try:
             sig = device_ledger.signature_of(
@@ -412,9 +412,7 @@ def f64_scope():
     silently downcast them to f32), while their f32/i32 outputs (IS
     weights, drawn indices) feed the ordinary f32 learner world
     outside."""
-    from jax.experimental import enable_x64
-
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 def compile_stats() -> Dict[str, Any]:

@@ -1,7 +1,7 @@
 """Mesh construction for the sharding runtime.
 
-One mesh per process (cached), built from whatever devices the backend
-exposes: real TPU cores, a CPU fallback, or simulated host devices via
+One mesh per process (cached), built from the devices the backend
+exposes: real TPU cores, or simulated host devices via
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the standard
 way to test multi-device layouts without hardware — tests/conftest.py
 forces 8).
@@ -39,16 +39,28 @@ _MESH_CACHE: dict = {}
 
 def available_devices(platform: Optional[str] = None):
     """Devices to build meshes from. ``platform`` filters ("tpu",
-    "cpu"); when the requested platform has no devices the CPU host
-    devices are the fallback, so a learner configured for TPU still
-    comes up (slowly) on a dev box."""
+    "cpu"); asking for a platform the backend does not expose is an
+    error — a learner configured for TPU never comes up on the CPU
+    unannounced."""
     devs = jax.devices()
-    if platform:
-        matched = [d for d in devs if d.platform == platform]
-        if matched:
-            return matched
-        devs = [d for d in jax.devices() if d.platform == "cpu"] or devs
-    return devs
+    if not platform:
+        return devs
+    matched = [d for d in devs if d.platform == platform]
+    if not matched:
+        found = sorted({d.platform for d in devs})
+        raise RuntimeError(
+            f"no {platform!r} devices: jax exposes {found} "
+            f"({len(devs)} device(s))"
+        )
+    return matched
+
+
+def all_cpu(mesh: Optional[Mesh] = None) -> bool:
+    """Whether the learner's devices (the mesh's, else the default
+    backend's) are all host CPUs — the question every ``"auto"`` knob
+    asks. If the backend cannot be asked, that is the error."""
+    devices = mesh.devices.flat if mesh is not None else jax.devices()
+    return all(d.platform == "cpu" for d in devices)
 
 
 def get_mesh(
@@ -134,10 +146,7 @@ def resolve_model_parallel(config, devices=None, strict: bool = False) -> int:
         devices = jax.devices()
     n = len(list(devices))
     if mode == "auto":
-        try:
-            if all(d.platform == "cpu" for d in devices):
-                return 1
-        except Exception:
+        if all(d.platform == "cpu" for d in devices):
             return 1
         return 2 if (n >= 2 and n % 2 == 0) else 1
     m = int(mode)

@@ -406,6 +406,37 @@ def named_tree(mesh: Mesh, pspec_tree):
     )
 
 
+def manual_pspecs(mesh: Mesh, pspec_tree):
+    """The spec tree a ``shard_map`` over ``mesh`` should take for a
+    placement ``pspec_tree``: names of size-1 axes dropped. On such an
+    axis a slice IS the leaf and the model emits no collectives
+    (models/transformer._bound_parallel_axis), so typing the leaf as
+    varying over it would only make jax insert single-device
+    reductions the replicated program does not have — the
+    ``model_parallel=1`` program must stay literally the replicated
+    one (the bitwise-parity geometry)."""
+    sizes = dict(mesh.shape)
+
+    def keep(e):
+        if e is None:
+            return None
+        names = tuple(
+            n for n in ((e,) if isinstance(e, str) else e)
+            if sizes[n] > 1
+        )
+        if not names:
+            return None
+        return names[0] if len(names) == 1 else names
+
+    def one(spec):
+        entries = [keep(e) for e in spec]
+        while entries and entries[-1] is None:
+            entries.pop()
+        return P(*entries)
+
+    return jax.tree_util.tree_map(one, pspec_tree, is_leaf=_is_pspec)
+
+
 def param_sharding(tree, mesh: Mesh, rules: Sequence):
     """Per-leaf :class:`NamedSharding` tree for a param tree — the
     builder the learn/serve/rollout call sites hand to
@@ -485,3 +516,66 @@ def shard_batch(
     if block:
         jax.block_until_ready(dev)
     return dev
+
+
+# -- varying-axes typing inside shard_map bodies ------------------------
+#
+# jax types every value in a ``shard_map`` body by the mesh axes it
+# varies over (``check_vma``): a replicated input (``P()``) is
+# *invarying*, a row-sharded one varies over the data axis. Two
+# consequences the learn programs depend on:
+#
+#   - differentiating a per-shard loss w.r.t. an INVARYING param makes
+#     jax insert the cross-shard ``psum`` itself (the transpose of the
+#     implicit broadcast), so the gradient comes back already SUMMED
+#     over shards and a following ``pmean`` is the identity — N× the
+#     intended mean gradient on an N-shard mesh. The update bodies
+#     therefore differentiate a :func:`varying` view of the params:
+#     the gradients stay per-shard and the explicit ``pmean`` is the
+#     one real collective, as written;
+#   - a ``lax.scan`` carry (and both ``lax.cond`` branches) must keep
+#     one type, and ``optimization_barrier`` types ALL its outputs by
+#     the union of its inputs' axes — :func:`vma_barrier` pins the
+#     same boundary without retyping the replicated carry.
+
+
+def vma_of(tree) -> frozenset:
+    """Union of the mesh axes the leaves of ``tree`` vary over (empty
+    outside ``shard_map``)."""
+    axes: frozenset = frozenset()
+    for x in jax.tree_util.tree_leaves(tree):
+        axes |= jax.typeof(x).vma
+    return axes
+
+
+def varying(tree, axes):
+    """``tree`` with every leaf typed as varying over ``axes`` (a name
+    or an iterable of names); leaves already varying over an axis keep
+    it. A type-level cast: no data moves."""
+    axes = (axes,) if isinstance(axes, str) else tuple(axes)
+
+    def cast(x):
+        missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
+        if not missing:
+            return x
+        return jax.lax.pcast(x, missing, to="varying")
+
+    return jax.tree_util.tree_map(cast, tree)
+
+
+def vma_barrier(tree):
+    """``lax.optimization_barrier`` over ``tree`` that keeps each
+    leaf's varying-axes type: leaves are pinned in groups of equal
+    type (one barrier per group), so a replicated scan carry that
+    shares a fusion boundary with a row-sharded batch stays
+    replicated."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    groups: Dict[frozenset, list] = {}
+    for i, x in enumerate(leaves):
+        groups.setdefault(jax.typeof(x).vma, []).append(i)
+    out = list(leaves)
+    for idxs in groups.values():
+        pinned = jax.lax.optimization_barrier([leaves[i] for i in idxs])
+        for i, v in zip(idxs, pinned):
+            out[i] = v
+    return jax.tree_util.tree_unflatten(treedef, out)

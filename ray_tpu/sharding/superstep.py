@@ -1,8 +1,8 @@
 """Superstep builder: K learner updates fused into ONE compiled program.
 
 The Podracer/Anakin lesson applied to this learner plane: the host
-boundary (dispatch + stats readback — a full tunnel RTT each on a
-remote TPU backend) is crossed once per *superstep* of K updates, not
+boundary (per-dispatch host cost + the stats readback's D2H sync) is
+crossed once per *superstep* of K updates, not
 once per update, so the fixed per-call overhead amortizes 1/K. This
 module generalizes what used to be a SAC special case
 (``sac.py learn_on_stacked_batch``) into the uniform learner contract:
@@ -50,8 +50,14 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.sharding.compile import ShardedFunction, sharded_jit
-from ray_tpu.sharding.mesh import data_axis, num_shards
-from ray_tpu.sharding.specs import batch_sharded, named_tree, replicated
+from ray_tpu.sharding.mesh import all_cpu, data_axis, num_shards
+from ray_tpu.sharding.specs import (
+    batch_sharded,
+    manual_pspecs,
+    named_tree,
+    replicated,
+    vma_barrier,
+)
 
 # stats-tree key for the in-scan nan_guard skip flag (1.0 = the slot's
 # update was suppressed because its batch contained non-finite floats)
@@ -76,17 +82,7 @@ def resolve_superstep(config: Dict, mesh=None) -> int:
     if config.get("sharding_backend", "mesh") != "mesh":
         return 1
     if mode == "auto":
-        try:
-            devices = (
-                mesh.devices.flatten()
-                if mesh is not None
-                else jax.devices()
-            )
-            if all(d.platform == "cpu" for d in devices):
-                return 1
-        except Exception:
-            return 1
-        return 8
+        return 1 if all_cpu(mesh) else 8
     return max(1, int(mode))
 
 
@@ -224,10 +220,8 @@ def build_superstep_fn(
             # backends). The barrier makes the body compile like the
             # standalone program, keeping the chain bit-identical to K
             # individual calls.
-            params, opt_state, aux, batch, rng = (
-                jax.lax.optimization_barrier(
-                    (params, opt_state, aux, batch, rng)
-                )
+            params, opt_state, aux, batch, rng = vma_barrier(
+                (params, opt_state, aux, batch, rng)
             )
             new_p, new_o, new_a, stats = update_fn(
                 params, opt_state, aux, batch, rng, coeffs
@@ -280,10 +274,11 @@ def build_superstep_fn(
         c: (P() if c in replicated_cols else P(None, axis))
         for c in cols
     }
-    sm_in = (p_ps, o_ps, a_ps, stacked_spec, P(), P()) + (
+    bp, bo, ba = manual_pspecs(mesh, (p_ps, o_ps, a_ps))
+    sm_in = (bp, bo, ba, stacked_spec, P(), P()) + (
         (P(), P()) if with_pri else (P(),)
     )
-    sm_out = (p_ps, o_ps, a_ps, P()) + (
+    sm_out = (bp, bo, ba, P()) + (
         (P(None, axis),) if with_pri else ()
     )
     sharded = jax.shard_map(
@@ -378,10 +373,8 @@ def _build_rollout_superstep(
             # compiles like the standalone rollout + update programs,
             # keeping the fused chain bit-identical to dispatching the
             # pieces separately
-            params, opt_state, aux, env_carry, rng, ro_rng = (
-                jax.lax.optimization_barrier(
-                    (params, opt_state, aux, env_carry, rng, ro_rng)
-                )
+            params, opt_state, aux, env_carry, rng, ro_rng = vma_barrier(
+                (params, opt_state, aux, env_carry, rng, ro_rng)
             )
             new_carry, batch, metrics = rollout_fn(
                 params, env_carry, ro_rng, coeffs
@@ -427,14 +420,15 @@ def _build_rollout_superstep(
     # carry leaves are per-env rows (leading dim N); metrics leaves
     # end in the env dim (engine contract) so they shard on axis -1
     p_ps, o_ps, a_ps = carry_pspecs
+    bp, bo, ba = manual_pspecs(mesh, (p_ps, o_ps, a_ps))
     sharded = jax.shard_map(
         multi_fn,
         mesh=mesh,
-        in_specs=(p_ps, o_ps, a_ps, P(axis), P(), P(), P(), P()),
+        in_specs=(bp, bo, ba, P(axis), P(), P(), P(), P()),
         out_specs=(
-            p_ps,
-            o_ps,
-            a_ps,
+            bp,
+            bo,
+            ba,
             P(axis),
             P(),
             P(*([None] * 2 + [axis])),

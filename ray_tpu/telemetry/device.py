@@ -99,8 +99,8 @@ def clear() -> None:
 
 # per-chip peak FLOPs (bf16 where the chip has it) and peak HBM
 # bytes/s, keyed by device_kind substring (public specs). The CPU
-# entry is a placeholder a container overrides — MFU against a wrong
-# peak is still a useful *relative* number across programs.
+# entry is a placeholder a container overrides (it keeps the ledger's
+# arithmetic testable on the CPU); a kind with no row is an error.
 PEAK_FLOPS_TABLE: Tuple[Tuple[str, float], ...] = (
     ("v6", 918e12),      # v6e (Trillium)
     ("v5p", 459e12),
@@ -140,12 +140,24 @@ def set_peak_flops(
 
 
 def device_kind() -> str:
-    try:
-        import jax
+    import jax
 
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "unknown"
+    return jax.devices()[0].device_kind
+
+
+def table_peak(table, kind: Optional[str], what: str) -> float:
+    """The table row whose key is a substring of the device kind; a
+    kind the table does not hold is an error, not a default — a
+    utilization against a guessed peak is a wrong number with a
+    device's name on it."""
+    k = kind or device_kind()
+    for key, peak in table:
+        if key in k.lower():
+            return peak
+    raise ValueError(
+        f"unknown device_kind {k!r}: no {what} row for it in "
+        "ray_tpu/telemetry/device.py — add the chip's published peak"
+    )
 
 
 def peak_flops_per_device(kind: Optional[str] = None) -> float:
@@ -157,11 +169,7 @@ def peak_flops_per_device(kind: Optional[str] = None) -> float:
             pass
     if _peak_flops_override:
         return _peak_flops_override
-    k = (kind or device_kind()).lower()
-    for key, peak in PEAK_FLOPS_TABLE:
-        if key in k:
-            return peak
-    return PEAK_FLOPS_TABLE[-1][1]
+    return table_peak(PEAK_FLOPS_TABLE, kind, "peak-FLOPs")
 
 
 def peak_hbm_bytes_per_s(kind: Optional[str] = None) -> float:
@@ -173,11 +181,7 @@ def peak_hbm_bytes_per_s(kind: Optional[str] = None) -> float:
             pass
     if _peak_hbm_override:
         return _peak_hbm_override
-    k = (kind or device_kind()).lower()
-    for key, peak in PEAK_HBM_TABLE:
-        if key in k:
-            return peak
-    return PEAK_HBM_TABLE[-1][1]
+    return table_peak(PEAK_HBM_TABLE, kind, "peak-HBM-bandwidth")
 
 
 # -- abstract signatures / forensics ------------------------------------

@@ -22,10 +22,20 @@ def load_experiments(path: str) -> Dict:
         return yaml.safe_load(f)
 
 
-def main(argv=None) -> int:
-    from ray_tpu.utils.platform import apply_platform_override
+def experiment_args(spec: Dict):
+    """``(run, config, stop)`` of one experiment spec (a yaml entry or
+    the flag-built equivalent): the config assembly this CLI hands to
+    ``tune.run`` — shared with ``chip_smoke.py`` so the smoke run
+    builds exactly what ``python -m ray_tpu.train -f`` would."""
+    config = dict(spec.get("config") or {})
+    if "env" in spec:
+        config["env"] = spec["env"]
+    stop = dict(spec.get("stop") or {})
+    stop.pop("time_total_s", None)
+    return spec["run"], config, stop
 
-    apply_platform_override()
+
+def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description="ray_tpu train CLI")
     parser.add_argument(
         "-f", "--file", type=str, default=None,
@@ -69,21 +79,10 @@ def main(argv=None) -> int:
         }
 
     for name, spec in experiments.items():
-        config = dict(spec.get("config") or {})
-        if "env" in spec:
-            config["env"] = spec["env"]
-        stop = dict(spec.get("stop") or {})
-        # yaml reward key parity with the reference regression format
-        stop.pop("time_total_s", None)
-        reward_stop = stop.pop("episode_reward_mean", None)
-        if reward_stop is not None:
-            stop["episode_reward_mean"] = reward_stop
-        timesteps = stop.pop("timesteps_total", None)
-        if timesteps is not None:
-            stop["timesteps_total"] = timesteps
-        print(f"== running experiment {name}: {spec.get('run')} ==")
+        run_name, config, stop = experiment_args(spec)
+        print(f"== running experiment {name}: {run_name} ==")
         analysis = run(
-            spec["run"],
+            run_name,
             config=config,
             stop=stop,
             num_samples=int(spec.get("num_samples", args.num_samples)),

@@ -16,18 +16,11 @@ leftover, wired at the ``JaxPolicy._build_learn_fn`` call sites):
 """
 
 import numpy as np
-import pytest
 
 import jax
 
 from ray_tpu import sharding as sharding_lib
 from ray_tpu.data.sample_batch import SampleBatch as SB
-from ray_tpu.sharding import aot as aot_lib
-
-pytestmark = pytest.mark.skipif(
-    not aot_lib.supported(),
-    reason="this jax build cannot serialize compiled executables",
-)
 
 BS = 16
 

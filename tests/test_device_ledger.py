@@ -243,6 +243,11 @@ def test_report_cli_renders_trace_and_ledger(tmp_path, capsys):
 # -- bit parity: ledger + tracing + profiler must not touch numerics ---
 
 
+@pytest.mark.slow  # ~6 s; fails at seed under jax 0.9.0, passes since
+# PR 21 — moved out of tier-1 by that PR's budget rule (the newly
+# passing tests compile and run where they used to fail at trace
+# time; only newly passing ones may leave); tier-1 keeps
+# test_superstep.py's PPO superstep bit-parity pin
 def test_policy_superstep_bit_parity_with_ledger(tmp_path):
     """Fixed-seed superstep PPO chain with the full ledger (AOT
     analysis) and span tracing running is BITWISE identical to the

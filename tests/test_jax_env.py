@@ -305,6 +305,11 @@ def test_ppo_jax_lane_lifecycle():
         algo.cleanup()
 
 
+@pytest.mark.slow  # ~6 s; fails at seed under jax 0.9.0, passes since
+# PR 21 — moved out of tier-1 by that PR's budget rule (the newly
+# passing tests compile and run where they used to fail at trace
+# time; only newly passing ones may leave); tier-1 keeps the
+# fused-vs-unfused and trajectory/GAE lane parity pins
 def test_ppo_lane_episode_parity_e2e():
     """Both lanes through the full Algorithm: identical episode
     stream (same env seeds, same action stream) on one iteration."""

@@ -334,6 +334,11 @@ def test_superstep_partitioned_zero_recompile_and_parity():
 # -- checkpoint reshard ------------------------------------------------
 
 
+@pytest.mark.slow  # ~4 s; fails at seed under jax 0.9.0, passes since
+# PR 21 — moved out of tier-1 by that PR's budget rule (the newly
+# passing tests compile and run where they used to fail at trace
+# time; only newly passing ones may leave); tier-1 keeps the
+# mp=1 bitwise-vs-replicated pin on the same learn path
 def test_checkpoint_reshard_roundtrip_across_geometries():
     rng = np.random.default_rng(4)
     batch = _ppo_batch(rng)

@@ -230,6 +230,33 @@ def test_mesh_pmap_parity_fixed_seed_ppo(n_dev):
         np.testing.assert_array_equal(a, b)
 
 
+def test_gradient_is_the_mean_over_shards_not_the_sum():
+    """One full-batch step has ONE true gradient, whatever the mesh:
+    ``grad_gnorm`` must not scale with the shard count. (jax types a
+    replicated param inside ``shard_map`` as invarying and sums its
+    gradient over shards itself; the nest differentiates a per-shard
+    view so the explicit pmean stays the only reduction.)"""
+    from ray_tpu.algorithms.ppo.ppo import PPOJaxPolicy
+
+    norms = {}
+    for n_dev in (1, 4):
+        pol = PPOJaxPolicy(
+            gym.spaces.Box(-1.0, 1.0, (8,), np.float32),
+            gym.spaces.Discrete(4),
+            {
+                "_mesh": sl.get_mesh(devices=jax.devices()[:n_dev]),
+                "model": {"fcnet_hiddens": [16]},
+                "train_batch_size": 32,
+                "sgd_minibatch_size": 32,
+                "num_sgd_iter": 1,
+                "lr": 1e-3,
+                "seed": 0,
+            },
+        )
+        norms[n_dev] = pol.learn_on_batch(_ppo_batch())["grad_gnorm"]
+    np.testing.assert_allclose(norms[4], norms[1], rtol=1e-5)
+
+
 def test_learn_timers_and_train_results(tmp_path):
     """Per-stage learner timers ride the policy and train() results;
     save_checkpoint survives (and is atomic — temp names never leak)."""

@@ -280,6 +280,11 @@ def test_sac_superstep_device_rings_parity():
     assert _eq_trees(fused[2], p_a.aux_state)
 
 
+@pytest.mark.slow  # ~5 s; fails at seed under jax 0.9.0, passes since
+# PR 21 — moved out of tier-1 by that PR's budget rule (the newly
+# passing tests compile and run where they used to fail at trace
+# time; only newly passing ones may leave); tier-1 keeps the PPO
+# superstep bit-parity pin and the device-tree update-order tests
 def test_dqn_prioritized_superstep_parity():
     """DQN + prioritized replay, host AND device buffers, single-shard
     mesh: superstep_train_replay is bit-identical — params, opt-state,
@@ -616,6 +621,10 @@ def test_learner_thread_superstep_fuses_queued_batches():
     assert infos and all(np.isfinite(i[1]["total_loss"]) for i in infos)
 
 
+@pytest.mark.slow  # ~5 s; fails at seed under jax 0.9.0, passes since
+# PR 21 — moved out of tier-1 by that PR's budget rule (the newly
+# passing tests compile and run where they used to fail at trace
+# time; only newly passing ones may leave)
 def test_dqn_chained_updates_superstep_and_recovery(tmp_path):
     """DQN end-to-end with ``superstep=2`` + training_intensity: the
     chained path runs fused windows (superstep counter moves), a
