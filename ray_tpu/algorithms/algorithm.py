@@ -302,7 +302,6 @@ class Algorithm(Trainable):
         )
         h2d_before = telemetry_lib.metrics.h2d_bytes_by_path()
         d2h_before = telemetry_lib.metrics.d2h_bytes_by_path()
-        results: Dict[str, Any] = {}
         train_info: Dict[str, Any] = {}
         min_t = config.get("min_time_s_per_iteration")
         min_ts = config.get("min_sample_timesteps_per_iteration") or 0
@@ -352,8 +351,29 @@ class Algorithm(Trainable):
             # iteration's telemetry window)
             self._recovery.maybe_checkpoint()
         t_train_end = time.time()
+        # what train() does outside training_step has a span of its
+        # own, so a profile shows it beside the layers' spans
+        with tracing.start_span("train:result"):
+            results = self._iteration_result(
+                train_info, t0, t_train_end, ts_before, learn_before,
+                superstep_before, h2d_before, d2h_before,
+            )
         self._maybe_stop_profile()
+        return results
 
+    def _iteration_result(
+        self, train_info, t0, t_train_end, ts_before, learn_before,
+        superstep_before, h2d_before, d2h_before,
+    ) -> Dict:
+        """The result dict of the iteration that ran from ``t0`` to
+        ``t_train_end``: counters, timers, recovery and telemetry
+        roll-ups (the ``*_before`` arguments are the counter readings
+        taken at ``t0``), rollout metrics, evaluation, callbacks."""
+        from ray_tpu import telemetry as telemetry_lib
+        from ray_tpu.util import tracing
+
+        config = self.config
+        results: Dict[str, Any] = {}
         results["info"] = {
             "learner": train_info,
             **{k: v for k, v in self._counters.items()},

@@ -41,6 +41,7 @@ from ray_tpu.algorithms.dqn.dqn_model import (
     categorical_projection,
 )
 from ray_tpu.policy.jax_policy import JaxPolicy
+from ray_tpu.util import tracing
 
 
 class DQNConfig(AlgorithmConfig):
@@ -850,14 +851,15 @@ class DQN(Algorithm):
         device-insert path for resident buffers (same donated scatter,
         zero H2D), one pull-back for host rings."""
         buf = self.local_replay_buffer._buffer(DEFAULT_POLICY_ID)
-        if isinstance(buf, DeviceReplayBuffer):
-            buf.add_device_tree(tree)
-        else:
-            import jax
+        with tracing.start_span("replay:insert"):
+            if isinstance(buf, DeviceReplayBuffer):
+                buf.add_device_tree(tree)
+            else:
+                import jax
 
-            self.local_replay_buffer.add(
-                SampleBatch(jax.device_get(tree))
-            )
+                self.local_replay_buffer.add(
+                    SampleBatch(jax.device_get(tree))
+                )
 
     def _jax_rollout_fill(self) -> int:
         """Device rollout lane for the off-policy family
@@ -953,8 +955,9 @@ class DQN(Algorithm):
             - self._last_target_update
             >= config.get("target_network_update_freq", 500)
         ):
-            for pid in self.workers.local_worker().policy_map:
-                self.get_policy(pid).update_target()
+            with tracing.start_span("learn:target_sync"):
+                for pid in self.workers.local_worker().policy_map:
+                    self.get_policy(pid).update_target()
             self._last_target_update = self._counters[
                 NUM_ENV_STEPS_TRAINED
             ]

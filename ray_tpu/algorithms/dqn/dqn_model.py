@@ -125,14 +125,18 @@ class DQNModel(RTModel):
             if obs.dtype == jnp.uint8:  # raw pixels only (VisionNet)
                 x = x / 255.0
             act = get_activation(self.conv_activation)
-            for conv in self._convs:
-                x = act(conv(x))
+            # layer scopes with the activation inside (flax's own
+            # module scope, `_convs_<i>`, ends before it)
+            for i, conv in enumerate(self._convs):
+                with jax.named_scope(f"conv{i}"):
+                    x = act(conv(x))
             x = x.reshape((x.shape[0], -1)).astype(jnp.float32)
         else:
             x = obs.astype(jnp.float32).reshape((obs.shape[0], -1))
         act = get_activation(self.activation)
-        for fc in self._fcs:
-            x = act(fc(x))
+        with jax.named_scope("fc"):
+            for fc in self._fcs:
+                x = act(fc(x))
         return x
 
     def q_dist(self, obs, noise_key=None):
@@ -145,16 +149,19 @@ class DQNModel(RTModel):
         if noise_key is not None:
             k_a, k_v = jax.random.split(noise_key)
         feat = self.features(obs)
-        adv = self._head(self._adv_head, feat, k_a).reshape(
-            (-1, self.num_outputs, self.num_atoms)
-        )
-        if self.dueling:
-            value = self._head(self._value_head, feat, k_v).reshape(
-                (-1, 1, self.num_atoms)
+        with jax.named_scope("head"):
+            adv = self._head(self._adv_head, feat, k_a).reshape(
+                (-1, self.num_outputs, self.num_atoms)
             )
-            support = value + adv - jnp.mean(adv, axis=1, keepdims=True)
-        else:
-            support = adv
+            if self.dueling:
+                value = self._head(
+                    self._value_head, feat, k_v
+                ).reshape((-1, 1, self.num_atoms))
+                support = (
+                    value + adv - jnp.mean(adv, axis=1, keepdims=True)
+                )
+            else:
+                support = adv
         if self.num_atoms > 1:
             probs = jax.nn.softmax(support, axis=-1)
             z = jnp.linspace(

@@ -15,6 +15,7 @@ import ray_tpu as ray
 from ray_tpu.evaluation.rollout_worker import RolloutWorker
 from ray_tpu.resilience.retry import RetryPolicy, probe_actors
 from ray_tpu.telemetry import metrics as telemetry_metrics
+from ray_tpu.util import tracing
 from ray_tpu.utils.filter import MeanStdFilter
 
 _ACTOR_DEAD_ERRORS = (
@@ -176,25 +177,30 @@ class WorkerSet:
         otherwise dominates the sync."""
         if self._local_worker is None:
             return
-        weights = self._local_worker.get_weights(
-            policies, inference_only=inference_only
-        )
-        if self._remote_workers:
-            ref = ray.put(weights)
-            targets = self._remote_workers
-            if to_worker_indices is not None:
-                targets = [
-                    w
-                    for i, w in enumerate(self._remote_workers)
-                    if i + 1 in to_worker_indices
-                ]
-            for w in targets:
-                try:
-                    w.set_weights.remote(ref, global_vars)
-                except _ACTOR_DEAD_ERRORS:
-                    # a corpse must not abort the broadcast to the
-                    # rest of the fleet (recovery replaces it later)
-                    continue
+        # the device->host pull of the acting weights is a blocking
+        # read whether or not a remote worker is there to take them
+        with tracing.start_span(
+            "rollout:sync_weights", workers=len(self._remote_workers)
+        ):
+            weights = self._local_worker.get_weights(
+                policies, inference_only=inference_only
+            )
+            if self._remote_workers:
+                ref = ray.put(weights)
+                targets = self._remote_workers
+                if to_worker_indices is not None:
+                    targets = [
+                        w
+                        for i, w in enumerate(self._remote_workers)
+                        if i + 1 in to_worker_indices
+                    ]
+                for w in targets:
+                    try:
+                        w.set_weights.remote(ref, global_vars)
+                    except _ACTOR_DEAD_ERRORS:
+                        # a corpse must not abort the broadcast to the
+                        # rest of the fleet (recovery replaces it later)
+                        continue
         if global_vars:
             self._local_worker.set_global_vars(global_vars)
 

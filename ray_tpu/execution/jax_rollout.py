@@ -178,29 +178,37 @@ class JaxRolloutEngine:
                 # compiles like the actor lane's standalone jitted
                 # programs (action fn / vmapped env step / reset) —
                 # the lane parity contract (docs/data_plane.md)
-                params_b, obs_b, key_t = jax.lax.optimization_barrier(
-                    (params, obs, key_t)
-                )
-                actions, _, extra, _ = policy._action_step_body(
-                    params_b, obs_b, key_t, coeffs,
-                    explore=True, expl_state=(),
-                )
-                # pin the OUTPUTS as well: the value head's result
-                # feeds the in-program GAE below, and without a
-                # barrier XLA fuses it differently than the actor
-                # lane's standalone action program (last-ulp drift)
-                actions, extra = jax.lax.optimization_barrier(
-                    (actions, extra)
-                )
-                env_state_b, actions_b = jax.lax.optimization_barrier(
-                    (env_state, actions)
-                )
-                env_state2, obs2, rew, term, trunc = step_b(
-                    env_state_b, actions_b
-                )
-                done = term | trunc
-                env_state2b = jax.lax.optimization_barrier(env_state2)
-                env_state3, obs3 = reset_b(env_state2b)
+                with jax.named_scope("rollout/act"):
+                    params_b, obs_b, key_t = (
+                        jax.lax.optimization_barrier(
+                            (params, obs, key_t)
+                        )
+                    )
+                    actions, _, extra, _ = policy._action_step_body(
+                        params_b, obs_b, key_t, coeffs,
+                        explore=True, expl_state=(),
+                    )
+                    # pin the OUTPUTS as well: the value head's result
+                    # feeds the in-program GAE below, and without a
+                    # barrier XLA fuses it differently than the actor
+                    # lane's standalone action program (last-ulp drift)
+                    actions, extra = jax.lax.optimization_barrier(
+                        (actions, extra)
+                    )
+                with jax.named_scope("rollout/env_step"):
+                    env_state_b, actions_b = (
+                        jax.lax.optimization_barrier(
+                            (env_state, actions)
+                        )
+                    )
+                    env_state2, obs2, rew, term, trunc = step_b(
+                        env_state_b, actions_b
+                    )
+                    done = term | trunc
+                    env_state2b = jax.lax.optimization_barrier(
+                        env_state2
+                    )
+                    env_state3, obs3 = reset_b(env_state2b)
                 rew = rew.astype(jnp.float32)
                 ep_ret2 = ep_ret + rew
                 ep_len2 = ep_len + 1
@@ -218,18 +226,20 @@ class JaxRolloutEngine:
                     # fresh V(final obs) for boundary/tail bootstraps
                     # — same (N,) forward shape as the act-path value,
                     # so the two lanes' bootstraps agree
-                    obs2_b = jax.lax.optimization_barrier(obs2)
-                    _, v_next, _ = value_fwd(params_b, obs2_b)
+                    with jax.named_scope("rollout/act"):
+                        obs2_b = jax.lax.optimization_barrier(obs2)
+                        _, v_next, _ = value_fwd(params_b, obs2_b)
                     row["_v_next"] = v_next
-                metrics = {
-                    "ep_return": jnp.where(done, ep_ret2, 0.0),
-                    "ep_length": jnp.where(done, ep_len2, 0),
-                    "done": done,
-                }
-                env_state = tree_where(done, env_state3, env_state2)
-                obs_next = tree_where(done, obs3, obs2)
-                ep_ret = jnp.where(done, 0.0, ep_ret2)
-                ep_len = jnp.where(done, 0, ep_len2)
+                with jax.named_scope("rollout/env_step"):
+                    metrics = {
+                        "ep_return": jnp.where(done, ep_ret2, 0.0),
+                        "ep_length": jnp.where(done, ep_len2, 0),
+                        "done": done,
+                    }
+                    env_state = tree_where(done, env_state3, env_state2)
+                    obs_next = tree_where(done, obs3, obs2)
+                    ep_ret = jnp.where(done, 0.0, ep_ret2)
+                    ep_len = jnp.where(done, 0, ep_len2)
                 return (
                     (env_state, obs_next, ep_ret, ep_len),
                     (row, metrics),
@@ -250,49 +260,54 @@ class JaxRolloutEngine:
                 "ep_ret": ep_ret,
                 "ep_len": ep_len,
             }
-            # global env index of each local row (host-lane
-            # AGENT_INDEX semantics)
-            shard0 = jax.lax.axis_index(axis) * n_loc
-            rows[SampleBatch.AGENT_INDEX] = jnp.broadcast_to(
-                shard0 + jnp.arange(n_loc, dtype=jnp.int32), (T, n_loc)
-            )
-            if mode == "gae":
-                values = rows[SampleBatch.VF_PREDS]  # (T, N)
-                fresh = rows.pop("_v_next")  # (T, N)
-                term = rows[SampleBatch.TERMINATEDS]
-                done = term | rows[SampleBatch.TRUNCATEDS]
-                # interior rows reuse the act-path values exactly like
-                # the host lane's vpred_t[1:]; boundary/tail rows use
-                # the fresh terminal-observation values
-                shifted = jnp.concatenate(
-                    [values[1:], fresh[-1:]], axis=0
+            with jax.named_scope("rollout/postprocess"):
+                # global env index of each local row (host-lane
+                # AGENT_INDEX semantics)
+                shard0 = jax.lax.axis_index(axis) * n_loc
+                rows[SampleBatch.AGENT_INDEX] = jnp.broadcast_to(
+                    shard0 + jnp.arange(n_loc, dtype=jnp.int32), (T, n_loc)
                 )
-                next_values = jnp.where(done, fresh, shifted)
-                adv, vt = compute_gae_fragment(
-                    rows[SampleBatch.REWARDS].T,
-                    values.T,
-                    next_values.T,
-                    term.T,
-                    done.T,
-                    gamma,
-                    lam,
-                )  # (N, T)
-                if standardize:
-                    m = jax.lax.pmean(adv.mean(), axis)
-                    var = jax.lax.pmean(((adv - m) ** 2).mean(), axis)
-                    adv = (adv - m) / jnp.maximum(
-                        1e-4, jnp.sqrt(var)
-                    )
-                rows[SampleBatch.ADVANTAGES] = adv.T
-                rows[SampleBatch.VALUE_TARGETS] = vt.T
+                if mode == "gae":
+                    with jax.named_scope("gae"):
+                        values = rows[SampleBatch.VF_PREDS]  # (T, N)
+                        fresh = rows.pop("_v_next")  # (T, N)
+                        term = rows[SampleBatch.TERMINATEDS]
+                        done = term | rows[SampleBatch.TRUNCATEDS]
+                        # interior rows reuse the act-path values
+                        # exactly like the host lane's vpred_t[1:];
+                        # boundary/tail rows use the fresh
+                        # terminal-observation values
+                        shifted = jnp.concatenate(
+                            [values[1:], fresh[-1:]], axis=0
+                        )
+                        next_values = jnp.where(done, fresh, shifted)
+                        adv, vt = compute_gae_fragment(
+                            rows[SampleBatch.REWARDS].T,
+                            values.T,
+                            next_values.T,
+                            term.T,
+                            done.T,
+                            gamma,
+                            lam,
+                        )  # (N, T)
+                        if standardize:
+                            m = jax.lax.pmean(adv.mean(), axis)
+                            var = jax.lax.pmean(
+                                ((adv - m) ** 2).mean(), axis
+                            )
+                            adv = (adv - m) / jnp.maximum(
+                                1e-4, jnp.sqrt(var)
+                            )
+                        rows[SampleBatch.ADVANTAGES] = adv.T
+                        rows[SampleBatch.VALUE_TARGETS] = vt.T
 
-            # (T, N, ...) -> env-major (N*T, ...) rows, the host
-            # lane's concat order
-            def to_rows(v):
-                v = jnp.swapaxes(v, 0, 1)
-                return v.reshape((n_loc * T,) + v.shape[2:])
+                # (T, N, ...) -> env-major (N*T, ...) rows, the host
+                # lane's concat order
+                def to_rows(v):
+                    v = jnp.swapaxes(v, 0, 1)
+                    return v.reshape((n_loc * T,) + v.shape[2:])
 
-            batch = {k: to_rows(v) for k, v in rows.items()}
+                batch = {k: to_rows(v) for k, v in rows.items()}
             return carry, batch, metrics
 
         self._body = body
@@ -394,12 +409,15 @@ class JaxRolloutEngine:
                     f"{self.N}x{self.T}]"
                 ),
             )
-        coeffs = self._pre_dispatch()
-        keys = []
-        for _ in range(self.T):
-            policy._rng, r = jax.random.split(policy._rng)
-            keys.append(r)
-        ro_rngs = jnp.stack(keys)
+        # host upkeep before the dispatch: exploration coefficients,
+        # then the T key splits
+        with tracing.start_span("rollout:keys", steps=self.T):
+            coeffs = self._pre_dispatch()
+            keys = []
+            for _ in range(self.T):
+                policy._rng, r = jax.random.split(policy._rng)
+                keys.append(r)
+            ro_rngs = jnp.stack(keys)
         telemetry_metrics.add_h2d_bytes("rollout", int(ro_rngs.nbytes))
         with tracing.start_span(
             "rollout:device", num_envs=self.N, steps=self.T
@@ -407,9 +425,14 @@ class JaxRolloutEngine:
             self._carry, batch, metrics = self._rollout_fn(
                 policy.params, self._carry, ro_rngs, coeffs
             )
-            metrics = jax.device_get(metrics)
-        self._record_metrics(metrics)
-        telemetry_metrics.inc_env_steps_on_device(self.batch_size)
+            # the one blocking read of the lane: the episode metrics
+            with tracing.start_span("rollout:drain") as drain:
+                metrics = jax.device_get(metrics)
+                drain.set_attribute(
+                    "bytes", sharding_lib.tree_nbytes(metrics)
+                )
+            self._record_metrics(metrics)
+            telemetry_metrics.inc_env_steps_on_device(self.batch_size)
         return dict(batch), self.batch_size
 
     def learn_batch(self, batch: Dict[str, Any]) -> Dict[str, Any]:

@@ -582,6 +582,7 @@ class DeviceReplayBuffer:
         up = self.use_pallas
         interp = self.pallas_interpret
 
+        @jax.named_scope("replay/insert")
         def fn(store, rows, pos):
             out = dict(store)
             for k, v in rows.items():
@@ -612,6 +613,7 @@ class DeviceReplayBuffer:
         up = self.use_pallas
         interp = self.pallas_interpret
 
+        @jax.named_scope("replay/gather")
         def fn(store, idx):
             out = {}
             for k, v in store.items():
@@ -818,6 +820,7 @@ class DeviceReplayBuffer:
         interp = self.pallas_interpret
         from ray_tpu.ops import framestack as framestack_lib
 
+        @jax.named_scope("replay/gather")
         def gather_fn(store, idx2):
             out = {}
             for k_, v in store.items():
@@ -1151,13 +1154,14 @@ class DevicePrioritizedReplayBuffer(_PrioritySampling, DeviceReplayBuffer):
             )
             idx32 = idx.astype(jnp.int32)
             out = {}
-            for k, v in store.items():
-                row_shape, dtype, packed = meta[k]
-                g = v[idx32]
-                if packed:
-                    u8 = jax.lax.bitcast_convert_type(g, jnp.uint8)
-                    g = u8.reshape((g.shape[0],) + row_shape)
-                out[k] = g
+            with jax.named_scope("replay/gather"):
+                for k, v in store.items():
+                    row_shape, dtype, packed = meta[k]
+                    g = v[idx32]
+                    if packed:
+                        u8 = jax.lax.bitcast_convert_type(g, jnp.uint8)
+                        g = u8.reshape((g.shape[0],) + row_shape)
+                    out[k] = g
             out["weights"] = weights
             return out, idx32
 

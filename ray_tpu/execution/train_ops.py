@@ -22,6 +22,7 @@ from ray_tpu.data.sample_batch import (
     MultiAgentBatch,
     SampleBatch,
 )
+from ray_tpu.util import tracing
 from ray_tpu.utils.metrics import timer_histogram
 
 NUM_ENV_STEPS_TRAINED = "num_env_steps_trained"
@@ -39,8 +40,6 @@ def train_one_step(algorithm, train_batch) -> Dict:
     of being fed to the optimizer, where a single NaN would corrupt
     the params beyond repair."""
     import time as _time
-
-    from ray_tpu.util import tracing
 
     injector = getattr(algorithm, "_fault_injector", None)
     if injector is not None:
@@ -139,23 +138,28 @@ def superstep_train_replay(
     )
     refresh = prioritized and policy._td_error_device_fn() is not None
     pad = k_max - k
-    if prioritized and device_tree:
-        idx, weights = buf.draw_prioritized_sets_device(
-            k, k_max, batch_size, beta
-        )
-    elif prioritized:
-        idx, weights = src.draw_prioritized_sets(k, batch_size, beta)
-    else:
-        idx = src.draw_index_sets(k, batch_size)
-        weights = None
-    if pad and not device_tree:
-        idx = np.concatenate(
-            [idx, np.zeros((pad, batch_size), idx.dtype)]
-        )
-        if weights is not None:
-            weights = np.concatenate(
-                [weights, np.ones((pad, batch_size), np.float32)]
+    # the draw schedule (host generator calls + the draw program under
+    # the device tree), on whichever plane holds the priorities
+    with tracing.start_span(
+        "replay:draw", k=k, batch_size=batch_size
+    ):
+        if prioritized and device_tree:
+            idx, weights = buf.draw_prioritized_sets_device(
+                k, k_max, batch_size, beta
             )
+        elif prioritized:
+            idx, weights = src.draw_prioritized_sets(k, batch_size, beta)
+        else:
+            idx = src.draw_index_sets(k, batch_size)
+            weights = None
+        if pad and not device_tree:
+            idx = np.concatenate(
+                [idx, np.zeros((pad, batch_size), idx.dtype)]
+            )
+            if weights is not None:
+                weights = np.concatenate(
+                    [weights, np.ones((pad, batch_size), np.float32)]
+                )
 
     if device_mode:
         extra = (
@@ -199,44 +203,47 @@ def superstep_train_replay(
             refresh_priorities=refresh,
         )
 
-    if prioritized and device_tree:
-        if pri is not None:
-            # ONE stacked device update, applied in update order with
-            # the skipped slots masked — the host tree walk is gone;
-            # what remains host-side is the alpha-power on the pulled
-            # |td| (docs/data_plane.md "device sum tree")
-            buf.refresh_priorities_stacked(
-                idx[:k], pri, active=[not s for s in skipped]
-            )
-        else:
+    # the PER refresh: host alpha-power of the drained |td|, then the
+    # tree writes (one stacked device update under the device tree)
+    with tracing.start_span("replay:refresh", k=k):
+        if prioritized and device_tree:
+            if pri is not None:
+                # ONE stacked device update, applied in update order with
+                # the skipped slots masked — the host tree walk is gone;
+                # what remains host-side is the alpha-power on the pulled
+                # |td| (docs/data_plane.md "device sum tree")
+                buf.refresh_priorities_stacked(
+                    idx[:k], pri, active=[not s for s in skipped]
+                )
+            else:
+                for i in range(k):
+                    if skipped[i]:
+                        continue
+                    buf.update_priorities(
+                        idx[i],
+                        np.full(
+                            batch_size,
+                            abs(infos[i].get("mean_td_error", 0.0)) + 1e-6,
+                        ),
+                    )
+        elif prioritized:
+            # apply in update order: overlapping draws must resolve
+            # exactly as the per-update path's interleaved writes would
             for i in range(k):
                 if skipped[i]:
                     continue
-                buf.update_priorities(
-                    idx[i],
-                    np.full(
-                        batch_size,
-                        abs(infos[i].get("mean_td_error", 0.0)) + 1e-6,
-                    ),
-                )
-    elif prioritized:
-        # apply in update order: overlapping draws must resolve
-        # exactly as the per-update path's interleaved writes would
-        for i in range(k):
-            if skipped[i]:
-                continue
-            if pri is not None:
-                src.update_priorities(idx[i], pri[i] + 1e-6)
-            else:
-                # policies without per-sample errors: batch-mean
-                # scalar fallback (mirrors DQN._single_update)
-                src.update_priorities(
-                    idx[i],
-                    np.full(
-                        batch_size,
-                        abs(infos[i].get("mean_td_error", 0.0)) + 1e-6,
-                    ),
-                )
+                if pri is not None:
+                    src.update_priorities(idx[i], pri[i] + 1e-6)
+                else:
+                    # policies without per-sample errors: batch-mean
+                    # scalar fallback (mirrors DQN._single_update)
+                    src.update_priorities(
+                        idx[i],
+                        np.full(
+                            batch_size,
+                            abs(infos[i].get("mean_td_error", 0.0)) + 1e-6,
+                        ),
+                    )
 
     n_skipped = sum(1 for s in skipped if s)
     if n_skipped and algorithm is not None:

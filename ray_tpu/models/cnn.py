@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import Sequence, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.base import RTModel, get_activation
@@ -49,22 +50,29 @@ class VisionNet(RTModel):
         x = obs.astype(dtype)
         if x.dtype == jnp.uint8 or obs.dtype == jnp.uint8:
             x = obs.astype(dtype) / 255.0
+        # layer scopes with the activation inside (flax's own module
+        # scope, `conv_<i>`, ends before it)
         for i, (ch, kernel, stride) in enumerate(self.conv_filters):
-            x = act(
-                nn.Conv(
-                    ch, kernel, strides=stride, padding="VALID",
-                    name=f"conv_{i}", dtype=dtype,
-                )(x)
-            )
+            with jax.named_scope(f"conv{i}"):
+                x = act(
+                    nn.Conv(
+                        ch, kernel, strides=stride, padding="VALID",
+                        name=f"conv_{i}", dtype=dtype,
+                    )(x)
+                )
         x = x.reshape(x.shape[0], -1)
-        for i, size in enumerate(self.post_fcnet_hiddens):
-            x = post_act(nn.Dense(size, name=f"post_fc_{i}", dtype=dtype)(x))
+        with jax.named_scope("fc"):
+            for i, size in enumerate(self.post_fcnet_hiddens):
+                x = post_act(
+                    nn.Dense(size, name=f"post_fc_{i}", dtype=dtype)(x)
+                )
 
-        logits = nn.Dense(
-            self.num_outputs, name="logits", dtype=jnp.float32,
-            kernel_init=nn.initializers.variance_scaling(
-                0.01, "fan_in", "truncated_normal"),
-        )(x.astype(jnp.float32))
+        with jax.named_scope("head"):
+            logits = nn.Dense(
+                self.num_outputs, name="logits", dtype=jnp.float32,
+                kernel_init=nn.initializers.variance_scaling(
+                    0.01, "fan_in", "truncated_normal"),
+            )(x.astype(jnp.float32))
         if self.vf_share_layers:
             value = nn.Dense(1, name="value", dtype=jnp.float32)(
                 x.astype(jnp.float32)

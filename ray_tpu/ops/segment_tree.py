@@ -229,33 +229,35 @@ def draw_body(
     ``beta`` are traced scalars so buffer growth and beta annealing
     never retrace. Returns ``(idx int64, weights f32, p_sample f64)``;
     every op except the two beta-powers is exact."""
+    import jax
     import jax.numpy as jnp
 
-    num_items = rand.shape[-1]
-    total = reduce_range_body(
-        sum_value, size, jnp.add, 0.0, capacity
-    )
-    strata = jnp.arange(num_items, dtype=jnp.float64)
-    mass = (rand + strata) / num_items * total
-    if use_pallas:
-        idx = find_prefixsum_pallas(
-            sum_value, mass, capacity, interpret=interpret
+    with jax.named_scope("replay/draw"):
+        num_items = rand.shape[-1]
+        total = reduce_range_body(
+            sum_value, size, jnp.add, 0.0, capacity
         )
-    else:
-        idx = find_prefixsum_body(sum_value, mass, capacity)
-    idx = jnp.clip(idx, 0, size - 1)
+        strata = jnp.arange(num_items, dtype=jnp.float64)
+        mass = (rand + strata) / num_items * total
+        if use_pallas:
+            idx = find_prefixsum_pallas(
+                sum_value, mass, capacity, interpret=interpret
+            )
+        else:
+            idx = find_prefixsum_body(sum_value, mass, capacity)
+        idx = jnp.clip(idx, 0, size - 1)
 
-    p_min = (
-        reduce_range_body(
-            min_value, size, jnp.minimum, float("inf"), capacity
+        p_min = (
+            reduce_range_body(
+                min_value, size, jnp.minimum, float("inf"), capacity
+            )
+            / total
         )
-        / total
-    )
-    max_weight = (p_min * size) ** (-beta)
-    p_sample = sum_value[capacity + idx] / total
-    weights = ((p_sample * size) ** (-beta) / max_weight).astype(
-        jnp.float32
-    )
+        max_weight = (p_min * size) ** (-beta)
+        p_sample = sum_value[capacity + idx] / total
+        weights = ((p_sample * size) ** (-beta) / max_weight).astype(
+            jnp.float32
+        )
     return idx, weights, p_sample
 
 
@@ -326,6 +328,7 @@ class DeviceSumTree:
     # -- updates --------------------------------------------------------
 
     def _build_update_fn(self, u: int, bp: int):
+        import jax
         import jax.numpy as jnp
 
         from ray_tpu import sharding as sharding_lib
@@ -334,16 +337,17 @@ class DeviceSumTree:
 
         # ray-tpu: f64
         def fn(sum_t, min_t, idx, vals, mask):
-            for i in range(u):
-                flat = jnp.where(mask[i], cap + idx[i], 0)
-                sum_t = sum_t.at[flat].set(
-                    jnp.where(mask[i], vals[i], sum_t[flat])
-                )
-                min_t = min_t.at[flat].set(
-                    jnp.where(mask[i], vals[i], min_t[flat])
-                )
-            sum_t = _rebuild_body(sum_t, jnp.add, cap)
-            min_t = _rebuild_body(min_t, jnp.minimum, cap)
+            with jax.named_scope("replay/refresh"):
+                for i in range(u):
+                    flat = jnp.where(mask[i], cap + idx[i], 0)
+                    sum_t = sum_t.at[flat].set(
+                        jnp.where(mask[i], vals[i], sum_t[flat])
+                    )
+                    min_t = min_t.at[flat].set(
+                        jnp.where(mask[i], vals[i], min_t[flat])
+                    )
+                sum_t = _rebuild_body(sum_t, jnp.add, cap)
+                min_t = _rebuild_body(min_t, jnp.minimum, cap)
             return sum_t, min_t
 
         rep = sharding_lib.replicated(self.mesh)

@@ -782,45 +782,52 @@ class JaxPolicy(Policy):
                 # __chunk__ columns hold one row per T-row unroll
                 # (chunk-start recurrent states); gather them by the
                 # unroll indices the row permutation selected
-                mb = {
-                    k: _unpack(
-                        k,
-                        (
-                            v[idx.reshape(-1, T_seq)[:, 0] // T_seq]
-                            if k.startswith("__chunk__")
-                            else v[idx]
-                        ),
-                    )
-                    for k, v in batch.items()
-                }
+                with jax.named_scope("learn/minibatch"):
+                    mb = {
+                        k: _unpack(
+                            k,
+                            (
+                                v[idx.reshape(-1, T_seq)[:, 0] // T_seq]
+                                if k.startswith("__chunk__")
+                                else v[idx]
+                            ),
+                        )
+                        for k, v in batch.items()
+                    }
                 # differentiate a per-shard view of the replicated
                 # params so the gradients stay per-shard and the pmean
                 # below is the one real cross-shard reduction
                 # (sharding/specs.py "varying-axes typing")
-                (loss, stats), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True
-                )(
-                    sharding_lib.varying(params, axis),
-                    aux, mb, mb_rng, coeffs,
-                )
-                grads = jax.lax.pmean(grads, axis)
-                updates, opt_state = tx.update(grads, opt_state, params)
-                lr = coeffs["lr"]
-                updates = jax.tree_util.tree_map(
-                    lambda u: -lr * u.astype(jnp.float32), updates
-                )
-                params = optax.apply_updates(params, updates)
+                with jax.named_scope("learn/loss_grad"):
+                    (loss, stats), grads = jax.value_and_grad(
+                        loss_fn, has_aux=True
+                    )(
+                        sharding_lib.varying(params, axis),
+                        aux, mb, mb_rng, coeffs,
+                    )
+                with jax.named_scope("learn/allreduce"):
+                    grads = jax.lax.pmean(grads, axis)
+                with jax.named_scope("learn/optimizer"):
+                    updates, opt_state = tx.update(
+                        grads, opt_state, params
+                    )
+                    lr = coeffs["lr"]
+                    updates = jax.tree_util.tree_map(
+                        lambda u: -lr * u.astype(jnp.float32), updates
+                    )
+                    params = optax.apply_updates(params, updates)
                 # grad_gnorm: FINAL minibatch only. The 12-leaf
                 # reduce+sqrt chain measures ~2x the model's own
                 # fwd+bwd per step on this backend (profile_nest2),
                 # so running it every step nearly halves nest MFU;
                 # the reference's torch learner likewise reports the
                 # last batch's extra_grad_info per update.
-                gnorm = jax.lax.cond(
-                    is_last,
-                    lambda: _global_norm(grads),
-                    lambda: jnp.float32(0.0),
-                )
+                with jax.named_scope("learn/grad_norm"):
+                    gnorm = jax.lax.cond(
+                        is_last,
+                        lambda: _global_norm(grads),
+                        lambda: jnp.float32(0.0),
+                    )
                 stats = dict(stats, total_loss=loss, grad_gnorm=gnorm)
                 return (params, opt_state), stats
 
@@ -848,11 +855,12 @@ class JaxPolicy(Policy):
                 return carry, stats
 
             rngs = jax.random.split(rng, num_iters)
-            (params, opt_state), stats = jax.lax.scan(
-                epoch,
-                (params, opt_state),
-                (rngs, jnp.arange(num_iters)),
-            )
+            with jax.named_scope("sgd_nest"):
+                (params, opt_state), stats = jax.lax.scan(
+                    epoch,
+                    (params, opt_state),
+                    (rngs, jnp.arange(num_iters)),
+                )
 
             # mean over epochs × minibatches, then over shards —
             # except grad_gnorm, which only the final step computed
@@ -1252,41 +1260,42 @@ class JaxPolicy(Policy):
             )
             fns[cache_key] = fn
 
-        coeffs = self._learn_coeffs()
-        # exact per-update host split order: learn split, then (iff the
-        # per-update priority pass consumes one) the td split. On the
-        # dieted path the whole chain runs as ONE fused program (k or
-        # 2k tiny split dispatches collapse to one — the dominant
-        # per-superstep host cost at K=8, bench.py --dispatch); the
-        # chain composes the same threefry splits in the same order,
-        # so the key stacks and the advanced self._rng are bit-
-        # identical to the sequential host loop.
-        td_rng = refresh_priorities and self._td_refresh_uses_rng
-        if sharding_lib.dispatch_diet_enabled():
-            rngs, pri = self._superstep_host_keys(
-                k, k_max, refresh_priorities, td_rng
-            )
-            rest = (pri,) if refresh_priorities else ()
-        else:
-            keys, pri_keys = [], []
-            for _ in range(k):
-                self._rng, r = jax.random.split(self._rng)
-                keys.append(r)
+        with tracing.start_span("learn:keys", k=k):
+            coeffs = self._learn_coeffs()
+            # exact per-update host split order: learn split, then (iff the
+            # per-update priority pass consumes one) the td split. On the
+            # dieted path the whole chain runs as ONE fused program (k or
+            # 2k tiny split dispatches collapse to one — the dominant
+            # per-superstep host cost at K=8, bench.py --dispatch); the
+            # chain composes the same threefry splits in the same order,
+            # so the key stacks and the advanced self._rng are bit-
+            # identical to the sequential host loop.
+            td_rng = refresh_priorities and self._td_refresh_uses_rng
+            if sharding_lib.dispatch_diet_enabled():
+                rngs, pri = self._superstep_host_keys(
+                    k, k_max, refresh_priorities, td_rng
+                )
+                rest = (pri,) if refresh_priorities else ()
+            else:
+                keys, pri_keys = [], []
+                for _ in range(k):
+                    self._rng, r = jax.random.split(self._rng)
+                    keys.append(r)
+                    if refresh_priorities:
+                        if td_rng:
+                            self._rng, r2 = jax.random.split(self._rng)
+                        else:
+                            r2 = jnp.zeros_like(r)
+                        pri_keys.append(r2)
+                pad_key = jnp.zeros_like(keys[0])
+                while len(keys) < k_max:
+                    keys.append(pad_key)
+                rngs = jnp.stack(keys)
+                rest = ()
                 if refresh_priorities:
-                    if td_rng:
-                        self._rng, r2 = jax.random.split(self._rng)
-                    else:
-                        r2 = jnp.zeros_like(r)
-                    pri_keys.append(r2)
-            pad_key = jnp.zeros_like(keys[0])
-            while len(keys) < k_max:
-                keys.append(pad_key)
-            rngs = jnp.stack(keys)
-            rest = ()
-            if refresh_priorities:
-                while len(pri_keys) < k_max:
-                    pri_keys.append(pad_key)
-                rest = (jnp.stack(pri_keys),)
+                    while len(pri_keys) < k_max:
+                        pri_keys.append(pad_key)
+                    rest = (jnp.stack(pri_keys),)
         active = self._active_mask(k, k_max)
 
         if rings is not None:
@@ -1345,51 +1354,56 @@ class JaxPolicy(Policy):
             # ONE drain for the whole chain: the stacked stats tree
             # (and the PER priority matrix) come back in a single
             # device→host readback
-            if pri is not None:
-                # ray-tpu: allow[RTA005] the ONE counted drain for the chain
-                stats, pri = jax.device_get((stats, pri))
-                pri = np.abs(np.asarray(pri)[:k])
-                # the |td| pull that feeds the host alpha-power — the
-                # PER path's one remaining D2H (docs/data_plane.md)
-                telemetry_metrics.add_d2h_bytes(
-                    "replay_priorities", pri.nbytes
-                )
-            else:
-                # ray-tpu: allow[RTA005] the ONE counted drain for the chain
-                stats = jax.device_get(stats)
+            with tracing.start_span("learn:drain") as _drain:
+                if pri is not None:
+                    # ray-tpu: allow[RTA005] the ONE counted drain for the chain
+                    stats, pri = jax.device_get((stats, pri))
+                    pri = np.abs(np.asarray(pri)[:k])
+                    # the |td| pull that feeds the host alpha-power —
+                    # the PER path's one remaining D2H
+                    # (docs/data_plane.md)
+                    telemetry_metrics.add_d2h_bytes(
+                        "replay_priorities", pri.nbytes
+                    )
+                    _drain.set_attribute("bytes", pri.nbytes)
+                else:
+                    # ray-tpu: allow[RTA005] the ONE counted drain for the chain
+                    stats = jax.device_get(stats)
             # the drain proves the superstep program finished: close
             # its device-busy interval in the ledger (timestamps only,
             # no extra sync)
             device_ledger.drain_point()
-        self.num_grad_updates += k * self._updates_per_learn_call(
-            batch_size
-        )
-        self._after_superstep()
-        telemetry_metrics.counter(
-            telemetry_metrics.LEARN_STEPS_TOTAL,
-            "SGD-nest programs dispatched",
-        ).inc(float(k))
-        telemetry_metrics.inc_superstep_updates(k)
-        self.last_learn_timers["learn_superstep_s"] = (
-            _time.perf_counter() - t0
-        )
-        self.last_learn_timers["learn_recompiles"] = float(
-            getattr(fn, "traces", 0) - compiles_before
-        )
+            # the host's share of the chain, still inside the span: the
+            # counters and the per-update stat dicts
+            self.num_grad_updates += k * self._updates_per_learn_call(
+                batch_size
+            )
+            self._after_superstep()
+            telemetry_metrics.counter(
+                telemetry_metrics.LEARN_STEPS_TOTAL,
+                "SGD-nest programs dispatched",
+            ).inc(float(k))
+            telemetry_metrics.inc_superstep_updates(k)
+            self.last_learn_timers["learn_superstep_s"] = (
+                _time.perf_counter() - t0
+            )
+            self.last_learn_timers["learn_recompiles"] = float(
+                getattr(fn, "traces", 0) - compiles_before
+            )
 
-        skip = np.asarray(
-            stats.get(superstep_lib.SKIP_KEY, np.zeros(k_max))
-        )
-        skipped = [bool(skip[i] > 0.5) for i in range(k)]
-        infos = [
-            {
-                name: float(np.asarray(v)[i])
-                for name, v in stats.items()
-                if name != superstep_lib.SKIP_KEY
-            }
-            for i in range(k)
-        ]
-        return infos, pri, skipped
+            skip = np.asarray(
+                stats.get(superstep_lib.SKIP_KEY, np.zeros(k_max))
+            )
+            skipped = [bool(skip[i] > 0.5) for i in range(k)]
+            infos = [
+                {
+                    name: float(np.asarray(v)[i])
+                    for name, v in stats.items()
+                    if name != superstep_lib.SKIP_KEY
+                }
+                for i in range(k)
+            ]
+            return infos, pri, skipped
 
     # ray-tpu: hot-path
     def learn_rollout_superstep(
@@ -1456,31 +1470,32 @@ class JaxPolicy(Policy):
             )
             fns[cache_key] = fn
 
-        coeffs = self._learn_coeffs()
-        T = int(rollout.steps)
-        # host rng schedule: T rollout splits then the learn split per
-        # slot. Dieted path fuses the whole k*(T+1)-split chain into
-        # ONE dispatch (bit-identical keys — same threefry chain, same
-        # order); see learn_superstep.
-        if sharding_lib.dispatch_diet_enabled():
-            rngs, ro_rngs = self._rollout_host_keys(k, k_max, T)
-        else:
-            learn_keys, ro_keys = [], []
-            for _ in range(k):
-                slot = []
-                for _ in range(T):
+        with tracing.start_span("learn:keys", k=k, rollout=True):
+            coeffs = self._learn_coeffs()
+            T = int(rollout.steps)
+            # host rng schedule: T rollout splits then the learn split per
+            # slot. Dieted path fuses the whole k*(T+1)-split chain into
+            # ONE dispatch (bit-identical keys — same threefry chain, same
+            # order); see learn_superstep.
+            if sharding_lib.dispatch_diet_enabled():
+                rngs, ro_rngs = self._rollout_host_keys(k, k_max, T)
+            else:
+                learn_keys, ro_keys = [], []
+                for _ in range(k):
+                    slot = []
+                    for _ in range(T):
+                        self._rng, r = jax.random.split(self._rng)
+                        slot.append(r)
+                    ro_keys.append(jnp.stack(slot))
                     self._rng, r = jax.random.split(self._rng)
-                    slot.append(r)
-                ro_keys.append(jnp.stack(slot))
-                self._rng, r = jax.random.split(self._rng)
-                learn_keys.append(r)
-            pad = jnp.zeros_like(learn_keys[0])
-            pad_slot = jnp.zeros_like(ro_keys[0])
-            while len(learn_keys) < k_max:
-                learn_keys.append(pad)
-                ro_keys.append(pad_slot)
-            rngs = jnp.stack(learn_keys)
-            ro_rngs = jnp.stack(ro_keys)
+                    learn_keys.append(r)
+                pad = jnp.zeros_like(learn_keys[0])
+                pad_slot = jnp.zeros_like(ro_keys[0])
+                while len(learn_keys) < k_max:
+                    learn_keys.append(pad)
+                    ro_keys.append(pad_slot)
+                rngs = jnp.stack(learn_keys)
+                ro_rngs = jnp.stack(ro_keys)
         active = self._active_mask(k, k_max)
         # the lane's entire H2D payload: key stacks + the mask
         telemetry_metrics.add_h2d_bytes(
@@ -1515,8 +1530,9 @@ class JaxPolicy(Policy):
                 getattr(fn, "traces", 0) - compiles_before,
             )
             # ONE drain: stacked stats + episode metrics together
-            # ray-tpu: allow[RTA005] the ONE counted drain for the chain
-            stats, metrics = jax.device_get((stats, metrics))
+            with tracing.start_span("learn:drain"):
+                # ray-tpu: allow[RTA005] the ONE counted drain for the chain
+                stats, metrics = jax.device_get((stats, metrics))
             # drain done → the fused rollout+learn program is finished;
             # close its ledger interval (timestamps only)
             device_ledger.drain_point()

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import re
 import threading
 import time
 import weakref
@@ -56,6 +57,18 @@ def set_dispatch_diet(on: bool) -> bool:
     prev = _DIET
     _DIET = bool(on)
     return prev
+
+
+def label_family(label: str) -> str:
+    """What a program is called in a profiler trace: its label up to
+    the first ``[``, reduced to ``[A-Za-z0-9_]``
+    (``replay_insert[buf:...]`` -> ``replay_insert``). The function
+    handed to ``jax.jit`` carries it as its name, so the trace reads
+    ``jit_replay_insert(<fingerprint>)`` on ``XLA Modules`` and
+    ``PjitFunction(replay_insert)`` on the host's plane; the label,
+    with its sizes, stays the key of ``compile_stats()`` and of the
+    device ledger."""
+    return re.sub(r"[^A-Za-z0-9_]", "_", label.split("[", 1)[0]) or "sharded_fn"
 
 
 def _mesh_geometry_token(tree) -> Tuple:
@@ -147,6 +160,8 @@ class ShardedFunction:
                 with self._lock:
                     self.traces += 1
             return fn(*args, **kwargs)
+
+        _counted.__name__ = _counted.__qualname__ = label_family(self.label)
 
         kw: Dict[str, Any] = {}
         if in_specs is not None:

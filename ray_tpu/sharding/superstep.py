@@ -242,13 +242,15 @@ def build_superstep_fn(
                     lambda n, o: jnp.where(ok > 0.5, n, o), new, old
                 )
 
-            params = keep(new_p, params)
-            opt_state = keep(new_o, opt_state)
-            aux = keep(new_a, aux)
+            with jax.named_scope("learn/commit"):
+                params = keep(new_p, params)
+                opt_state = keep(new_o, opt_state)
+                aux = keep(new_a, aux)
             if with_pri:
                 # post-update state, matching the per-update path's
                 # learn -> compute_td_error -> update_priorities order
-                pri = priority_fn(params, aux, batch, pri_rng)
+                with jax.named_scope("learn/td_error"):
+                    pri = priority_fn(params, aux, batch, pri_rng)
                 return (params, opt_state, aux), (stats, pri)
             return (params, opt_state, aux), stats
 
@@ -395,9 +397,10 @@ def _build_rollout_superstep(
                     lambda n, o: jnp.where(ok > 0.5, n, o), new, old
                 )
 
-            params = keep(new_p, params)
-            opt_state = keep(new_o, opt_state)
-            aux = keep(new_a, aux)
+            with jax.named_scope("learn/commit"):
+                params = keep(new_p, params)
+                opt_state = keep(new_o, opt_state)
+                aux = keep(new_a, aux)
             # a nan-guarded slot keeps its ROLLOUT (those env steps
             # happened; the host counts them) but reverts the update;
             # only an INACTIVE slot reverts the env advance

@@ -22,6 +22,28 @@ Usage::
 
 Enable for every process with ``RAY_TPU_TRACE=1`` (workers inherit the
 env), or per-driver with :func:`enable`.
+
+Two outputs, two clocks:
+
+- the span list (:func:`get_spans`, :func:`export_chrome_trace`, the
+  spans that ride back from workers) is stamped with ``time.time()``:
+  wall-clock seconds, comparable across processes up to their skew;
+- while a ``jax.profiler`` session is live in this process
+  (``profile_iters``, any ``jax.profiler.start_trace``) every span
+  site is ALSO a ``jax.profiler.TraceAnnotation`` of the same name and
+  attributes. It lands on the ``/host:CPU`` plane of the session's
+  ``.xplane.pb`` on the profiler's own clock (nanoseconds from about
+  ``start_trace``), the clock the device's operations are on, so a
+  program span lies over the device's idle gaps with no conversion.
+
+The profiler session is the switch for the second output; there is no
+other. Three states: :func:`enable` on and no session, the span list
+only; a session and :func:`enable` never called, the annotation only
+(no ``Span`` object, no uuid, nothing appended); neither, the null
+span, at the cost of one ``TraceAnnotation.is_enabled()`` per span
+site and of nothing in a process that has not imported jax. This
+module is imported by the CPU rollout workers and never imports jax
+itself: it looks the class up once jax is in ``sys.modules``.
 """
 
 from __future__ import annotations
@@ -30,6 +52,7 @@ import contextlib
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -68,6 +91,49 @@ def is_enabled() -> bool:
     return _enabled
 
 
+# -- the profiler's trace as a second output ---------------------------
+
+# jax.profiler.TraceAnnotation extended with the span interface, built
+# the first time a span site runs in a process that has imported jax
+_annotation = None
+
+
+def _annotation_class():
+    global _annotation
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+
+    class ProfilerSpan(profiler.TraceAnnotation):
+        """What a span site yields under a profiler-only session: the
+        annotation itself, with the null span's ids."""
+
+        __slots__ = ()
+        trace_id = None
+        span_id = None
+        parent_id = None
+
+        def set_attribute(self, key: str, value: Any) -> None:
+            self.set_metadata(**{key: value})
+
+    _annotation = ProfilerSpan
+    return ProfilerSpan
+
+
+def profiling() -> bool:
+    """Whether a ``jax.profiler`` session is live in this process."""
+    cls = _annotation or _annotation_class()
+    return cls is not None and cls.is_enabled()
+
+
+def _annotate(name: str, attributes: Dict[str, Any]):
+    """A context manager that is the profiler's annotation while a
+    session is live, and nothing otherwise."""
+    if profiling():
+        return _annotation(name, **attributes)
+    return contextlib.nullcontext()
+
+
 class Span:
     __slots__ = (
         "trace_id",
@@ -80,9 +146,13 @@ class Span:
         "process",
         "thread",
         "thread_name",
+        "note",
     )
 
     def __init__(self, name: str, trace_id=None, parent_id=None):
+        # the profiler's annotation of this span, while it is open
+        # under a live session (start_span sets it)
+        self.note = None
         self.trace_id = trace_id or uuid.uuid4().hex[:16]
         self.span_id = uuid.uuid4().hex[:16]
         self.parent_id = parent_id
@@ -99,6 +169,8 @@ class Span:
 
     def set_attribute(self, key: str, value: Any) -> None:
         self.attributes[key] = value
+        if self.note is not None:
+            self.note.set_metadata(**{key: value})
 
     def finish(self, end: Optional[float] = None) -> Dict:
         self.end = time.time() if end is None else end
@@ -120,9 +192,10 @@ class Span:
 
 
 class _NullSpan:
-    """Returned by start_span when tracing is off: every operation is a
-    no-op, so the disabled hot path costs one flag check (no uuid, no
-    clock reads, no allocation)."""
+    """Returned by start_span when tracing is off and no profiler
+    session is live: every operation is a no-op, so the disabled hot
+    path costs two flag checks (no uuid, no clock reads, no
+    allocation)."""
 
     __slots__ = ()
     trace_id = None
@@ -137,38 +210,54 @@ _NULL_SPAN = _NullSpan()
 
 
 @contextlib.contextmanager
-def start_span(name: str, **attributes):
-    """Open a span under the current one (driver or worker side)."""
-    if not _enabled:
-        yield _NULL_SPAN
-        return
-    parent = _current.get()
-    span = Span(
-        name,
-        trace_id=parent.trace_id if parent else None,
-        parent_id=parent.span_id if parent else None,
-    )
+def _open(span: "Span", attributes: Dict[str, Any]):
+    """Run the body under ``span`` as the current one (and under the
+    profiler's annotation of it, where a session is live)."""
     for k, v in attributes.items():
         span.set_attribute(k, v)
     token = _current.set(span)
     try:
-        yield span
+        with _annotate(span.name, attributes) as span.note:
+            yield span
     finally:
+        span.note = None
         _current.reset(token)
         span.finish()
 
 
-def event(name: str, **attributes) -> None:
-    """Record a zero-duration span (dead worker, recompile, ...)
-    parented under the current span. No-op when tracing is off."""
-    if not _enabled:
-        return
+def _child(name: str) -> "Span":
     parent = _current.get()
-    span = Span(
+    return Span(
         name,
         trace_id=parent.trace_id if parent else None,
         parent_id=parent.span_id if parent else None,
     )
+
+
+@contextlib.contextmanager
+def start_span(name: str, **attributes):
+    """Open a span under the current one (driver or worker side)."""
+    if not _enabled:
+        if profiling():
+            with _annotation(name, **attributes) as note:
+                yield note
+        else:
+            yield _NULL_SPAN
+        return
+    with _open(_child(name), attributes) as span:
+        yield span
+
+
+def event(name: str, **attributes) -> None:
+    """Record a zero-duration span (dead worker, recompile, ...)
+    parented under the current span. No-op when tracing is off and no
+    profiler session is live."""
+    if profiling():
+        with _annotation(name, **attributes):
+            pass
+    if not _enabled:
+        return
+    span = _child(name)
     span.attributes.update(attributes)
     span.finish(end=span.start)
 
@@ -178,22 +267,20 @@ def record_span(
 ) -> None:
     """Record a span whose interval was measured out-of-band (e.g. a
     queue wait that ended when ``get()`` returned). ``start``/``end``
-    are ``time.time()`` stamps. No-op when tracing is off."""
+    are ``time.time()`` stamps. The profiler's clock cannot be
+    back-dated: under a live session the span is an annotation of no
+    length at the moment of this call, with the interval's length as
+    its ``seconds`` attribute. No-op when tracing is off and no
+    session is live."""
+    if profiling():
+        with _annotation(name, seconds=end - start, **attributes):
+            pass
     if not _enabled:
         return
-    parent = _current.get()
-    span = Span(
-        name,
-        trace_id=parent.trace_id if parent else None,
-        parent_id=parent.span_id if parent else None,
-    )
+    span = _child(name)
     span.start = start
     span.attributes.update(attributes)
     span.finish(end=end)
-
-
-def get_current_span() -> Optional[Span]:
-    return _current.get()
 
 
 # -- boundary plumbing (called by core/api.py and core/worker_proc.py) --
@@ -247,16 +334,21 @@ def context_span(ctx: Optional[Dict], name: str, **attributes):
     stitch into one trace even though they run on different threads,
     where contextvars can't carry the parent). Unlike
     :func:`remote_span` this never force-enables tracing — when the
-    process has tracing off it costs one flag check and yields the
-    null span, so it is safe on the serve hot path. ``ctx`` is an
+    process has tracing off and no profiler session is live it costs
+    two flag checks and yields the null span, so it is safe on the
+    serve hot path. ``ctx`` is an
     :func:`inject_context`-shaped dict; ``None`` falls back to the
     calling context's current span (plain :func:`start_span`
     semantics)."""
     if not _enabled:
-        yield _NULL_SPAN
+        if profiling():
+            with _annotation(name, **attributes) as note:
+                yield note
+        else:
+            yield _NULL_SPAN
         return
     if ctx is None:
-        with start_span(name, **attributes) as span:
+        with _open(_child(name), attributes) as span:
             yield span
         return
     span = Span(
@@ -264,14 +356,8 @@ def context_span(ctx: Optional[Dict], name: str, **attributes):
         trace_id=ctx.get("trace_id"),
         parent_id=ctx.get("parent_span_id"),
     )
-    for k, v in attributes.items():
-        span.set_attribute(k, v)
-    token = _current.set(span)
-    try:
+    with _open(span, attributes):
         yield span
-    finally:
-        _current.reset(token)
-        span.finish()
 
 
 def drain_finished() -> List[Dict]:
