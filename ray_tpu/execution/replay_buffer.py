@@ -30,6 +30,7 @@ Two storage planes (docs/data_plane.md):
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
@@ -387,6 +388,57 @@ class SuperstepRingFeed:
         self.key = key  # compile-cache key: the stored column set
 
 
+def _stored_words(row_shape: tuple) -> int:
+    """Words one packed uint8 row occupies in its device ring: the
+    stored row width rule of :class:`DeviceReplayBuffer`'s docstring
+    (stated there, once)."""
+    words = int(np.prod(row_shape)) // 4
+    lanes = -(-words // 128) * 128
+    return lanes if 4 * lanes <= 5 * words else words
+
+
+def _pack_rows(v, width: int):
+    """``u8[R, *row_shape] -> u32[R, width]``: four pixels a word,
+    zero pad up to the ring's stored width."""
+    import jax
+    import jax.numpy as jnp
+
+    words = jax.lax.bitcast_convert_type(
+        v.reshape(v.shape[0], -1, 4), jnp.uint32
+    )
+    return jnp.pad(words, ((0, 0), (0, width - words.shape[1])))
+
+
+def _unpack_rows(g, row_shape: tuple):
+    """``u32[..., width] -> u8[..., *row_shape]``: slice the pad off,
+    then bitcast (a view of the same bytes)."""
+    import jax
+    import jax.numpy as jnp
+
+    words = int(np.prod(row_shape)) // 4
+    u8 = jax.lax.bitcast_convert_type(g[..., :words], jnp.uint8)
+    return u8.reshape(g.shape[:-1] + tuple(row_shape))
+
+
+def _gather_columns(store, idx, meta, use_pallas=None, interpret=False):
+    """Rows ``idx`` (any int shape) of every ring in ``store`` as
+    logical columns — the one gather body of ``gather``/``sample``,
+    the fused tree sample and the superstep's ring feed."""
+    import jax
+
+    from ray_tpu.ops import framestack as framestack_lib
+
+    out = {}
+    with jax.named_scope("replay/gather"):
+        for k, ring in store.items():
+            row_shape, _, packed = meta[k]
+            g = framestack_lib.gather_rows(
+                ring, idx, use_pallas=use_pallas, interpret=interpret
+            )
+            out[k] = _unpack_rows(g, row_shape) if packed else g
+    return out
+
+
 class DeviceReplayBuffer:
     """Uniform ring buffer whose column storage lives on the learner
     mesh (docs/data_plane.md).
@@ -396,6 +448,24 @@ class DeviceReplayBuffer:
       uint8 columns (pixel obs) are stored packed as uint32 lanes —
       the same element-width trick as ``_build_learn_fn``'s minibatch
       gather (MFU.md) — so the sample gather moves 4× wider elements.
+    - **Stored row width** of a packed column: ``inner // 4`` words
+      rounded up to whole 128-word lanes when that costs at most a
+      quarter more storage (84×84×4: 7,056 → 7,168 words, +1.6%;
+      one 84×84 frame: 1,764 → 1,792), else ``inner // 4`` as is. The
+      TPU client lays a 2-D array out with the ROW INDEX minor
+      (``{0,1:T(8,128)}``) whenever its last dimension is not a
+      multiple of 128, and a ring stored that way is transposed whole
+      before any row of it is scattered or gathered (3.7 GB a copy at
+      131,072 pixel rows); a whole number of lanes is laid out
+      row-major (``{1,0}``), the scatter runs in place on the donated
+      ring and the gather reads rows. Narrow rows (a few dozen words:
+      vector observations, small uint8 rows) are read column-major
+      natively and need nothing, which is why the rule stops where
+      the pad would multiply the storage. The pad words are zero,
+      are written with each row and sliced off after every gather;
+      ``storage_bytes`` counts what is allocated, and a checkpoint
+      (``get_state``) holds logical rows, so it loads whatever width
+      wrote it.
     - **Sample** draws indices on the HOST from the same seeded
       generator (same call order) as the host :class:`ReplayBuffer`,
       then gathers rows in one jit'd program; a fixed seed therefore
@@ -501,6 +571,13 @@ class DeviceReplayBuffer:
             and inner % 4 == 0
         )
 
+    @classmethod
+    def _ring_shape_dtype(cls, capacity: int, row_shape: tuple, dtype):
+        """``(shape, dtype)`` of the ring one column is stored in."""
+        if cls._packable(row_shape, dtype):
+            return (capacity, _stored_words(row_shape)), np.uint32
+        return (capacity,) + tuple(row_shape), np.dtype(dtype)
+
     def _ensure_storage(self, tree: Dict[str, np.ndarray]) -> bool:
         """Allocate device rings for any new columns; returns False
         when the projection spilled this buffer to the host ring."""
@@ -516,12 +593,18 @@ class DeviceReplayBuffer:
         }
         if not new_cols:
             return True
-        projected = self.storage_bytes + sum(
-            self.capacity
-            * int(np.prod(v.shape[1:]) if v.ndim > 1 else 1)
-            * v.dtype.itemsize
-            for v in new_cols.values()
-        )
+        # allocated bytes (a padded pixel column counts its pad)
+        rings = {
+            k: self._ring_shape_dtype(
+                self.capacity, tuple(v.shape[1:]), v.dtype
+            )
+            for k, v in new_cols.items()
+        }
+        ring_bytes = {
+            k: int(np.prod(shape)) * np.dtype(dtype).itemsize
+            for k, (shape, dtype) in rings.items()
+        }
+        projected = self.storage_bytes + sum(ring_bytes.values())
         cap = self._resolve_memory_cap()
         if cap is not None and projected > cap:
             # snapshot BEFORE arming the host fallback (get_state
@@ -546,15 +629,7 @@ class DeviceReplayBuffer:
         for k, v in new_cols.items():
             row_shape = tuple(v.shape[1:])
             packed = self._packable(row_shape, v.dtype)
-            if packed:
-                inner = int(np.prod(row_shape))
-                ring = jnp.zeros(
-                    (self.capacity, inner // 4), jnp.uint32
-                )
-            else:
-                ring = jnp.zeros(
-                    (self.capacity,) + row_shape, v.dtype
-                )
+            ring = jnp.zeros(*rings[k])
             # rows shard over the data axis when capacity divides the
             # shard count, else replicate (specs.leaf_sharding rule);
             # put_global assembles cross-process shards when the mesh
@@ -564,16 +639,13 @@ class DeviceReplayBuffer:
                 ring, sharding_lib.leaf_sharding(ring, self.mesh)
             )
             self._meta[k] = (row_shape, v.dtype, packed)
-            self.storage_bytes += self.capacity * int(
-                np.prod(row_shape) if row_shape else 1
-            ) * v.dtype.itemsize
+            self.storage_bytes += ring_bytes[k]
         self._insert_fn = None
         self._sample_fn = None
         return True
 
     def _build_insert_fn(self):
         import jax
-        import jax.numpy as jnp
 
         from ray_tpu import sharding as sharding_lib
         from ray_tpu.ops import framestack as framestack_lib
@@ -588,9 +660,7 @@ class DeviceReplayBuffer:
             for k, v in rows.items():
                 _, _, packed = meta[k]
                 if packed:
-                    v = jax.lax.bitcast_convert_type(
-                        v.reshape(v.shape[0], -1, 4), jnp.uint32
-                    )
+                    v = _pack_rows(v, store[k].shape[1])
                 out[k] = framestack_lib.scatter_rows(
                     store[k], pos, v, use_pallas=up, interpret=interp
                 )
@@ -602,30 +672,20 @@ class DeviceReplayBuffer:
             label=f"replay_insert[{self.label}]",
         )
 
+    def _gather_fn(self):
+        """``(store, idx) -> logical columns`` over this buffer's
+        current column set (holds the meta, not the buffer)."""
+        return functools.partial(
+            _gather_columns,
+            meta=dict(self._meta),
+            use_pallas=self.use_pallas,
+            interpret=self.pallas_interpret,
+        )
+
     def _build_sample_fn(self, row_sharded: bool):
-        import jax
-        import jax.numpy as jnp
-
         from ray_tpu import sharding as sharding_lib
-        from ray_tpu.ops import framestack as framestack_lib
 
-        meta = dict(self._meta)
-        up = self.use_pallas
-        interp = self.pallas_interpret
-
-        @jax.named_scope("replay/gather")
-        def fn(store, idx):
-            out = {}
-            for k, v in store.items():
-                row_shape, dtype, packed = meta[k]
-                g = framestack_lib.gather_rows(
-                    v, idx, use_pallas=up, interpret=interp
-                )
-                if packed:
-                    u8 = jax.lax.bitcast_convert_type(g, jnp.uint8)
-                    g = u8.reshape((g.shape[0],) + row_shape)
-                out[k] = g
-            return out
+        fn = self._gather_fn()
 
         # explicit output placement: the learn programs declare
         # row-sharded batch inputs, and jit rejects (rather than
@@ -811,35 +871,15 @@ class DeviceReplayBuffer:
                 "stacked path"
             )
         import jax
-        import jax.numpy as jnp
 
         if not isinstance(idx, jax.Array):
             idx = np.ascontiguousarray(idx, np.int32)
-        meta = dict(self._meta)
-        up = self.use_pallas
-        interp = self.pallas_interpret
-        from ray_tpu.ops import framestack as framestack_lib
-
-        @jax.named_scope("replay/gather")
-        def gather_fn(store, idx2):
-            out = {}
-            for k_, v in store.items():
-                row_shape, _, packed = meta[k_]
-                g = framestack_lib.gather_rows(
-                    v, idx2, use_pallas=up, interpret=interp
-                )
-                if packed:
-                    u8 = jax.lax.bitcast_convert_type(g, jnp.uint8)
-                    g = u8.reshape(tuple(idx2.shape) + row_shape)
-                out[k_] = g
-            return out
-
         shardings = {k_: v.sharding for k_, v in self._store.items()}
         return SuperstepRingFeed(
             store=self._store,
             idx=idx,
             extra=dict(extra or {}),
-            gather_fn=gather_fn,
+            gather_fn=self._gather_fn(),
             shardings=shardings,
             key=tuple(sorted(self._store)),
         )
@@ -865,12 +905,19 @@ class DeviceReplayBuffer:
         cols = {}
         for k, ring in host_store.items():
             row_shape, dtype, packed = self._meta[k]
+            rows = ring[: self._size]
             if packed:
-                ring = (
-                    ring.view(np.uint8)
-                    .reshape((self.capacity,) + row_shape)
+                # logical rows: the pad words stay behind
+                words = int(np.prod(row_shape)) // 4
+                rows = (
+                    rows[:, :words]
+                    .copy()
+                    .view(np.uint8)
+                    .reshape((self._size,) + row_shape)
                 )
-            cols[k] = ring[: self._size].copy()
+            else:
+                rows = rows.copy()
+            cols[k] = rows
         return {
             "cols": cols,
             "idx": self._idx,
@@ -1139,13 +1186,12 @@ class DevicePrioritizedReplayBuffer(_PrioritySampling, DeviceReplayBuffer):
         called in the f64 scope (the tree inputs); rows/weights leave
         as the learner's f32/u8 world with the same out-shardings the
         two-step path emitted."""
-        import jax
         import jax.numpy as jnp
 
         from ray_tpu import sharding as sharding_lib
         from ray_tpu.ops.segment_tree import draw_body
 
-        meta = dict(self._meta)
+        gather_fn = self._gather_fn()
         cap = self._dtree.capacity
 
         def fn(sum_t, min_t, store, rand, size, beta):
@@ -1153,15 +1199,7 @@ class DevicePrioritizedReplayBuffer(_PrioritySampling, DeviceReplayBuffer):
                 sum_t, min_t, rand, size, beta, cap
             )
             idx32 = idx.astype(jnp.int32)
-            out = {}
-            with jax.named_scope("replay/gather"):
-                for k, v in store.items():
-                    row_shape, dtype, packed = meta[k]
-                    g = v[idx32]
-                    if packed:
-                        u8 = jax.lax.bitcast_convert_type(g, jnp.uint8)
-                        g = u8.reshape((g.shape[0],) + row_shape)
-                    out[k] = g
+            out = gather_fn(store, idx32)
             out["weights"] = weights
             return out, idx32
 
@@ -1171,7 +1209,7 @@ class DevicePrioritizedReplayBuffer(_PrioritySampling, DeviceReplayBuffer):
             else sharding_lib.replicated(self.mesh)
         )
         rep = sharding_lib.replicated(self.mesh)
-        out_cols = {k: row_spec for k in meta}
+        out_cols = {k: row_spec for k in self._meta}
         out_cols["weights"] = row_spec
         return sharding_lib.sharded_jit(
             fn,

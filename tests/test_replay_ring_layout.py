@@ -1,0 +1,272 @@
+"""The stored row width of a packed uint8 replay column
+(docs/data_plane.md "stored row width"): a row is rounded up to whole
+128-word lanes where that is cheap, so the TPU client lays the ring
+out row-major and neither the insert nor a gather copies it.
+
+Two halves: CPU parity cases over row widths (the pad never reaches a
+consumer), and compiles for a described v5e (no chip needed) that hold
+the layout itself — no ``copy`` of the ring, the insert in place."""
+
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu import sharding as sharding_lib
+from ray_tpu.data.sample_batch import SampleBatch
+from ray_tpu.execution.replay_buffer import (
+    DevicePrioritizedReplayBuffer,
+    DeviceReplayBuffer,
+    PrioritizedReplayBuffer,
+    ReplayBuffer,
+    _stored_words,
+)
+
+CAPACITY = 24  # 8 simulated devices: 3 ring rows a shard
+
+# row shape -> words its ring stores a row in
+ROW_WIDTHS = [
+    pytest.param((84, 84, 4), 7168, id="7056w-padded"),
+    pytest.param((84, 84), 1792, id="1764w-padded"),
+    pytest.param((16, 8, 4), 128, id="128w-whole-lane"),
+    pytest.param((4, 4, 4), 16, id="16w-small-unpadded"),
+    pytest.param((40, 20), 200, id="200w-pad-too-dear"),
+]
+
+
+def _tree(n, base, rng, row_shape):
+    return {
+        "obs": base + np.arange(n * 6, dtype=np.float32).reshape(n, 6),
+        "pix": rng.integers(0, 255, (n,) + row_shape, dtype=np.uint8),
+        "rewards": np.arange(n, dtype=np.float32) + base,
+    }
+
+
+def _assert_rows_equal(want, got, what):
+    got = jax.device_get(got)
+    for k, col in want.items():
+        assert got[k].dtype == col.dtype, (what, k)
+        assert np.array_equal(got[k], col), (what, k)
+
+
+@pytest.mark.parametrize("row_shape,stored", ROW_WIDTHS)
+def test_rows_bit_identical_to_host_ring(row_shape, stored):
+    """Insert from the host and from the device (past capacity, so
+    the scatter wraps), then every read path — ``gather``, ``sample``
+    and the superstep feed's ``gather_fn`` — returns the host ring's
+    rows bit for bit, whatever width the ring stores them at."""
+    assert _stored_words(row_shape) == stored
+    rng = np.random.default_rng(0)
+    host = ReplayBuffer(CAPACITY, seed=9)
+    from_host = DeviceReplayBuffer(CAPACITY, seed=9)
+    from_dev = DeviceReplayBuffer(CAPACITY, seed=9)
+    for i in range(5):  # 35 rows into 24: wraps
+        t = _tree(7, float(100 * i), rng, row_shape)
+        host.add(SampleBatch(t))
+        from_host.add_tree(t)
+        from_dev.add_device_tree(
+            {k: jnp.asarray(v) for k, v in t.items()}
+        )
+    idx2 = np.arange(16, dtype=np.int32).reshape(2, 8) * 3 % CAPACITY
+    for what, dev in (("host", from_host), ("device", from_dev)):
+        assert dev._store["pix"].shape == (CAPACITY, stored), what
+        assert dev._store["pix"].dtype == jnp.uint32, what
+        assert (len(dev), dev._idx, dev.num_added) == (
+            len(host), host._idx, host.num_added
+        )
+        # what the gauge reports is what is allocated, pad included
+        assert dev.storage_bytes == sum(
+            ring.nbytes for ring in dev._store.values()
+        )
+        _assert_rows_equal(
+            host._cols, dev.gather(np.arange(CAPACITY)).tree, what
+        )
+        feed = dev.superstep_feed(idx2)
+        _assert_rows_equal(
+            {k: col[idx2] for k, col in host._cols.items()},
+            jax.jit(feed.gather_fn)(feed.store, feed.idx),
+            what,
+        )
+    hs = host.sample(8)
+    _assert_rows_equal(
+        {k: np.asarray(v) for k, v in hs.items()},
+        from_host.sample(8).tree,
+        "sample",
+    )
+
+
+@pytest.mark.parametrize("row_shape,stored", ROW_WIDTHS)
+def test_prioritized_tree_sample_bit_identical(row_shape, stored):
+    """The fused device-tree sample (draw, weights and row gather in
+    one program) hands out the host prioritized ring's rows."""
+    rng = np.random.default_rng(2)
+    host = PrioritizedReplayBuffer(CAPACITY, alpha=0.6, seed=4)
+    dev = DevicePrioritizedReplayBuffer(
+        CAPACITY, alpha=0.6, seed=4, device_tree=True
+    )
+    for i in range(4):
+        t = _tree(7, float(i), rng, row_shape)
+        pri = rng.uniform(0.1, 3.0, 7)
+        host.add_with_priorities(SampleBatch(t), pri)
+        dev.add_tree(t, priorities=pri)
+    assert dev._store["pix"].shape == (CAPACITY, stored)
+    hs = host.sample(8, beta=0.4)
+    ds = dev.sample(8, beta=0.4)
+    assert np.array_equal(hs["batch_indexes"], jax.device_get(ds.indices))
+    _assert_rows_equal(
+        {k: np.asarray(hs[k]) for k in ("obs", "pix", "rewards", "weights")},
+        ds.tree,
+        "tree sample",
+    )
+
+
+@pytest.mark.parametrize("row_shape,stored", ROW_WIDTHS)
+def test_state_holds_logical_rows(row_shape, stored):
+    """``get_state`` strips the pad and ``set_state`` adds it: the
+    round trip keeps the ring, and a state dict with unpadded ``cols``
+    — what the host ring writes, and what a checkpoint from before
+    the stored width existed holds — loads into a padded ring."""
+    rng = np.random.default_rng(4)
+    host = ReplayBuffer(CAPACITY, seed=2)
+    dev = DeviceReplayBuffer(CAPACITY, seed=2)
+    for i in range(2):  # 20 rows: size < capacity, so cols are cut
+        t = _tree(10, float(i), rng, row_shape)
+        host.add(SampleBatch(t))
+        dev.add_tree(t)
+    state = dev.get_state()
+    assert state["cols"]["pix"].shape == (20,) + row_shape
+    assert state["cols"]["pix"].dtype == np.uint8
+    _assert_rows_equal(host.get_state()["cols"], state["cols"], "state")
+    for what, st in (
+        ("round trip", state),
+        ("older checkpoint", {**host.get_state(), "spilled": False}),
+    ):
+        dev2 = DeviceReplayBuffer(CAPACITY, seed=2)
+        dev2.set_state(st)
+        assert dev2._store["pix"].shape == (CAPACITY, stored), what
+        assert dev2.storage_bytes == dev.storage_bytes, what
+        assert (len(dev2), dev2._idx, dev2.num_added) == (
+            len(dev), dev._idx, dev.num_added
+        )
+        _assert_rows_equal(
+            host._cols, dev2.gather(np.arange(CAPACITY)).tree, what
+        )
+
+
+# -- the layout itself, from the v5e compiler (no chip) -----------------
+#
+# The benchmark cell's ring: 131,072 rows of 84x84x4 uint8, a 512-row
+# insert, an (8, 512) superstep gather. Abstract arguments only; the
+# topology is described inside a fixture, never at import.
+
+CELL_ROWS = 131072
+
+
+@pytest.fixture(scope="module")
+def v5e_mesh():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu here, or its lock is taken
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return sharding_lib.get_mesh(devices=topo.devices[:1])
+
+
+def _abstract_buffer(mesh, capacity, cols):
+    """A buffer whose rings are shapes on the described chip: what
+    ``_ensure_storage`` would allocate, with nothing allocated."""
+    buf = DeviceReplayBuffer(capacity, mesh=mesh)
+    for k, (row_shape, dtype) in cols.items():
+        shape, ring_dtype = buf._ring_shape_dtype(
+            capacity, row_shape, dtype
+        )
+        ring = jax.ShapeDtypeStruct(shape, ring_dtype)
+        buf._store[k] = jax.ShapeDtypeStruct(
+            shape,
+            ring_dtype,
+            sharding=sharding_lib.leaf_sharding(ring, mesh),
+        )
+        buf._meta[k] = (
+            row_shape, np.dtype(dtype), buf._packable(row_shape, dtype)
+        )
+    return buf
+
+
+def _on(mesh, shape, dtype):
+    return jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding_lib.replicated(mesh)
+    )
+
+
+def _ring_copies(compiled, capacity):
+    """HLO ``copy`` instructions whose result has the ring's rows."""
+    return [
+        line.strip()[:160]
+        for line in compiled.as_text().splitlines()
+        if re.search(rf"= \w+\[{capacity},[^\]]*\]\S* copy\(", line)
+    ]
+
+
+RING_CASES = [
+    pytest.param(
+        CELL_ROWS,
+        {"obs": ((84, 84, 4), np.uint8), "new_obs": ((84, 84, 4), np.uint8),
+         "rewards": ((), np.float32)},
+        CELL_ROWS * (2 * 4 * 7168 + 4),
+        id="cell-pixel-ring",
+    ),
+    pytest.param(
+        1 << 20,
+        {"obs": ((17,), np.float32), "new_obs": ((17,), np.float32),
+         "actions": ((6,), np.float32), "rewards": ((), np.float32)},
+        (1 << 20) * 4 * (17 + 17 + 6 + 1),
+        id="vector-ring",
+    ),
+]
+
+
+@pytest.mark.parametrize("capacity,cols,ring_bytes", RING_CASES)
+def test_v5e_insert_runs_in_place(v5e_mesh, capacity, cols, ring_bytes):
+    buf = _abstract_buffer(v5e_mesh, capacity, cols)
+    rows = {
+        k: _on(v5e_mesh, (512,) + shape, dtype)
+        for k, (shape, dtype) in cols.items()
+    }
+    compiled = (
+        buf._build_insert_fn()
+        .lower(buf._store, rows, _on(v5e_mesh, (512,), np.int32))
+        .compile()
+    )
+    assert _ring_copies(compiled, capacity) == []
+    mem = compiled.memory_analysis()
+    assert ring_bytes == sum(
+        int(np.prod(r.shape)) * r.dtype.itemsize
+        for r in buf._store.values()
+    )
+    # every ring is written where it lies. Device bytes: equal to the
+    # ring's for lane-whole rows (the cell: 7,516,717,056), more where
+    # a narrow column-major row is tiled up to 8 words
+    assert mem.alias_size_in_bytes >= ring_bytes
+    assert mem.alias_size_in_bytes <= mem.output_size_in_bytes
+    assert mem.temp_size_in_bytes < 0.5e9
+
+
+@pytest.mark.parametrize("capacity,cols,ring_bytes", RING_CASES)
+def test_v5e_superstep_gather_reads_rows(
+    v5e_mesh, capacity, cols, ring_bytes
+):
+    buf = _abstract_buffer(v5e_mesh, capacity, cols)
+    feed = buf.superstep_feed(np.zeros((8, 512), np.int32))
+    compiled = (
+        jax.jit(feed.gather_fn)
+        .lower(buf._store, _on(v5e_mesh, (8, 512), np.int32))
+        .compile()
+    )
+    assert _ring_copies(compiled, capacity) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
