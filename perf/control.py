@@ -5,12 +5,13 @@
 In ONE process (set-up is paid once) and at the cell's own size, for
 each seed: the distances of the SYSTEM's outcome from the plain
 reference's (what sound runs give), and the distances of each
-CONTROL's — the reference computed in int8 and in float8, one
-precision step below the bf16 the configuration states, put in the
-system's place. The limits in ``perf/correct.py`` sit above the
-largest of the first and below the smallest of the second. Not part of
-a benchmark run; the same comparison at a small size is a test in
-``perf/tests``.
+CONTROL's — the reference computed in the configuration's
+``control_precisions`` (int8 and float8 where it states bf16), one
+precision step below what it states, put in the system's place. The
+limits in ``perf/limits/<config>.json`` sit above the largest of the
+first and below the smallest of the second, and carry both readings.
+Not part of a benchmark run; the same comparison at a small size is a
+test in ``perf/tests``.
 """
 
 from __future__ import annotations
@@ -33,9 +34,10 @@ def _fresh_optimizer(policy) -> None:
     )
 
 
-def readings(cell, seeds, require_tpu: bool = True, rows: int = 512):
+def readings(cell, seeds, require_tpu: bool = True):
     """One row a seed: ``{"seed", "system": {...}, "<control>": {...}}``
-    with every number of the cell's comparisons in each."""
+    with every number of the cell's set-up comparisons in each: what
+    each check's own ``readings(state)`` gives."""
     import jax
 
     import ray_tpu as ray
@@ -43,7 +45,6 @@ def readings(cell, seeds, require_tpu: bool = True, rows: int = 512):
     devices = jax.devices()
     if require_tpu and devices[0].platform != "tpu":
         raise SystemExit("perf.control: needs a TPU")
-    wanted = cell.config["checks"]
     algo = run_lib.build_algorithm(cell, 0, cell.chips, len(devices))
     out = []
     try:
@@ -57,41 +58,19 @@ def readings(cell, seeds, require_tpu: bool = True, rows: int = 512):
             ref_params = run_lib.load_seeded_weights(
                 cell, policy, ref, seed32, num_actions
             )
-            _fresh_optimizer(policy)
+            state = correct_lib.CheckState(
+                cell, algo, policy, ref, ref_params, seed, num_actions,
+                list(policy.mesh.devices.flat), correct_lib.Checks(),
+            )
             row = {"seed": int(seed), "system": {}}
-            row.update({p: {} for p in correct_lib.CONTROL_PRECISIONS})
-            if "learner_step" in wanted:
-                system = correct_lib.learner_check(
-                    correct_lib.Checks(), cell, policy, ref, ref_params, seed,
-                    num_actions, rows,
-                )
-                row["system"].update(system)
-                for p in correct_lib.CONTROL_PRECISIONS:
-                    row[p].update(correct_lib.control_readings(
-                        cell, ref, ref_params, seed, num_actions, rows, p
-                    ))
-                _fresh_optimizer(policy)
-            if "replay_superstep" in wanted:
-                drawn = correct_lib.fill_ring_and_draw(
-                    cell, algo, ref, seed, num_actions
-                )
-                sys_out = correct_lib.system_superstep(
-                    cell, algo, policy, ref, drawn
-                )
-                ref_out = correct_lib.reference_superstep(
-                    cell, ref, ref_params, drawn
-                )
-                row["system"].update(
-                    correct_lib.compare_updates(sys_out, ref_out, ref_params)
-                )
-                for p in correct_lib.CONTROL_PRECISIONS:
-                    ctl = correct_lib.reference_superstep(
-                        cell, ref, ref_params, drawn, p
-                    )
-                    # the control stands in the system's place
-                    row[p].update(
-                        correct_lib.compare_updates(ctl, ref_out, ref_params)
-                    )
+            row.update({p: {} for p in cell.control_precisions})
+            for stage in manifest_lib.SETUP_STAGES:
+                for _, check in cell.checks(stage):
+                    if not hasattr(check, "readings"):
+                        continue
+                    _fresh_optimizer(policy)
+                    for who, numbers in check.readings(state).items():
+                        row[who].update(numbers)
             print(f"[control] {json.dumps(row)}", flush=True)
             out.append(row)
     finally:
@@ -100,20 +79,20 @@ def readings(cell, seeds, require_tpu: bool = True, rows: int = 512):
     return out
 
 
-def summary(rows):
+def summary(cell, rows):
     """Per number: the largest the system read, the smallest each
     control read, and the limit."""
     out = {}
     for k in rows[0]["system"]:
-        if k not in correct_lib.LIMITS:
+        if k not in cell.limits:
             continue
         out[k] = {
             "system_max": max(r["system"][k] for r in rows),
             **{
                 f"{p}_min": min(r[p][k] for r in rows)
-                for p in correct_lib.CONTROL_PRECISIONS
+                for p in cell.control_precisions
             },
-            "limit": correct_lib.LIMITS[k],
+            "limit": cell.limit(k),
         }
     return out
 
@@ -134,7 +113,7 @@ def main(argv=None) -> int:
         "workload": cell.name,
         "device": {"platform": dev[0].platform, "kind": dev[0].device_kind,
                    "count": len(dev)},
-        "summary": summary(rows),
+        "summary": summary(cell, rows),
     }), flush=True)
     return 0
 

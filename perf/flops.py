@@ -1,4 +1,7 @@
-"""Operations and bytes the algorithms need, from shapes alone.
+"""Operations and bytes the algorithms need, from shapes alone: the
+arithmetic the FLOP rules share and the table of peaks. A model
+family's own rule is ``perf/flop_rules/<flops_family>.py``
+(``Cell.flop_rule()``).
 
 Copy of the sound arithmetic of ``bench.py:nature_cnn_train_flops_per_sample``
 (listed in PERF.md for deletion there), generalized to the filter
@@ -77,28 +80,6 @@ def forward_flops_per_sample(model: Dict, heads: int) -> float:
             heads,
         )
     )
-
-
-def train_flops_per_env_step(config: Dict, num_actions: int) -> float:
-    """Model operations one env step costs the LEARNER (the rollout's
-    own forward passes are the sampler's, not counted):
-
-    - ``ppo``: each sampled row is trained ``num_sgd_iter`` times,
-      forward + backward = 3 x forward.
-    - ``dqn``: each env step owes ``training_intensity`` trained rows;
-      a row is one online forward+backward on ``obs`` (3x), one target
-      forward on ``new_obs`` (1x) and, under double-Q, one online
-      forward on ``new_obs`` (1x)."""
-    algo = config["algo_config"]
-    family = config["flops_family"]
-    if family == "ppo":
-        fwd = forward_flops_per_sample(config["model"], num_actions + 1)
-        return 3.0 * fwd * int(algo["num_sgd_iter"])
-    if family == "dqn":
-        fwd = forward_flops_per_sample(config["model"], num_actions + 1)
-        per_row = (3.0 + 1.0 + (1.0 if algo.get("double_q", True) else 0.0)) * fwd
-        return per_row * float(algo["training_intensity"])
-    raise ValueError(f"no FLOP rule for flops_family={family!r}")
 
 
 def load_peaks(device_kind: str) -> Dict:

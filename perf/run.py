@@ -287,45 +287,42 @@ def warm_up(algo, iterations: int) -> int:
     return int(iterations)
 
 
-def run_correct(cell, algo, policy, ref, ref_params, seed, num_actions, devices):
-    """The comparisons of set-up, in the order the configuration file
-    lists them under ``checks``; between them the program's own first
-    iterations (``warmup.first_iterations``: they define the ring's
-    columns) have run. Returns ``(checks, iterations run)``."""
-    checks = correct_lib.Checks()
-    correct_lib.mesh_checks(checks, policy, devices)
-    wanted = cell.config["checks"]
-    if "learner_step" in wanted:
-        correct_lib.learner_check(
-            checks, cell, policy, ref, ref_params, seed, num_actions
-        )
+def run_correct(state):
+    """The comparisons of set-up: the layout the configuration states,
+    then the checks the cell's files list for before the program's own
+    first iterations (``warmup.first_iterations``: they define the
+    ring's columns) and for after them. Returns the iterations run."""
+    cell = state.cell
+    correct_lib.layout_checks(
+        state.checks, state.policy, state.devices, cell.param_layout
+    )
+    for _, check in cell.checks("before_first_iterations"):
+        check.run(state)
     warm = cell.traffic.get("warmup") or {}
-    n = warm_up(algo, warm.get("first_iterations", 0))
-    if "replay_superstep" in wanted:
-        correct_lib.replay_superstep_check(
-            checks, cell, algo, policy, ref, ref_params, seed, num_actions
-        )
-        # the bulk fill stands for that many sampled env steps: the
-        # configuration's learning start and epsilon schedule see them
-        buf = algo.local_replay_buffer.buffers["default_policy"]
-        algo._counters["num_env_steps_sampled"] += buf.capacity
-    n += warm_up(algo, warm.get("then_iterations", 1))
-    return checks, n
+    n = warm_up(state.algo, warm.get("first_iterations", 0))
+    for _, check in cell.checks("after_first_iterations"):
+        check.run(state)
+    n += warm_up(state.algo, warm.get("then_iterations", 1))
+    return n
 
 
-def run_correct_after_warmup(checks, cell, algo, ref, seed, devices) -> None:
-    """The comparisons that need a warmed system: K updates in one
-    dispatch, the env carry split over the chips, the replay plane."""
+def run_correct_after_warmup(state) -> None:
+    """The comparisons that need a warmed system, around one more real
+    iteration: what holds for any cell (K updates in one dispatch, the
+    env carry split over the chips, workers on the CPU) and the cell's
+    own ``after_warmup`` checks."""
     import numpy as np
 
+    cell, algo, checks = state.cell, state.algo, state.checks
     expect = cell.traffic.get("expect") or {}
-    buf = None
-    if expect.get("replay_resident"):
-        buf = algo.local_replay_buffer.buffers["default_policy"]
-        ring_cursor = buf.num_added % buf.capacity
+    after_warmup = cell.checks("after_warmup")
+    for name, check in after_warmup:
+        if hasattr(check, "prepare"):
+            state.prepared[name] = check.prepare(state)
     before = _counters(algo)
     result = algo.train()
     after = _counters(algo)
+    state.iteration = {"before": before, "after": after, "result": result}
     checks.true(
         "iteration_adds_up",
         _iteration_ok(before, after, result, expect, np),
@@ -349,21 +346,11 @@ def run_correct_after_warmup(checks, cell, algo, ref, seed, devices) -> None:
             correct_lib.rows_split_evenly(
                 {"obs": eng._carry["obs"], "ep_ret": eng._carry["ep_ret"]},
                 eng.N,
-                devices,
+                state.devices,
             ),
         )
-    if buf is not None:
-        correct_lib.rollout_rows_check(
-            checks, buf, ring_cursor, after["sampled"] - before["sampled"],
-            int(cell.config["model"].get("frame_stack", 1)),
-        )
-        checks.true(
-            "replay_ring_full_on_device_before_window",
-            not buf.spilled and buf.tree_plane == "device"
-            and len(buf) == buf.capacity,
-            f"{len(buf)} of {buf.capacity} rows, {buf.storage_bytes} B, "
-            f"tree plane {buf.tree_plane}",
-        )
+    for _, check in after_warmup:
+        check.run(state)
     workers = algo.workers.remote_workers()
     if workers:
         import ray_tpu as ray
@@ -477,11 +464,14 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
         ref_params = load_seeded_weights(cell, policy, ref, seed32, num_actions)
         lap("build_s")
 
-        checks, warm_iters = run_correct(
-            cell, algo, policy, ref, ref_params, seed, num_actions, cell_devices
+        checks = correct_lib.Checks()
+        state = correct_lib.CheckState(
+            cell, algo, policy, ref, ref_params, seed, num_actions,
+            cell_devices, checks,
         )
+        warm_iters = run_correct(state)
         lap("correct_and_warm_s")
-        run_correct_after_warmup(checks, cell, algo, ref, seed, cell_devices)
+        run_correct_after_warmup(state)
         _block(policy)
         program_temp = program_temp_bytes(device_ledger.snapshot())
         device_ledger.disable()  # the window runs the lean dispatch path

@@ -19,6 +19,9 @@ import pytest
 from perf import manifest as manifest_lib
 
 
+OTHER_FAMILY = os.path.join(os.path.dirname(__file__), "data", "other_family")
+
+
 def _rewrite(path, fn):
     with open(path) as f:
         data = json.load(f)
@@ -37,8 +40,11 @@ def make_tiny_root(root):
     NEW files added beside the committed ones and new entries appended
     to the manifest — no committed file is edited: a throw-away
     configuration ``tiny_dqn``, a traffic mix ``tiny_replay`` and a
-    per-layer metric
-    ``tiny.iterations``, sized for a CPU."""
+    per-layer metric ``tiny.iterations``, sized for a CPU; and a cell
+    of ANOTHER FAMILY, ``seq.ppo.mp4`` (PPO over a small decoder whose
+    parameters are split over a batch 2 x model 2 mesh), whose
+    configuration, reference, FLOP rule, limits, check, traffic mix
+    and choice of metrics are the files of ``data/other_family``."""
     shutil.copytree(
         manifest_lib.PERF_DIR,
         os.path.join(root, "perf"),
@@ -49,6 +55,7 @@ def make_tiny_root(root):
         os.path.join(root, "ray_tpu"),
     )
     perf = os.path.join(root, "perf")
+    shutil.copytree(OTHER_FAMILY, perf, dirs_exist_ok=True)
 
     def tiny_config(src, dst, edit):
         shutil.copy(
@@ -65,6 +72,7 @@ def make_tiny_root(root):
             replay_device_tree=True,
         )
         c["algo_config"]["replay_buffer_config"]["capacity"] = 256
+        c["learner_check"] = {"rows": 128, "batches": 4}
 
     tiny_config("nature_cnn_dqn_per.json", "tiny_dqn.json", dqn_edit)
 
@@ -84,10 +92,14 @@ def make_tiny_root(root):
         "ring_fill": {"chunk_envs": 4, "chunk_steps": 16},
         "warmup": {"first_iterations": 1, "then_iterations": 1},
         "expect": {"updates_per_iteration": 8, "dispatches_per_iteration": 1,
-                   "dispatch_label": "superstep[", "replay_resident": True,
-                   "trained_per_sampled": 8},
+                   "dispatch_label": "superstep[", "trained_per_sampled": 8},
+        "checks": ["rollout_rows_pong_lite", "replay_ring_full"],
         "trace_iterations": 2,
     })
+    with open(os.path.join(perf, "limits", "seq_ppo_mp.json")) as f:
+        lacking = json.load(f)
+    del lacking["limits"]["grad_rel_l2"]
+    write("limits/seq_ppo_mp_without_grad.json", lacking)
     write("layer_metrics/tiny.iterations.py",
           '"""Iterations in the window."""\n\n\n'
           "def read(ctx):\n    return float(len(ctx.window.walls))\n")
@@ -96,12 +108,16 @@ def make_tiny_root(root):
         m["configs"] += [
             {"name": "tiny_dqn", "source": "test", "reduced": [],
              "file": "perf/configs/tiny_dqn.json", "why": "test"},
+            {"name": "seq_ppo_mp", "source": "test", "reduced": [],
+             "file": "perf/configs/seq_ppo_mp.json", "why": "test"},
         ]
         m["workloads"] += [
             {"name": "tiny.dqn", "config": "tiny_dqn", "traffic": "tiny_replay",
              "chips": 1, "why": "test"},
             {"name": "tiny.dqn4", "config": "tiny_dqn", "traffic": "tiny_replay",
              "chips": 4, "why": "test"},
+            {"name": "seq.ppo.mp4", "config": "seq_ppo_mp",
+             "traffic": "host_rollout", "chips": 4, "why": "test"},
         ]
         m["per_layer"].append(
             {"name": "tiny.iterations", "unit": "count", "better": "higher",

@@ -43,6 +43,54 @@ def test_throwaway_cell_runs_end_to_end(tiny_root, cell_name):
     assert out["correct"], [r for r in out["checks"] if not r["ok"]]
 
 
+def test_a_cell_of_another_family_comes_as_files_alone(tiny_root):
+    """PPO over a small decoder whose parameters are SPLIT over a
+    batch 2 x model 2 mesh: its reference, FLOP rule, limits, check,
+    layout, traffic mix and choice of metrics are new files only
+    (``data/other_family``), and no file of the harness knows it."""
+    cell = manifest_lib.load_cell("seq.ppo.mp4", tiny_root)
+    out = run_lib.run_cell(cell, 2**31 + 77, 1.0, False, require_tpu=False)
+    by_name = {r["check"]: r for r in out["checks"]}
+    assert out["correct"], [r for r in out["checks"] if not r["ok"]]
+    assert out["failed"] == 0 and out["attempted"] >= 1
+    # the layout the configuration states, not replication
+    assert "params_replicated_on_every_chip" not in by_name
+    assert by_name["mesh_axes_as_stated"]["value"] == {"batch": 2, "model": 2}
+    assert by_name["param_leaves_not_laid_out_as_stated"]["value"] == 0
+    assert by_name["params_split_over_chips"]["ok"]
+    assert 0.25 < by_name["param_share_on_fullest_chip"]["value"] < 0.6
+    # limits from its own file, on its own shape of batch
+    assert by_name["grad_rel_l2"]["limit"] == cell.limit("grad_rel_l2") == 3e-5
+    assert "2 minibatches of 64 rows on 2 shard(s)" in by_name["grad_rel_l2"]["note"]
+    # a check module of its own
+    assert by_name["action_prob_abs_max"]["ok"]
+    assert by_name["reference_attention_is_a_causal_softmax"]["ok"]
+    assert by_name["iteration_adds_up"]["ok"]
+    # iter_p95_ms belongs to every cell; two restricted per-layer
+    # metrics come through the cell's own ``metrics`` list
+    assert set(out["metrics"]) == {"env_steps_per_s", "iter_p95_ms", "setup_s"}
+    assert out["metrics"]["iter_p95_ms"]["value"] > 0
+    names = {m["name"] for m in cell.per_layer}
+    assert {"tiny.iterations", "rollout.host_idle_ms_per_iter"} <= names
+    assert "replay.h2d_bytes_per_update" not in names
+    win = run_lib.Window()
+    win.walls, win.seconds = [0.1, 0.2], 2.0
+    win.before = {"sampled": 0, "trained": 0}
+    win.after = {"sampled": 256, "trained": 256}
+    ctx = run_lib.Context(cell, None, win, cell.chips, "TPU v5 lite", 2)
+    assert cell.reader("tiny.iterations")(ctx) == 2.0
+    assert cell.reader("rollout.host_idle_ms_per_iter")(ctx) is None  # no trace
+    # learner.mfu_pct from the family's own FLOP rule
+    per_step = cell.flop_rule()(cell.config, 2)
+    s, d, ff = 4, 64, 128
+    assert per_step == 3 * 2 * 2.0 * (
+        s * d + 2 * (4 * s * d * d + 2 * 10 * d + 2 * s * d * ff) + d * 3
+    )
+    assert cell.reader("learner.mfu_pct")(ctx) == pytest.approx(
+        100.0 * per_step * 128.0 / (4 * 197e12)
+    )
+
+
 def test_per_layer_metric_added_as_a_file_is_read(tiny_root):
     cell = manifest_lib.load_cell("tiny.dqn", tiny_root)
     names = [m["name"] for m in cell.per_layer]
@@ -80,7 +128,8 @@ def test_a_gradient_summed_over_shards_is_not_correct():
     summed = {"a": {k: 4.0 * v for k, v in g["a"].items()}}
     d = correct_lib.compare_grads(summed, g)
     assert abs(d["grad_rel_l2"] - 3.0) < 1e-12
-    assert d["grad_rel_l2"] > correct_lib.LIMITS["grad_rel_l2"]
+    cell = manifest_lib.load_cell(manifest_lib.load_manifest()["workloads"][0]["name"])
+    assert d["grad_rel_l2"] > cell.limit("grad_rel_l2")
 
 
 def test_runner_exits_nonzero_without_a_tpu():

@@ -1,6 +1,6 @@
 """The plain reference against the policy on tiny CPU shapes, and the
 controls: the reference one precision step down must come out as NOT
-correct under the limits of perf/correct.py."""
+correct under the limits of the configuration's perf/limits file."""
 
 import numpy as np
 import pytest
@@ -20,21 +20,25 @@ def readings(tmp_path_factory):
 
     root = make_tiny_root(str(tmp_path_factory.mktemp("tiny")))
     cell = manifest_lib.load_cell("tiny.dqn", root)
-    return control_lib.readings(cell, SEEDS, require_tpu=False, rows=128)
+    return cell, control_lib.readings(cell, SEEDS, require_tpu=False)
 
 
 def test_system_is_within_every_limit(readings):
-    for row in readings:
+    cell, rows = readings
+    assert cell.learner_check_shape == (128, 4)
+    for row in rows:
         for name, value in row["system"].items():
-            if name in correct_lib.LIMITS:
-                assert value <= correct_lib.LIMITS[name], (row["seed"], name, value)
+            if name in cell.limits:
+                assert value <= cell.limit(name), (row["seed"], name, value)
 
 
-@pytest.mark.parametrize("precision", correct_lib.CONTROL_PRECISIONS)
+@pytest.mark.parametrize("precision", ["int8", "fp8"])
 def test_control_comes_out_as_not_correct(readings, precision):
-    for row in readings:
+    cell, rows = readings
+    assert precision in cell.control_precisions
+    for row in rows:
         over = [n for n, v in row[precision].items()
-                if n in correct_lib.LIMITS and v > correct_lib.LIMITS[n]]
+                if n in cell.limits and v > cell.limit(n)]
         assert "grad_rel_l2" in over, (row["seed"], row[precision])
         assert row[precision]["grad_rel_l2"] > 3 * row["system"]["grad_rel_l2"]
         # the structure of the control's superstep is the reference's own
