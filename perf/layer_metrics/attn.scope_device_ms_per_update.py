@@ -1,0 +1,14 @@
+"""Device time per optimizer update of the leaf operations under the
+model's ``learn/attn`` scope(s) in the learn program (forward, the
+recomputation and the backward pass carry the scope on their
+``tf_op`` path)."""
+
+from perf import program_trace, sequence_model
+
+
+def read(ctx):
+    rep = program_trace.report(ctx)
+    seconds = sequence_model.seconds_under(rep, "learn/attn")
+    if seconds is None or not rep.updates:
+        return None
+    return 1e3 * seconds / rep.updates

@@ -126,7 +126,10 @@ class PPOJaxPolicy(JaxPolicy):
         vf_clip = cfg.get("vf_clip_param", 10.0)
         vf_coeff = cfg.get("vf_loss_coeff", 1.0)
 
-        dist_inputs, value, _ = self.model_forward_train(params, batch)
+        model_stats = {}
+        dist_inputs, value, _ = self.model_forward_train(
+            params, batch, stats_out=model_stats
+        )
         dist = self.dist_class(dist_inputs)
         prev_dist = self.dist_class(
             batch[SampleBatch.ACTION_DIST_INPUTS]
@@ -162,6 +165,7 @@ class PPOJaxPolicy(JaxPolicy):
             "vf_explained_var": _explained_variance(
                 value_targets, value
             ),
+            **model_stats,
         }
         return total, stats
 

@@ -72,6 +72,10 @@ class JaxVectorEnv:
 
     obs_spec: ArraySpec
     action_spec: ArraySpec
+    # an env whose actions are its product (generated tokens): the
+    # device lane hands every step's actions back with the episode
+    # metrics, on the one drain (``JaxRolloutEngine.last_actions``)
+    report_actions = False
 
     def __init__(self, config: Optional[Dict] = None):
         self.config = dict(config or {})

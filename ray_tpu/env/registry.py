@@ -24,7 +24,8 @@ def get_env_creator(env_spec) -> Callable[[EnvContext], Any]:
         return _env_registry[env_spec]
     if isinstance(env_spec, str) and (
         env_spec.startswith(
-            ("PongLite", "Synthetic", "CartPoleJax", "GridRoomsJax")
+            ("PongLite", "Synthetic", "CartPoleJax", "GridRoomsJax",
+             "TokenStreamJax")
         )
     ):
         # in-repo envs register on import; pull them in so yaml/CLI
@@ -32,6 +33,7 @@ def get_env_creator(env_spec) -> Callable[[EnvContext], Any]:
         # (reference tuned-example UX)
         import ray_tpu.env.jax_control  # noqa: F401
         import ray_tpu.env.jax_pong  # noqa: F401
+        import ray_tpu.env.jax_tokens  # noqa: F401
         import ray_tpu.env.pong_lite  # noqa: F401
         import ray_tpu.env.synthetic_env  # noqa: F401
 

@@ -75,8 +75,8 @@ def supports_jax_rollout_lane(policy, env) -> Tuple[bool, str]:
     if not getattr(policy, "supports_jax_rollout", False):
         return False, (
             f"policy {type(policy).__name__} cannot lower its act "
-            "path (recurrent model, stateful exploration, or non-mesh "
-            "backend)"
+            "path (stateful exploration, a model fed the previous "
+            "action or reward, or a non-mesh backend)"
         )
     return True, ""
 
@@ -126,13 +126,34 @@ class JaxRolloutEngine:
         self.lambda_ = float(policy.config.get("lambda", 1.0))
         self._seed = seed
         self._metrics: List[RolloutMetrics] = []
+        # ``env.report_actions``: the last dispatch's actions, host
+        # numpy ``([K,] T, N)``
+        self.last_actions = None
         self._rollout_fn = None
         self._body = None
         self.batch_size = self.N * self.T
 
+        # a model with per-stream state: its state rides the carry,
+        # and the learner gets the state at the start of every
+        # ``unroll``-step chunk of a fragment (its learn form's length)
+        self.stateful = bool(policy.model.is_recurrent)
+        self.unroll = int(policy._unroll_T) if self.stateful else self.T
+        if self.T % self.unroll:
+            raise ValueError(
+                f"rollout_fragment_length {self.T} is not a multiple of "
+                f"the model's max_seq_len {self.unroll}: the device lane "
+                "trains a model with state on whole unrolls"
+            )
+
         # initial env carry, resident and row-sharded from step zero
         keys = env_keys(seed, self.N)
-        state = jax.jit(jax.vmap(env.init))(keys)
+        if hasattr(env, "init_at"):
+            # an env that places each slot by its index (a phase offset)
+            state = jax.jit(jax.vmap(env.init_at))(
+                keys, jax.numpy.arange(self.N)
+            )
+        else:
+            state = jax.jit(jax.vmap(env.init))(keys)
         state, obs = jax.jit(jax.vmap(env.reset))(state)
         carry = {
             "env": state,
@@ -140,6 +161,9 @@ class JaxRolloutEngine:
             "ep_ret": jax.numpy.zeros(self.N, jax.numpy.float32),
             "ep_len": jax.numpy.zeros(self.N, jax.numpy.int32),
         }
+        if self.stateful:
+            with tracing.start_span("rollout:state_init", num_envs=self.N):
+                carry["state"] = tuple(policy.model.initial_state(self.N))
         self._carry = jax.device_put(
             carry, sharding_lib.batch_sharded(self.mesh)
         )
@@ -171,9 +195,16 @@ class JaxRolloutEngine:
         standardize = self.standardize and mode == "gae"
         value_fwd = policy.model_forward
 
+        stateful = self.stateful
+        unroll = self.unroll
+        report_actions = bool(env.report_actions)
+        stored = stateful and bool(
+            getattr(policy.model, "supports_stored_train_state", False)
+        )
+
         def body(params, carry, ro_rngs, coeffs):
             def step(c, key_t):
-                env_state, obs, ep_ret, ep_len = c
+                env_state, obs, ep_ret, ep_len, mstate = c
                 # pin each sub-program's fusion boundary so it
                 # compiles like the actor lane's standalone jitted
                 # programs (action fn / vmapped env step / reset) —
@@ -184,9 +215,9 @@ class JaxRolloutEngine:
                             (params, obs, key_t)
                         )
                     )
-                    actions, _, extra, _ = policy._action_step_body(
+                    actions, mstate2, extra, _ = policy._action_step_body(
                         params_b, obs_b, key_t, coeffs,
-                        explore=True, expl_state=(),
+                        explore=True, expl_state=(), state=mstate,
                     )
                     # pin the OUTPUTS as well: the value head's result
                     # feeds the in-program GAE below, and without a
@@ -222,7 +253,26 @@ class JaxRolloutEngine:
                     SampleBatch.T: ep_len,
                     **extra,
                 }
-                if mode == "gae":
+                if stateful:
+                    # the learn form opens a new episode where the
+                    # rollout reset the state: at a row that starts one
+                    row["resets"] = (ep_len == 0).astype(jnp.float32)
+                if mode == "gae" and stateful:
+                    # V(final obs) needs a forward from the advanced
+                    # state, which the next step's act computes anyway
+                    # unless the episode was cut: only a truncation
+                    # pays for a forward of its own
+                    with jax.named_scope("rollout/act"):
+                        row["_v_next"] = jax.lax.cond(
+                            jnp.any(trunc & ~term),
+                            lambda: sharding_lib.varying(
+                                value_fwd(params, obs2[:, None], mstate2)[1], axis
+                            ),
+                            lambda: sharding_lib.varying(
+                                jnp.zeros(rew.shape, jnp.float32), axis
+                            ),
+                        )
+                elif mode == "gae":
                     # fresh V(final obs) for boundary/tail bootstraps
                     # — same (N,) forward shape as the act-path value,
                     # so the two lanes' bootstraps agree
@@ -236,12 +286,17 @@ class JaxRolloutEngine:
                         "ep_length": jnp.where(done, ep_len2, 0),
                         "done": done,
                     }
+                    if report_actions:
+                        metrics["actions"] = actions
                     env_state = tree_where(done, env_state3, env_state2)
                     obs_next = tree_where(done, obs3, obs2)
                     ep_ret = jnp.where(done, 0.0, ep_ret2)
                     ep_len = jnp.where(done, 0, ep_len2)
+                if stateful:
+                    with jax.named_scope("rollout/state_reset"):
+                        mstate2 = policy.reset_model_state(mstate2, done)
                 return (
-                    (env_state, obs_next, ep_ret, ep_len),
+                    (env_state, obs_next, ep_ret, ep_len, mstate2),
                     (row, metrics),
                 )
 
@@ -250,16 +305,37 @@ class JaxRolloutEngine:
                 carry["obs"],
                 carry["ep_ret"],
                 carry["ep_len"],
+                tuple(carry["state"]) if stateful else (),
             )
-            (env_state, obs, ep_ret, ep_len), (rows, metrics) = (
-                jax.lax.scan(step, c0, ro_rngs)
-            )
+            starts = None
+            if not stored or unroll == T:
+                if stored:
+                    starts = jax.tree_util.tree_map(
+                        lambda x: x[None], c0[4]
+                    )
+                c1, (rows, metrics) = jax.lax.scan(step, c0, ro_rngs)
+            else:
+                # the state at the start of every unroll-step chunk
+                def chunk(c, keys):
+                    c2, ys = jax.lax.scan(step, c, keys)
+                    return c2, (ys, c[4])
+
+                c1, ((rows, metrics), starts) = jax.lax.scan(
+                    chunk, c0,
+                    ro_rngs.reshape((T // unroll, unroll) + ro_rngs.shape[1:]),
+                )
+                rows, metrics = jax.tree_util.tree_map(
+                    lambda x: x.reshape((T,) + x.shape[2:]), (rows, metrics)
+                )
+            env_state, obs, ep_ret, ep_len, mstate = c1
             carry = {
                 "env": env_state,
                 "obs": obs,
                 "ep_ret": ep_ret,
                 "ep_len": ep_len,
             }
+            if stateful:
+                carry["state"] = mstate
             with jax.named_scope("rollout/postprocess"):
                 # global env index of each local row (host-lane
                 # AGENT_INDEX semantics)
@@ -273,12 +349,19 @@ class JaxRolloutEngine:
                         fresh = rows.pop("_v_next")  # (T, N)
                         term = rows[SampleBatch.TERMINATEDS]
                         done = term | rows[SampleBatch.TRUNCATEDS]
+                        if stateful:
+                            # the tail's bootstrap: one forward from
+                            # the final state (not committed)
+                            with jax.named_scope("rollout/act"):
+                                tail = value_fwd(params, obs[:, None], mstate)[1][None]
+                        else:
+                            tail = fresh[-1:]
                         # interior rows reuse the act-path values
                         # exactly like the host lane's vpred_t[1:];
                         # boundary/tail rows use the fresh
                         # terminal-observation values
                         shifted = jnp.concatenate(
-                            [values[1:], fresh[-1:]], axis=0
+                            [values[1:], tail], axis=0
                         )
                         next_values = jnp.where(done, fresh, shifted)
                         adv, vt = compute_gae_fragment(
@@ -305,9 +388,15 @@ class JaxRolloutEngine:
                 # lane's concat order
                 def to_rows(v):
                     v = jnp.swapaxes(v, 0, 1)
-                    return v.reshape((n_loc * T,) + v.shape[2:])
+                    return v.reshape((n_loc * v.shape[1],) + v.shape[2:])
 
                 batch = {k: to_rows(v) for k, v in rows.items()}
+                if starts is not None:
+                    # hand-over: one row per unroll, env-major like the
+                    # rows (what the nest's ``__chunk__`` gather reads)
+                    with jax.named_scope("rollout/state_handover"):
+                        for i, leaf in enumerate(starts):
+                            batch[f"__chunk__state_in_{i}"] = to_rows(leaf)
             return carry, batch, metrics
 
         self._body = body
@@ -357,6 +446,64 @@ class JaxRolloutEngine:
 
     # -- standalone rollout (replay fill / per-update lane) --------------
 
+    def _rollout_program(self):
+        """The standalone rollout program: the per-shard body under
+        ``shard_map`` and ``sharded_jit``, built on first use."""
+        if self._rollout_fn is not None:
+            return self._rollout_fn
+        import jax
+        from jax.sharding import PartitionSpec as P
+
+        from ray_tpu import sharding as sharding_lib
+
+        policy = self.policy
+        axis = sharding_lib.data_axis(self.mesh)
+        body = self._rollout_body()
+
+        def program(params, carry, ro_rngs, coeffs):
+            return body(params, carry, ro_rngs, coeffs)
+
+        # params enter per their spec tree (P() = replicated on
+        # un-partitioned policies; per-leaf model-axis slices for
+        # partitioned ones — the model inserts its own collectives)
+        p_ps = getattr(policy, "param_pspecs", None)
+        p_ps = (
+            P()
+            if p_ps is None
+            else sharding_lib.manual_pspecs(self.mesh, p_ps)
+        )
+        sharded = jax.shard_map(
+            program,
+            mesh=self.mesh,
+            in_specs=(p_ps, P(axis), P(), P()),
+            out_specs=(
+                P(axis),
+                P(axis),
+                P(None, axis),
+            ),
+        )
+        rep = sharding_lib.replicated(self.mesh)
+        p_sh = getattr(policy, "param_shardings", None) or rep
+        dat = sharding_lib.batch_sharded(self.mesh)
+        met = sharding_lib.batch_sharded(self.mesh, ndim_prefix=2)
+        self._rollout_fn = sharding_lib.sharded_jit(
+            sharded,
+            in_specs=(p_sh, dat, rep, rep),
+            out_specs=(dat, dat, met),
+            label=(
+                f"jax_rollout[{type(self.env).__name__}:"
+                f"{self.N}x{self.T}]"
+            ),
+        )
+        return self._rollout_fn
+
+    def rollout_from(self, params, carry, ro_rngs, coeffs):
+        """One dispatch of the standalone rollout program from a GIVEN
+        carry and key stack: ``(carry, batch, metrics)``, all on the
+        device, nothing committed to the engine (a comparison replays
+        the lane's body this way)."""
+        return self._rollout_program()(params, carry, ro_rngs, coeffs)
+
     def rollout(self):
         """One dispatched rollout: returns ``(device batch tree,
         batch_size)`` with the env carry advanced and episode metrics
@@ -368,47 +515,6 @@ class JaxRolloutEngine:
         from ray_tpu import sharding as sharding_lib
 
         policy = self.policy
-        if self._rollout_fn is None:
-            from jax.sharding import PartitionSpec as P
-
-            axis = sharding_lib.data_axis(self.mesh)
-            body = self._rollout_body()
-
-            def program(params, carry, ro_rngs, coeffs):
-                return body(params, carry, ro_rngs, coeffs)
-
-            # params enter per their spec tree (P() = replicated on
-            # un-partitioned policies; per-leaf model-axis slices for
-            # partitioned ones — the model inserts its own collectives)
-            p_ps = getattr(policy, "param_pspecs", None)
-            p_ps = (
-                P()
-                if p_ps is None
-                else sharding_lib.manual_pspecs(self.mesh, p_ps)
-            )
-            sharded = jax.shard_map(
-                program,
-                mesh=self.mesh,
-                in_specs=(p_ps, P(axis), P(), P()),
-                out_specs=(
-                    P(axis),
-                    P(axis),
-                    P(None, axis),
-                ),
-            )
-            rep = sharding_lib.replicated(self.mesh)
-            p_sh = getattr(policy, "param_shardings", None) or rep
-            dat = sharding_lib.batch_sharded(self.mesh)
-            met = sharding_lib.batch_sharded(self.mesh, ndim_prefix=2)
-            self._rollout_fn = sharding_lib.sharded_jit(
-                sharded,
-                in_specs=(p_sh, dat, rep, rep),
-                out_specs=(dat, dat, met),
-                label=(
-                    f"jax_rollout[{type(self.env).__name__}:"
-                    f"{self.N}x{self.T}]"
-                ),
-            )
         # host upkeep before the dispatch: exploration coefficients,
         # then the T key splits
         with tracing.start_span("rollout:keys", steps=self.T):
@@ -422,7 +528,7 @@ class JaxRolloutEngine:
         with tracing.start_span(
             "rollout:device", num_envs=self.N, steps=self.T
         ):
-            self._carry, batch, metrics = self._rollout_fn(
+            self._carry, batch, metrics = self.rollout_from(
                 policy.params, self._carry, ro_rngs, coeffs
             )
             # the one blocking read of the lane: the episode metrics
@@ -452,6 +558,7 @@ class JaxRolloutEngine:
     # -- episode metrics --------------------------------------------------
 
     def _record_metrics(self, metrics) -> None:
+        self.last_actions = metrics.get("actions")
         done = np.asarray(metrics["done"]).reshape(-1)
         if not done.any():
             return

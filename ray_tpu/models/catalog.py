@@ -70,6 +70,19 @@ MODEL_DEFAULTS: Dict[str, Any] = {
     # per-leaf placement override (ordered (pattern, spec) rules —
     # sharding.specs grammar); None → the model class's own rules
     "partition_rules": None,
+    # decoder language model composed from a layer pattern
+    # (models/sequence_lm.py): observation = the last token id, action
+    # = the next token. "sequence_lm" is the architecture under the
+    # key names of a Hugging Face config.json (hidden_size,
+    # num_hidden_layers, full_attention_interval or layer_types, the
+    # attention / linear_* / expert sizes, max_position_embeddings =
+    # the longest episode), plus the chip's share: "router_outputs"
+    # (experts the router scores) and "experts_held" ([first, count]
+    # of them computed here). The vocabulary held is the action
+    # space's size; "max_seq_len" is the fragment the learn form runs;
+    # "dtype" the operands of its products (None: bfloat16).
+    "use_sequence_lm": False,
+    "sequence_lm": None,
 }
 
 _custom_models: Dict[str, Type[RTModel]] = {}
@@ -143,6 +156,12 @@ class ModelCatalog:
         obs_shape = obs_space.shape
         is_image = len(obs_shape) == 3
 
+        if cfg["use_sequence_lm"]:
+            from ray_tpu.models.sequence_lm import SequenceLM
+
+            return SequenceLM(
+                num_outputs, cfg["sequence_lm"], dtype=cfg["dtype"] or "bfloat16"
+            )
         if cfg["use_transformer"]:
             from ray_tpu.models.transformer import TransformerPolicyNet
 

@@ -1,0 +1,542 @@
+"""A decoder language model composed from a ``layer_types`` pattern, as
+the policy of a token-level RL problem: observation = the last token
+id, action = the next token, a value head beside the output head.
+
+Layer kinds (equations as in Hugging Face ``qwen3_next``; the keys of
+``config`` carry that file's names):
+
+- every block: ``h = x + mixer(rms(x))``, ``y = h + moe(rms(h))`` with a
+  zero-centred RMSNorm, ``x * rsqrt(mean(x^2) + eps) * (1 + w)``;
+- ``"full_attention"``: gated softmax attention. One projection gives
+  each head its query and an output gate, ``k`` and ``v`` come for
+  fewer KV heads (GQA), ``q`` and ``k`` are RMS-normed over the head,
+  RoPE turns the first ``partial_rotary_factor`` of the head, and the
+  attention output is multiplied by ``sigmoid(gate)`` before the output
+  projection. No biases;
+- ``"linear_attention"``: Gated DeltaNet. One projection gives ``q, k,
+  v, z``, another ``b, a``; a causal depthwise convolution (width
+  ``linear_conv_kernel_dim``) and SiLU over the channels of ``(q, k,
+  v)``; ``beta = sigmoid(b)``, ``g = -exp(A_log) * softplus(a +
+  dt_bias)``; ``q`` and ``k`` L2-normalised; the gated delta rule
+  (``ops/deltanet.py``) per value head; ``rms(o) * w * silu(z)`` per
+  head, then the output projection;
+- the feed-forward of every block: a router over ALL ``router_outputs``
+  experts (softmax, top-k, renormalised), the experts this chip HOLDS
+  (``experts_held``: ``[first, count]``; ``ops/moe.py``), and a shared
+  expert times ``sigmoid(x w_s)``.
+
+State (``initial_state``; one row per stream, a flat tuple): for each
+linear layer the ``(value heads, dk, dv)`` float32 DeltaNet matrix and
+the last ``conv - 1`` inputs of the convolution; for each full layer
+the keys and values of the episode so far (bfloat16, ``(positions,
+kv heads x head)``, keys stored after norm and RoPE); last, the stream's
+position. ``apply`` has two forms that are the same function of the
+same weights: ``T == 1`` is the recurrence (one token, state in and
+out: the rollout lane's step), ``T > 1`` runs a fragment from a stored
+start state (DeltaNet in chunks, attention over the stored keys plus
+the fragment's own, ``resets`` opening a new episode inside it: the
+learn program's form).
+
+Precision: float32 parameters; the projections, expert products, the
+head and the attention products take bfloat16 operands and accumulate
+in float32; the router, softmax, top-k, ``g``, ``beta``, the DeltaNet
+state and every norm are float32 (the router and the delta rule at
+precision "highest").
+
+Not a flax module (cf. ``models/transformer.py``): plain-dict params,
+two levels deep, ``{"layer_0": {"in_proj_qkvz": ...}, ...}``.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.ops import deltanet, moe
+
+_HI = jax.lax.Precision.HIGHEST
+
+LINEAR, FULL = "linear_attention", "full_attention"
+
+# envs of a fragment whose attention scores are alive at once
+_ATTN_ENV_BLOCK = 8
+
+
+def layer_types_of(config: Dict) -> Tuple[str, ...]:
+    """The pattern: ``layer_types`` if stated, else every
+    ``full_attention_interval``-th layer is full attention."""
+    if config.get("layer_types"):
+        return tuple(config["layer_types"])
+    every = int(config.get("full_attention_interval", 4))
+    return tuple(
+        FULL if (i + 1) % every == 0 else LINEAR
+        for i in range(int(config["num_hidden_layers"]))
+    )
+
+
+def _rms(x, weight, eps, centred=True):
+    x = x.astype(jnp.float32)
+    y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return y * ((1.0 + weight) if centred else weight)
+
+
+def _l2norm(x, eps=1e-6):
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+def _rope(x, positions, rotary: int, theta: float):
+    """Rotate the first ``rotary`` dimensions of each head (the
+    rotate-half form). ``x`` ``(B, T, H, D)``, ``positions`` ``(B, T)``."""
+    half = rotary // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary)
+    angle = positions.astype(jnp.float32)[..., None] * inv  # (B, T, half)
+    cos, sin = jnp.cos(angle)[:, :, None], jnp.sin(angle)[:, :, None]
+    x1, x2, rest = x[..., :half], x[..., half:rotary], x[..., rotary:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1
+    )
+
+
+class SequenceLM:
+    """Duck-typed :class:`~ray_tpu.models.base.RTModel` surface over
+    plain-dict params. Registered via ``model_config["use_sequence_lm"]``
+    with the architecture under ``model_config["sequence_lm"]``
+    (``models/catalog.py``). ``num_outputs`` is the vocabulary held;
+    ``dtype`` (``model_config["dtype"]``) the operands' and the cache's."""
+
+    is_recurrent = True
+    supports_stored_train_state = True
+    # apply() names its scopes under a given prefix and hands the
+    # expert-load counts out through ``stats_out``
+    train_stats = True
+    _partition_rules_override = None
+    # tokens a DeltaNet chunk solves at once, and streams of a fragment
+    # batch the learn form runs at once (constants; tests shrink them)
+    chunk = 64
+    learn_streams = 16
+
+    def __init__(self, num_outputs: int, config: Dict, dtype: str = "bfloat16"):
+        c = dict(config)
+        self.config = c
+        self.vocab = int(num_outputs)
+        self.hidden = int(c["hidden_size"])
+        self.layer_types = layer_types_of(c)
+        self.eps = float(c.get("rms_norm_eps", 1e-6))
+        # gated attention
+        self.heads = int(c["num_attention_heads"])
+        self.kv_heads = int(c["num_key_value_heads"])
+        self.head_dim = int(c["head_dim"])
+        self.rotary = int(self.head_dim * float(c.get("partial_rotary_factor", 1.0)))
+        self.theta = float(c.get("rope_theta", 10000.0))
+        self.positions = int(c["max_position_embeddings"])
+        # gated deltanet
+        self.k_heads = int(c["linear_num_key_heads"])
+        self.v_heads = int(c["linear_num_value_heads"])
+        self.dk = int(c["linear_key_head_dim"])
+        self.dv = int(c["linear_value_head_dim"])
+        self.conv = int(c["linear_conv_kernel_dim"])
+        # experts: the router scores all of them, this chip holds some
+        self.router_outputs = int(c.get("router_outputs", c["num_experts"]))
+        first, count = c.get("experts_held") or (0, int(c["num_experts"]))
+        self.first_expert, self.experts_held = int(first), int(count)
+        self.top_k = int(c["num_experts_per_tok"])
+        self.norm_topk = bool(c.get("norm_topk_prob", True))
+        self.expert_width = int(c["moe_intermediate_size"])
+        self.shared_width = int(c["shared_expert_intermediate_size"])
+        # operands of the projections, the expert products, the head
+        # and the attention products; the cache's dtype
+        self.dtype = jnp.dtype(dtype)
+        self.key_dim = self.k_heads * self.dk
+        self.value_dim = self.v_heads * self.dv
+        self.conv_dim = 2 * self.key_dim + self.value_dim
+
+    def partition_rules(self):
+        return None
+
+    def _dot(self, x, w):
+        return jnp.dot(
+            x.astype(self.dtype), w.astype(self.dtype),
+            preferred_element_type=jnp.float32,
+        )
+
+    # -- state -----------------------------------------------------------
+
+    def initial_state(self, batch_size: int = 1):
+        b = int(batch_size)
+        state = []
+        for kind in self.layer_types:
+            if kind == LINEAR:
+                state.append(
+                    jnp.zeros((b, self.v_heads, self.dk, self.dv), jnp.float32)
+                )
+                state.append(
+                    jnp.zeros((b, self.conv - 1, self.conv_dim), jnp.float32)
+                )
+            else:
+                # one row a position: kv heads x head, flat, so that the
+                # device tiles (positions, row) without padding 2 heads to 8
+                shape = (b, self.positions, self.kv_heads * self.head_dim)
+                state.append(jnp.zeros(shape, self.dtype))
+                state.append(jnp.zeros(shape, self.dtype))
+        state.append(jnp.zeros((b,), jnp.int32))
+        return tuple(state)
+
+    def reset_state(self, state, mask):
+        """Open a new episode on the rows of ``mask``: the DeltaNet
+        matrices, the convolution inputs and the position go to zero;
+        a key/value cache is left as it is, since only slots below the
+        position are ever read."""
+        out = []
+        for n, kind in enumerate(self.layer_types):
+            for leaf in state[2 * n : 2 * n + 2]:
+                if kind == LINEAR:
+                    m = mask.reshape((-1,) + (1,) * (leaf.ndim - 1))
+                    leaf = jnp.where(m, jnp.zeros_like(leaf), leaf)
+                out.append(leaf)
+        out.append(jnp.where(mask, 0, state[-1]))
+        return tuple(out)
+
+    # -- parameters ------------------------------------------------------
+
+    def param_shapes(self) -> Dict[str, Dict[str, tuple]]:
+        d, v = self.hidden, self.vocab
+        e, f, fs = self.experts_held, self.expert_width, self.shared_width
+        shapes = {
+            "embed": {"embedding": (v, d)},
+            "final_norm": {"weight": (d,)},
+            "head": {"kernel": (d, v)},
+            "value": {"kernel": (d, 1), "bias": (1,)},
+        }
+        for i, kind in enumerate(self.layer_types):
+            layer = {
+                "input_norm": (d,),
+                "post_norm": (d,),
+                "router": (d, self.router_outputs),
+                "experts_gate": (e, d, f),
+                "experts_up": (e, d, f),
+                "experts_down": (e, f, d),
+                "shared_gate": (d, fs),
+                "shared_up": (d, fs),
+                "shared_down": (fs, d),
+                "shared_expert_gate": (d, 1),
+            }
+            if kind == LINEAR:
+                layer.update(
+                    in_proj_qkvz=(d, 2 * self.key_dim + 2 * self.value_dim),
+                    in_proj_ba=(d, 2 * self.v_heads),
+                    conv=(self.conv_dim, self.conv),
+                    A_log=(self.v_heads,),
+                    dt_bias=(self.v_heads,),
+                    gdn_norm=(self.dv,),
+                    out_proj=(self.value_dim, d),
+                )
+            else:
+                layer.update(
+                    q_proj=(d, self.heads * self.head_dim * 2),
+                    k_proj=(d, self.kv_heads * self.head_dim),
+                    v_proj=(d, self.kv_heads * self.head_dim),
+                    o_proj=(self.heads * self.head_dim, d),
+                    q_norm=(self.head_dim,),
+                    k_norm=(self.head_dim,),
+                )
+            shapes[f"layer_{i}"] = layer
+        return shapes
+
+    def init(self, rng, obs=None, state=None, **_):
+        """Normal matrices of variance 1 / rows, zero-centred norm
+        weights at zero, ``A`` uniform in (1, 16), ``dt_bias`` one: the
+        published initialisation's forms at a scale that keeps the
+        activations of a random model of order one."""
+        shapes = self.param_shapes()
+
+        @jax.jit
+        def make(key):
+            # XLA's bit generator: a threefry stream for every leaf of a
+            # model this size compiles for half a minute on a TPU
+            key = jax.random.wrap_key_data(
+                jnp.tile(jax.random.key_data(key).astype(jnp.uint32).ravel(), 2)[:4],
+                impl="rbg",
+            )
+            out, n = {}, 0
+            for group in sorted(shapes):
+                out[group] = {}
+                for leaf, shape in sorted(shapes[group].items()):
+                    k = jax.random.fold_in(key, n)
+                    n += 1
+                    if leaf == "A_log":
+                        x = jnp.log(jax.random.uniform(k, shape, minval=1.0, maxval=16.0))
+                    elif leaf in ("dt_bias", "gdn_norm"):
+                        x = jnp.ones(shape, jnp.float32)
+                    elif len(shape) == 1:
+                        x = jnp.zeros(shape, jnp.float32)
+                    elif leaf == "embedding":
+                        x = jax.random.normal(k, shape, jnp.float32)
+                    else:
+                        x = jax.random.normal(k, shape, jnp.float32) / np.sqrt(shape[-2])
+                    out[group][leaf] = x
+            return out
+
+        return make(rng)
+
+    # -- forward ---------------------------------------------------------
+
+    def apply(self, params, obs, state, resets=None, scope: str = "",
+              stats_out: Optional[Dict] = None, **_):
+        """``obs`` ``(B, T[, 1])`` token ids; ``state`` as
+        ``initial_state``; ``resets`` ``(B, T)`` (1.0 where a token
+        opens an episode). Returns ``(logits (B*T, vocab), value
+        (B*T,), state)``. ``scope`` prefixes the named scopes;
+        ``stats_out`` receives the expert-load counts."""
+        tokens = obs.reshape(obs.shape[0], -1).astype(jnp.int32)
+        b, t = tokens.shape
+        if resets is None:
+            resets = jnp.zeros((b, t), jnp.float32)
+        fresh = resets.reshape(b, t) > 0.5
+        if t == 1:
+            state = self.reset_state(state, fresh[:, 0])
+            fresh = jnp.zeros_like(fresh)
+        seg = jnp.cumsum(fresh.astype(jnp.int32), axis=1)  # (B, T)
+        steps = jnp.arange(t, dtype=jnp.int32)[None]
+        opened = jax.lax.cummax(jnp.where(fresh, steps, -1), axis=1)
+        pos0 = state[-1]
+        positions = jnp.where(seg == 0, pos0[:, None] + steps, steps - opened)
+        rows_ctx = {
+            "seg": seg, "fresh": fresh, "positions": positions, "pos0": pos0,
+        }
+        prefix = (scope + "/") if scope else ""
+
+        x = jnp.take(params["embed"]["embedding"], tokens, axis=0)  # (B, T, D)
+
+        def block(x, p, layer_state, rows, kind):
+            ctx = dict(rows, scope=prefix)
+            mixer = self._linear_attn if kind == LINEAR else self._attn
+            y, new = mixer(p, _rms(x, p["input_norm"], self.eps), layer_state, ctx)
+            x = x + y
+            y, load, routes = self._moe(p, _rms(x, p["post_norm"], self.eps), ctx)
+            return x + y, new, load, routes
+
+        groups = b // self.learn_streams if (
+            t > 1 and b > self.learn_streams and b % self.learn_streams == 0
+        ) else 1
+        # the learn form keeps a block's input and recomputes the block
+        # in the backward pass, ``learn_streams`` streams at a time: one
+        # group's activations of one block are alive, not the batch's
+        # of the stack
+        whole = jax.checkpoint(block, static_argnums=(4,)) if t > 1 else block
+
+        def run_block(x, p, layer_state, rows, kind):
+            if groups == 1:
+                return whole(x, p, layer_state, rows, kind)
+            split = lambda a: a.reshape((groups, b // groups) + a.shape[1:])
+            merge = lambda a: a.reshape((b,) + a.shape[2:])
+            x, new, load, routes = jax.lax.map(
+                lambda xs: whole(xs[0], p, xs[1], xs[2], kind),
+                jax.tree_util.tree_map(split, (x, layer_state, rows)),
+            )
+            return (
+                merge(x),
+                jax.tree_util.tree_map(merge, new),
+                jax.tree_util.tree_map(lambda a: a.sum(0), load),
+                routes.reshape((-1,) + routes.shape[2:]),
+            )
+
+        state_out, loads, all_routes = [], [], []
+        for n, kind in enumerate(self.layer_types):
+            x, new, load, routes = run_block(
+                x, params[f"layer_{n}"], tuple(state[2 * n : 2 * n + 2]),
+                rows_ctx, kind,
+            )
+            state_out.extend(new)
+            loads.append(load)
+            all_routes.append(routes)
+        state_out.append(positions[:, -1] + 1)
+
+        with jax.named_scope(prefix + "head"):
+            feat = _rms(x, params["final_norm"]["weight"], self.eps).reshape(b * t, -1)
+            logits = self._dot(feat, params["head"]["kernel"])
+            value = (
+                jnp.dot(feat, params["value"]["kernel"], precision=_HI)
+                + params["value"]["bias"]
+            )[:, 0]
+        if stats_out is not None:
+            per_expert = jnp.stack([l[0] for l in loads])  # (layers, held)
+            stats_out["moe_tokens_per_held_expert"] = jnp.mean(per_expert)
+            stats_out["moe_max_tokens_per_held_expert"] = jnp.max(per_expert)
+            stats_out["moe_slots_on_absent_experts"] = sum(l[1] for l in loads)
+            if "moe_routes" in stats_out:
+                stats_out["moe_routes"] = jnp.stack(all_routes)
+        return logits, value, tuple(state_out)
+
+    # -- gated deltanet --------------------------------------------------
+
+    def _linear_attn(self, p, x, state, ctx):
+        with jax.named_scope(ctx["scope"] + "linear_attn"):
+            s0, tail = state
+            b, t, _ = x.shape
+            seg = ctx["seg"]
+            kd, vd, hv, hk = self.key_dim, self.value_dim, self.v_heads, self.k_heads
+            qkvz = self._dot(x, p["in_proj_qkvz"])
+            mixed, z = qkvz[..., : 2 * kd + vd], qkvz[..., 2 * kd + vd :]
+            ba = jnp.dot(x, p["in_proj_ba"], precision=_HI)
+            beta = jax.nn.sigmoid(ba[..., :hv])
+            g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[..., hv:] + p["dt_bias"])
+
+            # causal depthwise convolution over the stored inputs and
+            # the fragment's own; an input of an earlier episode is
+            # not seen
+            width = self.conv
+            full = jnp.concatenate([tail, mixed], axis=1)  # (B, T+w-1, C)
+            full_seg = jnp.concatenate(
+                [jnp.zeros((b, width - 1), seg.dtype), seg], axis=1
+            )
+            conv = jnp.zeros_like(mixed)
+            for back in range(width):
+                lo = width - 1 - back
+                seen = (full_seg[:, lo : lo + t] == seg)[..., None]
+                conv = conv + jnp.where(
+                    seen, full[:, lo : lo + t], 0.0
+                ) * p["conv"][:, width - 1 - back]
+            mixed = jax.nn.silu(conv)
+            live = (full_seg[:, t:] == seg[:, -1:])[..., None]
+            new_tail = jnp.where(live, full[:, t:], 0.0)
+
+            q = mixed[..., :kd].reshape(b, t, hk, self.dk)
+            k = mixed[..., kd : 2 * kd].reshape(b, t, hk, self.dk)
+            v = mixed[..., 2 * kd :].reshape(b, t, hv, self.dv)
+            q = _l2norm(q) * (self.dk ** -0.5)
+            k = _l2norm(k)
+            q = jnp.repeat(q, hv // hk, axis=2)
+            k = jnp.repeat(k, hv // hk, axis=2)
+            if t == 1:
+                s1, o = deltanet.gated_delta_step(
+                    s0, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0]
+                )
+                o = o[:, None]
+            else:
+                o, s1 = deltanet.gated_delta_chunked(
+                    s0, q, k, v, g, beta,
+                    resets=ctx["fresh"].astype(jnp.float32),
+                    chunk=self.chunk,
+                )
+            o = _rms(o, p["gdn_norm"], self.eps, centred=False)
+            o = o * jax.nn.silu(z.reshape(b, t, hv, self.dv))
+            return self._dot(o.reshape(b, t, vd), p["out_proj"]), (s1, new_tail)
+
+    # -- gated attention -------------------------------------------------
+
+    def _attn(self, p, x, state, ctx):
+        with jax.named_scope(ctx["scope"] + "attn"):
+            k_cache, v_cache = state
+            b, t, _ = x.shape
+            h, hkv, d = self.heads, self.kv_heads, self.head_dim
+            seg, positions, pos0 = ctx["seg"], ctx["positions"], ctx["pos0"]
+            qg = self._dot(x, p["q_proj"]).reshape(b, t, h, 2 * d)
+            q, gate = qg[..., :d], qg[..., d:]
+            k = self._dot(x, p["k_proj"]).reshape(b, t, hkv, d)
+            v = self._dot(x, p["v_proj"]).reshape(b, t, hkv, d)
+            q = _rope(_rms(q, p["q_norm"], self.eps), positions, self.rotary, self.theta)
+            k = _rope(_rms(k, p["k_norm"], self.eps), positions, self.rotary, self.theta)
+            k, v = k.astype(self.dtype), v.astype(self.dtype)
+
+            # the cache after the fragment: the last episode's tokens,
+            # each at its position (positions of one episode are
+            # distinct; earlier episodes' tokens are dropped)
+            slot = jnp.where(seg == seg[:, -1:], positions, self.positions)
+            rows = jnp.arange(b)[:, None]
+            new_k = k_cache.at[rows, slot].set(
+                k.reshape(b, t, hkv * d).astype(k_cache.dtype), mode="drop")
+            new_v = v_cache.at[rows, slot].set(
+                v.reshape(b, t, hkv * d).astype(v_cache.dtype), mode="drop")
+
+            scale = d ** -0.5
+            group = h // hkv
+            qh = (q * scale).astype(self.dtype).reshape(b, t, hkv, group, d)
+            slots = jnp.arange(self.positions)
+
+            def attend(qe, ke, ve, kc, vc, sege, pos0e):
+                kc = kc.reshape(kc.shape[:2] + (hkv, d))
+                vc = vc.reshape(vc.shape[:2] + (hkv, d))
+                # one block of envs: scores over the stored keys (a
+                # stored key is seen by the tokens before the first
+                # reset, below the start position) and the fragment's
+                # own (causal, same episode)
+                old = jnp.einsum(
+                    "btngd,bsnd->bngts", qe, kc, preferred_element_type=jnp.float32
+                )
+                see_old = (sege == 0)[:, :, None] & (
+                    slots[None, None] < pos0e[:, None, None]
+                )  # (b, t, S)
+                old = jnp.where(see_old[:, None, None], old, -jnp.inf)
+                if t == 1:
+                    # the step's own key is in the cache already
+                    own = jnp.full(old.shape[:-1] + (0,), -jnp.inf)
+                else:
+                    own = jnp.einsum(
+                        "btngd,bsnd->bngts", qe, ke,
+                        preferred_element_type=jnp.float32,
+                    )
+                    see = (steps_t[:, None] >= steps_t[None, :])[None] & (
+                        sege[:, :, None] == sege[:, None, :]
+                    )
+                    own = jnp.where(see[:, None, None], own, -jnp.inf)
+                w = jax.nn.softmax(jnp.concatenate([old, own], axis=-1), axis=-1)
+                w = w.astype(self.dtype)
+                out = jnp.einsum(
+                    "bngts,bsnd->btngd", w[..., : self.positions], vc,
+                    preferred_element_type=jnp.float32,
+                )
+                if t > 1:
+                    out = out + jnp.einsum(
+                        "bngts,bsnd->btngd", w[..., self.positions :], ve,
+                        preferred_element_type=jnp.float32,
+                    )
+                return out
+
+            steps_t = jnp.arange(t)
+            if t == 1:
+                # decode reads the cache it has just written: the own
+                # key sits at slot pos0, so the stored range is one longer
+                o = attend(qh, k, v, new_k, new_v, seg, pos0 + 1)
+            else:
+                nb = max(1, b // _ATTN_ENV_BLOCK)
+                if b % nb:
+                    nb = 1
+                args = (qh, k, v, k_cache, v_cache, seg, pos0)
+                blocked = tuple(
+                    a.reshape((nb, b // nb) + a.shape[1:]) for a in args
+                )
+                o = jax.lax.map(
+                    lambda xs: jax.checkpoint(attend)(*xs), blocked
+                )
+                o = o.reshape((b,) + o.shape[2:])
+            o = o.reshape(b, t, h, d) * jax.nn.sigmoid(gate)
+            return self._dot(o.reshape(b, t, h * d), p["o_proj"]), (new_k, new_v)
+
+    # -- experts ---------------------------------------------------------
+
+    def _moe(self, p, x, ctx):
+        b, t, d = x.shape
+        flat = x.reshape(b * t, d)
+        scope = ctx["scope"]
+        with jax.named_scope(scope + "moe/route"):
+            indices, weights = moe.route_top_k(
+                flat, p["router"], self.top_k, self.norm_topk
+            )
+            combine = moe.held_combine_weights(
+                indices, weights, self.first_expert, self.experts_held
+            )
+            load = moe.expert_load(indices, self.first_expert, self.experts_held)
+        with jax.named_scope(scope + "moe/experts"):
+            routed = moe.held_experts_product(
+                flat, p["experts_gate"], p["experts_up"], p["experts_down"], combine,
+                dtype=self.dtype,
+            )
+        with jax.named_scope(scope + "moe/shared"):
+            shared = moe.gated_mlp(
+                flat, p["shared_gate"], p["shared_up"], p["shared_down"],
+                dtype=self.dtype,
+            ) * jax.nn.sigmoid(jnp.dot(flat, p["shared_expert_gate"], precision=_HI))
+        return (routed + shared).reshape(b, t, d), load, indices

@@ -480,21 +480,35 @@ class JaxPolicy(Policy):
     # -- inference -------------------------------------------------------
 
     def _action_step_body(
-        self, params, obs, rng, coeffs, *, explore=True, expl_state=()
+        self, params, obs, rng, coeffs, *, explore=True, expl_state=(),
+        state=(), prev_actions=None, prev_rewards=None,
     ):
-        """The non-recurrent per-step action computation — model
-        forward, distribution, exploration sampling, extra fetches —
-        as a pure traced body: ``(actions, state_out, extra,
-        expl_state)``. Shared by the jitted ``compute_actions``
-        program (:meth:`_build_action_fn`) and the device rollout lane
+        """The per-step action computation — model forward,
+        distribution, exploration sampling, extra fetches — as a pure
+        traced body: ``(actions, state_out, extra, expl_state)``.
+        ``state`` is the model's per-stream state pytree (``()`` for a
+        feedforward model): a model with state takes ``obs`` as a
+        one-step unroll and hands the state back advanced. Shared by
+        the jitted ``compute_actions`` program
+        (:meth:`_build_action_fn`) and the device rollout lane
         (``execution/jax_rollout.py``), with the SAME internal rng
         split structure, so the two rollout lanes consume identical
         key streams per step (the fixed-seed parity contract of
         docs/pipeline.md)."""
-        rng_m, rng = jax.random.split(rng)
-        dist_inputs, value, state_out = self._apply_model_for_actions(
-            params, obs, rng_m, explore
-        )
+        if self.model.is_recurrent:
+            kwargs = {}
+            if prev_actions is not None:
+                kwargs["prev_actions"] = prev_actions[:, None]
+            if prev_rewards is not None:
+                kwargs["prev_rewards"] = prev_rewards[:, None]
+            dist_inputs, value, state_out = self.model.apply(
+                params, obs[:, None], state, **kwargs
+            )
+        else:
+            rng_m, rng = jax.random.split(rng)
+            dist_inputs, value, state_out = self._apply_model_for_actions(
+                params, obs, rng_m, explore
+            )
         dist = self.dist_class(dist_inputs)
         rng_x, rng = jax.random.split(rng)
         actions, logp, expl_state = self.exploration.sample_fn(
@@ -508,6 +522,20 @@ class JaxPolicy(Policy):
             self.extra_action_out(dist_inputs, value, dist, rng)
         )
         return actions, state_out, extra, expl_state
+
+    def reset_model_state(self, state, mask):
+        """The model's per-stream state with the rows of ``mask`` (N,)
+        bool set to the start of an episode: the model's own
+        ``reset_state`` where it has one (a key/value cache need not
+        be cleared), its ``initial_state`` otherwise."""
+        reset = getattr(self.model, "reset_state", None)
+        if reset is not None:
+            return reset(state, mask)
+        from ray_tpu.env.jax_env import tree_where
+
+        return tree_where(
+            mask, tuple(self.model.initial_state(mask.shape[0])), tuple(state)
+        )
 
     @property
     def supports_batched_serve(self) -> bool:
@@ -538,20 +566,22 @@ class JaxPolicy(Policy):
     def supports_jax_rollout(self) -> bool:
         """Whether this policy's act path can lower into the device
         rollout lane's scanned program (``execution/jax_rollout.py``):
-        feedforward model, stateless exploration, mesh backend (the
-        rollout program carries explicit shardings). Recurrent unrolls
-        and stateful exploration (OU noise, ParameterNoise) stay on
-        the actor lane."""
+        stateless exploration, mesh backend (the rollout program
+        carries explicit shardings), and a model whose only inputs are
+        the observation and its own per-stream state (the lane carries
+        that state with the env's; a model fed the previous action or
+        reward, and stateful exploration such as OU noise or
+        ParameterNoise, stay on the actor lane)."""
         return (
-            not self.model.is_recurrent
-            and self.sharding_backend == "mesh"
+            self.sharding_backend == "mesh"
+            and not getattr(self.model, "use_prev_action", False)
+            and not getattr(self.model, "use_prev_reward", False)
             and not self.exploration.needs_last_obs
             and self.exploration.initial_state(1) == ()
         )
 
     def _build_action_fn(self):
         model = self.model
-        dist_class = self.dist_class
         recurrent = model.is_recurrent
         use_prev_a = recurrent and getattr(
             model, "use_prev_action", False
@@ -559,36 +589,18 @@ class JaxPolicy(Policy):
         use_prev_r = recurrent and getattr(
             model, "use_prev_reward", False
         )
-        exploration = self.exploration
 
         def fn(
             params, obs, states, rng, explore, coeffs, expl_state,
             prev_a, prev_r,
         ):
-            if not recurrent:
-                return self._action_step_body(
-                    params, obs, rng, coeffs,
-                    explore=explore, expl_state=expl_state,
-                )
-            kwargs = {}
-            if use_prev_a:
-                kwargs["prev_actions"] = prev_a[:, None]
-            if use_prev_r:
-                kwargs["prev_rewards"] = prev_r[:, None]
-            dist_inputs, value, state_out = model.apply(
-                params, obs[:, None], states, **kwargs
+            return self._action_step_body(
+                params, obs, rng, coeffs,
+                explore=explore, expl_state=expl_state,
+                state=states if recurrent else (),
+                prev_actions=prev_a if use_prev_a else None,
+                prev_rewards=prev_r if use_prev_r else None,
             )
-            dist = dist_class(dist_inputs)
-            rng_x, rng = jax.random.split(rng)
-            actions, logp, expl_state = exploration.sample_fn(
-                dist, rng_x, explore, coeffs, expl_state
-            )
-            extra = {
-                SampleBatch.ACTION_DIST_INPUTS: dist_inputs,
-                SampleBatch.ACTION_LOGP: logp,
-            }
-            extra.update(self.extra_action_out(dist_inputs, value, dist, rng))
-            return actions, state_out, extra, expl_state
 
         return jax.jit(fn, static_argnames=("explore",))
 
@@ -769,6 +781,10 @@ class JaxPolicy(Policy):
                         v.reshape(v.shape[0], -1, 4), jnp.uint32
                     )
 
+            whole_batch = num_mb == 1 and mb_loc == b_loc and any(
+                k.startswith("__chunk__") for k in batch
+            )
+
             def _unpack(k, v):
                 shp = packed_shapes.get(k)
                 if shp is None:
@@ -783,17 +799,24 @@ class JaxPolicy(Policy):
                 # (chunk-start recurrent states); gather them by the
                 # unroll indices the row permutation selected
                 with jax.named_scope("learn/minibatch"):
-                    mb = {
-                        k: _unpack(
-                            k,
-                            (
-                                v[idx.reshape(-1, T_seq)[:, 0] // T_seq]
-                                if k.startswith("__chunk__")
-                                else v[idx]
-                            ),
-                        )
-                        for k, v in batch.items()
-                    }
+                    if whole_batch:
+                        # one minibatch of every row: a mean over rows
+                        # does not depend on their order, and a
+                        # gathered copy of the stored states would
+                        # double them
+                        mb = {k: _unpack(k, v) for k, v in batch.items()}
+                    else:
+                        mb = {
+                            k: _unpack(
+                                k,
+                                (
+                                    v[idx.reshape(-1, T_seq)[:, 0] // T_seq]
+                                    if k.startswith("__chunk__")
+                                    else v[idx]
+                                ),
+                            )
+                            for k, v in batch.items()
+                        }
                 # differentiate a per-shard view of the replicated
                 # params so the gradients stay per-shard and the pmean
                 # below is the one real cross-shard reduction
@@ -1461,6 +1484,11 @@ class JaxPolicy(Policy):
                     f"{batch_size}x{k_max}]"
                 ),
                 rollout_fn=rollout.body,
+                # a model with per-stream state: the params and the
+                # carry (a cache per env) are handed over, not copied
+                donate_rollout_state=bool(
+                    getattr(getattr(self, "model", None), "is_recurrent", False)
+                ),
                 nan_guard=nan_guard,
                 carry_pspecs=(
                     self._carry_pspecs()
@@ -1564,6 +1592,7 @@ class JaxPolicy(Policy):
             }
             for i in range(k)
         ]
+        telemetry_metrics.note_expert_load(infos)
         return infos, carry, metrics, skipped
 
     def prepare_batch(self, samples) -> Tuple[Dict[str, np.ndarray], int]:
@@ -2200,7 +2229,7 @@ class JaxPolicy(Policy):
             tree["resets"] = resets
         return tree
 
-    def model_forward_train(self, params, batch):
+    def model_forward_train(self, params, batch, stats_out=None):
         """Learn-path forward over a flat training batch. Feedforward
         models pass through; recurrent models reshape the N flat rows
         into (N/T, T) unrolls — chunk starts use the sampler's stored
@@ -2209,7 +2238,10 @@ class JaxPolicy(Policy):
         models/attention.py), with the ``resets`` column zeroing the
         carry at trajectory boundaries — and return flattened (N,)
         outputs, so losses written against flat rows work unchanged
-        (the reference's rnn_sequencing role, fixed-shape style)."""
+        (the reference's rnn_sequencing role, fixed-shape style). A
+        loss that hands in a ``stats_out`` dict gets back what a model
+        with ``train_stats`` counts in its forward (expert load), to
+        merge into its own stats."""
         obs = batch[SampleBatch.OBS]
         if not self.model.is_recurrent:
             return self.model.apply(params, obs)
@@ -2261,6 +2293,10 @@ class JaxPolicy(Policy):
             state0 = tuple(state0)
         else:
             state0 = self._zero_initial_state(obs, B)
+        if getattr(self.model, "train_stats", False):
+            # the model names its scopes under learn/
+            kwargs["scope"] = "learn"
+            kwargs["stats_out"] = stats_out
         return self.model.apply(
             params, obs.reshape((B, T) + obs.shape[1:]), state0,
             **kwargs,

@@ -113,6 +113,7 @@ def build_superstep_fn(
     priority_fn: Optional[Callable] = None,
     nan_guard: bool = False,
     carry_pspecs=None,
+    donate_rollout_state: bool = False,
 ) -> ShardedFunction:
     """Compile the K-update superstep program around ``update_fn``.
 
@@ -143,6 +144,9 @@ def build_superstep_fn(
         ``metrics`` (per-slot episode-completion arrays, any pytree of
         ``(..., N)`` leaves sharded on the last axis) stack to
         ``(K, ..., N)`` outputs and ride the single stats drain.
+        ``donate_rollout_state`` also donates ``params`` and ``carry``
+        (a policy whose per-stream state is a cache per env: a second
+        copy of either does not fit beside the first).
 
     ``priority_fn(params, aux, batch, rng) -> (B,)`` runs after each
     update on the post-update state (per-update PER refresh order) and
@@ -197,6 +201,7 @@ def build_superstep_fn(
             label=label,
             nan_guard=nan_guard,
             carry_pspecs=(p_ps, o_ps, a_ps),
+            donate=(0, 1, 3) if donate_rollout_state else (1,),
         )
 
     def multi_fn(params, opt_state, aux, stacked, active, *rest):
@@ -357,6 +362,7 @@ def _build_rollout_superstep(
     label: str,
     nan_guard: bool,
     carry_pspecs=(P(), P(), P()),
+    donate=(1,),
 ) -> ShardedFunction:
     """The rollout-producing feed of :func:`build_superstep_fn`: slot
     k of the scan rolls out the env carry with the CURRENT params,
@@ -439,7 +445,7 @@ def _build_rollout_superstep(
     )
     if backend != "mesh":
         return sharded_jit(
-            sharded, donate_argnums=(1,), label=label
+            sharded, donate_argnums=donate, label=label
         )
     rep = replicated(mesh)
     dat = batch_sharded(mesh)
@@ -451,7 +457,7 @@ def _build_rollout_superstep(
         sharded,
         in_specs=(p_sh, o_sh, a_sh, dat, rep, rep, rep, rep),
         out_specs=(p_sh, o_sh, a_sh, dat, rep, met),
-        donate_argnums=(1,),
+        donate_argnums=donate,
         label=label,
     )
 

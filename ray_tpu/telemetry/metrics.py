@@ -99,6 +99,12 @@ H2D_BYTES_TOTAL = "ray_tpu_h2d_bytes_total"
 # superstep learner contract (docs/data_plane.md): updates executed
 # inside fused K-updates-per-dispatch programs
 SUPERSTEP_UPDATES_TOTAL = "ray_tpu_superstep_updates_total"
+# routed-expert load of a model that holds a share of its experts
+# (models/sequence_lm.py): per update, the mean and the largest count
+# of tokens a held expert saw (summed over updates under "stat"), and
+# the (token, slot) pairs that fell on experts held elsewhere
+MOE_HELD_EXPERT_TOKENS_TOTAL = "ray_tpu_moe_held_expert_tokens_total"
+MOE_ABSENT_SLOTS_TOTAL = "ray_tpu_moe_absent_slots_total"
 # prioritized-replay segment-tree operations by op and by which tree
 # implementation performed them (docs/data_plane.md "device sum
 # tree"): host = the numpy SumSegmentTree walk, device = the
@@ -465,6 +471,40 @@ def inc_superstep_updates(n: int = 1) -> None:
         SUPERSTEP_UPDATES_TOTAL,
         "learner updates run inside fused superstep dispatches",
     ).inc(float(n))
+
+
+def note_expert_load(infos) -> None:
+    """Feed the expert-load counters from drained per-update learner
+    stats (a no-op for a policy whose model reports none)."""
+    for info in infos:
+        if "moe_tokens_per_held_expert" not in info:
+            continue
+        held = counter(
+            MOE_HELD_EXPERT_TOKENS_TOTAL,
+            "tokens a held expert saw per update: mean and max over the "
+            "held experts, summed over updates; and the updates counted",
+            ("stat",),
+        )
+        held.inc(float(info["moe_tokens_per_held_expert"]), {"stat": "mean"})
+        held.inc(
+            float(info["moe_max_tokens_per_held_expert"]), {"stat": "max"}
+        )
+        held.inc(1.0, {"stat": "updates"})
+        counter(
+            MOE_ABSENT_SLOTS_TOTAL,
+            "(token, slot) pairs routed to experts this chip does not hold",
+        ).inc(float(info["moe_slots_on_absent_experts"]))
+
+
+def expert_load_totals() -> Dict[str, float]:
+    """``{"mean", "max", "updates", "absent_slots"}`` sums since the
+    process began ({} for a model that reports no expert load)."""
+    m = get_metric(MOE_HELD_EXPERT_TOKENS_TOTAL)
+    if m is None:
+        return {}
+    out = {dict(tags).get("stat", ""): v for tags, v in m.series()}
+    out["absent_slots"] = counter_total(MOE_ABSENT_SLOTS_TOTAL)
+    return out
 
 
 def inc_env_steps_on_device(n: int) -> None:
