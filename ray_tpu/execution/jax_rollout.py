@@ -13,7 +13,8 @@ produces the actor lane's trajectories bit for bit
 
 Two consumption modes:
 
-- :meth:`JaxRolloutEngine.rollout` — one dispatch produces a
+- :meth:`JaxRolloutEngine.rollout` — the key schedule (one tiny
+  program) and ONE dispatch of the rollout program produce a
   device-resident trajectory batch (``(N·T, ...)`` columns, env-major
   row order like the host lane's concat). On-policy algorithms learn
   from it in place; off-policy algorithms insert the rows into a
@@ -507,23 +508,22 @@ class JaxRolloutEngine:
     def rollout(self):
         """One dispatched rollout: returns ``(device batch tree,
         batch_size)`` with the env carry advanced and episode metrics
-        absorbed. The policy's rng is split T times host-side (the
-        actor lane's per-step order)."""
+        absorbed. The policy's rng advances by T sequential splits,
+        the actor lane's per-step stream in its order, composed in one
+        program (``JaxPolicy._rollout_keys``), so a rollout is two
+        dispatches: the key schedule and the rollout program."""
         import jax
-        import jax.numpy as jnp
 
         from ray_tpu import sharding as sharding_lib
 
         policy = self.policy
         # host upkeep before the dispatch: exploration coefficients,
-        # then the T key splits
-        with tracing.start_span("rollout:keys", steps=self.T):
+        # then the T key splits as one program
+        with tracing.start_span(
+            "rollout:keys", steps=self.T, dispatches=1
+        ):
             coeffs = self._pre_dispatch()
-            keys = []
-            for _ in range(self.T):
-                policy._rng, r = jax.random.split(policy._rng)
-                keys.append(r)
-            ro_rngs = jnp.stack(keys)
+            ro_rngs = policy._rollout_keys(self.T)
         telemetry_metrics.add_h2d_bytes("rollout", int(ro_rngs.nbytes))
         with tracing.start_span(
             "rollout:device", num_envs=self.N, steps=self.T

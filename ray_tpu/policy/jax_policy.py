@@ -1096,81 +1096,92 @@ class JaxPolicy(Policy):
         return m
 
     # ray-tpu: hot-path
-    def _superstep_host_keys(self, k, k_max, refresh, td_rng):
-        """The superstep's host key schedule as ONE fused program: the
-        sequential split chain (learn split, then the optional td
-        split, per update) unrolls inside a single jitted function
-        that returns the advanced stream plus the padded (k_max, 2)
-        key stacks. threefry splitting is a deterministic integer
-        function of the key, so composing the chain inside one program
-        yields bit-identical keys and final stream to k (or 2k)
-        individual host splits — only the dispatch count changes
-        (bench.py --dispatch measures exactly this collapse)."""
+    def _split_chain(self, family, slot, k=None, k_max=None):
+        """A host key schedule as ONE program: advance ``self._rng``
+        through a slot's splits, ``k`` slots in a row, and return one
+        key stack per stream. ``slot`` lists the streams in split
+        order, each by its number of sequential
+        ``rng, r = jax.random.split(rng)``: ``None`` is one split and
+        yields a key, ``n > 0`` is n splits (a ``lax.scan`` of the
+        same split) and yields an ``(n, 2)`` stack, ``0`` takes no
+        split and yields a zero key. With ``k`` the stacks gain a
+        leading slot axis padded with zero keys to ``k_max``.
+
+        threefry splitting is an integer function of the key, so the
+        chain composed inside one jitted function gives the stacks and
+        the advanced stream of the sequential host loop bit for bit
+        (docs/data_plane.md "rng split order"); only the dispatch
+        count changes. Every lane's schedule is a signature of this
+        one cache; the program is ``<family>[<slots>x<splits>...]`` in
+        ``compile_stats()``, the device ledger and a profiler trace."""
         fns = self.__dict__.setdefault("_split_chain_fns", {})
-        sig = ("superstep", k, k_max, bool(refresh), bool(td_rng))
+        sig = (family, slot, k, k_max)
         fn = fns.get(sig)
         if fn is None:
 
-            def chain(rng):
-                keys, pri_keys = [], []
-                for _ in range(k):
-                    rng, r = jax.random.split(rng)
-                    keys.append(r)
-                    if refresh:
-                        if td_rng:
-                            rng, r2 = jax.random.split(rng)
-                        else:
-                            r2 = jnp.zeros_like(r)
-                        pri_keys.append(r2)
-                pad = jnp.zeros_like(keys[0])
-                keys += [pad] * (k_max - k)
-                if refresh:
-                    pri_keys += [pad] * (k_max - k)
-                    return rng, jnp.stack(keys), jnp.stack(pri_keys)
-                return rng, jnp.stack(keys)
+            def one_split(rng, _):
+                rng, r = jax.random.split(rng)
+                return rng, r
 
-            fn = jax.jit(chain)
+            def chain(rng):
+                streams = [[] for _ in slot]
+                for _ in range(1 if k is None else k):
+                    for keys, n in zip(streams, slot):
+                        if n is None:
+                            rng, r = jax.random.split(rng)
+                        elif n:
+                            rng, r = jax.lax.scan(
+                                one_split, rng, None, length=n
+                            )
+                        else:
+                            r = jnp.zeros_like(rng)
+                        keys.append(r)
+                if k is None:
+                    return (rng, *(keys[0] for keys in streams))
+                return (rng, *(
+                    jnp.stack(
+                        keys + [jnp.zeros_like(keys[0])] * (k_max - k)
+                    )
+                    for keys in streams
+                ))
+
+            dims = [] if k is None else [f"{k}of{k_max}"]
+            dims += [str(1 if n is None else n) for n in slot]
+            fn = sharding_lib.sharded_jit(
+                chain, label=f"{family}[{'x'.join(dims)}]"
+            )
             fns[sig] = fn
-        out = fn(self._rng)
-        self._rng = out[0]
-        return out[1], (out[2] if refresh else None)
+        self._rng, *stacks = fn(self._rng)
+        return stacks
+
+    # ray-tpu: hot-path
+    def _superstep_host_keys(self, k, k_max, refresh, td_rng):
+        """The superstep's key schedule: per update the learn split,
+        then (under ``refresh``) the priority pass's key, a split of
+        its own where that pass consumes one (``td_rng``) and a zero
+        key where it does not. Returns the ``(k_max, 2)`` learn stack
+        and the priority stack (``None`` without ``refresh``)."""
+        slot = (None, None if td_rng else 0) if refresh else (None,)
+        stacks = self._split_chain("learn_keys", slot, k, k_max)
+        return stacks[0], (stacks[1] if refresh else None)
 
     # ray-tpu: hot-path
     def _rollout_host_keys(self, k, k_max, T):
-        """Fused host key schedule for the rollout superstep: per slot,
-        T rollout splits then the learn split — k*(T+1) sequential
-        splits as ONE dispatch. The T-loop runs as a lax.scan of the
-        same split, which composes the identical threefry chain, so
-        the stacks are bit-identical to the sequential host loop."""
-        fns = self.__dict__.setdefault("_split_chain_fns", {})
-        sig = ("rollout", k, k_max, T)
-        fn = fns.get(sig)
-        if fn is None:
-
-            def chain(rng):
-                def one_split(rng, _):
-                    rng, r = jax.random.split(rng)
-                    return rng, r
-
-                learn_keys, ro_keys = [], []
-                for _ in range(k):
-                    rng, slot = jax.lax.scan(
-                        one_split, rng, None, length=T
-                    )
-                    ro_keys.append(slot)
-                    rng, r = jax.random.split(rng)
-                    learn_keys.append(r)
-                pad = jnp.zeros_like(learn_keys[0])
-                pad_slot = jnp.zeros_like(ro_keys[0])
-                learn_keys += [pad] * (k_max - k)
-                ro_keys += [pad_slot] * (k_max - k)
-                return rng, jnp.stack(learn_keys), jnp.stack(ro_keys)
-
-            fn = jax.jit(chain)
-            fns[sig] = fn
-        rng, rngs, ro_rngs = fn(self._rng)
-        self._rng = rng
+        """The rollout superstep's key schedule: per slot, T rollout
+        splits then the learn split, k*(T+1) sequential splits.
+        Returns the ``(k_max, 2)`` learn stack and the
+        ``(k_max, T, 2)`` rollout stack."""
+        ro_rngs, rngs = self._split_chain(
+            "rollout_learn_keys", (T, None), k, k_max
+        )
         return rngs, ro_rngs
+
+    # ray-tpu: hot-path
+    def _rollout_keys(self, T):
+        """The standalone rollout's key schedule: T rollout splits
+        (the actor lane's one split per env step) and no learn split.
+        Returns the ``(T, 2)`` stack."""
+        return self._split_chain("rollout_keys", (T,))[0]
 
     def learn_superstep(
         self,

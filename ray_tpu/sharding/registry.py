@@ -45,7 +45,7 @@ class ProgramSpec:
     (optionally) how to warm it ahead of first dispatch."""
 
     label: str
-    kind: str = "other"  # learn | superstep | rollout | replay | tree | serve | grads | stack | other
+    kind: str = "other"  # learn | superstep | rollout | replay | tree | serve | grads | stack | keys | other
     policy_id: str = ""
     regex: bool = False
     warm: Optional[Callable[[], Any]] = None
@@ -265,6 +265,15 @@ def for_policy(
         ProgramSpec(
             rf"apply_grads\[{cls}\]",
             kind="grads",
+            policy_id=policy_id,
+            regex=True,
+        ),
+        # a lane's host key schedule (JaxPolicy._split_chain): slots,
+        # then the splits of each stream of a slot
+        ProgramSpec(
+            rf"(?:rollout_learn|rollout|learn)_keys"
+            rf"\[(?:{_NUM}of{_NUM}x)?{_NUM}(?:x{_NUM})*\]",
+            kind="keys",
             policy_id=policy_id,
             regex=True,
         ),
