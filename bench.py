@@ -821,120 +821,6 @@ def bench_torch(b=B, mb=MB, iters=ITERS) -> float:
     return b / dt
 
 
-def bench_sharding_ab(
-    b=1024, mb=256, iters=2, rounds=20, out_path=None
-):
-    """Mesh-vs-pmap sharding-backend A/B on the SAME fixed-seed PPO
-    learn step (ISSUE 2): median per-step latency, compile time, and
-    recompile counts per backend, plus a bitwise parity check of the
-    resulting params. Small MLP geometry — the A/B isolates the
-    *backend* cost (placement, dispatch, donation), not model compute.
-    Writes one JSON to ``benchmarks/sharding_ab.json``."""
-    import gymnasium as gym
-    import jax
-
-    from ray_tpu import sharding as sl
-    from ray_tpu.algorithms.ppo.ppo import PPOJaxPolicy
-    from ray_tpu.data.sample_batch import SampleBatch
-    from ray_tpu.parallel import mesh as legacy
-
-    rng = np.random.default_rng(0)
-    cols = {
-        SampleBatch.OBS: rng.standard_normal((b, 16)).astype(
-            np.float32
-        ),
-        SampleBatch.ACTIONS: rng.integers(0, 6, b).astype(np.int64),
-        SampleBatch.ACTION_LOGP: np.full(b, -1.79, np.float32),
-        SampleBatch.ACTION_DIST_INPUTS: rng.standard_normal(
-            (b, 6)
-        ).astype(np.float32),
-        SampleBatch.ADVANTAGES: rng.standard_normal(b).astype(
-            np.float32
-        ),
-        SampleBatch.VALUE_TARGETS: rng.standard_normal(b).astype(
-            np.float32
-        ),
-    }
-    report = {
-        "metric": "sharding_backend_ab_learn_step",
-        "devices": len(jax.devices()),
-        "config": {
-            "train_batch": b,
-            "minibatch": mb,
-            "num_sgd_iter": iters,
-        },
-        "backends": {},
-    }
-    weights = {}
-    for backend in ("mesh", "pmap"):
-        mesh = (
-            sl.get_mesh()
-            if backend == "mesh"
-            else legacy.make_mesh()
-        )
-        policy = PPOJaxPolicy(
-            gym.spaces.Box(-10.0, 10.0, (16,), np.float32),
-            gym.spaces.Discrete(6),
-            {
-                "_mesh": mesh,
-                "sharding_backend": backend,
-                "model": {"fcnet_hiddens": [64, 64]},
-                "train_batch_size": b,
-                "sgd_minibatch_size": mb,
-                "num_sgd_iter": iters,
-                "lr": 1e-4,
-                "seed": 0,
-            },
-        )
-        policy.learn_on_batch(SampleBatch(cols))  # compile
-        times = []
-        for _ in range(rounds):
-            t0 = time.perf_counter()
-            policy.learn_on_batch(SampleBatch(cols))
-            times.append(time.perf_counter() - t0)
-        fn = policy.learn_fn(b)
-        report["backends"][backend] = {
-            "step_ms_median": round(
-                1e3 * float(np.median(times)), 3
-            ),
-            "step_ms_p90": round(
-                1e3 * float(np.quantile(times, 0.9)), 3
-            ),
-            "compile_s": round(
-                getattr(fn, "compile_time_s", 0.0), 3
-            ),
-            "recompiles": getattr(fn, "recompiles", None),
-            "transfer_s_last": round(
-                policy.last_learn_timers.get(
-                    "learn_transfer_s", 0.0
-                ),
-                5,
-            ),
-        }
-        weights[backend] = jax.device_get(policy.params)
-    import jax.tree_util as jtu
-
-    report["parity_bitwise"] = all(
-        np.array_equal(x, y)
-        for x, y in zip(
-            jtu.tree_leaves(weights["mesh"]),
-            jtu.tree_leaves(weights["pmap"]),
-        )
-    )
-    m = report["backends"]["mesh"]["step_ms_median"]
-    p = report["backends"]["pmap"]["step_ms_median"]
-    report["mesh_vs_pmap"] = round(p / m, 3) if m else None
-    if out_path is None:
-        import os
-
-        os.makedirs("benchmarks", exist_ok=True)
-        out_path = "benchmarks/sharding_ab.json"
-    with open(out_path, "w") as f:
-        json.dump(report, f, indent=1)
-    print(json.dumps(report))
-    return report
-
-
 def bench_telemetry_overhead(b=1024, mb=256, iters=2, rounds=20):
     """Disabled-vs-enabled tracing A/B on the SAME fixed-seed PPO
     learn step (small MLP geometry — isolates per-call instrumentation
@@ -4249,432 +4135,14 @@ def bench_lint(out_path=None, reps=2):
     return report
 
 
-def _make_mlp_policy(b, mb, iters=1, obs_dim=8, acts=4, hiddens=(32, 32)):
-    """A micro MLP PPO policy: the dispatch benches want per-call HOST
-    cost, so the device program should be as small as a real fused
-    learner's is large."""
-    import gymnasium as gym
-
-    from ray_tpu.algorithms.ppo.ppo import PPOJaxPolicy
-
-    return PPOJaxPolicy(
-        gym.spaces.Box(-1, 1, (obs_dim,), np.float32),
-        gym.spaces.Discrete(acts),
-        {
-            "train_batch_size": b,
-            "sgd_minibatch_size": mb,
-            "num_sgd_iter": iters,
-            "lr": 5e-5,
-            "model": {"fcnet_hiddens": list(hiddens)},
-        },
-    )
-
-
-def _mlp_batch(rng, b, obs_dim=8, acts=4):
-    return {
-        "obs": rng.standard_normal((b, obs_dim)).astype(np.float32),
-        "actions": rng.integers(0, acts, b).astype(np.int64),
-        "action_logp": np.full(b, -1.38, np.float32),
-        "action_dist_inputs": rng.standard_normal((b, acts)).astype(
-            np.float32
-        ),
-        "advantages": rng.standard_normal(b).astype(np.float32),
-        "value_targets": rng.standard_normal(b).astype(np.float32),
-    }
-
-
-def bench_dispatch(out_path=None, b=64, kmax=8, rounds=5, n=30):
-    """Per-dispatch HOST overhead microharness (the pjit-suite method
-    over ``sharded_jit``): the same compiled superstep executable is
-    dispatched with the diet on vs off (``sharding.set_dispatch_diet``
-    — off IS the parent-commit host path), so any wall difference is
-    host-side by construction. Device compute inside the scan is
-    estimated from the K∈{2, kmax} wall scaling (the per-update
-    in-scan marginal — per-dispatch host work cancels in the
-    difference), and ``overhead = wall − kmax·per_update`` on both
-    sides. Also reports the trivial-program per-call cost (cached
-    1-element add — the raw ``ShardedFunction.__call__`` bookkeeping
-    shave) and the ``specs.sharding_tree`` memo hit vs full
-    re-derivation. Writes ``benchmarks/e2e/dispatch_diet.json``."""
-    import os
-
-    import jax
-
-    from ray_tpu import sharding as sharding_lib
-    from ray_tpu.sharding import specs as specs_lib
-
-    os.makedirs("benchmarks/e2e", exist_ok=True)
-    out_path = out_path or "benchmarks/e2e/dispatch_diet.json"
-    rng = np.random.default_rng(0)
-
-    p = _make_mlp_policy(b, b)
-    host, bsize = p.prepare_batch(_mlp_batch(rng, b))
-
-    def feed(k):
-        stacked = {
-            cn: np.repeat(np.asarray(v)[None], k, axis=0)
-            for cn, v in host.items()
-        }
-        shard = {
-            cn: sharding_lib.batch_sharded(p.mesh, ndim_prefix=2)
-            for cn in stacked
-        }
-        d = jax.device_put(stacked, shard)
-        jax.block_until_ready(d)
-        return d
-
-    feeds = {k: feed(k) for k in (2, kmax)}
-    prev = sharding_lib.set_dispatch_diet(True)
-    try:
-        for k, f in feeds.items():
-            p.learn_superstep(k, bsize, stacked=dict(f), k_max=k)
-        sharding_lib.set_dispatch_diet(False)
-        p.learn_superstep(
-            kmax, bsize, stacked=dict(feeds[kmax]), k_max=kmax
-        )
-
-        def wall(k, diet):
-            sharding_lib.set_dispatch_diet(diet)
-            best = float("inf")
-            for _ in range(rounds):
-                t0 = time.perf_counter()
-                for _ in range(n):
-                    p.learn_superstep(
-                        k, bsize, stacked=dict(feeds[k]), k_max=k
-                    )
-                best = min(best, (time.perf_counter() - t0) / n)
-            return best
-
-        w2_on = wall(2, True)
-        wk_on = wall(kmax, True)
-        wk_off = wall(kmax, False)
-        # per-update in-scan device compute: host per-dispatch work is
-        # K-independent, so it cancels in the K difference
-        pu = (wk_on - w2_on) / (kmax - 2)
-        oh_on = max(wk_on - kmax * pu, 1e-7)
-        oh_off = max(wk_off - kmax * pu, 1e-7)
-
-        # trivial-program per-call cost: raw __call__ bookkeeping
-        x = jax.device_put(
-            np.ones((8, 8), np.float32),
-            sharding_lib.replicated(p.mesh),
-        )
-        tfn = sharding_lib.sharded_jit(
-            lambda a: a + 1.0, label="dispatch_micro"
-        )
-        tfn(x)
-
-        def call_us(diet, nn=3000):
-            sharding_lib.set_dispatch_diet(diet)
-            best = float("inf")
-            for _ in range(rounds):
-                t0 = time.perf_counter()
-                for _ in range(nn):
-                    tfn(x)
-                best = min(best, (time.perf_counter() - t0) / nn)
-            return best * 1e6
-
-        call_on = call_us(True)
-        call_off = call_us(False)
-    finally:
-        sharding_lib.set_dispatch_diet(prev)
-
-    # sharding_tree: signature-memo hit vs full re-derivation
-    mesh = p.mesh
-    tree = {cn: np.asarray(v) for cn, v in host.items()}
-    specs_lib.sharding_tree(tree, mesh)
-
-    def tree_us(clear, nn=2000):
-        best = float("inf")
-        for _ in range(rounds):
-            t0 = time.perf_counter()
-            for _ in range(nn):
-                if clear:
-                    specs_lib.clear_sharding_caches()
-                specs_lib.sharding_tree(dict(tree), mesh)
-            best = min(best, (time.perf_counter() - t0) / nn)
-        return best * 1e6
-
-    tree_memo = tree_us(False)
-    tree_full = tree_us(True)
-
-    report = {
-        "metric": "dispatch_diet_ab",
-        "config": {
-            "train_batch": b,
-            "kmax": kmax,
-            "rounds": rounds,
-            "calls_per_round": n,
-            "device": jax.devices()[0].device_kind,
-        },
-        "superstep_k8": {
-            "wall_us_diet_on": round(wk_on * 1e6, 1),
-            "wall_us_diet_off": round(wk_off * 1e6, 1),
-            "per_update_in_scan_us": round(pu * 1e6, 1),
-            "host_overhead_us_diet_on": round(oh_on * 1e6, 1),
-            "host_overhead_us_diet_off": round(oh_off * 1e6, 1),
-            "overhead_reduction": round(oh_off / oh_on, 1),
-        },
-        "trivial_call": {
-            "us_diet_on": round(call_on, 2),
-            "us_diet_off": round(call_off, 2),
-        },
-        "sharding_tree": {
-            "us_memo_hit": round(tree_memo, 2),
-            "us_full_derivation": round(tree_full, 2),
-        },
-        "note": (
-            "diet-off restores the parent host path on the SAME "
-            "compiled executables, so wall deltas are host-side by "
-            "construction. The K=8 overhead reduction is the "
-            "acceptance number: the fused key-schedule chain (one "
-            "program for the k split dispatches), cached sharding "
-            "trees, and the two-clock __call__ fast path together "
-            "must at least halve per-dispatch host work"
-        ),
-    }
-    with open(out_path, "w") as f:
-        json.dump(report, f, indent=1)
-    print(json.dumps(report))
-    return report
-
-
-def bench_pallas_kernels(out_path=None):
-    """Per-kernel Pallas-vs-XLA A/B + parity for the PR's hot-op
-    kernels (ops/framestack.py gather/scatter, ops/gae.py fragment
-    scan, ops/segment_tree.py prefix descent). Mosaic refuses all four
-    on a TPU (jax 0.9.0, v5e — each module quotes its refusal beside
-    ``_COMPILES_ON_TPU``), so ``use_pallas=None`` never selects them:
-    they run here through the Pallas interpreter for PARITY on any
-    backend, the XLA walls are recorded as the reference, and
-    ``engaged: false`` carries the why-not.
-    Writes ``benchmarks/e2e/pallas_kernels.json``."""
-    import os
-
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu import sharding as sharding_lib
-    from ray_tpu.ops import framestack as fs
-    from ray_tpu.ops import gae as gae_lib
-    from ray_tpu.ops import segment_tree as st
-
-    os.makedirs("benchmarks/e2e", exist_ok=True)
-    out_path = out_path or "benchmarks/e2e/pallas_kernels.json"
-    rng = np.random.default_rng(0)
-    refused = (
-        "Mosaic refuses this kernel on a TPU (refusal quoted beside "
-        "_COMPILES_ON_TPU in its module): auto resolves to XLA, "
-        "interpreter-mode parity measured"
-    )
-
-    def timed(fn, *args, n=20):
-        out = fn(*args)
-        jax.block_until_ready(out)
-        t0 = time.perf_counter()
-        for _ in range(n):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        return out, (time.perf_counter() - t0) / n
-
-    kernels = []
-
-    # 1. frame-pool gather (uint32-lane row copy)
-    frames = jnp.asarray(
-        rng.integers(0, 256, (2048, 16, 16, 1), dtype=np.uint8)
-    )
-    idx = jnp.asarray(rng.integers(0, 2044, 512), jnp.int32)
-    xla = jax.jit(lambda f, i: fs.build_stacks(f, i, 4))
-    pal = jax.jit(
-        lambda f, i: fs.build_stacks(
-            f, i, 4, use_pallas=True, interpret=True
-        )
-    )
-    a, t_x = timed(xla, frames, idx)
-    b_, t_p = timed(pal, frames, idx)
-    engaged = fs._COMPILES_ON_TPU
-    kernels.append(
-        {
-            "kernel": "framestack_gather_rows",
-            "engaged": bool(engaged),
-            "reason": None if engaged else refused,
-            "xla_wall_us": round(t_x * 1e6, 1),
-            "pallas_wall_us": round(t_p * 1e6, 1),
-            "pallas_mode": "tpu" if engaged else "interpret",
-            "speedup": round(t_x / t_p, 2) if engaged else None,
-            "parity": {
-                "contract": "bitwise",
-                "max_abs_diff": int(
-                    np.max(
-                        np.abs(
-                            np.asarray(a, np.int32)
-                            - np.asarray(b_, np.int32)
-                        )
-                    )
-                ),
-            },
-        }
-    )
-
-    # 2. replay ring scatter (insert lane, aliased ring)
-    ring = jnp.asarray(
-        rng.integers(0, 2**32, (4096, 64), dtype=np.uint32)
-    )
-    pos = jnp.asarray(rng.integers(0, 4096, 256), jnp.int32)
-    vals = jnp.asarray(
-        rng.integers(0, 2**32, (256, 64), dtype=np.uint32)
-    )
-    xla = jax.jit(lambda r, p_, v: r.at[p_].set(v))
-    pal = jax.jit(
-        lambda r, p_, v: fs.scatter_rows(
-            r, p_, v, use_pallas=True, interpret=True
-        )
-    )
-    a, t_x = timed(xla, ring, pos, vals)
-    b_, t_p = timed(pal, ring, pos, vals)
-    engaged = fs._COMPILES_ON_TPU
-    kernels.append(
-        {
-            "kernel": "replay_scatter_rows",
-            "engaged": bool(engaged),
-            "reason": None if engaged else refused,
-            "xla_wall_us": round(t_x * 1e6, 1),
-            "pallas_wall_us": round(t_p * 1e6, 1),
-            "pallas_mode": "tpu" if engaged else "interpret",
-            "speedup": round(t_x / t_p, 2) if engaged else None,
-            "parity": {
-                "contract": "bitwise",
-                "max_abs_diff": int(
-                    np.max(
-                        np.abs(
-                            np.asarray(a, np.int64)
-                            - np.asarray(b_, np.int64)
-                        )
-                    )
-                ),
-            },
-        }
-    )
-
-    # 3. GAE fragment scan (sequential kernel vs associative_scan)
-    B_, T_ = 64, 128
-    r_ = jnp.asarray(rng.standard_normal((B_, T_)).astype(np.float32))
-    v_ = jnp.asarray(rng.standard_normal((B_, T_)).astype(np.float32))
-    nv = jnp.asarray(rng.standard_normal((B_, T_)).astype(np.float32))
-    term = jnp.asarray(rng.random((B_, T_)) < 0.02)
-    done = term | jnp.asarray(rng.random((B_, T_)) < 0.02)
-    xla = jax.jit(
-        lambda *x: gae_lib.compute_gae_fragment(*x, use_pallas=False)
-    )
-    pal = jax.jit(
-        lambda *x: gae_lib.compute_gae_fragment(
-            *x, use_pallas=True, interpret=True
-        )
-    )
-    (a, _), t_x = timed(xla, r_, v_, nv, term, done)
-    (b2, _), t_p = timed(pal, r_, v_, nv, term, done)
-    engaged = gae_lib._COMPILES_ON_TPU
-    gae_diff = float(jnp.max(jnp.abs(a - b2)))
-    kernels.append(
-        {
-            "kernel": "gae_fragment_scan",
-            "engaged": bool(engaged),
-            "reason": None if engaged else refused,
-            "xla_wall_us": round(t_x * 1e6, 1),
-            "pallas_wall_us": round(t_p * 1e6, 1),
-            "pallas_mode": "tpu" if engaged else "interpret",
-            "speedup": round(t_x / t_p, 2) if engaged else None,
-            "parity": {
-                "contract": "float32 tolerance 1e-4 (sequential "
-                "recurrence vs associative-scan reassociation)",
-                "max_abs_diff": gae_diff,
-            },
-        }
-    )
-
-    # 4. sum-tree prefix descent (f64)
-    cap = 4096
-    host = st.SumSegmentTree(cap)
-    leaf = rng.random(cap) + 0.01
-    host.set_items(np.arange(cap), leaf)
-    with sharding_lib.f64_scope():
-        value = jnp.asarray(host.value, jnp.float64)
-        pfx = jnp.asarray(
-            rng.random(256) * host.sum(0, cap), jnp.float64
-        )
-        xla = jax.jit(
-            lambda v_, p_: st.find_prefixsum_body(v_, p_, cap)
-        )
-        pal = jax.jit(
-            lambda v_, p_: st.find_prefixsum_pallas(
-                v_, p_, cap, interpret=True
-            )
-        )
-        a, t_x = timed(xla, value, pfx)
-        b2, t_p = timed(pal, value, pfx)
-        engaged = False  # Mosaic has no f64: interpreter-only
-    kernels.append(
-        {
-            "kernel": "sumtree_prefix_descent",
-            "engaged": bool(engaged),
-            "reason": None
-            if engaged
-            else (
-                "f64 tree (the bit-exactness contract): Mosaic has "
-                "no f64 vectors, so auto never selects this kernel; "
-                "interpreter-mode parity measured — the kernel is "
-                "the template for f64-capable backends"
-            ),
-            "xla_wall_us": round(t_x * 1e6, 1),
-            "pallas_wall_us": round(t_p * 1e6, 1),
-            "pallas_mode": "tpu" if engaged else "interpret",
-            "speedup": round(t_x / t_p, 2) if engaged else None,
-            "parity": {
-                "contract": "bitwise (identical f64 op sequence)",
-                "max_abs_diff": int(
-                    np.max(
-                        np.abs(np.asarray(a) - np.asarray(b2))
-                    )
-                ),
-            },
-        }
-    )
-
-    report = {
-        "metric": "pallas_kernel_ab",
-        "device": jax.devices()[0].device_kind,
-        "backend": jax.default_backend(),
-        "kernels": kernels,
-        "note": (
-            "interpreter walls measure the reference semantics, not "
-            "performance; use_pallas=None resolves by "
-            "ops/_pallas.kernel_selected: the kernel on a TPU only "
-            "where it compiles there, the XLA reference otherwise"
-        ),
-    }
-    with open(out_path, "w") as f:
-        json.dump(report, f, indent=1)
-    print(json.dumps(report))
-    return report
-
-
 def main():
     if "--lint" in sys.argv:
         bench_lint()
-        return
-    if "--dispatch" in sys.argv:
-        bench_dispatch()
-        return
-    if "--pallas" in sys.argv:
-        bench_pallas_kernels()
         return
     if "--e2e" in sys.argv:
         from bench_e2e import main as e2e_main
 
         e2e_main()
-        return
-    if "--sharding-ab" in sys.argv:
-        bench_sharding_ab()
         return
     if "--replay-ab" in sys.argv:
         bench_replay_ab()

@@ -380,25 +380,6 @@ def fused_lane(meter, devices) -> dict:
     return with_walls(out, t0)
 
 
-def kernel_report() -> dict:
-    """Which implementation each Pallas-backed op of the two programs
-    runs here — read off the rule the ops themselves use
-    (``ops/_pallas.kernel_selected``), not off a caught exception."""
-    from ray_tpu.ops import framestack, gae
-    from ray_tpu.ops._pallas import kernel_selected
-
-    def impl(mod):
-        chosen = kernel_selected(
-            None, False, compiles_on_tpu=mod._COMPILES_ON_TPU
-        )
-        return "pallas" if chosen else "xla"
-
-    return {
-        "framestack_row_gather": impl(framestack),
-        "gae_fragment_scan": impl(gae),
-    }
-
-
 def main() -> int:
     t0 = time.perf_counter()
     import jax
@@ -436,7 +417,6 @@ def main() -> int:
             else 0
         ),
     )
-    say("kernels", **kernel_report())
     say("actor_lane", **actor_lane(meter, devices))
     say("fused_lane", **fused_lane(meter, devices))
     say("total", wall_s=round(time.perf_counter() - t0, 2))

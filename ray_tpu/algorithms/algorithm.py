@@ -21,7 +21,6 @@ from ray_tpu.data.sample_batch import DEFAULT_POLICY_ID
 from ray_tpu.env.registry import get_env_creator
 from ray_tpu.evaluation.metrics import summarize_episodes
 from ray_tpu.evaluation.worker_set import WorkerSet
-from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.tune.trainable import Trainable
 
 NUM_ENV_STEPS_SAMPLED = "num_env_steps_sampled"
@@ -102,10 +101,8 @@ class Algorithm(Trainable):
         dist_lib.initialize()
         ensure_compile_cache()
 
-        # learner mesh (driver-side policies): built through the
-        # backend the config selects — the sharding runtime's
-        # ("batch",) mesh by default, the legacy ("data",) mesh for
-        # the pmap fallback (docs/sharding.md)
+        # learner mesh (driver-side policies): the sharding runtime's
+        # ("batch",) mesh (docs/sharding.md)
         n_learner = config.get("learner_devices")
         import jax
 
@@ -126,32 +123,22 @@ class Algorithm(Trainable):
                     "participate; shrink the fleet by host instead"
                 )
             devices = devices[:n_learner]
-        if config.get("sharding_backend", "mesh") == "pmap":
-            if hosts > 1:
-                raise ValueError(
-                    "sharding(hosts=N) requires the 'mesh' backend — "
-                    "the pmap path is single-process only"
-                )
-            config["_mesh"] = mesh_lib.make_mesh(devices=devices)
-        else:
-            # model_parallel (docs/sharding.md): a 2-D (data x model)
-            # mesh — params of rule-declaring models split across M
-            # shards instead of replicating on every device
-            mp = sharding_lib.resolve_model_parallel(
-                config, devices, strict=True
+        # model_parallel (docs/sharding.md): a 2-D (data x model)
+        # mesh — params of rule-declaring models split across M
+        # shards instead of replicating on every device
+        mp = sharding_lib.resolve_model_parallel(
+            config, devices, strict=True
+        )
+        if mp:
+            config["_mesh"] = sharding_lib.get_mesh(
+                devices=devices,
+                axis_shapes=[
+                    ("batch", len(devices) // mp),
+                    ("model", mp),
+                ],
             )
-            if mp:
-                config["_mesh"] = sharding_lib.get_mesh(
-                    devices=devices,
-                    axis_shapes=[
-                        ("batch", len(devices) // mp),
-                        ("model", mp),
-                    ],
-                )
-            else:
-                config["_mesh"] = sharding_lib.get_mesh(
-                    devices=devices
-                )
+        else:
+            config["_mesh"] = sharding_lib.get_mesh(devices=devices)
 
         policy_specs = None
         policy_mapping_fn = config.get("policy_mapping_fn")

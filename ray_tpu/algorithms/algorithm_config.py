@@ -189,12 +189,6 @@ class AlgorithmConfig:
 
         # learner placement (TPU-specific)
         self.learner_devices = None  # None → all visible devices
-        # learner sharding runtime (docs/sharding.md): "mesh" lowers
-        # the learn program through ray_tpu.sharding's sharded_jit with
-        # explicit NamedShardings on a ("batch",) mesh; "pmap" keeps
-        # the legacy ("data",)-mesh path with implicit placement.
-        # Fixed-seed results are bit-identical between the two.
-        self.sharding_backend = "mesh"
         # tensor parallelism (docs/sharding.md "2-D mesh & param
         # partitioning"): None (default) keeps the 1-D data mesh; an
         # int M (or "auto") builds the 2-D [("batch", D//M),
@@ -415,36 +409,29 @@ class AlgorithmConfig:
         num_gpus: Optional[int] = None,
         num_cpus_per_worker: Optional[int] = None,
         learner_devices: Optional[int] = None,
-        sharding_backend: Optional[str] = None,
         **kwargs,
     ) -> "AlgorithmConfig":
+        from ray_tpu import sharding as sharding_lib
+
+        sharding_lib.refuse_removed_options(kwargs)
         if num_gpus is not None:
             self.num_gpus = num_gpus
         if num_cpus_per_worker is not None:
             self.num_cpus_per_worker = num_cpus_per_worker
         if learner_devices is not None:
             self.learner_devices = learner_devices
-        if sharding_backend is not None:
-            if sharding_backend not in ("mesh", "pmap"):
-                raise ValueError(
-                    "sharding_backend must be 'mesh' or 'pmap', got "
-                    f"{sharding_backend!r}"
-                )
-            self.sharding_backend = sharding_backend
         return self
 
     def sharding(
         self,
         *,
-        sharding_backend: Optional[str] = None,
         model_parallel=None,
         hosts=None,
         aot_cache_dir: Optional[str] = None,
         **kwargs,
     ) -> "AlgorithmConfig":
         """Learner-plane placement (docs/sharding.md).
-        ``sharding_backend``: "mesh" (default) | "pmap" — same knob as
-        :meth:`resources`. ``model_parallel``: "auto" | int M — build
+        ``model_parallel``: "auto" | int M — build
         the 2-D (data x model) mesh and partition params per the
         model's rules; see the attribute comment in ``__init__``.
         ``hosts``: "auto" | int N — span the learner mesh over the N
@@ -452,6 +439,9 @@ class AlgorithmConfig:
         fleet, docs/fleet.md). ``aot_cache_dir``: fleet-shared AOT
         executable cache the learn program warms through (zero fresh
         compiles for elastic joiners on a warm cache)."""
+        from ray_tpu import sharding as sharding_lib
+
+        sharding_lib.refuse_removed_options(kwargs)
         if aot_cache_dir is not None:
             self.aot_cache_dir = str(aot_cache_dir)
         if hosts is not None:
@@ -464,13 +454,6 @@ class AlgorithmConfig:
                     )
                 hosts = h
             self.hosts = hosts
-        if sharding_backend is not None:
-            if sharding_backend not in ("mesh", "pmap"):
-                raise ValueError(
-                    "sharding_backend must be 'mesh' or 'pmap', got "
-                    f"{sharding_backend!r}"
-                )
-            self.sharding_backend = sharding_backend
         if model_parallel is not None:
             if model_parallel != "auto":
                 m = int(model_parallel)

@@ -128,14 +128,14 @@ class AlphaStar(Algorithm):
         # fewer devices than trainables → everyone shares the full mesh
         import jax
 
-        from ray_tpu.parallel import mesh as mesh_lib
+        from ray_tpu import sharding as sharding_lib
 
         devices = list(jax.devices())
         per = len(devices) // len(trainable)
         submeshes = {}
         if per >= 1 and len(trainable) > 1 and len(devices) > 1:
             for i, pid in enumerate(trainable):
-                submeshes[pid] = mesh_lib.make_mesh(
+                submeshes[pid] = sharding_lib.get_mesh(
                     devices=devices[i * per : (i + 1) * per]
                 )
         self._learner_submeshes = submeshes

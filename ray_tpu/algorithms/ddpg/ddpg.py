@@ -178,7 +178,6 @@ class DDPGJaxPolicy(JaxPolicy):
         self.low = float(np.min(action_space.low))
         self.high = float(np.max(action_space.high))
 
-        self.sharding_backend = config.get("sharding_backend", "mesh")
         self.mesh = sharding_lib.resolve_mesh(config)
         self.n_shards = sharding_lib.num_shards(self.mesh)
         self._param_sharding = sharding_lib.replicated(self.mesh)
@@ -456,7 +455,6 @@ class DDPGJaxPolicy(JaxPolicy):
     def supports_superstep(self) -> bool:
         return (
             not self._superstep_opt_out
-            and self.sharding_backend == "mesh"
             and type(self)._build_learn_fn
             is DDPGJaxPolicy._build_learn_fn
         )

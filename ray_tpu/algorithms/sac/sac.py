@@ -159,7 +159,6 @@ class SACJaxPolicy(JaxPolicy):
         self.low = float(np.min(action_space.low))
         self.high = float(np.max(action_space.high))
 
-        self.sharding_backend = config.get("sharding_backend", "mesh")
         self.mesh = sharding_lib.resolve_mesh(config)
         self.n_shards = sharding_lib.num_shards(self.mesh)
         self._param_sharding = sharding_lib.replicated(self.mesh)
@@ -494,7 +493,6 @@ class SACJaxPolicy(JaxPolicy):
         opt-outs (RNNSAC's sequence state handling) are excluded."""
         return (
             not self._superstep_opt_out
-            and self.sharding_backend == "mesh"
             and type(self)._build_learn_fn is SACJaxPolicy._build_learn_fn
         )
 
@@ -559,19 +557,14 @@ class SACJaxPolicy(JaxPolicy):
             in_specs=(P(), P(), P(), P(None, axis), P(), P()),
             out_specs=(P(), P(), P(), P(), P()),
         )
-        label = f"multi_learn[{type(self).__name__}:{batch_size}x{k}]"
-        if self.sharding_backend == "mesh":
-            rep = self._param_sharding
-            dat = sharding_lib.batch_sharded(self.mesh, ndim_prefix=2)
-            return sharding_lib.sharded_jit(
-                sharded,
-                in_specs=(rep, rep, rep, dat, rep, rep),
-                out_specs=(rep, rep, rep, rep, rep),
-                donate_argnums=(1,),
-                label=label,
-            )
+        rep = self._param_sharding
+        dat = sharding_lib.batch_sharded(self.mesh, ndim_prefix=2)
         return sharding_lib.sharded_jit(
-            sharded, donate_argnums=(1,), label=label
+            sharded,
+            in_specs=(rep, rep, rep, dat, rep, rep),
+            out_specs=(rep, rep, rep, rep, rep),
+            donate_argnums=(1,),
+            label=f"multi_learn[{type(self).__name__}:{batch_size}x{k}]",
         )
 
     def learn_on_stacked_batch(

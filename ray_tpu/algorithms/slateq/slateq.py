@@ -247,7 +247,6 @@ class SlateQJaxPolicy(JaxPolicy):
             np.int32,
         )  # (A, S)
 
-        self.sharding_backend = config.get("sharding_backend", "mesh")
         self.mesh = sharding_lib.resolve_mesh(config)
         self.n_shards = sharding_lib.num_shards(self.mesh)
         self._param_sharding = sharding_lib.replicated(self.mesh)
@@ -503,19 +502,13 @@ class SlateQJaxPolicy(JaxPolicy):
             in_specs=(P(), P(), P(), P(axis), P(), P()),
             out_specs=(P(), P(), P()),
         )
-        label = f"learn[{type(self).__name__}:{batch_size}]"
-        if self.sharding_backend == "mesh":
-            rep = self._param_sharding
-            dat = self._data_sharding
-            return sharding_lib.sharded_jit(
-                sharded,
-                in_specs=(rep, rep, rep, dat, rep, rep),
-                out_specs=(rep, rep, rep),
-                donate_argnums=(1,),
-                label=label,
-            )
+        rep = self._param_sharding
         return sharding_lib.sharded_jit(
-            sharded, donate_argnums=(1,), label=label
+            sharded,
+            in_specs=(rep, rep, rep, self._data_sharding, rep, rep),
+            out_specs=(rep, rep, rep),
+            donate_argnums=(1,),
+            label=f"learn[{type(self).__name__}:{batch_size}]",
         )
 
     def _refold_exploration_config(self, new_config):

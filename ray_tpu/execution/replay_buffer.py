@@ -420,7 +420,7 @@ def _unpack_rows(g, row_shape: tuple):
     return u8.reshape(g.shape[:-1] + tuple(row_shape))
 
 
-def _gather_columns(store, idx, meta, use_pallas=None, interpret=False):
+def _gather_columns(store, idx, meta):
     """Rows ``idx`` (any int shape) of every ring in ``store`` as
     logical columns — the one gather body of ``gather``/``sample``,
     the fused tree sample and the superstep's ring feed."""
@@ -432,9 +432,7 @@ def _gather_columns(store, idx, meta, use_pallas=None, interpret=False):
     with jax.named_scope("replay/gather"):
         for k, ring in store.items():
             row_shape, _, packed = meta[k]
-            g = framestack_lib.gather_rows(
-                ring, idx, use_pallas=use_pallas, interpret=interpret
-            )
+            g = framestack_lib.gather_rows(ring, idx)
             out[k] = _unpack_rows(g, row_shape) if packed else g
     return out
 
@@ -487,8 +485,6 @@ class DeviceReplayBuffer:
         mesh=None,
         memory_cap_bytes: Optional[int] = None,
         label: str = "default_policy",
-        use_pallas=None,
-        pallas_interpret: bool = False,
     ):
         from ray_tpu import sharding as sharding_lib
 
@@ -497,13 +493,6 @@ class DeviceReplayBuffer:
         self.mesh = mesh if mesh is not None else sharding_lib.get_mesh()
         self.memory_cap_bytes = memory_cap_bytes
         self.label = label
-        # handed to ops/framestack.py's row gather/scatter as-is: None
-        # = auto, which is the XLA path (Mosaic refuses the row-copy
-        # kernels — see _COMPILES_ON_TPU there) unless
-        # pallas_interpret asks for the interpreter; a forced True is
-        # honored (tests) — bitwise-identical data movement either way.
-        self.use_pallas = use_pallas
-        self.pallas_interpret = bool(pallas_interpret)
         self._store: Dict[str, Any] = {}  # name -> device ring array
         # name -> (row_shape, dtype, packed_as_uint32)
         self._meta: Dict[str, tuple] = {}
@@ -651,8 +640,6 @@ class DeviceReplayBuffer:
         from ray_tpu.ops import framestack as framestack_lib
 
         meta = dict(self._meta)
-        up = self.use_pallas
-        interp = self.pallas_interpret
 
         @jax.named_scope("replay/insert")
         def fn(store, rows, pos):
@@ -661,9 +648,7 @@ class DeviceReplayBuffer:
                 _, _, packed = meta[k]
                 if packed:
                     v = _pack_rows(v, store[k].shape[1])
-                out[k] = framestack_lib.scatter_rows(
-                    store[k], pos, v, use_pallas=up, interpret=interp
-                )
+                out[k] = framestack_lib.scatter_rows(store[k], pos, v)
             return out
 
         return sharding_lib.sharded_jit(
@@ -675,12 +660,7 @@ class DeviceReplayBuffer:
     def _gather_fn(self):
         """``(store, idx) -> logical columns`` over this buffer's
         current column set (holds the meta, not the buffer)."""
-        return functools.partial(
-            _gather_columns,
-            meta=dict(self._meta),
-            use_pallas=self.use_pallas,
-            interpret=self.pallas_interpret,
-        )
+        return functools.partial(_gather_columns, meta=dict(self._meta))
 
     def _build_sample_fn(self, row_sharded: bool):
         from ray_tpu import sharding as sharding_lib

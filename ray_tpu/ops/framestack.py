@@ -31,143 +31,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-from ray_tpu.ops._pallas import kernel_selected
-
 # Batch columns of the deduplicated format.
 FRAMES = "obs_frames"
 FRAME_IDX = "obs_frame_idx"
 
 
-# -- Pallas row gather/scatter (docs/data_plane.md "Pallas kernels") ---
-#
-# The replay sample path, the superstep ring feed and the framestack
-# rebuild are all the same access pattern: gather R rows of a (M, D)
-# uint32-lane store (uint8 pixels ride packed 4-wide — see
-# build_stacks). XLA lowers that to a general gather HLO; the Pallas
-# kernel is a scalar-prefetch row copy — the index vector rides SMEM
-# ahead of the grid, each grid step DMAs exactly one store row
-# HBM→VMEM→HBM. Pure data movement at uint32 lane width, so outputs
-# are BITWISE identical to the XLA path (the uint8 unpack around the
-# kernel is a bitcast — a layout view, not a copy).
-#
-# Mosaic (jax 0.9.0, TPU v5e) refuses the one-row block for every row
-# width: "The Pallas TPU lowering currently requires that the last two
-# dimensions of your block shape are divisible by 8 and 128
-# respectively, or be equal to the respective dimensions of the
-# overall array. Block spec for args[1] in pallas_call _row_copy_kernel
-# ... has block shape (Blocked(block_size=1), Blocked(block_size=1764)),
-# array shape (2096, 1764)". So ``use_pallas=None`` (auto) resolves to
-# the XLA gather/scatter on every backend; the kernels run only when
-# forced (``use_pallas=True`` raises that message on a TPU) or through
-# the interpreter (``interpret=True``, the CPU parity tests).
-_COMPILES_ON_TPU = False
-
-
-def _row_copy_kernel(idx_ref, src_ref, out_ref):
-    # index plumbing lives entirely in the BlockSpec index_maps; the
-    # body is the DMA'd row copy
-    out_ref[...] = src_ref[...]
-
-
-def _row_scatter_kernel(idx_ref, vals_ref, ring_ref, out_ref):
-    # ring_ref is the aliased initial output (read untouched); the
-    # body overwrites just the block the out index_map routed here
-    del ring_ref
-    out_ref[...] = vals_ref[...]
-
-
-def _pallas_rows(src2, flat_idx, out_rows, scatter, interpret):
-    """Shared pallas_call for row gather/scatter on a (M, D) array.
-    Gather: out[i] = src2[idx[i]]; scatter: out starts as the aliased
-    ring and out[idx[i]] = src2[i]."""
-    r = flat_idx.shape[0]
-    d = src2.shape[1]
-    if scatter:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(r,),
-            in_specs=[
-                pl.BlockSpec((1, d), lambda i, idx_ref: (i, 0)),
-                # the aliased ring: route its block to the same row
-                # the output writes so the alias is block-consistent
-                pl.BlockSpec(
-                    (1, d), lambda i, idx_ref: (idx_ref[i], 0)
-                ),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, d), lambda i, idx_ref: (idx_ref[i], 0)
-            ),
-        )
-        # operand indices for aliasing count past the scalar-prefetch
-        # operand: 0=idx, 1=vals, 2=ring → output 0. Rows no grid step
-        # writes keep the ring's contents (the circular-buffer
-        # contract).
-        return pl.pallas_call(
-            _row_scatter_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((out_rows, d), src2.dtype),
-            input_output_aliases={2: 0},
-            interpret=interpret,
-        )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(r,),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda i, idx_ref: (idx_ref[i], 0))
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda i, idx_ref: (i, 0)),
-    )
-    return pl.pallas_call(
-        _row_copy_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((out_rows, d), src2.dtype),
-        interpret=interpret,
-    )
-
-
-def gather_rows(src, idx, *, use_pallas=None, interpret=False):
+# One access pattern (replay sample, superstep ring feed, framestack
+# rebuild): R rows of a (M, D) uint32-lane store. Mosaic (jax 0.9.0, v5e)
+# refuses a one-row Pallas block ("last two dimensions of your block
+# shape ... divisible by 8 and 128"): XLA's gather / scatter it is (PR 21).
+def gather_rows(src, idx):
     """``src[idx]`` over the leading axis — the replay/framestack row
-    gather, optionally through the Pallas row-copy kernel. ``src``:
-    (M, ...) any dtype; ``idx``: any int shape. Bitwise identical on
-    every path (pure data movement)."""
-    idx = jnp.asarray(idx)
-    inner = src.shape[1:]
-    d = int(np.prod(inner)) if inner else 1
-    if not kernel_selected(
-        use_pallas, interpret, compiles_on_tpu=_COMPILES_ON_TPU
-    ):
-        return src[idx]
-    flat_idx = idx.reshape(-1).astype(jnp.int32)
-    src2 = src.reshape(src.shape[0], d)
-    out2 = _pallas_rows(
-        src2, flat_idx, flat_idx.shape[0], False, interpret
-    )(flat_idx, src2)
-    return out2.reshape(idx.shape + inner)
+    gather. ``src``: (M, ...) any dtype; ``idx``: any int shape."""
+    return src[jnp.asarray(idx)]
 
 
-def scatter_rows(ring, pos, vals, *, use_pallas=None, interpret=False):
+def scatter_rows(ring, pos, vals):
     """``ring.at[pos].set(vals)`` over the leading axis — the replay
-    insert's circular scatter, optionally through the Pallas row-copy
-    kernel (ring aliased through, so unwritten rows keep their
-    contents). ``pos``: (R,) int; ``vals``: (R, ...) matching ring's
-    row shape. Bitwise identical on every path."""
-    pos = jnp.asarray(pos)
-    inner = ring.shape[1:]
-    d = int(np.prod(inner)) if inner else 1
-    r = int(pos.shape[0])
-    if not kernel_selected(
-        use_pallas, interpret, compiles_on_tpu=_COMPILES_ON_TPU
-    ):
-        return ring.at[pos].set(vals)
-    ring2 = ring.reshape(ring.shape[0], d)
-    vals2 = vals.reshape(r, d)
-    out2 = _pallas_rows(
-        vals2, pos.astype(jnp.int32), ring.shape[0], True, interpret
-    )(pos.astype(jnp.int32), vals2, ring2)
-    return out2.reshape(ring.shape)
+    insert's circular scatter (unwritten rows keep their contents).
+    ``pos``: (R,) int; ``vals``: (R, ...) matching ring's row shape."""
+    return ring.at[jnp.asarray(pos)].set(vals)
 
 
 def frame_stream_columns(
@@ -366,14 +249,7 @@ def materialize_fragment(batch_cols: Dict, k: int) -> Dict:
     return cols
 
 
-def build_stacks(
-    frames: jnp.ndarray,
-    idx: jnp.ndarray,
-    k: int,
-    *,
-    use_pallas=None,
-    interpret=False,
-):
+def build_stacks(frames: jnp.ndarray, idx: jnp.ndarray, k: int):
     """Device-side: (M, H, W, 1) frame pool + (N,) first-frame indices
     → (N, H, W, k) stacked observations (one gather, XLA-fusable).
 
@@ -382,35 +258,22 @@ def build_stacks(
     for uint8 vs ~420 GB/s through uint32 lanes on v5e, measured for
     the minibatch row gather — MFU.md), and the pool gather is the same
     access pattern at 4× fewer, 4× wider elements. Pure data movement:
-    the reconstructed stacks are byte-identical. ``use_pallas`` routes
-    the gather through the scalar-prefetch row-copy kernel
-    (:func:`gather_rows`) with the uint32 unpack fused around it — the
-    surrounding bitcasts are layout views, so the Pallas path stays
-    bitwise identical too."""
+    the reconstructed stacks are byte-identical."""
     assert frames.shape[-1] == 1, (
         "frame pools are single-channel (stack depth k comes from the "
         f"index expansion); got channel dim {frames.shape[-1]} — "
         "multi-channel frames would silently train on one channel"
     )
+    rows = idx[:, None] + jnp.arange(k)[None, :]
     inner = int(np.prod(frames.shape[1:]))
     if frames.dtype == jnp.uint8 and inner % 4 == 0:
         packed = jax.lax.bitcast_convert_type(
             frames.reshape(frames.shape[0], inner // 4, 4), jnp.uint32
         )
-        gathered = gather_rows(
-            packed,
-            idx[:, None] + jnp.arange(k)[None, :],
-            use_pallas=use_pallas,
-            interpret=interpret,
+        u8 = jax.lax.bitcast_convert_type(
+            gather_rows(packed, rows), jnp.uint8
         )
-        u8 = jax.lax.bitcast_convert_type(gathered, jnp.uint8)
         u8 = u8.reshape((u8.shape[0], k) + frames.shape[1:])
         return jnp.moveaxis(u8[..., 0], 1, -1)
-    gathered = gather_rows(
-        frames,
-        idx[:, None] + jnp.arange(k)[None, :],
-        use_pallas=use_pallas,
-        interpret=interpret,
-    )
     # (N, k, H, W, 1) → (N, H, W, k)
-    return jnp.moveaxis(gathered[..., 0], 1, -1)
+    return jnp.moveaxis(gather_rows(frames, rows)[..., 0], 1, -1)

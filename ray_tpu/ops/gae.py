@@ -10,15 +10,9 @@ rollout workers; here the fast path is a jit-compiled ``lax.scan`` over fixed
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from jax.experimental import pallas as pl
-
-from ray_tpu.ops._pallas import kernel_selected
 
 
 def discount_cumsum_np(x: np.ndarray, gamma: float) -> np.ndarray:
@@ -125,58 +119,6 @@ def compute_gae(
     return adv, value_targets
 
 
-# Mosaic (jax 0.9.0, TPU v5e) refuses the kernel's dynamic width-1
-# slice of the lane (time) axis: "Mosaic failed to compile TPU kernel:
-# cannot statically prove that index in dimension 1 is a multiple of
-# 128 ... vector.load ... memref<8x128xf32, #tpu.memory_space<vmem>>
-# -> vector<8x1xf32>". So ``use_pallas=None`` (auto) resolves to the
-# associative scan on every backend; the kernel runs only when forced
-# or through the interpreter.
-_COMPILES_ON_TPU = False
-
-
-def _gae_scan_kernel(deltas_ref, coeffs_ref, adv_ref, *, t):
-    """Reverse first-order recurrence over the time axis for one row
-    block: adv[t] = delta[t] + coeff[t] * adv[t+1]. Sequential in T
-    (the mathematically exact order — no reassociation), vectorized
-    over the row block."""
-    # ray-tpu: device-fn
-    rows = adv_ref.shape[0]
-
-    def body(i, run):
-        col = t - 1 - i
-        d = deltas_ref[:, pl.ds(col, 1)]
-        c = coeffs_ref[:, pl.ds(col, 1)]
-        run = d + c * run
-        adv_ref[:, pl.ds(col, 1)] = run
-        return run
-
-    jax.lax.fori_loop(
-        0, t, body, jnp.zeros((rows, 1), jnp.float32)
-    )
-
-
-def _gae_scan_pallas(deltas, coeffs, interpret):
-    b, t = deltas.shape
-    bq = min(b, 8) if b % 8 else 8
-    pad = (-b) % bq
-    if pad:
-        deltas = jnp.pad(deltas, ((0, pad), (0, 0)))
-        coeffs = jnp.pad(coeffs, ((0, pad), (0, 0)))
-    out = pl.pallas_call(
-        functools.partial(_gae_scan_kernel, t=t),
-        grid=((b + pad) // bq,),
-        in_specs=[
-            pl.BlockSpec((bq, t), lambda i: (i, 0)),
-            pl.BlockSpec((bq, t), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bq, t), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b + pad, t), jnp.float32),
-        interpret=interpret,
-    )(deltas, coeffs)
-    return out[:b] if pad else out
-
-
 def compute_gae_fragment(
     rewards: jnp.ndarray,
     values: jnp.ndarray,
@@ -185,8 +127,6 @@ def compute_gae_fragment(
     dones: jnp.ndarray,
     gamma: float = 0.99,
     lambda_: float = 1.0,
-    use_pallas=None,
-    interpret: bool = False,
 ):
     """GAE over (B, T) fragments with the HOST lane's truncation
     semantics (``evaluation/postprocessing.py``): bootstrap 0 across a
@@ -211,14 +151,9 @@ def compute_gae_fragment(
 
     Returns (advantages, value_targets), both (B, T) float32.
 
-    ``use_pallas`` (True/False forces; None = auto, which is the
-    associative scan — see ``_COMPILES_ON_TPU``) routes the reverse
-    recurrence through the Pallas fragment-scan kernel: sequential in
-    T per row block — the mathematically exact evaluation order — vs
-    the associative scan's log-depth reassociation, so the two paths
-    agree to float32 tolerance (~1e-5 rel), not bitwise; see
-    docs/data_plane.md. ``interpret=True`` runs the kernel through the
-    Pallas interpreter (the CPU parity path)."""
+    The reverse recurrence is the associative scan (log-depth,
+    reassociated: within 1e-4 of the sequential order, the contract of
+    docs/data_plane.md)."""
     rewards = rewards.astype(jnp.float32)
     values = values.astype(jnp.float32)
     next_values = next_values.astype(jnp.float32)
@@ -228,12 +163,8 @@ def compute_gae_fragment(
     deltas = rewards + gamma * next_values * not_term - values
     coeffs = gamma * lambda_ * not_done
 
-    if kernel_selected(
-        use_pallas, interpret, compiles_on_tpu=_COMPILES_ON_TPU
-    ):
-        adv = _gae_scan_pallas(deltas, coeffs, interpret)
-        return adv, adv + values
-
+    # Mosaic (jax 0.9.0, TPU v5e) refuses a sequential Pallas scan here: a
+    # dynamic width-1 slice of the lane (time) axis does not lower (PR 21).
     def combine(a, b):
         ca, va = a
         cb, vb = b

@@ -11,8 +11,6 @@ from __future__ import annotations
 
 import numpy as np
 
-from ray_tpu.ops._pallas import kernel_selected
-
 
 class SegmentTree:
     def __init__(self, capacity: int, operation, neutral_element: float):
@@ -146,7 +144,9 @@ def reduce_range_body(value, size, op, neutral, capacity: int):
 def find_prefixsum_body(value, prefixsum, capacity: int):
     """In-program ``SumSegmentTree.find_prefixsum_idx``: the lockstep
     root→leaf descent, one comparison + exact f64 subtraction per
-    level."""
+    level. XLA only: the tree is f64 (the determinism contract above)
+    and Mosaic has no f64 vectors, so no Pallas descent compiles on a
+    TPU (PR 21)."""
     import jax.numpy as jnp
 
     p = prefixsum
@@ -160,57 +160,6 @@ def find_prefixsum_body(value, prefixsum, capacity: int):
     return idx - capacity
 
 
-# -- Pallas prefix descent (docs/data_plane.md "Pallas kernels") -------
-#
-# The root→leaf descent as one Pallas kernel: the whole tree rides
-# VMEM-resident and each level is a vectorized gather + exact f64
-# compare/subtract — the identical op sequence to
-# ``find_prefixsum_body``, so draws stay bit-exact vs the host trees.
-# The tree is f64 (the determinism contract above) and Mosaic has no
-# f64 vectors, so the kernel can never compile on a TPU: it is
-# interpreter-only, ``use_pallas=None`` (auto) always resolves to the
-# XLA body, and the kernel exists as the parity-tested template for
-# backends that grow f64 VMEM support.
-
-
-# ray-tpu: device-fn f64
-def _descent_kernel(value_ref, p_ref, out_ref, *, levels, capacity):
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    p = p_ref[...]
-    idx = jnp.ones(p.shape, jnp.int32)
-    for _ in range(levels):
-        left = 2 * idx
-        left_vals = value_ref[left]
-        go_right = p > left_vals
-        p = jnp.where(go_right, p - left_vals, p)
-        idx = jnp.where(go_right, left + 1, left)
-    out_ref[...] = idx - capacity
-
-
-def find_prefixsum_pallas(value, prefixsum, capacity: int, *, interpret=False):
-    """Pallas counterpart of :func:`find_prefixsum_body`; returns int64
-    leaf indices, bit-exact vs the XLA body (same compares, same exact
-    f64 subtractions)."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    out = pl.pallas_call(
-        functools.partial(
-            _descent_kernel,
-            levels=capacity.bit_length() - 1,
-            capacity=capacity,
-        ),
-        out_shape=jax.ShapeDtypeStruct(prefixsum.shape, jnp.int32),
-        interpret=interpret,
-    )(value, prefixsum)
-    return out.astype(jnp.int64)
-
-
 # ray-tpu: device-fn f64
 def draw_body(
     sum_value,
@@ -219,8 +168,6 @@ def draw_body(
     size,
     beta,
     capacity: int,
-    use_pallas: bool = False,
-    interpret: bool = False,
 ):
     """The whole stratified proportional draw of
     ``_PrioritySampling._draw_prioritized`` as one in-program body:
@@ -239,12 +186,7 @@ def draw_body(
         )
         strata = jnp.arange(num_items, dtype=jnp.float64)
         mass = (rand + strata) / num_items * total
-        if use_pallas:
-            idx = find_prefixsum_pallas(
-                sum_value, mass, capacity, interpret=interpret
-            )
-        else:
-            idx = find_prefixsum_body(sum_value, mass, capacity)
+        idx = find_prefixsum_body(sum_value, mass, capacity)
         idx = jnp.clip(idx, 0, size - 1)
 
         p_min = (
@@ -295,8 +237,6 @@ class DeviceSumTree:
         capacity: int,
         mesh=None,
         label: str = "default_policy",
-        use_pallas=None,
-        pallas_interpret: bool = False,
     ):
         assert capacity > 0 and capacity & (capacity - 1) == 0, (
             "capacity must be a positive power of 2"
@@ -309,11 +249,6 @@ class DeviceSumTree:
         self.capacity = int(capacity)
         self.mesh = mesh if mesh is not None else sharding_lib.get_mesh()
         self.label = label
-        # None = auto: the XLA body, unless the interpreter was asked
-        # for — the f64 kernel cannot compile on a TPU, see the module
-        # comment above find_prefixsum_pallas
-        self.use_pallas = use_pallas
-        self.pallas_interpret = bool(pallas_interpret)
         self._update_fns = {}
         self._draw_fns = {}
         with sharding_lib.f64_scope():
@@ -436,24 +371,11 @@ class DeviceSumTree:
             import jax.numpy as jnp
 
             cap = self.capacity
-            interp = self.pallas_interpret
-            # Mosaic has no f64: the descent kernel never compiles on
-            # a TPU, so auto is the XLA body (ops/_pallas.py)
-            pallas = kernel_selected(
-                self.use_pallas, interp, compiles_on_tpu=False
-            )
 
             # ray-tpu: f64
             def prog(sum_t, min_t, r, size_, beta_):
                 idx, weights, _ = draw_body(
-                    sum_t,
-                    min_t,
-                    r,
-                    size_,
-                    beta_,
-                    cap,
-                    use_pallas=pallas,
-                    interpret=interp,
+                    sum_t, min_t, r, size_, beta_, cap
                 )
                 return idx.astype(jnp.int32), weights
 

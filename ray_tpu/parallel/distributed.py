@@ -110,9 +110,9 @@ def global_mesh():
     drift between the two paths."""
     import jax
 
-    from ray_tpu.parallel.mesh import make_mesh
+    from ray_tpu import sharding as sharding_lib
 
-    return make_mesh(devices=jax.devices())
+    return sharding_lib.get_mesh(devices=jax.devices())
 
 
 def broadcast_weights(tree, is_source: Optional[bool] = None):

@@ -13,8 +13,7 @@ with a single jitted update:
     XLA program via ``jax.shard_map`` over the learner mesh, lowered
     through the ``ray_tpu.sharding`` runtime (``sharded_jit`` with
     replicated-param / row-sharded-batch NamedShardings and opt-state
-    donation when ``config.sharding_backend == "mesh"``, the default;
-    ``"pmap"`` keeps legacy implicit placement);
+    donation);
   - no loader threads, no per-device towers, no CPU gradient averaging;
   - schedule-driven scalars (lr, entropy coeff, kl coeff) enter as traced
     scalar args so schedules never trigger recompilation.
@@ -84,10 +83,6 @@ class JaxPolicy(Policy):
     # bypass JaxPolicy.__init__ (SAC/DDPG families) stay feedforward.
     _unroll_T: int = 1
 
-    # Backend default for policies that bypass __init__ (their own
-    # constructors overwrite it from config via resolve_mesh).
-    sharding_backend: str = "mesh"
-
     # Per-leaf param placement (docs/sharding.md "2-D mesh & param
     # partitioning"). Class defaults = the replicated legacy contract,
     # so bespoke-net policies that bypass __init__ (SAC/DDPG families)
@@ -129,7 +124,6 @@ class JaxPolicy(Policy):
         )
 
         # ---- mesh / shardings (ray_tpu.sharding runtime) ----
-        self.sharding_backend = config.get("sharding_backend", "mesh")
         self.mesh = sharding_lib.resolve_mesh(config)
         self.n_shards = sharding_lib.num_shards(self.mesh)
         self._param_sharding = sharding_lib.replicated(self.mesh)
@@ -378,8 +372,6 @@ class JaxPolicy(Policy):
         """Derive per-leaf param specs from the model's rules when the
         mesh has a model axis (docs/sharding.md). Runs on the HOST
         param tree right after model.init, before device placement."""
-        if self.sharding_backend != "mesh":
-            return
         if sharding_lib.model_axis(self.mesh) is None:
             return
         rules = self._model_partition_rules()
@@ -459,6 +451,22 @@ class JaxPolicy(Policy):
         if with_frames and isinstance(a_ps, dict):
             a_ps = {"__frames__": P(), **a_ps}
         return p_ps, o_ps, a_ps
+
+    def _carry_shardings(self, a_ps):
+        """The learn programs' explicit placement: NamedShardings of
+        (params, opt_state, aux) per their spec trees (all replicated
+        unless partitioned; ``a_ps`` is the aux tree of
+        :meth:`_carry_pspecs`), then the replicated sharding that rng
+        and coeffs take — jit broadcasts one sharding over each
+        argument's pytree leaves."""
+        rep = sharding_lib.replicated(self.mesh)
+        p_sh = self._param_sharding
+        a_sh = (
+            sharding_lib.named_tree(self.mesh, a_ps)
+            if self._param_pspecs is not None
+            else rep
+        )
+        return p_sh, self._opt_sharding or p_sh, a_sh, rep
 
     def _publish_params_bytes(self) -> None:
         """``ray_tpu_params_bytes{policy,placement}``: global tree
@@ -566,15 +574,13 @@ class JaxPolicy(Policy):
     def supports_jax_rollout(self) -> bool:
         """Whether this policy's act path can lower into the device
         rollout lane's scanned program (``execution/jax_rollout.py``):
-        stateless exploration, mesh backend (the rollout program
-        carries explicit shardings), and a model whose only inputs are
+        stateless exploration and a model whose only inputs are
         the observation and its own per-stream state (the lane carries
         that state with the env's; a model fed the previous action or
         reward, and stateful exploration such as OU noise or
         ParameterNoise, stay on the actor lane)."""
         return (
-            self.sharding_backend == "mesh"
-            and not getattr(self.model, "use_prev_action", False)
+            not getattr(self.model, "use_prev_action", False)
             and not getattr(self.model, "use_prev_reward", False)
             and not self.exploration.needs_last_obs
             and self.exploration.initial_state(1) == ()
@@ -742,10 +748,7 @@ class JaxPolicy(Policy):
         num_iters = self.num_sgd_iter
         tx = self._tx
         mesh = self.mesh
-        # data axis name comes from the mesh: "batch" on the sharding
-        # runtime's meshes, "data" on legacy/pmap ones — the program
-        # must not hard-code either
-        axis = sharding_lib.data_axis(mesh)
+        axis = sharding_lib.BATCH_AXIS
         loss_fn = self.loss_with_aux
 
         rebuild_obs = self._rebuild_obs_from_frames
@@ -905,7 +908,7 @@ class JaxPolicy(Policy):
             batch_size, with_frames=with_frames
         )
         mesh = self.mesh
-        axis = sharding_lib.data_axis(mesh)
+        axis = sharding_lib.BATCH_AXIS
         # per-leaf carry specs: bare P() (replicated) on the legacy
         # path, the rule-derived trees when partitioned — the body
         # then sees LOCAL param slices and the model inserts its own
@@ -921,32 +924,13 @@ class JaxPolicy(Policy):
         # Donate only opt_state: params buffers must stay valid because an
         # async sampler thread may be running compute_actions with them
         # concurrently (IMPALA sync mode shares the policy object).
-        label = f"learn[{type(self).__name__}:{batch_size}]"
-        if self.sharding_backend == "mesh":
-            # explicit placement: params/opt/aux per their spec trees
-            # (all-replicated on the legacy path), rng/coeffs
-            # replicated, batch row-sharded — jit broadcasts one
-            # sharding over each argument's pytree leaves, and the
-            # compile layer tracks retraces (compile-cache stats)
-            rep = sharding_lib.replicated(mesh)
-            p_sh = self._param_sharding
-            o_sh = self._opt_sharding or p_sh
-            a_sh = (
-                sharding_lib.named_tree(mesh, a_ps)
-                if self._param_pspecs is not None
-                else rep
-            )
-            dat = self._data_sharding
-            return sharding_lib.sharded_jit(
-                sharded,
-                in_specs=(p_sh, o_sh, a_sh, dat, rep, rep),
-                out_specs=(p_sh, o_sh, rep),
-                donate_argnums=(1,),
-                label=label,
-            )
-        # pmap-era fallback: placement left to device_put, same program
+        p_sh, o_sh, a_sh, rep = self._carry_shardings(a_ps)
         return sharding_lib.sharded_jit(
-            sharded, donate_argnums=(1,), label=label
+            sharded,
+            in_specs=(p_sh, o_sh, a_sh, self._data_sharding, rep, rep),
+            out_specs=(p_sh, o_sh, rep),
+            donate_argnums=(1,),
+            label=f"learn[{type(self).__name__}:{batch_size}]",
         )
 
     # -- superstep: K updates per dispatch (docs/data_plane.md) ----------
@@ -965,11 +949,9 @@ class JaxPolicy(Policy):
         policy that replaced :meth:`_build_learn_fn` wholesale
         (AlphaZero, QMIX, MADDPG, SlateQ) must chain per-call. The
         actor-critic families override this with their own identity
-        checks. Requires the mesh backend (the scan program carries
-        explicit shardings)."""
+        checks."""
         return (
             not self._superstep_opt_out
-            and self.sharding_backend == "mesh"
             and type(self)._build_learn_fn is JaxPolicy._build_learn_fn
             and type(self)._nest_device_fn is JaxPolicy._nest_device_fn
             and type(self)._device_update_fn
@@ -1015,7 +997,7 @@ class JaxPolicy(Policy):
         body — the one per-call learn-program shape the actor-critic
         family (SAC/DDPG/CQL/CRR) shares."""
         mesh = self.mesh
-        axis = sharding_lib.data_axis(mesh)
+        axis = sharding_lib.BATCH_AXIS
         p_ps, o_ps, a_ps = self._carry_pspecs()
         bp, bo, ba = sharding_lib.manual_pspecs(mesh, (p_ps, o_ps, a_ps))
         sharded = jax.shard_map(
@@ -1024,26 +1006,13 @@ class JaxPolicy(Policy):
             in_specs=(bp, bo, ba, P(axis), P(), P()),
             out_specs=(bp, bo, ba, P()),
         )
-        label = f"learn[{type(self).__name__}:{batch_size}]"
-        if self.sharding_backend == "mesh":
-            rep = sharding_lib.replicated(mesh)
-            p_sh = self._param_sharding
-            o_sh = self._opt_sharding or p_sh
-            a_sh = (
-                sharding_lib.named_tree(mesh, a_ps)
-                if self._param_pspecs is not None
-                else rep
-            )
-            dat = self._data_sharding
-            return sharding_lib.sharded_jit(
-                sharded,
-                in_specs=(p_sh, o_sh, a_sh, dat, rep, rep),
-                out_specs=(p_sh, o_sh, a_sh, rep),
-                donate_argnums=(1,),
-                label=label,
-            )
+        p_sh, o_sh, a_sh, rep = self._carry_shardings(a_ps)
         return sharding_lib.sharded_jit(
-            sharded, donate_argnums=(1,), label=label
+            sharded,
+            in_specs=(p_sh, o_sh, a_sh, self._data_sharding, rep, rep),
+            out_specs=(p_sh, o_sh, a_sh, rep),
+            donate_argnums=(1,),
+            label=f"learn[{type(self).__name__}:{batch_size}]",
         )
 
     def _learn_coeffs(self):
@@ -1085,8 +1054,7 @@ class JaxPolicy(Policy):
     def _active_mask(self, k: int, k_max: int) -> np.ndarray:
         """The (k_max,) float32 active mask for a k-of-k_max superstep,
         cached per (k, k_max): the mask is read-only on the device side
-        so the same host array serves every dispatch (one less per-call
-        allocation on the dieted path)."""
+        so the same host array serves every dispatch."""
         masks = self.__dict__.setdefault("_active_masks", {})
         m = masks.get((k, k_max))
         if m is None:
@@ -1183,6 +1151,148 @@ class JaxPolicy(Policy):
         Returns the ``(T, 2)`` stack."""
         return self._split_chain("rollout_keys", (T,))[0]
 
+    # ray-tpu: hot-path
+    def _drive_superstep(
+        self,
+        k,
+        k_max,
+        batch_size,
+        *,
+        family,
+        cache_key,
+        program,
+        key_schedule,
+        feed,
+        carried=False,
+        drained=None,
+        **span_attrs,
+    ):
+        """The host side of a fused lane, once for all of them: check
+        ``k`` against ``k_max``, fetch or build the program, span
+        ``learn:keys`` around the coefficients and the key schedule,
+        ONE dispatch under ``learn:superstep``, ONE readback under
+        ``learn:drain``, then the host's share of the chain (counters,
+        timers, the per-update stat dicts).
+
+        A lane hands over what is its own: ``family`` and ``cache_key``
+        name its program, ``program()`` gives the
+        ``build_superstep_fn`` arguments of its feed (called on a cache
+        miss), ``key_schedule(k, k_max)`` its key stacks in argument
+        order, ``feed(keys, active)`` the feed argument after counting
+        what of it crosses H2D; ``carried`` says that the first output
+        after the learner state stays on the device (the env carry);
+        ``drained(span, *extra)`` finishes what rode the drain beside
+        the stats, inside the drain's span. ``span_attrs`` go on
+        ``learn:keys`` and ``learn:superstep``.
+
+        Returns ``(infos, skipped, carry, extra)``: per-update host
+        stat dicts and nan-guard skip flags, the carried output (None
+        without), and the list of drained outputs after the stats."""
+        import time as _time
+
+        from ray_tpu.sharding import superstep as superstep_lib
+
+        k = int(k)
+        k_max = int(k_max or k)
+        if not 1 <= k <= k_max:
+            raise ValueError(f"k={k} outside [1, k_max={k_max}]")
+        nan_guard = bool(self.config.get("nan_guard"))
+        cache_key = (*cache_key, batch_size, k_max, nan_guard)
+        fns = self.__dict__.setdefault("_superstep_fns", {})
+        fn = fns.get(cache_key)
+        if fn is None:
+            fn = fns[cache_key] = superstep_lib.build_superstep_fn(
+                mesh=self.mesh,
+                k=k_max,
+                label=(
+                    f"{family}[{type(self).__name__}:"
+                    f"{batch_size}x{k_max}]"
+                ),
+                nan_guard=nan_guard,
+                # per-leaf (params, opt, aux) placement threads
+                # through the scan carry + donation unchanged
+                carry_pspecs=(
+                    self._carry_pspecs()
+                    if self._param_pspecs is not None
+                    else None
+                ),
+                **program(),
+            )
+
+        with tracing.start_span("learn:keys", k=k, **span_attrs):
+            coeffs = self._learn_coeffs()
+            # the exact per-update host split order, as ONE program:
+            # the same threefry splits in the same order, so the key
+            # stacks and the advanced self._rng are those of the
+            # sequential host loop bit for bit (_split_chain)
+            keys = key_schedule(k, k_max)
+        active = self._active_mask(k, k_max)
+        feed_arg = feed(keys, active)
+
+        compiles_before = getattr(fn, "traces", 0)
+        t0 = _time.perf_counter()
+        with tracing.start_span(
+            "learn:superstep", k=k, batch_size=batch_size, **span_attrs
+        ) as _sp:
+            self.params, self.opt_state, self.aux_state, *tail = fn(
+                self.params,
+                self.opt_state,
+                self.aux_state,
+                feed_arg,
+                active,
+                *keys,
+                coeffs,
+            )
+            carry = tail.pop(0) if carried else None
+            _sp.set_attribute(
+                "recompiles",
+                getattr(fn, "traces", 0) - compiles_before,
+            )
+            # ONE drain for the whole chain: the stacked stats tree and
+            # whatever the lane stacked beside it (the PER priority
+            # matrix, the episode metrics) come back in a single
+            # device→host readback
+            with tracing.start_span("learn:drain") as _drain:
+                # ray-tpu: allow[RTA005] the ONE counted drain for the chain
+                stats, *extra = jax.device_get(tail)
+                if drained is not None:
+                    extra = drained(_drain, *extra)
+            # the drain proves the superstep program finished: close
+            # its device-busy interval in the ledger (timestamps only,
+            # no extra sync)
+            device_ledger.drain_point()
+            # the host's share of the chain, still inside the span: the
+            # counters and the per-update stat dicts
+            self.num_grad_updates += k * self._updates_per_learn_call(
+                batch_size
+            )
+            self._after_superstep()
+            telemetry_metrics.counter(
+                telemetry_metrics.LEARN_STEPS_TOTAL,
+                "SGD-nest programs dispatched",
+            ).inc(float(k))
+            telemetry_metrics.inc_superstep_updates(k)
+            self.last_learn_timers["learn_superstep_s"] = (
+                _time.perf_counter() - t0
+            )
+            self.last_learn_timers["learn_recompiles"] = float(
+                getattr(fn, "traces", 0) - compiles_before
+            )
+
+            skip = np.asarray(
+                stats.get(superstep_lib.SKIP_KEY, np.zeros(k_max))
+            )
+            skipped = [bool(skip[i] > 0.5) for i in range(k)]
+            infos = [
+                {
+                    name: float(np.asarray(v)[i])
+                    for name, v in stats.items()
+                    if name != superstep_lib.SKIP_KEY
+                }
+                for i in range(k)
+            ]
+            return infos, skipped, carry, extra
+
     def learn_superstep(
         self,
         k: int,
@@ -1223,18 +1333,10 @@ class JaxPolicy(Policy):
         dicts (update order), the ``(k, B)`` priority matrix (None
         unless refreshing), and the per-update nan-guard skip flags.
         """
-        import time as _time
-
         if (stacked is None) == (rings is None):
             raise ValueError(
                 "learn_superstep needs exactly one of stacked/rings"
             )
-        k = int(k)
-        k_max = int(k_max or k)
-        if not 1 <= k <= k_max:
-            raise ValueError(f"k={k} outside [1, k_max={k_max}]")
-        nan_guard = bool(self.config.get("nan_guard"))
-        with_frames = stacked is not None and _FRAMES in stacked
         pri_fn = (
             self._td_error_device_fn() if refresh_priorities else None
         )
@@ -1244,200 +1346,91 @@ class JaxPolicy(Policy):
                 "body; gate refresh_priorities on "
                 "policy._td_error_device_fn() is not None"
             )
-
-        from ray_tpu.sharding import superstep as superstep_lib
+        # the priority pass's key is a split of its own iff the
+        # per-update pass consumes one, a zero key otherwise
+        td_rng = refresh_priorities and self._td_refresh_uses_rng
 
         if rings is not None:
-            cache_mode = ("rings", rings.key, tuple(sorted(rings.extra)))
-        else:
-            cache_mode = ("stacked", tuple(sorted(stacked)))
-        cache_key = (
-            batch_size, k_max, cache_mode, refresh_priorities, nan_guard,
-        )
-        fns = self.__dict__.setdefault("_superstep_fns", {})
-        fn = fns.get(cache_key)
-        if fn is None:
-            kwargs = dict(
-                mesh=self.mesh,
-                backend=self.sharding_backend,
-                k=k_max,
-                label=(
-                    f"superstep[{type(self).__name__}:"
-                    f"{batch_size}x{k_max}]"
-                ),
-                priority_fn=pri_fn,
-                nan_guard=nan_guard,
-                # per-leaf (params, opt, aux) placement threads
-                # through the scan carry + donation unchanged
-                carry_pspecs=(
-                    self._carry_pspecs()
-                    if self._param_pspecs is not None
-                    else None
-                ),
-            )
-            if rings is not None:
-                kwargs.update(
+            extra_cols = tuple(sorted(rings.extra))
+            cache_mode = ("rings", rings.key, extra_cols)
+
+            def program():
+                return dict(
+                    update_fn=self._device_update_fn(batch_size),
                     gather_fn=rings.gather_fn,
                     store_shardings=rings.shardings,
-                    extra_cols=tuple(sorted(rings.extra)),
+                    extra_cols=extra_cols,
+                    priority_fn=pri_fn,
                 )
-            else:
-                kwargs.update(
-                    stacked_cols=tuple(sorted(stacked)),
-                    replicated_cols=(_FRAMES,) if with_frames else (),
-                )
-            fn = superstep_lib.build_superstep_fn(
-                self._device_update_fn(
-                    batch_size, with_frames=with_frames
-                ),
-                **kwargs,
-            )
-            fns[cache_key] = fn
 
-        with tracing.start_span("learn:keys", k=k):
-            coeffs = self._learn_coeffs()
-            # exact per-update host split order: learn split, then (iff the
-            # per-update priority pass consumes one) the td split. On the
-            # dieted path the whole chain runs as ONE fused program (k or
-            # 2k tiny split dispatches collapse to one — the dominant
-            # per-superstep host cost at K=8, bench.py --dispatch); the
-            # chain composes the same threefry splits in the same order,
-            # so the key stacks and the advanced self._rng are bit-
-            # identical to the sequential host loop.
-            td_rng = refresh_priorities and self._td_refresh_uses_rng
-            if sharding_lib.dispatch_diet_enabled():
-                rngs, pri = self._superstep_host_keys(
-                    k, k_max, refresh_priorities, td_rng
-                )
-                rest = (pri,) if refresh_priorities else ()
-            else:
-                keys, pri_keys = [], []
-                for _ in range(k):
-                    self._rng, r = jax.random.split(self._rng)
-                    keys.append(r)
-                    if refresh_priorities:
-                        if td_rng:
-                            self._rng, r2 = jax.random.split(self._rng)
-                        else:
-                            r2 = jnp.zeros_like(r)
-                        pri_keys.append(r2)
-                pad_key = jnp.zeros_like(keys[0])
-                while len(keys) < k_max:
-                    keys.append(pad_key)
-                rngs = jnp.stack(keys)
-                rest = ()
-                if refresh_priorities:
-                    while len(pri_keys) < k_max:
-                        pri_keys.append(pad_key)
-                    rest = (jnp.stack(pri_keys),)
-        active = self._active_mask(k, k_max)
-
-        if rings is not None:
-            feed = (rings.store, rings.idx, rings.extra)
-            # sample-path payload: the pre-drawn index matrix + stacked
-            # extra columns, counted only when they actually cross
-            # H2D — a device-tree draw hands device arrays here and
-            # the sample path ships zero payload bytes
-            telemetry_metrics.add_h2d_bytes(
-                "replay_sample",
-                sum(
-                    v.nbytes
-                    for v in (
-                        rings.idx,
-                        *rings.extra.values(),
-                    )
-                    if not isinstance(v, jax.Array)
-                ),
-            )
-        else:
-            feed = stacked
-            if not any(
-                isinstance(v, jax.Array) for v in stacked.values()
-            ):
+            def feed(keys, active):
+                # sample-path payload: the pre-drawn index matrix +
+                # stacked extra columns, counted only when they
+                # actually cross H2D — a device-tree draw hands device
+                # arrays here and the sample path ships zero payload
+                # bytes
                 telemetry_metrics.add_h2d_bytes(
-                    "learn", sharding_lib.tree_nbytes(stacked)
+                    "replay_sample",
+                    sum(
+                        v.nbytes
+                        for v in (rings.idx, *rings.extra.values())
+                        if not isinstance(v, jax.Array)
+                    ),
+                )
+                return rings.store, rings.idx, rings.extra
+
+        else:
+            cols = tuple(sorted(stacked))
+            cache_mode = ("stacked", cols)
+            with_frames = _FRAMES in stacked
+
+            def program():
+                return dict(
+                    update_fn=self._device_update_fn(
+                        batch_size, with_frames=with_frames
+                    ),
+                    stacked_cols=cols,
+                    replicated_cols=(_FRAMES,) if with_frames else (),
+                    priority_fn=pri_fn,
                 )
 
-        compiles_before = getattr(fn, "traces", 0)
-        t0 = _time.perf_counter()
-        with tracing.start_span(
-            "learn:superstep", k=k, batch_size=batch_size
-        ) as _sp:
-            out = fn(
-                self.params,
-                self.opt_state,
-                self.aux_state,
-                feed,
-                active,
-                rngs,
-                *rest,
-                coeffs,
-            )
-            if refresh_priorities:
-                (
-                    self.params, self.opt_state, self.aux_state,
-                    stats, pri,
-                ) = out
-            else:
-                self.params, self.opt_state, self.aux_state, stats = out
-                pri = None
-            _sp.set_attribute(
-                "recompiles",
-                getattr(fn, "traces", 0) - compiles_before,
-            )
-            # ONE drain for the whole chain: the stacked stats tree
-            # (and the PER priority matrix) come back in a single
-            # device→host readback
-            with tracing.start_span("learn:drain") as _drain:
-                if pri is not None:
-                    # ray-tpu: allow[RTA005] the ONE counted drain for the chain
-                    stats, pri = jax.device_get((stats, pri))
-                    pri = np.abs(np.asarray(pri)[:k])
-                    # the |td| pull that feeds the host alpha-power —
-                    # the PER path's one remaining D2H
-                    # (docs/data_plane.md)
-                    telemetry_metrics.add_d2h_bytes(
-                        "replay_priorities", pri.nbytes
+            def feed(keys, active):
+                if not any(
+                    isinstance(v, jax.Array) for v in stacked.values()
+                ):
+                    telemetry_metrics.add_h2d_bytes(
+                        "learn", sharding_lib.tree_nbytes(stacked)
                     )
-                    _drain.set_attribute("bytes", pri.nbytes)
-                else:
-                    # ray-tpu: allow[RTA005] the ONE counted drain for the chain
-                    stats = jax.device_get(stats)
-            # the drain proves the superstep program finished: close
-            # its device-busy interval in the ledger (timestamps only,
-            # no extra sync)
-            device_ledger.drain_point()
-            # the host's share of the chain, still inside the span: the
-            # counters and the per-update stat dicts
-            self.num_grad_updates += k * self._updates_per_learn_call(
-                batch_size
-            )
-            self._after_superstep()
-            telemetry_metrics.counter(
-                telemetry_metrics.LEARN_STEPS_TOTAL,
-                "SGD-nest programs dispatched",
-            ).inc(float(k))
-            telemetry_metrics.inc_superstep_updates(k)
-            self.last_learn_timers["learn_superstep_s"] = (
-                _time.perf_counter() - t0
-            )
-            self.last_learn_timers["learn_recompiles"] = float(
-                getattr(fn, "traces", 0) - compiles_before
-            )
+                return stacked
 
-            skip = np.asarray(
-                stats.get(superstep_lib.SKIP_KEY, np.zeros(k_max))
+        def key_schedule(k, k_max):
+            rngs, pri_rngs = self._superstep_host_keys(
+                k, k_max, refresh_priorities, td_rng
             )
-            skipped = [bool(skip[i] > 0.5) for i in range(k)]
-            infos = [
-                {
-                    name: float(np.asarray(v)[i])
-                    for name, v in stats.items()
-                    if name != superstep_lib.SKIP_KEY
-                }
-                for i in range(k)
-            ]
-            return infos, pri, skipped
+            return (rngs, pri_rngs) if refresh_priorities else (rngs,)
+
+        def drained(span, pri):
+            pri = np.abs(np.asarray(pri)[: int(k)])
+            # the |td| pull that feeds the host alpha-power — the PER
+            # path's one remaining D2H (docs/data_plane.md)
+            telemetry_metrics.add_d2h_bytes(
+                "replay_priorities", pri.nbytes
+            )
+            span.set_attribute("bytes", pri.nbytes)
+            return [pri]
+
+        infos, skipped, _, extra = self._drive_superstep(
+            k,
+            k_max,
+            batch_size,
+            family="superstep",
+            cache_key=(cache_mode, refresh_priorities),
+            program=program,
+            key_schedule=key_schedule,
+            feed=feed,
+            drained=drained if refresh_priorities else None,
+        )
+        return infos, (extra[0] if refresh_priorities else None), skipped
 
     # ray-tpu: hot-path
     def learn_rollout_superstep(
@@ -1471,138 +1464,42 @@ class JaxPolicy(Policy):
         the stacked per-slot metrics tree (host numpy), and per-update
         nan-guard skip flags.
         """
-        import time as _time
+        T = int(rollout.steps)
 
-        k = int(k)
-        k_max = int(k_max or k)
-        if not 1 <= k <= k_max:
-            raise ValueError(f"k={k} outside [1, k_max={k_max}]")
-        nan_guard = bool(self.config.get("nan_guard"))
-
-        from ray_tpu.sharding import superstep as superstep_lib
-
-        cache_key = ("rollout", batch_size, k_max, rollout.key, nan_guard)
-        fns = self.__dict__.setdefault("_superstep_fns", {})
-        fn = fns.get(cache_key)
-        if fn is None:
-            fn = superstep_lib.build_superstep_fn(
-                self._device_update_fn(batch_size),
-                mesh=self.mesh,
-                backend=self.sharding_backend,
-                k=k_max,
-                label=(
-                    f"rollout_superstep[{type(self).__name__}:"
-                    f"{batch_size}x{k_max}]"
-                ),
+        def program():
+            return dict(
+                update_fn=self._device_update_fn(batch_size),
                 rollout_fn=rollout.body,
                 # a model with per-stream state: the params and the
                 # carry (a cache per env) are handed over, not copied
                 donate_rollout_state=bool(
                     getattr(getattr(self, "model", None), "is_recurrent", False)
                 ),
-                nan_guard=nan_guard,
-                carry_pspecs=(
-                    self._carry_pspecs()
-                    if self._param_pspecs is not None
-                    else None
-                ),
             )
-            fns[cache_key] = fn
 
-        with tracing.start_span("learn:keys", k=k, rollout=True):
-            coeffs = self._learn_coeffs()
-            T = int(rollout.steps)
-            # host rng schedule: T rollout splits then the learn split per
-            # slot. Dieted path fuses the whole k*(T+1)-split chain into
-            # ONE dispatch (bit-identical keys — same threefry chain, same
-            # order); see learn_superstep.
-            if sharding_lib.dispatch_diet_enabled():
-                rngs, ro_rngs = self._rollout_host_keys(k, k_max, T)
-            else:
-                learn_keys, ro_keys = [], []
-                for _ in range(k):
-                    slot = []
-                    for _ in range(T):
-                        self._rng, r = jax.random.split(self._rng)
-                        slot.append(r)
-                    ro_keys.append(jnp.stack(slot))
-                    self._rng, r = jax.random.split(self._rng)
-                    learn_keys.append(r)
-                pad = jnp.zeros_like(learn_keys[0])
-                pad_slot = jnp.zeros_like(ro_keys[0])
-                while len(learn_keys) < k_max:
-                    learn_keys.append(pad)
-                    ro_keys.append(pad_slot)
-                rngs = jnp.stack(learn_keys)
-                ro_rngs = jnp.stack(ro_keys)
-        active = self._active_mask(k, k_max)
-        # the lane's entire H2D payload: key stacks + the mask
-        telemetry_metrics.add_h2d_bytes(
-            "rollout",
-            int(rngs.nbytes) + int(ro_rngs.nbytes) + active.nbytes,
-        )
-
-        compiles_before = getattr(fn, "traces", 0)
-        t0 = _time.perf_counter()
-        with tracing.start_span(
-            "learn:superstep", k=k, batch_size=batch_size, rollout=True
-        ) as _sp:
-            (
-                self.params,
-                self.opt_state,
-                self.aux_state,
-                carry,
-                stats,
-                metrics,
-            ) = fn(
-                self.params,
-                self.opt_state,
-                self.aux_state,
-                rollout.carry,
-                active,
-                rngs,
-                ro_rngs,
-                coeffs,
+        def feed(keys, active):
+            # the lane's entire H2D payload: key stacks + the mask
+            telemetry_metrics.add_h2d_bytes(
+                "rollout",
+                sum(int(r.nbytes) for r in keys) + active.nbytes,
             )
-            _sp.set_attribute(
-                "recompiles",
-                getattr(fn, "traces", 0) - compiles_before,
-            )
-            # ONE drain: stacked stats + episode metrics together
-            with tracing.start_span("learn:drain"):
-                # ray-tpu: allow[RTA005] the ONE counted drain for the chain
-                stats, metrics = jax.device_get((stats, metrics))
-            # drain done → the fused rollout+learn program is finished;
-            # close its ledger interval (timestamps only)
-            device_ledger.drain_point()
-        self.num_grad_updates += k * self._updates_per_learn_call(
-            batch_size
-        )
-        self._after_superstep()
-        telemetry_metrics.counter(
-            telemetry_metrics.LEARN_STEPS_TOTAL,
-            "SGD-nest programs dispatched",
-        ).inc(float(k))
-        telemetry_metrics.inc_superstep_updates(k)
-        self.last_learn_timers["learn_superstep_s"] = (
-            _time.perf_counter() - t0
-        )
-        self.last_learn_timers["learn_recompiles"] = float(
-            getattr(fn, "traces", 0) - compiles_before
-        )
+            return rollout.carry
 
-        skip = np.asarray(
-            stats.get(superstep_lib.SKIP_KEY, np.zeros(k_max))
+        infos, skipped, carry, (metrics,) = self._drive_superstep(
+            k,
+            k_max,
+            batch_size,
+            family="rollout_superstep",
+            cache_key=("rollout", rollout.key),
+            program=program,
+            # T rollout splits then the learn split per slot
+            key_schedule=lambda k, k_max: self._rollout_host_keys(
+                k, k_max, T
+            ),
+            feed=feed,
+            carried=True,
+            rollout=True,
         )
-        skipped = [bool(skip[i] > 0.5) for i in range(k)]
-        infos = [
-            {
-                name: float(np.asarray(v)[i])
-                for name, v in stats.items()
-                if name != superstep_lib.SKIP_KEY
-            }
-            for i in range(k)
-        ]
         telemetry_metrics.note_expert_load(infos)
         return infos, carry, metrics, skipped
 

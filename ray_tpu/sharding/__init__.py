@@ -1,6 +1,4 @@
-"""ray_tpu.sharding — the mesh-based sharding runtime of the learner.
-
-Replaces the per-call pmap/shard-map shims with a first-class layer
+"""ray_tpu.sharding — the mesh-based sharding runtime of the learner
 (docs/sharding.md):
 
   - :mod:`~ray_tpu.sharding.mesh`    mesh construction (cached,
@@ -12,20 +10,14 @@ Replaces the per-call pmap/shard-map shims with a first-class layer
   - :mod:`~ray_tpu.sharding.compile` ``sharded_jit`` — jit with
     shardings + donation + compile-cache stats.
 
-Policies select the backend via ``config["sharding_backend"]``:
-``"mesh"`` (default) lowers the learn program through ``sharded_jit``
-with explicit shardings on a ``("batch",)`` mesh; ``"pmap"`` keeps the
-legacy ``ray_tpu.parallel`` path (a ``("data",)`` mesh, placement left
-to device_put) — fixed-seed results are bit-identical between the two
-on one device.
+Every learn program lowers through ``sharded_jit`` with explicit
+shardings on the mesh :func:`resolve_mesh` builds from the config.
 """
 
 from ray_tpu.sharding.compile import (
     ShardedFunction,
     compile_stats,
-    dispatch_diet_enabled,
     f64_scope,
-    set_dispatch_diet,
     sharded_jit,
 )
 from ray_tpu.sharding.mesh import (
@@ -76,22 +68,29 @@ from ray_tpu.sharding.superstep import (
 )
 
 
+def refuse_removed_options(options) -> None:
+    """Fail on an option this package no longer reads (the config
+    setters and :func:`resolve_mesh` call it, so no spelling of a
+    config passes one silently)."""
+    if "sharding_backend" in options:
+        raise ValueError(
+            "sharding_backend was removed in PR 30: the 'pmap' backend "
+            "reached no fused lane and every learn program now lowers "
+            "through the mesh runtime; drop the option "
+            "(docs/MIGRATION.md)"
+        )
+
+
 def resolve_mesh(config):
     """The mesh a policy should learn on, per config: an injected
-    ``_mesh`` (Algorithm.setup, multi-host tests) wins; otherwise the
-    backend decides — ``"mesh"`` builds through this package,
-    ``"pmap"`` through the legacy ``ray_tpu.parallel`` adapter (axis
-    named ``"data"``), keeping that path byte-compatible.
-    ``sharding(hosts=N)`` builds over the GLOBAL device view (every
-    process of the jax.distributed runtime — the DCN × ICI mesh of
-    docs/fleet.md) instead of this process's local devices."""
+    ``_mesh`` (Algorithm.setup, multi-host tests) wins; otherwise it
+    is built here. ``sharding(hosts=N)`` builds over the GLOBAL device
+    view (every process of the jax.distributed runtime — the DCN × ICI
+    mesh of docs/fleet.md) instead of this process's local devices."""
+    refuse_removed_options(config)
     m = config.get("_mesh")
     if m is not None:
         return m
-    if config.get("sharding_backend", "mesh") == "pmap":
-        from ray_tpu.parallel import mesh as _legacy
-
-        return _legacy.make_mesh()
     hosts = resolve_hosts(config)
     mp = resolve_model_parallel(config)
     if hosts > 1:
@@ -146,6 +145,7 @@ __all__ = [
     "param_pspecs",
     "param_sharding",
     "put_global",
+    "refuse_removed_options",
     "replicated",
     "resolve_hosts",
     "resolve_mesh",

@@ -6,15 +6,20 @@ donation (the modern spelling of the retrieved ``pjit`` pattern:
 cache: every retrace is counted and its wall time recorded, so "did
 this step recompile?" is a metric instead of a profiler session.
 
-Both learner backends come through here — the ``mesh`` backend with
-``NamedSharding`` trees attached, the legacy ``pmap`` fallback as a
-plain jit — so compile stats cover the whole learner plane either way.
+Every program of the learner plane comes through here (learn bodies
+with ``NamedSharding`` trees attached, key schedules and tree programs
+as plain jits), so compile stats cover all of them.
+
+``ShardedFunction.__call__`` has two paths, chosen by what it can
+observe: with neither ``tracing`` nor the device ledger on, a warmed-up
+dispatch costs one perf-clock pair and an unlocked counter bump; with
+either on, every call takes the wall stamps, the span and the ledger
+hook they consume.
 """
 
 from __future__ import annotations
 
 import contextlib
-import os
 import re
 import threading
 import time
@@ -30,34 +35,6 @@ from ray_tpu.util import tracing
 _LOCK = threading.Lock()
 # live ShardedFunctions, for process-wide stats aggregation
 _REGISTRY: "weakref.WeakSet" = weakref.WeakSet()
-
-# -- dispatch diet (benchmarks/MFU.md "dispatch overhead") -------------
-#
-# Once a program is superstep-small, the per-call host work around the
-# actual XLA dispatch is the learner critical path. The diet arms a
-# steady-state fast path in ``ShardedFunction.__call__`` (one
-# perf-clock pair, no lock, no ledger/tracing hooks) plus the cached
-# NamedSharding trees in specs.py and the fused host rng chains in
-# jax_policy.py. ``RAY_TPU_DISPATCH_DIET=0`` restores the pre-diet
-# bookkeeping on every call — the A/B side ``bench.py --dispatch``
-# measures against.
-_DIET = os.environ.get("RAY_TPU_DISPATCH_DIET", "1").lower() not in (
-    "0", "false", "off",
-)
-
-
-def dispatch_diet_enabled() -> bool:
-    return _DIET
-
-
-def set_dispatch_diet(on: bool) -> bool:
-    """Flip the diet at runtime (tests, the --dispatch A/B). Returns
-    the previous setting."""
-    global _DIET
-    prev = _DIET
-    _DIET = bool(on)
-    return prev
-
 
 def label_family(label: str) -> str:
     """What a program is called in a profiler trace: its label up to
@@ -307,17 +284,15 @@ class ShardedFunction:
             if boxed is not None:
                 return boxed[0]
         before = self.traces
-        # dispatch-diet fast path (bench.py --dispatch): after warmup,
-        # with neither tracing nor the device ledger consuming the
-        # per-call stamps, dispatch costs one perf-clock pair and an
-        # unlocked counter bump — no time.time(), no lock, no span, no
-        # ledger hook. A retrace detected after the fact (shape drift,
-        # a genuinely changed sharding) falls back to the full
-        # bookkeeping below for THIS call, so compile stats and
-        # forensics stay exact on every path that compiles.
+        # fast path: after warmup, with neither tracing nor the device
+        # ledger consuming the per-call stamps, dispatch costs one
+        # perf-clock pair and an unlocked counter bump — no
+        # time.time(), no lock, no span, no ledger hook. A retrace
+        # detected after the fact (shape drift, a genuinely changed
+        # sharding) gets the full bookkeeping for THIS call, so compile
+        # stats and forensics stay exact on every path that compiles.
         if (
-            _DIET
-            and before > 0
+            before > 0
             and not tracing.is_enabled()
             and not device_ledger.enabled()
         ):

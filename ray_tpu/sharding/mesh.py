@@ -12,11 +12,6 @@ Axis conventions:
   - ``"model"``: reserved for tensor parallelism of large learner
     models (multi-chip PRs add shapes here; the name is fixed now so
     specs written against it won't churn).
-
-The legacy ``ray_tpu.parallel.mesh`` module is an adapter over this one
-and keeps its historical ``"data"`` axis name for the pmap-path
-programs; everything here derives the axis from the mesh object, so
-both namings interoperate.
 """
 
 from __future__ import annotations
@@ -102,9 +97,8 @@ def clear_mesh_cache() -> None:
 
 
 def data_axis(mesh: Mesh) -> str:
-    """The data-parallel axis of a mesh: its first axis. Works for
-    both the new ``("batch",)`` and the legacy ``("data",)`` naming —
-    learn programs must use this instead of a string literal."""
+    """The data-parallel axis of a mesh: its first axis (``"batch"``
+    on every mesh this package builds by default)."""
     return mesh.axis_names[0]
 
 

@@ -19,9 +19,8 @@ functions instead of per-call-site constructions:
     hot path shows in the Prometheus scrape instead of just running
     slow.
 
-Everything derives the axis name from the mesh object, so specs work
-on both the ``("batch",)`` meshes this package builds and the legacy
-``("data",)`` meshes of ``ray_tpu.parallel``.
+Everything derives the data axis from the mesh object (its first
+axis: ``"batch"`` on the meshes this package builds).
 """
 
 from __future__ import annotations

@@ -69,17 +69,14 @@ def resolve_superstep(config: Dict, mesh=None) -> int:
     K this run fuses per dispatch (1 = off).
 
     ``"auto"`` engages (K=8) exactly where the amortization pays: a
-    mesh-backend learner behind a real accelerator boundary, where the
+    learner behind a real accelerator boundary, where the
     per-dispatch RTT is the measured bottleneck (benchmarks/MFU.md).
     On the CPU client dispatch is cheap and the K-step scan is pure
     compile time, so auto resolves off — mirroring
     ``resolve_device_resident``. An explicit int forces that K
-    anywhere (tests, benchmarks). The legacy pmap backend keeps
-    per-update dispatch."""
+    anywhere (tests, benchmarks)."""
     mode = config.get("superstep", "auto")
     if mode in (None, False, 0, 1):
-        return 1
-    if config.get("sharding_backend", "mesh") != "mesh":
         return 1
     if mode == "auto":
         return 1 if all_cpu(mesh) else 8
@@ -101,7 +98,6 @@ def build_superstep_fn(
     update_fn: Callable,
     *,
     mesh,
-    backend: str = "mesh",
     k: int,
     label: str,
     stacked_cols: Optional[Sequence[str]] = None,
@@ -196,7 +192,6 @@ def build_superstep_fn(
             update_fn,
             rollout_fn,
             mesh=mesh,
-            backend=backend,
             axis=axis,
             label=label,
             nan_guard=nan_guard,
@@ -299,15 +294,13 @@ def build_superstep_fn(
 
         def program(params, opt_state, aux, feed, active, *rest):
             store, idx, extra = feed
-            stacked = dict(gather_fn(store, idx))
-            if backend == "mesh":
-                # layout-matched gather: emit rows already in the scan
-                # body's row-sharded batch layout, so no resharding
-                # collective fires at the scan-body boundary
-                stacked = {
-                    c: jax.lax.with_sharding_constraint(v, dat2)
-                    for c, v in stacked.items()
-                }
+            # layout-matched gather: emit rows already in the scan
+            # body's row-sharded batch layout, so no resharding
+            # collective fires at the scan-body boundary
+            stacked = {
+                c: jax.lax.with_sharding_constraint(v, dat2)
+                for c, v in gather_fn(store, idx).items()
+            }
             stacked.update(extra)
             return sharded(
                 params, opt_state, aux, stacked, active, *rest
@@ -320,10 +313,6 @@ def build_superstep_fn(
                 params, opt_state, aux, stacked, active, *rest
             )
 
-    if backend != "mesh":
-        return sharded_jit(
-            program, donate_argnums=(1,), label=label
-        )
     if gather_fn is not None:
         feed_spec = (
             dict(store_shardings),
@@ -357,7 +346,6 @@ def _build_rollout_superstep(
     rollout_fn: Callable,
     *,
     mesh,
-    backend: str,
     axis: str,
     label: str,
     nan_guard: bool,
@@ -443,10 +431,6 @@ def _build_rollout_superstep(
             P(*([None] * 2 + [axis])),
         ),
     )
-    if backend != "mesh":
-        return sharded_jit(
-            sharded, donate_argnums=donate, label=label
-        )
     rep = replicated(mesh)
     dat = batch_sharded(mesh)
     met = batch_sharded(mesh, ndim_prefix=3)

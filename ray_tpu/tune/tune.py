@@ -724,10 +724,12 @@ def run(
         # fractional requests (TPU: 0.5) keep the time-slicing path:
         # a submesh needs at least one whole device
         if per >= 1 and slots >= 2 and len(trials or []) != 1:
-            from ray_tpu.parallel.mesh import make_mesh
+            from ray_tpu import sharding as sharding_lib
 
             mesh_slots = [
-                make_mesh(devices=devs[i * per : (i + 1) * per])
+                sharding_lib.get_mesh(
+                    devices=devs[i * per : (i + 1) * per]
+                )
                 for i in range(slots)
             ]
     experiment_dir = (

@@ -1,22 +1,20 @@
-"""Dispatch diet + Pallas hot-op kernels (ROADMAP perf item).
+"""The compile layer's two dispatch paths, the replay plane's device
+ops, and the program registry.
 
-Four contracts from the PR's acceptance list:
-
-- **Diet parity**: the dieted ``ShardedFunction.__call__`` fast path
-  (cached sharding trees, pre-validated donation, single clock pair)
-  is an observability/host-overhead change only — fixed-seed learn
-  results are BITWISE identical with the diet on and off, steady-state
-  calls never retrace, and a genuinely new signature still falls back
-  to the full path and retraces correctly.
-- **Pallas kernel parity**: every hot-op kernel (replay row
-  gather/scatter, framestack build, GAE fragment scan, sum-tree prefix
-  descent) matches its XLA fallback — bitwise for pure data movement
-  and the descent, documented float32 tolerance for the GAE scan —
-  including through the interpreter-mode CPU fallback that tier-1 CI
-  exercises here.
-- **End-to-end knobs**: ``DeviceReplayBuffer`` / ``DeviceSumTree``
-  accept ``use_pallas``/``pallas_interpret`` and produce bit-identical
-  streams either way.
+- **Dispatch parity**: ``ShardedFunction.__call__`` has a fast path
+  (neither tracing nor the device ledger on: cached sharding trees,
+  pre-validated donation, single clock pair) and an observed one; the
+  choice is an observability/host-overhead matter only — fixed-seed
+  learn results are BITWISE identical on both, steady-state calls
+  never retrace, and a genuinely new signature still gets the full
+  bookkeeping and retraces correctly.
+- **Device ops against the host reference**: the replay row
+  gather/scatter, the framestack build, the GAE fragment scan and the
+  sum-tree prefix descent are XLA bodies (Mosaic refused a Pallas
+  kernel for each on the v5e); each is held to the host code it
+  stands in for — bitwise for data movement and the descent, the
+  documented float32 tolerance for the GAE scan — and the device
+  buffers to the host buffers' rows, indices and weights.
 - **Program registry completeness**: ``sharding.registry`` enumerates
   every executable an AlgorithmConfig lowers — a fused-lane PPO run
   and a prioritized device-replay DQN run leave ZERO observed compile
@@ -26,7 +24,6 @@ Four contracts from the PR's acceptance list:
 
 import gymnasium as gym
 import numpy as np
-import pytest
 
 import jax
 import jax.numpy as jnp
@@ -36,12 +33,7 @@ from ray_tpu.data.sample_batch import SampleBatch as SB
 from ray_tpu.ops import framestack as framestack_lib
 from ray_tpu.ops import gae as gae_lib
 from ray_tpu.ops import segment_tree as st_lib
-from ray_tpu.sharding.compile import (
-    compile_stats,
-    dispatch_diet_enabled,
-    set_dispatch_diet,
-    sharded_jit,
-)
+from ray_tpu.sharding.compile import compile_stats, sharded_jit
 
 
 def _one_shard_mesh():
@@ -52,15 +44,7 @@ def _labels():
     return {s["label"] for s in compile_stats()["per_function"]}
 
 
-@pytest.fixture
-def diet():
-    """Restore the process diet flag whatever a test sets it to."""
-    prev = dispatch_diet_enabled()
-    yield
-    set_dispatch_diet(prev)
-
-
-# -- dispatch diet ------------------------------------------------------
+# -- the two dispatch paths ---------------------------------------------
 
 
 BS = 16
@@ -111,23 +95,38 @@ def _leaves(policy):
     ]
 
 
-def test_diet_learn_bitwise_parity(diet):
-    """Fixed-seed learn through the dieted dispatch path is BITWISE
-    identical to the full-validation path — the diet drops host work,
-    never bytes (the PR's headline acceptance criterion)."""
+def test_diet_learn_bitwise_parity():
+    """Fixed-seed learn through the fast dispatch path is BITWISE
+    identical to the observed path (tracing + device ledger on, every
+    call stamped, spanned and handed to the ledger) — the fast path
+    drops host work, never bytes."""
+    from ray_tpu.telemetry import device as device_ledger
+    from ray_tpu.util import tracing
+
     batch = _batch()
 
-    set_dispatch_diet(False)
-    p_off = _policy()
+    assert not tracing.is_enabled() and not device_ledger.enabled()
+    p_fast = _policy()
     for _ in range(3):
-        p_off.learn_on_batch(batch)
+        p_fast.learn_on_batch(batch)
 
-    set_dispatch_diet(True)
-    p_on = _policy()
-    for _ in range(3):
-        p_on.learn_on_batch(batch)
+    tracing.enable()
+    device_ledger.enable(analyze=False)
+    try:
+        p_obs = _policy()
+        for _ in range(3):
+            p_obs.learn_on_batch(batch)
+        observed = {
+            sp["name"] for sp in tracing.get_spans()
+        }
+    finally:
+        device_ledger.disable()
+        device_ledger.clear()
+        tracing.disable()
+        tracing.clear()
+    assert any(n.startswith("jit:learn[") for n in observed), observed
 
-    a, b = _leaves(p_off), _leaves(p_on)
+    a, b = _leaves(p_fast), _leaves(p_obs)
     assert len(a) == len(b)
     for x, y in zip(a, b):
         np.testing.assert_array_equal(
@@ -135,10 +134,9 @@ def test_diet_learn_bitwise_parity(diet):
         )
 
 
-def test_diet_steady_state_never_retraces(diet):
+def test_diet_steady_state_never_retraces():
     """Repeated same-signature calls ride the fast path: one trace,
     N calls, zero recompiles."""
-    set_dispatch_diet(True)
     mesh = _one_shard_mesh()
     spec = sharding_lib.replicated(mesh)
     fn = sharded_jit(
@@ -157,11 +155,10 @@ def test_diet_steady_state_never_retraces(diet):
     assert st["calls"] == 10
 
 
-def test_diet_new_signature_falls_back_and_retraces(diet):
+def test_diet_new_signature_falls_back_and_retraces():
     """The fast path is signature-guarded: a genuinely new abstract
     signature drops to the full path, retraces, and still computes
     correctly (the post-hoc retrace fallback)."""
-    set_dispatch_diet(True)
     mesh = _one_shard_mesh()
     spec = sharding_lib.replicated(mesh)
     fn = sharded_jit(
@@ -175,7 +172,7 @@ def test_diet_new_signature_falls_back_and_retraces(diet):
     fn(x8)
     fn(x8)
     assert fn.stats()["traces"] == 1
-    out = fn(x16)  # new shape while dieted
+    out = fn(x16)  # new shape on the fast path
     np.testing.assert_array_equal(np.asarray(out), np.full(16, 2.0))
     assert fn.stats()["traces"] == 2
     # and the old signature still rides its cached executable
@@ -183,12 +180,11 @@ def test_diet_new_signature_falls_back_and_retraces(diet):
     assert fn.stats()["traces"] == 2
 
 
-def test_diet_superstep_k_sweep_zero_recompiles(diet):
-    """With the diet on (cached sharding trees), every k = 1..K_MAX
+def test_diet_superstep_k_sweep_zero_recompiles():
+    """On the fast path (cached sharding trees), every k = 1..K_MAX
     rides the ONE compiled superstep executable — zero recompiles
     across the whole sweep (the active-mask contract survives the
     fast path)."""
-    set_dispatch_diet(True)
     kmax, n = 8, BS
     p = _policy(num_sgd_iter=1)
     rng = np.random.default_rng(13)
@@ -210,7 +206,7 @@ def test_diet_superstep_k_sweep_zero_recompiles(diet):
     assert fn.calls == kmax
 
 
-def test_sharding_tree_cache_clear_is_sound(diet):
+def test_sharding_tree_cache_clear_is_sound():
     """``clear_sharding_caches`` invalidates the resolved-tree memo
     without changing results."""
     mesh = _one_shard_mesh()
@@ -227,13 +223,15 @@ def test_sharding_tree_cache_clear_is_sound(diet):
         assert s1 == s2
 
 
-# -- Pallas kernel parity (interpreter fallback on CPU CI) --------------
+# -- device ops against the host reference ------------------------------
 
 
 def test_gather_scatter_rows_pallas_bitwise():
-    """Row gather/scatter through the Pallas kernels is pure data
-    movement: bitwise vs the XLA fallback, f32 and packed-uint32
-    rings alike, and scatter leaves unwritten ring rows untouched."""
+    """Row gather/scatter is pure data movement: bitwise against numpy
+    fancy indexing, f32 and packed-uint32 rings alike; the scatter
+    leaves unwritten ring rows untouched and a colliding write keeps
+    one of its writers (XLA leaves the order open; numpy keeps the
+    last)."""
     rng = np.random.default_rng(0)
     for dtype in (np.float32, np.uint32):
         if dtype is np.uint32:
@@ -246,154 +244,158 @@ def test_gather_scatter_rows_pallas_bitwise():
             vals = rng.standard_normal((5, 12)).astype(dtype)
         idx = rng.integers(0, 32, 7)
 
-        want = np.asarray(ring)[idx]
         got = framestack_lib.gather_rows(
-            jnp.asarray(ring),
-            jnp.asarray(idx),
-            use_pallas=True,
-            interpret=True,
+            jnp.asarray(ring), jnp.asarray(idx)
         )
-        np.testing.assert_array_equal(np.asarray(got), want)
+        np.testing.assert_array_equal(np.asarray(got), ring[idx])
 
         pos = np.array([3, 9, 9, 0, 31])  # includes a collision
-        want_ring = np.asarray(ring).copy()
-        for p, v in zip(pos, vals):
-            want_ring[p] = v
-        got_ring = framestack_lib.scatter_rows(
-            jnp.asarray(ring),
-            jnp.asarray(pos),
-            jnp.asarray(vals),
-            use_pallas=True,
-            interpret=True,
+        want_ring = ring.copy()
+        want_ring[pos] = vals
+        got_ring = np.asarray(
+            framestack_lib.scatter_rows(
+                jnp.asarray(ring), jnp.asarray(pos), jnp.asarray(vals)
+            )
         )
-        np.testing.assert_array_equal(np.asarray(got_ring), want_ring)
+        free = np.arange(32) != 9
+        np.testing.assert_array_equal(got_ring[free], want_ring[free])
+        assert any(
+            np.array_equal(got_ring[9], vals[w]) for w in (1, 2)
+        )
 
 
 def test_build_stacks_pallas_bitwise():
-    """The framestack build through the Pallas gather (uint32-packed
-    frame pool) is bitwise identical to the XLA gather."""
+    """The framestack build (uint32-packed frame pool, one gather) is
+    bitwise the host's ``materialize_stacks_np``."""
     rng = np.random.default_rng(1)
     k, n = 4, 10
-    frames = jnp.asarray(
-        rng.integers(0, 255, (n + k - 1, 12, 12, 1)).astype(np.uint8)
+    frames = rng.integers(0, 255, (n + k - 1, 12, 12, 1)).astype(
+        np.uint8
     )
-    idx = jnp.arange(n, dtype=jnp.int32)
-    base = np.asarray(framestack_lib.build_stacks(frames, idx, k))
+    idx = rng.permutation(n).astype(np.int32)
     got = np.asarray(
         framestack_lib.build_stacks(
-            frames, idx, k, use_pallas=True, interpret=True
+            jnp.asarray(frames), jnp.asarray(idx), k
         )
     )
-    np.testing.assert_array_equal(got, base)
+    np.testing.assert_array_equal(
+        got, framestack_lib.materialize_stacks_np(frames, idx, k)
+    )
 
 
 def test_gae_fragment_pallas_tolerance():
-    """The sequential Pallas GAE scan vs the XLA associative scan:
-    same recurrence, different evaluation order — the documented
-    float32 contract is max |Δ| < 1e-4 on both outputs."""
+    """The associative GAE scan vs the host's sequential
+    ``compute_gae_np`` on each row: same recurrence, different
+    evaluation order — the documented float32 contract is
+    max |Δ| < 1e-4 on both outputs."""
     rng = np.random.default_rng(2)
     b, t = 12, 40
     rewards = rng.standard_normal((b, t)).astype(np.float32)
     values = rng.standard_normal((b, t)).astype(np.float32)
-    nexts = rng.standard_normal((b, t)).astype(np.float32)
-    term = (rng.random((b, t)) < 0.05).astype(np.float32)
-    done = np.maximum(
-        term, (rng.random((b, t)) < 0.05).astype(np.float32)
+    boot = rng.standard_normal(b).astype(np.float32)
+    # no truncation, so every boundary bootstraps 0 and V(next obs) is
+    # the next row's value: the single-mask semantics of the reference
+    done = (rng.random((b, t)) < 0.08).astype(np.float32)
+    nexts = np.concatenate([values[:, 1:], boot[:, None]], axis=1)
+    adv, vt = gae_lib.compute_gae_fragment(
+        *(jnp.asarray(a) for a in (rewards, values, nexts, done, done)),
+        gamma=0.99,
+        lambda_=0.95,
     )
-    args = tuple(
-        jnp.asarray(a) for a in (rewards, values, nexts, term, done)
-    )
-    adv0, vt0 = gae_lib.compute_gae_fragment(
-        *args, gamma=0.99, lambda_=0.95
-    )
-    adv1, vt1 = gae_lib.compute_gae_fragment(
-        *args, gamma=0.99, lambda_=0.95, use_pallas=True, interpret=True
-    )
-    for a0, a1 in ((adv0, adv1), (vt0, vt1)):
-        d = np.abs(np.asarray(a0) - np.asarray(a1))
-        assert np.isfinite(d).all()
-        assert d.max() < 1e-4, d.max()
+    for i in range(b):
+        adv_np, vt_np = gae_lib.compute_gae_np(
+            rewards[i], values[i], done[i], boot[i], 0.99, 0.95
+        )
+        for got, want in ((adv[i], adv_np), (vt[i], vt_np)):
+            d = np.abs(np.asarray(got) - want)
+            assert np.isfinite(d).all()
+            assert d.max() < 1e-4, d.max()
 
 
 def test_sumtree_descent_pallas_bitwise():
-    """The f64 prefix-sum descent kernel replays find_prefixsum_body's
-    exact op sequence — drawn leaf indices are identical."""
+    """The in-program f64 prefix-sum descent replays
+    ``SumSegmentTree.find_prefixsum_idx``'s exact op sequence — drawn
+    leaf indices are identical."""
     cap = 64
     rng = np.random.default_rng(3)
+    host = st_lib.SumSegmentTree(cap)
+    host.set_items(np.arange(cap), rng.random(cap) + 1e-3)
+    prefix = rng.random(17) * host.sum()
     with sharding_lib.f64_scope():
-        value = np.zeros(2 * cap, np.float64)
-        value[cap:] = rng.random(cap) + 1e-3
-        for i in range(cap - 1, 0, -1):
-            value[i] = value[2 * i] + value[2 * i + 1]
-        prefix = rng.random(17) * value[1]
-        base = np.asarray(
-            st_lib.find_prefixsum_body(
-                jnp.asarray(value), jnp.asarray(prefix), cap
-            )
-        )
         got = np.asarray(
-            st_lib.find_prefixsum_pallas(
-                jnp.asarray(value),
-                jnp.asarray(prefix),
-                cap,
-                interpret=True,
+            st_lib.find_prefixsum_body(
+                jnp.asarray(host.value), jnp.asarray(prefix), cap
             )
         )
-    np.testing.assert_array_equal(got, base)
+    np.testing.assert_array_equal(got, host.find_prefixsum_idx(prefix))
 
 
 def test_device_replay_pallas_end_to_end_bitwise():
-    """DeviceReplayBuffer with the Pallas row kernels forced on
-    (interpreter mode) inserts and samples bit-identically to the XLA
-    path — same seed, same draw stream, same rows."""
-    from ray_tpu.execution.replay_buffer import DeviceReplayBuffer
+    """DevicePrioritizedReplayBuffer (rows on the device, uint8 rows
+    packed into uint32 lanes, draws through the device tree) inserts
+    and samples the host prioritized buffer's rows — same seed, same
+    draw stream, same rows and weights."""
+    from ray_tpu.execution.replay_buffer import (
+        DevicePrioritizedReplayBuffer,
+        PrioritizedReplayBuffer,
+    )
 
-    mesh = _one_shard_mesh()
     rng = np.random.default_rng(4)
     frags = [
         {
-            "obs": rng.integers(0, 255, (8, 6, 6, 1)).astype(np.uint8),
+            "obs": rng.integers(0, 255, (8, 6, 6, 4)).astype(np.uint8),
             "rew": rng.standard_normal(8).astype(np.float32),
         }
-        for _ in range(6)
+        for _ in range(6)  # 48 rows into 32: the ring wraps
     ]
+    pris = [rng.random(8) + 0.1 for _ in frags]
 
-    def run(**knobs):
-        buf = DeviceReplayBuffer(
-            capacity=32, seed=9, mesh=mesh, **knobs
+    dev = DevicePrioritizedReplayBuffer(
+        capacity=32, seed=9, mesh=_one_shard_mesh(), device_tree=True
+    )
+    host = PrioritizedReplayBuffer(capacity=32, seed=9)
+    for f, p in zip(frags, pris):
+        dev.add_tree(dict(f), priorities=p)
+        host.add_with_priorities(SB(dict(f)), p)
+    got = dev.sample(16, beta=0.4)
+    want = host.sample(16, beta=0.4)
+    np.testing.assert_array_equal(
+        np.asarray(got.indices), want["batch_indexes"]
+    )
+    for k in ("obs", "rew", "weights"):
+        np.testing.assert_array_equal(
+            np.asarray(got.tree[k]), want[k], err_msg=k
         )
-        for f in frags:
-            buf.add_tree(dict(f))
-        out = buf.sample(16)
-        return {k: np.asarray(v) for k, v in out.tree.items()}
-
-    base = run()
-    got = run(use_pallas=True, pallas_interpret=True)
-    assert set(base) == set(got)
-    for k in base:
-        np.testing.assert_array_equal(base[k], got[k], err_msg=k)
 
 
 def test_device_sumtree_pallas_end_to_end_bitwise():
-    """DeviceSumTree draws through the Pallas descent (interpreter
-    mode) match the XLA body bit-for-bit: indices AND f32 IS
-    weights."""
-    cap = 32
+    """DeviceSumTree draws match the host trees' stratified draw
+    bit-for-bit: indices AND f32 IS weights."""
+    cap, size, beta = 32, 29, 0.4
     rng = np.random.default_rng(5)
-    base_p = rng.random(cap) * 2 + 1e-3
+    powered = rng.random(size) * 2 + 1e-3
+    rand = np.random.default_rng(6).random(16)
 
-    def run(**knobs):
-        dt = st_lib.DeviceSumTree(cap, mesh=_one_shard_mesh(), **knobs)
-        dt.set_powered(np.arange(cap), base_p)
-        rand = np.random.default_rng(6).random(16)
-        idx, w = dt.draw(rand, 16, 0.4)
-        return np.asarray(idx), np.asarray(w)
+    dt = st_lib.DeviceSumTree(cap, mesh=_one_shard_mesh())
+    dt.set_powered(np.arange(size), powered)
+    idx, w = dt.draw(rand, size, beta)
 
-    i0, w0 = run()
-    i1, w1 = run(use_pallas=True, pallas_interpret=True)
-    np.testing.assert_array_equal(i0, i1)
+    sum_t, min_t = st_lib.SumSegmentTree(cap), st_lib.MinSegmentTree(cap)
+    sum_t.set_items(np.arange(size), powered)
+    min_t.set_items(np.arange(size), powered)
+    total = sum_t.sum(0, size)
+    want_idx = np.clip(
+        sum_t.find_prefixsum_idx((rand + np.arange(16)) / 16 * total),
+        0,
+        size - 1,
+    )
+    max_weight = (min_t.min(0, size) / total * size) ** (-beta)
+    want_w = (
+        (sum_t[want_idx] / total * size) ** (-beta) / max_weight
+    ).astype(np.float32)
+    np.testing.assert_array_equal(np.asarray(idx), want_idx)
     np.testing.assert_array_equal(
-        w0.view(np.uint8), w1.view(np.uint8)
+        np.asarray(w).view(np.uint8), want_w.view(np.uint8)
     )
 
 

@@ -138,9 +138,9 @@ def test_policy_learns_identically_from_stream_and_stacks():
 def test_stream_batch_on_8_device_mesh():
     """Replicated frame pool + data-sharded idx rows on a real mesh:
     the gather happens per shard with global indices."""
-    from ray_tpu.parallel.mesh import make_mesh
+    from ray_tpu.sharding import get_mesh
 
-    mesh = make_mesh(devices=jax.devices()[:8])
+    mesh = get_mesh(devices=jax.devices()[:8])
     rng = np.random.default_rng(0)
     n = 16
     frames = _stream(rng, n)

@@ -9,16 +9,16 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.parallel import collectives as coll
-from ray_tpu.parallel.mesh import make_mesh
 from ray_tpu.parallel.ring_attention import (
     full_attention_reference,
     ring_attention,
 )
+from ray_tpu.sharding import get_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh8():
-    return make_mesh([("sp", 8)])
+    return get_mesh(axis_shapes=[("sp", 8)])
 
 
 def _smap(fn, mesh, in_specs, out_specs):

@@ -167,18 +167,30 @@ def test_manager_readd_clears_dead_mark_and_counts():
     assert mgr.take_dead_workers() == [w]
 
 
-def test_probe_actors_bounded_by_single_budget():
+def test_probe_actors_bounded_by_single_budget(monkeypatch):
     """Satellite: one wedged actor must cost the sweep at most the
-    probe budget — not a per-worker timeout each."""
+    probe budget — not a per-worker timeout each. Held as events, not
+    as a wall-clock bound (the machine's load is not the code's): the
+    sweep blocks in ONE wait, on the budget, and reports the wedged
+    actor alone."""
     if not ray.is_initialized():
         ray.init()
     ok = _Pingable.remote()
     wedged = _Pingable.remote(ping_delay=60.0)
-    t0 = time.monotonic()
-    bad = probe_actors([ok, wedged, ok], timeout_s=2.0)
-    elapsed = time.monotonic() - t0
+    # both processes are up before the budget starts: a healthy actor
+    # still starting is not a wedged one
+    assert ray.get([ok.sample.remote(), wedged.sample.remote()]) == [1, 1]
+    waits = []
+    wait = ray.wait
+
+    def counted(refs, **kw):
+        waits.append(kw.get("timeout"))
+        return wait(refs, **kw)
+
+    monkeypatch.setattr(ray, "wait", counted)
+    bad = probe_actors([ok, wedged, ok], timeout_s=5.0)
     assert bad == [1]
-    assert elapsed < 10.0, f"sweep took {elapsed:.1f}s for a 2s budget"
+    assert [t for t in waits if t] == [5.0], waits
 
 
 # ---------------------------------------------------------------------------

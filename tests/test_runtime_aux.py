@@ -30,26 +30,33 @@ def test_autoscaler_upscales_and_reaps(tmp_path):
         update_interval_s=0.1,
     )
 
-    @ray.remote
-    def slow():
-        time.sleep(0.5)
-        return 1
+    room = str(tmp_path)
 
-    refs = [slow.remote() for _ in range(4)]
-    assert sum(ray.get(refs)) == 4
-    stats = scaler.stats()
-    # demand-driven dispatch (the node-provider role) grew the pool
-    assert stats["num_workers"] >= 2
-    # idle reaping brings the pool back down
-    deadline = time.time() + 10
+    @ray.remote
+    def meet():
+        # holds its worker until a second worker is in the room too:
+        # an event, where a sleep would race the reaper and the
+        # machine's load
+        open(os.path.join(room, str(os.getpid())), "w").close()
+        deadline = time.time() + 60
+        while len(os.listdir(room)) < 2 and time.time() < deadline:
+            time.sleep(0.05)
+        return os.getpid()
+
+    # demand-driven dispatch (the node-provider role) grew the pool:
+    # two workers held a task at the same time
+    pids = ray.get([meet.remote() for _ in range(4)])
+    assert len(set(pids)) >= 2
+    # idle reaping brings the pool back down: wait on that state
+    deadline = time.time() + 60
     while time.time() < deadline:
         if scaler.stats()["num_workers"] == 0:
             break
         time.sleep(0.2)
     assert scaler.stats()["num_workers"] == 0
-    assert scaler.num_downscales >= 1
+    assert scaler.num_downscales >= 2
     # pool regrows on new demand after reaping
-    assert ray.get(slow.remote()) == 1
+    assert ray.get(meet.remote()) > 0
     scaler.stop()
 
 

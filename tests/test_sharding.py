@@ -3,9 +3,11 @@
 All run on the 8-device simulated CPU platform conftest.py forces
 (``--xla_force_host_platform_device_count=8``): mesh construction and
 caching, spec builders incl. the ragged-leading-dim fallback, donation,
-compile-cache stats, and mesh/pmap backend parity on a fixed-seed PPO
-learn step.
+compile-cache stats, and device-count parity on a fixed-seed PPO learn
+step.
 """
+
+import functools
 
 import gymnasium as gym
 import jax
@@ -45,23 +47,8 @@ def test_mesh_axis_shapes_and_oversubscription():
         sl.get_mesh(axis_shapes=[("batch", 16)])
 
 
-def test_legacy_parallel_adapter_keeps_data_axis():
-    from ray_tpu.parallel import mesh as legacy
-
-    mesh = legacy.make_mesh()
-    assert mesh.axis_names == ("data",)
-    # the adapter helpers derive the axis from the mesh, so they also
-    # accept the runtime's ("batch",) meshes
-    assert legacy.num_data_shards(sl.get_mesh()) == 8
-    spec = legacy.data_sharding(sl.get_mesh()).spec
-    assert tuple(spec) == ("batch",)
-
-
-def test_resolve_mesh_backend_selection():
+def test_resolve_mesh_default_and_injected():
     assert sl.resolve_mesh({}).axis_names == ("batch",)
-    assert sl.resolve_mesh(
-        {"sharding_backend": "pmap"}
-    ).axis_names == ("data",)
     injected = sl.get_mesh(devices=jax.devices()[:2])
     assert sl.resolve_mesh({"_mesh": injected}) is injected
 
@@ -148,29 +135,23 @@ def test_sharded_jit_compile_cache_stats():
 
 
 # ---------------------------------------------------------------------------
-# backend parity: fixed-seed PPO learn step, mesh vs pmap
+# device-count parity: fixed-seed PPO learn step, n devices vs one
 # ---------------------------------------------------------------------------
 
 
-def _ppo_policy(backend, n_dev):
+def _ppo_policy(n_dev):
     from ray_tpu.algorithms.ppo.ppo import PPOJaxPolicy
-    from ray_tpu.parallel import mesh as legacy
 
-    devs = jax.devices()[:n_dev]
-    mesh = (
-        sl.get_mesh(devices=devs)
-        if backend == "mesh"
-        else legacy.make_mesh(devices=devs)
-    )
     return PPOJaxPolicy(
         gym.spaces.Box(-1.0, 1.0, (8,), np.float32),
         gym.spaces.Discrete(4),
         {
-            "_mesh": mesh,
-            "sharding_backend": backend,
+            "_mesh": sl.get_mesh(devices=jax.devices()[:n_dev]),
             "model": {"fcnet_hiddens": [16]},
             "train_batch_size": 32,
-            "sgd_minibatch_size": 16,
+            # full-batch minibatches: the per-shard shuffle then picks
+            # no rows, so every device count takes the same two steps
+            "sgd_minibatch_size": 32,
             "num_sgd_iter": 2,
             "lr": 1e-3,
             "seed": 0,
@@ -203,31 +184,38 @@ def _ppo_batch(b=32):
 
 
 @pytest.mark.parametrize("n_dev", [1, 8])
-def test_mesh_pmap_parity_fixed_seed_ppo(n_dev):
-    """Acceptance: with sharding_backend="mesh" a fixed-seed PPO
-    learn_on_batch is numerically identical to the pmap backend —
-    bitwise, on 1 device AND on 8 simulated host devices — and the
-    compiled program does not retrace across constant-shape steps."""
+def test_mesh_parity_fixed_seed_ppo(n_dev):
+    """Acceptance: a fixed-seed PPO learn_on_batch on an n-device mesh
+    takes the steps of the one-device mesh — bitwise on 1 device
+    (two policies, one program), to float32 reduction order on 8
+    simulated host devices — and the compiled program does not
+    retrace across constant-shape steps."""
     results = {}
-    for backend in ("mesh", "pmap"):
-        pol = _ppo_policy(backend, n_dev)
+    for n in (n_dev, 1):
+        pol = _ppo_policy(n)
         pol.learn_on_batch(_ppo_batch())
         stats = pol.learn_on_batch(_ppo_batch())
         fn = pol.learn_fn(32)
-        assert fn.traces == 1 and fn.recompiles == 0, backend
-        # mesh backend: batch really lands sharded over "batch"
-        if backend == "mesh" and n_dev == 8:
-            assert sl.data_axis(pol.mesh) == "batch"
-            assert pol.n_shards == 8
-        results[backend] = (stats, jax.device_get(pol.params))
-    s_mesh, w_mesh = results["mesh"]
-    s_pmap, w_pmap = results["pmap"]
-    assert s_mesh["total_loss"] == s_pmap["total_loss"]
+        assert fn.traces == 1 and fn.recompiles == 0, n
+        # the batch really lands sharded over "batch"
+        assert sl.data_axis(pol.mesh) == "batch"
+        assert pol.n_shards == n
+        results[n] = (stats, jax.device_get(pol.params))
+    s_n, w_n = results[n_dev]
+    s_1, w_1 = results[1]
+    same = (
+        np.testing.assert_array_equal
+        if n_dev == 1
+        else functools.partial(
+            np.testing.assert_allclose, rtol=1e-4, atol=1e-6
+        )
+    )
+    same(s_n["total_loss"], s_1["total_loss"])
     for a, b in zip(
-        jax.tree_util.tree_leaves(w_mesh),
-        jax.tree_util.tree_leaves(w_pmap),
+        jax.tree_util.tree_leaves(w_n),
+        jax.tree_util.tree_leaves(w_1),
     ):
-        np.testing.assert_array_equal(a, b)
+        same(a, b)
 
 
 def test_gradient_is_the_mean_over_shards_not_the_sum():

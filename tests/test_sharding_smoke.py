@@ -1,34 +1,9 @@
-"""Tier-1 smoke for the sharding runtime + its bench entry.
-
-Runs the exact code path ``bench.py --sharding-ab`` drives (tiny
-geometry) so signature drift in the public sharding API fails tests
-instead of the driver run — the same contract test_bench_smoke.py
-establishes for the headline bench.
-"""
-
-import json
+"""Tier-1 smoke for the sharding runtime's public surface, so
+signature drift in it fails a test instead of a driver run."""
 
 import pytest
 
-import bench
-
 pytestmark = pytest.mark.smoke
-
-
-def test_bench_sharding_ab_runs_and_reports(tmp_path):
-    out = str(tmp_path / "sharding_ab.json")
-    report = bench.bench_sharding_ab(
-        b=64, mb=32, iters=1, rounds=2, out_path=out
-    )
-    assert set(report["backends"]) == {"mesh", "pmap"}
-    for be in report["backends"].values():
-        assert be["step_ms_median"] > 0
-        assert be["recompiles"] == 0
-    assert report["parity_bitwise"] is True
-    with open(out) as f:
-        assert json.load(f)["metric"] == (
-            "sharding_backend_ab_learn_step"
-        )
 
 
 def test_sharding_public_api_surface():

@@ -463,7 +463,7 @@ def test_superstep_ring_gather_adds_no_collective():
     buf.add_tree(dict(rows))
     idx = buf.draw_index_sets(K, BS)
     feed = buf.superstep_feed(idx)
-    common = dict(mesh=p.mesh, backend=p.sharding_backend, k=K)
+    common = dict(mesh=p.mesh, k=K)
     fn_rings = build_superstep_fn(
         p._device_update_fn(BS),
         label="rings",

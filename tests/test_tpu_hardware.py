@@ -4,12 +4,12 @@ The default test run forces the virtual 8-device CPU platform
 (``conftest.py``); these tests only run under ``RAY_TPU_HW_TEST=1
 pytest tests/test_tpu_hardware.py``, where the conftest leaves the real
 backend in place. They validate, for exactly the shapes the hot paths
-use, that each Pallas kernel either compiles and matches the XLA
-reference (flash attention — the concern raised for Mosaic tile
-alignment on small GTrXL head dims; reference precedent:
-``rllib/models/torch/attention_net.py:37`` shapes) or is refused by
-Mosaic with the message its module quotes, in which case ``auto`` runs
-the XLA path and THAT is checked against a host reference.
+use, that the flash-attention kernel compiles and matches the XLA
+reference (the concern raised for Mosaic tile alignment on small GTrXL
+head dims; reference precedent:
+``rllib/models/torch/attention_net.py:37`` shapes), and that the XLA
+bodies of the replay plane's ops (Mosaic refused a kernel for each,
+PR 21) match a host reference on the chip.
 """
 
 import os
@@ -68,19 +68,23 @@ def test_flash_block_stats_on_tpu(shape):
     np.testing.assert_allclose(out, ref, atol=2e-2)
 
 
-def test_auto_is_a_rule_not_a_probe():
-    """``use_pallas=None`` is decided by the backend and a per-kernel
-    constant — there is no lowering probe to cache, and nothing to
-    catch: flash attention compiles here, the row copy and the GAE
-    scan do not (below), so auto picks XLA for those."""
-    from ray_tpu.ops import flash_attention, framestack, gae
-    from ray_tpu.ops._pallas import kernel_selected
+def test_auto_is_the_kernel_on_a_tpu():
+    """``use_pallas=None`` is decided by the backend, with no lowering
+    probe and nothing caught: on the chip auto is the kernel, bitwise
+    the forced kernel."""
+    from ray_tpu.ops.flash_attention import flash_attention
 
-    assert kernel_selected(None, False, compiles_on_tpu=True) is True
-    assert kernel_selected(None, False, compiles_on_tpu=False) is False
-    assert flash_attention._COMPILES_ON_TPU is True
-    assert framestack._COMPILES_ON_TPU is False
-    assert gae._COMPILES_ON_TPU is False
+    rng = np.random.default_rng(7)
+    q, k, v = (
+        jnp.asarray(rng.normal(size=(4, 2, 64, 32)), jnp.float32)
+        for _ in range(3)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(flash_attention(q, k, v, causal_offset=0)),
+        np.asarray(
+            flash_attention(q, k, v, causal_offset=0, use_pallas=True)
+        ),
+    )
 
 
 # -- the PPO hot-path ops at their hot-path shapes ----------------------
@@ -89,11 +93,8 @@ def test_auto_is_a_rule_not_a_probe():
 # (M, 84, 84, 1) uint8 = (M, 1764) uint32 lanes, the rebuild gathers
 # R = 4 * 2048 rows of it per nest; the fused lane's GAE scans
 # (16, 128) fragments on one chip and (4, 128) per shard on four.
-# Mosaic refuses both kernels (the messages are quoted in
-# ops/framestack.py and ops/gae.py): forcing one raises, auto runs
-# the XLA path, and the XLA path is what must be right on the chip.
-# When a jax release lifts a refusal the ``raises`` case fails — flip
-# that kernel's _COMPILES_ON_TPU and turn the case into a parity test.
+# These are XLA bodies, and the XLA body is what must be right on the
+# chip.
 
 POOL_D = 84 * 84 // 4
 ROWS = 2048
@@ -101,23 +102,6 @@ ROWS = 2048
 
 def _pool(rng, m):
     return rng.integers(0, 2**32, (m, POOL_D), dtype=np.uint32)
-
-
-def test_row_copy_kernels_are_refused_when_forced():
-    from ray_tpu.ops.framestack import gather_rows, scatter_rows
-
-    rng = np.random.default_rng(2)
-    src = jnp.asarray(_pool(rng, ROWS + 48))
-    idx = jnp.asarray(rng.integers(0, ROWS + 48, 4 * ROWS), jnp.int32)
-    with pytest.raises(ValueError, match="divisible by 8 and 128"):
-        jax.jit(lambda s, i: gather_rows(s, i, use_pallas=True))(
-            src, idx
-        )
-    pos = jnp.asarray(rng.permutation(ROWS + 48)[:ROWS], jnp.int32)
-    with pytest.raises(ValueError, match="divisible by 8 and 128"):
-        jax.jit(
-            lambda r, p, v: scatter_rows(r, p, v, use_pallas=True)
-        )(src, pos, src[:ROWS])
 
 
 def test_row_gather_scatter_auto_hot_shape_bitwise():
@@ -141,8 +125,8 @@ def test_row_gather_scatter_auto_hot_shape_bitwise():
 
 
 def test_build_stacks_auto_hot_shape_bitwise():
-    """The call the learn program makes (``use_pallas=None``), on
-    uint8 frames, against the host materialization."""
+    """The call the learn program makes, on uint8 frames, against the
+    host materialization."""
     from ray_tpu.ops.framestack import (
         build_stacks,
         materialize_stacks_np,
@@ -170,18 +154,9 @@ def _gae_inputs(shape):
     return r, v, nv, term, done
 
 
-def test_gae_scan_kernel_is_refused_when_forced():
-    from ray_tpu.ops.gae import compute_gae_fragment
-
-    with pytest.raises(Exception, match="multiple of 128"):
-        jax.jit(
-            lambda *x: compute_gae_fragment(*x, use_pallas=True)
-        )(*map(jnp.asarray, _gae_inputs((16, 128))))
-
-
 @pytest.mark.parametrize("shape", [(16, 128), (4, 128)])
 def test_gae_fragment_auto_hot_shape(shape):
-    """Auto (the associative scan) against the sequential float32
+    """The associative scan against the sequential float32
     recurrence it reassociates: the op's stated 1e-4 contract."""
     from ray_tpu.ops.gae import compute_gae_fragment
 
@@ -206,9 +181,8 @@ def test_gae_fragment_auto_hot_shape(shape):
 
 
 def test_device_sumtree_auto_runs_the_xla_descent():
-    """The f64 prefix descent can never compile through Mosaic, so
-    auto must not select (or probe) it: the device tree's draw runs
-    the XLA body on the chip and reproduces the host tree's draw."""
+    """The device tree's draw runs the XLA f64 descent on the chip
+    and reproduces the host tree's draw."""
     from ray_tpu.ops.segment_tree import DeviceSumTree, SumSegmentTree
 
     cap = 1024
@@ -217,7 +191,6 @@ def test_device_sumtree_auto_runs_the_xla_descent():
     host = SumSegmentTree(cap)
     host.set_items(np.arange(cap), leaves)
     dt = DeviceSumTree(cap)
-    assert dt.use_pallas is None
     dt.set_powered(np.arange(cap), leaves)
     rand = rng.random(64)
     want = np.clip(

@@ -43,10 +43,10 @@ class _MeshQuadratic(Trainable):
         def dist_sq_err(xs):
             return jax.shard_map(
                 lambda a: jax.lax.psum(
-                    ((a - 3.0) ** 2).sum(), "data"
+                    ((a - 3.0) ** 2).sum(), "batch"
                 ),
                 mesh=mesh,
-                in_specs=P("data"),
+                in_specs=P("batch"),
                 out_specs=P(),
             )(xs)
 
