@@ -294,8 +294,17 @@ class JaxRolloutEngine:
                     ep_ret = jnp.where(done, 0.0, ep_ret2)
                     ep_len = jnp.where(done, 0, ep_len2)
                 if stateful:
+                    # only a step on which some stream ended pays for
+                    # the pass over the state (a language model's is
+                    # hundreds of MB; an episode ends once in thousands
+                    # of steps)
                     with jax.named_scope("rollout/state_reset"):
-                        mstate2 = policy.reset_model_state(mstate2, done)
+                        mstate2 = jax.lax.cond(
+                            jnp.any(done),
+                            lambda s: policy.reset_model_state(s, done),
+                            lambda s: s,
+                            mstate2,
+                        )
                 return (
                     (env_state, obs_next, ep_ret, ep_len, mstate2),
                     (row, metrics),

@@ -292,12 +292,15 @@ class SequenceLM:
         ``stats_out`` receives the expert-load counts."""
         tokens = obs.reshape(obs.shape[0], -1).astype(jnp.int32)
         b, t = tokens.shape
-        if resets is None:
+        # the one-token form opens an episode before the token it
+        # consumes; without ``resets`` nothing is reset here (the
+        # rollout lane has reset the stream after its last step) and
+        # no pass over the state is made for it
+        if t == 1 and resets is not None:
+            state = self.reset_state(state, resets.reshape(b) > 0.5)
+        if t == 1 or resets is None:
             resets = jnp.zeros((b, t), jnp.float32)
         fresh = resets.reshape(b, t) > 0.5
-        if t == 1:
-            state = self.reset_state(state, fresh[:, 0])
-            fresh = jnp.zeros_like(fresh)
         seg = jnp.cumsum(fresh.astype(jnp.int32), axis=1)  # (B, T)
         steps = jnp.arange(t, dtype=jnp.int32)[None]
         opened = jax.lax.cummax(jnp.where(fresh, steps, -1), axis=1)

@@ -19,36 +19,161 @@ and a write strength ``beta_t`` in (0, 1)::
   program's form, whose backward pass keeps ``T / C`` states instead
   of ``T``.
 
-``resets`` (1.0 where a token begins a new episode) zero the state
-before that token. In the chunked form a reset splits its chunk into
-segments: products across a segment boundary are masked out, and the
-start state reaches only the tokens before the first reset.
+**The one-token form has two lowerings of one algorithm**, picked by
+what the code can see when it is traced, never by an option:
 
-Everything here is float32 at precision "highest": the state is an
-accumulator over the whole episode, and the PPO ratio divides what the
-chunked form says by what the recurrence said.
+- :func:`gated_delta_step_kernel`, a Pallas (Mosaic) kernel, where the
+  default backend is a TPU and ``dk`` and ``dv`` are whole 128-lane
+  tiles (:func:`_kernel_applies`). A block of heads of one stream sits
+  in VMEM while the four lines run on it, and the state's buffer is
+  updated in place (``input_output_aliases``): per step and layer the
+  state crosses HBM ONCE in and ONCE out, 8 bytes an element.
+- :func:`_delta_step_body`, the four lines in ``jax.numpy``, everywhere
+  else (the CPU, odd head sizes). It is the statement of the function
+  and the kernel's reference. XLA cannot put a reduction and the
+  elementwise op that consumes its result into one fusion, so on a TPU
+  this body reads every matrix three times and writes it once.
+
+``ray_tpu_deltanet_step_lowerings_total{path="kernel"|"xla"}`` counts,
+at trace time, which one each traced one-token form took.
+
+**Resets.** ``resets`` (1.0 where a token begins a new episode) zero
+the state before that token. In the chunked form a reset splits its
+chunk into segments: products across a segment boundary are masked
+out, and the start state reaches only the tokens before the first
+reset. The one-token form has no argument for it; its caller zeroes
+the rows first (``SequenceLM.reset_state``). On the rollout lane that
+is the lane, AFTER the step on which a stream's episode ended
+(``execution/jax_rollout.py``, under a ``lax.cond`` on "some stream
+ended"), so no full-state pass runs on a step where none did. (As a
+property of the function, ``g = -inf`` on a row clears its finite
+matrix before the write, ``exp(g) = 0``; nothing in the repo relies on
+it.)
+
+Everything here is float32 at precision "highest" (the kernel: float32
+multiply-adds on the VPU): the state is an accumulator over the whole
+episode, and the PPO ratio divides what the chunked form says by what
+the recurrence said.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.telemetry import metrics as telemetry_metrics
 
 _HI = jax.lax.Precision.HIGHEST
+
+# heads of one stream a grid step of the kernel holds in VMEM: 1 MB of
+# matrices in and out at 128 x 128 (on the v5e 8 heads a step were 4%
+# slower, 32 no faster)
+_KERNEL_HEADS = 16
 
 
 def gated_delta_step(state, q, k, v, g, beta):
     """One token of the recurrence. ``state`` ``(..., dk, dv)``; ``q``,
     ``k`` ``(..., dk)``; ``v`` ``(..., dv)``; ``g``, ``beta`` ``(...)``.
     Returns ``(state, o)`` with ``o`` ``(..., dv)``."""
+    if _kernel_applies(state):
+        telemetry_metrics.inc_deltanet_step_lowering("kernel")
+        return gated_delta_step_kernel(state, q, k, v, g, beta)
+    telemetry_metrics.inc_deltanet_step_lowering("xla")
+    return _delta_step_body(state, q, k, v, g, beta)
+
+
+def _delta_step_body(state, q, k, v, g, beta):
     state = state * jnp.exp(g)[..., None, None]
     read = jnp.einsum("...kv,...k->...v", state, k, precision=_HI)
     delta = beta[..., None] * (v - read)
     state = state + k[..., :, None] * delta[..., None, :]
     out = jnp.einsum("...kv,...k->...v", state, q, precision=_HI)
     return state, out
+
+
+def _kernel_applies(state) -> bool:
+    """The kernel's lowering exists for a TPU, for ``(streams, heads,
+    dk, dv)`` float32 with ``dk`` and ``dv`` whole 128-lane tiles and
+    the heads whole 8-sublane tiles (``k`` and ``q`` are turned from
+    lanes to sublanes a block of heads at a time). "A TPU" is the
+    process's default backend, as ``ops/flash_attention.py`` has it,
+    not the platform a computation is lowered for: a compile for a
+    described TPU from a CPU host (the tests' ``v5e_mesh``, a memory
+    budget taken ahead of time) sees the ``jax.numpy`` body and has to
+    call :func:`gated_delta_step_kernel` itself to see the kernel."""
+    if jax.default_backend() != "tpu" or state.ndim != 4:
+        return False
+    heads, dk, dv = state.shape[-3:]
+    return (
+        state.dtype == jnp.float32
+        and dk % 128 == 0 and dv % 128 == 0 and heads % 8 == 0
+    )
+
+
+def _delta_step_kernel(decay_ref, beta_ref, k_ref, q_ref, v_ref, s_ref,
+                       s_out_ref, o_ref):
+    """One stream, a block of heads. ``s_ref`` ``(1, H, dk, dv)``; the
+    other inputs ``(1, H, width)`` rows, ``decay`` and ``beta`` repeated
+    along the lanes. ``k`` and ``q`` arrive with ``dk`` on the lanes and
+    are turned once a block, so that a head's column broadcasts along
+    the lanes of its matrix."""
+    heads = s_ref.shape[1]
+    k_cols, q_cols = k_ref[0].T, q_ref[0].T  # (dk, H)
+    decay, beta, v = decay_ref[0], beta_ref[0], v_ref[0]
+    outs = []
+    for h in range(heads):
+        k_col, q_col = k_cols[:, h : h + 1], q_cols[:, h : h + 1]
+        s = s_ref[0, h] * decay[h : h + 1]
+        read = jnp.sum(s * k_col, axis=0, keepdims=True)
+        delta = beta[h : h + 1] * (v[h : h + 1] - read)
+        s = s + k_col * delta
+        s_out_ref[0, h] = s
+        outs.append(jnp.sum(s * q_col, axis=0, keepdims=True))
+    o_ref[0] = jnp.concatenate(outs, axis=0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gated_delta_step_kernel(state, q, k, v, g, beta, *, interpret=False):
+    """:func:`gated_delta_step` as one Pallas call over ``(streams,
+    heads / block)``: ``state`` ``(B, H, dk, dv)`` float32, updated in
+    place. ``interpret`` runs it in the Pallas interpreter (the CPU
+    tests); nothing upstream passes it. A ``jit`` of its own, so that
+    a program with many call sites (a lane's rollout has nine: three
+    layers in the act, the truncation's value forward and the tail's)
+    traces the kernel once and lowers it once: traced at every site
+    it cost a run of the sequence cell 7 s of set-up."""
+    from ray_tpu import sharding as sharding_lib
+
+    b, h, dk, dv = state.shape
+    heads = _KERNEL_HEADS if h % _KERNEL_HEADS == 0 else 8
+    decay = jnp.broadcast_to(jnp.exp(g)[..., None], (b, h, dv))
+    beta = jnp.broadcast_to(beta[..., None], (b, h, dv))
+    # inside a ``shard_map`` the outputs vary over the mesh axes the
+    # inputs do
+    vma = sharding_lib.vma_of((state, q, k, v, decay, beta))
+    rows = lambda width: pl.BlockSpec((1, heads, width), lambda i, j: (i, j, 0))
+    matrices = pl.BlockSpec((1, heads, dk, dv), lambda i, j: (i, j, 0, 0))
+    return pl.pallas_call(
+        _delta_step_kernel,
+        grid=(b, h // heads),
+        in_specs=[rows(dv), rows(dv), rows(dk), rows(dk), rows(dv), matrices],
+        out_specs=[matrices, rows(dv)],
+        out_shape=[
+            jax.ShapeDtypeStruct(state.shape, state.dtype, vma=vma),
+            jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma),
+        ],
+        input_output_aliases={5: 0},
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")
+        ),
+        name="gated_delta_step",
+    )(decay, beta, k, q, v, state)
 
 
 def _unit_lower_inverse(a):

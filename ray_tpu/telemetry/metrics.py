@@ -105,6 +105,11 @@ SUPERSTEP_UPDATES_TOTAL = "ray_tpu_superstep_updates_total"
 # the (token, slot) pairs that fell on experts held elsewhere
 MOE_HELD_EXPERT_TOKENS_TOTAL = "ray_tpu_moe_held_expert_tokens_total"
 MOE_ABSENT_SLOTS_TOTAL = "ray_tpu_moe_absent_slots_total"
+# which lowering each traced one-token gated-delta step took
+# (ops/deltanet.py): path = kernel (the Pallas kernel: a TPU, whole
+# tiles) | xla (the jax.numpy body). Counted when the form is traced,
+# once per DeltaNet layer of a traced program
+DELTANET_STEP_LOWERINGS_TOTAL = "ray_tpu_deltanet_step_lowerings_total"
 # prioritized-replay segment-tree operations by op and by which tree
 # implementation performed them (docs/data_plane.md "device sum
 # tree"): host = the numpy SumSegmentTree walk, device = the
@@ -505,6 +510,24 @@ def expert_load_totals() -> Dict[str, float]:
     out = {dict(tags).get("stat", ""): v for tags, v in m.series()}
     out["absent_slots"] = counter_total(MOE_ABSENT_SLOTS_TOTAL)
     return out
+
+
+def inc_deltanet_step_lowering(path: str) -> None:
+    """One traced one-token gated-delta step took ``path`` (``kernel``
+    | ``xla``): ops/deltanet.py picks from platform and shape."""
+    counter(
+        DELTANET_STEP_LOWERINGS_TOTAL,
+        "one-token gated-delta steps traced, by the lowering they took",
+        ("path",),
+    ).inc(1.0, {"path": path})
+
+
+def deltanet_step_lowerings() -> Dict[str, float]:
+    """``{path: traced one-token steps}`` since the process began."""
+    m = get_metric(DELTANET_STEP_LOWERINGS_TOTAL)
+    if m is None:
+        return {}
+    return {dict(tags).get("path", ""): v for tags, v in m.series()}
 
 
 def inc_env_steps_on_device(n: int) -> None:

@@ -244,3 +244,97 @@ def test_model_counts_travel_with_the_loss_and_nothing_stays_on_the_policy():
         "moe_slots_on_absent_experts",
     }
     assert set(pol.__dict__) == attrs
+
+
+# -- the lane's contract around an episode's end --------------------------
+
+def _contract_engine():
+    """A small sequence model on the standalone lane: 8 streams x 16
+    steps in two unrolls of 8, episodes of 24 with stream ``i`` starting
+    ``4 i`` tokens in, so streams 4 and 5 end inside the fragment and
+    stream 2 on its LAST step (the reset shows in the carry)."""
+    from ray_tpu.env.jax_tokens import TokenStreamJax
+
+    env = TokenStreamJax({"vocab_size": 32, "episode_length": 24, "phase_stride": 4})
+    cfg = _lstm_cfg(rollout_fragment_length=16, train_batch_size=128)
+    cfg["model"] = {"use_sequence_lm": True, "sequence_lm": LM,
+                    "max_seq_len": 8, "dtype": "float32"}
+    pol = _policy(env, cfg)
+    return JaxRolloutEngine(pol, env, 8, 16, seed=5, standardize_advantages=False)
+
+
+def _contract_arrays():
+    eng = _contract_engine()
+    batch, _ = eng.rollout()
+    out = {f"carry_{i}": leaf for i, leaf in enumerate(
+        jax.tree_util.tree_leaves(jax.device_get(eng._carry)))}
+    for name in ("resets", "actions", "action_dist_inputs", "vf_preds", "t"):
+        out[name] = batch[name]
+    out.update({k: v for k, v in batch.items() if k.startswith("__chunk__state_in_")})
+    return {k: np.asarray(v) for k, v in out.items()}
+
+
+def test_lane_contract_around_an_episode_end_equals_the_plain_reset(monkeypatch):
+    """The carry, the start states handed to the learner, the stored
+    ``resets``, logits and values of a fragment in which streams end
+    (one on the last step) equal, to the bit, what the lane gives when
+    every step resets by a plain ``where`` (the reference: the one
+    ``cond`` of the step that carries the state as its operand takes
+    its true branch when it is traced)."""
+    got = _contract_arrays()
+    real, plain = jax.lax.cond, []
+
+    def cond(pred, true_fn, false_fn, *operands):
+        if not operands:
+            return real(pred, true_fn, false_fn)
+        plain.append(operands)
+        return true_fn(*operands)
+
+    monkeypatch.setattr(jax.lax, "cond", cond)
+    want = _contract_arrays()
+    monkeypatch.undo()
+    (state,), = plain  # the reset, traced once, and nothing else
+    assert len(state) == len(_contract_engine()._carry["state"])
+    assert set(got) == set(want)
+    ended = got["t"].reshape(8, 16)[:, 1:] == 0
+    assert ended.any() and got["carry_0"].shape[0] == 8
+    for name in sorted(got):
+        assert got[name].dtype == want[name].dtype, name
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
+def _eqns_outside_conds(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "cond":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns_outside_conds(sub)
+
+
+def test_no_select_over_the_deltanet_state_outside_a_cond():
+    """A step on which no stream ends runs no pass over the DeltaNet
+    matrices for the reset: in the lane's program every ``select`` of a
+    matrix's shape sits inside a ``cond`` (the act hands the model no
+    ``resets``, so the model resets nothing; the lane resets under
+    ``any(done)``)."""
+    eng = _contract_engine()
+    pol = eng.policy
+    keys = pol._rollout_keys(eng.T)
+    jaxpr = jax.make_jaxpr(eng._rollout_program()._jitted)(
+        pol.params, eng._carry, keys, eng._pre_dispatch()
+    )
+    matrix = tuple(eng._carry["state"][0].shape)
+    assert len(matrix) == 4
+    selects = [e for e in _eqns_outside_conds(jaxpr.jaxpr)
+               if e.primitive.name == "select_n"]
+    assert selects  # the env's and the small leaves' are there
+    assert not [e for e in selects if tuple(e.outvars[0].aval.shape) == matrix]
+    conds = [e for e in _eqns_outside_conds(jaxpr.jaxpr) if e.primitive.name == "cond"]
+    inside = [
+        e for c in conds for b in c.params["branches"]
+        for e in _eqns_outside_conds(b.jaxpr)
+        if e.primitive.name == "select_n"
+        and tuple(e.outvars[0].aval.shape) == matrix
+    ]
+    assert len(inside) == 3  # the lane's reset of the three DeltaNet layers

@@ -270,3 +270,48 @@ def test_v5e_superstep_gather_reads_rows(
     )
     assert _ring_copies(compiled, capacity) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_v5e_delta_step_kernel_compiles_in_place(v5e_mesh):
+    """The one-token gated-delta kernel (ops/deltanet.py) at the
+    sequence cell's width, 64 streams x 32 heads of 128 x 128, chained
+    in a scan under ``shard_map`` like the lane's decode steps (the
+    call has to say how its outputs vary over the mesh): Mosaic takes
+    it, and the 0.13 GB of matrices are written where they lie (no
+    second copy, no scratch)."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.ops import deltanet
+
+    b, h, dk, dv = 64, 32, 128, 128
+    axis = sharding_lib.data_axis(v5e_mesh)
+    rows = sharding_lib.batch_sharded(v5e_mesh)
+    on = lambda *shape: jax.ShapeDtypeStruct(shape, np.float32, sharding=rows)
+
+    def steps(state, q, k, v, g, beta):
+        def one(s, _):
+            with jax.named_scope("rollout/act/linear_attn"):
+                return deltanet.gated_delta_step_kernel(s, q, k, v, g, beta)
+
+        return jax.lax.scan(one, state, None, length=2)
+
+    sharded = jax.shard_map(
+        steps, mesh=v5e_mesh, in_specs=(P(axis),) * 6,
+        out_specs=(P(axis), P(None, axis)),
+    )
+    compiled = (
+        jax.jit(sharded, donate_argnums=(0,))
+        .lower(on(b, h, dk, dv), on(b, h, dk), on(b, h, dk), on(b, h, dv),
+               on(b, h), on(b, h))
+        .compile()
+    )
+    calls = [
+        line for line in compiled.as_text().splitlines()
+        if "tpu_custom_call" in line and "custom-call(" in line
+    ]
+    # the device op carries the caller's scope (the trace files its
+    # time under it) though the kernel is a jit of its own
+    assert calls and all("rollout/act/linear_attn" in line for line in calls)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == b * h * dk * dv * 4
+    assert mem.temp_size_in_bytes < 16e6

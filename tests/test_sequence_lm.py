@@ -199,7 +199,12 @@ def test_recurrence_matches_chunked_form_over_two_fragments(setup):
     assert np.array_equal(np.asarray(chunked[-1]), np.asarray(state[-1]))
 
 
-def test_delta_rule_chunked_equals_recurrence_with_resets():
+@pytest.mark.parametrize("reset_by", ["where", "decay"])
+def test_delta_rule_chunked_equals_recurrence_with_resets(reset_by):
+    """The recurrence with its state zeroed before a token that opens
+    an episode, by a ``where`` (what the model's ``reset_state`` does)
+    or by the step's own decay (``g = -inf``), against the chunked
+    form."""
     rng = np.random.default_rng(5)
     b, t, h, dk, dv = 2, 24, 3, 8, 4
     q, k = (rng.standard_normal((b, t, h, dk)).astype(np.float32) for _ in range(2))
@@ -212,8 +217,12 @@ def test_delta_rule_chunked_equals_recurrence_with_resets():
     s0 = rng.standard_normal((b, h, dk, dv)).astype(np.float32)
     s, want = jnp.asarray(s0), []
     for i in range(t):
-        s = jnp.where(resets[:, i, None, None, None] > 0.5, 0.0, s)
-        s, o = deltanet.gated_delta_step(s, q[:, i], k[:, i], v[:, i], g[:, i], beta[:, i])
+        gi = g[:, i]
+        if reset_by == "where":
+            s = jnp.where(resets[:, i, None, None, None] > 0.5, 0.0, s)
+        else:
+            gi = np.where(resets[:, i, None] > 0.5, -np.inf, gi)
+        s, o = deltanet.gated_delta_step(s, q[:, i], k[:, i], v[:, i], gi, beta[:, i])
         want.append(o)
     got, s_end = deltanet.gated_delta_chunked(
         jnp.asarray(s0), q, k, v, g, beta, resets=jnp.asarray(resets), chunk=8
