@@ -1,47 +1,86 @@
-"""A decoder language model composed from a ``layer_types`` pattern, as
-the policy of a token-level RL problem: observation = the last token
-id, action = the next token, a value head beside the output head.
+"""A decoder language model composed from per-layer kinds, as the policy
+of a token-level RL problem: observation = the last token id, action =
+the next token, a value head beside the output head. ``config`` carries
+the key names of the published ``config.json`` each kind comes from.
 
-Layer kinds (equations as in Hugging Face ``qwen3_next``; the keys of
-``config`` carry that file's names):
+A block is two sublayers, a MIXER and a FEED-FORWARD, each with its own
+zero-centred RMSNorm ``rms(x) = x * rsqrt(mean(x^2) + eps) * (1 + w)``,
+each applied through the block's RESIDUAL kind.
 
-- every block: ``h = x + mixer(rms(x))``, ``y = h + moe(rms(h))`` with a
-  zero-centred RMSNorm, ``x * rsqrt(mean(x^2) + eps) * (1 + w)``;
-- ``"full_attention"``: gated softmax attention. One projection gives
-  each head its query and an output gate, ``k`` and ``v`` come for
-  fewer KV heads (GQA), ``q`` and ``k`` are RMS-normed over the head,
-  RoPE turns the first ``partial_rotary_factor`` of the head, and the
+Mixer kinds (``layer_types``; every ``full_attention_interval``-th
+layer full attention where it is not stated; all latent where the
+config has ``kv_lora_rank``):
+
+- ``"full_attention"`` (Hugging Face ``qwen3_next``,
+  ``Qwen3NextAttention``): gated softmax attention. One projection gives
+  each head its query and an output gate, ``k`` and ``v`` come for fewer
+  KV heads (GQA), ``q`` and ``k`` are RMS-normed over the head, RoPE
+  turns the first ``partial_rotary_factor`` of the head, and the
   attention output is multiplied by ``sigmoid(gate)`` before the output
   projection. No biases;
-- ``"linear_attention"``: Gated DeltaNet. One projection gives ``q, k,
-  v, z``, another ``b, a``; a causal depthwise convolution (width
-  ``linear_conv_kernel_dim``) and SiLU over the channels of ``(q, k,
-  v)``; ``beta = sigmoid(b)``, ``g = -exp(A_log) * softplus(a +
-  dt_bias)``; ``q`` and ``k`` L2-normalised; the gated delta rule
-  (``ops/deltanet.py``) per value head; ``rms(o) * w * silu(z)`` per
-  head, then the output projection;
-- the feed-forward of every block: a router over ALL ``router_outputs``
-  experts (softmax, top-k, renormalised), the experts this chip HOLDS
-  (``experts_held``: ``[first, count]``; ``ops/moe.py``), and a shared
-  expert times ``sigmoid(x w_s)``.
+- ``"linear_attention"`` (``Qwen3NextGatedDeltaNet``; Yang et al.,
+  arXiv:2412.06464): one projection gives ``q, k, v, z``, another ``b,
+  a``; a causal depthwise convolution (width ``linear_conv_kernel_dim``)
+  and SiLU over the channels of ``(q, k, v)``; ``beta = sigmoid(b)``,
+  ``g = -exp(A_log) * softplus(a + dt_bias)``; ``q`` and ``k``
+  L2-normalised; the gated delta rule (``ops/deltanet.py``) per value
+  head; ``rms(o) * w * silu(z)`` per head, then the output projection;
+- ``"latent_attention"`` (DeepSeek-V3's ``DeepseekV3Attention``,
+  arXiv:2412.19437, with YaRN as that file applies it): ``c_q = rms(x
+  W_qa)``, ``q = c_q W_qb`` per head ``[q_nope | q_pe]``; ``[c_kv |
+  k_pe] = x W_kva``, ``c_kv = rms(c_kv)``, one roped ``k_pe`` for all
+  heads; ``[k_nope | v]`` per head ``= c_kv W_kvb``; ``score = (q_nope .
+  k_nope + rope(q_pe) . k_pe) * s``, ``s = (nope + rope)^-1/2 * m^2``,
+  causal softmax, ``o = P v``, output projection. The query/key product
+  is ``nope + rope`` wide, the value product ``v_head_dim``
+  (``ops/latent_attention.py`` holds both forms).
+
+Feed-forward kinds (``"dense"`` for the first ``first_k_dense_replace``
+layers, ``"experts"`` after):
+
+- ``"dense"``: SwiGLU of width ``intermediate_size``;
+- ``"experts"``: a router over ALL ``router_outputs`` experts, the
+  experts this chip HOLDS (``experts_held``: ``[first, count]``;
+  ``ops/moe.py``) and a shared expert. ``qwen3_next``: softmax, top-k,
+  renormalised, the shared expert times ``sigmoid(x w_s)``. DeepSeek-V3
+  (``scoring_func: sigmoid``, ``topk_method: noaux_tc``): a sigmoid each,
+  the top-k of ``score + select_bias`` chosen, weights the scores
+  without the bias over their sum times ``routed_scaling_factor``, the
+  shared expert ungated. ``select_bias`` is a buffer: it lies in the
+  parameter tree and the model reads it through ``stop_gradient``.
+
+Residual kinds:
+
+- ``"plain"``: ``x <- x + F(rms(x))``;
+- ``"hyper_connection"`` (``hc_mult`` lanes; manifold-constrained
+  hyper-connections, arXiv:2512.24880; ``ops/hyper_connection.py``):
+  the stream is ``hc_mult`` lanes, ``X <- H_res X + H_post^T F(rms(H_pre
+  X))`` with the maps made from the token's own stream and ``H_res``
+  through ``hc_sinkhorn_iters`` Sinkhorn rounds. The embedding is
+  copied into the lanes and the lanes are summed before the final norm.
 
 State (``initial_state``; one row per stream, a flat tuple): for each
 linear layer the ``(value heads, dk, dv)`` float32 DeltaNet matrix and
 the last ``conv - 1`` inputs of the convolution; for each full layer
 the keys and values of the episode so far (bfloat16, ``(positions,
-kv heads x head)``, keys stored after norm and RoPE); last, the stream's
-position. ``apply`` has two forms that are the same function of the
-same weights: ``T == 1`` is the recurrence (one token, state in and
-out: the rollout lane's step), ``T > 1`` runs a fragment from a stored
-start state (DeltaNet in chunks, attention over the stored keys plus
-the fragment's own, ``resets`` opening a new episode inside it: the
-learn program's form).
+kv heads x head)``, keys stored after norm and RoPE); for each latent
+layer ONE leaf of latent rows (bfloat16, ``(positions, kv_lora_rank +
+qk_rope_head_dim)``: the normed latent and the roped key part, whatever
+the head count); last, the stream's position. ``apply`` has two forms
+that are the same function of the same weights: ``T == 1`` is the
+recurrence (one token, state in and out: the rollout lane's step; for
+latent attention the ABSORBED product against the latent rows), ``T >
+1`` runs a fragment from a stored start state (DeltaNet in chunks,
+attention over the stored keys plus the fragment's own, latent
+attention EXPANDED through ``W_kvb`` a block of streams at a time,
+``resets`` opening a new episode inside it: the learn program's form).
 
 Precision: float32 parameters; the projections, expert products, the
 head and the attention products take bfloat16 operands and accumulate
 in float32; the router, softmax, top-k, ``g``, ``beta``, the DeltaNet
-state and every norm are float32 (the router and the delta rule at
-precision "highest").
+state, the hyper-connection maps and mixes and every norm are float32
+(the router, the maps' projection and the delta rule at precision
+"highest").
 
 Not a flax module (cf. ``models/transformer.py``): plain-dict params,
 two levels deep, ``{"layer_0": {"in_proj_qkvz": ...}, ...}``.
@@ -55,26 +94,35 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops import deltanet, moe
+from ray_tpu.ops import deltanet, hyper_connection, latent_attention, moe
 
 _HI = jax.lax.Precision.HIGHEST
 
-LINEAR, FULL = "linear_attention", "full_attention"
+LINEAR, FULL, LATENT = "linear_attention", "full_attention", "latent_attention"
+DENSE, EXPERTS = "dense", "experts"
+PLAIN, HYPER = "plain", "hyper_connection"
 
-# envs of a fragment whose attention scores are alive at once
+# envs of a fragment whose attention scores are alive at once; for
+# latent attention, whose keys and values of 32 heads are rebuilt for
+# the block as well (0.27 GB for 8 streams, and as much again for
+# their cotangents)
 _ATTN_ENV_BLOCK = 8
+_LATENT_ENV_BLOCK = 4
+# state leaves a layer of each mixer kind holds
+_STATE_LEAVES = {LINEAR: 2, FULL: 2, LATENT: 1}
 
 
 def layer_types_of(config: Dict) -> Tuple[str, ...]:
-    """The pattern: ``layer_types`` if stated, else every
+    """The pattern: ``layer_types`` if stated; all latent attention
+    where the config has a ``kv_lora_rank``; else every
     ``full_attention_interval``-th layer is full attention."""
     if config.get("layer_types"):
         return tuple(config["layer_types"])
+    layers = int(config["num_hidden_layers"])
+    if "kv_lora_rank" in config:
+        return (LATENT,) * layers
     every = int(config.get("full_attention_interval", 4))
-    return tuple(
-        FULL if (i + 1) % every == 0 else LINEAR
-        for i in range(int(config["num_hidden_layers"]))
-    )
+    return tuple(FULL if (i + 1) % every == 0 else LINEAR for i in range(layers))
 
 
 def _rms(x, weight, eps, centred=True):
@@ -124,34 +172,74 @@ class SequenceLM:
         self.vocab = int(num_outputs)
         self.hidden = int(c["hidden_size"])
         self.layer_types = layer_types_of(c)
+        dense_first = int(c.get("first_k_dense_replace", 0))
+        self.ffn_types = tuple(
+            DENSE if i < dense_first else EXPERTS
+            for i in range(len(self.layer_types))
+        )
+        self.lanes = int(c.get("hc_mult", 1))
+        self.residual = HYPER if self.lanes > 1 else PLAIN
         self.eps = float(c.get("rms_norm_eps", 1e-6))
-        # gated attention
         self.heads = int(c["num_attention_heads"])
-        self.kv_heads = int(c["num_key_value_heads"])
-        self.head_dim = int(c["head_dim"])
-        self.rotary = int(self.head_dim * float(c.get("partial_rotary_factor", 1.0)))
         self.theta = float(c.get("rope_theta", 10000.0))
         self.positions = int(c["max_position_embeddings"])
-        # gated deltanet
-        self.k_heads = int(c["linear_num_key_heads"])
-        self.v_heads = int(c["linear_num_value_heads"])
-        self.dk = int(c["linear_key_head_dim"])
-        self.dv = int(c["linear_value_head_dim"])
-        self.conv = int(c["linear_conv_kernel_dim"])
+        if FULL in self.layer_types:  # gated attention
+            self.kv_heads = int(c["num_key_value_heads"])
+            self.head_dim = int(c["head_dim"])
+            self.rotary = int(
+                self.head_dim * float(c.get("partial_rotary_factor", 1.0)))
+        if LINEAR in self.layer_types:  # gated deltanet
+            self.k_heads = int(c["linear_num_key_heads"])
+            self.v_heads = int(c["linear_num_value_heads"])
+            self.dk = int(c["linear_key_head_dim"])
+            self.dv = int(c["linear_value_head_dim"])
+            self.conv = int(c["linear_conv_kernel_dim"])
+            self.key_dim = self.k_heads * self.dk
+            self.value_dim = self.v_heads * self.dv
+            self.conv_dim = 2 * self.key_dim + self.value_dim
+        if LATENT in self.layer_types:  # latent attention
+            self.q_latent = int(c["q_lora_rank"])
+            self.kv_latent = int(c["kv_lora_rank"])
+            self.nope = int(c["qk_nope_head_dim"])
+            self.rope_dim = int(c["qk_rope_head_dim"])
+            self.v_head = int(c["v_head_dim"])
+            self.latent_row = self.kv_latent + self.rope_dim
+            scaling = c.get("rope_scaling")
+            self.inv_freq = latent_attention.yarn_inv_freq(
+                self.rope_dim, self.theta, scaling)
+            self.softmax_scale = latent_attention.yarn_softmax_scale(
+                self.nope + self.rope_dim, scaling)
+        if self.residual == HYPER:
+            # streams of a group of ``loss_groups``: a token's rows are
+            # ``hc_mult`` times as wide
+            self.learn_streams = 8
+            self.hc_rounds = int(c.get("hc_sinkhorn_iters", 20))
+            self.hc_eps = float(c.get("hc_eps", 1e-6))
+            self.hc_clamp = (float(c.get("mhc_h_res_clamp_min", -30.0)),
+                             float(c.get("mhc_h_res_clamp_max", 30.0)))
+        if DENSE in self.ffn_types:
+            self.dense_width = int(c["intermediate_size"])
         # experts: the router scores all of them, this chip holds some
-        self.router_outputs = int(c.get("router_outputs", c["num_experts"]))
-        first, count = c.get("experts_held") or (0, int(c["num_experts"]))
+        experts = int(c["num_experts"] if "num_experts" in c else c["n_routed_experts"])
+        self.router_outputs = int(c.get("router_outputs", experts))
+        first, count = c.get("experts_held") or (0, experts)
         self.first_expert, self.experts_held = int(first), int(count)
         self.top_k = int(c["num_experts_per_tok"])
         self.norm_topk = bool(c.get("norm_topk_prob", True))
+        self.scoring = str(c.get("scoring_func", "softmax"))
+        self.route_scale = float(c.get("routed_scaling_factor", 1.0))
+        self.select_bias = c.get("topk_method") == "noaux_tc"
         self.expert_width = int(c["moe_intermediate_size"])
-        self.shared_width = int(c["shared_expert_intermediate_size"])
+        # the shared expert: ``qwen3_next`` states its width and gates
+        # it; DeepSeek-V3 counts shared experts of the routed width
+        self.shared_gated = "shared_expert_intermediate_size" in c
+        self.shared_width = int(
+            c["shared_expert_intermediate_size"] if self.shared_gated
+            else int(c.get("n_shared_experts", 1)) * self.expert_width
+        )
         # operands of the projections, the expert products, the head
         # and the attention products; the cache's dtype
         self.dtype = jnp.dtype(dtype)
-        self.key_dim = self.k_heads * self.dk
-        self.value_dim = self.v_heads * self.dv
-        self.conv_dim = 2 * self.key_dim + self.value_dim
 
     def partition_rules(self):
         return None
@@ -164,6 +252,10 @@ class SequenceLM:
 
     # -- state -----------------------------------------------------------
 
+    def _layer_state(self, state, n: int):
+        lo = sum(_STATE_LEAVES[k] for k in self.layer_types[:n])
+        return tuple(state[lo : lo + _STATE_LEAVES[self.layer_types[n]]])
+
     def initial_state(self, batch_size: int = 1):
         b = int(batch_size)
         state = []
@@ -175,23 +267,27 @@ class SequenceLM:
                 state.append(
                     jnp.zeros((b, self.conv - 1, self.conv_dim), jnp.float32)
                 )
-            else:
+            elif kind == FULL:
                 # one row a position: kv heads x head, flat, so that the
                 # device tiles (positions, row) without padding 2 heads to 8
                 shape = (b, self.positions, self.kv_heads * self.head_dim)
                 state.append(jnp.zeros(shape, self.dtype))
                 state.append(jnp.zeros(shape, self.dtype))
+            else:
+                # one latent row a position, whatever the head count
+                state.append(
+                    jnp.zeros((b, self.positions, self.latent_row), self.dtype))
         state.append(jnp.zeros((b,), jnp.int32))
         return tuple(state)
 
     def reset_state(self, state, mask):
         """Open a new episode on the rows of ``mask``: the DeltaNet
         matrices, the convolution inputs and the position go to zero;
-        a key/value cache is left as it is, since only slots below the
-        position are ever read."""
+        a key/value or latent cache is left as it is, since only slots
+        below the position are ever read."""
         out = []
         for n, kind in enumerate(self.layer_types):
-            for leaf in state[2 * n : 2 * n + 2]:
+            for leaf in self._layer_state(state, n):
                 if kind == LINEAR:
                     m = mask.reshape((-1,) + (1,) * (leaf.ndim - 1))
                     leaf = jnp.where(m, jnp.zeros_like(leaf), leaf)
@@ -210,19 +306,32 @@ class SequenceLM:
             "head": {"kernel": (d, v)},
             "value": {"kernel": (d, 1), "bias": (1,)},
         }
-        for i, kind in enumerate(self.layer_types):
-            layer = {
-                "input_norm": (d,),
-                "post_norm": (d,),
-                "router": (d, self.router_outputs),
-                "experts_gate": (e, d, f),
-                "experts_up": (e, d, f),
-                "experts_down": (e, f, d),
-                "shared_gate": (d, fs),
-                "shared_up": (d, fs),
-                "shared_down": (fs, d),
-                "shared_expert_gate": (d, 1),
-            }
+        for i, (kind, ffn) in enumerate(zip(self.layer_types, self.ffn_types)):
+            layer = {"input_norm": (d,), "post_norm": (d,)}
+            if ffn == EXPERTS:
+                layer.update(
+                    router=(d, self.router_outputs),
+                    experts_gate=(e, d, f),
+                    experts_up=(e, d, f),
+                    experts_down=(e, f, d),
+                    shared_gate=(d, fs),
+                    shared_up=(d, fs),
+                    shared_down=(fs, d),
+                )
+                if self.shared_gated:
+                    layer["shared_expert_gate"] = (d, 1)
+                if self.select_bias:
+                    layer["select_bias"] = (self.router_outputs,)
+            else:
+                w = self.dense_width
+                layer.update(mlp_gate=(d, w), mlp_up=(d, w), mlp_down=(w, d))
+            if self.residual == HYPER:
+                n = self.lanes
+                for sub in ("mixer", "ffn"):
+                    layer[f"hc_{sub}_norm"] = (n * d,)
+                    layer[f"hc_{sub}_phi"] = (n * d, 2 * n + n * n)
+                    layer[f"hc_{sub}_a"] = (3,)
+                    layer[f"hc_{sub}_b"] = (2 * n + n * n,)
             if kind == LINEAR:
                 layer.update(
                     in_proj_qkvz=(d, 2 * self.key_dim + 2 * self.value_dim),
@@ -233,7 +342,7 @@ class SequenceLM:
                     gdn_norm=(self.dv,),
                     out_proj=(self.value_dim, d),
                 )
-            else:
+            elif kind == FULL:
                 layer.update(
                     q_proj=(d, self.heads * self.head_dim * 2),
                     k_proj=(d, self.kv_heads * self.head_dim),
@@ -242,6 +351,17 @@ class SequenceLM:
                     q_norm=(self.head_dim,),
                     k_norm=(self.head_dim,),
                 )
+            else:
+                h = self.heads
+                layer.update(
+                    q_a=(d, self.q_latent),
+                    q_a_norm=(self.q_latent,),
+                    q_b=(self.q_latent, h * (self.nope + self.rope_dim)),
+                    kv_a=(d, self.latent_row),
+                    kv_a_norm=(self.kv_latent,),
+                    kv_b=(self.kv_latent, h * (self.nope + self.v_head)),
+                    o_proj=(h * self.v_head, d),
+                )
             shapes[f"layer_{i}"] = layer
         return shapes
 
@@ -249,8 +369,11 @@ class SequenceLM:
         """Normal matrices of variance 1 / rows, zero-centred norm
         weights at zero, ``A`` uniform in (1, 16), ``dt_bias`` one: the
         published initialisation's forms at a scale that keeps the
-        activations of a random model of order one."""
+        activations of a random model of order one. A hyper-connection
+        starts near the plain residual (``a`` 0.01, ``b_res`` twice the
+        identity), a selection bias small and not zero."""
         shapes = self.param_shapes()
+        n = self.lanes
 
         @jax.jit
         def make(key):
@@ -260,16 +383,24 @@ class SequenceLM:
                 jnp.tile(jax.random.key_data(key).astype(jnp.uint32).ravel(), 2)[:4],
                 impl="rbg",
             )
-            out, n = {}, 0
+            out, count = {}, 0
             for group in sorted(shapes):
                 out[group] = {}
                 for leaf, shape in sorted(shapes[group].items()):
-                    k = jax.random.fold_in(key, n)
-                    n += 1
+                    k = jax.random.fold_in(key, count)
+                    count += 1
                     if leaf == "A_log":
                         x = jnp.log(jax.random.uniform(k, shape, minval=1.0, maxval=16.0))
                     elif leaf in ("dt_bias", "gdn_norm"):
                         x = jnp.ones(shape, jnp.float32)
+                    elif leaf.startswith("hc_") and leaf.endswith("_a"):
+                        x = jnp.full(shape, 0.01, jnp.float32)
+                    elif leaf.startswith("hc_") and leaf.endswith("_b"):
+                        x = jnp.concatenate(
+                            [jnp.zeros((2 * n,)), 2.0 * jnp.eye(n).ravel()]
+                        ).astype(jnp.float32)
+                    elif leaf == "select_bias":
+                        x = 0.01 * jax.random.normal(k, shape, jnp.float32)
                     elif len(shape) == 1:
                         x = jnp.zeros(shape, jnp.float32)
                     elif leaf == "embedding":
@@ -280,6 +411,41 @@ class SequenceLM:
             return out
 
         return make(rng)
+
+    # -- the learn form's grouping ---------------------------------------
+
+    def loss_groups(self, unrolls: int) -> Optional[int]:
+        """Groups of unrolls the learn program takes through the WHOLE
+        stack, the loss and the backward pass one at a time, its
+        gradient accumulated (``JaxPolicy`` asks). ``None`` for the
+        plain residual, whose learn form groups the streams inside
+        each block instead (``apply``): there a block's saved input is
+        one hidden row a token and the batch's fit. With ``hc_mult``
+        lanes a token's saved row is ``hc_mult`` times as wide and the
+        batch's no longer fit beside the weights, so only one group's
+        are alive."""
+        if self.residual == PLAIN:
+            return None
+        s = self.learn_streams
+        return unrolls // s if unrolls > s and unrolls % s == 0 else 1
+
+    def reduce_group_stats(self, stats: Dict) -> Dict:
+        """``stats`` stacked over the groups of ``loss_groups`` -> one
+        update's: loads add up over groups before their mean and max
+        are taken, an error's largest is kept, the rest is averaged."""
+        out = {}
+        for k, v in stats.items():
+            if k == "moe_held_load":
+                load = v.sum(0)  # (expert layers, held)
+                out["moe_tokens_per_held_expert"] = jnp.mean(load)
+                out["moe_max_tokens_per_held_expert"] = jnp.max(load)
+            elif k == "moe_slots_on_absent_experts":
+                out[k] = v.sum(0)
+            elif k.endswith("_err_max"):
+                out[k] = v.max(0)
+            else:
+                out[k] = v.mean(0)
+        return out
 
     # -- forward ---------------------------------------------------------
 
@@ -310,54 +476,74 @@ class SequenceLM:
             "seg": seg, "fresh": fresh, "positions": positions, "pos0": pos0,
         }
         prefix = (scope + "/") if scope else ""
+        hyper = self.residual == HYPER
 
         x = jnp.take(params["embed"]["embedding"], tokens, axis=0)  # (B, T, D)
+        if hyper:  # the embedding copied into every lane, held flat
+            x = jnp.tile(x, (1, 1, self.lanes))
 
-        def block(x, p, layer_state, rows, kind):
+        def block(x, p, layer_state, rows, kind, ffn_kind):
             ctx = dict(rows, scope=prefix)
-            mixer = self._linear_attn if kind == LINEAR else self._attn
+            mixer = {
+                LINEAR: self._linear_attn, FULL: self._attn,
+                LATENT: self._latent_attn,
+            }[kind]
+            ffn = self._moe if ffn_kind == EXPERTS else self._mlp
+            if hyper:
+                return self._hyper_block(x, p, layer_state, ctx, mixer, ffn)
             y, new = mixer(p, _rms(x, p["input_norm"], self.eps), layer_state, ctx)
             x = x + y
-            y, load, routes = self._moe(p, _rms(x, p["post_norm"], self.eps), ctx)
+            y, load, routes = ffn(p, _rms(x, p["post_norm"], self.eps), ctx)
             return x + y, new, load, routes
 
+        # the plain residual groups the streams inside each block; with
+        # lanes the policy groups them around the whole loss instead
+        # (``loss_groups``)
         groups = b // self.learn_streams if (
-            t > 1 and b > self.learn_streams and b % self.learn_streams == 0
+            not hyper
+            and t > 1 and b > self.learn_streams and b % self.learn_streams == 0
         ) else 1
         # the learn form keeps a block's input and recomputes the block
         # in the backward pass, ``learn_streams`` streams at a time: one
         # group's activations of one block are alive, not the batch's
         # of the stack
-        whole = jax.checkpoint(block, static_argnums=(4,)) if t > 1 else block
+        whole = jax.checkpoint(block, static_argnums=(4, 5)) if t > 1 else block
 
-        def run_block(x, p, layer_state, rows, kind):
+        def run_block(x, p, layer_state, rows, *kinds):
             if groups == 1:
-                return whole(x, p, layer_state, rows, kind)
+                return whole(x, p, layer_state, rows, *kinds)
             split = lambda a: a.reshape((groups, b // groups) + a.shape[1:])
             merge = lambda a: a.reshape((b,) + a.shape[2:])
             x, new, load, routes = jax.lax.map(
-                lambda xs: whole(xs[0], p, xs[1], xs[2], kind),
+                lambda xs: whole(xs[0], p, xs[1], xs[2], *kinds),
                 jax.tree_util.tree_map(split, (x, layer_state, rows)),
             )
             return (
                 merge(x),
                 jax.tree_util.tree_map(merge, new),
                 jax.tree_util.tree_map(lambda a: a.sum(0), load),
-                routes.reshape((-1,) + routes.shape[2:]),
+                jax.tree_util.tree_map(
+                    lambda r: r.reshape((-1,) + r.shape[2:]), routes),
             )
 
-        state_out, loads, all_routes = [], [], []
-        for n, kind in enumerate(self.layer_types):
+        state_out, loads, all_routes, errs = [], [], [], []
+        for n in range(len(self.layer_types)):
             x, new, load, routes = run_block(
-                x, params[f"layer_{n}"], tuple(state[2 * n : 2 * n + 2]),
-                rows_ctx, kind,
+                x, params[f"layer_{n}"], self._layer_state(state, n), rows_ctx,
+                self.layer_types[n], self.ffn_types[n],
             )
             state_out.extend(new)
-            loads.append(load)
-            all_routes.append(routes)
+            if hyper:
+                load, err = load
+                errs.append(err)
+            if self.ffn_types[n] == EXPERTS:
+                loads.append(load)
+                all_routes.append(routes)
         state_out.append(positions[:, -1] + 1)
 
         with jax.named_scope(prefix + "head"):
+            if hyper:  # the lanes summed
+                x = sum(hyper_connection.lanes_of(x, self.lanes))
             feat = _rms(x, params["final_norm"]["weight"], self.eps).reshape(b * t, -1)
             logits = self._dot(feat, params["head"]["kernel"])
             value = (
@@ -366,12 +552,50 @@ class SequenceLM:
             )[:, 0]
         if stats_out is not None:
             per_expert = jnp.stack([l[0] for l in loads])  # (layers, held)
-            stats_out["moe_tokens_per_held_expert"] = jnp.mean(per_expert)
-            stats_out["moe_max_tokens_per_held_expert"] = jnp.max(per_expert)
+            if hyper:
+                # per group of ``loss_groups``; ``reduce_group_stats``
+                # makes the update's numbers of them
+                stats_out["moe_held_load"] = per_expert
+                stats_out["hc_res_row_sum_err_max"] = jnp.max(
+                    jnp.stack([e[0] for e in errs]))
+                stats_out["hc_res_col_sum_err_max"] = jnp.max(
+                    jnp.stack([e[1] for e in errs]))
+            else:
+                stats_out["moe_tokens_per_held_expert"] = jnp.mean(per_expert)
+                stats_out["moe_max_tokens_per_held_expert"] = jnp.max(per_expert)
             stats_out["moe_slots_on_absent_experts"] = sum(l[1] for l in loads)
             if "moe_routes" in stats_out:
                 stats_out["moe_routes"] = jnp.stack(all_routes)
         return logits, value, tuple(state_out)
+
+    # -- the hyper-connection residual -----------------------------------
+
+    def _hyper_block(self, x, p, layer_state, ctx, mixer, ffn):
+        """Both sublayers through ``X <- H_res X + H_post^T F(rms(H_pre
+        X))``. Returns ``(X, state, (load, (row, column) sum errors of
+        H_res), routes)``."""
+        n = self.lanes
+
+        def around(sub, norm, f):
+            with jax.named_scope(ctx["scope"] + "hc"):
+                pre, post, res = hyper_connection.maps(
+                    x, p[f"hc_{sub}_norm"], p[f"hc_{sub}_phi"], p[f"hc_{sub}_a"],
+                    p[f"hc_{sub}_b"], n, self.eps, self.hc_rounds, self.hc_eps,
+                    *self.hc_clamp, unroll=x.shape[1] == 1,
+                )
+                h = hyper_connection.mix_in(x, pre)
+            out = f(p, _rms(h, p[norm], self.eps))
+            with jax.named_scope(ctx["scope"] + "hc"):
+                err = (jnp.max(jnp.abs(res.sum(-1) - 1.0)),
+                       jnp.max(jnp.abs(res.sum(-2) - 1.0)))
+                return hyper_connection.mix_out(x, out[0], post, res), out, err
+
+        x, (_, new), e1 = around(
+            "mixer", "input_norm", lambda p, h: mixer(p, h, layer_state, ctx))
+        x, (_, load, routes), e2 = around(
+            "ffn", "post_norm", lambda p, h: ffn(p, h, ctx))
+        err = tuple(jnp.maximum(a, b) for a, b in zip(e1, e2))
+        return x, new, (load, err), routes
 
     # -- gated deltanet --------------------------------------------------
 
@@ -518,7 +742,59 @@ class SequenceLM:
             o = o.reshape(b, t, h, d) * jax.nn.sigmoid(gate)
             return self._dot(o.reshape(b, t, h * d), p["o_proj"]), (new_k, new_v)
 
-    # -- experts ---------------------------------------------------------
+    # -- latent attention ------------------------------------------------
+
+    def _latent_attn(self, p, x, state, ctx):
+        from ray_tpu.telemetry import metrics
+
+        with jax.named_scope(ctx["scope"] + "mla"):
+            (cache,) = state
+            b, t, _ = x.shape
+            h, dn, c = self.heads, self.nope, self.kv_latent
+            seg, positions, pos0 = ctx["seg"], ctx["positions"], ctx["pos0"]
+            c_q = _rms(self._dot(x, p["q_a"]), p["q_a_norm"], self.eps)
+            q = self._dot(c_q, p["q_b"]).reshape(b, t, h, dn + self.rope_dim)
+            q_nope = q[..., :dn]
+            q_pe = latent_attention.rope(q[..., dn:], positions, self.inv_freq)
+            kv = self._dot(x, p["kv_a"])
+            k_pe = latent_attention.rope(
+                kv[:, :, None, c:], positions, self.inv_freq)[:, :, 0]
+            rows_new = jnp.concatenate(
+                [_rms(kv[..., :c], p["kv_a_norm"], self.eps), k_pe], axis=-1
+            ).astype(cache.dtype)
+
+            # the cache after the fragment, as ``_attn`` writes it: the
+            # last episode's rows, each at its position
+            slot = jnp.where(seg == seg[:, -1:], positions, self.positions)
+            new_cache = cache.at[jnp.arange(b)[:, None], slot].set(
+                rows_new, mode="drop")
+            metrics.inc_mla_decode_lowering("absorbed" if t == 1 else "expanded")
+            if t == 1:
+                # reads what the cache holds, its own row included
+                o = latent_attention.absorbed_step(
+                    q_nope[:, 0], q_pe[:, 0], new_cache, p["kv_b"], pos0,
+                    self.softmax_scale, self.dtype,
+                )[:, None]
+            else:
+                o = latent_attention.expanded_fragment(
+                    q_nope, q_pe, rows_new, cache, p["kv_b"], seg, pos0,
+                    self.softmax_scale, self.dtype, block=_LATENT_ENV_BLOCK,
+                )
+            return (
+                self._dot(o.reshape(b, t, h * self.v_head), p["o_proj"]),
+                (new_cache,),
+            )
+
+    # -- feed-forward ----------------------------------------------------
+
+    def _mlp(self, p, x, ctx):
+        with jax.named_scope(ctx["scope"] + "mlp"):
+            b, t, d = x.shape
+            out = moe.gated_mlp(
+                x.reshape(b * t, d), p["mlp_gate"], p["mlp_up"], p["mlp_down"],
+                dtype=self.dtype,
+            )
+        return out.reshape(b, t, d), None, None
 
     def _moe(self, p, x, ctx):
         b, t, d = x.shape
@@ -526,7 +802,9 @@ class SequenceLM:
         scope = ctx["scope"]
         with jax.named_scope(scope + "moe/route"):
             indices, weights = moe.route_top_k(
-                flat, p["router"], self.top_k, self.norm_topk
+                flat, p["router"], self.top_k, self.norm_topk,
+                scoring=self.scoring, select_bias=p.get("select_bias"),
+                scale=self.route_scale,
             )
             combine = moe.held_combine_weights(
                 indices, weights, self.first_expert, self.experts_held
@@ -541,5 +819,8 @@ class SequenceLM:
             shared = moe.gated_mlp(
                 flat, p["shared_gate"], p["shared_up"], p["shared_down"],
                 dtype=self.dtype,
-            ) * jax.nn.sigmoid(jnp.dot(flat, p["shared_expert_gate"], precision=_HI))
+            )
+            if self.shared_gated:
+                shared = shared * jax.nn.sigmoid(
+                    jnp.dot(flat, p["shared_expert_gate"], precision=_HI))
         return (routed + shared).reshape(b, t, d), load, indices

@@ -1,8 +1,9 @@
 """Routed expert layer that is told which experts it holds.
 
 The router keeps its published width: every token is scored against
-ALL ``E`` experts, the ``k`` largest probabilities are kept (and
-renormalised where the model says so). This chip holds experts
+ALL ``E`` experts (a softmax over them, or a sigmoid each), the ``k``
+largest scores are kept (and renormalised and scaled where the model
+says so; a selection bias may pick them without weighing them). This chip holds experts
 ``[first, first + held)`` of them. :func:`held_combine_weights` turns a
 token's ``k`` (expert, weight) slots into a dense ``(tokens, held)``
 weight matrix, zero where an expert is not among the token's ``k``, and
@@ -28,18 +29,38 @@ _HI = jax.lax.Precision.HIGHEST
 
 
 def route_top_k(
-    x, router_kernel, k: int, renormalise: bool
+    x, router_kernel, k: int, renormalise: bool, scoring: str = "softmax",
+    select_bias=None, scale: float = 1.0,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """``(indices (T, k) int32, weights (T, k) float32)``: softmax over
+    """``(indices (T, k) int32, weights (T, k) float32)``: scores over
     all router outputs in float32 at precision "highest" (a rounding
-    step here changes WHICH experts a token gets), then the top ``k``."""
+    step here changes WHICH experts a token gets), then the top ``k``.
+    ``scoring`` is ``"softmax"`` or ``"sigmoid"`` (each expert scored on
+    its own: DeepSeek-V3). ``select_bias`` ``(E,)`` is added to the
+    scores that PICK the experts and never to a weight, and takes no
+    gradient (the load-balancing bias of ``noaux_tc``); ``scale``
+    multiplies the weights after the renormalisation
+    (``routed_scaling_factor``)."""
     logits = jnp.dot(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32), precision=_HI
     )
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, indices = jax.lax.top_k(probs, k)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    if select_bias is None:
+        weights, indices = jax.lax.top_k(scores, k)
+    else:
+        _, indices = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), k
+        )
+        weights = jnp.take_along_axis(scores, indices, axis=-1)
     if renormalise:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return indices.astype(jnp.int32), weights
 
 

@@ -49,6 +49,42 @@ def _tree_to_device(tree, sharding=None):
     return jax.device_put(tree, sharding) if sharding else jax.device_put(tree)
 
 
+def _grouped_loss_grad(loss_fn, groups, reduce_stats, params, aux, mb, rng,
+                       coeffs, axis):
+    """``((loss, stats), grads)`` of the mean loss over a minibatch
+    whose rows go through ``loss_fn`` in ``groups`` equal groups of
+    whole unrolls, one after another: each group's forward, loss and
+    backward end before the next begins, and its gradient is added to
+    one accumulator, so one group's activations are alive and one
+    gradient tree. The loss is a mean over rows, so the mean of the
+    groups' losses is the minibatch's. ``__chunk__`` columns (a row an
+    unroll) split like the row columns (rows are unroll-major).
+    ``reduce_stats`` makes one update's stats of the groups' stacked."""
+    parts = {
+        k: v.reshape((groups, v.shape[0] // groups) + v.shape[1:])
+        for k, v in mb.items()
+    }
+
+    def scaled(p, part, key):
+        loss, stats = loss_fn(p, aux, part, key, coeffs)
+        return loss / groups, stats
+
+    def one(acc, xs):
+        part, i = xs
+        (loss, stats), g = jax.value_and_grad(scaled, has_aux=True)(
+            params, part, jax.random.fold_in(rng, i)
+        )
+        return jax.tree_util.tree_map(jnp.add, acc, g), (loss, stats)
+
+    zeros = sharding_lib.varying(
+        jax.tree_util.tree_map(jnp.zeros_like, params), axis
+    )
+    grads, (losses, stats) = jax.lax.scan(
+        one, zeros, (parts, jnp.arange(groups))
+    )
+    return (losses.sum(), reduce_stats(stats)), grads
+
+
 def _global_norm(grads):
     """``optax.global_norm`` of a gradient tree whose leaves may be
     partitioned over further mesh axes (the model axis): a sliced
@@ -787,6 +823,11 @@ class JaxPolicy(Policy):
             whole_batch = num_mb == 1 and mb_loc == b_loc and any(
                 k.startswith("__chunk__") for k in batch
             )
+            # a model whose saved activations of a whole minibatch do
+            # not fit asks for groups of unrolls, each taken through
+            # forward, loss and backward on its own (models/sequence_lm.py)
+            ask = getattr(self.model, "loss_groups", None)
+            loss_groups = ask(mb_loc // T_seq) if ask else None
 
             def _unpack(k, v):
                 shp = packed_shapes.get(k)
@@ -825,12 +866,20 @@ class JaxPolicy(Policy):
                 # below is the one real cross-shard reduction
                 # (sharding/specs.py "varying-axes typing")
                 with jax.named_scope("learn/loss_grad"):
-                    (loss, stats), grads = jax.value_and_grad(
-                        loss_fn, has_aux=True
-                    )(
-                        sharding_lib.varying(params, axis),
-                        aux, mb, mb_rng, coeffs,
-                    )
+                    if loss_groups is None:
+                        (loss, stats), grads = jax.value_and_grad(
+                            loss_fn, has_aux=True
+                        )(
+                            sharding_lib.varying(params, axis),
+                            aux, mb, mb_rng, coeffs,
+                        )
+                    else:
+                        (loss, stats), grads = _grouped_loss_grad(
+                            loss_fn, loss_groups,
+                            self.model.reduce_group_stats,
+                            sharding_lib.varying(params, axis),
+                            aux, mb, mb_rng, coeffs, axis,
+                        )
                 with jax.named_scope("learn/allreduce"):
                     grads = jax.lax.pmean(grads, axis)
                 with jax.named_scope("learn/optimizer"):

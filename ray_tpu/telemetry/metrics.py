@@ -110,6 +110,13 @@ MOE_ABSENT_SLOTS_TOTAL = "ray_tpu_moe_absent_slots_total"
 # tiles) | xla (the jax.numpy body). Counted when the form is traced,
 # once per DeltaNet layer of a traced program
 DELTANET_STEP_LOWERINGS_TOTAL = "ray_tpu_deltanet_step_lowerings_total"
+# which form each traced latent-attention layer took
+# (models/sequence_lm.py, ops/latent_attention.py): form = absorbed
+# (one token against the latent rows: the rollout's step) | expanded
+# (a fragment, keys and values rebuilt through W_kvb: the learn form).
+# Counted when the form is traced: once per latent layer body of a
+# program (layers whose checkpointed block is the same trace once)
+MLA_DECODE_LOWERINGS_TOTAL = "ray_tpu_mla_decode_lowerings_total"
 # prioritized-replay segment-tree operations by op and by which tree
 # implementation performed them (docs/data_plane.md "device sum
 # tree"): host = the numpy SumSegmentTree walk, device = the
@@ -520,6 +527,24 @@ def inc_deltanet_step_lowering(path: str) -> None:
         "one-token gated-delta steps traced, by the lowering they took",
         ("path",),
     ).inc(1.0, {"path": path})
+
+
+def inc_mla_decode_lowering(form: str) -> None:
+    """One traced latent-attention layer took ``form`` (``absorbed`` |
+    ``expanded``)."""
+    counter(
+        MLA_DECODE_LOWERINGS_TOTAL,
+        "latent-attention layers traced, by the form they took",
+        ("form",),
+    ).inc(1.0, {"form": form})
+
+
+def mla_decode_lowerings() -> Dict[str, float]:
+    """``{form: traced latent-attention layers}`` since the process began."""
+    m = get_metric(MLA_DECODE_LOWERINGS_TOTAL)
+    if m is None:
+        return {}
+    return {dict(tags).get("form", ""): v for tags, v in m.series()}
 
 
 def deltanet_step_lowerings() -> Dict[str, float]:
