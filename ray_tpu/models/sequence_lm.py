@@ -95,6 +95,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.ops import deltanet, hyper_connection, latent_attention, moe
+from ray_tpu.telemetry import metrics
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -564,6 +565,10 @@ class SequenceLM:
                 stats_out["moe_tokens_per_held_expert"] = jnp.mean(per_expert)
                 stats_out["moe_max_tokens_per_held_expert"] = jnp.max(per_expert)
             stats_out["moe_slots_on_absent_experts"] = sum(l[1] for l in loads)
+            # of the dense form's (token, held expert) rows, those the
+            # experts' products computed (the grouped form: its buffers)
+            stats_out["moe_rows_computed_share"] = sum(l[2] for l in loads) / (
+                b * t * self.experts_held * len(loads))
             if "moe_routes" in stats_out:
                 stats_out["moe_routes"] = jnp.stack(all_routes)
         return logits, value, tuple(state_out)
@@ -745,8 +750,6 @@ class SequenceLM:
     # -- latent attention ------------------------------------------------
 
     def _latent_attn(self, p, x, state, ctx):
-        from ray_tpu.telemetry import metrics
-
         with jax.named_scope(ctx["scope"] + "mla"):
             (cache,) = state
             b, t, _ = x.shape
@@ -800,21 +803,37 @@ class SequenceLM:
         b, t, d = x.shape
         flat = x.reshape(b * t, d)
         scope = ctx["scope"]
+        held = self.experts_held
+        # a token of each stream: dense; a fragment of each: grouped
+        lowering = moe.product_lowering(b * t, self.top_k, self.router_outputs)
+        metrics.inc_moe_product_lowering(lowering)
         with jax.named_scope(scope + "moe/route"):
             indices, weights = moe.route_top_k(
                 flat, p["router"], self.top_k, self.norm_topk,
                 scoring=self.scoring, select_bias=p.get("select_bias"),
                 scale=self.route_scale,
             )
-            combine = moe.held_combine_weights(
-                indices, weights, self.first_expert, self.experts_held
+            if lowering == "dense":
+                combine = moe.held_combine_weights(
+                    indices, weights, self.first_expert, held
+                )
+            per_expert, absent = moe.expert_load(indices, self.first_expert, held)
+            load = (
+                per_expert, absent,
+                moe.rows_computed(
+                    per_expert, b * t, self.top_k, self.router_outputs, lowering),
             )
-            load = moe.expert_load(indices, self.first_expert, self.experts_held)
+        experts = (p["experts_gate"], p["experts_up"], p["experts_down"])
         with jax.named_scope(scope + "moe/experts"):
-            routed = moe.held_experts_product(
-                flat, p["experts_gate"], p["experts_up"], p["experts_down"], combine,
-                dtype=self.dtype,
-            )
+            if lowering == "dense":
+                routed = moe.dense_experts_product(
+                    flat, *experts, combine, dtype=self.dtype
+                )
+            else:
+                routed = moe.grouped_experts_product(
+                    flat, *experts, indices, weights, per_expert,
+                    self.first_expert, self.router_outputs, dtype=self.dtype,
+                )
         with jax.named_scope(scope + "moe/shared"):
             shared = moe.gated_mlp(
                 flat, p["shared_gate"], p["shared_up"], p["shared_down"],

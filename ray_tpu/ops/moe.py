@@ -4,18 +4,29 @@ The router keeps its published width: every token is scored against
 ALL ``E`` experts (a softmax over them, or a sigmoid each), the ``k``
 largest scores are kept (and renormalised and scaled where the model
 says so; a selection bias may pick them without weighing them). This chip holds experts
-``[first, first + held)`` of them. :func:`held_combine_weights` turns a
-token's ``k`` (expert, weight) slots into a dense ``(tokens, held)``
-weight matrix, zero where an expert is not among the token's ``k``, and
-:func:`held_experts_product` computes the grouped product over the held
-experts under those weights. What the absent experts would add is left
-out (model-configs guide, section 4): no token is dropped, no capacity
-is set, and nothing stands in for other chips.
+``[first, first + held)`` of them and computes what they add to each
+token. What the absent experts would add
+is left out (model-configs guide, section 4): no token is dropped, no
+capacity is set, and nothing stands in for other chips.
 
-The grouped product is written densely (every held expert over every
-token of a block, times the weight): exact for any routing, and the
-baseline a sorted or ragged kernel has to beat. A decode step reads
-every held expert's weights once either way.
+The product has two forms with one result (float32 summation order
+apart), and :func:`product_lowering` picks one from the static shapes:
+
+- **dense** (:func:`dense_experts_product`): every held expert over
+  every token, times a ``(tokens, held)`` weight matrix
+  (:func:`held_combine_weights`) that is zero where the expert is not
+  among the token's ``k``: ``tokens x held`` rows. A decode step reads
+  every held expert's weights once either way, and its few tokens leave
+  a sort nothing to take.
+- **grouped** (:func:`grouped_experts_product`): the (token, slot) pairs
+  on held experts sorted by expert, each expert's rows gathered into a
+  buffer of its own, one batched product a projection over ``(held,
+  buffer)`` rows, the rows added back onto their tokens. A buffer is
+  static (:func:`expert_buffer_rows`: three times the tokens a uniform
+  router sends one expert, in whole tiles); a call in which ANY held
+  expert gets more takes the dense form under ``lax.cond``, so every
+  routing is computed in full: all tokens on one expert cost what the
+  dense form costs and drop nothing.
 """
 
 from __future__ import annotations
@@ -93,16 +104,54 @@ def gated_mlp(x, w_gate, w_up, w_down, dtype=jnp.bfloat16):
     return jnp.dot(hidden, w_down.astype(dtype), preferred_element_type=jnp.float32)
 
 
-def held_experts_product(
+# the row tile a held expert's buffer is a multiple of
+GROUP_TILE = 128
+# a held expert's buffer over the tokens a uniform router sends it:
+# the largest load of a held expert read 1.5-1.8 times the mean in the
+# cells (``moe.max_expert_load_ratio``)
+_ROOM = 3
+
+
+def expert_buffer_rows(tokens: int, k: int, num_experts: int) -> int:
+    """Rows of one held expert's buffer in the grouped form, static:
+    ``_ROOM`` times ``tokens x k / E`` in whole tiles. 2,048 tokens,
+    top-10 of 512: 128; 1,024 tokens, top-4 of 64: 256."""
+    return max(1, -(-_ROOM * tokens * k // (num_experts * GROUP_TILE))) * GROUP_TILE
+
+
+def product_lowering(tokens: int, k: int, num_experts: int) -> str:
+    """``"dense"`` or ``"grouped"``, from static shapes alone: dense
+    while the tokens are no more than one expert's buffer, so that the
+    buffers would hold as many rows as the dense form computes. A
+    decode step's 64 tokens (top-10 of 512): dense; a learn block's
+    2,048: 65,536 dense rows against 32 buffers of 128, grouped."""
+    fits_a_buffer = tokens <= expert_buffer_rows(tokens, k, num_experts)
+    return "dense" if fits_a_buffer else "grouped"
+
+
+def rows_computed(per_expert, tokens: int, k: int, num_experts: int, lowering: str):
+    """Rows of the ``(rows, D) x (D, F)`` products that ``lowering``
+    computes for these per-expert counts, float32: every token under
+    every held expert (dense, and a grouped call that outgrew a
+    buffer), or every held expert's buffer."""
+    held = per_expert.shape[0]
+    dense_rows = jnp.float32(tokens * held)
+    if lowering == "dense":
+        return dense_rows
+    buffer = expert_buffer_rows(tokens, k, num_experts)
+    return jnp.where(jnp.max(per_expert) <= buffer, held * buffer, dense_rows)
+
+
+def dense_experts_product(
     x, w_gate, w_up, w_down, combine, *, block_tokens: int = 1024,
     dtype=jnp.bfloat16,
 ):
-    """``sum_e combine[t, e] * expert_e(x_t)`` over the held experts.
-    ``x`` ``(T, D)``; ``w_gate``, ``w_up`` ``(held, D, F)``; ``w_down``
-    ``(held, F, D)``; ``combine`` ``(T, held)``. Tokens go through in
-    blocks of ``block_tokens`` (each recomputed in the backward pass),
-    so the ``(block, held, F)`` hidden activations bound the memory,
-    not ``(T, held, F)``."""
+    """``sum_e combine[t, e] * expert_e(x_t)``, every held expert over
+    every token. ``x`` ``(T, D)``; ``w_gate``, ``w_up`` ``(held, D,
+    F)``; ``w_down`` ``(held, F, D)``; ``combine`` ``(T, held)``. Tokens
+    go through in blocks of ``block_tokens`` (each recomputed in the
+    backward pass), so the ``(block, held, F)`` hidden activations bound
+    the memory, not ``(T, held, F)``."""
     t, d = x.shape
     wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
 
@@ -116,7 +165,7 @@ def held_experts_product(
             "tef,efd->td", hidden, wd, preferred_element_type=jnp.float32
         )
 
-    n = max(1, t // int(block_tokens))
+    n = max(1, t // block_tokens)
     if n == 1 or t % n:
         return block(x, combine)
     out = jax.lax.map(
@@ -124,3 +173,53 @@ def held_experts_product(
         (x.reshape(n, t // n, d), combine.reshape(n, t // n, -1)),
     )
     return out.reshape(t, d)
+
+
+def grouped_experts_product(
+    x, w_gate, w_up, w_down, indices, weights, per_expert, first: int,
+    num_experts: int, dtype=jnp.bfloat16,
+):
+    """The same sum over the (token, slot) pairs on held experts only.
+    ``indices``, ``weights`` ``(T, k)`` as :func:`route_top_k` gives
+    them, ``per_expert`` ``(held,)`` the pairs on each held expert
+    (:func:`expert_load`). Pairs are sorted by expert (stable; pairs on
+    absent experts last), so expert ``e``'s pairs are ``per_expert[e]``
+    consecutive places of the order and slot ``c`` of its buffer reads
+    the ``c``-th of them; a slot past the count carries weight zero and
+    no token. The products are plain batched ones: the backward pass is
+    their transposes, the weights' gradient accumulated in float32 by
+    the product itself."""
+    t, d = x.shape
+    held = w_gate.shape[0]
+    k = indices.shape[-1]
+    buffer = expert_buffer_rows(t, k, num_experts)
+    counts = per_expert.astype(jnp.int32)
+    # cast once for both ways: the cond hands back ``dtype`` gradients
+    wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
+
+    def grouped():
+        local = indices.reshape(-1) - first  # (T k,)
+        here = (local >= 0) & (local < held)
+        order = jnp.argsort(jnp.where(here, local, held), stable=True)
+        slot = jnp.arange(buffer, dtype=jnp.int32)
+        place = (jnp.cumsum(counts) - counts)[:, None] + slot  # (held, buffer)
+        filled = slot < counts[:, None]
+        pair = jnp.take(order, jnp.minimum(place, t * k - 1))
+        token = jnp.where(filled, pair // k, t)  # t: no token
+        weight = jnp.where(filled, jnp.take(weights.reshape(-1), pair), 0.0)
+        rows = jnp.take(x.astype(dtype), token, axis=0, mode="fill", fill_value=0)
+        gate = jnp.einsum("ecd,edf->ecf", rows, wg, preferred_element_type=jnp.float32)
+        up = jnp.einsum("ecd,edf->ecf", rows, wu, preferred_element_type=jnp.float32)
+        hidden = (jax.nn.silu(gate) * up * weight[..., None]).astype(dtype)
+        out = jnp.einsum(
+            "ecf,efd->ecd", hidden, wd, preferred_element_type=jnp.float32
+        )
+        return jnp.zeros((t, d), jnp.float32).at[token.reshape(-1)].add(
+            out.reshape(-1, d), mode="drop"
+        )
+
+    def dense():
+        combine = held_combine_weights(indices, weights, first, held)
+        return dense_experts_product(x, wg, wu, wd, combine, dtype=dtype)
+
+    return jax.lax.cond(jnp.max(counts) <= buffer, grouped, dense)

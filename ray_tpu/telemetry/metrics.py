@@ -110,6 +110,12 @@ MOE_ABSENT_SLOTS_TOTAL = "ray_tpu_moe_absent_slots_total"
 # tiles) | xla (the jax.numpy body). Counted when the form is traced,
 # once per DeltaNet layer of a traced program
 DELTANET_STEP_LOWERINGS_TOTAL = "ray_tpu_deltanet_step_lowerings_total"
+# which form each traced routed-expert layer's product took
+# (models/sequence_lm.py, ops/moe.product_lowering): path = grouped
+# (only the (token, slot) pairs on held experts, sorted by expert) |
+# dense (every held expert over every token). Chosen from static shapes
+# and counted when the layer is traced
+MOE_PRODUCT_LOWERINGS_TOTAL = "ray_tpu_moe_product_lowerings_total"
 # which form each traced latent-attention layer took
 # (models/sequence_lm.py, ops/latent_attention.py): form = absorbed
 # (one token against the latent rows: the rollout's step) | expanded
@@ -519,6 +525,15 @@ def expert_load_totals() -> Dict[str, float]:
     return out
 
 
+def _totals_by_tag(name: str, tag: str) -> Dict[str, float]:
+    """``{value of tag: total}`` of a counter ({} before its first
+    increment)."""
+    m = get_metric(name)
+    if m is None:
+        return {}
+    return {dict(tags).get(tag, ""): v for tags, v in m.series()}
+
+
 def inc_deltanet_step_lowering(path: str) -> None:
     """One traced one-token gated-delta step took ``path`` (``kernel``
     | ``xla``): ops/deltanet.py picks from platform and shape."""
@@ -527,6 +542,21 @@ def inc_deltanet_step_lowering(path: str) -> None:
         "one-token gated-delta steps traced, by the lowering they took",
         ("path",),
     ).inc(1.0, {"path": path})
+
+
+def inc_moe_product_lowering(path: str) -> None:
+    """One traced routed-expert layer took ``path`` (``grouped`` |
+    ``dense``) for the held experts' product."""
+    counter(
+        MOE_PRODUCT_LOWERINGS_TOTAL,
+        "routed-expert layers traced, by the form their product took",
+        ("path",),
+    ).inc(1.0, {"path": path})
+
+
+def moe_product_lowerings() -> Dict[str, float]:
+    """``{path: traced routed-expert layers}`` since the process began."""
+    return _totals_by_tag(MOE_PRODUCT_LOWERINGS_TOTAL, "path")
 
 
 def inc_mla_decode_lowering(form: str) -> None:
@@ -541,18 +571,12 @@ def inc_mla_decode_lowering(form: str) -> None:
 
 def mla_decode_lowerings() -> Dict[str, float]:
     """``{form: traced latent-attention layers}`` since the process began."""
-    m = get_metric(MLA_DECODE_LOWERINGS_TOTAL)
-    if m is None:
-        return {}
-    return {dict(tags).get("form", ""): v for tags, v in m.series()}
+    return _totals_by_tag(MLA_DECODE_LOWERINGS_TOTAL, "form")
 
 
 def deltanet_step_lowerings() -> Dict[str, float]:
     """``{path: traced one-token steps}`` since the process began."""
-    m = get_metric(DELTANET_STEP_LOWERINGS_TOTAL)
-    if m is None:
-        return {}
-    return {dict(tags).get("path", ""): v for tags, v in m.series()}
+    return _totals_by_tag(DELTANET_STEP_LOWERINGS_TOTAL, "path")
 
 
 def inc_env_steps_on_device(n: int) -> None:

@@ -241,7 +241,7 @@ def test_model_counts_travel_with_the_loss_and_nothing_stays_on_the_policy():
     np.testing.assert_allclose(a, b, atol=1e-6)
     assert set(counts) == {
         "moe_tokens_per_held_expert", "moe_max_tokens_per_held_expert",
-        "moe_slots_on_absent_experts",
+        "moe_slots_on_absent_experts", "moe_rows_computed_share",
     }
     assert set(pol.__dict__) == attrs
 

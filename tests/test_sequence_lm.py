@@ -251,7 +251,7 @@ def test_expert_shares_add_up_to_the_uncut_layer():
             sl = slice(first, first + 2)
             share = {**p, **{k: p[k][sl] for k in
                              ("experts_gate", "experts_up", "experts_down")}}
-            part, (per_expert, absent), _ = model._moe(share, x, {"scope": ""})
+            part, (per_expert, absent, _), _ = model._moe(share, x, {"scope": ""})
             total = total + (part - shared_only)
             assert float(per_expert.sum() + absent) == 2 * T * 3
     np.testing.assert_allclose(total, whole, atol=2e-5)
@@ -265,6 +265,167 @@ def test_held_combine_weights_and_load():
     per_expert, absent = moe.expert_load(idx, 4, 4)
     np.testing.assert_allclose(per_expert, [0, 1, 1, 1])
     assert float(absent) == 3
+
+
+# the two routers of the cells, small: (E, k, held, first, route_top_k's options)
+ROUTERS = {
+    "softmax_10_of_512_held_32_at_64": (512, 10, 32, 64, {}),
+    "sigmoid_bias_scale_4_of_64_held_8": (
+        64, 4, 8, 0, {"scoring": "sigmoid", "scale": 2.0, "select_bias": True}),
+}
+
+
+def _routing(name, indices, first, held):
+    """The router's own indices, or a routing that a scheme with
+    per-expert buffers gets wrong. A token's experts stay distinct."""
+    t, k = indices.shape
+    token = jnp.arange(t, dtype=jnp.int32)
+    # every slot of every token on an expert past the held ones
+    absent = jnp.broadcast_to(first + held + jnp.arange(k, dtype=jnp.int32), (t, k))
+    if name in ("routed", "tokens_not_a_tile"):
+        return indices
+    if name == "all_on_one_expert":
+        return absent.at[:, 0].set(first + 3)
+    if name == "none_held":
+        return absent
+    if name == "several_held_a_token":
+        several = first + (token[:, None] + 3 * jnp.arange(3)) % held
+        return absent.at[:, 1:4].set(several)
+    if name == "empty_expert_between":  # held experts 0 and 2, never 1
+        return absent.at[:, k - 1].set(first + 2 * (token % 2))
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("routing", [
+    "routed", "all_on_one_expert", "none_held", "several_held_a_token",
+    "tokens_not_a_tile", "empty_expert_between",
+])
+@pytest.mark.parametrize("router", sorted(ROUTERS))
+def test_grouped_product_equals_dense(router, routing, dtype):
+    """The grouped form against the dense form: the result and the
+    gradient of every operand. float32 operands leave the summation
+    order alone between them; bfloat16 adds the rounding of a pair's
+    row where the dense form rounds a token's."""
+    experts, k, held, first, options = ROUTERS[router]
+    t, d, f = (131 if routing == "tokens_not_a_tile" else 256), 32, 16
+    keys = jax.random.split(jax.random.PRNGKey(11), 8)
+    x = jax.random.normal(keys[0], (t, d), jnp.float32)
+    options = dict(options)
+    if options.pop("select_bias", False):
+        options["select_bias"] = jax.random.normal(keys[6], (experts,)) * 0.02
+    indices, weights = moe.route_top_k(
+        x, jax.random.normal(keys[1], (d, experts)) * d ** -0.5, k, True, **options)
+    indices = _routing(routing, indices, first, held)
+    assert bool(jnp.all(jnp.sort(indices, -1)[:, 1:] != jnp.sort(indices, -1)[:, :-1]))
+    stacks = (
+        jax.random.normal(keys[2], (held, d, f)) * d ** -0.5,
+        jax.random.normal(keys[3], (held, d, f)) * d ** -0.5,
+        jax.random.normal(keys[4], (held, f, d)) * f ** -0.5,
+    )
+    ct = jax.random.normal(keys[5], (t, d), jnp.float32)
+
+    def dense(x, wg, wu, wd, w):
+        combine = moe.held_combine_weights(indices, w, first, held)
+        return moe.dense_experts_product(x, wg, wu, wd, combine, dtype=dtype)
+
+    per_expert, _ = moe.expert_load(indices, first, held)
+    # which way the call goes: every token on one expert outgrows its
+    # buffer and takes the dense form under the cond; the two experts
+    # of "empty_expert_between" fill theirs to the last row
+    fits = int(per_expert.max()) <= moe.expert_buffer_rows(t, k, experts)
+    assert fits == (routing != "all_on_one_expert")
+
+    def grouped(x, wg, wu, wd, w):
+        return moe.grouped_experts_product(
+            x, wg, wu, wd, indices, w, per_expert, first, experts, dtype=dtype)
+
+    want, want_vjp = jax.vjp(dense, x, *stacks, weights)
+    got, got_vjp = jax.vjp(grouped, x, *stacks, weights)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    names = ("out", "x", "w_gate", "w_up", "w_down", "weights")
+    for name, a, b in zip(names, (want,) + want_vjp(ct), (got,) + got_vjp(ct)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_allclose(
+            b, a, atol=tol * max(1.0, np.abs(a).max()), rtol=0, err_msg=name)
+    if routing == "none_held":
+        assert not np.any(np.asarray(got))
+
+
+@pytest.mark.parametrize("tokens,k,experts,want,buffer", [
+    (64, 10, 512, "dense", 128),       # the Qwen3-Next cell's decode step
+    (2048, 10, 512, "grouped", 128),   # its learn block of 16 fragments
+    (1024, 4, 64, "grouped", 256),     # the Xing4 cell's group of 8 fragments
+    (32, 4, 64, "dense", 128),         # its decode step
+])
+def test_product_lowering_rule_at_the_cells_sizes(tokens, k, experts, want, buffer):
+    assert moe.product_lowering(tokens, k, experts) == want
+    assert moe.expert_buffer_rows(tokens, k, experts) == buffer
+
+
+def _reduced_qwen3_next():
+    """Top-10 of 512 with 32 held at the widths of ``small_config``."""
+    config = small_config(
+        held=(0, 32), router_outputs=512, num_experts_per_tok=10,
+        max_position_embeddings=256)
+    return SequenceLM(VOCAB, config["algo_config"]["model"]["sequence_lm"])
+
+
+def test_learn_form_counts_grouped_and_one_token_form_dense():
+    """Tracing alone: a fragment of each of 16 streams, then a token of
+    each, at the cell's routing and token counts."""
+    from ray_tpu.telemetry import metrics
+
+    model = _reduced_qwen3_next()
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    for t, path in ((128, "grouped"), (1, "dense")):
+        tokens = jax.ShapeDtypeStruct((16, t, 1), jnp.int32)
+        state = jax.eval_shape(lambda: model.initial_state(16))
+        before = metrics.moe_product_lowerings()
+        jax.eval_shape(model.apply, params, tokens, state)
+        after = metrics.moe_product_lowerings()
+        moved = {p: after.get(p, 0.0) - before.get(p, 0.0) for p in after}
+        assert moved.pop(path) >= 1.0, (t, moved)
+        assert not any(moved.values()), (t, moved)
+
+
+def test_grouped_form_through_the_model_and_its_rows_computed_share(monkeypatch):
+    """The learn form over 256 tokens takes the grouped product; its
+    logits, value and every gradient leaf against the same model held
+    to the dense form, and the share of the dense rows it computed:
+    the buffers' rows where every held expert fits its own."""
+    config = small_config(
+        held=(2, 4), router_outputs=64, num_experts_per_tok=3,
+        max_position_embeddings=96)
+    lm = config["algo_config"]["model"]["sequence_lm"]
+    model = _model(lm)
+    model.learn_streams = 4
+    params = ref.init_params(jax.random.PRNGKey(5), config, VOCAB)
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (4, 64, 1), 0, VOCAB)
+    state = _f32_state(model.initial_state(4))
+    assert moe.product_lowering(256, 3, 64) == "grouped"
+
+    def outputs(params):
+        stats = {"moe_routes": None}
+        logits, value, _ = model.apply(params, tokens, state, stats_out=stats)
+        return jnp.sum(jnp.sin(logits)) + jnp.sum(value), (logits, value, stats)
+
+    with jax.default_matmul_precision("highest"):
+        (_, (logits, value, stats)), grads = jax.value_and_grad(
+            outputs, has_aux=True)(params)
+        monkeypatch.setattr(moe, "product_lowering", lambda *a: "dense")
+        (_, (logits_d, value_d, stats_d)), grads_d = jax.value_and_grad(
+            outputs, has_aux=True)(params)
+    np.testing.assert_allclose(logits, logits_d, atol=2e-5)
+    np.testing.assert_allclose(value, value_d, atol=2e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(grads_d)):
+        np.testing.assert_allclose(a, b, atol=2e-4 * max(1.0, float(jnp.abs(b).max())))
+    assert float(stats_d["moe_rows_computed_share"]) == 1.0
+    routes = np.asarray(stats["moe_routes"])  # (layers, tokens, k)
+    buffer = moe.expert_buffer_rows(256, 3, 64)
+    assert all(np.sum(layer == e) <= buffer for layer in routes for e in range(2, 6))
+    np.testing.assert_allclose(float(stats["moe_rows_computed_share"]), buffer / 256)
 
 
 def test_token_env_rows():
