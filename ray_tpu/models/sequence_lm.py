@@ -7,9 +7,9 @@ A block is two sublayers, a MIXER and a FEED-FORWARD, each with its own
 zero-centred RMSNorm ``rms(x) = x * rsqrt(mean(x^2) + eps) * (1 + w)``,
 each applied through the block's RESIDUAL kind.
 
-Mixer kinds (``layer_types``; every ``full_attention_interval``-th
-layer full attention where it is not stated; all latent where the
-config has ``kv_lora_rank``):
+Mixer kinds (``layer_types``, under the published names; every
+``full_attention_interval``-th layer full attention where it is not
+stated; all latent where the config has ``kv_lora_rank``):
 
 - ``"full_attention"`` (Hugging Face ``qwen3_next``,
   ``Qwen3NextAttention``): gated softmax attention. One projection gives
@@ -33,12 +33,28 @@ config has ``kv_lora_rank``):
   k_nope + rope(q_pe) . k_pe) * s``, ``s = (nope + rope)^-1/2 * m^2``,
   causal softmax, ``o = P v``, output projection. The query/key product
   is ``nope + rope`` wide, the value product ``v_head_dim``
-  (``ops/latent_attention.py`` holds both forms).
+  (``ops/latent_attention.py`` holds both forms);
+- ``"mamba"`` (Mamba-2 as Hugging Face ``granitemoehybrid`` holds it;
+  Dao & Gu, arXiv:2405.21060): ``[z | x | B | C | dt] = h W_in`` (no
+  bias); a causal depthwise convolution (width ``mamba_d_conv``) WITH a
+  bias and SiLU over the channels of ``(x, B, C)``; ``dt = softplus(dt
+  + dt_bias)``, ``A = -exp(A_log)`` a head; per head ``S <- exp(dt A) S
+  + dt x B^T``, ``y = S C + D x`` (``ops/ssd.py``; ``B`` and ``C`` are
+  shared by every head: ``mamba_n_groups`` 1); ``rms(y * silu(z)) * w``
+  over the whole inner width, then the output projection;
+- ``"attention"`` (``GraniteMoeHybridAttention`` with
+  ``position_embedding_type: nope``): plain GQA softmax attention with
+  NO positions, no q/k norm and no gate, its scores scaled by
+  ``attention_multiplier`` (not ``head^-1/2``). No biases.
 
 Feed-forward kinds (``"dense"`` for the first ``first_k_dense_replace``
 layers, ``"experts"`` after):
 
-- ``"dense"``: SwiGLU of width ``intermediate_size``;
+- ``"dense"``: SwiGLU of width ``intermediate_size``
+  (``shared_intermediate_size`` where the config states one). A config
+  that counts no experts (``num_local_experts`` 0, or no such key) has
+  NO expert layer: every feed-forward is dense, there is no router and
+  ``apply`` reports no expert statistic;
 - ``"experts"``: a router over ALL ``router_outputs`` experts, the
   experts this chip HOLDS (``experts_held``: ``[first, count]``;
   ``ops/moe.py``) and a shared expert. ``qwen3_next``: softmax, top-k,
@@ -59,6 +75,21 @@ Residual kinds:
   through ``hc_sinkhorn_iters`` Sinkhorn rounds. The embedding is
   copied into the lanes and the lanes are summed before the final norm.
 
+The Granite multipliers, each applied only where the config states it:
+``x0 = embedding_multiplier * E[token]``, each sublayer's output times
+``residual_multiplier`` before it is added, logits over
+``logits_scaling``. With ``tie_word_embeddings`` the output head IS the
+embedding (``logits = rms(x) E^T``): the tree has no ``head`` leaf, the
+table's gradient comes from both uses and Adam holds one pair of
+moments for it.
+
+A run of consecutive ``"mamba"`` layers is ONE group of the parameter
+tree, ``"layers_<first>_<last>"``, its leaves stacked on a leading
+layer axis, and one ``lax.scan`` over them in either form: the run's
+layers are traced once (ten layers unrolled compiled for longer than a
+run of the benchmark may take). Every other layer is its own group
+``"layer_<n>"``.
+
 State (``initial_state``; one row per stream, a flat tuple): for each
 linear layer the ``(value heads, dk, dv)`` float32 DeltaNet matrix and
 the last ``conv - 1`` inputs of the convolution; for each full layer
@@ -66,7 +97,13 @@ the keys and values of the episode so far (bfloat16, ``(positions,
 kv heads x head)``, keys stored after norm and RoPE); for each latent
 layer ONE leaf of latent rows (bfloat16, ``(positions, kv_lora_rank +
 qk_rope_head_dim)``: the normed latent and the roped key part, whatever
-the head count); last, the stream's position. ``apply`` has two forms
+the head count); for each RUN of state-space layers two leaves with the
+layer axis after the stream's, the ``(layers, heads, head, state)``
+float32 matrices and the last ``conv - 1`` inputs of each convolution
+(the one-token form reads and writes one layer's slice of them in
+place, the fragment form scans over them); for an ``"attention"`` layer
+keys and values as for a full layer (no norm, no RoPE); last, the
+stream's position. ``apply`` has two forms
 that are the same function of the same weights: ``T == 1`` is the
 recurrence (one token, state in and out: the rollout lane's step; for
 latent attention the ABSORBED product against the latent rows), ``T >
@@ -80,7 +117,9 @@ head and the attention products take bfloat16 operands and accumulate
 in float32; the router, softmax, top-k, ``g``, ``beta``, the DeltaNet
 state, the hyper-connection maps and mixes and every norm are float32
 (the router, the maps' projection and the delta rule at precision
-"highest").
+"highest"); the state-space recurrence in both forms, ``dt``, ``A``,
+its convolution and the multipliers float32 (the recurrence at
+precision "highest").
 
 Not a flax module (cf. ``models/transformer.py``): plain-dict params,
 two levels deep, ``{"layer_0": {"in_proj_qkvz": ...}, ...}``.
@@ -94,12 +133,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops import deltanet, hyper_connection, latent_attention, moe
+from ray_tpu.ops import deltanet, hyper_connection, latent_attention, moe, ssd
 from ray_tpu.telemetry import metrics
 
 _HI = jax.lax.Precision.HIGHEST
 
 LINEAR, FULL, LATENT = "linear_attention", "full_attention", "latent_attention"
+MAMBA, ATTENTION = "mamba", "attention"
 DENSE, EXPERTS = "dense", "experts"
 PLAIN, HYPER = "plain", "hyper_connection"
 
@@ -109,8 +149,11 @@ PLAIN, HYPER = "plain", "hyper_connection"
 # their cotangents)
 _ATTN_ENV_BLOCK = 8
 _LATENT_ENV_BLOCK = 4
-# state leaves a layer of each mixer kind holds
-_STATE_LEAVES = {LINEAR: 2, FULL: 2, LATENT: 1}
+# state leaves a layer of each mixer kind holds (a state-space RUN: its
+# layers' matrices stacked in one leaf, their convolution inputs in another)
+_STATE_LEAVES = {LINEAR: 2, FULL: 2, LATENT: 1, MAMBA: 2, ATTENTION: 2}
+# a stacked run's leaves that enter a bfloat16 product
+_RUN_PRODUCT_LEAVES = ("in_proj", "out_proj", "mlp_gate", "mlp_up", "mlp_down")
 
 
 def layer_types_of(config: Dict) -> Tuple[str, ...]:
@@ -136,6 +179,33 @@ def _l2norm(x, eps=1e-6):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
 
 
+def _causal_conv(tail, x, seg, kernel, bias=None):
+    """Causal depthwise convolution + SiLU over the stored inputs
+    ``tail`` ``(B, width - 1, C)`` and the fragment's own ``x`` ``(B,
+    T, C)``; an input of an earlier episode (``seg`` ``(B, T)``, the
+    episode's number inside the fragment) is not seen. ``kernel`` ``(C,
+    width)``. Returns the output and the last ``width - 1`` inputs of
+    the fragment's last episode."""
+    b, t, _ = x.shape
+    width = kernel.shape[-1]
+    full = jnp.concatenate([tail, x], axis=1)  # (B, T+w-1, C)
+    full_seg = jnp.concatenate(
+        [jnp.zeros((b, width - 1), seg.dtype), seg], axis=1
+    )
+    conv = jnp.zeros_like(x)
+    for back in range(width):
+        lo = width - 1 - back
+        seen = (full_seg[:, lo : lo + t] == seg)[..., None]
+        conv = conv + jnp.where(
+            seen, full[:, lo : lo + t], 0.0
+        ) * kernel[:, width - 1 - back]
+    if bias is not None:
+        conv = conv + bias
+    out = jax.nn.silu(conv)
+    live = (full_seg[:, t:] == seg[:, -1:])[..., None]
+    return out, jnp.where(live, full[:, t:], 0.0)
+
+
 def _rope(x, positions, rotary: int, theta: float):
     """Rotate the first ``rotary`` dimensions of each head (the
     rotate-half form). ``x`` ``(B, T, H, D)``, ``positions`` ``(B, T)``."""
@@ -147,6 +217,25 @@ def _rope(x, positions, rotary: int, theta: float):
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1
     )
+
+
+def _init_ssm_leaf(key, leaf: str, shape):
+    """One leaf of a run of state-space layers, ``shape`` with its
+    leading layer axis."""
+    if leaf == "A_log":
+        x = jnp.log(jnp.arange(1, shape[-1] + 1, dtype=jnp.float32))
+    elif leaf == "D":
+        x = jnp.ones(shape, jnp.float32)
+    elif leaf == "dt_bias":
+        dt = jnp.exp(jax.random.uniform(
+            key, shape, minval=np.log(1e-3), maxval=np.log(1e-1)))
+        x = dt + jnp.log(-jnp.expm1(-dt))
+    elif len(shape) == 2:  # norm weights, the convolution's bias
+        x = jnp.zeros(shape, jnp.float32)
+    else:
+        rows = shape[-1] if leaf == "conv" else shape[-2]
+        x = jax.random.normal(key, shape, jnp.float32) / np.sqrt(rows)
+    return jnp.broadcast_to(x, shape)
 
 
 class SequenceLM:
@@ -173,11 +262,32 @@ class SequenceLM:
         self.vocab = int(num_outputs)
         self.hidden = int(c["hidden_size"])
         self.layer_types = layer_types_of(c)
-        dense_first = int(c.get("first_k_dense_replace", 0))
+        # experts the config counts, under whichever family's key; none
+        # (or no such key) is a model with no expert layer
+        experts = int(next(
+            (c[k] for k in ("num_experts", "n_routed_experts", "num_local_experts")
+             if k in c), 0))
+        dense_first = int(c.get("first_k_dense_replace", 0)) if experts else (
+            len(self.layer_types))
         self.ffn_types = tuple(
             DENSE if i < dense_first else EXPERTS
             for i in range(len(self.layer_types))
         )
+        # groups of the parameter tree: a run of state-space layers
+        # with its leaves stacked, every other layer alone
+        segments = []
+        for i, (kind, ffn) in enumerate(zip(self.layer_types, self.ffn_types)):
+            if kind == MAMBA and segments and segments[-1][1:3] == [kind, ffn]:
+                segments[-1][3] += 1
+            else:
+                segments.append([i, kind, ffn, 1])
+        self.segments = tuple(
+            (f"layers_{i}_{i + n - 1}" if kind == MAMBA else f"layer_{i}",
+             kind, ffn, n) for i, kind, ffn, n in segments)
+        self.embed_scale = float(c.get("embedding_multiplier", 1.0))
+        self.residual_scale = float(c.get("residual_multiplier", 1.0))
+        self.logits_scale = float(c.get("logits_scaling", 1.0))
+        self.tied_head = bool(c.get("tie_word_embeddings", False))
         self.lanes = int(c.get("hc_mult", 1))
         self.residual = HYPER if self.lanes > 1 else PLAIN
         self.eps = float(c.get("rms_norm_eps", 1e-6))
@@ -189,6 +299,27 @@ class SequenceLM:
             self.head_dim = int(c["head_dim"])
             self.rotary = int(
                 self.head_dim * float(c.get("partial_rotary_factor", 1.0)))
+        if ATTENTION in self.layer_types:  # plain GQA, no positions
+            if c.get("position_embedding_type", "nope") != "nope":
+                raise ValueError('an "attention" layer takes no positions')
+            self.kv_heads = int(c["num_key_value_heads"])
+            self.head_dim = int(c.get("head_dim") or self.hidden // self.heads)
+            self.attn_scale = float(
+                c.get("attention_multiplier", self.head_dim ** -0.5))
+        if MAMBA in self.layer_types:  # Mamba-2
+            if int(c.get("mamba_n_groups", 1)) != 1:
+                raise ValueError("B and C are shared by all heads: mamba_n_groups 1")
+            self.ssm_heads = int(c["mamba_n_heads"])
+            self.ssm_head = int(c["mamba_d_head"])
+            self.ssm_state = int(c["mamba_d_state"])
+            self.ssm_conv = int(c["mamba_d_conv"])
+            self.ssm_inner = self.ssm_heads * self.ssm_head
+            if self.ssm_inner != int(c.get("mamba_expand", 2)) * self.hidden:
+                raise ValueError("mamba_n_heads x mamba_d_head is not the inner width")
+            self.ssm_conv_dim = self.ssm_inner + 2 * self.ssm_state
+            self.ssm_conv_bias = bool(c.get("mamba_conv_bias", True))
+            # how the published code computes a fragment, not a width
+            self.ssm_chunk = int(c.get("mamba_chunk_size", 256))
         if LINEAR in self.layer_types:  # gated deltanet
             self.k_heads = int(c["linear_num_key_heads"])
             self.v_heads = int(c["linear_num_value_heads"])
@@ -210,6 +341,8 @@ class SequenceLM:
                 self.rope_dim, self.theta, scaling)
             self.softmax_scale = latent_attention.yarn_softmax_scale(
                 self.nope + self.rope_dim, scaling)
+        if self.residual == HYPER and self.residual_scale != 1.0:
+            raise ValueError("residual_multiplier with hc_mult lanes is not defined")
         if self.residual == HYPER:
             # streams of a group of ``loss_groups``: a token's rows are
             # ``hc_mult`` times as wide
@@ -219,9 +352,14 @@ class SequenceLM:
             self.hc_clamp = (float(c.get("mhc_h_res_clamp_min", -30.0)),
                              float(c.get("mhc_h_res_clamp_max", 30.0)))
         if DENSE in self.ffn_types:
-            self.dense_width = int(c["intermediate_size"])
+            self.dense_width = int(
+                c.get("shared_intermediate_size", c.get("intermediate_size")))
+        # operands of the projections, the expert products, the head
+        # and the attention products; the cache's dtype
+        self.dtype = jnp.dtype(dtype)
+        if not experts:
+            return
         # experts: the router scores all of them, this chip holds some
-        experts = int(c["num_experts"] if "num_experts" in c else c["n_routed_experts"])
         self.router_outputs = int(c.get("router_outputs", experts))
         first, count = c.get("experts_held") or (0, experts)
         self.first_expert, self.experts_held = int(first), int(count)
@@ -238,9 +376,6 @@ class SequenceLM:
             c["shared_expert_intermediate_size"] if self.shared_gated
             else int(c.get("n_shared_experts", 1)) * self.expert_width
         )
-        # operands of the projections, the expert products, the head
-        # and the attention products; the cache's dtype
-        self.dtype = jnp.dtype(dtype)
 
     def partition_rules(self):
         return None
@@ -253,22 +388,28 @@ class SequenceLM:
 
     # -- state -----------------------------------------------------------
 
-    def _layer_state(self, state, n: int):
-        lo = sum(_STATE_LEAVES[k] for k in self.layer_types[:n])
-        return tuple(state[lo : lo + _STATE_LEAVES[self.layer_types[n]]])
+    def _segment_state(self, state, n: int):
+        lo = sum(_STATE_LEAVES[seg[1]] for seg in self.segments[:n])
+        return tuple(state[lo : lo + _STATE_LEAVES[self.segments[n][1]]])
 
     def initial_state(self, batch_size: int = 1):
         b = int(batch_size)
         state = []
-        for kind in self.layer_types:
-            if kind == LINEAR:
+        for _, kind, _, layers in self.segments:
+            if kind == MAMBA:
+                state.append(jnp.zeros(
+                    (b, layers, self.ssm_heads, self.ssm_head, self.ssm_state),
+                    jnp.float32))
+                state.append(jnp.zeros(
+                    (b, layers, self.ssm_conv - 1, self.ssm_conv_dim), jnp.float32))
+            elif kind == LINEAR:
                 state.append(
                     jnp.zeros((b, self.v_heads, self.dk, self.dv), jnp.float32)
                 )
                 state.append(
                     jnp.zeros((b, self.conv - 1, self.conv_dim), jnp.float32)
                 )
-            elif kind == FULL:
+            elif kind in (FULL, ATTENTION):
                 # one row a position: kv heads x head, flat, so that the
                 # device tiles (positions, row) without padding 2 heads to 8
                 shape = (b, self.positions, self.kv_heads * self.head_dim)
@@ -282,14 +423,14 @@ class SequenceLM:
         return tuple(state)
 
     def reset_state(self, state, mask):
-        """Open a new episode on the rows of ``mask``: the DeltaNet
-        matrices, the convolution inputs and the position go to zero;
-        a key/value or latent cache is left as it is, since only slots
-        below the position are ever read."""
+        """Open a new episode on the rows of ``mask``: the DeltaNet and
+        state-space matrices, the convolution inputs and the position
+        go to zero; a key/value or latent cache is left as it is, since
+        only slots below the position are ever read."""
         out = []
-        for n, kind in enumerate(self.layer_types):
-            for leaf in self._layer_state(state, n):
-                if kind == LINEAR:
+        for n, (_, kind, _, _) in enumerate(self.segments):
+            for leaf in self._segment_state(state, n):
+                if kind in (LINEAR, MAMBA):
                     m = mask.reshape((-1,) + (1,) * (leaf.ndim - 1))
                     leaf = jnp.where(m, jnp.zeros_like(leaf), leaf)
                 out.append(leaf)
@@ -300,14 +441,17 @@ class SequenceLM:
 
     def param_shapes(self) -> Dict[str, Dict[str, tuple]]:
         d, v = self.hidden, self.vocab
-        e, f, fs = self.experts_held, self.expert_width, self.shared_width
         shapes = {
             "embed": {"embedding": (v, d)},
             "final_norm": {"weight": (d,)},
             "head": {"kernel": (d, v)},
             "value": {"kernel": (d, 1), "bias": (1,)},
         }
-        for i, (kind, ffn) in enumerate(zip(self.layer_types, self.ffn_types)):
+        if self.tied_head:
+            del shapes["head"]
+        if EXPERTS in self.ffn_types:
+            e, f, fs = self.experts_held, self.expert_width, self.shared_width
+        for name, kind, ffn, layers in self.segments:
             layer = {"input_norm": (d,), "post_norm": (d,)}
             if ffn == EXPERTS:
                 layer.update(
@@ -343,6 +487,28 @@ class SequenceLM:
                     gdn_norm=(self.dv,),
                     out_proj=(self.value_dim, d),
                 )
+            elif kind == MAMBA:
+                layer.update(
+                    # columns [z | x | B | C | dt]
+                    in_proj=(d, 2 * self.ssm_inner + 2 * self.ssm_state
+                             + self.ssm_heads),
+                    conv=(self.ssm_conv_dim, self.ssm_conv),
+                    dt_bias=(self.ssm_heads,),
+                    A_log=(self.ssm_heads,),
+                    D=(self.ssm_heads,),
+                    ssm_norm=(self.ssm_inner,),
+                    out_proj=(self.ssm_inner, d),
+                )
+                if self.ssm_conv_bias:
+                    layer["conv_bias"] = (self.ssm_conv_dim,)
+                layer = {k: (layers,) + shape for k, shape in layer.items()}
+            elif kind == ATTENTION:
+                layer.update(
+                    q_proj=(d, self.heads * self.head_dim),
+                    k_proj=(d, self.kv_heads * self.head_dim),
+                    v_proj=(d, self.kv_heads * self.head_dim),
+                    o_proj=(self.heads * self.head_dim, d),
+                )
             elif kind == FULL:
                 layer.update(
                     q_proj=(d, self.heads * self.head_dim * 2),
@@ -363,7 +529,7 @@ class SequenceLM:
                     kv_b=(self.kv_latent, h * (self.nope + self.v_head)),
                     o_proj=(h * self.v_head, d),
                 )
-            shapes[f"layer_{i}"] = layer
+            shapes[name] = layer
         return shapes
 
     def init(self, rng, obs=None, state=None, **_):
@@ -372,9 +538,13 @@ class SequenceLM:
         published initialisation's forms at a scale that keeps the
         activations of a random model of order one. A hyper-connection
         starts near the plain residual (``a`` 0.01, ``b_res`` twice the
-        identity), a selection bias small and not zero."""
+        identity), a selection bias small and not zero. A state-space
+        layer starts as the family's does: ``A`` 1..heads, ``D`` one,
+        ``dt_bias`` the inverse softplus of a log-uniform step in
+        (0.001, 0.1)."""
         shapes = self.param_shapes()
         n = self.lanes
+        stacked = {seg[0] for seg in self.segments if seg[1] == MAMBA}
 
         @jax.jit
         def make(key):
@@ -390,6 +560,9 @@ class SequenceLM:
                 for leaf, shape in sorted(shapes[group].items()):
                     k = jax.random.fold_in(key, count)
                     count += 1
+                    if group in stacked:
+                        out[group][leaf] = _init_ssm_leaf(k, leaf, shape)
+                        continue
                     if leaf == "A_log":
                         x = jnp.log(jax.random.uniform(k, shape, minval=1.0, maxval=16.0))
                     elif leaf in ("dt_bias", "gdn_norm"):
@@ -442,7 +615,7 @@ class SequenceLM:
                 out["moe_max_tokens_per_held_expert"] = jnp.max(load)
             elif k == "moe_slots_on_absent_experts":
                 out[k] = v.sum(0)
-            elif k.endswith("_err_max"):
+            elif k.endswith("_max"):
                 out[k] = v.max(0)
             else:
                 out[k] = v.mean(0)
@@ -480,6 +653,8 @@ class SequenceLM:
         hyper = self.residual == HYPER
 
         x = jnp.take(params["embed"]["embedding"], tokens, axis=0)  # (B, T, D)
+        if self.embed_scale != 1.0:
+            x = x * self.embed_scale
         if hyper:  # the embedding copied into every lane, held flat
             x = jnp.tile(x, (1, 1, self.lanes))
 
@@ -487,15 +662,16 @@ class SequenceLM:
             ctx = dict(rows, scope=prefix)
             mixer = {
                 LINEAR: self._linear_attn, FULL: self._attn,
-                LATENT: self._latent_attn,
+                LATENT: self._latent_attn, MAMBA: self._mamba,
+                ATTENTION: self._plain_attn,
             }[kind]
             ffn = self._moe if ffn_kind == EXPERTS else self._mlp
             if hyper:
                 return self._hyper_block(x, p, layer_state, ctx, mixer, ffn)
             y, new = mixer(p, _rms(x, p["input_norm"], self.eps), layer_state, ctx)
-            x = x + y
+            x = x + self._scaled(y)
             y, load, routes = ffn(p, _rms(x, p["post_norm"], self.eps), ctx)
-            return x + y, new, load, routes
+            return x + self._scaled(y), new, load, routes
 
         # the plain residual groups the streams inside each block; with
         # lanes the policy groups them around the whole loss instead
@@ -527,17 +703,20 @@ class SequenceLM:
                     lambda r: r.reshape((-1,) + r.shape[2:]), routes),
             )
 
-        state_out, loads, all_routes, errs = [], [], [], []
-        for n in range(len(self.layer_types)):
-            x, new, load, routes = run_block(
-                x, params[f"layer_{n}"], self._layer_state(state, n), rows_ctx,
-                self.layer_types[n], self.ffn_types[n],
-            )
+        state_out, loads, all_routes, errs, steps_seen = [], [], [], [], []
+        for n, (name, kind, ffn_kind, _) in enumerate(self.segments):
+            args = (params[name], self._segment_state(state, n), rows_ctx,
+                    kind, ffn_kind)
+            if kind == MAMBA:
+                x, new, seen = self._run_of_layers(run_block, prefix, x, *args)
+                steps_seen.append(seen)
+            else:
+                x, new, load, routes = run_block(x, *args)
             state_out.extend(new)
             if hyper:
                 load, err = load
                 errs.append(err)
-            if self.ffn_types[n] == EXPERTS:
+            if ffn_kind == EXPERTS:
                 loads.append(load)
                 all_routes.append(routes)
         state_out.append(positions[:, -1] + 1)
@@ -546,12 +725,28 @@ class SequenceLM:
             if hyper:  # the lanes summed
                 x = sum(hyper_connection.lanes_of(x, self.lanes))
             feat = _rms(x, params["final_norm"]["weight"], self.eps).reshape(b * t, -1)
-            logits = self._dot(feat, params["head"]["kernel"])
+            if self.tied_head:  # the embedding, contracted over the hidden axis
+                logits = jax.lax.dot_general(
+                    feat.astype(self.dtype),
+                    params["embed"]["embedding"].astype(self.dtype),
+                    (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+                )
+            else:
+                logits = self._dot(feat, params["head"]["kernel"])
+            if self.logits_scale != 1.0:
+                logits = logits / self.logits_scale
             value = (
                 jnp.dot(feat, params["value"]["kernel"], precision=_HI)
                 + params["value"]["bias"]
             )[:, 0]
-        if stats_out is not None:
+        if stats_out is not None and steps_seen:
+            stats_out["ssm_dt_max"] = jnp.max(jnp.stack(steps_seen))
+        if stats_out is not None and not loads:
+            if "moe_routes" in stats_out:
+                # asked for every token's expert set where no layer
+                # routes: the one feed-forward there is, for every token
+                stats_out["moe_routes"] = jnp.zeros((1, b * t, 1), jnp.int32)
+        elif stats_out is not None:
             per_expert = jnp.stack([l[0] for l in loads])  # (layers, held)
             if hyper:
                 # per group of ``loss_groups``; ``reduce_group_stats``
@@ -602,6 +797,90 @@ class SequenceLM:
         err = tuple(jnp.maximum(a, b) for a, b in zip(e1, e2))
         return x, new, (load, err), routes
 
+    # -- a run of stacked layers -----------------------------------------
+
+    def _scaled(self, y):
+        return y if self.residual_scale == 1.0 else y * self.residual_scale
+
+    def _run_of_layers(self, run_block, scope, x, p, run_state, rows, kind, ffn_kind):
+        """A run of identical layers whose leaves are stacked on a
+        leading layer axis, as ONE ``lax.scan``: the layer is traced
+        once. ``run_state``'s leaves carry the layer axis after the
+        stream's; beside its two new leaves the mixer hands back the
+        largest step size each stream saw. Returns ``(x, state leaves,
+        largest step size)``."""
+        layers = jnp.arange(next(iter(p.values())).shape[0])
+        if x.shape[1] == 1:
+            # one token: the run's state rides in the carry and a layer
+            # reads and writes its own slice of it in place. The product
+            # weights are cast here, outside the scan over layers, so
+            # that they are loop-invariant in the lane's scan over steps
+            # and converted once a rollout, as an unstacked layer's are
+            p = {k: v.astype(self.dtype) if k in _RUN_PRODUCT_LEAVES else v
+                 for k, v in p.items()}
+
+            def step(carry, xs):
+                x, *leaves = carry
+                p_l, layer = xs
+                # the compiler fuses a layer's write of its matrix into
+                # the update of the slice: that fusion carries this scope
+                with jax.named_scope(scope + "ssm/carry"):
+                    mine = tuple(
+                        jax.lax.dynamic_index_in_dim(s, layer, 1, keepdims=False)
+                        for s in leaves)
+                x, (*new, seen), _, _ = run_block(x, p_l, mine, rows, kind, ffn_kind)
+                with jax.named_scope(scope + "ssm/carry"):
+                    leaves = [
+                        jax.lax.dynamic_update_index_in_dim(
+                            s, n.astype(s.dtype), layer, 1)
+                        for s, n in zip(leaves, new)]
+                return (x, *leaves), seen
+
+            (x, *leaves), seen = jax.lax.scan(step, (x, *run_state), (p, layers))
+            return x, tuple(leaves), jnp.max(seen)
+
+        def fragment(x, xs):
+            p_l, mine = xs
+            x, (*new, seen), _, _ = run_block(x, p_l, mine, rows, kind, ffn_kind)
+            return x, (tuple(new), seen)
+
+        x, (new, seen) = jax.lax.scan(
+            fragment, x, (p, tuple(jnp.moveaxis(s, 1, 0) for s in run_state)))
+        return x, tuple(jnp.moveaxis(s, 0, 1) for s in new), jnp.max(seen)
+
+    # -- state space (Mamba-2) -------------------------------------------
+
+    def _mamba(self, p, x, state, ctx):
+        scope = ctx["scope"] + "ssm"
+        s0, tail = state
+        b, t, _ = x.shape
+        inner, n, heads = self.ssm_inner, self.ssm_state, self.ssm_heads
+        with jax.named_scope(scope + "/in"):
+            zxbcdt = self._dot(x, p["in_proj"])
+            z = zxbcdt[..., :inner]
+            mixed = zxbcdt[..., inner : inner + self.ssm_conv_dim]
+            dt = jax.nn.softplus(zxbcdt[..., inner + self.ssm_conv_dim :] + p["dt_bias"])
+            a = -jnp.exp(p["A_log"])
+        with jax.named_scope(scope + "/conv"):
+            mixed, new_tail = _causal_conv(
+                tail, mixed, ctx["seg"], p["conv"], p.get("conv_bias"))
+        with jax.named_scope(scope + "/step"):
+            xs = mixed[..., :inner].reshape(b, t, heads, self.ssm_head)
+            bt, ct = mixed[..., inner : inner + n], mixed[..., inner + n :]
+            if t == 1:
+                s1, y = ssd.ssd_step(s0, xs[:, 0], dt[:, 0], a, bt[:, 0], ct[:, 0])
+                y = y[:, None]
+            else:
+                y, s1 = ssd.ssd_chunked(
+                    s0, xs, dt, a, bt, ct,
+                    resets=ctx["fresh"].astype(jnp.float32), chunk=self.ssm_chunk,
+                )
+            y = (y + p["D"][:, None] * xs).reshape(b, t, inner)
+        with jax.named_scope(scope + "/out"):
+            y = _rms(y * jax.nn.silu(z), p["ssm_norm"], self.eps)
+            # beside the state, the largest step size each stream saw
+            return self._dot(y, p["out_proj"]), (s1, new_tail, jnp.max(dt, axis=(1, 2)))
+
     # -- gated deltanet --------------------------------------------------
 
     def _linear_attn(self, p, x, state, ctx):
@@ -616,24 +895,7 @@ class SequenceLM:
             beta = jax.nn.sigmoid(ba[..., :hv])
             g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[..., hv:] + p["dt_bias"])
 
-            # causal depthwise convolution over the stored inputs and
-            # the fragment's own; an input of an earlier episode is
-            # not seen
-            width = self.conv
-            full = jnp.concatenate([tail, mixed], axis=1)  # (B, T+w-1, C)
-            full_seg = jnp.concatenate(
-                [jnp.zeros((b, width - 1), seg.dtype), seg], axis=1
-            )
-            conv = jnp.zeros_like(mixed)
-            for back in range(width):
-                lo = width - 1 - back
-                seen = (full_seg[:, lo : lo + t] == seg)[..., None]
-                conv = conv + jnp.where(
-                    seen, full[:, lo : lo + t], 0.0
-                ) * p["conv"][:, width - 1 - back]
-            mixed = jax.nn.silu(conv)
-            live = (full_seg[:, t:] == seg[:, -1:])[..., None]
-            new_tail = jnp.where(live, full[:, t:], 0.0)
+            mixed, new_tail = _causal_conv(tail, mixed, seg, p["conv"])
 
             q = mixed[..., :kd].reshape(b, t, hk, self.dk)
             k = mixed[..., kd : 2 * kd].reshape(b, t, hk, self.dk)
@@ -661,91 +923,113 @@ class SequenceLM:
 
     def _attn(self, p, x, state, ctx):
         with jax.named_scope(ctx["scope"] + "attn"):
-            k_cache, v_cache = state
             b, t, _ = x.shape
             h, hkv, d = self.heads, self.kv_heads, self.head_dim
-            seg, positions, pos0 = ctx["seg"], ctx["positions"], ctx["pos0"]
+            positions = ctx["positions"]
             qg = self._dot(x, p["q_proj"]).reshape(b, t, h, 2 * d)
             q, gate = qg[..., :d], qg[..., d:]
             k = self._dot(x, p["k_proj"]).reshape(b, t, hkv, d)
             v = self._dot(x, p["v_proj"]).reshape(b, t, hkv, d)
             q = _rope(_rms(q, p["q_norm"], self.eps), positions, self.rotary, self.theta)
             k = _rope(_rms(k, p["k_norm"], self.eps), positions, self.rotary, self.theta)
-            k, v = k.astype(self.dtype), v.astype(self.dtype)
+            o, new = self._cached_attention(q, k, v, state, ctx, d ** -0.5)
+            o = o * jax.nn.sigmoid(gate)
+            return self._dot(o.reshape(b, t, h * d), p["o_proj"]), new
 
-            # the cache after the fragment: the last episode's tokens,
-            # each at its position (positions of one episode are
-            # distinct; earlier episodes' tokens are dropped)
-            slot = jnp.where(seg == seg[:, -1:], positions, self.positions)
-            rows = jnp.arange(b)[:, None]
-            new_k = k_cache.at[rows, slot].set(
-                k.reshape(b, t, hkv * d).astype(k_cache.dtype), mode="drop")
-            new_v = v_cache.at[rows, slot].set(
-                v.reshape(b, t, hkv * d).astype(v_cache.dtype), mode="drop")
+    def _plain_attn(self, p, x, state, ctx):
+        """GQA softmax attention with no positions, no q/k norm and no
+        gate, scaled by ``attention_multiplier``."""
+        with jax.named_scope(ctx["scope"] + "attn"):
+            b, t, _ = x.shape
+            h, hkv, d = self.heads, self.kv_heads, self.head_dim
+            q = self._dot(x, p["q_proj"]).reshape(b, t, h, d)
+            k = self._dot(x, p["k_proj"]).reshape(b, t, hkv, d)
+            v = self._dot(x, p["v_proj"]).reshape(b, t, hkv, d)
+            o, new = self._cached_attention(q, k, v, state, ctx, self.attn_scale)
+            return self._dot(o.reshape(b, t, h * d), p["o_proj"]), new
 
-            scale = d ** -0.5
-            group = h // hkv
-            qh = (q * scale).astype(self.dtype).reshape(b, t, hkv, group, d)
-            slots = jnp.arange(self.positions)
+    def _cached_attention(self, q, k, v, state, ctx, scale):
+        """Causal attention of a fragment's ``q`` ``(B, T, heads, D)``
+        over the stored keys and values and the fragment's own ``k``,
+        ``v`` ``(B, T, kv heads, D)``. Returns ``(o (B, T, heads, D),
+        (keys, values) after the fragment)``."""
+        k_cache, v_cache = state
+        b, t, h, d = q.shape
+        hkv = self.kv_heads
+        seg, positions, pos0 = ctx["seg"], ctx["positions"], ctx["pos0"]
+        k, v = k.astype(self.dtype), v.astype(self.dtype)
 
-            def attend(qe, ke, ve, kc, vc, sege, pos0e):
-                kc = kc.reshape(kc.shape[:2] + (hkv, d))
-                vc = vc.reshape(vc.shape[:2] + (hkv, d))
-                # one block of envs: scores over the stored keys (a
-                # stored key is seen by the tokens before the first
-                # reset, below the start position) and the fragment's
-                # own (causal, same episode)
-                old = jnp.einsum(
-                    "btngd,bsnd->bngts", qe, kc, preferred_element_type=jnp.float32
-                )
-                see_old = (sege == 0)[:, :, None] & (
-                    slots[None, None] < pos0e[:, None, None]
-                )  # (b, t, S)
-                old = jnp.where(see_old[:, None, None], old, -jnp.inf)
-                if t == 1:
-                    # the step's own key is in the cache already
-                    own = jnp.full(old.shape[:-1] + (0,), -jnp.inf)
-                else:
-                    own = jnp.einsum(
-                        "btngd,bsnd->bngts", qe, ke,
-                        preferred_element_type=jnp.float32,
-                    )
-                    see = (steps_t[:, None] >= steps_t[None, :])[None] & (
-                        sege[:, :, None] == sege[:, None, :]
-                    )
-                    own = jnp.where(see[:, None, None], own, -jnp.inf)
-                w = jax.nn.softmax(jnp.concatenate([old, own], axis=-1), axis=-1)
-                w = w.astype(self.dtype)
-                out = jnp.einsum(
-                    "bngts,bsnd->btngd", w[..., : self.positions], vc,
+        # the cache after the fragment: the last episode's tokens,
+        # each at its position (positions of one episode are
+        # distinct; earlier episodes' tokens are dropped)
+        slot = jnp.where(seg == seg[:, -1:], positions, self.positions)
+        rows = jnp.arange(b)[:, None]
+        new_k = k_cache.at[rows, slot].set(
+            k.reshape(b, t, hkv * d).astype(k_cache.dtype), mode="drop")
+        new_v = v_cache.at[rows, slot].set(
+            v.reshape(b, t, hkv * d).astype(v_cache.dtype), mode="drop")
+
+        group = h // hkv
+        qh = (q * scale).astype(self.dtype).reshape(b, t, hkv, group, d)
+        slots = jnp.arange(self.positions)
+
+        def attend(qe, ke, ve, kc, vc, sege, pos0e):
+            kc = kc.reshape(kc.shape[:2] + (hkv, d))
+            vc = vc.reshape(vc.shape[:2] + (hkv, d))
+            # one block of envs: scores over the stored keys (a
+            # stored key is seen by the tokens before the first
+            # reset, below the start position) and the fragment's
+            # own (causal, same episode)
+            old = jnp.einsum(
+                "btngd,bsnd->bngts", qe, kc, preferred_element_type=jnp.float32
+            )
+            see_old = (sege == 0)[:, :, None] & (
+                slots[None, None] < pos0e[:, None, None]
+            )  # (b, t, S)
+            old = jnp.where(see_old[:, None, None], old, -jnp.inf)
+            if t == 1:
+                # the step's own key is in the cache already
+                own = jnp.full(old.shape[:-1] + (0,), -jnp.inf)
+            else:
+                own = jnp.einsum(
+                    "btngd,bsnd->bngts", qe, ke,
                     preferred_element_type=jnp.float32,
                 )
-                if t > 1:
-                    out = out + jnp.einsum(
-                        "bngts,bsnd->btngd", w[..., self.positions :], ve,
-                        preferred_element_type=jnp.float32,
-                    )
-                return out
+                see = (steps_t[:, None] >= steps_t[None, :])[None] & (
+                    sege[:, :, None] == sege[:, None, :]
+                )
+                own = jnp.where(see[:, None, None], own, -jnp.inf)
+            w = jax.nn.softmax(jnp.concatenate([old, own], axis=-1), axis=-1)
+            w = w.astype(self.dtype)
+            out = jnp.einsum(
+                "bngts,bsnd->btngd", w[..., : self.positions], vc,
+                preferred_element_type=jnp.float32,
+            )
+            if t > 1:
+                out = out + jnp.einsum(
+                    "bngts,bsnd->btngd", w[..., self.positions :], ve,
+                    preferred_element_type=jnp.float32,
+                )
+            return out
 
-            steps_t = jnp.arange(t)
-            if t == 1:
-                # decode reads the cache it has just written: the own
-                # key sits at slot pos0, so the stored range is one longer
-                o = attend(qh, k, v, new_k, new_v, seg, pos0 + 1)
-            else:
-                nb = max(1, b // _ATTN_ENV_BLOCK)
-                if b % nb:
-                    nb = 1
-                args = (qh, k, v, k_cache, v_cache, seg, pos0)
-                blocked = tuple(
-                    a.reshape((nb, b // nb) + a.shape[1:]) for a in args
-                )
-                o = jax.lax.map(
-                    lambda xs: jax.checkpoint(attend)(*xs), blocked
-                )
-                o = o.reshape((b,) + o.shape[2:])
-            o = o.reshape(b, t, h, d) * jax.nn.sigmoid(gate)
-            return self._dot(o.reshape(b, t, h * d), p["o_proj"]), (new_k, new_v)
+        steps_t = jnp.arange(t)
+        if t == 1:
+            # decode reads the cache it has just written: the own
+            # key sits at slot pos0, so the stored range is one longer
+            o = attend(qh, k, v, new_k, new_v, seg, pos0 + 1)
+        else:
+            nb = max(1, b // _ATTN_ENV_BLOCK)
+            if b % nb:
+                nb = 1
+            args = (qh, k, v, k_cache, v_cache, seg, pos0)
+            blocked = tuple(
+                a.reshape((nb, b // nb) + a.shape[1:]) for a in args
+            )
+            o = jax.lax.map(
+                lambda xs: jax.checkpoint(attend)(*xs), blocked
+            )
+            o = o.reshape((b,) + o.shape[2:])
+        return o.reshape(b, t, h, d), (new_k, new_v)
 
     # -- latent attention ------------------------------------------------
 

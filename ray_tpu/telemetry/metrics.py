@@ -123,6 +123,11 @@ MOE_PRODUCT_LOWERINGS_TOTAL = "ray_tpu_moe_product_lowerings_total"
 # Counted when the form is traced: once per latent layer body of a
 # program (layers whose checkpointed block is the same trace once)
 MLA_DECODE_LOWERINGS_TOTAL = "ray_tpu_mla_decode_lowerings_total"
+# which lowering each traced one-token state-space step took
+# (ops/ssd.py): path = xla (the jax.numpy body; there is no kernel).
+# Counted when the form is traced: once per body of a run of stacked
+# layers (a run is one scan, so its layers share one trace)
+SSM_STEP_LOWERINGS_TOTAL = "ray_tpu_ssm_step_lowerings_total"
 # prioritized-replay segment-tree operations by op and by which tree
 # implementation performed them (docs/data_plane.md "device sum
 # tree"): host = the numpy SumSegmentTree walk, device = the
@@ -572,6 +577,20 @@ def inc_mla_decode_lowering(form: str) -> None:
 def mla_decode_lowerings() -> Dict[str, float]:
     """``{form: traced latent-attention layers}`` since the process began."""
     return _totals_by_tag(MLA_DECODE_LOWERINGS_TOTAL, "form")
+
+
+def inc_ssm_step_lowering(path: str) -> None:
+    """One traced one-token state-space step took ``path`` (``xla``)."""
+    counter(
+        SSM_STEP_LOWERINGS_TOTAL,
+        "one-token state-space steps traced, by the lowering they took",
+        ("path",),
+    ).inc(1.0, {"path": path})
+
+
+def ssm_step_lowerings() -> Dict[str, float]:
+    """``{path: traced one-token steps}`` since the process began."""
+    return _totals_by_tag(SSM_STEP_LOWERINGS_TOTAL, "path")
 
 
 def deltanet_step_lowerings() -> Dict[str, float]:
