@@ -820,21 +820,19 @@ class SequenceLM:
                  for k, v in p.items()}
 
             def step(carry, xs):
-                x, *leaves = carry
+                x, matrices, tails = carry
                 p_l, layer = xs
-                # the compiler fuses a layer's write of its matrix into
-                # the update of the slice: that fusion carries this scope
+                # the matrices go to the mixer whole with the layer's
+                # index (``ssd.ssd_step`` updates that layer where it
+                # lies); the convolution tail, 4 MB a run, as a slice
                 with jax.named_scope(scope + "ssm/carry"):
-                    mine = tuple(
-                        jax.lax.dynamic_index_in_dim(s, layer, 1, keepdims=False)
-                        for s in leaves)
-                x, (*new, seen), _, _ = run_block(x, p_l, mine, rows, kind, ffn_kind)
+                    tail = jax.lax.dynamic_index_in_dim(tails, layer, 1, keepdims=False)
+                x, (matrices, tail, seen), _, _ = run_block(
+                    x, p_l, ((matrices, layer), tail), rows, kind, ffn_kind)
                 with jax.named_scope(scope + "ssm/carry"):
-                    leaves = [
-                        jax.lax.dynamic_update_index_in_dim(
-                            s, n.astype(s.dtype), layer, 1)
-                        for s, n in zip(leaves, new)]
-                return (x, *leaves), seen
+                    tails = jax.lax.dynamic_update_index_in_dim(
+                        tails, tail.astype(tails.dtype), layer, 1)
+                return (x, matrices, tails), seen
 
             (x, *leaves), seen = jax.lax.scan(step, (x, *run_state), (p, layers))
             return x, tuple(leaves), jnp.max(seen)
@@ -867,8 +865,10 @@ class SequenceLM:
         with jax.named_scope(scope + "/step"):
             xs = mixed[..., :inner].reshape(b, t, heads, self.ssm_head)
             bt, ct = mixed[..., inner : inner + n], mixed[..., inner + n :]
-            if t == 1:
-                s1, y = ssd.ssd_step(s0, xs[:, 0], dt[:, 0], a, bt[:, 0], ct[:, 0])
+            if t == 1:  # the run's stacked matrices and this layer's index
+                stacked, layer = s0
+                s1, y = ssd.ssd_step(
+                    stacked, xs[:, 0], dt[:, 0], a, bt[:, 0], ct[:, 0], layer=layer)
                 y = y[:, None]
             else:
                 y, s1 = ssd.ssd_chunked(

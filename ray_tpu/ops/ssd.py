@@ -11,16 +11,41 @@ state size), a step size ``dt_t > 0``, a scalar ``A < 0`` a head, and
 (the skip ``D x_t`` and the gate are the caller's).
 
 - :func:`ssd_step` is that recurrence for ONE token: the rollout
-  lane's decode step (state in, state out). Plain ``jax.numpy``: the
-  write is elementwise and the read a reduction of its result, with no
-  reduction BEFORE the write as the delta rule has, so there is no
-  kernel; ``ray_tpu_ssm_step_lowerings_total{path="xla"}`` counts, at
-  trace time, each traced one-token form.
+  lane's decode step (state in, state out).
 - :func:`ssd_chunked` computes a fragment of ``T`` tokens from a start
   state in chunks of ``C``: inside a chunk token ``i`` reads token
   ``j <= i`` through ``exp(sum_{j < l <= i} dt_l A) (C_i . B_j) dt_j``
   (the semiseparable matrix, all matrix products) and only the
   chunk-end state is carried: the learn program's form.
+
+**The one-token form has two lowerings of one algorithm**, picked by
+what the code can see when it is traced, never by an option:
+
+- :func:`ssd_step_kernel`, a Pallas (Mosaic) kernel, where the default
+  backend is a TPU and the state is a run's STACKED float32 leaf
+  ``(streams, layers, H, P, N)`` with ``N`` whole 128-lane tiles
+  (:func:`_kernel_applies`). It takes the leaf whole and the layer's
+  index: the index rides as a scalar-prefetch operand into the
+  matrices' index map and the leaf's buffer is the call's own output
+  (``input_output_aliases``), so only that layer's blocks cross HBM,
+  ONCE in and ONCE out, 8 bytes an element, and the other layers lie
+  untouched in the same buffer. (Fed a layer's slice and followed by an
+  update of the slice, a kernel would make the compiler materialise
+  the slice and copy it back: four passes.) A block of heads of one
+  stream sits in VMEM while both lines run on it; ``y`` is read from
+  the block just written, its sum over the lanes on the MXU
+  (:func:`_lane_sums`), which is idle otherwise.
+- :func:`_step_body`, the two lines in ``jax.numpy``, everywhere else
+  (the CPU, odd sizes, a state without a layer axis; on a stacked leaf
+  around a dynamic slice and its update in place). It is the statement
+  of the function and the kernel's reference. XLA does not put the
+  in-place update of a slice and a reduction over its result into one
+  fusion: on a TPU this body writes the matrices in one pass (67 MB in
+  and out a layer in 102 us at the granite cell's size) and reads the
+  OLD matrices again in a second (34 MB, 48 us) to compute ``y``.
+
+``ray_tpu_ssm_step_lowerings_total{path="kernel"|"xla"}`` counts, at
+trace time, which one each traced one-token form took.
 
 **Resets.** ``resets`` (1.0 where a token begins a new episode) zero
 the state before that token. In the chunked form a reset splits its
@@ -29,33 +54,170 @@ out, and the start state reaches only the tokens before the first
 reset. The one-token form has no argument for it; its caller zeroes
 the rows first (``SequenceLM.reset_state``), as for ``ops/deltanet.py``.
 
-Everything here is float32 at precision "highest": the state is an
-accumulator over the whole episode, and the PPO ratio divides what the
-chunked form says by what the recurrence said.
+Everything here is float32 at precision "highest" (the kernel:
+float32 multiply-adds on the VPU, and a sum whose addends reach the
+MXU as exact pieces): the state is an accumulator over the whole
+episode, and the PPO ratio divides what the chunked form says by what
+the recurrence said.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.telemetry import metrics as telemetry_metrics
 
 _HI = jax.lax.Precision.HIGHEST
 
+# heads of one stream a grid step holds in VMEM: 1 MB of matrices in and
+# out at 64 x 128 (on the v5e 16 heads were 1.1% slower, 64 0.7% faster)
+_KERNEL_HEADS = 32
 
-def ssd_step(state, x, dt, a, b, c):
+
+def ssd_step(state, x, dt, a, b, c, layer=None):
     """One token. ``state`` ``(..., H, P, N)``; ``x`` ``(..., H, P)``;
     ``dt`` ``(..., H)``; ``a`` ``(H,)``; ``b``, ``c`` ``(..., N)``.
-    Returns ``(state, y)`` with ``y`` ``(..., H, P)``."""
+    Returns ``(state, y)`` with ``y`` ``(..., H, P)``.
+
+    With ``layer`` (an int32 scalar) ``state`` is a run's stacked leaf
+    ``(B, layers, H, P, N)`` of which that layer's matrices take the
+    step: the leaf comes back whole, the other layers as they were."""
+    if layer is not None and _kernel_applies(state):
+        telemetry_metrics.inc_ssm_step_lowering("kernel")
+        return ssd_step_kernel(state, layer, x, dt, a, b, c)
     telemetry_metrics.inc_ssm_step_lowering("xla")
+    if layer is None:
+        return _step_body(state, x, dt, a, b, c)
+    return _stacked_step_body(state, layer, x, dt, a, b, c)
+
+
+def _step_body(state, x, dt, a, b, c):
     decay = jnp.exp(dt * a)[..., None, None]
     write = (dt[..., None] * x)[..., None] * b[..., None, None, :]
     state = decay * state + write
     y = jnp.sum(state * c[..., None, None, :], axis=-1)
     return state, y
+
+
+def _stacked_step_body(state, layer, x, dt, a, b, c):
+    """:func:`_step_body` on layer ``layer`` of a stacked leaf: a
+    dynamic slice, and its update in place."""
+    mine = jax.lax.dynamic_index_in_dim(state, layer, 1, keepdims=False)
+    mine, y = _step_body(mine, x, dt, a, b, c)
+    return jax.lax.dynamic_update_index_in_dim(
+        state, mine.astype(state.dtype), layer, 1), y
+
+
+def _kernel_applies(state) -> bool:
+    """The kernel's lowering exists for a TPU, for a stacked float32
+    leaf ``(streams, layers, H, P, N)`` with ``N`` whole 128-lane tiles
+    and ``P`` and the heads whole 8-sublane tiles (a matrix's rows; ``x``
+    is turned from lanes to sublanes a block of heads at a time). "A
+    TPU" is the process's default backend, as in ``ops/deltanet.py``."""
+    if jax.default_backend() != "tpu" or state.ndim != 5:
+        return False
+    heads, p, n = state.shape[-3:]
+    return (
+        state.dtype == jnp.float32
+        and n % 128 == 0 and p % 8 == 0 and heads % 8 == 0
+    )
+
+
+def _lane_sums(t, ones):
+    """The sums over the lanes of float32 ``t`` ``(P, N)`` as a ``(1,
+    P)`` row, on the MXU at float32 accuracy: ``t`` in three bfloat16
+    pieces (three times 8 bits of mantissa, all a float32 has), each
+    against ``ones`` ``(8, N)`` (exact products) with float32
+    accumulation; ``ones . piece^T`` puts ``P`` on the lanes. The XLU's
+    lane reduction beside the column broadcast of the write held the
+    kernel at 130 us a call on the v5e where a copy takes 103."""
+    total = None
+    for _ in range(3):
+        piece = t.astype(jnp.bfloat16)
+        t = t - piece.astype(jnp.float32)
+        part = jax.lax.dot_general(
+            ones, piece, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        total = part if total is None else total + part
+    return total[:1]
+
+
+def _ssd_step_kernel(layer_ref, decay_ref, dtx_ref, b_ref, c_ref, s_ref,
+                     s_out_ref, y_ref):
+    """One stream, a block of heads of the layer ``layer_ref`` names (the
+    index maps have used it). ``s_ref`` ``(1, 1, H, P, N)``; ``decay``
+    ``(1, H, N)``, a head's scalar repeated along the lanes; ``dtx``
+    ``(1, H, P)`` arrives with ``P`` on the lanes and is turned once a
+    block, so that a head's column broadcasts along the lanes of its
+    matrix; ``b``, ``c`` ``(1, 1, N)`` rows every head shares. The read
+    is taken from the block just written, while it is in VMEM."""
+    del layer_ref
+    heads, _, n = s_ref.shape[2:]
+    dtx_cols = dtx_ref[0].T  # (P, H)
+    decay, b, c = decay_ref[0], b_ref[0], c_ref[0]
+    ones = jnp.ones((8, n), jnp.bfloat16)
+    rows = []
+    for h in range(heads):
+        s = s_ref[0, 0, h] * decay[h : h + 1] + dtx_cols[:, h : h + 1] * b
+        s_out_ref[0, 0, h] = s
+        rows.append(_lane_sums(s * c, ones))
+    y_ref[0] = jnp.concatenate(rows, axis=0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ssd_step_kernel(state, layer, x, dt, a, b, c, *, interpret=False):
+    """:func:`ssd_step` on layer ``layer`` of a stacked leaf as one
+    Pallas call over ``(streams, heads / block)``: ``state`` ``(B,
+    layers, H, P, N)`` float32, aliased in to out. ``layer`` rides as a
+    scalar-prefetch operand into the matrices' index map, so only that
+    layer's blocks cross HBM, once in and once out, and the other
+    layers lie untouched in the same buffer. ``interpret`` runs it in
+    the Pallas interpreter (the CPU tests); nothing upstream passes it.
+    A ``jit`` of its own, so that a program with many call sites (the
+    act, the truncation's value forward and the tail's, two runs each)
+    traces and lowers the kernel once a leaf shape."""
+    from ray_tpu import sharding as sharding_lib
+
+    bsz, _, h, p, n = state.shape
+    heads = _KERNEL_HEADS if h % _KERNEL_HEADS == 0 else 8
+    f32 = lambda v: v.astype(state.dtype)
+    decay = jnp.broadcast_to(f32(jnp.exp(dt * a))[..., None], (bsz, h, n))
+    dtx = f32(dt[..., None] * x)
+    # inside a ``shard_map`` the outputs vary over the mesh axes the
+    # inputs do
+    vma = sharding_lib.vma_of((state, x, dt, b, c))
+    rows = lambda width: pl.BlockSpec(
+        (1, heads, width), lambda i, j, layer: (i, j, 0))
+    shared = pl.BlockSpec((1, 1, n), lambda i, j, layer: (i, 0, 0))
+    matrices = pl.BlockSpec(
+        (1, 1, heads, p, n), lambda i, j, layer: (i, layer[0], j, 0, 0))
+    return pl.pallas_call(
+        _ssd_step_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bsz, h // heads),
+            in_specs=[rows(n), rows(p), shared, shared, matrices],
+            out_specs=[matrices, rows(p)],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(state.shape, state.dtype, vma=vma),
+            jax.ShapeDtypeStruct(x.shape, state.dtype, vma=vma),
+        ],
+        # operand 5 counts the prefetched scalar
+        input_output_aliases={5: 0},
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")
+        ),
+        name="ssd_step",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), decay, dtx,
+      f32(b)[:, None], f32(c)[:, None], state)
 
 
 def ssd_chunked(

@@ -124,9 +124,11 @@ MOE_PRODUCT_LOWERINGS_TOTAL = "ray_tpu_moe_product_lowerings_total"
 # program (layers whose checkpointed block is the same trace once)
 MLA_DECODE_LOWERINGS_TOTAL = "ray_tpu_mla_decode_lowerings_total"
 # which lowering each traced one-token state-space step took
-# (ops/ssd.py): path = xla (the jax.numpy body; there is no kernel).
-# Counted when the form is traced: once per body of a run of stacked
-# layers (a run is one scan, so its layers share one trace)
+# (ops/ssd.py): path = kernel (the Pallas kernel on the run's stacked
+# leaf: a TPU backend, float32, whole-tile sizes) | xla (the jax.numpy
+# body, everywhere else). Counted when the form is traced: once per
+# body of a run of stacked layers (a run is one scan, so its layers
+# share one trace)
 SSM_STEP_LOWERINGS_TOTAL = "ray_tpu_ssm_step_lowerings_total"
 # prioritized-replay segment-tree operations by op and by which tree
 # implementation performed them (docs/data_plane.md "device sum
@@ -580,7 +582,8 @@ def mla_decode_lowerings() -> Dict[str, float]:
 
 
 def inc_ssm_step_lowering(path: str) -> None:
-    """One traced one-token state-space step took ``path`` (``xla``)."""
+    """One traced one-token state-space step took ``path`` (``kernel``
+    or ``xla``)."""
     counter(
         SSM_STEP_LOWERINGS_TOTAL,
         "one-token state-space steps traced, by the lowering they took",

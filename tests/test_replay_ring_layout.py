@@ -315,3 +315,54 @@ def test_v5e_delta_step_kernel_compiles_in_place(v5e_mesh):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == b * h * dk * dv * 4
     assert mem.temp_size_in_bytes < 16e6
+
+
+def test_v5e_ssd_step_kernel_compiles_in_place(v5e_mesh):
+    """The one-token state-space kernel (ops/ssd.py) at the granite
+    cell's width, a run of 5 layers of 16 streams x 64 heads of 64 x 128,
+    as the lane runs it: the run's scan over layers chained in a scan
+    of 2 steps under ``shard_map``, the leaf donated. Mosaic takes it,
+    the 168 MB leaf is written where it lies (aliased through both
+    scans and the call: no second copy, no scratch), and the device op
+    carries the caller's scopes, under which the trace files its time."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.ops import ssd
+
+    b, layers, h, p, n = 16, 5, 64, 64, 128
+    axis = sharding_lib.data_axis(v5e_mesh)
+    rows = sharding_lib.batch_sharded(v5e_mesh)
+    on = lambda *shape: jax.ShapeDtypeStruct(shape, np.float32, sharding=rows)
+
+    def steps(leaf, x, dt, a, bb, cc):
+        def run(leaf, _):
+            def layer(carry, i):
+                leaf, y = carry
+                with jax.named_scope("rollout/act"), jax.named_scope("ssm/step"):
+                    return ssd.ssd_step_kernel(leaf, i, x + y, dt, a, bb, cc), None
+
+            return jax.lax.scan(
+                layer, (leaf, jnp.zeros_like(x)), jnp.arange(layers))[0]
+
+        return jax.lax.scan(run, leaf, None, length=2)
+
+    sharded = jax.shard_map(
+        steps, mesh=v5e_mesh,
+        in_specs=(P(axis), P(axis), P(axis), P(), P(axis), P(axis)),
+        out_specs=(P(axis), P(None, axis)),
+    )
+    compiled = (
+        jax.jit(sharded, donate_argnums=(0,))
+        .lower(on(b, layers, h, p, n), on(b, h, p), on(b, h),
+               _on(v5e_mesh, (h,), np.float32), on(b, n), on(b, n))
+        .compile()
+    )
+    calls = [
+        line for line in compiled.as_text().splitlines()
+        if "tpu_custom_call" in line and "custom-call(" in line
+    ]
+    assert calls and all(
+        re.search(r"rollout/act.*ssm/step", line) for line in calls)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == b * layers * h * p * n * 4
+    assert mem.temp_size_in_bytes < 16e6

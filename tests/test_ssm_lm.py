@@ -331,6 +331,51 @@ def test_a_run_of_layers_is_traced_once(setup):
     assert metrics.ssm_step_lowerings()["xla"] == before + 2
 
 
+def test_one_token_form_takes_the_kernel_on_a_tpu_and_the_body_here(monkeypatch):
+    """At whole-tile sizes the model's one-token form counts one traced
+    step a run and call site, ``kernel`` where the backend is a TPU and
+    ``xla`` on the CPU, with nothing passed down to say so; on the TPU
+    each run's matrices pass through the kernel's call whole and no
+    equation of the program makes a layer's slice of them."""
+    from ray_tpu.telemetry import metrics
+
+    lm = small_config(mamba_d_state=128)["algo_config"]["model"]["sequence_lm"]
+    model = SequenceLM(VOCAB, lm, dtype="float32")
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(lambda: model.initial_state(2))
+    obs = jax.ShapeDtypeStruct((2, 1, 1), jnp.int32)
+
+    def traced():
+        before = dict(metrics.ssm_step_lowerings())
+        jaxpr = jax.make_jaxpr(lambda p, o, s: model.apply(p, o, s))(params, obs, state)
+        after = metrics.ssm_step_lowerings()
+        return jaxpr, {k: after.get(k, 0) - before.get(k, 0) for k in ("kernel", "xla")}
+
+    jaxpr, took = traced()
+    assert took == {"kernel": 0, "xla": 2}
+    assert "pallas_call" not in str(jaxpr)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jaxpr, took = traced()
+    assert took == {"kernel": 2, "xla": 0}
+
+    def equations(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(inner)
+
+    made = [
+        (eqn.primitive.name, var.aval.shape)
+        for eqn in equations(jaxpr.jaxpr) for var in eqn.outvars
+        if getattr(var.aval, "shape", ())[-3:] == (8, 8, 128)
+    ]
+    # the leaves (2, layers, 8, 8, 128) leave the kernel's call, its jit
+    # and the scans that carry them, and nothing makes a (2, 8, 8, 128)
+    assert {name for name, _ in made} == {"pallas_call", "jit", "scan"}
+    assert {shape for _, shape in made} == {(2, 2, 8, 8, 128), (2, 3, 8, 8, 128)}
+    assert sum(name == "pallas_call" for name, _ in made) == 2
+
+
 def test_reset_state_clears_the_recurrence_and_keeps_the_cache(setup):
     config, params, model, batch = setup
     state = _f32_state(ref.batch_state(batch))
