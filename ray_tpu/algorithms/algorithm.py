@@ -22,6 +22,7 @@ from ray_tpu.env.registry import get_env_creator
 from ray_tpu.evaluation.metrics import summarize_episodes
 from ray_tpu.evaluation.worker_set import WorkerSet
 from ray_tpu.tune.trainable import Trainable
+from ray_tpu.util import tracing
 
 NUM_ENV_STEPS_SAMPLED = "num_env_steps_sampled"
 NUM_AGENT_STEPS_SAMPLED = "num_agent_steps_sampled"
@@ -42,7 +43,8 @@ class Algorithm(Trainable):
             config.setdefault("env", env)
         defaults = self.get_default_config().to_dict()
         merged = {**defaults, **config}
-        super().__init__(merged, logger_creator)
+        with tracing.phase("setup:algorithm", algorithm=type(self).__name__):
+            super().__init__(merged, logger_creator)
 
     def get_default_policy_class(self, config: Dict):
         return self._default_policy_class
@@ -164,14 +166,16 @@ class Algorithm(Trainable):
                         {},
                     )
 
-        self.workers = WorkerSet(
-            env_creator=env_creator,
-            policy_cls=policy_cls,
-            policy_specs=policy_specs,
-            policy_mapping_fn=policy_mapping_fn,
-            config=config,
-            num_workers=int(config.get("num_workers", 0)),
-        )
+        num_workers = int(config.get("num_workers", 0))
+        with tracing.phase("setup:workers", num_workers=num_workers):
+            self.workers = WorkerSet(
+                env_creator=env_creator,
+                policy_cls=policy_cls,
+                policy_specs=policy_specs,
+                policy_mapping_fn=policy_mapping_fn,
+                config=config,
+                num_workers=num_workers,
+            )
         # non-worker episode sources (the device rollout lane's
         # engine, drained fleet workers): callables returning
         # RolloutMetrics lists, read by _collect_rollout_metrics
@@ -278,7 +282,6 @@ class Algorithm(Trainable):
     def step(self) -> Dict:
         """reference algorithm.py:547 (incl. worker-failure handling)."""
         from ray_tpu import telemetry as telemetry_lib
-        from ray_tpu.util import tracing
 
         config = self.config
         t0 = time.time()
@@ -357,7 +360,6 @@ class Algorithm(Trainable):
         roll-ups (the ``*_before`` arguments are the counter readings
         taken at ``t0``), rollout metrics, evaluation, callbacks."""
         from ray_tpu import telemetry as telemetry_lib
-        from ray_tpu.util import tracing
 
         config = self.config
         results: Dict[str, Any] = {}
@@ -413,6 +415,16 @@ class Algorithm(Trainable):
             results["info"]["device_ledger"] = (
                 telemetry_lib.device.snapshot()
             )
+        if self._iteration == 0:
+            # where the seconds before this iteration went: the
+            # build's steps and every compile by family and phase,
+            # kept whether or not tracing is on
+            from ray_tpu.sharding.compile import compile_stats
+
+            results["info"]["setup"] = {
+                "phases": tracing.phases(),
+                "compile": compile_stats()["families"],
+            }
         if tracing.is_enabled():
             # roll up THIS iteration's window first: worker rollout
             # spans ride the result messages and are harvested (→
@@ -424,46 +436,45 @@ class Algorithm(Trainable):
             # is still in flight at the edge (no sample span landed in
             # it yet) fall back to the previous, now-settled window —
             # `window_iterations_ago` says which one this is.
-            spans = tracing.get_spans()
+            # Each span is read ONCE: the cursor hands over what
+            # finished since the last roll-up (the spans that can lie
+            # in this window, and the late ones below), so an
+            # iteration's cost does not grow with the span buffer.
+            fresh, self._span_cursor = tracing.spans_since(
+                getattr(self, "_span_cursor", 0)
+            )
             # late-harvest accounting (fleetview satellite): a span
             # first seen THIS iteration whose interval ended before a
             # window opened missed that window's roll-up entirely —
             # credit its full duration to the window we report now
-            # instead of dropping it (late_stage_times)
-            seen = getattr(self, "_rollup_seen_span_ids", frozenset())
-            fresh = [
-                s for s in spans if s.get("span_id") not in seen
-            ]
-            self._rollup_seen_span_ids = frozenset(
-                s.get("span_id") for s in spans
-            )
-            # spans from before the first window ever rolled up (worker
-            # init, compile warmup) belong to NO window — not late
+            # instead of dropping it (late_stage_times). Spans from
+            # before the first window ever rolled up (worker init,
+            # compile warmup) belong to NO window — not late
             first = getattr(self, "_first_window_start", None)
             if first is None:
                 self._first_window_start = first = t0
 
             def _late_for(window_start):
-                out = []
-                for s in fresh:
-                    end = s.get("end") or s.get("start")
-                    if end is None:
-                        continue
-                    if first <= end <= window_start:
-                        out.append(s)
-                return out
+                return [
+                    s for s in fresh
+                    if first
+                    <= (s.get("end") or s.get("start") or 0.0)
+                    <= window_start
+                ]
 
             rollup = telemetry_lib.iteration_rollup(
-                spans, t0, t_train_end, late=_late_for(t0)
+                fresh, t0, t_train_end, late=_late_for(t0)
             )
             lag = 0
             prev = getattr(self, "_prev_iter_window", None)
             if rollup["sample_s"] == 0.0 and prev is not None:
                 settled = telemetry_lib.iteration_rollup(
-                    spans, *prev, late=_late_for(prev[0])
+                    getattr(self, "_prev_iter_spans", []) + fresh, *prev,
+                    late=_late_for(prev[0]),
                 )
                 if settled["sample_s"] > 0.0:
                     rollup, lag = settled, 1
+            self._prev_iter_spans = fresh
             rollup["window_iterations_ago"] = lag
             # per-iteration H2D bytes by path (docs/data_plane.md):
             # feeder/learn/replay_insert deltas next to the stage busy
@@ -705,8 +716,6 @@ class Algorithm(Trainable):
         recorded). ``last_n`` keeps only the last N train iterations,
         bounded by the span buffer (``RAY_TPU_TRACE_BUFFER``). Load at
         chrome://tracing or https://ui.perfetto.dev."""
-        from ray_tpu.util import tracing
-
         since = None
         marks = getattr(self, "_iteration_marks", None)
         if last_n and marks:

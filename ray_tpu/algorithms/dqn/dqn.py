@@ -834,14 +834,15 @@ class DQN(Algorithm):
                 1, int(self.config.get("num_workers", 0))
             )
             T = int(self.config.get("rollout_fragment_length", 4))
-            eng = JaxRolloutEngine(
-                policy,
-                env,
-                N,
-                T,
-                seed=self.config.get("seed"),
-                postprocess="none",
-            )
+            with tracing.phase("setup:rollout_engine", num_envs=N):
+                eng = JaxRolloutEngine(
+                    policy,
+                    env,
+                    N,
+                    T,
+                    seed=self.config.get("seed"),
+                    postprocess="none",
+                )
             self._jax_rollout_engine = eng
             self._extra_metric_sources = [eng.get_metrics]
         return eng

@@ -269,6 +269,7 @@ class PPO(Algorithm):
                 JaxRolloutEngine,
                 supports_jax_rollout_lane,
             )
+            from ray_tpu.util import tracing
 
             policy = self.get_policy()
             env = self.workers.local_worker().env
@@ -289,15 +290,17 @@ class PPO(Algorithm):
                     f"rollout_fragment_length, got {N * T} != "
                     f"{self.config['train_batch_size']}"
                 )
-            eng = JaxRolloutEngine(
-                policy,
-                env,
-                N,
-                T,
-                seed=self.config.get("seed"),
-                postprocess="gae",
-                standardize_advantages=True,
-            )
+            # a step of set-up, built where the lane first needs it
+            with tracing.phase("setup:rollout_engine", num_envs=N):
+                eng = JaxRolloutEngine(
+                    policy,
+                    env,
+                    N,
+                    T,
+                    seed=self.config.get("seed"),
+                    postprocess="gae",
+                    standardize_advantages=True,
+                )
             self._jax_rollout_engine = eng
             # Algorithm._collect_rollout_metrics drains these — the
             # lane's episode returns come back with the stats readback

@@ -16,8 +16,8 @@ This rule makes the doc the enforced source of truth:
   every tag key must appear on the doc line(s) that mention the
   family (the catalog table row documents the label set — a tag the
   row doesn't name is an undocumented cardinality axis);
-- every literal span name opened via ``start_span("...")`` or
-  ``context_span(ctx, "...")`` must be documented: the full name
+- every literal span name opened via ``start_span("...")``,
+  ``phase("...")`` or ``context_span(ctx, "...")`` must be documented: the full name
   appears in the doc, a documented ``prefix:*`` glob covers it, or it
   starts with a stage prefix of ``telemetry/rollup.py``'s
   ``STAGE_PREFIXES`` map (when that module is in the scan). Dynamic
@@ -53,8 +53,9 @@ _INSTRUMENT_CTORS = {
     "timer_histogram", "get_metric",
 }
 # opener -> index of the span-name argument (context_span takes the
-# propagated context first, the name second)
-_SPAN_OPENERS = {"start_span": 0, "context_span": 1}
+# propagated context first, the name second; phase is the span site
+# that is kept with tracing off)
+_SPAN_OPENERS = {"start_span": 0, "context_span": 1, "phase": 0}
 
 
 def _doc(program) -> Optional[str]:

@@ -151,7 +151,10 @@ class RolloutWorker:
             # meshes; the local worker builds its learner mesh from config.
             if worker_index > 0:
                 pol_config.pop("_mesh", None)
-            self.policy_map[pid] = cls(eff_obs_space, act_space, pol_config)
+            with tracing.phase("setup:policy", policy=pid):
+                self.policy_map[pid] = cls(
+                    eff_obs_space, act_space, pol_config
+                )
             self.filters[pid] = get_filter(
                 self.config.get("observation_filter", "NoFilter"),
                 eff_obs_space.shape,

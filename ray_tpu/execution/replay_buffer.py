@@ -615,20 +615,27 @@ class DeviceReplayBuffer:
                 )
             self.storage_bytes = 0
             return False
-        for k, v in new_cols.items():
-            row_shape = tuple(v.shape[1:])
-            packed = self._packable(row_shape, v.dtype)
-            ring = jnp.zeros(*rings[k])
-            # rows shard over the data axis when capacity divides the
-            # shard count, else replicate (specs.leaf_sharding rule);
-            # put_global assembles cross-process shards when the mesh
-            # spans hosts (fleet rings, docs/fleet.md) and is plain
-            # device_put on a local mesh
-            self._store[k] = sharding_lib.put_global(
-                ring, sharding_lib.leaf_sharding(ring, self.mesh)
-            )
-            self._meta[k] = (row_shape, v.dtype, packed)
-            self.storage_bytes += ring_bytes[k]
+        from ray_tpu.util import tracing
+
+        with tracing.phase(
+            "setup:replay",
+            columns=len(new_cols),
+            bytes=sum(ring_bytes.values()),
+        ):
+            for k, v in new_cols.items():
+                row_shape = tuple(v.shape[1:])
+                packed = self._packable(row_shape, v.dtype)
+                ring = jnp.zeros(*rings[k])
+                # rows shard over the data axis when capacity divides
+                # the shard count, else replicate (specs.leaf_sharding
+                # rule); put_global assembles cross-process shards when
+                # the mesh spans hosts (fleet rings, docs/fleet.md) and
+                # is plain device_put on a local mesh
+                self._store[k] = sharding_lib.put_global(
+                    ring, sharding_lib.leaf_sharding(ring, self.mesh)
+                )
+                self._meta[k] = (row_shape, v.dtype, packed)
+                self.storage_bytes += ring_bytes[k]
         self._insert_fn = None
         self._sample_fn = None
         return True

@@ -169,31 +169,30 @@ class JaxPolicy(Policy):
         seed = int(config.get("seed") or 0)
         self._rng = jax.random.PRNGKey(seed)
         self._rng, init_rng = jax.random.split(self._rng)
-        dummy_obs = self._dummy_obs(batch=2)
-        init_state = self.model.initial_state(2)
-        if self.model.is_recurrent:
+        with tracing.phase("setup:model_init") as _init:
+            init_args = (init_rng, self._dummy_obs(batch=2))
             init_kwargs = {}
-            if getattr(self.model, "use_prev_action", False):
-                init_kwargs["prev_actions"] = jnp.zeros(
-                    (2, 1) + tuple(action_space.shape or ()),
-                    jnp.float32,
+            if self.model.is_recurrent:
+                init_args = (
+                    init_rng,
+                    init_args[1][:, None],
+                    self.model.initial_state(2),
                 )
-            if getattr(self.model, "use_prev_reward", False):
-                init_kwargs["prev_rewards"] = jnp.zeros(
-                    (2, 1), jnp.float32
-                )
-            self.params = self.model.init(
-                init_rng, dummy_obs[:, None], init_state, **init_kwargs
-            )
-        else:
-            self.params = self.model.init(init_rng, dummy_obs)
-        # per-leaf partitioned placement: when the mesh carries a
-        # "model" axis and the model declares partition rules, params
-        # become first-class sharded trees (attention/MLP kernels
-        # split megatron-style, the rest replicated); otherwise the
-        # replicated default above stands
-        self._install_param_placement()
-        self.params = _tree_to_device(self.params, self._param_sharding)
+                if getattr(self.model, "use_prev_action", False):
+                    init_kwargs["prev_actions"] = jnp.zeros(
+                        (2, 1) + tuple(action_space.shape or ()),
+                        jnp.float32,
+                    )
+                if getattr(self.model, "use_prev_reward", False):
+                    init_kwargs["prev_rewards"] = jnp.zeros(
+                        (2, 1), jnp.float32
+                    )
+            self.params = self.model.init(*init_args, **init_kwargs)
+            # per-leaf placement: on a mesh with a "model" axis a model
+            # that declares partition rules gets sharded param trees
+            self._install_param_placement()
+            self.params = _tree_to_device(self.params, self._param_sharding)
+            _note_tree_size(_init, self.params)
 
         grad_clip = config.get("grad_clip")
         chain = []
@@ -201,19 +200,20 @@ class JaxPolicy(Policy):
             chain.append(optax.clip_by_global_norm(grad_clip))
         chain.append(optax.scale_by_adam(eps=config.get("adam_epsilon", 1e-8)))
         self._tx = optax.chain(*chain)
-        opt0 = self._tx.init(self.params)
-        if self._param_pspecs is not None:
-            # optimizer moments inherit each param's placement
-            # (suffix-matched by path+shape); counts/scalars replicate
-            self._opt_pspecs = sharding_lib.state_pspecs(
-                opt0, self.params, self._param_pspecs
-            )
-            self._opt_sharding = sharding_lib.named_tree(
-                self.mesh, self._opt_pspecs
-            )
-        else:
-            self._opt_sharding = self._param_sharding
-        self.opt_state = _tree_to_device(opt0, self._opt_sharding)
+        with tracing.phase("setup:optimizer_init"):
+            opt0 = self._tx.init(self.params)
+            if self._param_pspecs is not None:
+                # optimizer moments inherit each param's placement
+                # (suffix-matched by path+shape); scalars replicate
+                self._opt_pspecs = sharding_lib.state_pspecs(
+                    opt0, self.params, self._param_pspecs
+                )
+                self._opt_sharding = sharding_lib.named_tree(
+                    self.mesh, self._opt_pspecs
+                )
+            else:
+                self._opt_sharding = self._param_sharding
+            self.opt_state = _tree_to_device(opt0, self._opt_sharding)
 
         # ---- schedules / coefficients ----
         from ray_tpu.utils.schedules import make_schedule
@@ -2509,3 +2509,13 @@ def build_jax_policy(
     _Built.__name__ = name
     _Built.__qualname__ = name
     return _Built
+
+
+def _note_tree_size(span, tree) -> None:
+    """``params`` and ``bytes`` of a parameter tree, as attributes of
+    the set-up span that built it."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    span.set_attribute("params", int(sum(x.size for x in leaves)))
+    span.set_attribute(
+        "bytes", int(sum(x.size * x.dtype.itemsize for x in leaves))
+    )

@@ -87,8 +87,8 @@ class ShardedFunction:
       - ``recompiles``: traces beyond the first (should be 0 across
         steps with constant shapes);
       - ``calls``: total invocations;
-      - ``compile_time_s``: wall time of the calls that traced
-        (compile + first dispatch); steady-state calls add nothing.
+      - ``compile_time_s``: ``trace_s + lower_s + backend_s``, jax's
+        own seconds of this program's compiles (the account below).
     """
 
     def __init__(
@@ -103,7 +103,8 @@ class ShardedFunction:
         self.label = label or getattr(fn, "__name__", "sharded_fn")
         self.traces = 0
         self.calls = 0
-        self.compile_time_s = 0.0
+        self.account = CompileAccount(label_family(self.label))
+        self.retrace_causes: list = []  # what moved, the last few
         # AOT-installed dispatch path (sharding/aot.py): a compiled
         # executable restored from the persistent cache ("aot_cache")
         # or compiled ahead of time here ("aot_live"); None = plain jit
@@ -130,6 +131,7 @@ class ShardedFunction:
         self._uncounted = threading.local()
 
         def _counted(*args, **kwargs):
+            _now_tracing(self)
             # the ledger's ahead-of-time analysis compile re-traces
             # abstractly; that must not count as a (re)trace of the
             # execution path
@@ -154,14 +156,16 @@ class ShardedFunction:
             _REGISTRY.add(self)
 
     @contextlib.contextmanager
-    def uncounted_traces(self):
-        """Scope in which re-traces don't bump ``traces`` (the device
-        ledger's AOT analysis compile — same function, abstract args)."""
-        self._uncounted.on = True
+    def uncounted_traces(self, analysis: bool = False):
+        """Scope whose compiles are this function's without bumping
+        ``traces``; ``analysis``: the device ledger's second compile
+        of the same program, kept apart as ``analysis_s``."""
+        self._uncounted.on = "analysis" if analysis else True
+        _TLS.claim = self
         try:
             yield
         finally:
-            self._uncounted.on = False
+            self._uncounted.on = _TLS.claim = False
 
     def aot_warmup(self, cache, *args, **kwargs) -> str:
         """Install an ahead-of-time compiled executable for the ONE
@@ -207,7 +211,6 @@ class ShardedFunction:
             self.aot_source = "aot_cache"
             device_ledger.on_aot(self, 0.0, "aot_cache")
             return "hit"
-        t0 = time.perf_counter()
         try:
             with self.uncounted_traces():
                 compiled = self._jitted.lower(
@@ -215,14 +218,13 @@ class ShardedFunction:
                 ).compile()
         except Exception:
             return "disabled"
-        dt = time.perf_counter() - t0
         with self._lock:
             # a real XLA compile: count it exactly like a jit trace so
             # compile_stats stays honest about cold-start cost
             self.traces += 1
-            self.compile_time_s += dt
         self._aot = compiled
         self.aot_source = "aot_live"
+        dt = self.account.settle(self.label)
         device_ledger.on_aot(self, dt, "aot_live")
         cache.save(self.label, sig, compiled)
         return "compiled"
@@ -301,29 +303,29 @@ class ShardedFunction:
             if self.traces == before:
                 self.calls += 1
                 return out
-            dt = time.perf_counter() - t0
-            device_ledger.on_traced(self, args, kwargs, dt)
+            # found after the fact: the listeners already gave this
+            # compile's seconds to the account, by phase
+            self._compiled(args, kwargs)
             with self._lock:
                 self.calls += 1
-                self.compile_time_s += dt
             return out
         t_wall0 = time.time()
         t0 = time.perf_counter()
         if tracing.is_enabled():
             # trace-vs-cached-execute span: "did this step recompile?"
             # shows up as a lane in the chrome trace, and a retrace
-            # after warmup additionally records a recompile event —
-            # with the ledger on, carrying the forensics cause (which
-            # abstract leaf's shape/dtype moved)
+            # after warmup additionally records a recompile event with
+            # the forensics cause (which abstract leaf's shape/dtype
+            # moved) where the ledger saw the program's last trace
             with tracing.start_span("jit:" + self.label) as sp:
                 out = self._jitted(*args, **kwargs)
                 traced = self.traces != before
                 sp.set_attribute("traced", traced)
                 if traced:
-                    cause = device_ledger.on_traced(
-                        self, args, kwargs,
-                        time.perf_counter() - t0,
-                    )
+                    # the compile itself: a ``compile:<family>`` span
+                    # over jax's own three phases (trace, lower,
+                    # backend), inside this one
+                    cause = self._compiled(args, kwargs)
                     if before > 0:
                         ev = {"label": self.label}
                         if cause:
@@ -332,22 +334,37 @@ class ShardedFunction:
         else:
             out = self._jitted(*args, **kwargs)
             if self.traces != before:
-                device_ledger.on_traced(
-                    self, args, kwargs, time.perf_counter() - t0
-                )
+                self._compiled(args, kwargs)
         dt = time.perf_counter() - t0
         with self._lock:
             self.calls += 1
-            if self.traces != before:
-                self.compile_time_s += dt
         device_ledger.on_call(
             self, t_wall0, dt, traced=self.traces != before
         )
         return out
 
+    def _compiled(self, args, kwargs) -> Optional[str]:
+        """The bookkeeping of a call that compiled, on whichever path
+        found it: the ledger's row takes jax's seconds of this
+        compile, the forensics say what moved (None on a first trace),
+        and while tracing is on or a profiler session is live the
+        compile becomes its spans."""
+        cause = device_ledger.on_traced(
+            self, args, kwargs, self.account.pending_s()
+        )
+        self.account.settle(self.label, cause)
+        if cause:
+            with self._lock:
+                self.retrace_causes = self.retrace_causes[-7:] + [cause]
+        return cause
+
     @property
     def recompiles(self) -> int:
         return max(0, self.traces - 1)
+
+    @property
+    def compile_time_s(self) -> float:
+        return self.account.compile_time_s
 
     def stats(self) -> Dict[str, Any]:
         out = {
@@ -355,8 +372,10 @@ class ShardedFunction:
             "traces": self.traces,
             "recompiles": self.recompiles,
             "calls": self.calls,
-            "compile_time_s": self.compile_time_s,
+            **self.account.row(),
         }
+        if self.retrace_causes:
+            out["retrace_causes"] = list(self.retrace_causes)
         if self.aot_source is not None or self.aot_fallbacks:
             out["aot_source"] = self.aot_source
             out["aot_fallbacks"] = self.aot_fallbacks
@@ -411,15 +430,240 @@ def compile_stats() -> Dict[str, Any]:
     with _LOCK:
         fns = list(_REGISTRY)
     per_fn = [f.stats() for f in fns]
+    with _LOCK:
+        families = {k: v.row() for k, v in _FAMILIES.items()}
     return {
         "functions": len(per_fn),
         "traces": sum(s["traces"] for s in per_fn),
         "recompiles": sum(s["recompiles"] for s in per_fn),
         "calls": sum(s["calls"] for s in per_fn),
-        "compile_time_s": sum(s["compile_time_s"] for s in per_fn),
+        **{k: sum(s[k] for s in per_fn) for k in _ROW},
         "per_function": per_fn,
+        # the same account by program family since the process began:
+        # it keeps the seconds of programs that are gone, and under
+        # ``other`` what no ShardedFunction compiled
+        "families": families,
         # forensics rollup (telemetry/device.py): per-label recompile
         # causes — the abstract-signature diffs of every retrace seen
         # while the device ledger ran ({} with the ledger off)
         "recompile_causes": device_ledger.recompile_causes(),
     }
+
+
+# -- the compile account -------------------------------------------------
+#
+# jax times the three phases of every compile itself (tracing to a
+# jaxpr, lowering to a module, the backend's compile or the persistent
+# cache's retrieval) and says whether the cache hit. The listeners
+# below give each event to the ShardedFunction that is compiling on
+# that thread, so "where did set-up go" is read from the program's own
+# tables instead of a log. ALWAYS ON: a listener runs only when
+# something compiles (a steady dispatch raises no event), a handful of
+# times a process, and set-up is over before anyone could switch
+# tracing on for it.
+
+OTHER = "other"
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+# phases of one function's compiles that nobody settled yet (a bare
+# ``lower()`` from outside): the oldest go
+_MAX_PENDING = 48
+_TLS = threading.local()
+# the columns of an account's row, in stats() and compile_stats() alike
+_ROW = (
+    "compile_time_s", "trace_s", "lower_s", "backend_s", "analysis_s",
+    "cache_hits", "cache_misses",
+)
+
+
+class CompileAccount:
+    """jax's own seconds of one program's compiles: ``trace_s``,
+    ``lower_s``, ``backend_s`` (the compile, or the retrieval on a
+    cache hit) of the execution path, each exclusive of what ran
+    inside it, ``analysis_s`` (all three phases of the device ledger's
+    second compile for its cost figures, where the ledger analyzes),
+    and the persistent cache's hits and misses (a miss is counted when
+    the entry is written)."""
+
+    __slots__ = (
+        "family", "trace_s", "lower_s", "backend_s", "analysis_s",
+        "cache_hits", "cache_misses", "_pending",
+    )
+
+    def __init__(self, family: str):
+        self.family = family
+        self.trace_s = self.lower_s = self.backend_s = 0.0
+        self.analysis_s = 0.0
+        self.cache_hits = self.cache_misses = 0
+        # (phase, start, end, cache, analysis) of the outermost phases
+        # since the last settle()
+        self._pending: list = []
+
+    @property
+    def compile_time_s(self) -> float:
+        return self.trace_s + self.lower_s + self.backend_s
+
+    def row(self) -> Dict[str, Any]:
+        return {k: getattr(self, k) for k in _ROW}
+
+    @staticmethod
+    def _seconds(entries) -> float:
+        return sum(
+            end - start
+            for _, start, end, _, analysis in entries
+            if not analysis
+        )
+
+    def pending_s(self) -> float:
+        """Seconds of the compile that just ran and was not settled."""
+        with _LOCK:
+            return self._seconds(self._pending)
+
+    def settle(self, label: str, cause: Optional[str] = None) -> float:
+        """Close the books on the compile that just ran: its seconds,
+        and while tracing is on or a profiler session is live a
+        ``compile:<family>`` span over its ``compile:trace`` /
+        ``compile:lower`` / ``compile:backend`` (the ledger's
+        analysis compile a second such span, ``analysis=True``), from
+        jax's own ``time.time()`` stamps."""
+        with _LOCK:
+            entries, self._pending = self._pending, []
+        if tracing.is_enabled() or tracing.profiling():
+            for analysis in (False, True):
+                phases = [e for e in entries if e[4] == analysis]
+                if not phases:
+                    continue
+                attrs = {"label": label}
+                cache = [e[3] for e in phases if e[3]]
+                if cache:
+                    attrs["cache"] = cache[-1]
+                if analysis:
+                    attrs["analysis"] = True
+                elif cause:
+                    attrs["cause"] = cause
+                ctx = tracing.record_span(
+                    "compile:" + self.family,
+                    min(e[1] for e in phases),
+                    max(e[2] for e in phases),
+                    **attrs,
+                )
+                for phase, start, end, _, _ in phases:
+                    tracing.record_span(
+                        "compile:" + phase, start, end, ctx=ctx
+                    )
+        return self._seconds(entries)
+
+
+_FAMILIES: Dict[str, CompileAccount] = {}
+
+
+def _accounts(sf):
+    """The accounts an event on this thread goes to: the compiling
+    function's own and its family's row, or ``other``'s alone."""
+    family = sf.account.family if sf is not None else OTHER
+    row = _FAMILIES.get(family) or _FAMILIES.setdefault(
+        family, CompileAccount(family)
+    )
+    return (row,) if sf is None else (sf.account, row)
+
+
+def _now_tracing(sf) -> None:
+    """jax is tracing ``sf`` on this thread (its traced wrapper calls
+    this): the compile events that follow on the thread are its own. A
+    program traced INSIDE another's trace leaves the outer's claim."""
+    if len(getattr(_TLS, "open", ())) <= 1:
+        _TLS.sf = weakref.ref(sf)
+
+
+def _compiling():
+    """The ShardedFunction compiling on this thread: the one inside
+    its ``uncounted_traces`` scope (an ahead-of-time compile, which
+    may trace nothing anew), else the one jax last traced here."""
+    claim = getattr(_TLS, "claim", None)
+    if claim:
+        return claim
+    ref = getattr(_TLS, "sf", None)
+    return ref() if ref is not None else None
+
+
+def _on_phase_start(event: str, value, **_) -> None:
+    if event not in _PHASES:
+        return
+    open_ = _TLS.__dict__.setdefault("open", [])
+    if not open_ and _PHASES[event] == "trace":
+        # a new compile begins: whoever is traced claims it
+        _TLS.sf = None
+    open_.append([event, 0.0])
+
+
+def _on_phase_end(
+    event: str, start: float, end: float, fun_name: str = "", **_
+) -> None:
+    phase = _PHASES.get(event)
+    if phase is None:
+        return
+    open_ = getattr(_TLS, "open", None) or []
+    inner = 0.0
+    while open_:
+        opened, inner = open_.pop()
+        if opened == event:
+            break
+    # exclusive of the phases that ran inside this one (a program
+    # traced inside a trace, an eager op compiled while tracing)
+    seconds = max(0.0, (end - start) - inner)
+    if open_:
+        open_[-1][1] += end - start
+    sf = _compiling()
+    cache = None
+    if phase == "backend":
+        cache, _TLS.cache = getattr(_TLS, "cache", None), None
+    analysis = (
+        sf is not None
+        and getattr(sf._uncounted, "on", False) == "analysis"
+    )
+    field = "analysis_s" if analysis else phase + "_s"
+    accounts = _accounts(sf)
+    with _LOCK:
+        for a in accounts:
+            setattr(a, field, getattr(a, field) + seconds)
+        if sf is not None and not open_:
+            sf.account._pending.append(
+                (phase, start, end, cache, analysis)
+            )
+            del sf.account._pending[:-_MAX_PENDING]
+    telemetry_metrics.add_compile_phase_seconds(
+        accounts[-1].family, "analysis" if analysis else phase, seconds
+    )
+    if not open_:
+        if sf is None and (tracing.is_enabled() or tracing.profiling()):
+            tracing.record_span(
+                "compile:" + phase, start, end,
+                family=OTHER, fun_name=fun_name,
+            )
+        if phase == "backend":
+            _TLS.sf = None  # this compile is over
+
+
+def _on_cache_event(event: str, **_) -> None:
+    result = _CACHE_EVENTS.get(event)
+    if result is None:
+        return
+    _TLS.cache = result
+    accounts = _accounts(_compiling())
+    field = "cache_hits" if result == "hit" else "cache_misses"
+    with _LOCK:
+        for a in accounts:
+            setattr(a, field, getattr(a, field) + 1)
+    telemetry_metrics.inc_compile_cache_event(accounts[-1].family, result)
+
+
+jax.monitoring.register_scalar_listener(_on_phase_start)
+jax.monitoring.register_event_time_span_listener(_on_phase_end)
+jax.monitoring.register_event_listener(_on_cache_event)

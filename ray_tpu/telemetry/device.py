@@ -447,7 +447,7 @@ def _analyze_program(entry: "_ProgramEntry", sf, args, kwargs) -> None:
         sds_args, sds_kwargs = _abstractify(
             args, kwargs, getattr(sf, "static_argnames", ())
         )
-        with sf.uncounted_traces():
+        with sf.uncounted_traces(analysis=True):
             compiled = sf._jitted.lower(
                 *sds_args, **sds_kwargs
             ).compile()
@@ -506,9 +506,9 @@ def on_traced(sf, args, kwargs, compile_s: float) -> Optional[str]:
     """One trace (compile) just happened on ``sf``. Records the
     signature, runs the forensics diff against the cached ones, and
     (full mode) captures the program's cost/memory analysis. Returns
-    the cause string for retraces beyond the first, else None."""
-    if not _enabled:
-        return None
+    the cause string for retraces beyond the first, else None.
+    ``compile_s``: jax's own seconds of that compile
+    (``ShardedFunction.account``)."""
     sig = None
     try:
         sig = signature_of(
@@ -516,6 +516,14 @@ def on_traced(sf, args, kwargs, compile_s: float) -> Optional[str]:
         )
     except Exception:
         pass
+    if not _enabled:
+        # off: record nothing; a retrace of a program the ledger saw
+        # while it ran still learns what moved since
+        with _LOCK:
+            seen = getattr(_entries.get(sf.label), "signatures", None)
+            if sig is None or not seen:
+                return None
+            return cause_string(diff_signatures(seen[-1], sig))
     with _LOCK:
         entry = _entry_for(sf)
         entry.traces += 1

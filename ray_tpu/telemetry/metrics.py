@@ -206,6 +206,13 @@ ROUTER_REROUTED_TOTAL = "ray_tpu_router_rerouted_total"
 # the failure lanes (load_error/save_error → misses; fallback = an
 # installed executable rejected at dispatch, reverted to live jit)
 AOT_CACHE_EVENTS_TOTAL = "ray_tpu_aot_cache_events_total"
+# the compile account (sharding/compile.py): jax's own seconds of every
+# compile by program family and phase (trace | lower | backend |
+# analysis, the device ledger's second compile), and the persistent
+# cache's verdicts (hit | miss); family "other" is what no
+# ShardedFunction compiled
+COMPILE_PHASE_SECONDS_TOTAL = "ray_tpu_compile_phase_seconds_total"
+COMPILE_CACHE_EVENTS_TOTAL = "ray_tpu_compile_cache_events_total"
 # device-plane program ledger (docs/observability.md "device ledger",
 # telemetry/device.py): per compiled program — steady-state execution
 # count, cumulative device-busy seconds closed at the drain points,
@@ -944,6 +951,27 @@ def inc_aot_cache_event(event: str, n: int = 1) -> None:
         "AOT compiled-program cache events",
         ("event",),
     ).inc(float(n), {"event": event})
+
+
+def add_compile_phase_seconds(
+    family: str, phase: str, seconds: float
+) -> None:
+    """Seconds jax spent in one phase of a compile of ``family``."""
+    counter(
+        COMPILE_PHASE_SECONDS_TOTAL,
+        "seconds compiling, by program family and phase",
+        ("family", "phase"),
+    ).inc(float(seconds), {"family": family, "phase": phase})
+
+
+def inc_compile_cache_event(family: str, result: str) -> None:
+    """The persistent compile cache answered ``result`` (``hit`` |
+    ``miss``) for a program of ``family``."""
+    counter(
+        COMPILE_CACHE_EVENTS_TOTAL,
+        "persistent compile cache hits and misses, by program family",
+        ("family", "result"),
+    ).inc(1.0, {"family": family, "result": result})
 
 
 def inc_program_execution(program: str, n: int = 1) -> None:

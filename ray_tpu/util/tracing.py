@@ -44,6 +44,21 @@ span, at the cost of one ``TraceAnnotation.is_enabled()`` per span
 site and of nothing in a process that has not imported jax. This
 module is imported by the CPU rollout workers and never imports jax
 itself: it looks the class up once jax is in ``sys.modules``.
+
+What is kept with tracing OFF: the sites opened with :func:`phase`
+and nothing else. Those are the steps of building an Algorithm
+(``setup:algorithm``, ``setup:workers``, ``setup:policy``,
+``setup:model_init``, ``setup:optimizer_init``,
+``setup:rollout_engine``, ``setup:replay``): each runs once a process,
+before anyone could have switched tracing on for it, and what an
+operator asks after a slow restart is where those seconds went. A
+``phase`` costs a clock pair and one row of a bounded table
+(:func:`phases`; the first ``train()`` result carries it under
+``info/setup``). No site on the path of an iteration is a ``phase``:
+a hot site is a :func:`start_span`, which costs two flag checks when
+off. The other account that is always on, the seconds of every
+compile by phase and program family, lives with the compile layer
+(``sharding/compile.py``) because it is fed by jax's own events.
 """
 
 from __future__ import annotations
@@ -63,6 +78,8 @@ _current: contextvars.ContextVar[Optional["Span"]] = (
     contextvars.ContextVar("ray_tpu_span", default=None)
 )
 _finished: List[Dict] = []
+# spans ever appended: the clock of ``spans_since``'s cursor
+_appended = 0
 _lock = threading.Lock()
 # bound the span buffer: long-running jobs must not grow driver memory
 # monotonically — oldest spans drop first (export/inspect regularly,
@@ -71,8 +88,10 @@ _MAX_SPANS = int(os.environ.get("RAY_TPU_TRACE_BUFFER", 100_000))
 
 
 def _append_bounded(records: List[Dict]) -> None:
+    global _appended
     with _lock:
         _finished.extend(records)
+        _appended += len(records)
         if len(_finished) > _MAX_SPANS:
             del _finished[: len(_finished) - _MAX_SPANS]
 
@@ -263,24 +282,79 @@ def event(name: str, **attributes) -> None:
 
 
 def record_span(
-    name: str, start: float, end: float, **attributes
-) -> None:
+    name: str, start: float, end: float, ctx: Optional[Dict] = None,
+    **attributes,
+) -> Optional[Dict]:
     """Record a span whose interval was measured out-of-band (e.g. a
     queue wait that ended when ``get()`` returned). ``start``/``end``
     are ``time.time()`` stamps. The profiler's clock cannot be
     back-dated: under a live session the span is an annotation of no
     length at the moment of this call, with the interval's length as
     its ``seconds`` attribute. No-op when tracing is off and no
-    session is live."""
+    session is live. Returns the context a further ``record_span``
+    takes as ``ctx`` to lie under this one (None when nothing was
+    recorded); without ``ctx`` the span lies under the current one."""
     if profiling():
         with _annotation(name, seconds=end - start, **attributes):
             pass
     if not _enabled:
-        return
-    span = _child(name)
+        return None
+    if ctx is None:
+        span = _child(name)
+    else:
+        span = Span(name, ctx["trace_id"], ctx["parent_span_id"])
     span.start = start
     span.attributes.update(attributes)
     span.finish(end=end)
+    return {"trace_id": span.trace_id, "parent_span_id": span.span_id}
+
+
+# -- the sites that are kept with tracing off ---------------------------
+
+_MAX_PHASES = 256
+_phases: List[Dict[str, Any]] = []
+_phase: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
+    "ray_tpu_phase", default=None
+)
+
+
+@contextlib.contextmanager
+def phase(name: str, **attributes):
+    """A span site that runs once a process (a step of building the
+    Algorithm): a :func:`start_span`, and whether or not tracing is on
+    one row ``{name, seconds, parent}`` of :func:`phases`. Not for a
+    site an iteration reaches."""
+    token = _phase.set(name)
+    t0 = time.perf_counter()
+    try:
+        with start_span(name, **attributes) as span:
+            yield span
+    finally:
+        _phase.reset(token)
+        row = {
+            "name": name,
+            "seconds": time.perf_counter() - t0,
+            "parent": _phase.get(),
+        }
+        with _lock:
+            _phases.append(row)
+            del _phases[:-_MAX_PHASES]
+
+
+def phases() -> List[Dict[str, Any]]:
+    """The :func:`phase` sites that finished in this process, oldest
+    first (the last ``_MAX_PHASES``): ``name``, ``seconds`` on the
+    host's clock, and ``parent``, the name of the phase it ran inside
+    (None at the top)."""
+    with _lock:
+        return [dict(row) for row in _phases]
+
+
+def phase_seconds(name: str) -> Optional[float]:
+    """Seconds under every finished phase called ``name``; None when
+    there is none."""
+    found = [r["seconds"] for r in phases() if r["name"] == name]
+    return sum(found) if found else None
 
 
 # -- boundary plumbing (called by core/api.py and core/worker_proc.py) --
@@ -380,9 +454,21 @@ def get_spans() -> List[Dict]:
         return list(_finished)
 
 
+def spans_since(cursor: int = 0):
+    """``(spans, cursor)``: the spans appended since ``cursor`` (what
+    an earlier call returned; 0 reads from the start) and the cursor
+    to pass next. A consumer that runs every iteration reads each span
+    once, whatever the buffer holds; what the bounded buffer dropped in
+    between is gone."""
+    with _lock:
+        n = min(len(_finished), max(0, _appended - cursor))
+        return (_finished[len(_finished) - n:] if n else []), _appended
+
+
 def clear() -> None:
     with _lock:
         _finished.clear()
+        _phases.clear()
 
 
 def _clamped_intervals(spans: List[Dict]) -> Dict[str, tuple]:

@@ -220,6 +220,65 @@ def test_a_process_without_jax_opens_spans_without_importing_it():
     assert out.stdout.strip() == "no jax"
 
 
+# -- set-up and compiles in the profiler's trace (PR 36) ---------------------
+
+
+@pytest.fixture(scope="module")
+def setup_session(tmp_path_factory):
+    """A profiler-only session around one ``phase`` site and the first
+    call of a program (``tracing.enable()`` never called)."""
+    tracing.disable()
+    tracing.clear()
+    fn = sharded_jit(lambda x: x * 3.0 + 1.0, label="acct_profiled[p:1]")
+    x = jnp.ones(4)
+    log_dir = str(tmp_path_factory.mktemp("setup_profile"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=options)
+    try:
+        with tracing.phase("setup:replay", bytes=7) as span:
+            jax.block_until_ready(fn(x))
+        span_type = type(span)
+    finally:
+        jax.profiler.stop_trace()
+    out = {
+        "events": _host_events(log_dir),
+        "spans": tracing.get_spans(),
+        "phases": tracing.phases(),
+        "stats": fn.stats(),
+        "span_type": span_type,
+    }
+    tracing.clear()
+    return out
+
+
+def test_a_phase_is_an_annotation_and_a_row_and_no_span(setup_session):
+    (ev,) = _named(setup_session, "setup:replay")
+    assert ev[3]["bytes"] == 7
+    assert setup_session["spans"] == []
+    assert not issubclass(setup_session["span_type"], tracing.Span)
+    (row,) = setup_session["phases"]
+    assert row["name"] == "setup:replay" and row["parent"] is None
+    assert row["seconds"] == pytest.approx(ev[2] / 1e9, rel=0.2, abs=0.01)
+
+
+def test_a_compile_is_annotated_by_family_and_phase(setup_session):
+    """The profiler's clock cannot be back-dated: each is an annotation
+    of no length with jax's own seconds as its ``seconds``."""
+    stats = setup_session["stats"]
+    (family,) = _named(setup_session, "compile:acct_profiled")
+    assert family[3]["label"] == "acct_profiled[p:1]"
+    assert float(family[3]["seconds"]) >= stats["compile_time_s"] * 0.999
+    seconds = {}
+    for phase in ("trace", "lower", "backend"):
+        (ev,) = _named(setup_session, "compile:" + phase)
+        seconds[phase] = float(ev[3]["seconds"])
+        assert seconds[phase] >= stats[phase + "_s"] * 0.999 > 0.0
+    # inside the phase that was open while the program compiled
+    (outer,) = _named(setup_session, "setup:replay")
+    assert outer[1] <= family[1] <= outer[1] + outer[2]
+
+
 # -- named scopes in the lowered programs ------------------------------------
 
 
