@@ -208,13 +208,31 @@ def _rebuild_body(arr, op, capacity: int):
     """Recompute every internal node bottom-up. Bit-identical to the
     host's incremental ancestor updates: each node is always exactly
     ``op(child_left, child_right)`` of the FINAL children — the same
-    two-operand f64 op the host applies."""
-    n = capacity // 2
-    while n >= 1:
-        pairs = arr[2 * n : 4 * n].reshape(n, 2)
-        arr = arr.at[n : 2 * n].set(op(pairs[:, 0], pairs[:, 1]))
-        n //= 2
-    return arr
+    two-operand f64 op the host applies.
+
+    Each level is computed from the level just computed, held as a
+    value of its own, and the ``2 x capacity`` array is written once:
+    what a level costs is what it is wide. The children are taken
+    apart by two ``lax.slice`` of stride 2, which the TPU runs as
+    strided copies. Not ``pairs = level.reshape(n, 2)``: the chip
+    pads a minor dimension of 2 to 128 lanes, and read out of the
+    whole array that was a relayout of ALL of it at every level
+    (64 x 90 us of a 6.2 ms program at 131,072 leaves, PR 37); not
+    ``level[0::2]`` either, which ``jax.numpy`` lowers to a gather."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    level = arr[capacity:]
+    levels = [level]
+    while level.shape[0] > 1:
+        n = level.shape[0]
+        level = op(
+            lax.slice(level, (0,), (n,), (2,)),
+            lax.slice(level, (1,), (n,), (2,)),
+        )
+        levels.append(level)
+    # slot 0 is the one the host layout never reads; the root is slot 1
+    return jnp.concatenate([arr[:1]] + levels[::-1])
 
 
 class DeviceSumTree:

@@ -131,6 +131,125 @@ def test_device_tree_stacked_update_order_and_skip():
     assert np.array_equal(hidx, np.asarray(didx))
 
 
+def _nodes_equal(dt, hs, hm):
+    """ALL ``2 x capacity`` nodes of both trees, as the host reads
+    them, against the host trees' arrays bit for bit."""
+    for dev, host in ((dt.sum_value, hs), (dt.min_value, hm)):
+        got = np.asarray(jax.device_get(dev), np.float64)
+        assert got.shape == host.value.shape
+        bad = np.flatnonzero(got.view(np.uint64) != host.value.view(np.uint64))
+        assert bad.size == 0, (type(host).__name__, bad[:8])
+
+
+@pytest.mark.parametrize(
+    "shape", [(1, 1), (1, 512), (8, 512)], ids=lambda s: "x".join(map(str, s))
+)
+@pytest.mark.parametrize("cap", [8, 1024, 131072])
+def test_device_tree_rebuild_every_node_bitwise(cap, shape):
+    """The rebuild of the internal nodes at the capacities and update
+    shapes that matter (the DQN cell's are 131,072 x (1, 512) and
+    (8, 512)): after every call, with indices repeated across the
+    updates of a call and across calls and one update (or the whole
+    call) masked out, every node of both trees equals the host's, and
+    a draw returns the host's indices."""
+    u, b = shape
+    hs, hm = SumSegmentTree(cap), MinSegmentTree(cap)
+    dt = DeviceSumTree(cap)
+    rng = np.random.default_rng(1000 * cap + 10 * u + b)
+    base, _ = powered_priorities(rng.random(cap) * 2 + 1e-3, 0.6)
+    hs.set_items(np.arange(cap), base)
+    hm.set_items(np.arange(cap), base)
+    dt.set_powered(np.arange(cap), base)
+    _nodes_equal(dt, hs, hm)
+
+    draws = min(16, cap)
+    for call in range(3):
+        idx = rng.integers(0, cap, (u, b))
+        idx[-1, 0] = idx[0, 0]  # repeated across the call's updates
+        # a row wider than the tree repeats leaves INSIDE an update,
+        # where a scatter's order is not defined: one value a leaf and
+        # update, so any order writes the same
+        table, _ = powered_priorities(rng.random((u, cap)) * 3, 0.6)
+        powered = np.take_along_axis(table, idx, axis=1)
+        active = np.ones(u, bool)
+        if call == 1:
+            active[u // 2] = False  # at u == 1 the whole call
+        for i in range(u):
+            if active[i]:
+                hs.set_items(idx[i], powered[i])
+                hm.set_items(idx[i], powered[i])
+        dt.set_powered(idx, powered, active=active)
+        _nodes_equal(dt, hs, hm)
+
+        rand = rng.random(draws)
+        mass = (rand + np.arange(draws)) / draws * hs.sum(0, cap)
+        hidx = np.clip(hs.find_prefixsum_idx(mass), 0, cap - 1)
+        didx, _ = dt.draw(rand, cap, 0.4)
+        assert np.array_equal(hidx, np.asarray(didx)), call
+
+
+def _full_length_equations(cap, u=2, b=8):
+    """``(count, reshapes)`` over the update program's jaxpr, nested
+    ones included: equations that take or produce an array of the
+    tree's full ``2 x capacity`` length, and those among them that
+    reshape it."""
+    from ray_tpu import sharding as sharding_lib
+
+    full = 2 * cap
+    with sharding_lib.f64_scope():
+        fn = DeviceSumTree(cap)._build_update_fn(u, b)
+        tree = jax.ShapeDtypeStruct((full,), np.float64)
+        with fn.uncounted_traces():
+            closed = jax.make_jaxpr(fn._jitted)(
+                tree,
+                tree,
+                jax.ShapeDtypeStruct((u, b), np.int32),
+                jax.ShapeDtypeStruct((u, b), np.float64),
+                jax.ShapeDtypeStruct((u, b), np.bool_),
+            )
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            inner = list(jax.core.jaxprs_in_params(eqn.params))
+            if not inner:
+                yield eqn
+            for sub in inner:  # a nested call is its body, not an equation
+                yield from walk(sub)
+
+    def touches(eqn):
+        return any(
+            int(np.prod(v.aval.shape)) == full
+            for v in list(eqn.invars) + list(eqn.outvars)
+            if hasattr(v.aval, "shape")
+        )
+
+    hits = [e for e in walk(closed.jaxpr) if touches(e)]
+    return len(hits), [e for e in hits if e.primitive.name == "reshape"]
+
+
+def test_device_tree_rebuild_touches_full_array_once():
+    """Structure of the update program, on the CPU: the rebuild reads
+    each level out of the level it just computed and writes the array
+    once, so the equations on the full-length array do not multiply
+    with the tree's depth, and none of them reshapes it."""
+    small, big = 1 << 6, 1 << 14
+    n_small, reshapes_small = _full_length_equations(small)
+    n_big, reshapes_big = _full_length_equations(big)
+    why = (
+        "PR 37: a rebuild that re-read every level out of the 2 x "
+        "capacity array (a slice AND a scatter-set of it a level and "
+        "tree) made the TPU compiler relayout the WHOLE array at every "
+        "level, `reshape f32[131072,2]` padded to 128 lanes: 64 x 90 us "
+        "of each 6.2-6.9 ms jit_tree_update in the DQN cell, against "
+        "0.4 ms of arithmetic. Carry the level as a value of its own."
+    )
+    assert not reshapes_small and not reshapes_big, why
+    # at most the one write a level and tree beyond what the depth
+    # does not change (the leaves' scatters, one read, one write)
+    levels = big.bit_length() - small.bit_length()
+    assert n_big - n_small <= 2 * levels, (n_small, n_big, why)
+
+
 def test_device_tree_buffer_zero_recompiles_and_zero_copy():
     """One executable per program across buffer growth, wraparound,
     and beta annealing (size/beta are traced scalars), and the sample

@@ -366,3 +366,32 @@ def test_v5e_ssd_step_kernel_compiles_in_place(v5e_mesh):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == b * layers * h * p * n * 4
     assert mem.temp_size_in_bytes < 16e6
+
+
+def test_v5e_tree_update_pays_for_its_levels(v5e_mesh):
+    """The DQN cell's priority refresh (ops/segment_tree.py: an
+    (8, 512) update of a 131,072-leaf f64 tree pair) as the chip's
+    compiler leaves it: no array with a minor dimension of 2, which
+    the chip pads to 128 lanes (PR 37: ``reshape f32[131072,2]`` of
+    the WHOLE array at every level, 64 x 90 us of a 6.9 ms program),
+    and no gather wider than an update's 512 leaves (how a strided
+    ``jax.numpy`` index lowers: 4.1 ms at the widest level)."""
+    from ray_tpu.ops.segment_tree import DeviceSumTree
+
+    tree = DeviceSumTree.__new__(DeviceSumTree)  # no array on a described chip
+    tree.capacity, tree.mesh, tree.label = CELL_ROWS, v5e_mesh, "default_policy"
+    with sharding_lib.f64_scope():
+        full = _on(v5e_mesh, (2 * CELL_ROWS,), np.float64)
+        text = (
+            tree._build_update_fn(8, 512)
+            .lower(full, full, _on(v5e_mesh, (8, 512), np.int32),
+                   _on(v5e_mesh, (8, 512), np.float64),
+                   _on(v5e_mesh, (8, 512), np.bool_))
+            .compile()
+            .as_text()
+        )
+    assert re.findall(r"= \w+\[\d+,2\]\{\S* \w+\(", text) == []
+    gathers = [int(n) for n in re.findall(r"= \w+\[(\d+)\]\S* gather\(", text)]
+    assert max(gathers, default=0) <= 512, gathers
+    # the rebuild is there, as strided slices of every width
+    assert len(re.findall(r"= f32\[65536\]\S* slice\(", text)) >= 8
