@@ -20,6 +20,12 @@ allocation with its size: diff two trees' lists). Code that asks
 delta rule lowers to its ``jax.numpy`` body, not the kernel). Only one
 process at a time may hold libtpu: side by side only under
 ``ALLOW_MULTIPLE_LIBTPU_LOAD=1``, and never inside the tests.
+
+With a second argument, a file name, it stops before the described chip:
+the program as lowered on the CPU mesh is written there as StableHLO
+text without locations, for ``cmp`` against the same cell's text from
+another checkout (a change that must leave a cell's program as it was:
+PR 39 held three cells to that).
 """
 
 from __future__ import annotations
@@ -41,7 +47,7 @@ class _Taken(Exception):
     """Raised out of ``train()`` once the piece looked for is in hand."""
 
 
-def main(cell_name: str) -> None:
+def main(cell_name: str, text_to: str = "") -> None:
     from perf import manifest, run as perf_run
     from ray_tpu.sharding import compile as compile_lib
     from ray_tpu.sharding import superstep as superstep_lib
@@ -62,6 +68,7 @@ def main(cell_name: str) -> None:
             if hasattr(x, "shape") and hasattr(x, "dtype") else x,
             (args, kwargs),
         )
+        taken["cpu_fn"] = self
         raise _Taken()
 
     dispatch = compile_lib.ShardedFunction.__call__
@@ -71,6 +78,13 @@ def main(cell_name: str) -> None:
     except _Taken:
         print(f"built and traced as far as the dispatch in {time.time() - start:.0f} s",
               flush=True)
+
+    if text_to:
+        args, kwargs = taken["args"]
+        with open(text_to, "w") as f:
+            f.write(taken["cpu_fn"]._jitted.lower(*args, **kwargs).as_text())
+        print(f"{cell_name}: lowered text in {text_to}", flush=True)
+        os._exit(0)
 
     # 2. the same program over the described chip
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -110,6 +124,6 @@ def main(cell_name: str) -> None:
 
 
 if __name__ == "__main__":
-    if len(sys.argv) != 2:
+    if len(sys.argv) not in (2, 3):
         sys.exit(__doc__)
-    main(sys.argv[1])
+    main(*sys.argv[1:])

@@ -9,7 +9,9 @@ each applied through the block's RESIDUAL kind.
 
 Mixer kinds (``layer_types``, under the published names; every
 ``full_attention_interval``-th layer full attention where it is not
-stated; all latent where the config has ``kv_lora_rank``):
+stated; all latent where the config has ``kv_lora_rank``; window and
+position-free layers by ``sliding_window_layout`` where the config has
+one):
 
 - ``"full_attention"`` (Hugging Face ``qwen3_next``,
   ``Qwen3NextAttention``): gated softmax attention. One projection gives
@@ -45,7 +47,15 @@ stated; all latent where the config has ``kv_lora_rank``):
 - ``"attention"`` (``GraniteMoeHybridAttention`` with
   ``position_embedding_type: nope``): plain GQA softmax attention with
   NO positions, no q/k norm and no gate, its scores scaled by
-  ``attention_multiplier`` (not ``head^-1/2``). No biases.
+  ``attention_multiplier`` (``head^-1/2`` where the config states
+  none). No biases;
+- ``"sliding_attention"`` (SmallThinker's window layers:
+  ``sliding_window_layout[l] = 1``, which is also where ``rope_layout``
+  turns): GQA softmax attention with RoPE (rotate-half) over the WHOLE
+  head, no q/k norm, no gate, scores scaled by ``head^-1/2``; a query
+  at position ``p`` sees the keys at ``p - sliding_window_size + 1 ..
+  p`` of its episode and nothing older. The layers where the layout
+  says 0 are the ``"attention"`` kind above: full depth, no positions.
 
 Feed-forward kinds (``"dense"`` for the first ``first_k_dense_replace``
 layers, ``"experts"`` after):
@@ -64,6 +74,13 @@ layers, ``"experts"`` after):
   without the bias over their sum times ``routed_scaling_factor``, the
   shared expert ungated. ``select_bias`` is a buffer: it lies in the
   parameter tree and the model reads it through ``stop_gradient``.
+  SmallThinker (``moe_num_primary_experts``): softmax, top-k,
+  renormalised like ``qwen3_next``, but the router reads the LAYER'S
+  INPUT, un-normed and before the mixer runs (the experts still take
+  ``rms`` of the stream after the mixer); the experts gate with ReLU
+  (ReGLU) where the others gate with SiLU (``ops/moe.py``'s
+  ``activation``); and there is NO shared expert: the layer has no
+  ``shared_*`` leaves and its result is the routed sum alone.
 
 Residual kinds:
 
@@ -102,8 +119,12 @@ layer axis after the stream's, the ``(layers, heads, head, state)``
 float32 matrices and the last ``conv - 1`` inputs of each convolution
 (the one-token form reads and writes one layer's slice of them in
 place, the fragment form scans over them); for an ``"attention"`` layer
-keys and values as for a full layer (no norm, no RoPE); last, the
-stream's position. ``apply`` has two forms
+keys and values as for a full layer (no norm, no RoPE); for a
+``"sliding_attention"`` layer a RING of keys and values, ``(min(window,
+positions), kv heads x head)`` bfloat16 whatever the episode's depth,
+keys stored after RoPE, the token at position ``p`` in slot ``p mod
+window`` (docs/policy_state.md, "The ring"); last, the stream's
+position. ``apply`` has two forms
 that are the same function of the same weights: ``T == 1`` is the
 recurrence (one token, state in and out: the rollout lane's step; for
 latent attention the ABSORBED product against the latent rows), ``T >
@@ -127,6 +148,7 @@ two levels deep, ``{"layer_0": {"in_proj_qkvz": ...}, ...}``.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -139,34 +161,53 @@ from ray_tpu.telemetry import metrics
 _HI = jax.lax.Precision.HIGHEST
 
 LINEAR, FULL, LATENT = "linear_attention", "full_attention", "latent_attention"
-MAMBA, ATTENTION = "mamba", "attention"
+MAMBA, ATTENTION, SLIDING = "mamba", "attention", "sliding_attention"
 DENSE, EXPERTS = "dense", "experts"
 PLAIN, HYPER = "plain", "hyper_connection"
 
-# envs of a fragment whose attention scores are alive at once; for
-# latent attention, whose keys and values of 32 heads are rebuilt for
-# the block as well (0.27 GB for 8 streams, and as much again for
-# their cotangents)
-_ATTN_ENV_BLOCK = 8
+# envs of a fragment whose attention scores are alive at once: at most
+# 8, fewer (a power of two) where 8 streams' float32 scores of every
+# head over the rows a block sees pass ``_ATTN_SCORE_BYTES`` (8 at 2,304
+# rows of 32 heads x 256 tokens; 2 at 8,448 rows of 28 x 256, 4 at
+# 4,352); for latent attention, whose keys and values of 32 heads are
+# rebuilt for the block as well (0.27 GB for 8 streams, and as much
+# again for their cotangents), a constant
+_ATTN_SCORE_BYTES = 5 * 2 ** 27
 _LATENT_ENV_BLOCK = 4
 # state leaves a layer of each mixer kind holds (a state-space RUN: its
 # layers' matrices stacked in one leaf, their convolution inputs in another)
-_STATE_LEAVES = {LINEAR: 2, FULL: 2, LATENT: 1, MAMBA: 2, ATTENTION: 2}
+_STATE_LEAVES = {LINEAR: 2, FULL: 2, LATENT: 1, MAMBA: 2, ATTENTION: 2, SLIDING: 2}
 # a stacked run's leaves that enter a bfloat16 product
 _RUN_PRODUCT_LEAVES = ("in_proj", "out_proj", "mlp_gate", "mlp_up", "mlp_down")
 
 
 def layer_types_of(config: Dict) -> Tuple[str, ...]:
     """The pattern: ``layer_types`` if stated; all latent attention
-    where the config has a ``kv_lora_rank``; else every
-    ``full_attention_interval``-th layer is full attention."""
+    where the config has a ``kv_lora_rank``; by
+    ``sliding_window_layout`` where the config has one (1: a window
+    layer, which is also where ``rope_layout`` turns; 0: full depth and
+    no positions); else every ``full_attention_interval``-th layer is
+    full attention."""
     if config.get("layer_types"):
         return tuple(config["layer_types"])
     layers = int(config["num_hidden_layers"])
+    if "sliding_window_layout" in config:
+        window = list(config["sliding_window_layout"])[:layers]
+        if window != list(config.get("rope_layout", window))[:layers]:
+            raise ValueError(
+                "a window layer without RoPE, or a full layer with it, is no kind")
+        return tuple(SLIDING if w else ATTENTION for w in window)
     if "kv_lora_rank" in config:
         return (LATENT,) * layers
     every = int(config.get("full_attention_interval", 4))
     return tuple(FULL if (i + 1) % every == 0 else LINEAR for i in range(layers))
+
+
+def _attn_env_block(heads: int, tokens: int, rows: int) -> int:
+    """Streams of a fragment whose float32 scores (every head, every
+    token against ``rows`` keys) are alive at once."""
+    fit = _ATTN_SCORE_BYTES // (4 * heads * tokens * rows)
+    return min(8, 1 << max(0, int(fit).bit_length() - 1))
 
 
 def _rms(x, weight, eps, centred=True):
@@ -265,7 +306,8 @@ class SequenceLM:
         # experts the config counts, under whichever family's key; none
         # (or no such key) is a model with no expert layer
         experts = int(next(
-            (c[k] for k in ("num_experts", "n_routed_experts", "num_local_experts")
+            (c[k] for k in ("num_experts", "n_routed_experts", "num_local_experts",
+                            "moe_num_primary_experts")
              if k in c), 0))
         dense_first = int(c.get("first_k_dense_replace", 0)) if experts else (
             len(self.layer_types))
@@ -306,6 +348,10 @@ class SequenceLM:
             self.head_dim = int(c.get("head_dim") or self.hidden // self.heads)
             self.attn_scale = float(
                 c.get("attention_multiplier", self.head_dim ** -0.5))
+        if SLIDING in self.layer_types:  # GQA with RoPE inside a window
+            self.kv_heads = int(c["num_key_value_heads"])
+            self.head_dim = int(c.get("head_dim") or self.hidden // self.heads)
+            self.window = int(c["sliding_window_size"])
         if MAMBA in self.layer_types:  # Mamba-2
             if int(c.get("mamba_n_groups", 1)) != 1:
                 raise ValueError("B and C are shared by all heads: mamba_n_groups 1")
@@ -363,18 +409,31 @@ class SequenceLM:
         self.router_outputs = int(c.get("router_outputs", experts))
         first, count = c.get("experts_held") or (0, experts)
         self.first_expert, self.experts_held = int(first), int(count)
-        self.top_k = int(c["num_experts_per_tok"])
+        # SmallThinker's expert layer (its own key names): the router
+        # reads the layer's input before the mixer, ReGLU experts, no
+        # shared expert
+        primary = "moe_num_primary_experts" in c
+        if primary and not c.get("moe_primary_router_apply_softmax", True):
+            raise ValueError("a primary router without its softmax is not supported")
+        self.route_on_input = primary
+        self.expert_act = "relu" if primary else "silu"
+        if primary and self.residual == HYPER:
+            raise ValueError("a router on the layer's input with hc_mult lanes")
+        self.top_k = int(c["moe_num_active_primary_experts" if primary
+                           else "num_experts_per_tok"])
         self.norm_topk = bool(c.get("norm_topk_prob", True))
         self.scoring = str(c.get("scoring_func", "softmax"))
         self.route_scale = float(c.get("routed_scaling_factor", 1.0))
         self.select_bias = c.get("topk_method") == "noaux_tc"
-        self.expert_width = int(c["moe_intermediate_size"])
+        self.expert_width = int(
+            c["moe_ffn_hidden_size" if primary else "moe_intermediate_size"])
         # the shared expert: ``qwen3_next`` states its width and gates
         # it; DeepSeek-V3 counts shared experts of the routed width
         self.shared_gated = "shared_expert_intermediate_size" in c
         self.shared_width = int(
             c["shared_expert_intermediate_size"] if self.shared_gated
-            else int(c.get("n_shared_experts", 1)) * self.expert_width
+            else int(c.get("n_shared_experts", 0 if primary else 1))
+            * self.expert_width
         )
 
     def partition_rules(self):
@@ -409,10 +468,13 @@ class SequenceLM:
                 state.append(
                     jnp.zeros((b, self.conv - 1, self.conv_dim), jnp.float32)
                 )
-            elif kind in (FULL, ATTENTION):
+            elif kind in (FULL, ATTENTION, SLIDING):
                 # one row a position: kv heads x head, flat, so that the
-                # device tiles (positions, row) without padding 2 heads to 8
-                shape = (b, self.positions, self.kv_heads * self.head_dim)
+                # device tiles (positions, row) without padding 2 heads to 8;
+                # a window layer holds a ring of its window's rows
+                depth = self.positions if kind != SLIDING else min(
+                    self.window, self.positions)
+                shape = (b, depth, self.kv_heads * self.head_dim)
                 state.append(jnp.zeros(shape, self.dtype))
                 state.append(jnp.zeros(shape, self.dtype))
             else:
@@ -426,7 +488,10 @@ class SequenceLM:
         """Open a new episode on the rows of ``mask``: the DeltaNet and
         state-space matrices, the convolution inputs and the position
         go to zero; a key/value or latent cache is left as it is, since
-        only slots below the position are ever read."""
+        only slots below the position are ever read (of a ring: slots
+        whose row's position, recovered from the slot and the stream's
+        position, is not negative; what an earlier episode left in the
+        others is written over before it is read)."""
         out = []
         for n, (_, kind, _, _) in enumerate(self.segments):
             for leaf in self._segment_state(state, n):
@@ -459,10 +524,10 @@ class SequenceLM:
                     experts_gate=(e, d, f),
                     experts_up=(e, d, f),
                     experts_down=(e, f, d),
-                    shared_gate=(d, fs),
-                    shared_up=(d, fs),
-                    shared_down=(fs, d),
                 )
+                if fs:
+                    layer.update(
+                        shared_gate=(d, fs), shared_up=(d, fs), shared_down=(fs, d))
                 if self.shared_gated:
                     layer["shared_expert_gate"] = (d, 1)
                 if self.select_bias:
@@ -502,7 +567,7 @@ class SequenceLM:
                 if self.ssm_conv_bias:
                     layer["conv_bias"] = (self.ssm_conv_dim,)
                 layer = {k: (layers,) + shape for k, shape in layer.items()}
-            elif kind == ATTENTION:
+            elif kind in (ATTENTION, SLIDING):
                 layer.update(
                     q_proj=(d, self.heads * self.head_dim),
                     k_proj=(d, self.kv_heads * self.head_dim),
@@ -663,14 +728,21 @@ class SequenceLM:
             mixer = {
                 LINEAR: self._linear_attn, FULL: self._attn,
                 LATENT: self._latent_attn, MAMBA: self._mamba,
-                ATTENTION: self._plain_attn,
+                ATTENTION: self._plain_attn, SLIDING: self._sliding_attn,
             }[kind]
             ffn = self._moe if ffn_kind == EXPERTS else self._mlp
             if hyper:
                 return self._hyper_block(x, p, layer_state, ctx, mixer, ffn)
+            if ffn_kind == EXPERTS and self.route_on_input:
+                # the router reads the layer's input, before the mixer
+                with jax.named_scope(prefix + "moe/route"):
+                    ctx["route"] = self._route(p, x.reshape(-1, x.shape[-1]))
             y, new = mixer(p, _rms(x, p["input_norm"], self.eps), layer_state, ctx)
             x = x + self._scaled(y)
             y, load, routes = ffn(p, _rms(x, p["post_norm"], self.eps), ctx)
+            if kind == SLIDING:  # beside the state, the rows its queries saw
+                *new, seen = new
+                new, load = tuple(new), (load, seen)
             return x + self._scaled(y), new, load, routes
 
         # the plain residual groups the streams inside each block; with
@@ -703,7 +775,8 @@ class SequenceLM:
                     lambda r: r.reshape((-1,) + r.shape[2:]), routes),
             )
 
-        state_out, loads, all_routes, errs, steps_seen = [], [], [], [], []
+        state_out, loads, all_routes, errs, steps_seen, rows_seen = (
+            [], [], [], [], [], [])
         for n, (name, kind, ffn_kind, _) in enumerate(self.segments):
             args = (params[name], self._segment_state(state, n), rows_ctx,
                     kind, ffn_kind)
@@ -716,6 +789,9 @@ class SequenceLM:
             if hyper:
                 load, err = load
                 errs.append(err)
+            if kind == SLIDING:
+                load, seen = load
+                rows_seen.append(seen)
             if ffn_kind == EXPERTS:
                 loads.append(load)
                 all_routes.append(routes)
@@ -741,6 +817,10 @@ class SequenceLM:
             )[:, 0]
         if stats_out is not None and steps_seen:
             stats_out["ssm_dt_max"] = jnp.max(jnp.stack(steps_seen))
+        if stats_out is not None and rows_seen:
+            # rows inside the window a query of a window layer saw
+            stats_out["window_rows_seen_mean"] = sum(rows_seen) / (
+                b * t * len(rows_seen))
         if stats_out is not None and not loads:
             if "moe_routes" in stats_out:
                 # asked for every token's expert set where no layer
@@ -948,88 +1028,151 @@ class SequenceLM:
             o, new = self._cached_attention(q, k, v, state, ctx, self.attn_scale)
             return self._dot(o.reshape(b, t, h * d), p["o_proj"]), new
 
-    def _cached_attention(self, q, k, v, state, ctx, scale):
+    def _sliding_attn(self, p, x, state, ctx):
+        """GQA softmax attention inside a window of ``sliding_window_size``
+        positions over a ring cache, RoPE over the whole head, no q/k
+        norm and no gate. Beside the ring it hands back the number of
+        rows its queries saw."""
+        scope = ctx["scope"] + "swa"
+        with jax.named_scope(scope):
+            b, t, _ = x.shape
+            h, hkv, d = self.heads, self.kv_heads, self.head_dim
+            positions = ctx["positions"]
+            metrics.inc_window_cache_lowering("step" if t == 1 else "fragment")
+            q = self._dot(x, p["q_proj"]).reshape(b, t, h, d)
+            k = self._dot(x, p["k_proj"]).reshape(b, t, hkv, d)
+            v = self._dot(x, p["v_proj"]).reshape(b, t, hkv, d)
+            q = _rope(q, positions, d, self.theta)
+            k = _rope(k, positions, d, self.theta)
+        o, new, seen = self._cached_attention(
+            q, k, v, state, ctx, d ** -0.5, window=self.window, scope=scope)
+        with jax.named_scope(scope + "/out"):
+            return self._dot(o.reshape(b, t, h * d), p["o_proj"]), new + (seen,)
+
+    def _cached_attention(self, q, k, v, state, ctx, scale, window=None, scope=None):
         """Causal attention of a fragment's ``q`` ``(B, T, heads, D)``
         over the stored keys and values and the fragment's own ``k``,
         ``v`` ``(B, T, kv heads, D)``. Returns ``(o (B, T, heads, D),
-        (keys, values) after the fragment)``."""
+        (keys, values) after the fragment)``.
+
+        With a ``window`` the cache is a RING of ``R = min(window,
+        positions)`` slots, position ``p`` in slot ``p mod R``: the row
+        a stream at ``pos0`` holds in slot ``s`` is the one of the
+        largest position below ``pos0`` that is ``s mod R`` (none where
+        that is negative), a query sees the rows whose position is less
+        than ``window`` behind its own, and the masks come from those
+        positions, never from slot numbers. The parts then run under
+        ``scope``'s ``/scatter``, ``/scores`` and ``/out`` and the
+        number of (query, key) pairs seen is returned third."""
         k_cache, v_cache = state
         b, t, h, d = q.shape
         hkv = self.kv_heads
+        depth = k_cache.shape[1]
         seg, positions, pos0 = ctx["seg"], ctx["positions"], ctx["pos0"]
         k, v = k.astype(self.dtype), v.astype(self.dtype)
+        part = lambda name: (
+            jax.named_scope(f"{scope}/{name}") if scope else contextlib.nullcontext())
 
         # the cache after the fragment: the last episode's tokens,
         # each at its position (positions of one episode are
-        # distinct; earlier episodes' tokens are dropped)
-        slot = jnp.where(seg == seg[:, -1:], positions, self.positions)
+        # distinct; earlier episodes' tokens are dropped); in a ring
+        # the last ``depth`` of them, each at its position mod ``depth``
+        if window is None:
+            slot = jnp.where(seg == seg[:, -1:], positions, depth)
+        else:
+            kept = (seg == seg[:, -1:]) & (positions > positions[:, -1:] - depth)
+            slot = jnp.where(kept, positions % depth, depth)
         rows = jnp.arange(b)[:, None]
-        new_k = k_cache.at[rows, slot].set(
-            k.reshape(b, t, hkv * d).astype(k_cache.dtype), mode="drop")
-        new_v = v_cache.at[rows, slot].set(
-            v.reshape(b, t, hkv * d).astype(v_cache.dtype), mode="drop")
+        with part("scatter"):
+            new_k = k_cache.at[rows, slot].set(
+                k.reshape(b, t, hkv * d).astype(k_cache.dtype), mode="drop")
+            new_v = v_cache.at[rows, slot].set(
+                v.reshape(b, t, hkv * d).astype(v_cache.dtype), mode="drop")
 
         group = h // hkv
         qh = (q * scale).astype(self.dtype).reshape(b, t, hkv, group, d)
-        slots = jnp.arange(self.positions)
+        slots = jnp.arange(depth)
 
-        def attend(qe, ke, ve, kc, vc, sege, pos0e):
+        def attend(qe, ke, ve, kc, vc, sege, pos0e, pose=None):
             kc = kc.reshape(kc.shape[:2] + (hkv, d))
             vc = vc.reshape(vc.shape[:2] + (hkv, d))
             # one block of envs: scores over the stored keys (a
             # stored key is seen by the tokens before the first
             # reset, below the start position) and the fragment's
             # own (causal, same episode)
-            old = jnp.einsum(
-                "btngd,bsnd->bngts", qe, kc, preferred_element_type=jnp.float32
-            )
-            see_old = (sege == 0)[:, :, None] & (
-                slots[None, None] < pos0e[:, None, None]
-            )  # (b, t, S)
-            old = jnp.where(see_old[:, None, None], old, -jnp.inf)
-            if t == 1:
-                # the step's own key is in the cache already
-                own = jnp.full(old.shape[:-1] + (0,), -jnp.inf)
-            else:
-                own = jnp.einsum(
-                    "btngd,bsnd->bngts", qe, ke,
+            with part("scores"):
+                old = jnp.einsum(
+                    "btngd,bsnd->bngts", qe, kc, preferred_element_type=jnp.float32
+                )
+                if window is None:
+                    see_old = (sege == 0)[:, :, None] & (
+                        slots[None, None] < pos0e[:, None, None]
+                    )  # (b, t, S)
+                else:
+                    # the position of the row in each slot
+                    last = pos0e[:, None] - 1
+                    held = last - (last - slots[None]) % depth  # (b, S)
+                    see_old = (sege == 0)[:, :, None] & (held >= 0)[:, None] & (
+                        pose[:, :, None] - held[:, None] < window)
+                old = jnp.where(see_old[:, None, None], old, -jnp.inf)
+                if t == 1:
+                    # the step's own key is in the cache already
+                    own = jnp.full(old.shape[:-1] + (0,), -jnp.inf)
+                else:
+                    own = jnp.einsum(
+                        "btngd,bsnd->bngts", qe, ke,
+                        preferred_element_type=jnp.float32,
+                    )
+                    see = (steps_t[:, None] >= steps_t[None, :])[None] & (
+                        sege[:, :, None] == sege[:, None, :]
+                    )
+                    if window is not None:
+                        see = see & (steps_t[:, None] - steps_t[None, :] < window)[None]
+                    own = jnp.where(see[:, None, None], own, -jnp.inf)
+                w = jax.nn.softmax(jnp.concatenate([old, own], axis=-1), axis=-1)
+                w = w.astype(self.dtype)
+            with part("out"):
+                out = jnp.einsum(
+                    "bngts,bsnd->btngd", w[..., :depth], vc,
                     preferred_element_type=jnp.float32,
                 )
-                see = (steps_t[:, None] >= steps_t[None, :])[None] & (
-                    sege[:, :, None] == sege[:, None, :]
-                )
-                own = jnp.where(see[:, None, None], own, -jnp.inf)
-            w = jax.nn.softmax(jnp.concatenate([old, own], axis=-1), axis=-1)
-            w = w.astype(self.dtype)
-            out = jnp.einsum(
-                "bngts,bsnd->btngd", w[..., : self.positions], vc,
-                preferred_element_type=jnp.float32,
-            )
+                if t > 1:
+                    out = out + jnp.einsum(
+                        "bngts,bsnd->btngd", w[..., depth:], ve,
+                        preferred_element_type=jnp.float32,
+                    )
+            if window is None:
+                return out
+            # (query, key) pairs seen, a stream
+            seen = jnp.sum(see_old, axis=(1, 2), dtype=jnp.float32)
             if t > 1:
-                out = out + jnp.einsum(
-                    "bngts,bsnd->btngd", w[..., self.positions :], ve,
-                    preferred_element_type=jnp.float32,
-                )
-            return out
+                seen = seen + jnp.sum(see, axis=(1, 2), dtype=jnp.float32)
+            return out, seen
 
         steps_t = jnp.arange(t)
+        # a ring's masks need each query's position
+        own_positions = () if window is None else (positions,)
         if t == 1:
             # decode reads the cache it has just written: the own
             # key sits at slot pos0, so the stored range is one longer
-            o = attend(qh, k, v, new_k, new_v, seg, pos0 + 1)
+            o = attend(qh, k, v, new_k, new_v, seg, pos0 + 1, *own_positions)
         else:
-            nb = max(1, b // _ATTN_ENV_BLOCK)
+            nb = max(1, b // _attn_env_block(h, t, depth + t))
             if b % nb:
                 nb = 1
-            args = (qh, k, v, k_cache, v_cache, seg, pos0)
+            args = (qh, k, v, k_cache, v_cache, seg, pos0) + own_positions
             blocked = tuple(
                 a.reshape((nb, b // nb) + a.shape[1:]) for a in args
             )
             o = jax.lax.map(
                 lambda xs: jax.checkpoint(attend)(*xs), blocked
             )
-            o = o.reshape((b,) + o.shape[2:])
-        return o.reshape(b, t, h, d), (new_k, new_v)
+            o = jax.tree_util.tree_map(
+                lambda a: a.reshape((b,) + a.shape[2:]), o)
+        if window is None:
+            return o.reshape(b, t, h, d), (new_k, new_v)
+        o, seen = o
+        return o.reshape(b, t, h, d), (new_k, new_v), jnp.sum(seen)
 
     # -- latent attention ------------------------------------------------
 
@@ -1083,6 +1226,16 @@ class SequenceLM:
             )
         return out.reshape(b, t, d), None, None
 
+    def _route(self, p, flat):
+        """Every token's ``(indices, weights)`` from ``flat`` ``(tokens,
+        D)``: the expert layer's input, or the block's where the router
+        stands before the mixer."""
+        return moe.route_top_k(
+            flat, p["router"], self.top_k, self.norm_topk,
+            scoring=self.scoring, select_bias=p.get("select_bias"),
+            scale=self.route_scale,
+        )
+
     def _moe(self, p, x, ctx):
         b, t, d = x.shape
         flat = x.reshape(b * t, d)
@@ -1092,11 +1245,8 @@ class SequenceLM:
         lowering = moe.product_lowering(b * t, self.top_k, self.router_outputs)
         metrics.inc_moe_product_lowering(lowering)
         with jax.named_scope(scope + "moe/route"):
-            indices, weights = moe.route_top_k(
-                flat, p["router"], self.top_k, self.norm_topk,
-                scoring=self.scoring, select_bias=p.get("select_bias"),
-                scale=self.route_scale,
-            )
+            # routed already where the router reads the block's input
+            indices, weights = ctx.get("route") or self._route(p, flat)
             if lowering == "dense":
                 combine = moe.held_combine_weights(
                     indices, weights, self.first_expert, held
@@ -1111,13 +1261,17 @@ class SequenceLM:
         with jax.named_scope(scope + "moe/experts"):
             if lowering == "dense":
                 routed = moe.dense_experts_product(
-                    flat, *experts, combine, dtype=self.dtype
+                    flat, *experts, combine, dtype=self.dtype,
+                    activation=self.expert_act,
                 )
             else:
                 routed = moe.grouped_experts_product(
                     flat, *experts, indices, weights, per_expert,
                     self.first_expert, self.router_outputs, dtype=self.dtype,
+                    activation=self.expert_act,
                 )
+        if not self.shared_width:  # the routed sum alone
+            return routed.reshape(b, t, d), load, indices
         with jax.named_scope(scope + "moe/shared"):
             shared = moe.gated_mlp(
                 flat, p["shared_gate"], p["shared_up"], p["shared_down"],

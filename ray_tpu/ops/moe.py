@@ -9,6 +9,9 @@ token. What the absent experts would add
 is left out (model-configs guide, section 4): no token is dropped, no
 capacity is set, and nothing stands in for other chips.
 
+An expert is gated: ``(act(x Wg) * (x Wu)) Wd`` with ``act`` named by
+``activation`` (``"silu"``: SwiGLU; ``"relu"``: ReGLU).
+
 The product has two forms with one result (float32 summation order
 apart), and :func:`product_lowering` picks one from the static shapes:
 
@@ -94,13 +97,17 @@ def expert_load(indices, first: int, held: int):
     return per_expert, jnp.sum(~here).astype(jnp.float32)
 
 
-def gated_mlp(x, w_gate, w_up, w_down, dtype=jnp.bfloat16):
-    """``(silu(x Wg) * (x Wu)) Wd`` with ``dtype`` operands and float32
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def gated_mlp(x, w_gate, w_up, w_down, dtype=jnp.bfloat16, activation: str = "silu"):
+    """``(act(x Wg) * (x Wu)) Wd`` with ``dtype`` operands and float32
     accumulation: the shared expert, and one routed expert."""
+    act = _ACTIVATIONS[activation]
     xb = x.astype(dtype)
     gate = jnp.dot(xb, w_gate.astype(dtype), preferred_element_type=jnp.float32)
     up = jnp.dot(xb, w_up.astype(dtype), preferred_element_type=jnp.float32)
-    hidden = (jax.nn.silu(gate) * up).astype(dtype)
+    hidden = (act(gate) * up).astype(dtype)
     return jnp.dot(hidden, w_down.astype(dtype), preferred_element_type=jnp.float32)
 
 
@@ -144,7 +151,7 @@ def rows_computed(per_expert, tokens: int, k: int, num_experts: int, lowering: s
 
 def dense_experts_product(
     x, w_gate, w_up, w_down, combine, *, block_tokens: int = 1024,
-    dtype=jnp.bfloat16,
+    dtype=jnp.bfloat16, activation: str = "silu",
 ):
     """``sum_e combine[t, e] * expert_e(x_t)``, every held expert over
     every token. ``x`` ``(T, D)``; ``w_gate``, ``w_up`` ``(held, D,
@@ -153,6 +160,7 @@ def dense_experts_product(
     backward pass), so the ``(block, held, F)`` hidden activations bound
     the memory, not ``(T, held, F)``."""
     t, d = x.shape
+    act = _ACTIVATIONS[activation]
     wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
 
     @jax.checkpoint
@@ -160,7 +168,7 @@ def dense_experts_product(
         xb = xb.astype(dtype)
         gate = jnp.einsum("td,edf->tef", xb, wg, preferred_element_type=jnp.float32)
         up = jnp.einsum("td,edf->tef", xb, wu, preferred_element_type=jnp.float32)
-        hidden = (jax.nn.silu(gate) * up * cb[..., None]).astype(dtype)
+        hidden = (act(gate) * up * cb[..., None]).astype(dtype)
         return jnp.einsum(
             "tef,efd->td", hidden, wd, preferred_element_type=jnp.float32
         )
@@ -177,7 +185,7 @@ def dense_experts_product(
 
 def grouped_experts_product(
     x, w_gate, w_up, w_down, indices, weights, per_expert, first: int,
-    num_experts: int, dtype=jnp.bfloat16,
+    num_experts: int, dtype=jnp.bfloat16, activation: str = "silu",
 ):
     """The same sum over the (token, slot) pairs on held experts only.
     ``indices``, ``weights`` ``(T, k)`` as :func:`route_top_k` gives
@@ -194,6 +202,7 @@ def grouped_experts_product(
     k = indices.shape[-1]
     buffer = expert_buffer_rows(t, k, num_experts)
     counts = per_expert.astype(jnp.int32)
+    act = _ACTIVATIONS[activation]
     # cast once for both ways: the cond hands back ``dtype`` gradients
     wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
 
@@ -210,7 +219,7 @@ def grouped_experts_product(
         rows = jnp.take(x.astype(dtype), token, axis=0, mode="fill", fill_value=0)
         gate = jnp.einsum("ecd,edf->ecf", rows, wg, preferred_element_type=jnp.float32)
         up = jnp.einsum("ecd,edf->ecf", rows, wu, preferred_element_type=jnp.float32)
-        hidden = (jax.nn.silu(gate) * up * weight[..., None]).astype(dtype)
+        hidden = (act(gate) * up * weight[..., None]).astype(dtype)
         out = jnp.einsum(
             "ecf,efd->ecd", hidden, wd, preferred_element_type=jnp.float32
         )
@@ -220,6 +229,7 @@ def grouped_experts_product(
 
     def dense():
         combine = held_combine_weights(indices, weights, first, held)
-        return dense_experts_product(x, wg, wu, wd, combine, dtype=dtype)
+        return dense_experts_product(
+            x, wg, wu, wd, combine, dtype=dtype, activation=activation)
 
     return jax.lax.cond(jnp.max(counts) <= buffer, grouped, dense)

@@ -130,6 +130,15 @@ MLA_DECODE_LOWERINGS_TOTAL = "ray_tpu_mla_decode_lowerings_total"
 # body of a run of stacked layers (a run is one scan, so its layers
 # share one trace)
 SSM_STEP_LOWERINGS_TOTAL = "ray_tpu_ssm_step_lowerings_total"
+# which form each traced sliding-window attention layer took over its
+# ring cache (models/sequence_lm.py): form = step (one token written to
+# slot position mod window, then the ring read under the slots' own
+# positions: the rollout's step) | fragment (a fragment's queries over
+# the stored ring, each row's position recovered from its slot and the
+# start position, and the fragment's own keys inside the window: the
+# learn form). Counted when the form is traced: once per window layer
+# body of a program
+WINDOW_CACHE_LOWERINGS_TOTAL = "ray_tpu_window_cache_lowerings_total"
 # prioritized-replay segment-tree operations by op and by which tree
 # implementation performed them (docs/data_plane.md "device sum
 # tree"): host = the numpy SumSegmentTree walk, device = the
@@ -601,6 +610,21 @@ def inc_ssm_step_lowering(path: str) -> None:
 def ssm_step_lowerings() -> Dict[str, float]:
     """``{path: traced one-token steps}`` since the process began."""
     return _totals_by_tag(SSM_STEP_LOWERINGS_TOTAL, "path")
+
+
+def inc_window_cache_lowering(form: str) -> None:
+    """One traced sliding-window attention layer took ``form``
+    (``step`` | ``fragment``) over its ring cache."""
+    counter(
+        WINDOW_CACHE_LOWERINGS_TOTAL,
+        "sliding-window attention layers traced, by the form they took",
+        ("form",),
+    ).inc(1.0, {"form": form})
+
+
+def window_cache_lowerings() -> Dict[str, float]:
+    """``{form: traced sliding-window layers}`` since the process began."""
+    return _totals_by_tag(WINDOW_CACHE_LOWERINGS_TOTAL, "form")
 
 
 def deltanet_step_lowerings() -> Dict[str, float]:
