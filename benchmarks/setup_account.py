@@ -7,7 +7,9 @@ to ``<out.jsonl>``: ``compile_stats()["families"]`` (every compile by
 program family and phase, the persistent cache's hits and misses; no
 ``JAX_LOG_COMPILES``), the live programs' rows and ``tracing.phases()``
 as they stood when the measured window began, the harness's own
-``[setup]`` line, and the result line. ``benchmarks/chip/setup_account.sh``
+``[setup]`` line, the result line, the lowering counters at exit
+(``ray_tpu_*_lowerings_total``) and the newest iteration's
+``attn_key_blocks_skipped_share`` / ``window_rows_seen_mean``. ``benchmarks/chip/setup_account.sh``
 runs it cold and warm for each cell; ``--table`` prints PERF.md's
 "Where set-up goes" from such lines.
 """
@@ -48,6 +50,19 @@ def run(out_path: str, argv) -> int:
         return window
 
     perf_run.measure = measure_after_snapshot
+    # the newest iteration's learn stats that say which path a layer took
+    from ray_tpu.algorithms.algorithm import Algorithm
+
+    train = Algorithm.train
+
+    def train_and_keep(self):
+        result = train(self)
+        found = _find(result, ("attn_key_blocks_skipped_share", "window_rows_seen_mean"))
+        if found:
+            record["learn_stats"] = found
+        return result
+
+    Algorithm.train = train_and_keep
     stdout = io.StringIO()
 
     class Tee(io.TextIOBase):
@@ -71,9 +86,31 @@ def run(out_path: str, argv) -> int:
     except (IndexError, ValueError):
         pass
     record["families_at_exit"] = compile_stats().get("families")
+    from ray_tpu.telemetry import metrics
+
+    record["lowerings"] = {
+        name: getattr(metrics, name)()
+        for name in ("attention_fragment_lowerings", "window_cache_lowerings",
+                     "deltanet_step_lowerings", "ssm_step_lowerings")
+        if hasattr(metrics, name)  # an older tree lacks the newest
+    }
     with open(out_path, "a") as f:
         f.write(json.dumps(record) + "\n")
     return rc
+
+
+def _find(tree, names):
+    """``{name: value}`` of the first entry of each name anywhere in a
+    result's nested dicts."""
+    found = {}
+    if isinstance(tree, dict):
+        for key, value in tree.items():
+            if key in names:
+                found.setdefault(key, float(value))
+            else:
+                for k, v in _find(value, names).items():
+                    found.setdefault(k, v)
+    return found
 
 
 def table(paths) -> None:

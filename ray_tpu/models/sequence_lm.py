@@ -155,7 +155,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops import deltanet, hyper_connection, latent_attention, moe, ssd
+from ray_tpu.ops import (
+    deltanet, flash_attention, hyper_connection, latent_attention, moe, ssd)
 from ray_tpu.telemetry import metrics
 
 _HI = jax.lax.Precision.HIGHEST
@@ -821,6 +822,24 @@ class SequenceLM:
             # rows inside the window a query of a window layer saw
             stats_out["window_rows_seen_mean"] = sum(rows_seen) / (
                 b * t * len(rows_seen))
+        cached = [
+            self._segment_state(state, n)[0].shape[1]
+            for n, (_, kind, _, _) in enumerate(self.segments)
+            if kind in (FULL, ATTENTION, SLIDING)
+        ]
+        if stats_out is not None and t > 1 and cached:
+            # of the key blocks the fragment kernel walks (a stream's
+            # stored blocks and its own), those it skips: the stored ones
+            # at or past the start position (0 where the XLA text runs,
+            # which multiplies every slot)
+            skipped, walked = 0.0, 0
+            for depth in cached:
+                if flash_attention.fragment_kernel_applies(
+                        t, self.heads, self.kv_heads, self.head_dim, depth,
+                        self.dtype):
+                    more, blocks = flash_attention.fragment_key_blocks(pos0, depth)
+                    skipped, walked = skipped + more, walked + blocks
+            stats_out["attn_key_blocks_skipped_share"] = skipped / max(walked, 1)
         if stats_out is not None and not loads:
             if "moe_routes" in stats_out:
                 # asked for every token's expert set where no layer
@@ -1156,7 +1175,20 @@ class SequenceLM:
             # decode reads the cache it has just written: the own
             # key sits at slot pos0, so the stored range is one longer
             o = attend(qh, k, v, new_k, new_v, seg, pos0 + 1, *own_positions)
+        elif flash_attention.fragment_kernel_applies(
+                t, h, hkv, d, depth, self.dtype):
+            # one tiled kernel, forward and backward: no score matrix
+            # is written, and no block of streams is needed to hold one
+            metrics.inc_attention_fragment_lowering("kernel")
+            with part("scores"):
+                o = flash_attention.fragment_attention(
+                    qh, k, v, k_cache, v_cache, pos0, seg, positions,
+                    window=window)
+                if window is not None:
+                    o = o, flash_attention.fragment_pairs_seen(
+                        pos0, seg, positions, depth, window)
         else:
+            metrics.inc_attention_fragment_lowering("xla")
             nb = max(1, b // _attn_env_block(h, t, depth + t))
             if b % nb:
                 nb = 1

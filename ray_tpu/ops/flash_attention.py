@@ -290,3 +290,423 @@ def flash_attention(
     else:
         out = _reference_attention(qf, kf, vf, causal_offset)
     return out.reshape(B, H, T, D)
+
+
+# -- a fragment over a stored cache (the sequence models' learn form) -------
+#
+# ``models/sequence_lm._cached_attention``'s fragment form: T queries of
+# every stream over the rows its cache holds and the fragment's own
+# keys. One grid step is one stream, one key head and one block of keys;
+# the ``group`` query heads that share the key head are rows of the one
+# query tile, so a key block crosses HBM once a key head. The masks are
+# built in the kernel from the stream's start position (a scalar-prefetch
+# operand, which also keeps the blocks past it off the grid's work) and
+# the queries' episode numbers and positions.
+
+_FRAGMENT_BLOCK_K = 512
+# of the v5e's 128 MiB of VMEM, what a call may take (the default is 16)
+_FRAGMENT_VMEM_BYTES = 64 * 2 ** 20
+_LANES = 128
+# a masked score: finite, so that a row a block masks whole carries
+# ``exp(0)`` sums that the first real key's correction ``exp(_MASKED -
+# m)`` = 0 wipes out exactly (every query sees its own key, last)
+_MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_FAR = 2 ** 30  # a position no query's reaches
+
+
+def fragment_block_k(depth: int, block_k: int | None = None) -> int:
+    """Keys of one stored block: the largest of 512, 256, 128 that
+    divides ``depth`` (0 where none does)."""
+    if block_k is not None:
+        return block_k if depth % block_k == 0 else 0
+    for size in (_FRAGMENT_BLOCK_K, 256, _LANES):
+        if depth % size == 0:
+            return size
+    return 0
+
+
+def _heads_packed(head_dim: int, kv_heads: int) -> int:
+    """Key heads that share one block of 128 lanes: ``128 / D`` of a
+    head narrower than the lanes where the key heads divide so, else 1."""
+    pack = _LANES // head_dim if _LANES % head_dim == 0 else 1
+    return pack if kv_heads % pack == 0 else 1
+
+
+def fragment_kernel_applies(
+        tokens, heads, kv_heads, head_dim, depth, dtype) -> bool:
+    """The fragment kernel's lowering exists on a TPU (the process's
+    default backend, as in ``ops/deltanet.py``) for bfloat16 operands,
+    a fragment of whole 128-lane tiles of tokens (the own keys' episode
+    numbers lie along the lanes, and a tile of weights is turned for the
+    own keys' gradients), a cache of whole key blocks, a head that is
+    whole lane tiles or packs into one (64: two key heads a block), and
+    a query tile (every token of the key head's query heads) that the
+    backward pass can hold in VMEM: per row and lane ``q`` and ``dq`` in
+    bfloat16 and ``o`` and ``do`` in float32, each twice for the
+    pipeline, and the float32 ``dq`` accumulator (28 bytes), and three
+    one-lane statistics that occupy whole 128-lane rows."""
+    pack = _heads_packed(head_dim, kv_heads)
+    rows = pack * (heads // kv_heads) * tokens
+    return (
+        jax.default_backend() == "tpu"
+        and dtype == jnp.bfloat16
+        and tokens % _LANES == 0
+        and fragment_block_k(depth) > 0
+        and head_dim * pack % _LANES == 0
+        and rows * (28 * head_dim * pack + 12 * _LANES)
+        <= _FRAGMENT_VMEM_BYTES // 2
+    )
+
+
+def _blocks_held(pos0, block_k: int, stored: int):
+    """Of a stream's ``stored`` key blocks, those with a slot below its
+    start position."""
+    return jnp.minimum((pos0 + block_k - 1) // block_k, stored)
+
+
+def fragment_key_blocks(pos0, depth: int, block_k: int | None = None):
+    """``(skipped, all)`` key blocks of one key head of a fragment over
+    the streams ``pos0`` ``(B,)``: a stream's stored blocks at or past
+    its start position are skipped, the own block never."""
+    bk = fragment_block_k(depth, block_k)
+    stored = depth // bk
+    held = _blocks_held(pos0, bk, stored)
+    return jnp.sum(stored - held), pos0.shape[0] * (stored + 1)
+
+
+def fragment_pairs_seen(pos0, seg, positions, depth: int, window: int):
+    """(query, key) pairs a window layer's fragment sees, summed over
+    the streams: the masks' arithmetic of ``_cached_attention``, reduced
+    where it is built (no mask is written)."""
+    t = seg.shape[1]
+    slots = jnp.arange(depth)
+    last = pos0[:, None] - 1
+    held = last - (last - slots[None]) % depth  # (B, S)
+    see_old = (seg == 0)[:, :, None] & (held >= 0)[:, None] & (
+        positions[:, :, None] - held[:, None] < window)
+    steps = jnp.arange(t)
+    behind = steps[:, None] - steps[None, :]
+    see = ((behind >= 0) & (behind < window))[None] & (
+        seg[:, :, None] == seg[:, None, :])
+    # a stream's count is exact in float32; so is the text's
+    return jnp.sum(jnp.sum(see_old, axis=(1, 2), dtype=jnp.float32)
+                   + jnp.sum(see, axis=(1, 2), dtype=jnp.float32))
+
+
+def _stored_mask(pos0, seg_q, pos_q, first, block_k, depth, window):
+    """``(T, block_k)``: which of the stored slots ``first ..`` each
+    query sees. No window: the queries before the first reset see the
+    slots below ``pos0``. A ring of ``depth`` slots: slot ``s`` holds
+    position ``held = last - (last - s) mod depth`` (``last = pos0 -
+    1``; nothing where that is negative), seen from less than
+    ``window`` positions ahead. One comparison of a row of slots with a
+    column of queries: the other conditions move the row or the column
+    out of reach."""
+    slot = first + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    if window is None:
+        return slot < jnp.where(seg_q == 0, pos0, 0)
+    last = pos0 - 1
+    turn = jax.lax.rem(last + depth, depth)  # last mod depth; last >= -1
+    top = last - turn
+    held = slot + jnp.where(slot <= turn, top, top - depth)
+    held = jnp.where(held >= 0, held, -_FAR)
+    return held > jnp.where(seg_q == 0, pos_q - window, _FAR)
+
+
+def _own_mask(seg_q, seg_k, window):
+    """``(T, T)``: causal, same episode, inside the window."""
+    t = seg_q.shape[0]
+    behind = (jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+              - jax.lax.broadcasted_iota(jnp.int32, (t, t), 1))
+    mask = (behind >= 0) & (seg_q == seg_k)
+    if window is not None:
+        mask = mask & (behind < window)
+    return mask
+
+
+def _fragment_fwd_kernel(
+    pos0_ref, q_ref, kc_ref, vc_ref, k_ref, v_ref, seg_q_ref, pos_q_ref,
+    seg_k_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
+    window, depth, block_k,
+):
+    """One stream, one key head, one block of keys: the stored blocks in
+    turn, then the fragment's own. ``q_ref`` ``(1, 1, group, T, D)``;
+    the running max, sum and accumulator of every query row live in
+    scratch across the key blocks."""
+    b, kb = pl.program_id(0), pl.program_id(2)
+    stored = depth // block_k
+    pos0 = pos0_ref[b]
+    group = q_ref.shape[2]
+
+    @pl.when(kb == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def fold(keys, values, mask):
+        for g in range(group):
+            s = jax.lax.dot_general(
+                q_ref[0, 0, g], keys, _NT, preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s, _MASKED)
+            m_prev = m_ref[g]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[g] = alpha * l_ref[g] + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[g] = alpha * acc_ref[g] + jnp.dot(
+                p.astype(values.dtype), values,
+                preferred_element_type=jnp.float32)
+            m_ref[g] = m_new
+
+    # a stored block at or past the start position holds nothing any
+    # query may see (in a ring too: the slots from ``pos0`` on are empty
+    # until the ring has turned once)
+    @pl.when((kb < stored) & (kb * block_k < pos0))
+    def _():
+        fold(kc_ref[0], vc_ref[0], _stored_mask(
+            pos0, seg_q_ref[0], pos_q_ref[0], kb * block_k, block_k, depth,
+            window))
+
+    @pl.when(kb == stored)
+    def _():
+        fold(k_ref[0], v_ref[0], _own_mask(seg_q_ref[0], seg_k_ref[0], window))
+        for g in range(group):
+            l = l_ref[g]
+            o_ref[0, 0, g] = (acc_ref[g] / l).astype(o_ref.dtype)
+            lse_ref[0, 0, g] = m_ref[g] + jnp.log(l)
+
+
+def _fragment_bwd_kernel(
+    pos0_ref, q_ref, kc_ref, vc_ref, k_ref, v_ref, seg_q_ref, pos_q_ref,
+    seg_k_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref, dq_acc,
+    delta_ref, *, window, depth, block_k,
+):
+    """The same walk; every score tile is computed again from the row
+    statistics. ``dq`` gathers over all key blocks, the own keys' ``dk``
+    and ``dv`` are made in the last step; a stored row gets nothing."""
+    b, kb = pl.program_id(0), pl.program_id(2)
+    stored = depth // block_k
+    pos0 = pos0_ref[b]
+    group = q_ref.shape[2]
+
+    @pl.when(kb == 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+        for g in range(group):
+            delta_ref[g] = jnp.sum(
+                o_ref[0, 0, g] * do_ref[0, 0, g], axis=-1, keepdims=True)
+
+    def fold(keys, values, mask, own):
+        dk = dv = None
+        for g in range(group):
+            q = q_ref[0, 0, g]
+            do = do_ref[0, 0, g].astype(values.dtype)
+            s = jax.lax.dot_general(
+                q, keys, _NT, preferred_element_type=jnp.float32)
+            p = jnp.exp(jnp.where(mask, s, _MASKED) - lse_ref[0, 0, g])
+            dp = jax.lax.dot_general(
+                do, values, _NT, preferred_element_type=jnp.float32)
+            ds = p * (dp - delta_ref[g])
+            dq_acc[g] += jnp.dot(
+                ds.astype(keys.dtype), keys, preferred_element_type=jnp.float32)
+            if own:
+                dv_g = jnp.dot(
+                    p.T.astype(do.dtype), do, preferred_element_type=jnp.float32)
+                dk_g = jnp.dot(
+                    ds.T.astype(q.dtype), q, preferred_element_type=jnp.float32)
+                dv = dv_g if dv is None else dv + dv_g
+                dk = dk_g if dk is None else dk + dk_g
+        return dk, dv
+
+    @pl.when((kb < stored) & (kb * block_k < pos0))
+    def _():
+        fold(kc_ref[0], vc_ref[0], _stored_mask(
+            pos0, seg_q_ref[0], pos_q_ref[0], kb * block_k, block_k, depth,
+            window), False)
+
+    @pl.when(kb == stored)
+    def _():
+        dk, dv = fold(
+            k_ref[0], v_ref[0],
+            _own_mask(seg_q_ref[0], seg_k_ref[0], window), True)
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+        for g in range(group):
+            dq_ref[0, 0, g] = dq_acc[g].astype(dq_ref.dtype)
+
+
+def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
+                   interpret, name):
+    """One pass over the grid ``(streams, key heads, stored blocks +
+    1)``. ``operands``: ``q`` ``(B, kv, group, T, D)``, the own ``k``,
+    ``v`` ``(B, T, kv * D)``, the caches ``(B, depth, kv * D)``,
+    ``pos0`` ``(B,)``, ``seg``, ``positions`` ``(B, T)``; ``rows``:
+    further operands blocked like ``q``; ``outs``: ``(shape, dtype)`` of
+    each result, blocked like ``q`` at five axes and like the own keys
+    at three."""
+    from ray_tpu import sharding as sharding_lib
+
+    q, k, v, k_cache, v_cache, pos0, seg, positions = operands
+    bsz, kv, group, t, d = q.shape
+    dv = v.shape[-1] // kv
+    depth = k_cache.shape[1]
+    stored = depth // block_k
+
+    def cached(width):
+        # past the last block a stream holds the index stays where it
+        # is, so nothing is fetched for the steps that are skipped
+        def index(b, n, kb, pos0):
+            last = jnp.maximum(_blocks_held(pos0[b], block_k, stored) - 1, 0)
+            return b, jnp.minimum(kb, last), n
+        return pl.BlockSpec((1, block_k, width), index)
+
+    tile = lambda shape: pl.BlockSpec(
+        (1, 1) + tuple(shape[2:]), lambda b, n, kb, pos0: (b, n, 0, 0, 0))
+    own = lambda width: pl.BlockSpec(
+        (1, t, width), lambda b, n, kb, pos0: (b, 0, n))
+    per_stream = lambda *shape: pl.BlockSpec(
+        (1,) + shape, lambda b, n, kb, pos0: (b, 0, 0))
+    # inside a ``shard_map`` the results vary over the axes the operands do
+    vma = sharding_lib.vma_of(operands)
+    return pl.pallas_call(
+        functools.partial(kernel, window=window, depth=depth, block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bsz, kv, stored + 1),
+            in_specs=[
+                tile(q.shape), cached(d), cached(dv), own(d), own(dv),
+                per_stream(t, 1), per_stream(t, 1), per_stream(1, t),
+                *(tile(r.shape) for r in rows),
+            ],
+            out_specs=[
+                tile(shape) if len(shape) == 5 else own(shape[-1] // kv)
+                for shape, _ in outs
+            ],
+            scratch_shapes=scratch,
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(shape, dtype, vma=vma) for shape, dtype in outs
+        ],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_FRAGMENT_VMEM_BYTES,
+        ),
+        name=name,
+    )(pos0.astype(jnp.int32), q, k_cache, v_cache, k, v,
+      seg[:, :, None], positions[:, :, None], seg[:, None, :], *rows)
+
+
+def _fragment_fwd(operands, window, block_k, interpret):
+    q, _, v = operands[:3]
+    bsz, kv, group, t, _ = q.shape
+    dv = v.shape[-1] // kv
+    stat = lambda: pltpu.VMEM((group, t, 1), jnp.float32)
+    return _fragment_call(
+        _fragment_fwd_kernel, operands, (),
+        [((bsz, kv, group, t, dv), jnp.float32),
+         ((bsz, kv, group, t, 1), jnp.float32)],
+        [stat(), stat(), pltpu.VMEM((group, t, dv), jnp.float32)],
+        window=window, block_k=block_k, interpret=interpret,
+        name="fragment_attention_fwd",
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions,
+                        window, block_k, interpret):
+    return _fragment_fwd(
+        (q, k, v, k_cache, v_cache, pos0, seg, positions),
+        window, block_k, interpret)[0]
+
+
+def _fragment_fwd_rule(*args):
+    operands, static = args[:8], args[8:]
+    o, lse = _fragment_fwd(operands, *static)
+    return o, (operands, o, lse)
+
+
+def _fragment_bwd_rule(window, block_k, interpret, residuals, do):
+    operands, o, lse = residuals
+    q, k, v = operands[:3]
+    group, t, d = q.shape[2:]
+    dq, dk, dv = _fragment_call(
+        _fragment_bwd_kernel, operands, (o, do, lse),
+        [(q.shape, q.dtype), (k.shape, k.dtype), (v.shape, v.dtype)],
+        [pltpu.VMEM((group, t, d), jnp.float32),
+         pltpu.VMEM((group, t, 1), jnp.float32)],
+        window=window, block_k=block_k, interpret=interpret,
+        name="fragment_attention_bwd",
+    )
+    # the stored rows are the rollout's, handed over as data: no
+    # gradient (``None`` is a zero cotangent), nor for the integers
+    return (dq, dk, dv) + (None,) * 5
+
+
+_fragment_attention.defvjp(_fragment_fwd_rule, _fragment_bwd_rule)
+
+
+def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
+                       window=None, block_k=None, interpret=False):
+    """A fragment's causal attention over its streams' stored keys and
+    values and its own, as one tiled kernel with an online softmax in
+    both directions: no ``(T, rows)`` matrix of scores or weights
+    reaches HBM in the forward pass, its recomputation or the backward
+    pass.
+
+    ``q`` ``(B, T, kv, group, D)``, scaled already, in the products'
+    type; ``k``, ``v`` ``(B, T, kv, D)`` the fragment's own (``v`` may
+    be of another width than ``k``); ``k_cache``, ``v_cache`` ``(B,
+    depth, kv * D)`` as the carry holds them BEFORE the fragment's
+    scatter; ``pos0`` ``(B,)`` the streams' start positions; ``seg``,
+    ``positions`` ``(B, T)`` each query's episode number inside the
+    fragment and position. Returns ``o`` ``(B, T, kv, group, Dv)``
+    float32. Scores, masks, running max and sum and the accumulators
+    are float32; the weights enter the value product in ``v``'s type
+    and are normalised after it.
+
+    A stored slot ``s`` is seen by the queries before the fragment's
+    first reset (``seg == 0``): without a ``window`` where ``s <
+    pos0``; in a ring of ``depth`` slots where the position it holds,
+    ``held = last - (last - s) mod depth`` with ``last = pos0 - 1``, is
+    not negative and less than ``window`` behind the query's. Own key
+    ``j`` is seen by query ``i`` where ``j <= i``, both of one episode
+    and ``i - j < window``. Stored blocks at or past ``pos0`` are
+    skipped whole.
+
+    Differentiable in ``q``, ``k`` and ``v``. The caches get NO
+    gradient (zeros): they are the rollout's rows, handed over as data,
+    and the learn lane truncates at the fragment's start; no caller
+    differentiates through them (``sharding/superstep.py``,
+    ``policy/jax_policy._grouped_loss_grad`` and ``perf/checks`` take
+    gradients in the parameters, with the state a column of the batch).
+    ``block_k`` and ``interpret`` are the tests' spellings."""
+    bsz, t, kv, group, d = q.shape
+    dv = v.shape[-1]
+    depth = k_cache.shape[1]
+    block_k = fragment_block_k(depth, block_k)
+    if not block_k:
+        raise ValueError(f"a cache of {depth} rows is not whole key blocks")
+    pack = _heads_packed(d, kv) if d == dv else 1
+    # a head narrower than the lanes: ``pack`` key heads a block, each
+    # of their query heads zero outside its own head's lanes, so that
+    # the products over the whole block are the head's own
+    own_lanes = jnp.eye(pack).reshape(pack, 1, pack, 1)
+
+    def spread(x):  # (B, T, kv, group, D) -> (B, kv', pack * group, T, pack * D)
+        x = x.reshape(bsz, t, kv // pack, pack, group, 1, -1)
+        if pack > 1:
+            x = x * own_lanes.astype(x.dtype)
+        return x.transpose(0, 2, 3, 4, 1, 5, 6).reshape(
+            bsz, kv // pack, pack * group, t, -1)
+
+    def gather(x):  # and back, each head from its own lanes
+        x = x.reshape(bsz, kv // pack, pack, group, t, pack, -1)
+        x = jnp.stack([x[:, :, a, :, :, a] for a in range(pack)], axis=2)
+        return x.reshape(bsz, kv, group, t, -1).transpose(0, 3, 1, 2, 4)
+
+    return gather(_fragment_attention(
+        spread(q), k.reshape(bsz, t, kv * d), v.reshape(bsz, t, kv * dv),
+        k_cache, v_cache, pos0, seg, positions, window, block_k, interpret))

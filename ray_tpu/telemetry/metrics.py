@@ -139,6 +139,15 @@ SSM_STEP_LOWERINGS_TOTAL = "ray_tpu_ssm_step_lowerings_total"
 # learn form). Counted when the form is traced: once per window layer
 # body of a program
 WINDOW_CACHE_LOWERINGS_TOTAL = "ray_tpu_window_cache_lowerings_total"
+# which lowering each traced attention layer's fragment form took
+# (models/sequence_lm._cached_attention, every softmax attention kind
+# over a stored cache): path = kernel (ops/flash_attention's tiled
+# fragment kernel, forward and backward: a TPU backend, bfloat16,
+# fragments and caches of whole blocks) | xla (the score matrices a
+# block of streams at a time, everywhere else). Counted when the form
+# is traced: once per attention layer body of a program
+ATTENTION_FRAGMENT_LOWERINGS_TOTAL = (
+    "ray_tpu_attention_fragment_lowerings_total")
 # prioritized-replay segment-tree operations by op and by which tree
 # implementation performed them (docs/data_plane.md "device sum
 # tree"): host = the numpy SumSegmentTree walk, device = the
@@ -620,6 +629,21 @@ def inc_window_cache_lowering(form: str) -> None:
         "sliding-window attention layers traced, by the form they took",
         ("form",),
     ).inc(1.0, {"form": form})
+
+
+def inc_attention_fragment_lowering(path: str) -> None:
+    """One traced attention layer's fragment form took ``path``
+    (``kernel`` | ``xla``)."""
+    counter(
+        ATTENTION_FRAGMENT_LOWERINGS_TOTAL,
+        "attention layers' fragment forms traced, by the lowering they took",
+        ("path",),
+    ).inc(1.0, {"path": path})
+
+
+def attention_fragment_lowerings() -> Dict[str, float]:
+    """``{path: traced fragment forms}`` since the process began."""
+    return _totals_by_tag(ATTENTION_FRAGMENT_LOWERINGS_TOTAL, "path")
 
 
 def window_cache_lowerings() -> Dict[str, float]:

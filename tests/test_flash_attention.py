@@ -94,3 +94,155 @@ def test_bf16_inputs():
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         atol=3e-2, rtol=3e-2,
     )
+
+
+# -- the fragment kernel against ``_cached_attention``'s XLA text ----------
+
+def _fragment(b=3, t=16, kv=2, group=4, d=128, depth=32, window=None,
+              pos0=(0, 10, 32), resets=((), (5,), ()), dtype=jnp.float32,
+              seed=0):
+    """A fragment of ``b`` streams at ``pos0`` with episode resets at
+    ``resets``: its operands as the model hands them over, and the rows
+    the lane derives from the resets."""
+    rng = np.random.default_rng(seed)
+    h = kv * group
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    q, k, v = normal(b, t, h, d), normal(b, t, kv, d), normal(b, t, kv, d)
+    caches = tuple(normal(b, depth, kv * d).astype(dtype) for _ in range(2))
+    fresh = np.zeros((b, t), bool)
+    for i, at in enumerate(resets):
+        fresh[i, list(at)] = True
+    seg = jnp.asarray(np.cumsum(fresh, 1), jnp.int32)
+    steps = np.arange(t)[None]
+    opened = np.maximum.accumulate(np.where(fresh, steps, -1), axis=1)
+    pos0 = jnp.asarray(pos0, jnp.int32)
+    positions = jnp.where(
+        seg == 0, pos0[:, None] + steps, steps - opened).astype(jnp.int32)
+    rows = {"seg": seg, "positions": positions, "pos0": pos0}
+    return (q, k, v) + caches, rows, normal(b, t, h, d)
+
+
+def _text_and_kernel(rows, kv, window, dtype, block_k):
+    """``(q, k, v, k_cache, v_cache) -> o (B, T, heads, D)`` twice: the
+    model's XLA text (the rule's branch off a TPU) and the kernel in the
+    interpreter."""
+    import types
+
+    from ray_tpu.models.sequence_lm import SequenceLM
+    from ray_tpu.ops.flash_attention import fragment_attention
+
+    stub = types.SimpleNamespace(kv_heads=kv, dtype=dtype)
+
+    def text(q, k, v, kc, vc):
+        return SequenceLM._cached_attention(
+            stub, q, k, v, (kc, vc), rows, q.shape[-1] ** -0.5, window=window,
+            scope="swa" if window else None)[0]
+
+    def kernel(q, k, v, kc, vc):
+        b, t, h, d = q.shape
+        qh = (q * d ** -0.5).astype(dtype).reshape(b, t, kv, h // kv, d)
+        return fragment_attention(
+            qh, k.astype(dtype), v.astype(dtype), kc, vc, rows["pos0"],
+            rows["seg"], rows["positions"], window=window, block_k=block_k,
+            interpret=True).reshape(b, t, h, d)
+
+    return text, kernel
+
+
+_FRAGMENT_CASES = {
+    # (i) no window: an empty cache, one part full with an episode reset
+    # inside the fragment, a full one; blocks of 16 keys, so the streams
+    # skip two, one and no stored block
+    "full_depths_and_a_reset": dict(),
+    "full_reset_at_the_first_token": dict(resets=((0,), (5, 9), ())),
+    # (ii) rings of 24 slots under a window of 24: not yet turned, just
+    # at the window, turned (positions 30, 47 and 100 deep)
+    "ring_not_turned": dict(window=24, depth=24, pos0=(0, 10, 23), block_k=8),
+    "ring_at_the_window": dict(
+        window=24, depth=24, pos0=(24, 24, 25), resets=((), (7,), ()), block_k=8),
+    "ring_turned": dict(window=24, depth=24, pos0=(30, 100, 47), block_k=8),
+    "ring_shorter_than_the_window": dict(
+        window=40, depth=32, pos0=(0, 20, 32), block_k=16),
+    # (iii) the three cells' heads and groups
+    "head_64_group_4": dict(d=64, kv=4, group=4),
+    "head_128_group_7": dict(d=128, kv=2, group=7),
+    "head_256_group_8": dict(d=256, kv=1, group=8),
+    "head_64_group_4_ring": dict(
+        d=64, kv=2, group=4, window=24, depth=24, pos0=(3, 100, 24), block_k=8),
+    "bfloat16": dict(dtype=jnp.bfloat16, group=7),
+}
+
+
+@pytest.mark.parametrize("name", list(_FRAGMENT_CASES) + [
+    "cache_cotangents_are_zeros", "a_skipped_block_changes_nothing"])
+def test_fragment_kernel(name):
+    case = dict(_FRAGMENT_CASES.get(name, {}))
+    block_k = case.pop("block_k", 16)
+    operands, rows, w = _fragment(**case)
+    kv, window = case.get("kv", 2), case.get("window")
+    dtype = case.get("dtype", jnp.float32)
+    text, kernel = _text_and_kernel(rows, kv, window, dtype, block_k)
+    loss = lambda f: lambda *a: jnp.sum(f(*a) * w)
+    if name == "cache_cotangents_are_zeros":
+        # the stored rows are the rollout's: the text would hand them a
+        # gradient, the kernel by its contract hands them none
+        got = jax.grad(loss(kernel), argnums=(3, 4))(*operands)
+        want = jax.grad(loss(text), argnums=(3, 4))(*operands)
+        assert all(float(jnp.max(jnp.abs(g))) == 0.0 for g in got)
+        assert all(float(jnp.max(jnp.abs(g))) > 0.1 for g in want)
+        return
+    if name == "a_skipped_block_changes_nothing":
+        # streams at 0, 10 and 16 of 32 slots: the second block of 16 is
+        # skipped for all three, so a cache cut to the first block, or
+        # one whose second block holds other rows, gives the same bits
+        operands, rows, w = _fragment(pos0=(0, 10, 16))
+        _, kernel = _text_and_kernel(rows, kv, window, dtype, block_k)
+        q, k, v, kc, vc = operands
+        both = jax.value_and_grad(loss(kernel), argnums=(0, 1, 2))
+        want = both(*operands)
+        cut = both(q, k, v, kc[:, :16], vc[:, :16])
+        other = both(q, k, v, kc.at[:, 16:].set(7.0), vc.at[:, 16:].set(-7.0))
+        for got in (cut, other):
+            for a, b in zip(jax.tree_util.tree_leaves(got),
+                            jax.tree_util.tree_leaves(want)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        return
+    tol = dict(atol=1e-4, rtol=1e-4) if dtype == jnp.float32 else dict(
+        atol=0.15, rtol=5e-2)
+    np.testing.assert_allclose(
+        np.asarray(kernel(*operands)), np.asarray(text(*operands)), **tol)
+    got = jax.grad(loss(kernel), argnums=(0, 1, 2))(*operands)
+    want = jax.grad(loss(text), argnums=(0, 1, 2))(*operands)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)
+
+
+def test_fragment_rule_blocks_and_pairs():
+    from ray_tpu.ops import flash_attention as fa
+
+    # off a TPU the rule says XLA, whatever the shape
+    assert not fa.fragment_kernel_applies(256, 28, 4, 128, 8192, jnp.bfloat16)
+    assert fa.fragment_block_k(8192) == 512
+    assert fa.fragment_block_k(2048 + 256) == 256
+    assert fa.fragment_block_k(24) == 0
+    # 16 stored blocks of 512 and the own: a stream at 0 skips all 16, one
+    # at 513 skips 14, one past the cache none
+    skipped, walked = fa.fragment_key_blocks(
+        jnp.asarray([0, 513, 9000], jnp.int32), 8192)
+    assert (int(skipped), walked) == (30, 51)
+    # the pairs a window layer sees, against the text's own count
+    _, rows, _ = _fragment(window=24, depth=24, pos0=(3, 100, 24),
+                           resets=((), (7,), ()))
+    slots, steps = np.arange(24), np.arange(16)
+    want = 0
+    for n in range(3):
+        last = int(rows["pos0"][n]) - 1
+        held = last - (last - slots) % 24
+        seg, pos = np.asarray(rows["seg"][n]), np.asarray(rows["positions"][n])
+        want += np.sum((seg == 0)[:, None] & (held >= 0)[None]
+                       & (pos[:, None] - held[None] < 24))
+        want += np.sum((steps[:, None] >= steps[None]) & (seg[:, None] == seg[None])
+                       & (steps[:, None] - steps[None] < 24))
+    got = fa.fragment_pairs_seen(
+        rows["pos0"], rows["seg"], rows["positions"], 24, 24)
+    assert float(got) == float(want)

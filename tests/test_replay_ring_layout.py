@@ -368,6 +368,64 @@ def test_v5e_ssd_step_kernel_compiles_in_place(v5e_mesh):
     assert mem.temp_size_in_bytes < 16e6
 
 
+# a group of the learn form's streams, tokens, key heads, query heads a
+# key head, head, cache depth, window: the three sequence cells' layers
+FRAGMENT_LAYERS = {
+    "smallthinker_full": (16, 256, 4, 7, 128, 8192, None),
+    "smallthinker_ring": (16, 256, 4, 7, 128, 4096, 4096),
+    "qwen3next": (16, 128, 2, 8, 256, 2048, None),
+    "granite4h": (16, 256, 8, 4, 64, 2048, None),
+}
+
+
+@pytest.mark.parametrize("layer", list(FRAGMENT_LAYERS))
+def test_v5e_fragment_attention_kernel_compiles(v5e_mesh, layer):
+    """The learn form's attention kernel (ops/flash_attention.py) at a
+    sequence cell's width, forward, recomputation and backward under
+    ``shard_map`` and a checkpoint as the learn program runs it. Mosaic
+    takes all three heads (64 rides two key heads a block); the three
+    custom calls carry the caller's scope, under which the trace files
+    their time; and no float32 array of (tokens, stored rows) exists
+    anywhere in the compiled program: the score matrices are never
+    written."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.ops import flash_attention
+
+    b, t, kv, group, d, depth, window = FRAGMENT_LAYERS[layer]
+    axis = sharding_lib.data_axis(v5e_mesh)
+    rows = sharding_lib.batch_sharded(v5e_mesh)
+    on = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=rows)
+    bf, i32 = jnp.bfloat16, jnp.int32
+
+    def grads(q, k, v, kc, vc, pos0, seg, positions):
+        @jax.checkpoint
+        def attention(q, k, v):
+            with jax.named_scope("learn/attn"):
+                return flash_attention.fragment_attention(
+                    q, k, v, kc, vc, pos0, seg, positions, window=window)
+
+        return jax.grad(
+            lambda *qkv: jnp.sum(jnp.square(attention(*qkv))),
+            argnums=(0, 1, 2))(q, k, v)
+
+    sharded = jax.shard_map(
+        grads, mesh=v5e_mesh, in_specs=(P(axis),) * 8, out_specs=P(axis))
+    compiled = jax.jit(sharded).lower(
+        on(bf, b, t, kv, group, d), on(bf, b, t, kv, d), on(bf, b, t, kv, d),
+        on(bf, b, depth, kv * d), on(bf, b, depth, kv * d),
+        on(i32, b), on(i32, b, t), on(i32, b, t),
+    ).compile()
+    text = compiled.as_text()
+    calls = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "custom-call(" in line
+    ]
+    assert len(calls) == 3 and all("learn/attn" in line for line in calls)
+    assert sum("fragment_attention_bwd" in line for line in calls) == 1
+    assert not re.search(rf"f32\[[0-9,]*{t},({depth}|{depth + t})\]", text)
+
+
 def test_v5e_tree_update_pays_for_its_levels(v5e_mesh):
     """The DQN cell's priority refresh (ops/segment_tree.py: an
     (8, 512) update of a 131,072-leaf f64 tree pair) as the chip's

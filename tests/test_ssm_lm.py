@@ -284,7 +284,9 @@ def test_a_model_with_no_expert_layer_reports_no_expert_statistic(setup):
     assert not hasattr(model, "router_outputs")
     stats = {}
     _model_forward(model, params, batch, stats)
-    assert sorted(stats) == ["ssm_dt_max"]
+    # beside it the attention layers' (no block is skipped by the XLA text)
+    assert sorted(stats) == ["attn_key_blocks_skipped_share", "ssm_dt_max"]
+    assert float(stats["attn_key_blocks_skipped_share"]) == 0.0
     # the largest step size the update saw: softplus of the in-projection's
     # dt columns plus dt_bias
     assert 0.0 < float(stats["ssm_dt_max"]) < 10.0

@@ -50,6 +50,103 @@ def test_flash_attention_on_tpu(shape):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-2)
 
 
+# the learn form's attention at the three sequence cells' sizes: a group
+# of streams, tokens, key heads, query heads a key head, head, cache
+# depth, window (SmallThinker's full layer and rings, Qwen3-Next, granite)
+FRAGMENT_SHAPES = {
+    "smallthinker_full": (4, 256, 4, 7, 128, 8192, None),
+    "smallthinker_ring": (4, 256, 4, 7, 128, 4096, 4096),
+    "qwen3next": (4, 128, 2, 8, 256, 2048, None),
+    "granite4h": (4, 256, 8, 4, 64, 2048, None),
+}
+
+
+@pytest.mark.parametrize("cell", list(FRAGMENT_SHAPES))
+def test_fragment_attention_on_tpu(cell, monkeypatch):
+    """``_cached_attention``'s fragment form takes the kernel on the
+    chip by its own rule, and output and gradients agree with the XLA
+    text (the rule's other branch) within bfloat16's rounding."""
+    import types
+
+    from ray_tpu.models.sequence_lm import SequenceLM
+    from ray_tpu.ops import flash_attention
+    from ray_tpu.telemetry import metrics
+
+    b, t, kv, group, d, depth, window = FRAGMENT_SHAPES[cell]
+    h = kv * group
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    q = jax.random.normal(keys[0], (b, t, h, d), jnp.float32)
+    k = jax.random.normal(keys[1], (b, t, kv, d), jnp.float32)
+    v = jax.random.normal(keys[2], (b, t, kv, d), jnp.float32)
+    caches = tuple(
+        jax.random.normal(key, (b, depth, kv * d), jnp.bfloat16)
+        for key in keys[3:5])
+    w = jax.random.normal(keys[5], (b, t, h, d), jnp.float32)
+    # an empty cache, one part full with a reset inside the fragment, one
+    # just past a block's edge, and one deeper than a ring
+    pos0 = jnp.asarray([0, depth // 4 + 3, 513, 2 * depth - 256], jnp.int32)
+    if window is None:
+        pos0 = jnp.minimum(pos0, depth - t)
+    fresh = np.zeros((b, t), bool)
+    fresh[1, t // 2] = True
+    seg = jnp.asarray(np.cumsum(fresh, 1), jnp.int32)
+    steps = np.arange(t)[None]
+    opened = np.maximum.accumulate(np.where(fresh, steps, -1), axis=1)
+    positions = jnp.where(
+        seg == 0, pos0[:, None] + steps, steps - opened).astype(jnp.int32)
+    rows = {"seg": seg, "positions": positions, "pos0": pos0}
+    stub = types.SimpleNamespace(kv_heads=kv, dtype=jnp.bfloat16)
+
+    def run():
+        # new functions a side: a jit of the same one would not trace again
+        def attention(q, k, v):
+            return SequenceLM._cached_attention(
+                stub, q, k, v, caches, rows, d ** -0.5, window=window,
+                scope="swa" if window else None)[0]
+
+        grads = jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(attention(q, k, v) * w), argnums=(0, 1, 2)))
+        return jax.jit(attention)(q, k, v), grads(q, k, v)
+
+    count = lambda path: metrics.attention_fragment_lowerings().get(path, 0)
+    before = count("kernel"), count("xla")
+    out, grads = run()
+    assert (count("kernel"), count("xla")) == (before[0] + 2, before[1])
+    monkeypatch.setattr(
+        flash_attention, "fragment_kernel_applies", lambda *a: False)
+    want, want_grads = run()
+    assert (count("kernel"), count("xla")) == (before[0] + 2, before[1] + 2)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-2)
+    for got, ref in zip(grads, want_grads):
+        scale = float(jnp.max(jnp.abs(ref)))
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(ref, np.float32),
+            atol=2e-2 * scale)
+
+
+def test_fragment_kernel_forced_on_a_refused_shape_raises():
+    """A head of 96 is neither whole lane tiles nor a part of one: the
+    rule keeps such a layer on the XLA text, and the kernel called for
+    it all the same raises the lowering's own message."""
+    from ray_tpu.ops import flash_attention
+
+    b, t, kv, group, d, depth = 2, 128, 2, 4, 96, 512
+    assert not flash_attention.fragment_kernel_applies(
+        t, kv * group, kv, d, depth, jnp.bfloat16)
+    assert flash_attention.fragment_kernel_applies(
+        t, kv * group, kv, 128, depth, jnp.bfloat16)
+    # a query tile the backward pass cannot hold in VMEM stays on the text
+    assert not flash_attention.fragment_kernel_applies(
+        4096, 28, 4, 128, 8192, jnp.bfloat16)
+    zeros = lambda *shape: jnp.zeros(shape, jnp.bfloat16)
+    rows = jnp.zeros((b, t), jnp.int32)
+    with pytest.raises(Exception, match="divisible by 8 and 128"):
+        jax.jit(flash_attention.fragment_attention)(
+            zeros(b, t, kv, group, d), zeros(b, t, kv, d), zeros(b, t, kv, d),
+            zeros(b, depth, kv * d), zeros(b, depth, kv * d),
+            jnp.zeros((b,), jnp.int32), rows, rows)
+
+
 @pytest.mark.parametrize("shape", STATS_SHAPES)
 def test_flash_block_stats_on_tpu(shape):
     from ray_tpu.ops.flash_attention import (

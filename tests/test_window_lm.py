@@ -454,6 +454,7 @@ def test_window_statistic_and_lowering_counter(setup):
     config, params, model, batch, _ = setup
     rows = batch["obs"].shape[0]
     before = dict(metrics.window_cache_lowerings())
+    paths = dict(metrics.attention_fragment_lowerings())
     stats = {}
     model.apply(
         params, jnp.asarray(batch["obs"]).reshape(rows // T, T, 1),
@@ -465,6 +466,13 @@ def test_window_statistic_and_lowering_counter(setup):
     # the three window layers' checkpointed block is one trace
     assert after["fragment"] - before.get("fragment", 0) == 1
     assert after["step"] - before.get("step", 0) == 3
+    # off a TPU every fragment form is the XLA text (the full layer's
+    # trace and the window layers'), the one-token form is not counted,
+    # and the text skips no key block
+    now = metrics.attention_fragment_lowerings()
+    assert now["xla"] - paths.get("xla", 0) == 2
+    assert now.get("kernel", 0) == paths.get("kernel", 0)
+    assert float(stats["attn_key_blocks_skipped_share"]) == 0.0
     # by hand from the positions: min(position + 1, window) a query
     pos0 = np.asarray(batch["__chunk__state_in_8"])
     fresh = batch["resets"].reshape(-1, T) > 0.5
@@ -477,6 +485,7 @@ def test_window_statistic_and_lowering_counter(setup):
             p += 1
     assert abs(float(stats["window_rows_seen_mean"]) - np.mean(seen)) < 1e-5
     assert sorted(stats) == [
+        "attn_key_blocks_skipped_share",
         "moe_max_tokens_per_held_expert", "moe_rows_computed_share",
         "moe_slots_on_absent_experts", "moe_tokens_per_held_expert",
         "window_rows_seen_mean"]
