@@ -7,11 +7,16 @@ A block is two sublayers, a MIXER and a FEED-FORWARD, each with its own
 zero-centred RMSNorm ``rms(x) = x * rsqrt(mean(x^2) + eps) * (1 + w)``,
 each applied through the block's RESIDUAL kind.
 
-Mixer kinds (``layer_types``, under the published names; every
-``full_attention_interval``-th layer full attention where it is not
-stated; all latent where the config has ``kv_lora_rank``; window and
-position-free layers by ``sliding_window_layout`` where the config has
-one):
+Mixer kinds (``layer_types``, under the published names, its first
+``num_hidden_layers`` entries; every ``full_attention_interval``-th
+layer full attention where it is not stated; all latent where the
+config has ``kv_lora_rank``; window and position-free layers by
+``sliding_window_layout`` where the config has one). The three softmax
+kinds (``"full_attention"``, ``"attention"``, ``"sliding_attention"``)
+are ONE body, ``_attention``, over a per-layer description
+(:class:`AttentionLayer`, read from the config by
+:func:`attention_layers_of`): query heads, window, RoPE (how much of
+the head, base, YaRN), gate and q/k norm are the layer's own:
 
 - ``"full_attention"`` (Hugging Face ``qwen3_next``,
   ``Qwen3NextAttention``): gated softmax attention. One projection gives
@@ -56,9 +61,22 @@ one):
   at position ``p`` sees the keys at ``p - sliding_window_size + 1 ..
   p`` of its episode and nothing older. The layers where the layout
   says 0 are the ``"attention"`` kind above: full depth, no positions.
+- Laguna's layers (``model_type: laguna``: ``layer_types`` names them
+  ``"full_attention"`` and ``"sliding_attention"``, and the keys beside
+  it say what they are): ``num_attention_heads_per_layer`` gives every
+  layer its own count of query heads over the same KV heads (48 at full
+  depth, 64 in a window of ``sliding_window`` 512); ``rope_parameters``
+  holds a block a kind (the full layers YaRN on the first
+  ``partial_rotary_factor`` of the head, ``cos`` and ``sin`` times its
+  ``attention_factor``; the window layers plain RoPE with another base
+  over the whole head); ``gating`` puts an output gate on EVERY
+  attention layer, ``sigmoid(x W_g)`` with one number a head and token
+  (``g_proj``, a leaf of its own), so a gated layer stands on a ring;
+  and a gated layer norms ``q`` and ``k`` over the head.
 
-Feed-forward kinds (``"dense"`` for the first ``first_k_dense_replace``
-layers, ``"experts"`` after):
+Feed-forward kinds (``mlp_layer_types``, ``"dense"`` or ``"sparse"`` a
+layer, where the config states them; else ``"dense"`` for the first
+``first_k_dense_replace`` layers, ``"experts"`` after):
 
 - ``"dense"``: SwiGLU of width ``intermediate_size``
   (``shared_intermediate_size`` where the config states one). A config
@@ -81,6 +99,10 @@ layers, ``"experts"`` after):
   (ReGLU) where the others gate with SiLU (``ops/moe.py``'s
   ``activation``); and there is NO shared expert: the layer has no
   ``shared_*`` leaves and its result is the routed sum alone.
+  Laguna (``moe_routed_scaling_factor`` beside ``qwen3_next``'s names
+  for the counts and widths): a sigmoid each, top-k with no selection
+  bias, the chosen scores over their sum times the factor, and the
+  shared expert of ``shared_expert_intermediate_size`` UNGATED.
 
 Residual kinds:
 
@@ -122,9 +144,10 @@ place, the fragment form scans over them); for an ``"attention"`` layer
 keys and values as for a full layer (no norm, no RoPE); for a
 ``"sliding_attention"`` layer a RING of keys and values, ``(min(window,
 positions), kv heads x head)`` bfloat16 whatever the episode's depth,
-keys stored after RoPE, the token at position ``p`` in slot ``p mod
-window`` (docs/policy_state.md, "The ring"); last, the stream's
-position. ``apply`` has two forms
+keys stored after the q/k norm and RoPE (YaRN's factor included), the
+token at position ``p`` in slot ``p mod window`` (docs/policy_state.md,
+"The ring"; a row is KV heads x head wide whatever the layer's query
+heads); last, the stream's position. ``apply`` has two forms
 that are the same function of the same weights: ``T == 1`` is the
 recurrence (one token, state in and out: the rollout lane's step; for
 latent attention the ABSORBED product against the latent rows), ``T >
@@ -148,7 +171,7 @@ two levels deep, ``{"layer_0": {"in_proj_qkvz": ...}, ...}``.
 
 from __future__ import annotations
 
-import contextlib
+import dataclasses
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -190,7 +213,8 @@ def layer_types_of(config: Dict) -> Tuple[str, ...]:
     no positions); else every ``full_attention_interval``-th layer is
     full attention."""
     if config.get("layer_types"):
-        return tuple(config["layer_types"])
+        # a published list: its first ``num_hidden_layers``
+        return tuple(config["layer_types"])[:config.get("num_hidden_layers")]
     layers = int(config["num_hidden_layers"])
     if "sliding_window_layout" in config:
         window = list(config["sliding_window_layout"])[:layers]
@@ -202,6 +226,115 @@ def layer_types_of(config: Dict) -> Tuple[str, ...]:
         return (LATENT,) * layers
     every = int(config.get("full_attention_interval", 4))
     return tuple(FULL if (i + 1) % every == 0 else LINEAR for i in range(layers))
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionLayer:
+    """One softmax-attention layer as ``SequenceLM._attention`` runs it:
+    what the ``"full_attention"``, ``"attention"`` and
+    ``"sliding_attention"`` kinds of every family differ in, read from
+    the config once (:func:`attention_layers_of`). Hashable: a
+    checkpointed block takes it as a static argument."""
+
+    kind: str
+    heads: int
+    kv_heads: int
+    head_dim: int
+    scale: float  # of the scores
+    # a ring of ``min(window, positions)`` rows; None: the episode's rows
+    window: Optional[int] = None
+    # RoPE: the head's leading dimensions it turns (0: no positions),
+    # its base, a YaRN block's items and the factor on cos and sin
+    rotary: int = 0
+    theta: float = 10000.0
+    yarn: Tuple[Tuple[str, float], ...] = ()
+    rope_factor: float = 1.0
+    # the output gate: "element" (``q_proj`` gives every head ``[q |
+    # gate]``, one number a dimension), "head" (``g_proj``, one number a
+    # head and token) or None
+    gate: Optional[str] = None
+    qk_norm: bool = False
+
+    @property
+    def scope(self) -> str:
+        return "swa" if self.window else "attn"
+
+    @property
+    def rope(self) -> str:
+        return "none" if not self.rotary else "yarn" if self.yarn else "default"
+
+    def cache_rows(self, positions: int) -> int:
+        return positions if self.window is None else min(self.window, positions)
+
+
+_YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast", "beta_slow")
+
+
+def attention_layers_of(config: Dict, layer_types) -> Dict[int, AttentionLayer]:
+    """``{layer: AttentionLayer}`` for the softmax-attention layers of
+    ``layer_types``, each key read for what it states and under
+    whichever family's name it has:
+
+    - heads: ``num_attention_heads_per_layer[l]``, else
+      ``num_attention_heads``; KV heads and the head's size are one for
+      the model;
+    - window (a ``"sliding_attention"`` layer): ``sliding_window_size``
+      or ``sliding_window``;
+    - RoPE: the ``rope_parameters`` block of the layer's kind where the
+      config has them (``rope_theta``, ``partial_rotary_factor``,
+      ``rope_type`` ``default`` or ``yarn`` with its ``factor``,
+      ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``
+      and ``attention_factor``, ``0.1 ln(factor) + 1`` where none is
+      stated); else ``rope_theta`` on the first
+      ``partial_rotary_factor`` of a ``"full_attention"`` head, on the
+      whole of a ``"sliding_attention"`` head, and none on an
+      ``"attention"`` layer;
+    - gate: ``gating`` states one for EVERY attention layer, a number a
+      head (``g_proj``); without the key a ``"full_attention"`` layer is
+      ``qwen3_next``'s, gated a dimension out of ``q_proj``, and the
+      other kinds have none. A gated layer norms ``q`` and ``k`` over
+      the head;
+    - scale: ``attention_multiplier`` on an ``"attention"`` layer that
+      states one, else ``head^-1/2``."""
+    c = config
+    out = {}
+    per_layer = c.get("num_attention_heads_per_layer")
+    for i, kind in enumerate(layer_types):
+        if kind not in (FULL, ATTENTION, SLIDING):
+            continue
+        heads = int(per_layer[i] if per_layer else c["num_attention_heads"])
+        head_dim = int(c.get("head_dim") or int(c["hidden_size"]) // heads)
+        window = None
+        if kind == SLIDING:
+            window = int(c.get("sliding_window_size") or c["sliding_window"])
+        rotary, theta, yarn, factor = 0, float(c.get("rope_theta", 10000.0)), (), 1.0
+        if "rope_parameters" in c:
+            rope = c["rope_parameters"][kind]
+            theta = float(rope["rope_theta"])
+            rotary = int(head_dim * float(rope.get("partial_rotary_factor", 1.0)))
+            kind_of = rope.get("rope_type", "default")
+            if kind_of == "yarn":
+                yarn = tuple((k, float(rope[k])) for k in _YARN_KEYS if k in rope)
+                factor = float(rope.get("attention_factor")
+                               or 0.1 * np.log(float(rope["factor"])) + 1.0)
+            elif kind_of != "default":
+                raise ValueError(f"rope_type {kind_of!r} is not supported")
+        elif kind == FULL:
+            rotary = int(head_dim * float(c.get("partial_rotary_factor", 1.0)))
+        elif kind == SLIDING:
+            rotary = head_dim
+        elif c.get("position_embedding_type", "nope") != "nope":
+            raise ValueError('an "attention" layer takes no positions')
+        gate = "head" if c.get("gating") else "element" if kind == FULL else None
+        scale = head_dim ** -0.5
+        if kind == ATTENTION:
+            scale = float(c.get("attention_multiplier", scale))
+        out[i] = AttentionLayer(
+            kind=kind, heads=heads, kv_heads=int(c["num_key_value_heads"]),
+            head_dim=head_dim, scale=scale, window=window, rotary=rotary,
+            theta=theta, yarn=yarn, rope_factor=factor, gate=gate,
+            qk_norm=gate is not None)
+    return out
 
 
 def _attn_env_block(heads: int, tokens: int, rows: int) -> int:
@@ -248,13 +381,23 @@ def _causal_conv(tail, x, seg, kernel, bias=None):
     return out, jnp.where(live, full[:, t:], 0.0)
 
 
-def _rope(x, positions, rotary: int, theta: float):
+def _rope(x, positions, rotary: int, theta: float, yarn=(), factor: float = 1.0):
     """Rotate the first ``rotary`` dimensions of each head (the
-    rotate-half form). ``x`` ``(B, T, H, D)``, ``positions`` ``(B, T)``."""
+    rotate-half form). ``x`` ``(B, T, H, D)``, ``positions`` ``(B, T)``.
+    ``yarn`` (the items of a YaRN block: ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``)
+    scales the frequencies as ``ops/latent_attention.yarn_inv_freq``
+    does; ``factor`` multiplies ``cos`` and ``sin`` (YaRN's
+    ``attention_factor``), so the turned dimensions alone carry it."""
     half = rotary // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary)
+    if yarn:
+        inv = jnp.asarray(latent_attention.yarn_inv_freq(rotary, theta, dict(yarn)))
+    else:
+        inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary)
     angle = positions.astype(jnp.float32)[..., None] * inv  # (B, T, half)
     cos, sin = jnp.cos(angle)[:, :, None], jnp.sin(angle)[:, :, None]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2, rest = x[..., :half], x[..., half:rotary], x[..., rotary:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1
@@ -310,12 +453,15 @@ class SequenceLM:
             (c[k] for k in ("num_experts", "n_routed_experts", "num_local_experts",
                             "moe_num_primary_experts")
              if k in c), 0))
-        dense_first = int(c.get("first_k_dense_replace", 0)) if experts else (
-            len(self.layer_types))
-        self.ffn_types = tuple(
-            DENSE if i < dense_first else EXPERTS
-            for i in range(len(self.layer_types))
-        )
+        layers = len(self.layer_types)
+        if experts and "mlp_layer_types" in c:  # stated a layer
+            self.ffn_types = tuple(
+                DENSE if kind == DENSE else EXPERTS
+                for kind in c["mlp_layer_types"][:layers])
+        else:
+            dense_first = int(c.get("first_k_dense_replace", 0)) if experts else layers
+            self.ffn_types = tuple(
+                DENSE if i < dense_first else EXPERTS for i in range(layers))
         # groups of the parameter tree: a run of state-space layers
         # with its leaves stacked, every other layer alone
         segments = []
@@ -337,22 +483,10 @@ class SequenceLM:
         self.heads = int(c["num_attention_heads"])
         self.theta = float(c.get("rope_theta", 10000.0))
         self.positions = int(c["max_position_embeddings"])
-        if FULL in self.layer_types:  # gated attention
-            self.kv_heads = int(c["num_key_value_heads"])
-            self.head_dim = int(c["head_dim"])
-            self.rotary = int(
-                self.head_dim * float(c.get("partial_rotary_factor", 1.0)))
-        if ATTENTION in self.layer_types:  # plain GQA, no positions
-            if c.get("position_embedding_type", "nope") != "nope":
-                raise ValueError('an "attention" layer takes no positions')
-            self.kv_heads = int(c["num_key_value_heads"])
-            self.head_dim = int(c.get("head_dim") or self.hidden // self.heads)
-            self.attn_scale = float(
-                c.get("attention_multiplier", self.head_dim ** -0.5))
-        if SLIDING in self.layer_types:  # GQA with RoPE inside a window
-            self.kv_heads = int(c["num_key_value_heads"])
-            self.head_dim = int(c.get("head_dim") or self.hidden // self.heads)
-            self.window = int(c["sliding_window_size"])
+        # the softmax-attention layers, each with its own geometry
+        self.attention = {
+            f"layer_{i}": a
+            for i, a in attention_layers_of(c, self.layer_types).items()}
         if MAMBA in self.layer_types:  # Mamba-2
             if int(c.get("mamba_n_groups", 1)) != 1:
                 raise ValueError("B and C are shared by all heads: mamba_n_groups 1")
@@ -423,16 +557,23 @@ class SequenceLM:
         self.top_k = int(c["moe_num_active_primary_experts" if primary
                            else "num_experts_per_tok"])
         self.norm_topk = bool(c.get("norm_topk_prob", True))
-        self.scoring = str(c.get("scoring_func", "softmax"))
-        self.route_scale = float(c.get("routed_scaling_factor", 1.0))
+        # a config with ``moe_routed_scaling_factor`` (Laguna) names its
+        # experts as ``qwen3_next`` does and routes as DeepSeek-V3 does
+        # without the selection bias: a sigmoid each, top-k, renormalised,
+        # times the factor, the shared expert ungated
+        scaled = "moe_routed_scaling_factor" in c
+        self.scoring = str(c.get("scoring_func", "sigmoid" if scaled else "softmax"))
+        self.route_scale = float(c.get(
+            "routed_scaling_factor", c.get("moe_routed_scaling_factor", 1.0)))
         self.select_bias = c.get("topk_method") == "noaux_tc"
         self.expert_width = int(
             c["moe_ffn_hidden_size" if primary else "moe_intermediate_size"])
         # the shared expert: ``qwen3_next`` states its width and gates
         # it; DeepSeek-V3 counts shared experts of the routed width
-        self.shared_gated = "shared_expert_intermediate_size" in c
+        stated = "shared_expert_intermediate_size" in c
+        self.shared_gated = stated and not scaled
         self.shared_width = int(
-            c["shared_expert_intermediate_size"] if self.shared_gated
+            c["shared_expert_intermediate_size"] if stated
             else int(c.get("n_shared_experts", 0 if primary else 1))
             * self.expert_width
         )
@@ -455,7 +596,7 @@ class SequenceLM:
     def initial_state(self, batch_size: int = 1):
         b = int(batch_size)
         state = []
-        for _, kind, _, layers in self.segments:
+        for name, kind, _, layers in self.segments:
             if kind == MAMBA:
                 state.append(jnp.zeros(
                     (b, layers, self.ssm_heads, self.ssm_head, self.ssm_state),
@@ -469,13 +610,12 @@ class SequenceLM:
                 state.append(
                     jnp.zeros((b, self.conv - 1, self.conv_dim), jnp.float32)
                 )
-            elif kind in (FULL, ATTENTION, SLIDING):
+            elif name in self.attention:
                 # one row a position: kv heads x head, flat, so that the
                 # device tiles (positions, row) without padding 2 heads to 8;
                 # a window layer holds a ring of its window's rows
-                depth = self.positions if kind != SLIDING else min(
-                    self.window, self.positions)
-                shape = (b, depth, self.kv_heads * self.head_dim)
+                a = self.attention[name]
+                shape = (b, a.cache_rows(self.positions), a.kv_heads * a.head_dim)
                 state.append(jnp.zeros(shape, self.dtype))
                 state.append(jnp.zeros(shape, self.dtype))
             else:
@@ -568,22 +708,20 @@ class SequenceLM:
                 if self.ssm_conv_bias:
                     layer["conv_bias"] = (self.ssm_conv_dim,)
                 layer = {k: (layers,) + shape for k, shape in layer.items()}
-            elif kind in (ATTENTION, SLIDING):
+            elif name in self.attention:
+                a = self.attention[name]
+                wide = a.heads * a.head_dim
                 layer.update(
-                    q_proj=(d, self.heads * self.head_dim),
-                    k_proj=(d, self.kv_heads * self.head_dim),
-                    v_proj=(d, self.kv_heads * self.head_dim),
-                    o_proj=(self.heads * self.head_dim, d),
+                    # every head's [q | gate] where the gate is a dimension's
+                    q_proj=(d, wide * (2 if a.gate == "element" else 1)),
+                    k_proj=(d, a.kv_heads * a.head_dim),
+                    v_proj=(d, a.kv_heads * a.head_dim),
+                    o_proj=(wide, d),
                 )
-            elif kind == FULL:
-                layer.update(
-                    q_proj=(d, self.heads * self.head_dim * 2),
-                    k_proj=(d, self.kv_heads * self.head_dim),
-                    v_proj=(d, self.kv_heads * self.head_dim),
-                    o_proj=(self.heads * self.head_dim, d),
-                    q_norm=(self.head_dim,),
-                    k_norm=(self.head_dim,),
-                )
+                if a.qk_norm:
+                    layer.update(q_norm=(a.head_dim,), k_norm=(a.head_dim,))
+                if a.gate == "head":
+                    layer["g_proj"] = (d, a.heads)
             else:
                 h = self.heads
                 layer.update(
@@ -724,13 +862,15 @@ class SequenceLM:
         if hyper:  # the embedding copied into every lane, held flat
             x = jnp.tile(x, (1, 1, self.lanes))
 
-        def block(x, p, layer_state, rows, kind, ffn_kind):
+        def block(x, p, layer_state, rows, kind, ffn_kind, attention):
             ctx = dict(rows, scope=prefix)
-            mixer = {
-                LINEAR: self._linear_attn, FULL: self._attn,
-                LATENT: self._latent_attn, MAMBA: self._mamba,
-                ATTENTION: self._plain_attn, SLIDING: self._sliding_attn,
-            }[kind]
+            if attention is not None:  # a softmax-attention layer's geometry
+                mixer = lambda p, h, s, ctx: self._attention(p, h, s, ctx, attention)
+            else:
+                mixer = {
+                    LINEAR: self._linear_attn, LATENT: self._latent_attn,
+                    MAMBA: self._mamba,
+                }[kind]
             ffn = self._moe if ffn_kind == EXPERTS else self._mlp
             if hyper:
                 return self._hyper_block(x, p, layer_state, ctx, mixer, ffn)
@@ -757,7 +897,7 @@ class SequenceLM:
         # in the backward pass, ``learn_streams`` streams at a time: one
         # group's activations of one block are alive, not the batch's
         # of the stack
-        whole = jax.checkpoint(block, static_argnums=(4, 5)) if t > 1 else block
+        whole = jax.checkpoint(block, static_argnums=(4, 5, 6)) if t > 1 else block
 
         def run_block(x, p, layer_state, rows, *kinds):
             if groups == 1:
@@ -779,8 +919,10 @@ class SequenceLM:
         state_out, loads, all_routes, errs, steps_seen, rows_seen = (
             [], [], [], [], [], [])
         for n, (name, kind, ffn_kind, _) in enumerate(self.segments):
+            # the block's static arguments: its kinds and, for a
+            # softmax-attention layer, its geometry
             args = (params[name], self._segment_state(state, n), rows_ctx,
-                    kind, ffn_kind)
+                    kind, ffn_kind, self.attention.get(name))
             if kind == MAMBA:
                 x, new, seen = self._run_of_layers(run_block, prefix, x, *args)
                 steps_seen.append(seen)
@@ -822,21 +964,19 @@ class SequenceLM:
             # rows inside the window a query of a window layer saw
             stats_out["window_rows_seen_mean"] = sum(rows_seen) / (
                 b * t * len(rows_seen))
-        cached = [
-            self._segment_state(state, n)[0].shape[1]
-            for n, (_, kind, _, _) in enumerate(self.segments)
-            if kind in (FULL, ATTENTION, SLIDING)
-        ]
-        if stats_out is not None and t > 1 and cached:
+        if stats_out is not None and t > 1 and self.attention:
             # of the key blocks the fragment kernel walks (a stream's
             # stored blocks and its own), those it skips: the stored ones
             # at or past the start position (0 where the XLA text runs,
             # which multiplies every slot)
             skipped, walked = 0.0, 0
-            for depth in cached:
+            for n, (name, _, _, _) in enumerate(self.segments):
+                if name not in self.attention:
+                    continue
+                a = self.attention[name]
+                depth = self._segment_state(state, n)[0].shape[1]
                 if flash_attention.fragment_kernel_applies(
-                        t, self.heads, self.kv_heads, self.head_dim, depth,
-                        self.dtype):
+                        t, a.heads, a.kv_heads, a.head_dim, depth, self.dtype):
                     more, blocks = flash_attention.fragment_key_blocks(pos0, depth)
                     skipped, walked = skipped + more, walked + blocks
             stats_out["attn_key_blocks_skipped_share"] = skipped / max(walked, 1)
@@ -858,6 +998,13 @@ class SequenceLM:
             else:
                 stats_out["moe_tokens_per_held_expert"] = jnp.mean(per_expert)
                 stats_out["moe_max_tokens_per_held_expert"] = jnp.max(per_expert)
+                # of the held experts, those that some stream's token at
+                # the same place of its fragment reached: where a
+                # fragment is one stream's rollout (the fused lane) a
+                # place is a decode step, and this is the share of the
+                # held experts' weights a step's tokens chose
+                stats_out["moe_decode_held_experts_touched_share"] = jnp.mean(
+                    jnp.stack([l[3] for l in loads]) > 0)
             stats_out["moe_slots_on_absent_experts"] = sum(l[1] for l in loads)
             # of the dense form's (token, held expert) rows, those the
             # experts' products computed (the grouped form: its buffers)
@@ -901,7 +1048,7 @@ class SequenceLM:
     def _scaled(self, y):
         return y if self.residual_scale == 1.0 else y * self.residual_scale
 
-    def _run_of_layers(self, run_block, scope, x, p, run_state, rows, kind, ffn_kind):
+    def _run_of_layers(self, run_block, scope, x, p, run_state, rows, *kinds):
         """A run of identical layers whose leaves are stacked on a
         leading layer axis, as ONE ``lax.scan``: the layer is traced
         once. ``run_state``'s leaves carry the layer axis after the
@@ -927,7 +1074,7 @@ class SequenceLM:
                 with jax.named_scope(scope + "ssm/carry"):
                     tail = jax.lax.dynamic_index_in_dim(tails, layer, 1, keepdims=False)
                 x, (matrices, tail, seen), _, _ = run_block(
-                    x, p_l, ((matrices, layer), tail), rows, kind, ffn_kind)
+                    x, p_l, ((matrices, layer), tail), rows, *kinds)
                 with jax.named_scope(scope + "ssm/carry"):
                     tails = jax.lax.dynamic_update_index_in_dim(
                         tails, tail.astype(tails.dtype), layer, 1)
@@ -938,7 +1085,7 @@ class SequenceLM:
 
         def fragment(x, xs):
             p_l, mine = xs
-            x, (*new, seen), _, _ = run_block(x, p_l, mine, rows, kind, ffn_kind)
+            x, (*new, seen), _, _ = run_block(x, p_l, mine, rows, *kinds)
             return x, (tuple(new), seen)
 
         x, (new, seen) = jax.lax.scan(
@@ -1018,61 +1165,63 @@ class SequenceLM:
             o = o * jax.nn.silu(z.reshape(b, t, hv, self.dv))
             return self._dot(o.reshape(b, t, vd), p["out_proj"]), (s1, new_tail)
 
-    # -- gated attention -------------------------------------------------
+    # -- softmax attention -----------------------------------------------
 
-    def _attn(self, p, x, state, ctx):
-        with jax.named_scope(ctx["scope"] + "attn"):
-            b, t, _ = x.shape
-            h, hkv, d = self.heads, self.kv_heads, self.head_dim
-            positions = ctx["positions"]
-            qg = self._dot(x, p["q_proj"]).reshape(b, t, h, 2 * d)
-            q, gate = qg[..., :d], qg[..., d:]
-            k = self._dot(x, p["k_proj"]).reshape(b, t, hkv, d)
-            v = self._dot(x, p["v_proj"]).reshape(b, t, hkv, d)
-            q = _rope(_rms(q, p["q_norm"], self.eps), positions, self.rotary, self.theta)
-            k = _rope(_rms(k, p["k_norm"], self.eps), positions, self.rotary, self.theta)
-            o, new = self._cached_attention(q, k, v, state, ctx, d ** -0.5)
-            o = o * jax.nn.sigmoid(gate)
-            return self._dot(o.reshape(b, t, h * d), p["o_proj"]), new
-
-    def _plain_attn(self, p, x, state, ctx):
-        """GQA softmax attention with no positions, no q/k norm and no
-        gate, scaled by ``attention_multiplier``."""
-        with jax.named_scope(ctx["scope"] + "attn"):
-            b, t, _ = x.shape
-            h, hkv, d = self.heads, self.kv_heads, self.head_dim
-            q = self._dot(x, p["q_proj"]).reshape(b, t, h, d)
-            k = self._dot(x, p["k_proj"]).reshape(b, t, hkv, d)
-            v = self._dot(x, p["v_proj"]).reshape(b, t, hkv, d)
-            o, new = self._cached_attention(q, k, v, state, ctx, self.attn_scale)
-            return self._dot(o.reshape(b, t, h * d), p["o_proj"]), new
-
-    def _sliding_attn(self, p, x, state, ctx):
-        """GQA softmax attention inside a window of ``sliding_window_size``
-        positions over a ring cache, RoPE over the whole head, no q/k
-        norm and no gate. Beside the ring it hands back the number of
-        rows its queries saw."""
-        scope = ctx["scope"] + "swa"
-        with jax.named_scope(scope):
-            b, t, _ = x.shape
-            h, hkv, d = self.heads, self.kv_heads, self.head_dim
-            positions = ctx["positions"]
+    def _attention(self, p, x, state, ctx, a: AttentionLayer):
+        """A softmax-attention layer of the geometry ``a``: GQA over the
+        layer's own head count, ``q`` and ``k`` RMS-normed over the head
+        where it is gated, RoPE on the head's leading ``a.rotary``
+        dimensions (none: no positions), the cache the episode's rows or
+        a ring of ``a.window``, the output times a sigmoid gate (a number
+        a dimension out of ``q_proj``, or a number a head from
+        ``g_proj``). Projections, norms and RoPE run under the scope
+        ``attn`` (``swa`` for a window layer), the cached attention's
+        parts under its ``/scatter``, ``/scores`` and ``/out``, the gate
+        under ``/gate``, the output projection under ``/out``. A window
+        layer hands back, beside its ring, the number of rows its queries
+        saw."""
+        scope = ctx["scope"] + a.scope
+        b, t, _ = x.shape
+        h, hkv, d = a.heads, a.kv_heads, a.head_dim
+        positions = ctx["positions"]
+        metrics.inc_attention_layer_lowering(a.kind, h, a.rope)
+        if a.window is not None:
             metrics.inc_window_cache_lowering("step" if t == 1 else "fragment")
-            q = self._dot(x, p["q_proj"]).reshape(b, t, h, d)
+        with jax.named_scope(scope):
+            q = self._dot(x, p["q_proj"])
+            if a.gate == "element":
+                q = q.reshape(b, t, h, 2 * d)
+                q, gate = q[..., :d], q[..., d:]
+            else:
+                q = q.reshape(b, t, h, d)
             k = self._dot(x, p["k_proj"]).reshape(b, t, hkv, d)
             v = self._dot(x, p["v_proj"]).reshape(b, t, hkv, d)
-            q = _rope(q, positions, d, self.theta)
-            k = _rope(k, positions, d, self.theta)
-        o, new, seen = self._cached_attention(
-            q, k, v, state, ctx, d ** -0.5, window=self.window, scope=scope)
-        with jax.named_scope(scope + "/out"):
-            return self._dot(o.reshape(b, t, h * d), p["o_proj"]), new + (seen,)
 
-    def _cached_attention(self, q, k, v, state, ctx, scale, window=None, scope=None):
+            def normed_and_turned(z, norm):
+                if a.qk_norm:
+                    z = _rms(z, p[norm], self.eps)
+                if a.rotary:
+                    z = _rope(z, positions, a.rotary, a.theta, a.yarn, a.rope_factor)
+                return z
+
+            q, k = normed_and_turned(q, "q_norm"), normed_and_turned(k, "k_norm")
+        o, new, seen = self._cached_attention(
+            q, k, v, state, ctx, a.scale, window=a.window, scope=scope)
+        if a.gate is not None:
+            with jax.named_scope(scope + "/gate"):
+                if a.gate == "head":
+                    gate = self._dot(x, p["g_proj"])[..., None]  # (B, T, H, 1)
+                o = o * jax.nn.sigmoid(gate)
+        with jax.named_scope(scope + "/out"):
+            y = self._dot(o.reshape(b, t, h * d), p["o_proj"])
+        return y, new if a.window is None else new + (seen,)
+
+    def _cached_attention(self, q, k, v, state, ctx, scale, window=None, scope=""):
         """Causal attention of a fragment's ``q`` ``(B, T, heads, D)``
         over the stored keys and values and the fragment's own ``k``,
         ``v`` ``(B, T, kv heads, D)``. Returns ``(o (B, T, heads, D),
-        (keys, values) after the fragment)``.
+        (keys, values) after the fragment, pairs seen)``, its parts
+        under ``scope``'s ``/scatter``, ``/scores`` and ``/out``.
 
         With a ``window`` the cache is a RING of ``R = min(window,
         positions)`` slots, position ``p`` in slot ``p mod R``: the row
@@ -1080,17 +1229,15 @@ class SequenceLM:
         largest position below ``pos0`` that is ``s mod R`` (none where
         that is negative), a query sees the rows whose position is less
         than ``window`` behind its own, and the masks come from those
-        positions, never from slot numbers. The parts then run under
-        ``scope``'s ``/scatter``, ``/scores`` and ``/out`` and the
-        number of (query, key) pairs seen is returned third."""
+        positions, never from slot numbers, and the number of (query,
+        key) pairs seen is returned third (None without a window)."""
         k_cache, v_cache = state
         b, t, h, d = q.shape
-        hkv = self.kv_heads
+        hkv = k.shape[2]
         depth = k_cache.shape[1]
         seg, positions, pos0 = ctx["seg"], ctx["positions"], ctx["pos0"]
         k, v = k.astype(self.dtype), v.astype(self.dtype)
-        part = lambda name: (
-            jax.named_scope(f"{scope}/{name}") if scope else contextlib.nullcontext())
+        part = lambda name: jax.named_scope(f"{scope}/{name}")
 
         # the cache after the fragment: the last episode's tokens,
         # each at its position (positions of one episode are
@@ -1202,7 +1349,7 @@ class SequenceLM:
             o = jax.tree_util.tree_map(
                 lambda a: a.reshape((b,) + a.shape[2:]), o)
         if window is None:
-            return o.reshape(b, t, h, d), (new_k, new_v)
+            return o.reshape(b, t, h, d), (new_k, new_v), None
         o, seen = o
         return o.reshape(b, t, h, d), (new_k, new_v), jnp.sum(seen)
 
@@ -1284,10 +1431,17 @@ class SequenceLM:
                     indices, weights, self.first_expert, held
                 )
             per_expert, absent = moe.expert_load(indices, self.first_expert, held)
+            # tokens each held expert got at each place of the fragment,
+            # over the streams: ``(T, held)``
+            local = indices.reshape(b, t, -1) - self.first_expert
+            by_place = jnp.sum(
+                local[..., None] == jnp.arange(held, dtype=jnp.int32),
+                axis=(0, 2), dtype=jnp.float32)
             load = (
                 per_expert, absent,
                 moe.rows_computed(
                     per_expert, b * t, self.top_k, self.router_outputs, lowering),
+                by_place,
             )
         experts = (p["experts_gate"], p["experts_up"], p["experts_down"])
         with jax.named_scope(scope + "moe/experts"):

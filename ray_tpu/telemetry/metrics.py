@@ -105,6 +105,11 @@ SUPERSTEP_UPDATES_TOTAL = "ray_tpu_superstep_updates_total"
 # the (token, slot) pairs that fell on experts held elsewhere
 MOE_HELD_EXPERT_TOKENS_TOTAL = "ray_tpu_moe_held_expert_tokens_total"
 MOE_ABSENT_SLOTS_TOTAL = "ray_tpu_moe_absent_slots_total"
+# of the held experts of a layer, the share that at least one stream's
+# token reached at the same place of its fragment (a decode step of the
+# fused lane, where a fragment is one stream's rollout): summed over
+# updates under stat = share, the updates counted under stat = updates
+MOE_DECODE_HELD_TOUCHED_TOTAL = "ray_tpu_moe_decode_held_experts_touched_total"
 # which lowering each traced one-token gated-delta step took
 # (ops/deltanet.py): path = kernel (the Pallas kernel: a TPU, whole
 # tiles) | xla (the jax.numpy body). Counted when the form is traced,
@@ -148,6 +153,13 @@ WINDOW_CACHE_LOWERINGS_TOTAL = "ray_tpu_window_cache_lowerings_total"
 # is traced: once per attention layer body of a program
 ATTENTION_FRAGMENT_LOWERINGS_TOTAL = (
     "ray_tpu_attention_fragment_lowerings_total")
+# the geometry of each traced softmax-attention layer body
+# (models/sequence_lm.SequenceLM._attention, one body over a per-layer
+# description): kind = the layer's name in ``layer_types``, heads = ITS
+# query heads, rope = none | default | yarn. Counted when the body is
+# traced (layers whose checkpointed block is the same trace once), in
+# either form, so a trace says that every layer got its own geometry
+ATTENTION_LAYER_LOWERINGS_TOTAL = "ray_tpu_attention_layer_lowerings_total"
 # prioritized-replay segment-tree operations by op and by which tree
 # implementation performed them (docs/data_plane.md "device sum
 # tree"): host = the numpy SumSegmentTree walk, device = the
@@ -544,6 +556,17 @@ def note_expert_load(infos) -> None:
             MOE_ABSENT_SLOTS_TOTAL,
             "(token, slot) pairs routed to experts this chip does not hold",
         ).inc(float(info["moe_slots_on_absent_experts"]))
+        if "moe_decode_held_experts_touched_share" in info:
+            touched = counter(
+                MOE_DECODE_HELD_TOUCHED_TOTAL,
+                "share of the held experts a decode step's tokens reached, "
+                "summed over updates; and the updates counted",
+                ("stat",),
+            )
+            touched.inc(
+                float(info["moe_decode_held_experts_touched_share"]),
+                {"stat": "share"})
+            touched.inc(1.0, {"stat": "updates"})
 
 
 def expert_load_totals() -> Dict[str, float]:
@@ -555,6 +578,12 @@ def expert_load_totals() -> Dict[str, float]:
     out = {dict(tags).get("stat", ""): v for tags, v in m.series()}
     out["absent_slots"] = counter_total(MOE_ABSENT_SLOTS_TOTAL)
     return out
+
+
+def decode_held_experts_touched() -> Dict[str, float]:
+    """``{"share", "updates"}`` sums since the process began ({} for a
+    model that reports none)."""
+    return _totals_by_tag(MOE_DECODE_HELD_TOUCHED_TOTAL, "stat")
 
 
 def _totals_by_tag(name: str, tag: str) -> Dict[str, float]:
@@ -629,6 +658,28 @@ def inc_window_cache_lowering(form: str) -> None:
         "sliding-window attention layers traced, by the form they took",
         ("form",),
     ).inc(1.0, {"form": form})
+
+
+def inc_attention_layer_lowering(kind: str, heads: int, rope: str) -> None:
+    """One traced softmax-attention layer body of this geometry."""
+    counter(
+        ATTENTION_LAYER_LOWERINGS_TOTAL,
+        "softmax-attention layer bodies traced, by their geometry",
+        ("kind", "heads", "rope"),
+    ).inc(1.0, {"kind": kind, "heads": str(int(heads)), "rope": rope})
+
+
+def attention_layer_lowerings() -> Dict[str, float]:
+    """``{"<kind>/<heads>/<rope>": traced layer bodies}`` since the
+    process began."""
+    m = get_metric(ATTENTION_LAYER_LOWERINGS_TOTAL)
+    if m is None:
+        return {}
+    out = {}
+    for tags, v in m.series():
+        t = dict(tags)
+        out["/".join(t.get(k, "") for k in ("kind", "heads", "rope"))] = v
+    return out
 
 
 def inc_attention_fragment_lowering(path: str) -> None:

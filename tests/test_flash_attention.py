@@ -136,7 +136,7 @@ def _text_and_kernel(rows, kv, window, dtype, block_k):
     def text(q, k, v, kc, vc):
         return SequenceLM._cached_attention(
             stub, q, k, v, (kc, vc), rows, q.shape[-1] ** -0.5, window=window,
-            scope="swa" if window else None)[0]
+            scope="swa" if window else "attn")[0]
 
     def kernel(q, k, v, kc, vc):
         b, t, h, d = q.shape
@@ -170,6 +170,17 @@ _FRAGMENT_CASES = {
     "head_64_group_4_ring": dict(
         d=64, kv=2, group=4, window=24, depth=24, pos0=(3, 100, 24), block_k=8),
     "bfloat16": dict(dtype=jnp.bfloat16, group=7),
+    # (iv) the mixed-geometry cell's two layers at its fragment of 256
+    # and the rule's own key blocks of 512: six query heads a key head
+    # over a cache of 4,096 (streams 700 and 3,840 deep: six and no
+    # stored blocks skipped), eight over a ring of 512 that is one key
+    # block, narrower than two fragments, not yet turned and turned
+    "group_6_cache_4096": dict(
+        b=2, t=256, kv=1, group=6, depth=4096, pos0=(700, 3840),
+        resets=((), (100,)), block_k=None),
+    "group_8_ring_512": dict(
+        b=2, t=256, kv=1, group=8, window=512, depth=512, pos0=(300, 3840),
+        resets=((), (100,)), block_k=None),
 }
 
 
@@ -223,6 +234,8 @@ def test_fragment_rule_blocks_and_pairs():
     # off a TPU the rule says XLA, whatever the shape
     assert not fa.fragment_kernel_applies(256, 28, 4, 128, 8192, jnp.bfloat16)
     assert fa.fragment_block_k(8192) == 512
+    # the mixed-geometry cell's layers: eight stored blocks, and one
+    assert fa.fragment_block_k(4096) == fa.fragment_block_k(512) == 512
     assert fa.fragment_block_k(2048 + 256) == 256
     assert fa.fragment_block_k(24) == 0
     # 16 stored blocks of 512 and the own: a stream at 0 skips all 16, one
@@ -246,3 +259,26 @@ def test_fragment_rule_blocks_and_pairs():
     got = fa.fragment_pairs_seen(
         rows["pos0"], rows["seg"], rows["positions"], 24, 24)
     assert float(got) == float(want)
+
+
+@pytest.mark.parametrize("tokens,heads,kv,head,depth", [
+    (256, 28, 4, 128, 8192),   # SmallThinker's full layer
+    (256, 28, 4, 128, 4096),   # its rings
+    (128, 16, 2, 256, 2048),   # Qwen3-Next's gated layer
+    (256, 32, 8, 64, 2048),    # Granite's
+    (256, 48, 8, 128, 4096),   # Laguna's full layers: 6 x 256 query rows a key head
+    (256, 64, 8, 128, 512),    # its rings: 8 x 256, one key block
+])
+def test_fragment_rule_admits_the_cells_layers_on_a_tpu(
+        monkeypatch, tokens, heads, kv, head, depth):
+    """What the rule says where the backend is a TPU, from the shapes
+    alone: every sequence cell's attention layer takes the kernel, in
+    bfloat16 only, and a fragment of 4,096 tokens does not."""
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert fa.fragment_kernel_applies(tokens, heads, kv, head, depth, jnp.bfloat16)
+    assert not fa.fragment_kernel_applies(tokens, heads, kv, head, depth, jnp.float32)
+    assert not fa.fragment_kernel_applies(4096, heads, kv, head, depth, jnp.bfloat16)
+    assert not fa.fragment_kernel_applies(
+        tokens, heads, kv, head, depth + 24, jnp.bfloat16)

@@ -369,12 +369,16 @@ def test_v5e_ssd_step_kernel_compiles_in_place(v5e_mesh):
 
 
 # a group of the learn form's streams, tokens, key heads, query heads a
-# key head, head, cache depth, window: the three sequence cells' layers
+# key head, head, cache depth, window: the four sequence cells' layers
 FRAGMENT_LAYERS = {
     "smallthinker_full": (16, 256, 4, 7, 128, 8192, None),
     "smallthinker_ring": (16, 256, 4, 7, 128, 4096, 4096),
     "qwen3next": (16, 128, 2, 8, 256, 2048, None),
     "granite4h": (16, 256, 8, 4, 64, 2048, None),
+    # the mixed-geometry cell: six query heads a key head over the
+    # episode's rows, eight over a ring of one key block
+    "laguna_full": (16, 256, 8, 6, 128, 4096, None),
+    "laguna_ring": (16, 256, 8, 8, 128, 512, 512),
 }
 
 

@@ -251,7 +251,7 @@ def test_expert_shares_add_up_to_the_uncut_layer():
             sl = slice(first, first + 2)
             share = {**p, **{k: p[k][sl] for k in
                              ("experts_gate", "experts_up", "experts_down")}}
-            part, (per_expert, absent, _), _ = model._moe(share, x, {"scope": ""})
+            part, (per_expert, absent, *_), _ = model._moe(share, x, {"scope": ""})
             total = total + (part - shared_only)
             assert float(per_expert.sum() + absent) == 2 * T * 3
     np.testing.assert_allclose(total, whole, atol=2e-5)

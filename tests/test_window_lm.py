@@ -19,6 +19,7 @@ differ by summation order only: 3e-4 on logits and values of order one,
 controls read 30 times that and more, and tests hold them to failing.
 """
 
+import dataclasses
 import importlib.util
 import os
 
@@ -267,30 +268,24 @@ def test_the_ring_equals_a_full_depth_cache_under_the_same_mask(setup):
             np.testing.assert_allclose(leaf[s], full[s][held[s]], atol=1e-6)
 
 
-class _RopeOnTheFullLayer(SequenceLM):
-    def _plain_attn(self, p, x, state, ctx):
-        b, t, _ = x.shape
-        h, hkv, d = self.heads, self.kv_heads, self.head_dim
-        q = self._dot(x, p["q_proj"]).reshape(b, t, h, d)
-        k = self._dot(x, p["k_proj"]).reshape(b, t, hkv, d)
-        v = self._dot(x, p["v_proj"]).reshape(b, t, hkv, d)
-        q = sequence_lm._rope(q, ctx["positions"], d, self.theta)
-        k = sequence_lm._rope(k, ctx["positions"], d, self.theta)
-        o, new = self._cached_attention(q, k, v, state, ctx, self.attn_scale)
-        return self._dot(o.reshape(b, t, h * d), p["o_proj"]), new
+def _with_layers(model, **changed):
+    """``model`` with the named fields of its attention layers'
+    descriptions replaced, where ``only`` (a kind) says which."""
+    only = changed.pop("only")
+    model.attention = {
+        name: dataclasses.replace(a, **changed) if a.kind == only else a
+        for name, a in model.attention.items()}
+    return model
 
 
 def _window_plus_one(config):
-    model = _model(config)
-    model.window = WINDOW + 1  # the ring keeps its 8 slots
-    return model
+    # the ring keeps its 8 slots
+    return _with_layers(_model(config), only="sliding_attention", window=WINDOW + 1)
 
 
 def _rope_on_the_full_layer(config):
-    model = _RopeOnTheFullLayer(
-        VOCAB, config["algo_config"]["model"]["sequence_lm"], dtype="float32")
-    model.learn_streams = 2
-    return model
+    return _with_layers(
+        _model(config), only="attention", rotary=8, theta=10000.0)
 
 
 def _router_on_the_normed_stream(config):
@@ -485,7 +480,7 @@ def test_window_statistic_and_lowering_counter(setup):
             p += 1
     assert abs(float(stats["window_rows_seen_mean"]) - np.mean(seen)) < 1e-5
     assert sorted(stats) == [
-        "attn_key_blocks_skipped_share",
+        "attn_key_blocks_skipped_share", "moe_decode_held_experts_touched_share",
         "moe_max_tokens_per_held_expert", "moe_rows_computed_share",
         "moe_slots_on_absent_experts", "moe_tokens_per_held_expert",
         "window_rows_seen_mean"]
