@@ -1,7 +1,7 @@
 """The device operations of a sequence cell's decode step, by name.
 
     python3 -m perf.run --workload <cell> --seed <n> --seconds 30 --trace 1
-    PYTHONPATH=. python benchmarks/decode_step_ops.py .perf_trace <steps> [top]
+    PYTHONPATH=. python benchmarks/decode_step_ops.py .perf_trace <steps> [top [scope]]
 
 Reads the ``.xplane.pb`` a traced run leaves and prints one line a leaf
 operation under the lane's ``rollout/act`` scope inside the annotated
@@ -12,7 +12,9 @@ operation's name and the tail of its ``tf_op`` path (the
 scope says what a fusion named by its output shape computes: PR 34 read
 ``multiply_reduce_fusion f32[16]`` as the state's read; its path ends
 ``mlp/dot_general``). The traced result line's ``breakdown`` holds the
-ten largest operations of the whole program only.
+ten largest operations of the whole program only. With ``scope`` the
+same table of another scope's operations (``learn/mla`` with ``steps``
+20: a latent layer's update of one group of streams, by operation).
 """
 
 from __future__ import annotations
@@ -30,6 +32,7 @@ def main(argv) -> int:
         sys.exit(__doc__)
     path, steps = argv[1], int(argv[2])
     top = int(argv[3]) if len(argv) > 3 else 40
+    scope = argv[4] if len(argv) > 4 else "rollout/act"
     if os.path.isdir(path):
         path = tr.newest_xplane(path)
     bounds = tr.annotation_bounds(tr.load_xplane(path), tr.TRAIN_ANNOTATION)
@@ -37,12 +40,12 @@ def main(argv) -> int:
     for op, duration_ns in program_trace._leaf_ops(
             program_trace.load_op_scopes(path), bounds):
         tf_op, _, _, name = op
-        if "rollout/act" not in tf_op:
+        if scope not in tf_op:
             continue
-        key = name.split(" ", 1)[-1] + " | " + tf_op.split("rollout/act/", 1)[-1][-60:]
+        key = name.split(" ", 1)[-1] + " | " + tf_op.split(scope + "/", 1)[-1][-60:]
         total[key] += duration_ns / 1e3
         count[key] += 1
-    print(f"rollout/act: {sum(total.values()) / steps:.1f} us a step in "
+    print(f"{scope}: {sum(total.values()) / steps:.1f} us a step in "
           f"{sum(count.values()) / steps:.0f} operations")
     for key, us in total.most_common(top):
         print(f"{us / steps:9.2f} us  x{count[key] / steps:5.1f}  {key}")

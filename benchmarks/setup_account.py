@@ -97,7 +97,8 @@ def run(out_path: str, argv) -> int:
         name: getattr(metrics, name)()
         for name in ("attention_fragment_lowerings", "attention_layer_lowerings",
                      "window_cache_lowerings", "moe_product_lowerings",
-                     "deltanet_step_lowerings", "ssm_step_lowerings")
+                     "deltanet_step_lowerings", "ssm_step_lowerings",
+                     "mla_decode_lowerings")
         if hasattr(metrics, name)  # an older tree lacks the newest
     }
     with open(out_path, "a") as f:

@@ -964,19 +964,25 @@ class SequenceLM:
             # rows inside the window a query of a window layer saw
             stats_out["window_rows_seen_mean"] = sum(rows_seen) / (
                 b * t * len(rows_seen))
-        if stats_out is not None and t > 1 and self.attention:
+        if stats_out is not None and t > 1 and (
+                self.attention or LATENT in self.layer_types):
             # of the key blocks the fragment kernel walks (a stream's
             # stored blocks and its own), those it skips: the stored ones
             # at or past the start position (0 where the XLA text runs,
-            # which multiplies every slot)
+            # which multiplies every slot); a latent layer's rows are one
+            # key head for all its query heads
             skipped, walked = 0.0, 0
-            for n, (name, _, _, _) in enumerate(self.segments):
-                if name not in self.attention:
+            for n, (name, kind, _, _) in enumerate(self.segments):
+                if name in self.attention:
+                    a = self.attention[name]
+                    geometry = a.heads, a.kv_heads, a.head_dim
+                elif kind == LATENT:
+                    geometry = self.heads, 1, self.latent_row
+                else:
                     continue
-                a = self.attention[name]
                 depth = self._segment_state(state, n)[0].shape[1]
                 if flash_attention.fragment_kernel_applies(
-                        t, a.heads, a.kv_heads, a.head_dim, depth, self.dtype):
+                        t, *geometry, depth, self.dtype):
                     more, blocks = flash_attention.fragment_key_blocks(pos0, depth)
                     skipped, walked = skipped + more, walked + blocks
             stats_out["attn_key_blocks_skipped_share"] = skipped / max(walked, 1)
@@ -1377,14 +1383,26 @@ class SequenceLM:
             slot = jnp.where(seg == seg[:, -1:], positions, self.positions)
             new_cache = cache.at[jnp.arange(b)[:, None], slot].set(
                 rows_new, mode="drop")
-            metrics.inc_mla_decode_lowering("absorbed" if t == 1 else "expanded")
             if t == 1:
                 # reads what the cache holds, its own row included
+                metrics.inc_mla_decode_lowering("absorbed")
                 o = latent_attention.absorbed_step(
                     q_nope[:, 0], q_pe[:, 0], new_cache, p["kv_b"], pos0,
                     self.softmax_scale, self.dtype,
                 )[:, None]
+            elif flash_attention.fragment_kernel_applies(
+                    t, h, 1, self.latent_row, cache.shape[1], self.dtype):
+                # the same absorbed product on the tiled kernel: one key
+                # head, the latent rows as they lie
+                metrics.inc_mla_decode_lowering("absorbed_fragment")
+                metrics.inc_attention_fragment_lowering("kernel")
+                o = latent_attention.absorbed_fragment(
+                    q_nope, q_pe, rows_new, cache, p["kv_b"], seg, positions,
+                    pos0, self.softmax_scale, self.dtype,
+                )
             else:
+                metrics.inc_mla_decode_lowering("expanded")
+                metrics.inc_attention_fragment_lowering("xla")
                 o = latent_attention.expanded_fragment(
                     q_nope, q_pe, rows_new, cache, p["kv_b"], seg, pos0,
                     self.softmax_scale, self.dtype, block=_LATENT_ENV_BLOCK,

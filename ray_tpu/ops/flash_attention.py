@@ -298,7 +298,10 @@ def flash_attention(
 # every stream over the rows its cache holds and the fragment's own
 # keys. One grid step is one stream, one key head and one block of keys;
 # the ``group`` query heads that share the key head are rows of the one
-# query tile, so a key block crosses HBM once a key head. The masks are
+# query tile, so a key block crosses HBM once a key head; where the
+# backward pass cannot hold them all (the latent rows' one key head for
+# 32 query heads of 576 lanes), a further grid axis walks tiles of query
+# heads, each over the stream's key blocks. The masks are
 # built in the kernel from the stream's start position (a scalar-prefetch
 # operand, which also keeps the blocks past it off the grid's work) and
 # the queries' episode numbers and positions.
@@ -333,29 +336,43 @@ def _heads_packed(head_dim: int, kv_heads: int) -> int:
     return pack if kv_heads % pack == 0 else 1
 
 
+def fragment_head_tile(tokens, heads, kv_heads, head_dim) -> int:
+    """Query heads of one block of keys in a query tile: the most (a
+    divisor of the group) that the backward pass can hold in VMEM, 0
+    where not even one fits. Per row and lane ``q`` and ``dq`` in
+    bfloat16 and ``o`` and ``do`` in float32, each twice for the
+    pipeline, and the float32 ``dq`` accumulator (28 bytes), and three
+    one-lane statistics that occupy whole 128-lane rows; beside the
+    rows a head's float32 tiles of scores, weights and their gradient
+    over the widest key block (the stored 512, or the fragment's own)."""
+    pack = _heads_packed(head_dim, kv_heads)
+    group = pack * (heads // kv_heads)
+    row = 28 * _ceil_to(head_dim * pack, _LANES) + 12 * _LANES
+    room = _FRAGMENT_VMEM_BYTES // 2 - 12 * tokens * max(tokens, _FRAGMENT_BLOCK_K)
+    return next((tile for tile in range(group, 0, -1)
+                 if group % tile == 0 and tile * tokens * row <= room), 0)
+
+
 def fragment_kernel_applies(
         tokens, heads, kv_heads, head_dim, depth, dtype) -> bool:
     """The fragment kernel's lowering exists on a TPU (the process's
     default backend, as in ``ops/deltanet.py``) for bfloat16 operands,
     a fragment of whole 128-lane tiles of tokens (the own keys' episode
     numbers lie along the lanes, and a tile of weights is turned for the
-    own keys' gradients), a cache of whole key blocks, a head that is
-    whole lane tiles or packs into one (64: two key heads a block), and
-    a query tile (every token of the key head's query heads) that the
-    backward pass can hold in VMEM: per row and lane ``q`` and ``dq`` in
-    bfloat16 and ``o`` and ``do`` in float32, each twice for the
-    pipeline, and the float32 ``dq`` accumulator (28 bytes), and three
-    one-lane statistics that occupy whole 128-lane rows."""
+    own keys' gradients), a cache of whole key blocks, a key that is
+    whole lane tiles, packs into one (64: two key heads a block) or is
+    the one key head's (its block is the cache's whole minor dimension:
+    the latent row of 576, whole half tiles), and a query tile of at
+    least one head (:func:`fragment_head_tile`)."""
     pack = _heads_packed(head_dim, kv_heads)
-    rows = pack * (heads // kv_heads) * tokens
     return (
         jax.default_backend() == "tpu"
         and dtype == jnp.bfloat16
         and tokens % _LANES == 0
         and fragment_block_k(depth) > 0
-        and head_dim * pack % _LANES == 0
-        and rows * (28 * head_dim * pack + 12 * _LANES)
-        <= _FRAGMENT_VMEM_BYTES // 2
+        and (head_dim * pack % _LANES == 0
+             or kv_heads == 1 and head_dim % (_LANES // 2) == 0)
+        and fragment_head_tile(tokens, heads, kv_heads, head_dim) > 0
     )
 
 
@@ -428,13 +445,14 @@ def _own_mask(seg_q, seg_k, window):
 def _fragment_fwd_kernel(
     pos0_ref, q_ref, kc_ref, vc_ref, k_ref, v_ref, seg_q_ref, pos_q_ref,
     seg_k_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-    window, depth, block_k,
+    window, depth, block_k, tiles,
 ):
-    """One stream, one key head, one block of keys: the stored blocks in
-    turn, then the fragment's own. ``q_ref`` ``(1, 1, group, T, D)``;
-    the running max, sum and accumulator of every query row live in
-    scratch across the key blocks."""
-    b, kb = pl.program_id(0), pl.program_id(2)
+    """One stream, one key head, one tile of its query heads, one block
+    of keys: the stored blocks in turn, then the fragment's own.
+    ``q_ref`` ``(1, 1, heads of the tile, T, D)``; the running max, sum
+    and accumulator of every query row live in scratch across the key
+    blocks."""
+    b, kb = pl.program_id(0), pl.program_id(2 + (tiles > 1))
     stored = depth // block_k
     pos0 = pos0_ref[b]
     group = q_ref.shape[2]
@@ -481,12 +499,15 @@ def _fragment_fwd_kernel(
 def _fragment_bwd_kernel(
     pos0_ref, q_ref, kc_ref, vc_ref, k_ref, v_ref, seg_q_ref, pos_q_ref,
     seg_k_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref, dq_acc,
-    delta_ref, *, window, depth, block_k,
+    delta_ref, *own_acc, window, depth, block_k, tiles,
 ):
     """The same walk; every score tile is computed again from the row
     statistics. ``dq`` gathers over all key blocks, the own keys' ``dk``
-    and ``dv`` are made in the last step; a stored row gets nothing."""
-    b, kb = pl.program_id(0), pl.program_id(2)
+    and ``dv`` are made in the last step (summed in the float32
+    ``own_acc`` where the key head's query heads come in several
+    tiles); a stored row gets nothing."""
+    b, kb = pl.program_id(0), pl.program_id(2 + (tiles > 1))
+    tile = pl.program_id(2) if tiles > 1 else 0
     stored = depth // block_k
     pos0 = pos0_ref[b]
     group = q_ref.shape[2]
@@ -531,21 +552,41 @@ def _fragment_bwd_kernel(
         dk, dv = fold(
             k_ref[0], v_ref[0],
             _own_mask(seg_q_ref[0], seg_k_ref[0], window), True)
-        dk_ref[0] = dk.astype(dk_ref.dtype)
-        dv_ref[0] = dv.astype(dv_ref.dtype)
+        if tiles > 1:
+            dk_acc, dv_acc = own_acc
+
+            @pl.when(tile == 0)
+            def _():
+                dk_acc[...] = dk
+                dv_acc[...] = dv
+
+            @pl.when(tile > 0)
+            def _():
+                dk_acc[...] += dk
+                dv_acc[...] += dv
+
+            # the own keys' block stays where it is until the last tile
+            @pl.when(tile == tiles - 1)
+            def _():
+                dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+                dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        else:
+            dk_ref[0] = dk.astype(dk_ref.dtype)
+            dv_ref[0] = dv.astype(dv_ref.dtype)
         for g in range(group):
             dq_ref[0, 0, g] = dq_acc[g].astype(dq_ref.dtype)
 
 
 def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
-                   interpret, name):
+                   tile, interpret, name):
     """One pass over the grid ``(streams, key heads, stored blocks +
-    1)``. ``operands``: ``q`` ``(B, kv, group, T, D)``, the own ``k``,
-    ``v`` ``(B, T, kv * D)``, the caches ``(B, depth, kv * D)``,
-    ``pos0`` ``(B,)``, ``seg``, ``positions`` ``(B, T)``; ``rows``:
-    further operands blocked like ``q``; ``outs``: ``(shape, dtype)`` of
-    each result, blocked like ``q`` at five axes and like the own keys
-    at three."""
+    1)``, with an axis of ``group / tile`` query tiles before the blocks
+    where a tile holds fewer query heads than the group. ``operands``:
+    ``q`` ``(B, kv, group, T, D)``, the own ``k``, ``v`` ``(B, T, kv *
+    D)``, the caches ``(B, depth, kv * D)``, ``pos0`` ``(B,)``, ``seg``,
+    ``positions`` ``(B, T)``; ``rows``: further operands blocked like
+    ``q``; ``outs``: ``(shape, dtype)`` of each result, blocked like
+    ``q`` at five axes and like the own keys at three."""
     from ray_tpu import sharding as sharding_lib
 
     q, k, v, k_cache, v_cache, pos0, seg, positions = operands
@@ -553,35 +594,51 @@ def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
     dv = v.shape[-1] // kv
     depth = k_cache.shape[1]
     stored = depth // block_k
+    tiles = group // tile
+
+    def step(ids):  # (stream, key head, query tile, key block, pos0) of a step
+        b, n, *rest, kb, pos0 = ids
+        return b, n, rest[0] if rest else 0, kb, pos0
 
     def cached(width):
         # past the last block a stream holds the index stays where it
         # is, so nothing is fetched for the steps that are skipped
-        def index(b, n, kb, pos0):
+        def index(*ids):
+            b, n, _, kb, pos0 = step(ids)
             last = jnp.maximum(_blocks_held(pos0[b], block_k, stored) - 1, 0)
             return b, jnp.minimum(kb, last), n
         return pl.BlockSpec((1, block_k, width), index)
 
-    tile = lambda shape: pl.BlockSpec(
-        (1, 1) + tuple(shape[2:]), lambda b, n, kb, pos0: (b, n, 0, 0, 0))
-    own = lambda width: pl.BlockSpec(
-        (1, t, width), lambda b, n, kb, pos0: (b, 0, n))
-    per_stream = lambda *shape: pl.BlockSpec(
-        (1,) + shape, lambda b, n, kb, pos0: (b, 0, 0))
+    def heads(shape):
+        def index(*ids):
+            b, n, part, _, _ = step(ids)
+            return b, n, part, 0, 0
+        return pl.BlockSpec((1, 1, tile) + tuple(shape[3:]), index)
+
+    def own(width):
+        def index(*ids):
+            b, n, _, _, _ = step(ids)
+            return b, 0, n
+        return pl.BlockSpec((1, t, width), index)
+
+    def per_stream(*shape):
+        return pl.BlockSpec((1,) + shape, lambda *ids: (ids[0], 0, 0))
+
     # inside a ``shard_map`` the results vary over the axes the operands do
     vma = sharding_lib.vma_of(operands)
     return pl.pallas_call(
-        functools.partial(kernel, window=window, depth=depth, block_k=block_k),
+        functools.partial(
+            kernel, window=window, depth=depth, block_k=block_k, tiles=tiles),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bsz, kv, stored + 1),
+            grid=(bsz, kv) + (tiles,) * (tiles > 1) + (stored + 1,),
             in_specs=[
-                tile(q.shape), cached(d), cached(dv), own(d), own(dv),
+                heads(q.shape), cached(d), cached(dv), own(d), own(dv),
                 per_stream(t, 1), per_stream(t, 1), per_stream(1, t),
-                *(tile(r.shape) for r in rows),
+                *(heads(r.shape) for r in rows),
             ],
             out_specs=[
-                tile(shape) if len(shape) == 5 else own(shape[-1] // kv)
+                heads(shape) if len(shape) == 5 else own(shape[-1] // kv)
                 for shape, _ in outs
             ],
             scratch_shapes=scratch,
@@ -591,7 +648,9 @@ def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
         ],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # the own keys' gradients are summed over the query tiles
+            dimension_semantics=("parallel", "parallel")
+            + ("arbitrary",) * (1 + (tiles > 1)),
             vmem_limit_bytes=_FRAGMENT_VMEM_BYTES,
         ),
         name=name,
@@ -599,47 +658,63 @@ def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
       seg[:, :, None], positions[:, :, None], seg[:, None, :], *rows)
 
 
-def _fragment_fwd(operands, window, block_k, interpret):
+# A ``jit`` of their own, so that a program with many call sites (five
+# layers, the forward pass, its recomputation and the backward pass, the
+# standalone learn program and the fused one) traces and lowers the
+# kernels once a shape.
+_STATIC = ("window", "block_k", "tile", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _fragment_fwd(operands, *, window, block_k, tile, interpret):
     q, _, v = operands[:3]
     bsz, kv, group, t, _ = q.shape
     dv = v.shape[-1] // kv
-    stat = lambda: pltpu.VMEM((group, t, 1), jnp.float32)
+    stat = lambda: pltpu.VMEM((tile, t, 1), jnp.float32)
     return _fragment_call(
         _fragment_fwd_kernel, operands, (),
         [((bsz, kv, group, t, dv), jnp.float32),
          ((bsz, kv, group, t, 1), jnp.float32)],
-        [stat(), stat(), pltpu.VMEM((group, t, dv), jnp.float32)],
-        window=window, block_k=block_k, interpret=interpret,
+        [stat(), stat(), pltpu.VMEM((tile, t, dv), jnp.float32)],
+        window=window, block_k=block_k, tile=tile, interpret=interpret,
         name="fragment_attention_fwd",
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _fragment_bwd(operands, o, do, lse, *, window, block_k, tile, interpret):
+    q, k, v = operands[:3]
+    kv, group, t, d = q.shape[1:]
+    own_acc = [pltpu.VMEM((t, a.shape[-1] // kv), jnp.float32) for a in (k, v)]
+    return _fragment_call(
+        _fragment_bwd_kernel, operands, (o, do, lse),
+        [(q.shape, q.dtype), (k.shape, k.dtype), (v.shape, v.dtype)],
+        [pltpu.VMEM((tile, t, d), jnp.float32),
+         pltpu.VMEM((tile, t, 1), jnp.float32)] + own_acc * (tile < group),
+        window=window, block_k=block_k, tile=tile, interpret=interpret,
+        name="fragment_attention_bwd",
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
 def _fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions,
-                        window, block_k, interpret):
+                        window, block_k, tile, interpret):
     return _fragment_fwd(
         (q, k, v, k_cache, v_cache, pos0, seg, positions),
-        window, block_k, interpret)[0]
+        window=window, block_k=block_k, tile=tile, interpret=interpret)[0]
 
 
 def _fragment_fwd_rule(*args):
-    operands, static = args[:8], args[8:]
-    o, lse = _fragment_fwd(operands, *static)
+    operands, static = args[:8], dict(zip(_STATIC, args[8:]))
+    o, lse = _fragment_fwd(operands, **static)
     return o, (operands, o, lse)
 
 
-def _fragment_bwd_rule(window, block_k, interpret, residuals, do):
+def _fragment_bwd_rule(window, block_k, tile, interpret, residuals, do):
     operands, o, lse = residuals
-    q, k, v = operands[:3]
-    group, t, d = q.shape[2:]
-    dq, dk, dv = _fragment_call(
-        _fragment_bwd_kernel, operands, (o, do, lse),
-        [(q.shape, q.dtype), (k.shape, k.dtype), (v.shape, v.dtype)],
-        [pltpu.VMEM((group, t, d), jnp.float32),
-         pltpu.VMEM((group, t, 1), jnp.float32)],
-        window=window, block_k=block_k, interpret=interpret,
-        name="fragment_attention_bwd",
-    )
+    dq, dk, dv = _fragment_bwd(
+        operands, o, do, lse,
+        window=window, block_k=block_k, tile=tile, interpret=interpret)
     # the stored rows are the rollout's, handed over as data: no
     # gradient (``None`` is a zero cotangent), nor for the integers
     return (dq, dk, dv) + (None,) * 5
@@ -649,7 +724,8 @@ _fragment_attention.defvjp(_fragment_fwd_rule, _fragment_bwd_rule)
 
 
 def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
-                       window=None, block_k=None, interpret=False):
+                       window=None, block_k=None, head_tile=None,
+                       interpret=False):
     """A fragment's causal attention over its streams' stored keys and
     values and its own, as one tiled kernel with an online softmax in
     both directions: no ``(T, rows)`` matrix of scores or weights
@@ -660,7 +736,10 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
     type; ``k``, ``v`` ``(B, T, kv, D)`` the fragment's own (``v`` may
     be of another width than ``k``); ``k_cache``, ``v_cache`` ``(B,
     depth, kv * D)`` as the carry holds them BEFORE the fragment's
-    scatter; ``pos0`` ``(B,)`` the streams' start positions; ``seg``,
+    scatter (where ONE key head's value is its key's leading ``Dv``
+    lanes, as in a latent row, the key cache itself is the value cache:
+    the value's block is those lanes of it, and no sliced copy is
+    made); ``pos0`` ``(B,)`` the streams' start positions; ``seg``,
     ``positions`` ``(B, T)`` each query's episode number inside the
     fragment and position. Returns ``o`` ``(B, T, kv, group, Dv)``
     float32. Scores, masks, running max and sum and the accumulators
@@ -682,7 +761,12 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
     differentiates through them (``sharding/superstep.py``,
     ``policy/jax_policy._grouped_loss_grad`` and ``perf/checks`` take
     gradients in the parameters, with the state a column of the batch).
-    ``block_k`` and ``interpret`` are the tests' spellings."""
+
+    The query heads of a key head are one tile where the backward pass
+    can hold them (:func:`fragment_head_tile`: every softmax layer of
+    the cells), else several, each walking the stream's key blocks, with
+    the own keys' gradients summed over them. ``block_k``, ``head_tile``
+    and ``interpret`` are the tests' spellings."""
     bsz, t, kv, group, d = q.shape
     dv = v.shape[-1]
     depth = k_cache.shape[1]
@@ -690,6 +774,10 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
     if not block_k:
         raise ValueError(f"a cache of {depth} rows is not whole key blocks")
     pack = _heads_packed(d, kv) if d == dv else 1
+    tile = head_tile or fragment_head_tile(t, kv * group, kv, d)
+    if not tile or pack * group % tile:
+        raise ValueError(
+            f"no tile of the {pack * group} query heads of a key block fits")
     # a head narrower than the lanes: ``pack`` key heads a block, each
     # of their query heads zero outside its own head's lanes, so that
     # the products over the whole block are the head's own
@@ -709,4 +797,5 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
 
     return gather(_fragment_attention(
         spread(q), k.reshape(bsz, t, kv * d), v.reshape(bsz, t, kv * dv),
-        k_cache, v_cache, pos0, seg, positions, window, block_k, interpret))
+        k_cache, v_cache, pos0, seg, positions, window, block_k, tile,
+        interpret))

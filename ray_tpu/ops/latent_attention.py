@@ -1,5 +1,5 @@
 """Multi-head latent attention (DeepSeek-V2/V3, arXiv:2405.04434 and
-2412.19437) over a cache of latent rows, in its two forms.
+2412.19437) over a cache of latent rows, in its three forms.
 
 A position's cache row is ``[c_kv | k_pe]``: the RMS-normed key/value
 latent (``kv_lora_rank`` numbers) and the roped key part that all heads
@@ -13,14 +13,27 @@ key part and a ``v_head_dim`` value: the query/key product is
   and the weighted sum run against the latent rows as the cache holds
   them, and the value half is applied after (``o_h = (P_h C) W_UV_h``):
   no key or value of an earlier position is ever rebuilt.
-- :func:`expanded_fragment` is the fragment form: keys and values of
-  the stored rows and of the fragment's own are rebuilt through
-  ``W_kvb`` for a block of streams at a time, and the scores are the
-  masked ``(T, S + T)`` matrix, with ``seg`` marking episodes that open
-  inside the fragment.
+- :func:`absorbed_fragment` is the fragment form where the tiled
+  fragment kernel's lowering exists
+  (``ops/flash_attention.fragment_kernel_applies``: bfloat16 on a TPU):
+  the same absorbed product for ``T`` tokens a stream, the 32 heads'
+  absorbed queries against ONE 576-wide key head that is the latent
+  rows as they lie (the stored cache and the fragment's own) with the
+  value their leading ``kv_lora_rank`` lanes. No stored key is rebuilt,
+  no score matrix is written, stored blocks past a stream's depth are
+  skipped, and ``W_kvb`` gets its gradient through the two absorbed
+  halves.
+- :func:`expanded_fragment` is the fragment form everywhere else (the
+  CPU, float32) and the kernel's test oracle: keys and values of the
+  stored rows and of the fragment's own are rebuilt through ``W_kvb``
+  for a block of streams at a time, and the scores are the masked
+  ``(T, S + T)`` matrix, with ``seg`` marking episodes that open inside
+  the fragment.
 
-Both take ``dtype`` operands (the cache's) and accumulate in float32;
-masks and softmax are float32. :func:`yarn_inv_freq` and
+``ray_tpu_mla_decode_lowerings_total{form}`` counts which a traced layer
+took (``absorbed`` | ``absorbed_fragment`` | ``expanded``). All take
+``dtype`` operands (the cache's) and accumulate in float32; masks and
+softmax are float32. :func:`yarn_inv_freq` and
 :func:`yarn_softmax_scale` are YaRN (arXiv:2309.00071) as DeepSeek-V3's
 ``modeling_deepseek.py`` applies it: the inverse frequencies blend
 ``theta^(-2i/d)`` and that over ``factor`` by a linear ramp between the
@@ -115,6 +128,42 @@ def absorbed_step(q_nope, q_pe, cache, kv_b, pos0, scale: float, dtype):
         )[..., :latent]
         return jnp.einsum(
             "bhc,chv->bhv", mixed.astype(dtype), w[..., dn:],
+            preferred_element_type=jnp.float32,
+        )
+
+
+def absorbed_fragment(q_nope, q_pe, rows_new, cache, kv_b, seg, positions,
+                      pos0, scale: float, dtype, **kernel):
+    """A fragment against the latent rows as they lie, on the tiled
+    fragment kernel. Operands as :func:`expanded_fragment`'s, and
+    ``positions`` ``(B, T)``; ``cache`` as the carry holds it BEFORE the
+    fragment's scatter, and it gets no gradient (the kernel's contract).
+    Returns ``(B, T, H, dv)`` float32. Its three parts open the scopes
+    ``absorb``, ``scores`` and ``out`` under the caller's, as
+    :func:`absorbed_step`'s do. ``kernel``: the tests' spellings of
+    ``fragment_attention`` (``block_k``, ``head_tile``, ``interpret``)."""
+    from ray_tpu.ops import flash_attention
+
+    heads, dn = q_nope.shape[2:]
+    latent = kv_b.shape[0]
+    w = _kv_b_by_head(kv_b, heads, dtype)
+    with jax.named_scope("absorb"):
+        q_lat = jnp.einsum(
+            "bthd,chd->bthc", q_nope.astype(dtype), w[..., :dn],
+            preferred_element_type=jnp.float32,
+        )
+        q_row = (jnp.concatenate([q_lat, q_pe], axis=-1) * scale).astype(dtype)
+    with jax.named_scope("scores"):
+        # one key head for all the query heads; the value is the row's
+        # leading lanes, read out of the cache's own blocks
+        mixed = flash_attention.fragment_attention(
+            q_row[:, :, None], rows_new[:, :, None],
+            rows_new[:, :, None, :latent], cache, cache, pos0, seg, positions,
+            **kernel,
+        )[:, :, 0]
+    with jax.named_scope("out"):
+        return jnp.einsum(
+            "bthc,chv->bthv", mixed.astype(dtype), w[..., dn:],
             preferred_element_type=jnp.float32,
         )
 

@@ -123,8 +123,11 @@ DELTANET_STEP_LOWERINGS_TOTAL = "ray_tpu_deltanet_step_lowerings_total"
 MOE_PRODUCT_LOWERINGS_TOTAL = "ray_tpu_moe_product_lowerings_total"
 # which form each traced latent-attention layer took
 # (models/sequence_lm.py, ops/latent_attention.py): form = absorbed
-# (one token against the latent rows: the rollout's step) | expanded
-# (a fragment, keys and values rebuilt through W_kvb: the learn form).
+# (one token against the latent rows: the rollout's step) |
+# absorbed_fragment (a fragment against the latent rows on the tiled
+# fragment kernel: the learn form where ops/flash_attention's rule
+# admits it, bfloat16 on a TPU) | expanded (a fragment, keys and values
+# rebuilt through W_kvb: the learn form everywhere else).
 # Counted when the form is traced: once per latent layer body of a
 # program (layers whose checkpointed block is the same trace once)
 MLA_DECODE_LOWERINGS_TOTAL = "ray_tpu_mla_decode_lowerings_total"
@@ -146,9 +149,10 @@ SSM_STEP_LOWERINGS_TOTAL = "ray_tpu_ssm_step_lowerings_total"
 WINDOW_CACHE_LOWERINGS_TOTAL = "ray_tpu_window_cache_lowerings_total"
 # which lowering each traced attention layer's fragment form took
 # (models/sequence_lm._cached_attention, every softmax attention kind
-# over a stored cache): path = kernel (ops/flash_attention's tiled
-# fragment kernel, forward and backward: a TPU backend, bfloat16,
-# fragments and caches of whole blocks) | xla (the score matrices a
+# over a stored cache, and _latent_attn over its latent rows): path =
+# kernel (ops/flash_attention's tiled fragment kernel, forward and
+# backward: a TPU backend, bfloat16, fragments and caches of whole
+# blocks) | xla (the score matrices a
 # block of streams at a time, everywhere else). Counted when the form
 # is traced: once per attention layer body of a program
 ATTENTION_FRAGMENT_LOWERINGS_TOTAL = (
@@ -622,7 +626,7 @@ def moe_product_lowerings() -> Dict[str, float]:
 
 def inc_mla_decode_lowering(form: str) -> None:
     """One traced latent-attention layer took ``form`` (``absorbed`` |
-    ``expanded``)."""
+    ``absorbed_fragment`` | ``expanded``)."""
     counter(
         MLA_DECODE_LOWERINGS_TOTAL,
         "latent-attention layers traced, by the form they took",

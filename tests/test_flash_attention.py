@@ -149,6 +149,47 @@ def _text_and_kernel(rows, kv, window, dtype, block_k):
     return text, kernel
 
 
+def _latent_fragment(b=3, t=16, heads=4, dn=16, rope=16, latent=128, dv=16,
+                     depth=32, pos0=(0, 10, 32), resets=((), (5,), ()),
+                     dtype=jnp.float32, seed=0, **_):
+    """The latent layer's fragment: ``(q_nope, q_pe, rows_new, kv_b,
+    cache)`` as ``SequenceLM._latent_attn`` hands them over (a row of
+    ``latent + rope`` lanes: not whole lane tiles, the value its leading
+    ``latent``), the lane's rows, and a cotangent."""
+    _, rows, _ = _fragment(b=b, t=t, depth=depth, pos0=pos0, resets=resets)
+    rng = np.random.default_rng(seed)
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    operands = (
+        normal(b, t, heads, dn), normal(b, t, heads, rope),
+        normal(b, t, latent + rope).astype(dtype),
+        normal(latent, heads * (dn + dv)) * latent ** -0.5,
+        normal(b, depth, latent + rope).astype(dtype))
+    return operands, rows, normal(b, t, heads, dv)
+
+
+def _latent_text_and_kernel(rows, dtype, block_k, head_tile):
+    """``(q_nope, q_pe, rows_new, kv_b, cache) -> o (B, T, heads, dv)``
+    twice: ``expanded_fragment``'s text (every key rebuilt through
+    ``W_kvb``, the masked score matrix) and the absorbed product on the
+    kernel in the interpreter."""
+    from ray_tpu.ops import latent_attention
+
+    scale = 0.1
+
+    def text(q_nope, q_pe, rows_new, kv_b, cache):
+        return latent_attention.expanded_fragment(
+            q_nope, q_pe, rows_new, cache, kv_b, rows["seg"], rows["pos0"],
+            scale, dtype, block=2)
+
+    def kernel(q_nope, q_pe, rows_new, kv_b, cache):
+        return latent_attention.absorbed_fragment(
+            q_nope, q_pe, rows_new, cache, kv_b, rows["seg"], rows["positions"],
+            rows["pos0"], scale, dtype, block_k=block_k, head_tile=head_tile,
+            interpret=True)
+
+    return text, kernel
+
+
 _FRAGMENT_CASES = {
     # (i) no window: an empty cache, one part full with an episode reset
     # inside the fragment, a full one; blocks of 16 keys, so the streams
@@ -181,38 +222,62 @@ _FRAGMENT_CASES = {
     "group_8_ring_512": dict(
         b=2, t=256, kv=1, group=8, window=512, depth=512, pos0=(300, 3840),
         resets=((), (100,)), block_k=None),
+    # (v) the latent layer: ONE key head of 144 lanes (no whole lane
+    # tiles) whose leading 128 are its value, the absorbed product
+    # against ``expanded_fragment``'s text; the group of four query heads
+    # in two tiles and in four, streams empty, part full and full, a
+    # reset inside the fragment and one at its first token
+    "latent_two_head_tiles": dict(latent=True, head_tile=2),
+    "latent_four_head_tiles_reset_at_the_first_token": dict(
+        latent=True, head_tile=1, resets=((0,), (5, 9), ())),
+    "latent_one_head_tile": dict(latent=True, head_tile=4),
+    "latent_bfloat16": dict(latent=True, head_tile=2, dtype=jnp.bfloat16),
+}
+_CONTRACT_CASES = {
+    "cache_cotangents_are_zeros": dict(),
+    "a_skipped_block_changes_nothing": dict(pos0=(0, 10, 16)),
+    "latent_cache_cotangents_are_zeros": dict(latent=True, head_tile=2),
+    "latent_a_skipped_block_changes_nothing": dict(
+        latent=True, head_tile=2, pos0=(0, 10, 16)),
 }
 
 
-@pytest.mark.parametrize("name", list(_FRAGMENT_CASES) + [
-    "cache_cotangents_are_zeros", "a_skipped_block_changes_nothing"])
+@pytest.mark.parametrize("name", list(_FRAGMENT_CASES) + list(_CONTRACT_CASES))
 def test_fragment_kernel(name):
-    case = dict(_FRAGMENT_CASES.get(name, {}))
+    case = dict({**_FRAGMENT_CASES, **_CONTRACT_CASES}[name])
     block_k = case.pop("block_k", 16)
-    operands, rows, w = _fragment(**case)
-    kv, window = case.get("kv", 2), case.get("window")
     dtype = case.get("dtype", jnp.float32)
-    text, kernel = _text_and_kernel(rows, kv, window, dtype, block_k)
+    latent = case.pop("latent", False)
+    if latent:
+        operands, rows, w = _latent_fragment(**case)
+        text, kernel = _latent_text_and_kernel(
+            rows, dtype, block_k, case["head_tile"])
+    else:
+        operands, rows, w = _fragment(**case)
+        text, kernel = _text_and_kernel(
+            rows, case.get("kv", 2), case.get("window"), dtype, block_k)
+    # the stored rows come last; everything before them is differentiated
+    learned = tuple(range(4 if latent else 3))
+    stored = tuple(range(len(learned), len(operands)))
     loss = lambda f: lambda *a: jnp.sum(f(*a) * w)
-    if name == "cache_cotangents_are_zeros":
+    if name.endswith("cache_cotangents_are_zeros"):
         # the stored rows are the rollout's: the text would hand them a
         # gradient, the kernel by its contract hands them none
-        got = jax.grad(loss(kernel), argnums=(3, 4))(*operands)
-        want = jax.grad(loss(text), argnums=(3, 4))(*operands)
+        got = jax.grad(loss(kernel), argnums=stored)(*operands)
+        want = jax.grad(loss(text), argnums=stored)(*operands)
         assert all(float(jnp.max(jnp.abs(g))) == 0.0 for g in got)
         assert all(float(jnp.max(jnp.abs(g))) > 0.1 for g in want)
         return
-    if name == "a_skipped_block_changes_nothing":
+    if name.endswith("a_skipped_block_changes_nothing"):
         # streams at 0, 10 and 16 of 32 slots: the second block of 16 is
         # skipped for all three, so a cache cut to the first block, or
         # one whose second block holds other rows, gives the same bits
-        operands, rows, w = _fragment(pos0=(0, 10, 16))
-        _, kernel = _text_and_kernel(rows, kv, window, dtype, block_k)
-        q, k, v, kc, vc = operands
-        both = jax.value_and_grad(loss(kernel), argnums=(0, 1, 2))
+        both = jax.value_and_grad(loss(kernel), argnums=learned)
         want = both(*operands)
-        cut = both(q, k, v, kc[:, :16], vc[:, :16])
-        other = both(q, k, v, kc.at[:, 16:].set(7.0), vc.at[:, 16:].set(-7.0))
+        fresh, caches = operands[:len(learned)], operands[len(learned):]
+        cut = both(*fresh, *(c[:, :16] for c in caches))
+        other = both(*fresh, *(
+            c.at[:, 16:].set(7.0 - 14.0 * n) for n, c in enumerate(caches)))
         for got in (cut, other):
             for a, b in zip(jax.tree_util.tree_leaves(got),
                             jax.tree_util.tree_leaves(want)):
@@ -222,10 +287,11 @@ def test_fragment_kernel(name):
         atol=0.15, rtol=5e-2)
     np.testing.assert_allclose(
         np.asarray(kernel(*operands)), np.asarray(text(*operands)), **tol)
-    got = jax.grad(loss(kernel), argnums=(0, 1, 2))(*operands)
-    want = jax.grad(loss(text), argnums=(0, 1, 2))(*operands)
+    got = jax.grad(loss(kernel), argnums=learned)(*operands)
+    want = jax.grad(loss(text), argnums=learned)(*operands)
     for a, b in zip(got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), **tol)
 
 
 def test_fragment_rule_blocks_and_pairs():
@@ -261,23 +327,28 @@ def test_fragment_rule_blocks_and_pairs():
     assert float(got) == float(want)
 
 
-@pytest.mark.parametrize("tokens,heads,kv,head,depth", [
-    (256, 28, 4, 128, 8192),   # SmallThinker's full layer
-    (256, 28, 4, 128, 4096),   # its rings
-    (128, 16, 2, 256, 2048),   # Qwen3-Next's gated layer
-    (256, 32, 8, 64, 2048),    # Granite's
-    (256, 48, 8, 128, 4096),   # Laguna's full layers: 6 x 256 query rows a key head
-    (256, 64, 8, 128, 512),    # its rings: 8 x 256, one key block
+@pytest.mark.parametrize("tokens,heads,kv,head,depth,tile", [
+    (256, 28, 4, 128, 8192, 7),   # SmallThinker's full layer
+    (256, 28, 4, 128, 4096, 7),   # its rings
+    (128, 16, 2, 256, 2048, 8),   # Qwen3-Next's gated layer
+    (256, 32, 8, 64, 2048, 8),    # Granite's: two key heads of four a block
+    (256, 48, 8, 128, 4096, 6),   # Laguna's full layers: 6 x 256 query rows a key head
+    (256, 64, 8, 128, 512, 8),    # its rings: 8 x 256, one key block
+    # Xing4's latent rows: one key head of 576 lanes for 32 query heads,
+    # whose 4,096 rows the backward pass cannot hold: four tiles of 8
+    (128, 32, 1, 576, 2048, 8),
 ])
 def test_fragment_rule_admits_the_cells_layers_on_a_tpu(
-        monkeypatch, tokens, heads, kv, head, depth):
+        monkeypatch, tokens, heads, kv, head, depth, tile):
     """What the rule says where the backend is a TPU, from the shapes
     alone: every sequence cell's attention layer takes the kernel, in
-    bfloat16 only, and a fragment of 4,096 tokens does not."""
+    bfloat16 only, the softmax kinds with a key block's whole group of
+    query heads in one tile, and a fragment of 4,096 tokens does not."""
     from ray_tpu.ops import flash_attention as fa
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert fa.fragment_kernel_applies(tokens, heads, kv, head, depth, jnp.bfloat16)
+    assert fa.fragment_head_tile(tokens, heads, kv, head) == tile
     assert not fa.fragment_kernel_applies(tokens, heads, kv, head, depth, jnp.float32)
     assert not fa.fragment_kernel_applies(4096, heads, kv, head, depth, jnp.bfloat16)
     assert not fa.fragment_kernel_applies(

@@ -168,6 +168,88 @@ def test_fragment_form_equals_the_chain_of_one_token_steps(setup):
             np.testing.assert_allclose(a[s, : depth[s]], b[s, : depth[s]], atol=2e-4)
 
 
+def _fragment_kernel_in_the_interpreter(monkeypatch):
+    """What a TPU's rule would say, at this file's sizes: the latent
+    layers' fragment form on the tiled kernel in the Pallas interpreter,
+    the cache of 48 rows as three key blocks of 16, the four query heads
+    of the one key head in two tiles."""
+    import functools
+
+    from ray_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "fragment_kernel_applies", lambda *a: True)
+    monkeypatch.setattr(flash_attention, "fragment_block_k", lambda depth, _=None: 16)
+    monkeypatch.setattr(flash_attention, "fragment_head_tile", lambda *a: 2)
+    monkeypatch.setattr(
+        flash_attention, "fragment_attention",
+        functools.partial(flash_attention.fragment_attention, interpret=True))
+
+
+def test_fragment_form_on_the_kernel_equals_the_text_and_the_steps(
+        setup, monkeypatch):
+    """(b') the absorbed product on the tiled kernel against the
+    expanded text it replaces on a TPU: the same logits, loss and
+    parameter gradient (``kv_b``'s leaves by themselves: they get it
+    through the two absorbed halves, the stored rows' share included),
+    for streams at three depths, an episode that ends inside a fragment
+    and one that opens at its first token; and the kernel's fragment
+    form against the chain of absorbed steps, as (b)."""
+    from ray_tpu.telemetry import metrics
+
+    config, params, model, batch = setup
+    dev = {k: jnp.asarray(v) for k, v in batch.items()}
+    rows = batch["obs"].shape[0]
+    n = rows // T
+    tokens = jnp.asarray(batch["obs"]).reshape(n, T, 1)
+    resets = jnp.asarray(batch["resets"]).reshape(n, T)
+    pos0 = np.asarray(ref.batch_state(batch)[-1])
+    assert len(set(pos0.tolist())) >= 3 and pos0.min() == 0 and pos0.max() > 16
+    assert float(resets[0, 0]) == 1.0 and float(resets[1, 1:].sum()) == 1.0
+
+    def loss(p):
+        stats = {}
+        logits, value, _ = _model_forward(model, p, batch, stats)
+        return ref.ppo_loss(logits, value, dev, config["algo_config"]), (logits, stats)
+
+    with jax.default_matmul_precision("highest"):
+        (want_loss, (want_logits, want_stats)), want = jax.value_and_grad(
+            loss, has_aux=True)(params)
+        _fragment_kernel_in_the_interpreter(monkeypatch)
+        forms, paths = metrics.mla_decode_lowerings(), dict(
+            metrics.attention_fragment_lowerings())
+        (got_loss, (got_logits, got_stats)), got = jax.value_and_grad(
+            loss, has_aux=True)(params)
+        state, chain = _f32_state(ref.batch_state(batch)), []
+        for i in range(T):
+            lg, _, state = model.apply(
+                params, tokens[:, i : i + 1], state, resets=resets[:, i : i + 1])
+            chain.append(lg)
+    # a traced block once: the dense layer, and the two expert layers together
+    now = metrics.mla_decode_lowerings()
+    assert now.get("absorbed_fragment", 0) - forms.get("absorbed_fragment", 0) == 2
+    assert now.get("expanded", 0) == forms.get("expanded", 0)
+    now = metrics.attention_fragment_lowerings()
+    assert now.get("kernel", 0) - paths.get("kernel", 0) == 2
+    assert now.get("xla", 0) == paths.get("xla", 0)
+    np.testing.assert_allclose(got_logits, want_logits, atol=3e-4, rtol=3e-4)
+    np.testing.assert_allclose(
+        jnp.stack(chain, 1).reshape(rows, VOCAB), got_logits, atol=3e-4, rtol=3e-4)
+    assert abs(float(got_loss) - float(want_loss)) < 1e-5 * abs(float(want_loss))
+    whole = np.sqrt(sum(float(jnp.sum(g * g)) for g in jax.tree_util.tree_leaves(want)))
+    for group in want:
+        for leaf in want[group]:
+            g, w = np.asarray(got[group][leaf]), np.asarray(want[group][leaf])
+            floor = 1e-3 * whole if leaf != "kv_b" else 1e-12
+            err = np.linalg.norm(g - w) / max(np.linalg.norm(w), floor)
+            assert err < 1e-3, (group, leaf, err)
+    # of a stream's three stored blocks of 16 and its own, the stored
+    # ones at or past its start position are skipped; the text skips none
+    skipped = sum(3 - min(-(-int(p) // 16), 3) for p in pos0)
+    assert float(got_stats["attn_key_blocks_skipped_share"]) == pytest.approx(
+        skipped / (4.0 * n))
+    assert float(want_stats["attn_key_blocks_skipped_share"]) == 0.0
+
+
 def test_hyper_connection_block_equals_the_reference_and_its_gradient(setup):
     """(c) rows and columns of ``H_res`` sum to 1, the block is the
     reference's token by token, and the gradient through the 20 rounds
@@ -403,6 +485,7 @@ def test_the_forms_are_counted(setup):
 
     config, params, model, batch = setup
     before = metrics.mla_decode_lowerings()
+    paths = dict(metrics.attention_fragment_lowerings())
     _model_forward(model, params, batch)
     state = _f32_state(ref.batch_state(batch))
     model.apply(params, jnp.zeros((4, 1, 1), jnp.int32), state)
@@ -412,3 +495,9 @@ def test_the_forms_are_counted(setup):
     # one-token form traces every layer
     assert after.get("expanded", 0) - before.get("expanded", 0) == 2
     assert after.get("absorbed", 0) - before.get("absorbed", 0) == 3
+    # off a TPU no fragment takes the kernel, and the one-token form is
+    # no fragment
+    assert after.get("absorbed_fragment", 0) == before.get("absorbed_fragment", 0)
+    now = metrics.attention_fragment_lowerings()
+    assert now.get("xla", 0) - paths.get("xla", 0) == 2
+    assert now.get("kernel", 0) == paths.get("kernel", 0)

@@ -379,6 +379,10 @@ FRAGMENT_LAYERS = {
     # episode's rows, eight over a ring of one key block
     "laguna_full": (16, 256, 8, 6, 128, 4096, None),
     "laguna_ring": (16, 256, 8, 8, 128, 512, 512),
+    # the latent cell: ONE key head of 576 lanes (the array's whole minor
+    # dimension, no whole lane tiles) for 32 query heads in four tiles,
+    # the value the row's leading 512 lanes, read out of the key cache
+    "xing4_latent": (8, 128, 1, 32, 576, 2048, None, 512),
 }
 
 
@@ -387,7 +391,8 @@ def test_v5e_fragment_attention_kernel_compiles(v5e_mesh, layer):
     """The learn form's attention kernel (ops/flash_attention.py) at a
     sequence cell's width, forward, recomputation and backward under
     ``shard_map`` and a checkpoint as the learn program runs it. Mosaic
-    takes all three heads (64 rides two key heads a block); the three
+    takes all three heads (64 rides two key heads a block) and the
+    latent row's 576 lanes as one key head in four query tiles; the three
     custom calls carry the caller's scope, under which the trace files
     their time; and no float32 array of (tokens, stored rows) exists
     anywhere in the compiled program: the score matrices are never
@@ -396,7 +401,8 @@ def test_v5e_fragment_attention_kernel_compiles(v5e_mesh, layer):
 
     from ray_tpu.ops import flash_attention
 
-    b, t, kv, group, d, depth, window = FRAGMENT_LAYERS[layer]
+    b, t, kv, group, d, depth, window, *dv = FRAGMENT_LAYERS[layer]
+    (dv,) = dv or (d,)
     axis = sharding_lib.data_axis(v5e_mesh)
     rows = sharding_lib.batch_sharded(v5e_mesh)
     on = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=rows)
@@ -407,7 +413,8 @@ def test_v5e_fragment_attention_kernel_compiles(v5e_mesh, layer):
         def attention(q, k, v):
             with jax.named_scope("learn/attn"):
                 return flash_attention.fragment_attention(
-                    q, k, v, kc, vc, pos0, seg, positions, window=window)
+                    q, k, v, kc, kc if dv != d else vc, pos0, seg, positions,
+                    window=window)
 
         return jax.grad(
             lambda *qkv: jnp.sum(jnp.square(attention(*qkv))),
@@ -416,7 +423,7 @@ def test_v5e_fragment_attention_kernel_compiles(v5e_mesh, layer):
     sharded = jax.shard_map(
         grads, mesh=v5e_mesh, in_specs=(P(axis),) * 8, out_specs=P(axis))
     compiled = jax.jit(sharded).lower(
-        on(bf, b, t, kv, group, d), on(bf, b, t, kv, d), on(bf, b, t, kv, d),
+        on(bf, b, t, kv, group, d), on(bf, b, t, kv, d), on(bf, b, t, kv, dv),
         on(bf, b, depth, kv * d), on(bf, b, depth, kv * d),
         on(i32, b), on(i32, b, t), on(i32, b, t),
     ).compile()

@@ -61,6 +61,19 @@ FRAGMENT_SHAPES = {
 }
 
 
+def _rows_with_a_reset(pos0, t):
+    """``seg`` and ``positions`` ``(B, T)`` of streams that start at
+    ``pos0``, the second with an episode reset in the fragment's middle."""
+    fresh = np.zeros((pos0.shape[0], t), bool)
+    fresh[1, t // 2] = True
+    seg = jnp.asarray(np.cumsum(fresh, 1), jnp.int32)
+    steps = np.arange(t)[None]
+    opened = np.maximum.accumulate(np.where(fresh, steps, -1), axis=1)
+    positions = jnp.where(
+        seg == 0, pos0[:, None] + steps, steps - opened).astype(jnp.int32)
+    return seg, positions
+
+
 @pytest.mark.parametrize("cell", list(FRAGMENT_SHAPES))
 def test_fragment_attention_on_tpu(cell, monkeypatch):
     """``_cached_attention``'s fragment form takes the kernel on the
@@ -87,13 +100,7 @@ def test_fragment_attention_on_tpu(cell, monkeypatch):
     pos0 = jnp.asarray([0, depth // 4 + 3, 513, 2 * depth - 256], jnp.int32)
     if window is None:
         pos0 = jnp.minimum(pos0, depth - t)
-    fresh = np.zeros((b, t), bool)
-    fresh[1, t // 2] = True
-    seg = jnp.asarray(np.cumsum(fresh, 1), jnp.int32)
-    steps = np.arange(t)[None]
-    opened = np.maximum.accumulate(np.where(fresh, steps, -1), axis=1)
-    positions = jnp.where(
-        seg == 0, pos0[:, None] + steps, steps - opened).astype(jnp.int32)
+    seg, positions = _rows_with_a_reset(pos0, t)
     rows = {"seg": seg, "positions": positions, "pos0": pos0}
     stub = types.SimpleNamespace(kv_heads=kv, dtype=jnp.bfloat16)
 
@@ -119,6 +126,58 @@ def test_fragment_attention_on_tpu(cell, monkeypatch):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-2)
     for got, ref in zip(grads, want_grads):
         scale = float(jnp.max(jnp.abs(ref)))
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(ref, np.float32),
+            atol=2e-2 * scale)
+
+
+def test_latent_fragment_on_tpu():
+    """The latent layer's fragment at the Xing4 cell's width takes the
+    kernel by the rule (one key head of 576 lanes, the 32 query heads in
+    four tiles), and the absorbed product's output and gradients (the
+    queries, the own rows and ``W_kvb``) agree with the expanded text
+    within bfloat16's rounding."""
+    from ray_tpu.ops import flash_attention, latent_attention
+
+    b, t, h, dn, rope, latent, dv, depth = 4, 128, 32, 128, 64, 512, 128, 2048
+    bf = jnp.bfloat16
+    assert flash_attention.fragment_kernel_applies(
+        t, h, 1, latent + rope, depth, bf)
+    assert flash_attention.fragment_head_tile(t, h, 1, latent + rope) == 8
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    operands = (
+        jax.random.normal(keys[0], (b, t, h, dn), jnp.float32),
+        jax.random.normal(keys[1], (b, t, h, rope), jnp.float32),
+        jax.random.normal(keys[2], (b, t, latent + rope), bf),
+        jax.random.normal(keys[3], (latent, h * (dn + dv)), jnp.float32)
+        * latent ** -0.5,
+    )
+    cache = jax.random.normal(keys[4], (b, depth, latent + rope), bf)
+    w = jax.random.normal(keys[5], (b, t, h, dv), jnp.float32)
+    # an empty cache, one part full with a reset inside the fragment, one
+    # just past a block's edge, a full one
+    pos0 = jnp.asarray([0, depth // 4 + 3, 513, depth - t], jnp.int32)
+    seg, positions = _rows_with_a_reset(pos0, t)
+    scale = (dn + rope) ** -0.5
+
+    def kernel(q_nope, q_pe, rows_new, kv_b):
+        return latent_attention.absorbed_fragment(
+            q_nope, q_pe, rows_new, cache, kv_b, seg, positions, pos0, scale, bf)
+
+    def text(q_nope, q_pe, rows_new, kv_b):
+        return latent_attention.expanded_fragment(
+            q_nope, q_pe, rows_new, cache, kv_b, seg, pos0, scale, bf, block=2)
+
+    def run(f):
+        grads = jax.jit(jax.grad(
+            lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2, 3)))
+        return jax.jit(f)(*operands), grads(*operands)
+
+    out, grads = run(kernel)
+    want, want_grads = run(text)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-2)
+    for got, ref in zip(grads, want_grads):
+        scale = float(jnp.max(jnp.abs(ref.astype(jnp.float32))))
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(ref, np.float32),
             atol=2e-2 * scale)
