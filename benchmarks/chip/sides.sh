@@ -40,5 +40,6 @@ for path in sorted(glob.glob(f"{out}/sides_{cell}_*.jsonl")):
         m = {k: round(v["value"], 3) for k, v in r["result"]["metrics"].items()}
         print(path.rsplit("_", 1)[-1], r["result"]["seed"], r["result"].get("correct"), m,
               "missed:", missed, (r.get("lowerings") or {}).get("attention_fragment_lowerings"),
+              "step:", (r.get("lowerings") or {}).get("attention_step_lowerings"),
               r.get("learn_stats"))
 PY

@@ -16,10 +16,21 @@ key rebuilt through ``W_kvb``, against ``absorbed_fragment`` on the
 kernel, with the gradients of ``q_nope``, ``q_pe``, the own rows and
 ``W_kvb``. Prints one JSON line a case. TPU only: a time from another
 backend is not a device time.
+
+    ... benchmarks/profile_fragment_attention.py step [<case> ...] [<block_k> ...]
+
+The ONE-TOKEN form of a full-depth softmax layer alone, as the rollout
+runs it (all of a cell's streams, depths spread evenly over the episode,
+the caches donated so that the step's scatter is in place): the text
+(every slot under a mask) against ``ops/flash_attention.step_attention``,
+microseconds a step over 5 calls of 64 scanned steps, and the GB/s of
+each over the bytes it moves (the text all slots of both caches, the
+kernel the key blocks its streams hold).
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import sys
 import time
@@ -44,7 +55,16 @@ CASES = {
 LATENT_CASES = {
     "xing4_latent": (8, 128, 32, 128, 64, 512, 128, 2048),
 }
+# a cell's streams, key heads, group, head, depth (= episode)
+STEP_CASES = {
+    "smallthinker_full": (32, 4, 7, 128, 8192),
+    "laguna_full": (16, 8, 6, 128, 4096),
+    "qwen3next": (64, 2, 8, 256, 2048),
+    "granite4h": (16, 8, 4, 64, 2048),
+}
 CALLS = 10
+STEP_CALLS = 5
+STEPS = 64  # of one call
 
 
 def ms_per_call(fn, *args):
@@ -169,9 +189,83 @@ def run_latent(name, b, t, h, dn, rope, latent, dv, depth, blocks):
            ("o", "dq_nope", "dq_pe", "drows", "dkv_b"), pos0, depth, blocks)
 
 
+def run_step(name, b, kv, group, d, depth, blocks, pos0=None):
+    bf = jnp.bfloat16
+    h = kv * group
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    q = jax.random.normal(keys[0], (b, 1, h, d), jnp.float32)
+    k = jax.random.normal(keys[1], (b, 1, kv, d), jnp.float32)
+    v = jax.random.normal(keys[2], (b, 1, kv, d), jnp.float32)
+    if pos0 is None:  # every stream half a fragment past its place in the episode
+        pos0 = np.arange(b) * (depth // b) + depth // (2 * b)
+    pos0 = jnp.asarray(pos0, jnp.int32)
+    ctx = {"seg": jnp.zeros((b, 1), jnp.int32), "positions": pos0[:, None],
+           "pos0": pos0}
+    stub = types.SimpleNamespace(kv_heads=kv, dtype=bf)
+
+    def measure(applies, block_k):
+        """Microseconds a step and the last step's output: ``STEPS``
+        steps a call under one ``lax.scan`` with the caches in its carry,
+        as the rollout runs them (a call alone is the host's dispatch,
+        0.3 ms), the rule and the kernel's key block patched in while
+        the call is traced."""
+        step_attention = flash_attention.step_attention
+        rule = flash_attention.step_kernel_applies
+        flash_attention.step_kernel_applies = applies
+        flash_attention.step_attention = functools.partial(
+            step_attention, block_k=block_k)
+
+        @functools.partial(jax.jit, donate_argnums=(3, 4))
+        def call(q, k, v, kc, vc):
+            def step(caches, i):
+                o, caches, _ = SequenceLM._cached_attention(
+                    stub, q + i, k, v, caches, ctx, d ** -0.5, scope="attn")
+                return caches, o
+
+            (kc, vc), o = jax.lax.scan(
+                step, (kc, vc), jnp.arange(STEPS, dtype=jnp.float32) / STEPS)
+            return o[0], kc, vc
+
+        kc = jax.random.normal(keys[3], (b, depth, kv * d), bf)
+        vc = jax.random.normal(keys[4], (b, depth, kv * d), bf)
+        try:
+            for _ in range(2):
+                o, kc, vc = call(q, k, v, kc, vc)
+            jax.block_until_ready(o)
+            start = time.perf_counter()
+            for _ in range(STEP_CALLS):
+                o, kc, vc = call(q, k, v, kc, vc)
+            jax.block_until_ready(o)
+            return (time.perf_counter() - start) * 1e6 / (STEP_CALLS * STEPS), o
+        finally:
+            flash_attention.step_attention = step_attention
+            flash_attention.step_kernel_applies = rule
+
+    text_us, want = measure(lambda *a: False, None)
+    row = 2 * 2 * kv * d  # bytes of a slot's key and value
+    for block_k in blocks:
+        us, got = measure(lambda *a: True, block_k)
+        bk = flash_attention.fragment_block_k(depth, block_k)
+        skipped, held = flash_attention.step_key_blocks(pos0 + 1, depth, block_k)
+        moved = (held - int(skipped)) * bk * row
+        print(json.dumps({
+            "case": name, "form": "step", "block_k": bk,
+            "text_us": round(text_us, 1), "kernel_us": round(us, 1),
+            "text_gb_per_s": round(b * depth * row / text_us / 1e3, 1),
+            "kernel_gb_per_s": round(moved / us / 1e3, 1),
+            "rel_o": round(rel(got, want), 5),
+            "key_blocks_skipped_share": round(float(skipped) / held, 4),
+        }), flush=True)
+
+
 def main(argv):
     if jax.default_backend() != "tpu":
         raise SystemExit("a TPU is needed: a time from another backend is no device time")
+    if argv[:1] == ["step"]:
+        blocks = [int(a) for a in argv if a.isdigit()] or [None]
+        for name in [a for a in argv[1:] if not a.isdigit()] or STEP_CASES:
+            run_step(name, *STEP_CASES[name], blocks)
+        return
     # the text is the rule's other branch
     flash_attention.fragment_kernel_applies = lambda *a: False
     blocks = [int(a) for a in argv if a.isdigit()] or [None]

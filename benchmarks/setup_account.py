@@ -9,7 +9,8 @@ program family and phase, the persistent cache's hits and misses; no
 as they stood when the measured window began, the harness's own
 ``[setup]`` line, the result line, the lowering counters at exit
 (``ray_tpu_*_lowerings_total``) and the newest iteration's
-``LEARN_STATS`` (``attn_key_blocks_skipped_share``, ...). ``benchmarks/chip/setup_account.sh``
+``LEARN_STATS`` (``attn_key_blocks_skipped_share``,
+``attn_decode_key_blocks_skipped_share``, ...). ``benchmarks/chip/setup_account.sh``
 runs it cold and warm for each cell; ``--table`` prints PERF.md's
 "Where set-up goes" from such lines.
 """
@@ -23,7 +24,8 @@ import sys
 
 
 # the newest iteration's learn statistics kept beside a run's result
-LEARN_STATS = ("attn_key_blocks_skipped_share", "window_rows_seen_mean",
+LEARN_STATS = ("attn_key_blocks_skipped_share",
+               "attn_decode_key_blocks_skipped_share", "window_rows_seen_mean",
                "moe_decode_held_experts_touched_share", "moe_rows_computed_share")
 
 
@@ -95,7 +97,8 @@ def run(out_path: str, argv) -> int:
 
     record["lowerings"] = {
         name: getattr(metrics, name)()
-        for name in ("attention_fragment_lowerings", "attention_layer_lowerings",
+        for name in ("attention_fragment_lowerings", "attention_step_lowerings",
+                     "attention_layer_lowerings",
                      "window_cache_lowerings", "moe_product_lowerings",
                      "deltanet_step_lowerings", "ssm_step_lowerings",
                      "mla_decode_lowerings")

@@ -971,10 +971,14 @@ class SequenceLM:
             # at or past the start position (0 where the XLA text runs,
             # which multiplies every slot); a latent layer's rows are one
             # key head for all its query heads
-            skipped, walked = 0.0, 0
+            # and of the key blocks the one-token kernel's layers hold
+            # (the full-depth softmax layers), those a step at each of
+            # the fragment's positions skips: the blocks with no slot at
+            # or below the position (0 where the text runs)
+            skipped, walked, step_skipped, step_walked = 0.0, 0, 0.0, 0
             for n, (name, kind, _, _) in enumerate(self.segments):
-                if name in self.attention:
-                    a = self.attention[name]
+                a = self.attention.get(name)
+                if a is not None:
                     geometry = a.heads, a.kv_heads, a.head_dim
                 elif kind == LATENT:
                     geometry = self.heads, 1, self.latent_row
@@ -985,7 +989,17 @@ class SequenceLM:
                         t, *geometry, depth, self.dtype):
                     more, blocks = flash_attention.fragment_key_blocks(pos0, depth)
                     skipped, walked = skipped + more, walked + blocks
+                if a is not None and a.window is None and (
+                        flash_attention.step_kernel_applies(
+                            *geometry, depth, self.dtype)):
+                    more, blocks = flash_attention.step_key_blocks(
+                        positions + 1, depth)
+                    step_skipped, step_walked = (
+                        step_skipped + more, step_walked + blocks)
             stats_out["attn_key_blocks_skipped_share"] = skipped / max(walked, 1)
+            if self.attention:
+                stats_out["attn_decode_key_blocks_skipped_share"] = (
+                    step_skipped / max(step_walked, 1))
         if stats_out is not None and not loads:
             if "moe_routes" in stats_out:
                 # asked for every token's expert set where no layer
@@ -1236,7 +1250,19 @@ class SequenceLM:
         that is negative), a query sees the rows whose position is less
         than ``window`` behind its own, and the masks come from those
         positions, never from slot numbers, and the number of (query,
-        key) pairs seen is returned third (None without a window)."""
+        key) pairs seen is returned third (None without a window).
+
+        Which form runs where, each chosen by what the call sees in its
+        input. A fragment (``T > 1``): the tiled kernel
+        ``ops/flash_attention.fragment_attention`` where
+        ``fragment_kernel_applies`` says so (a TPU, bfloat16, whole
+        blocks), window or none, else the XLA text a block of streams
+        at a time. One token over a full-depth cache (``window is
+        None``): ``ops/flash_attention.step_attention`` where
+        ``step_kernel_applies`` says so, which fetches a stream's key
+        blocks below its depth only, else the text. One token over a
+        ring: the text, always (every slot under the position's mask:
+        past its first turn a ring has no unwritten slot to skip)."""
         k_cache, v_cache = state
         b, t, h, d = q.shape
         hkv = k.shape[2]
@@ -1324,9 +1350,19 @@ class SequenceLM:
         steps_t = jnp.arange(t)
         # a ring's masks need each query's position
         own_positions = () if window is None else (positions,)
-        if t == 1:
+        if t == 1 and window is None and flash_attention.step_kernel_applies(
+                h, hkv, d, depth, self.dtype):
+            # a full-depth cache is half unwritten at the mean: the
+            # tiled step kernel fetches a stream's key blocks below its
+            # depth only (a ring past its first turn has no unwritten
+            # slot: its step stays on the text)
+            metrics.inc_attention_step_lowering("kernel")
+            with part("scores"):
+                o = flash_attention.step_attention(qh, new_k, new_v, pos0 + 1)
+        elif t == 1:
             # decode reads the cache it has just written: the own
             # key sits at slot pos0, so the stored range is one longer
+            metrics.inc_attention_step_lowering("xla")
             o = attend(qh, k, v, new_k, new_v, seg, pos0 + 1, *own_positions)
         elif flash_attention.fragment_kernel_applies(
                 t, h, hkv, d, depth, self.dtype):

@@ -799,3 +799,239 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
         spread(q), k.reshape(bsz, t, kv * d), v.reshape(bsz, t, kv * dv),
         k_cache, v_cache, pos0, seg, positions, window, block_k, tile,
         interpret))
+
+
+# -- one token over a stored cache (the sequence models' rollout form) ------
+#
+# ``models/sequence_lm._cached_attention``'s one-token form over a
+# full-depth cache: a stream's query heads over the rows its cache holds,
+# its own (written by the step's scatter) the last of them. One grid step
+# is one stream, which walks the key blocks it holds in a loop, all key
+# heads of a block together; the query heads of a key head are the rows
+# of one small tile. A stream's depth is a scalar-prefetch operand: the
+# blocks past it are never fetched (nor is a grid step spent on them:
+# as a BlockSpec pipeline over (streams, key blocks) the skipped steps
+# of a static grid cost 0.25 us each and a working step 0.15 us beside
+# its 1 MB, 502 us for the 401 of this form at 32 streams of 8,192 rows;
+# one key head a grid step, 128 KB, was slower than the XLA text), and
+# the mask inside the last held block comes from the slot numbers.
+
+# the key and value blocks in flight and in use: two slots of each
+_STEP_VMEM_BYTES = 16 * 2 ** 20
+_SUBLANES = 8
+
+
+def step_kernel_applies(heads, kv_heads, head_dim, depth, dtype) -> bool:
+    """The step kernel's lowering exists on a TPU (the process's default
+    backend) for bfloat16 operands, a cache of whole key blocks, a key
+    that is whole lane tiles or packs into one (64: two key heads a
+    block), and a block of all key heads that fits VMEM four times."""
+    pack = _heads_packed(head_dim, kv_heads)
+    block_k = fragment_block_k(depth)
+    return (
+        jax.default_backend() == "tpu"
+        and dtype == jnp.bfloat16
+        and block_k > 0
+        and heads % kv_heads == 0
+        and head_dim * pack % _LANES == 0
+        and 4 * block_k * kv_heads * head_dim * 2 <= _STEP_VMEM_BYTES
+    )
+
+
+def step_key_blocks(rows_held, depth: int, block_k: int | None = None):
+    """``(skipped, all)`` key blocks of one key head of a step over the
+    streams ``rows_held`` (any shape): the blocks with no slot below a
+    stream's depth are skipped."""
+    bk = fragment_block_k(depth, block_k)
+    stored = depth // bk
+    return (jnp.sum(stored - _blocks_held(rows_held, bk, stored)),
+            rows_held.size * stored)
+
+
+def _step_kernel(held_ref, q_ref, kc_ref, vc_ref, o_ref, k_buf, v_buf, sem,
+                 slot_ref, *, block_k):
+    """One stream a grid step: its held key blocks in a loop, each
+    fetched by the step before it (a stream's first by the last step of
+    the stream before), so that no step is spent on a block that is
+    skipped. ``kc_ref``, ``vc_ref`` the whole caches in HBM; ``k_buf``,
+    ``v_buf`` ``(2, block_k, heads * lanes)``."""
+    b = pl.program_id(0)
+    heads, rows, lanes = q_ref.shape[1:]
+    stored = kc_ref.shape[1] // block_k
+    held = held_ref[b]
+    # at least the first: the stream before has started its fetch
+    blocks = jnp.maximum(_blocks_held(held, block_k, stored), 1)
+
+    def copies(stream, kb, slot):
+        at = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
+        return (
+            pltpu.make_async_copy(
+                kc_ref.at[stream, at], k_buf.at[slot], sem.at[0, slot]),
+            pltpu.make_async_copy(
+                vc_ref.at[stream, at], v_buf.at[slot], sem.at[1, slot]),
+        )
+
+    def start(stream, kb, slot):
+        for copy in copies(stream, kb, slot):
+            copy.start()
+
+    @pl.when(b == 0)
+    def _():
+        slot_ref[0] = 0
+        start(0, 0, 0)
+
+    first = slot_ref[0]
+
+    def block(kb, carry):
+        slot = (first + kb) % 2
+
+        @pl.when(kb + 1 < blocks)
+        def _():
+            start(b, kb + 1, 1 - slot)
+
+        @pl.when((kb + 1 == blocks) & (b + 1 < pl.num_programs(0)))
+        def _():
+            start(b + 1, 0, 1 - slot)
+
+        for copy in copies(b, kb, slot):
+            copy.wait()
+        mask = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1) < held
+        out = []
+        for n, (m_prev, l_prev, acc) in enumerate(carry):
+            at = pl.ds(n * lanes, lanes)
+            s = jax.lax.dot_general(
+                q_ref[0, n], k_buf[slot, :, at], _NT,
+                preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s, _MASKED)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            out.append((
+                m_new,
+                alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True),
+                alpha * acc + jnp.dot(
+                    p.astype(v_buf.dtype), v_buf[slot, :, at],
+                    preferred_element_type=jnp.float32),
+            ))
+        return tuple(out)
+
+    init = (jnp.full((rows, 1), _MASKED, jnp.float32),
+            jnp.zeros((rows, 1), jnp.float32),
+            jnp.zeros((rows, lanes), jnp.float32))
+    done = jax.lax.fori_loop(0, blocks, block, (init,) * heads)
+    slot_ref[0] = (first + blocks) % 2
+    for n, (_, l, acc) in enumerate(done):
+        o_ref[0, n] = acc / l
+
+
+@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
+def _step_fwd(q, k_cache, v_cache, rows_held, *, block_k, interpret):
+    """``q`` ``(B, key heads' lane blocks, rows, lanes)`` over the caches
+    ``(B, depth, blocks * lanes)``, which stay in HBM: a grid step a
+    stream. A ``jit`` of its own: traced once a shape, not once a
+    layer."""
+    from ray_tpu import sharding as sharding_lib
+
+    bsz, kv, rows, lanes = q.shape
+    of_stream = pl.BlockSpec((1, kv, rows, lanes), lambda b, held: (b, 0, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    operands = (q, k_cache, v_cache, rows_held)
+    return pl.pallas_call(
+        functools.partial(_step_kernel, block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bsz,),
+            in_specs=[of_stream, in_hbm, in_hbm],
+            out_specs=of_stream,
+            scratch_shapes=[
+                pltpu.VMEM((2, block_k, kv * lanes), k_cache.dtype),
+                pltpu.VMEM((2, block_k, kv * lanes), v_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            q.shape, jnp.float32, vma=sharding_lib.vma_of(operands)),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_FRAGMENT_VMEM_BYTES,
+        ),
+        name="step_attention",
+    )(rows_held.astype(jnp.int32), q, k_cache, v_cache)
+
+
+def step_attention_text(q, k_cache, v_cache, rows_held):
+    """The same attention as XLA writes it, every slot under a mask
+    (``_cached_attention``'s one-token text): the kernel's backward pass
+    and its oracle."""
+    kv, d = q.shape[2], q.shape[-1]
+    kc = k_cache.reshape(k_cache.shape[:2] + (kv, d))
+    vc = v_cache.reshape(v_cache.shape[:2] + (kv, d))
+    s = jnp.einsum("btngd,bsnd->bngts", q, kc, preferred_element_type=jnp.float32)
+    see = jnp.arange(kc.shape[1])[None] < rows_held[:, None]
+    w = jax.nn.softmax(jnp.where(see[:, None, None, None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bngts,bsnd->btngd", w.astype(q.dtype), vc,
+                      preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _step_attention(q, k_cache, v_cache, rows_held, block_k, interpret):
+    bsz, _, kv, group, d = q.shape
+    pack = _heads_packed(d, kv)
+    rows = _ceil_to(pack * group, _SUBLANES)
+    # a head narrower than the lanes: ``pack`` key heads a block, each
+    # of their query heads zero outside its own head's lanes, so that
+    # the products over the whole block are the head's own
+    x = q.reshape(bsz, kv // pack, pack, group, 1, d)
+    if pack > 1:
+        x = x * jnp.eye(pack, dtype=q.dtype).reshape(pack, 1, pack, 1)
+    x = x.reshape(bsz, kv // pack, pack * group, pack * d)
+    o = _step_fwd(
+        _pad_to(x, 2, rows), k_cache, v_cache, rows_held,
+        block_k=block_k, interpret=interpret)
+    # and back, each head from its own lanes
+    o = o[:, :, :pack * group].reshape(bsz, kv // pack, pack, group, pack, d)
+    o = jnp.stack([o[:, :, a, :, a] for a in range(pack)], axis=2)
+    return o.reshape(bsz, 1, kv, group, d)
+
+
+def _step_fwd_rule(q, k_cache, v_cache, rows_held, block_k, interpret):
+    return (_step_attention(q, k_cache, v_cache, rows_held, block_k, interpret),
+            (q, k_cache, v_cache, rows_held))
+
+
+def _step_bwd_rule(block_k, interpret, residuals, do):
+    *operands, rows_held = residuals
+    _, vjp = jax.vjp(
+        lambda *a: step_attention_text(*a, rows_held), *operands)
+    return vjp(do) + (None,)
+
+
+_step_attention.defvjp(_step_fwd_rule, _step_bwd_rule)
+
+
+def step_attention(q, k_cache, v_cache, rows_held, *, block_k=None,
+                   interpret=False):
+    """One token's attention over its stream's stored keys and values
+    as one tiled kernel, forward only: of a full-depth cache only the key
+    blocks with a slot below the stream's depth cross HBM, a key block
+    once a key head and the value block once.
+
+    ``q`` ``(B, 1, kv, group, D)``, scaled already, in the products'
+    type; ``k_cache``, ``v_cache`` ``(B, depth, kv * D)`` AFTER the
+    step's scatter, so that the own key sits among the ``rows_held``
+    ``(B,)`` leading slots a stream sees (at least one). Returns ``o``
+    ``(B, 1, kv, group, D)`` float32. Scores, masks, running max and sum
+    and the accumulator are float32; the weights enter the value product
+    in the cache's type and are normalised after it.
+
+    Differentiable in ``q`` and the caches: the backward pass is
+    :func:`step_attention_text`'s (rollout takes no gradient).
+    ``block_k`` and ``interpret`` are the tests' spellings."""
+    block_k = fragment_block_k(k_cache.shape[1], block_k)
+    if not block_k:
+        raise ValueError(
+            f"a cache of {k_cache.shape[1]} rows is not whole key blocks")
+    return _step_attention(q, k_cache, v_cache, rows_held, block_k, interpret)

@@ -157,6 +157,13 @@ WINDOW_CACHE_LOWERINGS_TOTAL = "ray_tpu_window_cache_lowerings_total"
 # is traced: once per attention layer body of a program
 ATTENTION_FRAGMENT_LOWERINGS_TOTAL = (
     "ray_tpu_attention_fragment_lowerings_total")
+# which lowering each traced softmax-attention layer's ONE-TOKEN form
+# took (models/sequence_lm._cached_attention): path = kernel
+# (ops/flash_attention.step_attention: a full-depth cache on a TPU
+# backend, bfloat16, whole key blocks; a stream's key blocks past its
+# depth are not fetched) | xla (every slot under a mask: every ring, and
+# everywhere else). Counted when the form is traced
+ATTENTION_STEP_LOWERINGS_TOTAL = "ray_tpu_attention_step_lowerings_total"
 # the geometry of each traced softmax-attention layer body
 # (models/sequence_lm.SequenceLM._attention, one body over a per-layer
 # description): kind = the layer's name in ``layer_types``, heads = ITS
@@ -699,6 +706,21 @@ def inc_attention_fragment_lowering(path: str) -> None:
 def attention_fragment_lowerings() -> Dict[str, float]:
     """``{path: traced fragment forms}`` since the process began."""
     return _totals_by_tag(ATTENTION_FRAGMENT_LOWERINGS_TOTAL, "path")
+
+
+def inc_attention_step_lowering(path: str) -> None:
+    """One traced attention layer's one-token form took ``path``
+    (``kernel`` | ``xla``)."""
+    counter(
+        ATTENTION_STEP_LOWERINGS_TOTAL,
+        "attention layers' one-token forms traced, by the lowering they took",
+        ("path",),
+    ).inc(1.0, {"path": path})
+
+
+def attention_step_lowerings() -> Dict[str, float]:
+    """``{path: traced one-token forms}`` since the process began."""
+    return _totals_by_tag(ATTENTION_STEP_LOWERINGS_TOTAL, "path")
 
 
 def window_cache_lowerings() -> Dict[str, float]:

@@ -1,6 +1,8 @@
 """Flash-attention Pallas kernel vs the XLA reference (interpret mode
 runs the real kernel on CPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -353,3 +355,129 @@ def test_fragment_rule_admits_the_cells_layers_on_a_tpu(
     assert not fa.fragment_kernel_applies(4096, heads, kv, head, depth, jnp.bfloat16)
     assert not fa.fragment_kernel_applies(
         tokens, heads, kv, head, depth + 24, jnp.bfloat16)
+
+
+# -- the step kernel against ``_cached_attention``'s one-token text --------
+
+def _step(b=5, kv=2, group=4, d=128, depth=2048, pos0=(0, 510, 511, 512, 2047),
+          dtype=jnp.bfloat16, seed=0):
+    """One token of ``b`` streams at ``pos0``: the operands as the model
+    hands them over and the rows the lane derives."""
+    rng = np.random.default_rng(seed)
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    q, k, v = normal(b, 1, kv * group, d), normal(b, 1, kv, d), normal(b, 1, kv, d)
+    caches = tuple(normal(b, depth, kv * d).astype(dtype) for _ in range(2))
+    pos0 = jnp.asarray(pos0, jnp.int32)
+    rows = {"seg": jnp.zeros((b, 1), jnp.int32), "positions": pos0[:, None],
+            "pos0": pos0}
+    return (q, k, v) + caches, rows, normal(b, 1, kv * group, d)
+
+
+def _step_text_and_kernel(rows, kv, dtype, block_k, monkeypatch, gate=False):
+    """``(q, k, v, k_cache, v_cache) -> o (B, 1, heads, D)`` twice
+    through the model's ``_cached_attention``: its XLA text (the rule's
+    branch off a TPU) and, the rule forced, the kernel in the
+    interpreter; with ``gate`` a sigmoid gate of the query after it, as
+    a gated layer applies one."""
+    import types
+
+    from ray_tpu.models.sequence_lm import SequenceLM
+    from ray_tpu.ops import flash_attention as fa
+
+    stub = types.SimpleNamespace(kv_heads=kv, dtype=dtype)
+
+    def text(q, k, v, kc, vc):
+        o = SequenceLM._cached_attention(
+            stub, q, k, v, (kc, vc), rows, q.shape[-1] ** -0.5, scope="attn")[0]
+        return o * jax.nn.sigmoid(q) if gate else o
+
+    def kernel(*operands):
+        with monkeypatch.context() as patch:
+            patch.setattr(fa, "step_kernel_applies", lambda *a: True)
+            patch.setattr(fa, "step_attention", functools.partial(
+                fa.step_attention, block_k=block_k, interpret=True))
+            return text(*operands)
+
+    return text, kernel
+
+
+_STEP_CASES = {
+    # the four geometries that run it, each with a stream at depth 0
+    # beside a full one and rows held of 1, 511, 512, 513 and the full
+    # depth in one batch (key blocks of 512: one to four held)
+    "heads_28_kv_4_d_128": dict(kv=4, group=7),
+    "heads_48_kv_8_d_128_gated": dict(kv=8, group=6, gate=True),
+    "heads_16_kv_2_d_256": dict(kv=2, group=8, d=256),
+    "heads_32_kv_8_d_64_packed": dict(kv=8, group=4, d=64),
+    # a cache of one key block; small blocks, float32: the arithmetic alone
+    "one_block": dict(depth=512, pos0=(0, 5, 510, 511, 300)),
+    "float32_blocks_of_16": dict(
+        depth=64, pos0=(0, 15, 16, 17, 63), block_k=16, dtype=jnp.float32),
+    # past the cache's end the scatter drops the key and every slot is seen
+    "a_stream_past_the_depth": dict(depth=1024, pos0=(0, 1023, 1024, 2000, 512)),
+}
+_STEP_CONTRACT_CASES = {
+    "gradient_is_the_texts": dict(
+        depth=64, pos0=(0, 15, 16, 17, 63), block_k=16, dtype=jnp.float32),
+    "a_skipped_block_changes_nothing": dict(
+        depth=64, pos0=(0, 15, 20, 31, 7), block_k=16, dtype=jnp.float32),
+}
+
+
+@pytest.mark.parametrize("name", list(_STEP_CASES) + list(_STEP_CONTRACT_CASES))
+def test_step_kernel(name, monkeypatch):
+    case = dict({**_STEP_CASES, **_STEP_CONTRACT_CASES}[name])
+    block_k, gate = case.pop("block_k", None), case.pop("gate", False)
+    dtype = case.get("dtype", jnp.bfloat16)
+    operands, rows, w = _step(**case)
+    text, kernel = _step_text_and_kernel(
+        rows, case.get("kv", 2), dtype, block_k, monkeypatch, gate)
+    loss = lambda f: lambda *a: jnp.sum(f(*a) * w)
+    if name == "a_skipped_block_changes_nothing":
+        # no stream is deeper than 32 of 64 slots: the last two blocks of
+        # 16 are skipped for all, so other rows there give the same bits
+        q, k, v, *caches = operands
+        other = (c.at[:, 33:].set(7.0 - 14.0 * n) for n, c in enumerate(caches))
+        np.testing.assert_array_equal(
+            np.asarray(kernel(q, k, v, *other)), np.asarray(kernel(*operands)))
+        return
+    tol = dict(atol=1e-4, rtol=1e-4) if dtype == jnp.float32 else dict(
+        atol=2e-2, rtol=2e-2)
+    np.testing.assert_allclose(
+        np.asarray(kernel(*operands)), np.asarray(text(*operands)), **tol)
+    if name == "gradient_is_the_texts":
+        # rollout takes none; a differentiated call gets the text's, in
+        # the query, the own key and value and the stored rows
+        got = jax.grad(loss(kernel), argnums=range(5))(*operands)
+        want = jax.grad(loss(text), argnums=range(5))(*operands)
+        for a, b in zip(got, want):
+            assert float(jnp.max(jnp.abs(b))) > 1e-3
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)
+
+
+@pytest.mark.parametrize("heads,kv,head,depth", [
+    (28, 4, 128, 8192),  # SmallThinker's full layer
+    (48, 8, 128, 4096),  # Laguna's full layers
+    (16, 2, 256, 2048),  # Qwen3-Next's gated layer
+    (32, 8, 64, 2048),   # Granite's: two key heads a block
+])
+def test_step_rule_by_shape(monkeypatch, heads, kv, head, depth):
+    """Off a TPU the rule says XLA whatever the shape; on one, from the
+    shapes alone: every cell's full-depth layer in bfloat16, and neither
+    float32 nor a cache of no whole key block (a ring never asks: its
+    window sends it to the text before the rule)."""
+    from ray_tpu.ops import flash_attention as fa
+
+    assert not fa.step_kernel_applies(heads, kv, head, depth, jnp.bfloat16)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert fa.step_kernel_applies(heads, kv, head, depth, jnp.bfloat16)
+    assert not fa.step_kernel_applies(heads, kv, head, depth, jnp.float32)
+    assert not fa.step_kernel_applies(heads, kv, head, depth + 24, jnp.bfloat16)
+    assert not fa.step_kernel_applies(heads, kv, 96, depth, jnp.bfloat16)
+    # two slots of a block of all key heads, keys and values, fit VMEM
+    assert not fa.step_kernel_applies(16 * heads, 16 * kv, head, depth, jnp.bfloat16)
+    # 16 blocks of 512: rows held of 1, 512, 513 and past the cache skip
+    # 15, 15, 14 and none
+    skipped, held = fa.step_key_blocks(
+        jnp.asarray([[1, 512], [513, 9000]], jnp.int32), 8192)
+    assert (int(skipped), held) == (44, 64)

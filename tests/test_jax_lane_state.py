@@ -243,6 +243,7 @@ def test_model_counts_travel_with_the_loss_and_nothing_stays_on_the_policy():
         "moe_tokens_per_held_expert", "moe_max_tokens_per_held_expert",
         "moe_slots_on_absent_experts", "moe_rows_computed_share",
         "attn_key_blocks_skipped_share", "moe_decode_held_experts_touched_share",
+        "attn_decode_key_blocks_skipped_share",
     }
     assert set(pol.__dict__) == attrs
 

@@ -248,6 +248,9 @@ def test_fragment_form_on_the_kernel_equals_the_text_and_the_steps(
     assert float(got_stats["attn_key_blocks_skipped_share"]) == pytest.approx(
         skipped / (4.0 * n))
     assert float(want_stats["attn_key_blocks_skipped_share"]) == 0.0
+    # the one-token kernel is the softmax kinds': a latent model's learn
+    # program carries no statistic of it (its text is as it was)
+    assert "attn_decode_key_blocks_skipped_share" not in got_stats
 
 
 def test_hyper_connection_block_equals_the_reference_and_its_gradient(setup):

@@ -590,6 +590,7 @@ def test_counters_and_statistics_say_every_layer_got_its_geometry(setup):
     assert 0.0 < touched < 1.0
     assert abs(float(stats["moe_decode_held_experts_touched_share"]) - touched) < 1e-6
     assert sorted(stats) == [
+        "attn_decode_key_blocks_skipped_share",
         "attn_key_blocks_skipped_share", "moe_decode_held_experts_touched_share",
         "moe_max_tokens_per_held_expert", "moe_rows_computed_share",
         "moe_slots_on_absent_experts", "moe_tokens_per_held_expert",
@@ -600,6 +601,47 @@ def test_counters_and_statistics_say_every_layer_got_its_geometry(setup):
     got = metrics.decode_held_experts_touched()
     assert got["updates"] - totals.get("updates", 0) == 1
     assert abs(got["share"] - totals.get("share", 0) - touched) < 1e-6
+
+
+@pytest.mark.parametrize("forced", [False, True])
+def test_one_token_form_the_rings_on_the_text_the_full_layers_by_the_rule(
+        setup, monkeypatch, forced):
+    """The three gated rings' one-token calls lower to the text whatever
+    the rule says, the two gated full layers' (four query heads over two
+    key heads, YaRN) to the step kernel where it says so, here in the
+    interpreter with the cache of 32 rows as four key blocks of 8: the
+    same logits, values and state; the learn form's statistic counts the
+    blocks a step at each position skips."""
+    import functools
+
+    from ray_tpu.ops import flash_attention
+    from ray_tpu.telemetry import metrics
+
+    config, params, model, batch, _ = setup
+    rows = batch["obs"].shape[0]
+    state = _f32_state(ref.batch_state(batch))
+    tokens = jnp.asarray(batch["obs"]).reshape(rows // T, T, 1)
+    want = model.apply(params, tokens[:, :1], state)
+    if forced:
+        monkeypatch.setattr(flash_attention, "step_kernel_applies", lambda *a: True)
+        monkeypatch.setattr(
+            flash_attention, "fragment_block_k", lambda depth, _=None: 8)
+        monkeypatch.setattr(
+            flash_attention, "step_attention",
+            functools.partial(flash_attention.step_attention, interpret=True))
+    before = dict(metrics.attention_step_lowerings())
+    got = model.apply(params, tokens[:, :1], state)
+    now = metrics.attention_step_lowerings()
+    assert now.get("kernel", 0) - before.get("kernel", 0) == (2 if forced else 0)
+    assert now["xla"] - before.get("xla", 0) == (3 if forced else 5)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=3e-4, rtol=3e-4)
+    stats = {}
+    model.apply(
+        params, tokens, state,
+        resets=jnp.asarray(batch["resets"]).reshape(rows // T, T), stats_out=stats)
+    share = float(stats["attn_decode_key_blocks_skipped_share"])
+    assert (0.2 < share < 0.8) if forced else share == 0.0
 
 
 def test_the_description_reads_the_other_families_as_before():

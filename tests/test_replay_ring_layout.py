@@ -437,6 +437,54 @@ def test_v5e_fragment_attention_kernel_compiles(v5e_mesh, layer):
     assert not re.search(rf"f32\[[0-9,]*{t},({depth}|{depth + t})\]", text)
 
 
+# all of a cell's streams, key heads, query heads a key head, head, depth:
+# the four cells' full-depth softmax layers as the rollout steps them
+STEP_LAYERS = {
+    "smallthinker_full": (32, 4, 7, 128, 8192),
+    "laguna_full": (16, 8, 6, 128, 4096),
+    "qwen3next": (64, 2, 8, 256, 2048),
+    "granite4h": (16, 8, 4, 64, 2048),
+}
+
+
+@pytest.mark.parametrize("layer", list(STEP_LAYERS))
+def test_v5e_step_attention_kernel_compiles(v5e_mesh, layer):
+    """The one-token form's kernel (ops/flash_attention.step_attention)
+    at a sequence cell's streams and width under ``shard_map``: Mosaic
+    takes all four geometries (6 and 7 query heads padded to the
+    sublanes, 64 two key heads a block) as ONE custom call under the
+    caller's scope, and no float32 array over the cache's slots exists
+    in the compiled program: no score of a slot past a stream's depth
+    is computed."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.ops import flash_attention
+
+    b, kv, group, d, depth = STEP_LAYERS[layer]
+    axis = sharding_lib.data_axis(v5e_mesh)
+    rows = sharding_lib.batch_sharded(v5e_mesh)
+    on = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=rows)
+    bf = jnp.bfloat16
+
+    def step(q, kc, vc, held):
+        with jax.named_scope("rollout/act/attn/scores"):
+            return flash_attention.step_attention(q, kc, vc, held)
+
+    sharded = jax.shard_map(
+        step, mesh=v5e_mesh, in_specs=(P(axis),) * 4, out_specs=P(axis))
+    text = jax.jit(sharded).lower(
+        on(bf, b, 1, kv, group, d), on(bf, b, depth, kv * d),
+        on(bf, b, depth, kv * d), on(jnp.int32, b),
+    ).compile().as_text()
+    calls = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "custom-call(" in line
+    ]
+    assert len(calls) == 1 and "rollout/act/attn/scores" in calls[0]
+    assert "step_attention" in calls[0]
+    assert not re.search(rf"f32\[[0-9,]*{depth}\]", text)
+
+
 def test_v5e_tree_update_pays_for_its_levels(v5e_mesh):
     """The DQN cell's priority refresh (ops/segment_tree.py: an
     (8, 512) update of a 131,072-leaf f64 tree pair) as the chip's

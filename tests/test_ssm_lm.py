@@ -285,8 +285,11 @@ def test_a_model_with_no_expert_layer_reports_no_expert_statistic(setup):
     stats = {}
     _model_forward(model, params, batch, stats)
     # beside it the attention layers' (no block is skipped by the XLA text)
-    assert sorted(stats) == ["attn_key_blocks_skipped_share", "ssm_dt_max"]
+    assert sorted(stats) == [
+        "attn_decode_key_blocks_skipped_share", "attn_key_blocks_skipped_share",
+        "ssm_dt_max"]
     assert float(stats["attn_key_blocks_skipped_share"]) == 0.0
+    assert float(stats["attn_decode_key_blocks_skipped_share"]) == 0.0
     # the largest step size the update saw: softplus of the in-projection's
     # dt columns plus dt_bias
     assert 0.0 < float(stats["ssm_dt_max"]) < 10.0

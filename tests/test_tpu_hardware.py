@@ -131,6 +131,58 @@ def test_fragment_attention_on_tpu(cell, monkeypatch):
             atol=2e-2 * scale)
 
 
+# streams, key heads, query heads a key head, head, depth: the four
+# cells' full-depth layers at a few streams
+STEP_SHAPES = {
+    "smallthinker": (5, 4, 7, 128, 8192),
+    "laguna": (5, 8, 6, 128, 4096),
+    "qwen3next": (5, 2, 8, 256, 2048),
+    "granite4h": (5, 8, 4, 64, 2048),
+}
+
+
+@pytest.mark.parametrize("cell", list(STEP_SHAPES))
+def test_step_attention_on_tpu(cell, monkeypatch):
+    """``_cached_attention``'s one-token form over a full-depth cache
+    takes the step kernel on the chip by its own rule and a ring's the
+    text, and the kernel's output agrees with the text (the rule's other
+    branch) within bfloat16's rounding: an empty stream, a block's edge
+    from both sides and a full cache in one batch."""
+    import types
+
+    from ray_tpu.models.sequence_lm import SequenceLM
+    from ray_tpu.ops import flash_attention
+    from ray_tpu.telemetry import metrics
+
+    b, kv, group, d, depth = STEP_SHAPES[cell]
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    q = jax.random.normal(keys[0], (b, 1, kv * group, d), jnp.float32)
+    k = jax.random.normal(keys[1], (b, 1, kv, d), jnp.float32)
+    v = jax.random.normal(keys[2], (b, 1, kv, d), jnp.float32)
+    caches = tuple(
+        jax.random.normal(key, (b, depth, kv * d), jnp.bfloat16)
+        for key in keys[3:5])
+    pos0 = jnp.asarray([0, 510, 511, 512, depth - 1], jnp.int32)
+    rows = {"seg": jnp.zeros((b, 1), jnp.int32), "positions": pos0[:, None],
+            "pos0": pos0}
+    stub = types.SimpleNamespace(kv_heads=kv, dtype=jnp.bfloat16)
+
+    def run(window=None):
+        return jax.jit(lambda q, k, v: SequenceLM._cached_attention(
+            stub, q, k, v, caches, rows, d ** -0.5, window=window,
+            scope="swa" if window else "attn")[0])(q, k, v)
+
+    count = lambda path: metrics.attention_step_lowerings().get(path, 0)
+    before = count("kernel"), count("xla")
+    out = run()
+    run(window=depth)
+    assert (count("kernel"), count("xla")) == (before[0] + 1, before[1] + 1)
+    monkeypatch.setattr(flash_attention, "step_kernel_applies", lambda *a: False)
+    want = run()
+    assert (count("kernel"), count("xla")) == (before[0] + 1, before[1] + 2)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-2)
+
+
 def test_latent_fragment_on_tpu():
     """The latent layer's fragment at the Xing4 cell's width takes the
     kernel by the rule (one key head of 576 lanes, the 32 query heads in
