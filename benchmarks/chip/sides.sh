@@ -7,7 +7,7 @@
 # (":1" traces the run and leaves perf.program_trace's summary of it in
 # chiprun_out/program_trace_<cell>_<side>_<seed>.json.)
 # <side> is a directory under .chip_check/ (change, parent, moved: the
-# change with one line inserted at the top of models/sequence_lm.py).
+# change with one line inserted at the top of models/sequence_lm/model.py).
 # "parent:S change:S" is a pair on one seed; the first run fills the cache.
 # Lines land in chiprun_out/sides_<cell>_<side>.jsonl.
 set -u

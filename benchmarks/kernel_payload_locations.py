@@ -17,7 +17,7 @@ Found with it (PR 36): the payload names the TEN innermost frames of
 the kernel's call (``jax_traceback_in_locations_limit``) by ABSOLUTE
 file path, line and column. So the programs that hold a kernel get a
 new cache key from a moved line in ``ops/ssd.py`` / ``ops/deltanet.py``,
-``models/sequence_lm.py`` or at ``JaxPolicy._action_step_body``'s and
+``models/sequence_lm`` or at ``JaxPolicy._action_step_body``'s and
 ``model_forward``'s call of the model, AND from the checkout's own
 directory: two checkouts of one tree never share those entries.
 """

@@ -3,7 +3,7 @@
     chiprun -- env PYTHONPATH=. python benchmarks/profile_fragment_attention.py \
         [<case> ...] [<block_k> ...]
 
-One attention layer's ``_cached_attention`` of each sequence cell as the
+One attention layer's ``cached_attention`` of each sequence cell as the
 learn form runs it (a group of ``learn_streams`` streams, depths spread
 evenly over the episode): the forward pass, and the forward pass with the
 gradients of ``q``, ``k`` and ``v``, on the host's clock over 10 queued
@@ -34,15 +34,12 @@ import functools
 import json
 import sys
 import time
-import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import sequence_lm
-from ray_tpu.models.sequence_lm import SequenceLM
-from ray_tpu.ops import flash_attention, latent_attention
+from ray_tpu.ops import cached_attention, flash_attention, latent_attention
 
 # streams of a group, tokens, key heads, group, head, depth, window, episode
 CASES = {
@@ -139,13 +136,12 @@ def run(name, b, t, kv, group, d, depth, window, episode, blocks):
     w = jax.random.normal(keys[5], (b, t, h, d), jnp.float32)
     pos0, seg, positions = fragment_rows(b, t, episode)
     ctx = {"seg": seg, "positions": positions, "pos0": pos0}
-    stub = types.SimpleNamespace(kv_heads=kv, dtype=bf)
     scale = d ** -0.5
 
     def text(q, k, v):
-        return SequenceLM._cached_attention(
-            stub, q, k, v, (kc, vc), ctx, scale, window=window,
-            scope="swa" if window else None)[0]
+        return cached_attention.cached_attention(
+            q, k, v, (kc, vc), ctx, scale=scale, window=window, dtype=bf,
+            scope="swa" if window else "attn")[0]
 
     def kernel(block_k):
         def attention(q, k, v):
@@ -176,7 +172,7 @@ def run_latent(name, b, t, h, dn, rope, latent, dv, depth, blocks):
     def text(q_nope, q_pe, rows_new, kv_b):
         return latent_attention.expanded_fragment(
             q_nope, q_pe, rows_new, cache, kv_b, seg, pos0, scale, bf,
-            block=sequence_lm._LATENT_ENV_BLOCK)
+            block=latent_attention._ENV_BLOCK)
 
     def kernel(block_k):
         def attention(q_nope, q_pe, rows_new, kv_b):
@@ -201,7 +197,6 @@ def run_step(name, b, kv, group, d, depth, blocks, pos0=None):
     pos0 = jnp.asarray(pos0, jnp.int32)
     ctx = {"seg": jnp.zeros((b, 1), jnp.int32), "positions": pos0[:, None],
            "pos0": pos0}
-    stub = types.SimpleNamespace(kv_heads=kv, dtype=bf)
 
     def measure(applies, block_k):
         """Microseconds a step and the last step's output: ``STEPS``
@@ -218,8 +213,9 @@ def run_step(name, b, kv, group, d, depth, blocks, pos0=None):
         @functools.partial(jax.jit, donate_argnums=(3, 4))
         def call(q, k, v, kc, vc):
             def step(caches, i):
-                o, caches, _ = SequenceLM._cached_attention(
-                    stub, q + i, k, v, caches, ctx, d ** -0.5, scope="attn")
+                o, caches, _ = cached_attention.cached_attention(
+                    q + i, k, v, caches, ctx, scale=d ** -0.5, window=None,
+                    dtype=bf, scope="attn")
                 return caches, o
 
             (kc, vc), o = jax.lax.scan(

@@ -71,7 +71,7 @@ MODEL_DEFAULTS: Dict[str, Any] = {
     # sharding.specs grammar); None → the model class's own rules
     "partition_rules": None,
     # decoder language model composed from a layer pattern
-    # (models/sequence_lm.py): observation = the last token id, action
+    # (models/sequence_lm): observation = the last token id, action
     # = the next token. "sequence_lm" is the architecture under the
     # key names of a Hugging Face config.json (hidden_size,
     # num_hidden_layers, full_attention_interval or layer_types, the

@@ -1,0 +1,255 @@
+"""From a published ``config.json``'s key names to the kinds' frozen
+descriptions: THE code that reads a family's key names. Every body
+branches on a description's fields, never on a key's presence."""
+
+from __future__ import annotations
+
+from typing import Dict, NamedTuple, Tuple
+
+import numpy as np
+
+from ray_tpu.models.sequence_lm.kinds import (
+    AttentionLayer, DeltaNetLayer, DenseLayer, ExpertLayer, HyperResidual,
+    LatentLayer, MambaLayer, PlainResidual)
+from ray_tpu.ops import latent_attention
+
+LINEAR, FULL, LATENT = "linear_attention", "full_attention", "latent_attention"
+MAMBA, ATTENTION, SLIDING = "mamba", "attention", "sliding_attention"
+DENSE, EXPERTS = "dense", "experts"
+
+
+def layer_types_of(config: Dict) -> Tuple[str, ...]:
+    """The pattern: ``layer_types`` if stated; all latent attention
+    where the config has a ``kv_lora_rank``; by
+    ``sliding_window_layout`` where the config has one (1: a window
+    layer, which is also where ``rope_layout`` turns; 0: full depth and
+    no positions); else every ``full_attention_interval``-th layer is
+    full attention."""
+    if config.get("layer_types"):
+        # a published list: its first ``num_hidden_layers``
+        return tuple(config["layer_types"])[:config.get("num_hidden_layers")]
+    layers = int(config["num_hidden_layers"])
+    if "sliding_window_layout" in config:
+        window = list(config["sliding_window_layout"])[:layers]
+        if window != list(config.get("rope_layout", window))[:layers]:
+            raise ValueError(
+                "a window layer without RoPE, or a full layer with it, is no kind")
+        return tuple(SLIDING if w else ATTENTION for w in window)
+    if "kv_lora_rank" in config:
+        return (LATENT,) * layers
+    every = int(config.get("full_attention_interval", 4))
+    return tuple(FULL if (i + 1) % every == 0 else LINEAR for i in range(layers))
+
+
+_YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast", "beta_slow")
+
+
+def attention_layers_of(config: Dict, layer_types) -> Dict[int, AttentionLayer]:
+    """``{layer: AttentionLayer}`` for the softmax-attention layers of
+    ``layer_types``, each key read for what it states and under
+    whichever family's name it has:
+
+    - heads: ``num_attention_heads_per_layer[l]``, else
+      ``num_attention_heads``; KV heads and the head's size are one for
+      the model;
+    - window (a ``"sliding_attention"`` layer): ``sliding_window_size``
+      or ``sliding_window``;
+    - RoPE: the ``rope_parameters`` block of the layer's kind where the
+      config has them (``rope_theta``, ``partial_rotary_factor``,
+      ``rope_type`` ``default`` or ``yarn`` with its ``factor``,
+      ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``
+      and ``attention_factor``, ``0.1 ln(factor) + 1`` where none is
+      stated); else ``rope_theta`` on the first
+      ``partial_rotary_factor`` of a ``"full_attention"`` head, on the
+      whole of a ``"sliding_attention"`` head, and none on an
+      ``"attention"`` layer;
+    - gate: ``gating`` states one for EVERY attention layer, a number a
+      head (``g_proj``); without the key a ``"full_attention"`` layer is
+      ``qwen3_next``'s, gated a dimension out of ``q_proj``, and the
+      other kinds have none. A gated layer norms ``q`` and ``k`` over
+      the head;
+    - scale: ``attention_multiplier`` on an ``"attention"`` layer that
+      states one, else ``head^-1/2``."""
+    c = config
+    out = {}
+    per_layer = c.get("num_attention_heads_per_layer")
+    for i, kind in enumerate(layer_types):
+        if kind not in (FULL, ATTENTION, SLIDING):
+            continue
+        heads = int(per_layer[i] if per_layer else c["num_attention_heads"])
+        head_dim = int(c.get("head_dim") or int(c["hidden_size"]) // heads)
+        window = None
+        if kind == SLIDING:
+            window = int(c.get("sliding_window_size") or c["sliding_window"])
+        rotary, theta, yarn, factor = 0, float(c.get("rope_theta", 10000.0)), (), 1.0
+        if "rope_parameters" in c:
+            rope = c["rope_parameters"][kind]
+            theta = float(rope["rope_theta"])
+            rotary = int(head_dim * float(rope.get("partial_rotary_factor", 1.0)))
+            kind_of = rope.get("rope_type", "default")
+            if kind_of == "yarn":
+                yarn = tuple((k, float(rope[k])) for k in _YARN_KEYS if k in rope)
+                factor = float(rope.get("attention_factor")
+                               or 0.1 * np.log(float(rope["factor"])) + 1.0)
+            elif kind_of != "default":
+                raise ValueError(f"rope_type {kind_of!r} is not supported")
+        elif kind == FULL:
+            rotary = int(head_dim * float(c.get("partial_rotary_factor", 1.0)))
+        elif kind == SLIDING:
+            rotary = head_dim
+        elif c.get("position_embedding_type", "nope") != "nope":
+            raise ValueError('an "attention" layer takes no positions')
+        gate = "head" if c.get("gating") else "element" if kind == FULL else None
+        scale = head_dim ** -0.5
+        if kind == ATTENTION:
+            scale = float(c.get("attention_multiplier", scale))
+        out[i] = AttentionLayer(
+            kind=kind, heads=heads, kv_heads=int(c["num_key_value_heads"]),
+            head_dim=head_dim, scale=scale, window=window, rotary=rotary,
+            theta=theta, yarn=yarn, rope_factor=factor, gate=gate,
+            qk_norm=gate is not None)
+    return out
+
+
+def _latent_layer(c: Dict) -> LatentLayer:
+    rope_dim, nope = int(c["qk_rope_head_dim"]), int(c["qk_nope_head_dim"])
+    scaling = c.get("rope_scaling")
+    inv_freq = latent_attention.yarn_inv_freq(
+        rope_dim, float(c.get("rope_theta", 10000.0)), scaling)
+    return LatentLayer(
+        heads=int(c["num_attention_heads"]), q_latent=int(c["q_lora_rank"]),
+        kv_latent=int(c["kv_lora_rank"]), nope=nope, rope_dim=rope_dim,
+        v_head=int(c["v_head_dim"]), inv_freq=tuple(float(f) for f in inv_freq),
+        softmax_scale=latent_attention.yarn_softmax_scale(nope + rope_dim, scaling))
+
+
+def _deltanet_layer(c: Dict) -> DeltaNetLayer:
+    return DeltaNetLayer(
+        k_heads=int(c["linear_num_key_heads"]), v_heads=int(c["linear_num_value_heads"]),
+        dk=int(c["linear_key_head_dim"]), dv=int(c["linear_value_head_dim"]),
+        conv=int(c["linear_conv_kernel_dim"]))
+
+
+def _mamba_layer(c: Dict) -> MambaLayer:
+    if int(c.get("mamba_n_groups", 1)) != 1:
+        raise ValueError("B and C are shared by all heads: mamba_n_groups 1")
+    layer = MambaLayer(
+        heads=int(c["mamba_n_heads"]), head=int(c["mamba_d_head"]),
+        state=int(c["mamba_d_state"]), conv=int(c["mamba_d_conv"]),
+        conv_bias=bool(c.get("mamba_conv_bias", True)),
+        chunk=int(c.get("mamba_chunk_size", 256)))
+    if layer.inner != int(c.get("mamba_expand", 2)) * int(c["hidden_size"]):
+        raise ValueError("mamba_n_heads x mamba_d_head is not the inner width")
+    return layer
+
+
+def _expert_layer(c: Dict, experts: int) -> ExpertLayer:
+    """The expert layer under whichever family's names the config has.
+    SmallThinker (``moe_num_primary_experts``, its own key names): the
+    router on the layer's input, ReGLU, no shared expert. A config with
+    ``moe_routed_scaling_factor`` (Laguna) names its experts as
+    ``qwen3_next`` does and routes as DeepSeek-V3 does without the
+    selection bias. The shared expert: ``qwen3_next`` states its width
+    and gates it; DeepSeek-V3 counts shared experts of the routed
+    width."""
+    primary = "moe_num_primary_experts" in c
+    if primary and not c.get("moe_primary_router_apply_softmax", True):
+        raise ValueError("a primary router without its softmax is not supported")
+    scaled = "moe_routed_scaling_factor" in c
+    stated = "shared_expert_intermediate_size" in c
+    width = int(c["moe_ffn_hidden_size" if primary else "moe_intermediate_size"])
+    # the router scores all experts, this chip holds some
+    first, held = c.get("experts_held") or (0, experts)
+    return ExpertLayer(
+        router_outputs=int(c.get("router_outputs", experts)),
+        first=int(first), held=int(held),
+        top_k=int(c["moe_num_active_primary_experts" if primary
+                    else "num_experts_per_tok"]),
+        norm_topk=bool(c.get("norm_topk_prob", True)),
+        width=width,
+        route_on="input" if primary else "stream",
+        activation="relu" if primary else "silu",
+        scoring=str(c.get("scoring_func", "sigmoid" if scaled else "softmax")),
+        select_bias=c.get("topk_method") == "noaux_tc",
+        scale=float(c.get(
+            "routed_scaling_factor", c.get("moe_routed_scaling_factor", 1.0))),
+        shared_width=int(
+            c["shared_expert_intermediate_size"] if stated
+            else int(c.get("n_shared_experts", 0 if primary else 1)) * width),
+        shared_gated=stated and not scaled,
+    )
+
+
+class Segment(NamedTuple):
+    """One group of the parameter tree: a layer, or a run of ``layers``
+    stacked ones, with its kinds' descriptions."""
+
+    name: str
+    mixer: object
+    ffn: object
+    layers: int
+
+
+def describe(config: Dict) -> Dict:
+    """What ``SequenceLM`` holds of a config, by attribute. Mixers by
+    :func:`layer_types_of`. Feed-forwards by ``mlp_layer_types``
+    (``"dense"`` or ``"sparse"`` a layer) where the config states them,
+    else ``"dense"`` for the first ``first_k_dense_replace`` layers and
+    ``"experts"`` after; a config that counts no experts
+    (``num_local_experts`` 0, or no such key) has NO expert layer. The
+    residual by ``hc_mult``. ``segments``: a run of consecutive layers
+    of one ``stacked`` description is one group ``"layers_<first>_<last>"``,
+    every other layer its own ``"layer_<n>"``. The Granite multipliers,
+    each 1 where the config states none."""
+    c = config
+    layer_types = layer_types_of(c)
+    layers = len(layer_types)
+    attention = attention_layers_of(c, layer_types)
+    others = {LINEAR: _deltanet_layer, LATENT: _latent_layer, MAMBA: _mamba_layer}
+    made = {kind: others[kind](c) for kind in set(layer_types) & set(others)}
+    # experts the config counts, under whichever family's key
+    experts = int(next(
+        (c[k] for k in ("num_experts", "n_routed_experts", "num_local_experts",
+                        "moe_num_primary_experts")
+         if k in c), 0))
+    if experts and "mlp_layer_types" in c:  # stated a layer
+        ffn_types = tuple(
+            DENSE if kind == DENSE else EXPERTS for kind in c["mlp_layer_types"][:layers])
+    else:
+        dense_first = int(c.get("first_k_dense_replace", 0)) if experts else layers
+        ffn_types = tuple(DENSE if i < dense_first else EXPERTS for i in range(layers))
+    ffn_of = {}
+    if DENSE in ffn_types:
+        ffn_of[DENSE] = DenseLayer(
+            int(c.get("shared_intermediate_size", c.get("intermediate_size"))))
+    if EXPERTS in ffn_types:
+        ffn_of[EXPERTS] = _expert_layer(c, experts)
+    lanes = int(c.get("hc_mult", 1))
+    residual = PlainResidual(float(c.get("residual_multiplier", 1.0)))
+    if lanes > 1:
+        if residual.scale != 1.0:
+            raise ValueError("residual_multiplier with hc_mult lanes is not defined")
+        if EXPERTS in ffn_of and ffn_of[EXPERTS].route_on == "input":
+            raise ValueError("a router on the layer's input with hc_mult lanes")
+        residual = HyperResidual(
+            lanes=lanes, rounds=int(c.get("hc_sinkhorn_iters", 20)),
+            eps=float(c.get("hc_eps", 1e-6)),
+            clamp=(float(c.get("mhc_h_res_clamp_min", -30.0)),
+                   float(c.get("mhc_h_res_clamp_max", 30.0))))
+    runs = []
+    for i, kind in enumerate(layer_types):
+        mixer, ffn = attention.get(i) or made[kind], ffn_of[ffn_types[i]]
+        if mixer.stacked and runs and runs[-1][1:3] == [mixer, ffn]:
+            runs[-1][3] += 1
+        else:
+            runs.append([i, mixer, ffn, 1])
+    return dict(
+        layer_types=layer_types, ffn_types=ffn_types, residual=residual,
+        segments=tuple(
+            Segment(f"layers_{i}_{i + n - 1}" if mixer.stacked else f"layer_{i}",
+                    mixer, ffn, n) for i, mixer, ffn, n in runs),
+        hidden=int(c["hidden_size"]), positions=int(c["max_position_embeddings"]),
+        eps=float(c.get("rms_norm_eps", 1e-6)),
+        embed_scale=float(c.get("embedding_multiplier", 1.0)),
+        logits_scale=float(c.get("logits_scaling", 1.0)),
+        tied_head=bool(c.get("tie_word_embeddings", False)))

@@ -1,0 +1,809 @@
+"""The kinds of layer: the protocol (:class:`Kind`), what every kind is
+written in (the norms, the causal convolution, RoPE, the bfloat16
+product), the reductions a kind's statistics declare, and the mixers,
+the feed-forwards and the residuals themselves, each with its published
+source and arithmetic on its description."""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.ops import (
+    cached_attention, deltanet, hyper_connection, latent_attention, moe, ssd)
+from ray_tpu.telemetry import metrics
+
+HI = jax.lax.Precision.HIGHEST
+
+
+class Kind:
+    """One kind of mixer, feed-forward or residual: a frozen description
+    of one layer, made by ``config.describe`` (hashable: a checkpointed
+    block takes it as a static argument), that answers the questions
+    ``SequenceLM`` asks of any kind; here, what one with nothing of its
+    own answers. A mixer or feed-forward also has ``apply(p, x, state,
+    ctx) -> (y, new state, stats)`` on the normed stream ``x`` ``(B, T,
+    D)``; ``ctx`` holds the fragment's rows (``seg``, ``fresh``,
+    ``positions`` ``(B, T)``, ``pos0`` ``(B,)``), the ``scope`` prefix,
+    the operands' ``dtype``, the norms' ``eps`` and the DeltaNet
+    ``chunk``."""
+
+    # a run of consecutive such layers is ONE group of the parameter
+    # tree, its leaves on a leading layer axis
+    stacked = False
+    # whether a new episode zeroes the state leaves (a recurrent matrix
+    # is; a cache is not: only slots below the position are ever read)
+    cleared_on_reset = False
+    # ``{leaf: f(key, shape)}`` where the shared ladder is wrong for a
+    # leaf; ``shape`` carries a stacked run's leading layer axis
+    init_rules = {}
+    # the keys of ``apply``'s statistics, each with its ``REDUCTIONS``
+    stats = {}
+
+    def param_shapes(self, hidden: int):
+        """The leaves of the layer's group that are this kind's."""
+        return {}
+
+    def state_shapes(self, streams: int, positions: int, dtype):
+        """``[(shape, dtype)]`` of its leaves of the state tuple."""
+        return []
+
+
+def dot(x, w, dtype):
+    """``x @ w`` on ``dtype`` operands, accumulated in float32."""
+    return jnp.dot(x.astype(dtype), w.astype(dtype), preferred_element_type=jnp.float32)
+
+
+def rms(x, weight, eps, centred=True):
+    x = x.astype(jnp.float32)
+    y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return y * ((1.0 + weight) if centred else weight)
+
+
+def l2norm(x, eps=1e-6):
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+def causal_conv(tail, x, seg, kernel, bias=None):
+    """Causal depthwise convolution + SiLU over the stored inputs
+    ``tail`` ``(B, width - 1, C)`` and the fragment's own ``x`` ``(B,
+    T, C)``; an input of an earlier episode (``seg`` ``(B, T)``, the
+    episode's number inside the fragment) is not seen. ``kernel`` ``(C,
+    width)``. Returns the output and the last ``width - 1`` inputs of
+    the fragment's last episode."""
+    b, t, _ = x.shape
+    width = kernel.shape[-1]
+    full = jnp.concatenate([tail, x], axis=1)  # (B, T+w-1, C)
+    full_seg = jnp.concatenate(
+        [jnp.zeros((b, width - 1), seg.dtype), seg], axis=1
+    )
+    conv = jnp.zeros_like(x)
+    for back in range(width):
+        lo = width - 1 - back
+        seen = (full_seg[:, lo : lo + t] == seg)[..., None]
+        conv = conv + jnp.where(
+            seen, full[:, lo : lo + t], 0.0
+        ) * kernel[:, width - 1 - back]
+    if bias is not None:
+        conv = conv + bias
+    out = jax.nn.silu(conv)
+    live = (full_seg[:, t:] == seg[:, -1:])[..., None]
+    return out, jnp.where(live, full[:, t:], 0.0)
+
+
+def rope(x, positions, rotary: int, theta: float, yarn=(), factor: float = 1.0):
+    """Rotate the first ``rotary`` dimensions of each head (the
+    rotate-half form). ``x`` ``(B, T, H, D)``, ``positions`` ``(B, T)``.
+    ``yarn`` (the items of a YaRN block: ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``)
+    scales the frequencies as ``ops/latent_attention.yarn_inv_freq``
+    does; ``factor`` multiplies ``cos`` and ``sin`` (YaRN's
+    ``attention_factor``), so the turned dimensions alone carry it."""
+    half = rotary // 2
+    if yarn:
+        inv = jnp.asarray(latent_attention.yarn_inv_freq(rotary, theta, dict(yarn)))
+    else:
+        inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary)
+    angle = positions.astype(jnp.float32)[..., None] * inv  # (B, T, half)
+    cos, sin = jnp.cos(angle)[:, :, None], jnp.sin(angle)[:, :, None]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    x1, x2, rest = x[..., :half], x[..., half:rotary], x[..., rotary:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1
+    )
+
+
+# -- statistics -----------------------------------------------------------
+#
+# A kind hands ``apply`` a dict of statistics and declares, a key, how
+# the values of several calls become one: ``(over groups of streams,
+# over layers)``. The first is what ``run_block``'s ``lax.map`` and
+# ``reduce_group_stats`` apply to values stacked on a leading axis, the
+# second what the layer loop applies to the values of the layers that
+# reported the key (a run's scan has stacked them already).
+
+_keep = lambda a: a
+REDUCTIONS = {
+    "sum": (lambda a: a.sum(0), lambda a: a.sum(0)),
+    "max": (lambda a: a.max(0), lambda a: a.max(0)),
+    "mean": (lambda a: a.mean(0), lambda a: a.mean(0)),
+    # a row a layer: added up over the streams, kept a layer
+    "layer": (lambda a: a.sum(0), _keep),
+    # a row a token: the groups' tokens one after another, kept a layer
+    "tokens": (lambda a: a.reshape((-1,) + a.shape[2:]), _keep),
+}
+
+
+def over_streams(stats, declared):
+    """``stats`` stacked over groups of streams -> the batch's. A key no
+    kind declares (``reduce_group_stats`` is handed the loss's own beside
+    the model's, and the ratios ``apply`` made) is averaged, an
+    ``..._max`` kept at its largest."""
+    return {k: REDUCTIONS[declared.get(
+        k, "max" if k.endswith("_max") else "mean")][0](v) for k, v in stats.items()}
+
+
+def over_layers(stats, declared):
+    """``{key: [(layers, ...) a segment]}`` -> the stack's."""
+    return {k: REDUCTIONS[declared[k]][1](jnp.concatenate(v))
+            for k, v in stats.items()}
+
+
+# -- mixers ---------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class AttentionLayer(Kind):
+    """One softmax-attention layer: what the ``"full_attention"``,
+    ``"attention"`` and ``"sliding_attention"`` kinds of every family
+    differ in (``config.attention_layers_of``). GQA over the layer's own
+    head count, ``q`` and ``k`` RMS-normed over the head where it is
+    gated, RoPE on the head's leading ``rotary`` dimensions (none: no
+    positions), the cache the episode's rows or a ring of ``window``
+    (``ops/cached_attention.py``), the output times a sigmoid gate (a
+    number a dimension out of ``q_proj``, or a number a head from
+    ``g_proj``). No biases.
+
+    - ``"full_attention"`` (Hugging Face ``qwen3_next``,
+      ``Qwen3NextAttention``): one projection gives each head its query
+      and an output gate, ``q`` and ``k`` normed, RoPE on the first
+      ``partial_rotary_factor`` of the head;
+    - ``"attention"`` (``GraniteMoeHybridAttention`` with
+      ``position_embedding_type: nope``): NO positions, no q/k norm, no
+      gate, scores scaled by ``attention_multiplier``;
+    - ``"sliding_attention"`` (SmallThinker's window layers): RoPE
+      (rotate-half) over the WHOLE head, no norm, no gate; a query at
+      position ``p`` sees the keys at ``p - window + 1 .. p`` of its
+      episode and nothing older;
+    - Laguna's layers (``model_type: laguna``): a head count a layer
+      over the same KV heads, a ``rope_parameters`` block a kind (YaRN
+      on part of a full layer's head, ``cos`` and ``sin`` times its
+      ``attention_factor``), and ``gating`` puts a gate a head
+      (``g_proj``) and the q/k norms on EVERY layer, so a gated layer
+      stands on a ring.
+
+    State: keys and values, ``(rows, kv heads x head)`` in the operands'
+    type, one row a position, flat, so that the device tiles (rows, row)
+    without padding 2 heads to 8; keys stored after norm and RoPE (YaRN's
+    factor included); a window layer a RING of ``min(window, positions)``
+    rows whatever the episode's depth (docs/policy_state.md, "The ring").
+
+    Scopes: projections, norms and RoPE under ``attn`` (``swa`` for a
+    window layer), the cached attention's parts under its ``/scatter``,
+    ``/scores`` and ``/out``, the gate under ``/gate``, the output
+    projection under ``/out``."""
+
+    kind: str
+    heads: int
+    kv_heads: int
+    head_dim: int
+    scale: float  # of the scores
+    # a ring of ``min(window, positions)`` rows; None: the episode's rows
+    window: Optional[int] = None
+    # RoPE: the head's leading dimensions it turns (0: no positions),
+    # its base, a YaRN block's items and the factor on cos and sin
+    rotary: int = 0
+    theta: float = 10000.0
+    yarn: Tuple[Tuple[str, float], ...] = ()
+    rope_factor: float = 1.0
+    # the output gate: "element" (``q_proj`` gives every head ``[q |
+    # gate]``, one number a dimension), "head" (``g_proj``, one number a
+    # head and token) or None
+    gate: Optional[str] = None
+    qk_norm: bool = False
+
+    stats = {
+        # rows inside the window a query of a window layer saw
+        "window_rows_seen_mean": "mean",
+        "attn_key_blocks_skipped": "sum", "attn_key_blocks_walked": "sum",
+        "attn_decode_key_blocks_skipped": "sum",
+        "attn_decode_key_blocks_walked": "sum",
+    }
+
+    @property
+    def scope(self) -> str:
+        return "swa" if self.window else "attn"
+
+    @property
+    def rope(self) -> str:
+        return "none" if not self.rotary else "yarn" if self.yarn else "default"
+
+    def cache_rows(self, positions: int) -> int:
+        return positions if self.window is None else min(self.window, positions)
+
+    def param_shapes(self, d: int):
+        wide = self.heads * self.head_dim
+        shapes = dict(
+            # every head's [q | gate] where the gate is a dimension's
+            q_proj=(d, wide * (2 if self.gate == "element" else 1)),
+            k_proj=(d, self.kv_heads * self.head_dim),
+            v_proj=(d, self.kv_heads * self.head_dim),
+            o_proj=(wide, d),
+        )
+        if self.qk_norm:
+            shapes.update(q_norm=(self.head_dim,), k_norm=(self.head_dim,))
+        if self.gate == "head":
+            shapes["g_proj"] = (d, self.heads)
+        return shapes
+
+    def state_shapes(self, streams: int, positions: int, dtype):
+        shape = (streams, self.cache_rows(positions), self.kv_heads * self.head_dim)
+        return [(shape, dtype), (shape, dtype)]
+
+    def apply(self, p, x, state, ctx):
+        scope = ctx["scope"] + self.scope
+        dtype, eps = ctx["dtype"], ctx["eps"]
+        b, t, _ = x.shape
+        h, hkv, d = self.heads, self.kv_heads, self.head_dim
+        metrics.inc_attention_layer_lowering(self.kind, h, self.rope)
+        if self.window is not None:
+            metrics.inc_window_cache_lowering("step" if t == 1 else "fragment")
+        with jax.named_scope(scope):
+            q = dot(x, p["q_proj"], dtype)
+            if self.gate == "element":
+                q = q.reshape(b, t, h, 2 * d)
+                q, gate = q[..., :d], q[..., d:]
+            else:
+                q = q.reshape(b, t, h, d)
+            k = dot(x, p["k_proj"], dtype).reshape(b, t, hkv, d)
+            v = dot(x, p["v_proj"], dtype).reshape(b, t, hkv, d)
+
+            def normed_and_turned(z, norm):
+                if self.qk_norm:
+                    z = rms(z, p[norm], eps)
+                if self.rotary:
+                    z = rope(z, ctx["positions"], self.rotary, self.theta,
+                             self.yarn, self.rope_factor)
+                return z
+
+            q, k = normed_and_turned(q, "q_norm"), normed_and_turned(k, "k_norm")
+        o, new, stats = cached_attention.cached_attention(
+            q, k, v, state, ctx, scale=self.scale, window=self.window,
+            dtype=dtype, scope=scope)
+        if "pairs_seen" in stats:
+            stats["window_rows_seen_mean"] = stats.pop("pairs_seen") / (b * t)
+        if self.gate is not None:
+            with jax.named_scope(scope + "/gate"):
+                if self.gate == "head":
+                    gate = dot(x, p["g_proj"], dtype)[..., None]  # (B, T, H, 1)
+                o = o * jax.nn.sigmoid(gate)
+        with jax.named_scope(scope + "/out"):
+            return dot(o.reshape(b, t, h * d), p["o_proj"], dtype), new, stats
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentLayer(Kind):
+    """``"latent_attention"`` (DeepSeek-V3's ``DeepseekV3Attention``,
+    arXiv:2412.19437, with YaRN as that file applies it): ``c_q = rms(x
+    W_qa)``, ``q = c_q W_qb`` per head ``[q_nope | q_pe]``; ``[c_kv |
+    k_pe] = x W_kva``, ``c_kv = rms(c_kv)``, one roped ``k_pe`` for all
+    heads; ``[k_nope | v]`` per head ``= c_kv W_kvb``; ``score = (q_nope .
+    k_nope + rope(q_pe) . k_pe) * s``, ``s = (nope + rope)^-1/2 * m^2``,
+    causal softmax, ``o = P v``, output projection. The query/key product
+    is ``nope + rope`` wide, the value product ``v_head``
+    (``ops/latent_attention.py`` holds its three forms and picks).
+
+    State: ONE leaf of latent rows, ``(positions, kv_latent + rope_dim)``
+    in the operands' type: the normed latent and the roped key part,
+    whatever the head count. Scopes: ``mla`` and its ``/absorb``,
+    ``/scores``, ``/out``."""
+
+    heads: int
+    q_latent: int
+    kv_latent: int
+    nope: int
+    rope_dim: int
+    v_head: int
+    inv_freq: Tuple[float, ...]  # ``yarn_inv_freq`` of the rope part
+    softmax_scale: float
+
+    stats = {"attn_key_blocks_skipped": "sum", "attn_key_blocks_walked": "sum"}
+
+    def param_shapes(self, d: int):
+        h, row = self.heads, self.kv_latent + self.rope_dim
+        return dict(
+            q_a=(d, self.q_latent),
+            q_a_norm=(self.q_latent,),
+            q_b=(self.q_latent, h * (self.nope + self.rope_dim)),
+            kv_a=(d, row),
+            kv_a_norm=(self.kv_latent,),
+            kv_b=(self.kv_latent, h * (self.nope + self.v_head)),
+            o_proj=(h * self.v_head, d),
+        )
+
+    def state_shapes(self, streams: int, positions: int, dtype):
+        return [((streams, positions, self.kv_latent + self.rope_dim), dtype)]
+
+    def apply(self, p, x, state, ctx):
+        with jax.named_scope(ctx["scope"] + "mla"):
+            (cache,) = state
+            dtype, eps, positions = ctx["dtype"], ctx["eps"], ctx["positions"]
+            b, t, _ = x.shape
+            h, dn, c = self.heads, self.nope, self.kv_latent
+            inv_freq = np.asarray(self.inv_freq, np.float32)
+            c_q = rms(dot(x, p["q_a"], dtype), p["q_a_norm"], eps)
+            q = dot(c_q, p["q_b"], dtype).reshape(b, t, h, dn + self.rope_dim)
+            q_pe = latent_attention.rope(q[..., dn:], positions, inv_freq)
+            kv = dot(x, p["kv_a"], dtype)
+            k_pe = latent_attention.rope(
+                kv[:, :, None, c:], positions, inv_freq)[:, :, 0]
+            rows_new = jnp.concatenate(
+                [rms(kv[..., :c], p["kv_a_norm"], eps), k_pe], axis=-1
+            ).astype(cache.dtype)
+            o, new_cache, stats = latent_attention.latent_attention(
+                q[..., :dn], q_pe, rows_new, cache, p["kv_b"], ctx,
+                scale=self.softmax_scale, dtype=dtype)
+            return (dot(o.reshape(b, t, h * self.v_head), p["o_proj"], dtype),
+                    (new_cache,), stats)
+
+
+_ones = lambda key, shape: jnp.ones(shape, jnp.float32)
+
+
+@dataclasses.dataclass(frozen=True)
+class DeltaNetLayer(Kind):
+    """``"linear_attention"`` (``Qwen3NextGatedDeltaNet``; Yang et al.,
+    arXiv:2412.06464): one projection gives ``q, k, v, z``, another ``b,
+    a``; a causal depthwise convolution (width ``conv``) and SiLU over
+    the channels of ``(q, k, v)``; ``beta = sigmoid(b)``, ``g =
+    -exp(A_log) * softplus(a + dt_bias)``; ``q`` and ``k`` L2-normalised;
+    the gated delta rule (``ops/deltanet.py``: one token the step, a
+    fragment in chunks) per value head; ``rms(o) * w * silu(z)`` per
+    head, then the output projection. Starts with ``A`` uniform in (1,
+    16) and ``dt_bias`` one.
+
+    State: the ``(value heads, dk, dv)`` float32 matrix and the last
+    ``conv - 1`` inputs of the convolution. Scope: ``linear_attn``."""
+
+    k_heads: int
+    v_heads: int
+    dk: int
+    dv: int
+    conv: int
+
+    cleared_on_reset = True
+    init_rules = {
+        "A_log": lambda key, shape: jnp.log(
+            jax.random.uniform(key, shape, minval=1.0, maxval=16.0)),
+        "dt_bias": _ones, "gdn_norm": _ones}
+
+    @property
+    def widths(self):
+        """Of the keys, the values and the convolution's channels."""
+        kd, vd = self.k_heads * self.dk, self.v_heads * self.dv
+        return kd, vd, 2 * kd + vd
+
+    def param_shapes(self, d: int):
+        kd, vd, conv_dim = self.widths
+        return dict(
+            in_proj_qkvz=(d, 2 * kd + 2 * vd),
+            in_proj_ba=(d, 2 * self.v_heads),
+            conv=(conv_dim, self.conv),
+            A_log=(self.v_heads,),
+            dt_bias=(self.v_heads,),
+            gdn_norm=(self.dv,),
+            out_proj=(vd, d),
+        )
+
+    def state_shapes(self, streams: int, positions: int, dtype):
+        return [((streams, self.v_heads, self.dk, self.dv), jnp.float32),
+                ((streams, self.conv - 1, self.widths[2]), jnp.float32)]
+
+    def apply(self, p, x, state, ctx):
+        with jax.named_scope(ctx["scope"] + "linear_attn"):
+            s0, tail = state
+            dtype = ctx["dtype"]
+            b, t, _ = x.shape
+            (kd, vd, _), hv, hk = self.widths, self.v_heads, self.k_heads
+            qkvz = dot(x, p["in_proj_qkvz"], dtype)
+            mixed, z = qkvz[..., : 2 * kd + vd], qkvz[..., 2 * kd + vd :]
+            ba = jnp.dot(x, p["in_proj_ba"], precision=HI)
+            beta = jax.nn.sigmoid(ba[..., :hv])
+            g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[..., hv:] + p["dt_bias"])
+
+            mixed, new_tail = causal_conv(tail, mixed, ctx["seg"], p["conv"])
+
+            q = mixed[..., :kd].reshape(b, t, hk, self.dk)
+            k = mixed[..., kd : 2 * kd].reshape(b, t, hk, self.dk)
+            v = mixed[..., 2 * kd :].reshape(b, t, hv, self.dv)
+            q = l2norm(q) * (self.dk ** -0.5)
+            k = l2norm(k)
+            q = jnp.repeat(q, hv // hk, axis=2)
+            k = jnp.repeat(k, hv // hk, axis=2)
+            if t == 1:
+                s1, o = deltanet.gated_delta_step(
+                    s0, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0]
+                )
+                o = o[:, None]
+            else:
+                o, s1 = deltanet.gated_delta_chunked(
+                    s0, q, k, v, g, beta,
+                    resets=ctx["fresh"].astype(jnp.float32),
+                    chunk=ctx["chunk"],
+                )
+            o = rms(o, p["gdn_norm"], ctx["eps"], centred=False)
+            o = o * jax.nn.silu(z.reshape(b, t, hv, self.dv))
+            return dot(o.reshape(b, t, vd), p["out_proj"], dtype), (s1, new_tail), {}
+
+
+def _inverse_softplus_of_a_step(key, shape):
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, minval=np.log(1e-3), maxval=np.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+@dataclasses.dataclass(frozen=True)
+class MambaLayer(Kind):
+    """``"mamba"`` (Mamba-2 as Hugging Face ``granitemoehybrid`` holds
+    it; Dao & Gu, arXiv:2405.21060): ``[z | x | B | C | dt] = h W_in``
+    (no bias); a causal depthwise convolution (width ``conv``) WITH a
+    bias and SiLU over the channels of ``(x, B, C)``; ``dt = softplus(dt
+    + dt_bias)``, ``A = -exp(A_log)`` a head; per head ``S <- exp(dt A) S
+    + dt x B^T``, ``y = S C + D x`` (``ops/ssd.py``; ``B`` and ``C`` are
+    shared by every head: ``mamba_n_groups`` 1); ``rms(y * silu(z)) * w``
+    over the whole inner width, then the output projection. Starts as
+    the family's does: ``A`` 1..heads, ``D`` one, ``dt_bias`` the inverse
+    softplus of a log-uniform step in (0.001, 0.1).
+
+    A run of consecutive layers is one stacked group. State, a RUN: two
+    leaves with the layer axis after the stream's, the ``(layers, heads,
+    head, state)`` float32 matrices and the last ``conv - 1`` inputs of
+    each convolution (the one-token form is handed the run's matrices
+    whole with the layer's index and updates that layer where it lies,
+    the fragment form scans over them). Scopes: ``ssm/in``, ``ssm/conv``,
+    ``ssm/step``, ``ssm/out``."""
+
+    heads: int
+    head: int
+    state: int
+    conv: int
+    conv_bias: bool
+    # how the published code computes a fragment, not a width
+    chunk: int
+
+    stacked = True
+    cleared_on_reset = True
+    init_rules = {
+        "A_log": lambda key, shape: jnp.broadcast_to(
+            jnp.log(jnp.arange(1, shape[-1] + 1, dtype=jnp.float32)), shape),
+        "D": _ones, "dt_bias": _inverse_softplus_of_a_step,
+        "conv": lambda key, shape: (
+            jax.random.normal(key, shape, jnp.float32) / np.sqrt(shape[-1])),
+    }
+    # the largest step size a stream saw
+    stats = {"ssm_dt_max": "max"}
+
+    @property
+    def inner(self) -> int:
+        return self.heads * self.head
+
+    @property
+    def conv_dim(self) -> int:
+        return self.inner + 2 * self.state
+
+    def param_shapes(self, d: int):
+        shapes = dict(
+            # columns [z | x | B | C | dt]
+            in_proj=(d, 2 * self.inner + 2 * self.state + self.heads),
+            conv=(self.conv_dim, self.conv),
+            dt_bias=(self.heads,),
+            A_log=(self.heads,),
+            D=(self.heads,),
+            ssm_norm=(self.inner,),
+            out_proj=(self.inner, d),
+        )
+        if self.conv_bias:
+            shapes["conv_bias"] = (self.conv_dim,)
+        return shapes
+
+    def state_shapes(self, streams: int, positions: int, dtype):
+        return [((streams, self.heads, self.head, self.state), jnp.float32),
+                ((streams, self.conv - 1, self.conv_dim), jnp.float32)]
+
+    def apply(self, p, x, state, ctx):
+        scope = ctx["scope"] + "ssm"
+        dtype = ctx["dtype"]
+        s0, tail = state
+        b, t, _ = x.shape
+        inner, n, heads = self.inner, self.state, self.heads
+        with jax.named_scope(scope + "/in"):
+            zxbcdt = dot(x, p["in_proj"], dtype)
+            z = zxbcdt[..., :inner]
+            mixed = zxbcdt[..., inner : inner + self.conv_dim]
+            dt = jax.nn.softplus(zxbcdt[..., inner + self.conv_dim :] + p["dt_bias"])
+            a = -jnp.exp(p["A_log"])
+        with jax.named_scope(scope + "/conv"):
+            mixed, new_tail = causal_conv(
+                tail, mixed, ctx["seg"], p["conv"], p.get("conv_bias"))
+        with jax.named_scope(scope + "/step"):
+            xs = mixed[..., :inner].reshape(b, t, heads, self.head)
+            bt, ct = mixed[..., inner : inner + n], mixed[..., inner + n :]
+            if t == 1:  # the run's stacked matrices and this layer's index
+                stacked, layer = s0
+                s1, y = ssd.ssd_step(
+                    stacked, xs[:, 0], dt[:, 0], a, bt[:, 0], ct[:, 0], layer=layer)
+                y = y[:, None]
+            else:
+                y, s1 = ssd.ssd_chunked(
+                    s0, xs, dt, a, bt, ct,
+                    resets=ctx["fresh"].astype(jnp.float32), chunk=self.chunk,
+                )
+            y = (y + p["D"][:, None] * xs).reshape(b, t, inner)
+        with jax.named_scope(scope + "/out"):
+            y = rms(y * jax.nn.silu(z), p["ssm_norm"], ctx["eps"])
+            return (dot(y, p["out_proj"], dtype), (s1, new_tail),
+                    {"ssm_dt_max": jnp.max(dt)})
+
+
+# -- feed-forwards --------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class DenseLayer(Kind):
+    """``"dense"``: SwiGLU of width ``intermediate_size``
+    (``shared_intermediate_size`` where the config states one). Scope:
+    ``mlp``."""
+
+    width: int
+
+    route_on = None  # no router
+
+    def param_shapes(self, d: int):
+        w = self.width
+        return dict(mlp_gate=(d, w), mlp_up=(d, w), mlp_down=(w, d))
+
+    def apply(self, p, x, state, ctx):
+        with jax.named_scope(ctx["scope"] + "mlp"):
+            b, t, d = x.shape
+            out = moe.gated_mlp(
+                x.reshape(b * t, d), p["mlp_gate"], p["mlp_up"], p["mlp_down"],
+                dtype=ctx["dtype"],
+            )
+        return out.reshape(b, t, d), (), {}
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertLayer(Kind):
+    """``"experts"``: a router over ALL ``router_outputs`` experts, the
+    ``held`` experts this chip holds from ``first`` on (``ops/moe.py``)
+    and a shared expert of ``shared_width`` (0: none, the result is the
+    routed sum alone). What the families differ in is the fields:
+
+    - ``qwen3_next``: softmax, top-k, renormalised, the shared expert
+      times ``sigmoid(x w_s)`` (``shared_gated``);
+    - DeepSeek-V3 (``scoring_func: sigmoid``, ``topk_method:
+      noaux_tc``): a sigmoid each, the top-k of ``score + select_bias``
+      chosen, weights the scores without the bias over their sum times
+      ``scale``, the shared expert ungated. ``select_bias`` is a buffer:
+      it lies in the parameter tree, starts small and not zero, and the
+      model reads it through ``stop_gradient``;
+    - SmallThinker: softmax, top-k, renormalised like ``qwen3_next``,
+      but the router reads the LAYER'S INPUT, un-normed and before the
+      mixer runs (``route_on: "input"``; the experts still take ``rms``
+      of the stream after the mixer), the experts gate with ReLU
+      (ReGLU) where the others gate with SiLU, and there is NO shared
+      expert;
+    - Laguna: a sigmoid each, top-k with no selection bias, the chosen
+      scores over their sum times ``scale``, the shared expert UNGATED.
+
+    Scopes: ``moe/route`` (entered before the mixer where the router
+    reads the input), ``moe/experts``, ``moe/shared``."""
+
+    router_outputs: int
+    first: int
+    held: int
+    top_k: int
+    norm_topk: bool
+    width: int
+    route_on: str = "stream"  # or "input": the block's, before the mixer
+    activation: str = "silu"
+    scoring: str = "softmax"
+    select_bias: bool = False
+    scale: float = 1.0
+    shared_width: int = 0
+    shared_gated: bool = False
+
+    init_rules = {
+        "select_bias": lambda key, shape: 0.01 * jax.random.normal(key, shape)}
+    stats = {
+        # tokens each held expert got: ``(held,)`` a layer, and at each
+        # place of the fragment over the streams, ``(T, held)``
+        "moe_held_load": "layer", "moe_place_load": "layer",
+        "moe_slots_on_absent_experts": "sum",
+        # of the dense form's (token, held expert) rows, those the
+        # experts' products computed (the grouped form: its buffers)
+        "moe_rows_computed_share": "mean",
+        # every token's expert set
+        "moe_routes": "tokens",
+    }
+
+    def param_shapes(self, d: int):
+        e, f, fs = self.held, self.width, self.shared_width
+        shapes = dict(
+            router=(d, self.router_outputs),
+            experts_gate=(e, d, f),
+            experts_up=(e, d, f),
+            experts_down=(e, f, d),
+        )
+        if fs:
+            shapes.update(shared_gate=(d, fs), shared_up=(d, fs), shared_down=(fs, d))
+        if self.shared_gated:
+            shapes["shared_expert_gate"] = (d, 1)
+        if self.select_bias:
+            shapes["select_bias"] = (self.router_outputs,)
+        return shapes
+
+    def route(self, p, flat):
+        """Every token's ``(indices, weights)`` from ``flat`` ``(tokens,
+        D)``: the expert layer's input, or the block's where the router
+        stands before the mixer."""
+        return moe.route_top_k(
+            flat, p["router"], self.top_k, self.norm_topk,
+            scoring=self.scoring, select_bias=p.get("select_bias"),
+            scale=self.scale,
+        )
+
+    def apply(self, p, x, state, ctx):
+        b, t, d = x.shape
+        flat = x.reshape(b * t, d)
+        scope, dtype = ctx["scope"], ctx["dtype"]
+        held = self.held
+        # a token of each stream: dense; a fragment of each: grouped
+        lowering = moe.product_lowering(b * t, self.top_k, self.router_outputs)
+        metrics.inc_moe_product_lowering(lowering)
+        with jax.named_scope(scope + "moe/route"):
+            # routed already where the router reads the block's input
+            indices, weights = ctx.get("route") or self.route(p, flat)
+            if lowering == "dense":
+                combine = moe.held_combine_weights(indices, weights, self.first, held)
+            per_expert, absent = moe.expert_load(indices, self.first, held)
+            local = indices.reshape(b, t, -1) - self.first
+            stats = {
+                "moe_held_load": per_expert,
+                "moe_place_load": jnp.sum(
+                    local[..., None] == jnp.arange(held, dtype=jnp.int32),
+                    axis=(0, 2), dtype=jnp.float32),
+                "moe_slots_on_absent_experts": absent,
+                "moe_rows_computed_share": moe.rows_computed(
+                    per_expert, b * t, self.top_k, self.router_outputs, lowering
+                ) / (b * t * held),
+                "moe_routes": indices,
+            }
+        experts = (p["experts_gate"], p["experts_up"], p["experts_down"])
+        with jax.named_scope(scope + "moe/experts"):
+            if lowering == "dense":
+                routed = moe.dense_experts_product(
+                    flat, *experts, combine, dtype=dtype, activation=self.activation)
+            else:
+                routed = moe.grouped_experts_product(
+                    flat, *experts, indices, weights, per_expert,
+                    self.first, self.router_outputs, dtype=dtype,
+                    activation=self.activation,
+                )
+        if not self.shared_width:  # the routed sum alone
+            return routed.reshape(b, t, d), (), stats
+        with jax.named_scope(scope + "moe/shared"):
+            shared = moe.gated_mlp(
+                flat, p["shared_gate"], p["shared_up"], p["shared_down"], dtype=dtype)
+            if self.shared_gated:
+                shared = shared * jax.nn.sigmoid(
+                    jnp.dot(flat, p["shared_expert_gate"], precision=HI))
+        return (routed + shared).reshape(b, t, d), (), stats
+
+
+# -- residuals ------------------------------------------------------------
+
+_NORM = {"mixer": "input_norm", "ffn": "post_norm"}
+
+
+@dataclasses.dataclass(frozen=True)
+class PlainResidual(Kind):
+    """``"plain"``: ``x <- x + scale * F(rms(x))`` (``scale``: Granite's
+    ``residual_multiplier``). A block's saved input is one hidden row a
+    token and the batch's fit, so the learn form groups the streams
+    inside each block."""
+
+    scale: float = 1.0
+
+    groups_the_loss = False
+    learn_streams = 16
+
+    def enter(self, x):
+        return x
+
+    def leave(self, x):
+        return x
+
+    def around(self, x, p, sub, f, ctx):
+        y, new, stats = f(rms(x, p[_NORM[sub]], ctx["eps"]))
+        return x + (y if self.scale == 1.0 else y * self.scale), new, stats
+
+
+@dataclasses.dataclass(frozen=True)
+class HyperResidual(Kind):
+    """``"hyper_connection"`` (``hc_mult`` lanes; manifold-constrained
+    hyper-connections, arXiv:2512.24880; ``ops/hyper_connection.py``):
+    the stream is ``lanes`` lanes, held flat, ``X <- H_res X + H_post^T
+    F(rms(H_pre X))`` with the maps made from the token's own stream and
+    ``H_res`` through ``rounds`` Sinkhorn rounds. The embedding is copied
+    into the lanes and the lanes are summed before the final norm. A
+    layer starts near the plain residual (``a`` 0.01, ``b_res`` twice the
+    identity). A token's saved row is ``lanes`` times as wide and the
+    batch's no longer fit beside the weights, so the policy groups the
+    streams around the whole loss, half as many at a time. Scope:
+    ``hc``."""
+
+    lanes: int
+    rounds: int
+    eps: float
+    clamp: Tuple[float, float]
+
+    groups_the_loss = True
+    learn_streams = 8
+    # how far H_res is from doubly stochastic, over tokens and sublayers
+    stats = {"hc_res_row_sum_err_max": "max", "hc_res_col_sum_err_max": "max"}
+
+    @property
+    def init_rules(self):
+        n = self.lanes
+        small = lambda key, shape: jnp.full(shape, 0.01, jnp.float32)
+        near_plain = lambda key, shape: jnp.concatenate(
+            [jnp.zeros((2 * n,)), 2.0 * jnp.eye(n).ravel()]).astype(jnp.float32)
+        return {f"hc_{sub}_{leaf}": rule for sub in _NORM
+                for leaf, rule in (("a", small), ("b", near_plain))}
+
+    def param_shapes(self, d: int):
+        n = self.lanes
+        shapes = {}
+        for sub in _NORM:
+            shapes[f"hc_{sub}_norm"] = (n * d,)
+            shapes[f"hc_{sub}_phi"] = (n * d, 2 * n + n * n)
+            shapes[f"hc_{sub}_a"] = (3,)
+            shapes[f"hc_{sub}_b"] = (2 * n + n * n,)
+        return shapes
+
+    def enter(self, x):
+        return jnp.tile(x, (1, 1, self.lanes))
+
+    def leave(self, x):
+        return sum(hyper_connection.lanes_of(x, self.lanes))
+
+    def around(self, x, p, sub, f, ctx):
+        hc = lambda: jax.named_scope(ctx["scope"] + "hc")
+        with hc():
+            pre, post, res = hyper_connection.maps(
+                x, p[f"hc_{sub}_norm"], p[f"hc_{sub}_phi"], p[f"hc_{sub}_a"],
+                p[f"hc_{sub}_b"], self.lanes, ctx["eps"], self.rounds, self.eps,
+                *self.clamp, unroll=x.shape[1] == 1,
+            )
+            h = hyper_connection.mix_in(x, pre)
+        y, new, stats = f(rms(h, p[_NORM[sub]], ctx["eps"]))
+        with hc():
+            stats = dict(
+                stats,
+                hc_res_row_sum_err_max=jnp.max(jnp.abs(res.sum(-1) - 1.0)),
+                hc_res_col_sum_err_max=jnp.max(jnp.abs(res.sum(-2) - 1.0)))
+            return hyper_connection.mix_out(x, y, post, res), new, stats

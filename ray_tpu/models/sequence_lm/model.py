@@ -1,0 +1,373 @@
+"""``SequenceLM``: what is the model's and not a kind's (see the
+package's docstring)."""
+
+from __future__ import annotations
+
+from typing import Dict, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.models.sequence_lm.config import Segment, describe
+from ray_tpu.models.sequence_lm.kinds import HI, dot, over_layers, over_streams, rms
+
+# a stacked run's leaves that enter a bfloat16 product
+_RUN_PRODUCT_LEAVES = ("in_proj", "out_proj", "mlp_gate", "mlp_up", "mlp_down")
+
+
+def _shared_init(key, leaf: str, shape, rank: int):
+    """The ladder every kind shares: vectors zero (zero-centred norm
+    weights, biases), the embedding normal, matrices normal of variance
+    1 / rows. ``rank``: the leaf's own, without a run's layer axis."""
+    if rank == 1:
+        return jnp.zeros(shape, jnp.float32)
+    x = jax.random.normal(key, shape, jnp.float32)
+    return x if leaf == "embedding" else x / np.sqrt(shape[-2])
+
+
+class SequenceLM:
+    """Duck-typed :class:`~ray_tpu.models.base.RTModel` surface over
+    plain-dict params. Registered via ``model_config["use_sequence_lm"]``
+    with the architecture under ``model_config["sequence_lm"]``
+    (``models/catalog.py``). ``num_outputs`` is the vocabulary held;
+    ``dtype`` (``model_config["dtype"]``) the operands' and the cache's."""
+
+    is_recurrent = True
+    supports_stored_train_state = True
+    # apply() names its scopes under a given prefix and hands the
+    # expert-load counts out through ``stats_out``
+    train_stats = True
+    _partition_rules_override = None
+    # tokens a DeltaNet chunk solves at once (a constant; tests shrink it)
+    chunk = 64
+
+    def __init__(self, num_outputs: int, config: Dict, dtype: str = "bfloat16"):
+        self.config = dict(config)
+        self.vocab = int(num_outputs)
+        # layer_types, ffn_types, segments, residual, hidden, positions,
+        # eps, embed_scale, logits_scale, tied_head
+        vars(self).update(describe(self.config))
+        # streams of a fragment batch the learn form runs at once (tests
+        # shrink it)
+        self.learn_streams = self.residual.learn_streams
+        # operands of the projections, the expert products, the head
+        # and the attention products; the cache's dtype
+        self.dtype = jnp.dtype(dtype)
+
+    def partition_rules(self):
+        return None
+
+    @property
+    def _reductions(self) -> Dict[str, str]:
+        """Every statistic a kind of this model declares."""
+        kinds = [self.residual] + [k for s in self.segments for k in (s.mixer, s.ffn)]
+        return {k: v for kind in kinds for k, v in kind.stats.items()}
+
+    # -- state -----------------------------------------------------------
+
+    def _state_shapes(self, seg: Segment, streams: int):
+        """A segment's leaves; a stacked run's with the layer axis after
+        the stream's."""
+        shapes = seg.mixer.state_shapes(streams, self.positions, self.dtype)
+        if seg.mixer.stacked:
+            shapes = [(s[:1] + (seg.layers,) + s[1:], t) for s, t in shapes]
+        return shapes
+
+    def _by_segment(self, state):
+        """``(segment, its leaves of state)`` in order."""
+        lo = 0
+        for seg in self.segments:
+            hi = lo + len(self._state_shapes(seg, 1))
+            yield seg, tuple(state[lo:hi])
+            lo = hi
+
+    def initial_state(self, batch_size: int = 1):
+        state = [jnp.zeros(shape, dtype) for seg in self.segments
+                 for shape, dtype in self._state_shapes(seg, int(batch_size))]
+        state.append(jnp.zeros((int(batch_size),), jnp.int32))
+        return tuple(state)
+
+    def reset_state(self, state, mask):
+        """Open a new episode on the rows of ``mask``: the leaves a kind
+        clears on a reset (the DeltaNet and state-space matrices, the
+        convolution inputs) and the position go to zero; a key/value or
+        latent cache is left as it is, since only slots below the
+        position are ever read (of a ring: slots whose row's position,
+        recovered from the slot and the stream's position, is not
+        negative; what an earlier episode left in the others is written
+        over before it is read)."""
+        out = []
+        for seg, leaves in self._by_segment(state):
+            for leaf in leaves:
+                if seg.mixer.cleared_on_reset:
+                    m = mask.reshape((-1,) + (1,) * (leaf.ndim - 1))
+                    leaf = jnp.where(m, jnp.zeros_like(leaf), leaf)
+                out.append(leaf)
+        out.append(jnp.where(mask, 0, state[-1]))
+        return tuple(out)
+
+    # -- parameters ------------------------------------------------------
+
+    def param_shapes(self) -> Dict[str, Dict[str, tuple]]:
+        d, v = self.hidden, self.vocab
+        shapes = {
+            "embed": {"embedding": (v, d)},
+            "final_norm": {"weight": (d,)},
+            "head": {"kernel": (d, v)},
+            "value": {"kernel": (d, 1), "bias": (1,)},
+        }
+        if self.tied_head:
+            del shapes["head"]
+        for seg in self.segments:
+            layer = {"input_norm": (d,), "post_norm": (d,)}
+            for kind in (seg.ffn, self.residual, seg.mixer):
+                layer.update(kind.param_shapes(d))
+            if seg.mixer.stacked:
+                layer = {k: (seg.layers,) + shape for k, shape in layer.items()}
+            shapes[seg.name] = layer
+        return shapes
+
+    def init(self, rng, obs=None, state=None, **_):
+        """Each leaf by its kind's ``init_rules`` where it has one, else
+        by the shared ladder: the published initialisation's forms at a
+        scale that keeps the activations of a random model of order
+        one."""
+        shapes = self.param_shapes()
+        rules = {seg.name: {**seg.ffn.init_rules, **self.residual.init_rules,
+                            **seg.mixer.init_rules} for seg in self.segments}
+        stacked = {seg.name for seg in self.segments if seg.mixer.stacked}
+
+        @jax.jit
+        def make(key):
+            # XLA's bit generator: a threefry stream for every leaf of a
+            # model this size compiles for half a minute on a TPU
+            key = jax.random.wrap_key_data(
+                jnp.tile(jax.random.key_data(key).astype(jnp.uint32).ravel(), 2)[:4],
+                impl="rbg",
+            )
+            out, count = {}, 0
+            for group in sorted(shapes):
+                out[group] = {}
+                for leaf, shape in sorted(shapes[group].items()):
+                    k = jax.random.fold_in(key, count)
+                    count += 1
+                    rule = rules.get(group, {}).get(leaf)
+                    out[group][leaf] = rule(k, shape) if rule else _shared_init(
+                        k, leaf, shape, len(shape) - (group in stacked))
+            return out
+
+        return make(rng)
+
+    # -- the learn form's grouping ---------------------------------------
+
+    def loss_groups(self, unrolls: int) -> Optional[int]:
+        """Groups of unrolls the learn program takes through the WHOLE
+        stack, the loss and the backward pass one at a time, its
+        gradient accumulated (``JaxPolicy`` asks). ``None`` for a
+        residual whose learn form groups the streams inside each block
+        instead (``apply``; the residual's description says which and why)."""
+        if not self.residual.groups_the_loss:
+            return None
+        s = self.learn_streams
+        return unrolls // s if unrolls > s and unrolls % s == 0 else 1
+
+    def reduce_group_stats(self, stats: Dict) -> Dict:
+        """``stats`` stacked over the groups of ``loss_groups`` -> one
+        update's, each key by the reduction its kind declares: loads add
+        up over groups before their mean and max are taken, an error's
+        largest is kept; the ratios and the loss's own keys are
+        averaged."""
+        out = over_streams(stats, self._reductions)
+        if "moe_held_load" in out:
+            out.update(_held_load_stats(out.pop("moe_held_load")))
+        return out
+
+    # -- forward ---------------------------------------------------------
+
+    def apply(self, params, obs, state, resets=None, scope: str = "",
+              stats_out: Optional[Dict] = None, **_):
+        """``obs`` ``(B, T[, 1])`` token ids; ``state`` as
+        ``initial_state``; ``resets`` ``(B, T)`` (1.0 where a token
+        opens an episode). Returns ``(logits (B*T, vocab), value
+        (B*T,), state)``. ``scope`` prefixes the named scopes;
+        ``stats_out`` receives the kinds' statistics."""
+        tokens = obs.reshape(obs.shape[0], -1).astype(jnp.int32)
+        b, t = tokens.shape
+        # the one-token form opens an episode before the token it
+        # consumes; without ``resets`` nothing is reset here (the
+        # rollout lane has reset the stream after its last step) and
+        # no pass over the state is made for it
+        if t == 1 and resets is not None:
+            state = self.reset_state(state, resets.reshape(b) > 0.5)
+        if t == 1 or resets is None:
+            resets = jnp.zeros((b, t), jnp.float32)
+        fresh = resets.reshape(b, t) > 0.5
+        seg = jnp.cumsum(fresh.astype(jnp.int32), axis=1)  # (B, T)
+        steps = jnp.arange(t, dtype=jnp.int32)[None]
+        opened = jax.lax.cummax(jnp.where(fresh, steps, -1), axis=1)
+        pos0 = state[-1]
+        positions = jnp.where(seg == 0, pos0[:, None] + steps, steps - opened)
+        rows_ctx = {
+            "seg": seg, "fresh": fresh, "positions": positions, "pos0": pos0,
+        }
+        prefix = (scope + "/") if scope else ""
+        residual, declared = self.residual, self._reductions
+
+        x = jnp.take(params["embed"]["embedding"], tokens, axis=0)  # (B, T, D)
+        if self.embed_scale != 1.0:
+            x = x * self.embed_scale
+        x = residual.enter(x)
+
+        def block(x, p, layer_state, rows, mixer, ffn):
+            ctx = dict(rows, scope=prefix, dtype=self.dtype, eps=self.eps,
+                       chunk=self.chunk)
+            if ffn.route_on == "input":
+                # the router reads the layer's input, before the mixer
+                with jax.named_scope(prefix + "moe/route"):
+                    ctx["route"] = ffn.route(p, x.reshape(-1, x.shape[-1]))
+            x, new, stats = residual.around(
+                x, p, "mixer", lambda h: mixer.apply(p, h, layer_state, ctx), ctx)
+            x, _, more = residual.around(
+                x, p, "ffn", lambda h: ffn.apply(p, h, (), ctx), ctx)
+            # what both sublayers report (the residual's own) as two layers'
+            both = over_layers(
+                {k: [jnp.stack([stats[k], more[k]])] for k in set(stats) & set(more)},
+                declared)
+            return x, new, {**stats, **more, **both}
+
+        # the residual says whether the streams are grouped inside each
+        # block, here, or around the whole loss (``loss_groups``)
+        groups = b // self.learn_streams if (
+            not residual.groups_the_loss
+            and t > 1 and b > self.learn_streams and b % self.learn_streams == 0
+        ) else 1
+        # the learn form keeps a block's input and recomputes the block
+        # in the backward pass, ``learn_streams`` streams at a time: one
+        # group's activations of one block are alive, not the batch's
+        # of the stack
+        whole = jax.checkpoint(block, static_argnums=(4, 5)) if t > 1 else block
+
+        def run_block(x, p, layer_state, rows, *kinds):
+            if groups == 1:
+                return whole(x, p, layer_state, rows, *kinds)
+            split = lambda a: a.reshape((groups, b // groups) + a.shape[1:])
+            merge = lambda a: a.reshape((b,) + a.shape[2:])
+            x, new, stats = jax.lax.map(
+                lambda xs: whole(xs[0], p, xs[1], xs[2], *kinds),
+                jax.tree_util.tree_map(split, (x, layer_state, rows)),
+            )
+            return (merge(x), jax.tree_util.tree_map(merge, new),
+                    over_streams(stats, declared))
+
+        state_out, stats = [], {}
+        for s, leaves in self._by_segment(state):
+            args = (params[s.name], leaves, rows_ctx, s.mixer, s.ffn)
+            if s.mixer.stacked:
+                x, new, seen = self._run_of_layers(run_block, prefix, x, *args)
+            else:
+                x, new, seen = run_block(x, *args)
+                seen = {k: v[None] for k, v in seen.items()}
+            state_out.extend(new)
+            for k, v in seen.items():
+                stats.setdefault(k, []).append(v)
+        state_out.append(positions[:, -1] + 1)
+
+        with jax.named_scope(prefix + "head"):
+            x = residual.leave(x)
+            feat = rms(x, params["final_norm"]["weight"], self.eps).reshape(b * t, -1)
+            if self.tied_head:  # the embedding, contracted over the hidden axis
+                logits = jax.lax.dot_general(
+                    feat.astype(self.dtype),
+                    params["embed"]["embedding"].astype(self.dtype),
+                    (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+                )
+            else:
+                logits = dot(feat, params["head"]["kernel"], self.dtype)
+            if self.logits_scale != 1.0:
+                logits = logits / self.logits_scale
+            value = (
+                jnp.dot(feat, params["value"]["kernel"], precision=HI)
+                + params["value"]["bias"]
+            )[:, 0]
+        if stats_out is not None:
+            self._report(stats_out, over_layers(stats, declared), b * t)
+        return logits, value, tuple(state_out)
+
+    def _report(self, stats_out: Dict, stats: Dict, tokens: int):
+        """The stack's statistics into ``stats_out``: the kinds' own
+        keys as they are, and the few ratios made of their sums."""
+        routes = stats.pop("moe_routes", None)
+        if "moe_routes" in stats_out:
+            # asked for every token's expert set; where no layer routes:
+            # the one feed-forward there is, for every token
+            stats_out["moe_routes"] = routes if routes is not None else jnp.zeros(
+                (1, tokens, 1), jnp.int32)
+        for name in ("attn_key_blocks", "attn_decode_key_blocks"):
+            if name + "_walked" in stats:
+                stats_out[name + "_skipped_share"] = stats.pop(
+                    name + "_skipped") / jnp.maximum(stats.pop(name + "_walked"), 1)
+        place = stats.pop("moe_place_load", None)
+        if place is not None and not self.residual.groups_the_loss:
+            # a group of ``loss_groups`` hands out ``moe_held_load`` as it
+            # is and ``reduce_group_stats`` makes the update's numbers
+            stats_out.update(_held_load_stats(stats.pop("moe_held_load")))
+            # of the held experts, those that some stream's token at
+            # the same place of its fragment reached: where a
+            # fragment is one stream's rollout (the fused lane) a
+            # place is a decode step, and this is the share of the
+            # held experts' weights a step's tokens chose
+            stats_out["moe_decode_held_experts_touched_share"] = jnp.mean(place > 0)
+        stats_out.update(stats)
+
+    # -- a run of stacked layers -----------------------------------------
+
+    def _run_of_layers(self, run_block, scope, x, p, run_state, rows, *kinds):
+        """A run of identical layers whose leaves are stacked on a
+        leading layer axis, as ONE ``lax.scan``: the layer is traced
+        once (ten layers unrolled compiled for longer than a run of the
+        benchmark may take). ``run_state``'s leaves carry the layer axis
+        after the stream's. Returns ``(x, state leaves, statistics a
+        layer)``."""
+        layers = jnp.arange(next(iter(p.values())).shape[0])
+        if x.shape[1] == 1:
+            # one token: the run's state rides in the carry and a layer
+            # reads and writes its own slice of it in place. The product
+            # weights are cast here, outside the scan over layers, so
+            # that they are loop-invariant in the lane's scan over steps
+            # and converted once a rollout, as an unstacked layer's are
+            p = {k: v.astype(self.dtype) if k in _RUN_PRODUCT_LEAVES else v
+                 for k, v in p.items()}
+
+            def step(carry, xs):
+                x, matrices, tails = carry
+                p_l, layer = xs
+                # the matrices go to the mixer whole with the layer's
+                # index (``ssd.ssd_step`` updates that layer where it
+                # lies); the convolution tail, 4 MB a run, as a slice
+                with jax.named_scope(scope + "ssm/carry"):
+                    tail = jax.lax.dynamic_index_in_dim(tails, layer, 1, keepdims=False)
+                x, (matrices, tail), stats = run_block(
+                    x, p_l, ((matrices, layer), tail), rows, *kinds)
+                with jax.named_scope(scope + "ssm/carry"):
+                    tails = jax.lax.dynamic_update_index_in_dim(
+                        tails, tail.astype(tails.dtype), layer, 1)
+                return (x, matrices, tails), stats
+
+            (x, *leaves), stats = jax.lax.scan(step, (x, *run_state), (p, layers))
+            return x, tuple(leaves), stats
+
+        def fragment(x, xs):
+            p_l, mine = xs
+            x, new, stats = run_block(x, p_l, mine, rows, *kinds)
+            return x, (new, stats)
+
+        x, (new, stats) = jax.lax.scan(
+            fragment, x, (p, tuple(jnp.moveaxis(s, 1, 0) for s in run_state)))
+        return x, tuple(jnp.moveaxis(s, 0, 1) for s in new), stats
+
+
+def _held_load_stats(load) -> Dict:
+    """``load`` ``(expert layers, held)``, an update's."""
+    return {"moe_tokens_per_held_expert": jnp.mean(load),
+            "moe_max_tokens_per_held_expert": jnp.max(load)}

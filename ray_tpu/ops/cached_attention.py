@@ -1,0 +1,211 @@
+"""Causal softmax attention over a stored cache as ONE op
+(:func:`cached_attention`), the way ``ops/ssd.ssd_step`` and
+``ops/deltanet.gated_delta_step`` are: the cache's format, the masks,
+the XLA text of both forms and the choice of a kernel live here, and a
+model's layer sees none of them.
+
+The cache. A stream's keys and values lie one row a position, ``(B,
+depth, kv heads x head)`` in the products' type. At FULL DEPTH (no
+``window``) position ``p`` lies in slot ``p`` and the slots below the
+stream's position are its episode so far. With a ``window`` the cache
+is a RING of ``depth = min(window, positions)`` slots, position ``p``
+in slot ``p mod depth``: the row a stream at ``pos0`` holds in slot
+``s`` is the one of the largest position below ``pos0`` that is ``s
+mod depth`` (none where that is negative), and a query sees the rows
+whose position is less than ``window`` behind its own. The masks come
+from those positions, never from slot numbers.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops import flash_attention
+from ray_tpu.telemetry import metrics
+
+# streams of a fragment whose attention scores are alive at once: at
+# most 8, fewer (a power of two) where 8 streams' float32 scores of
+# every head over the rows a block sees pass ``_ATTN_SCORE_BYTES`` (8 at
+# 2,304 rows of 32 heads x 256 tokens; 2 at 8,448 rows of 28 x 256, 4 at
+# 4,352)
+_ATTN_SCORE_BYTES = 5 * 2 ** 27
+
+
+def env_block(heads: int, tokens: int, rows: int) -> int:
+    """Streams of a fragment whose float32 scores (every head, every
+    token against ``rows`` keys) are alive at once."""
+    fit = _ATTN_SCORE_BYTES // (4 * heads * tokens * rows)
+    return min(8, 1 << max(0, int(fit).bit_length() - 1))
+
+
+def scatter_rows(cache, new, rows, ring: bool = False):
+    """``cache`` ``(B, depth, W)`` after a fragment whose tokens' rows
+    are ``new`` ``(B, T, W)``: the last episode's tokens, each at its
+    position (positions of one episode are distinct; earlier episodes'
+    tokens are dropped); in a ``ring`` the last ``depth`` of them, each
+    at its position mod ``depth``. ``rows``: the fragment's ``seg`` and
+    ``positions`` ``(B, T)``."""
+    seg, positions = rows["seg"], rows["positions"]
+    depth = cache.shape[1]
+    kept = seg == seg[:, -1:]
+    if ring:
+        kept = kept & (positions > positions[:, -1:] - depth)
+        positions = positions % depth
+    slot = jnp.where(kept, positions, depth)
+    return cache.at[jnp.arange(cache.shape[0])[:, None], slot].set(
+        new.astype(cache.dtype), mode="drop")
+
+
+def fragment_masks(seg, pos0, positions, depth: int, window):
+    """``(see_old (B, T, depth), see (B, T, T))``: a stored key is seen
+    by the tokens before the fragment's first reset, below the start
+    position (in a ring: where the slot holds a row, less than
+    ``window`` behind the query); the fragment's own causally, within an
+    episode and the window."""
+    steps = jnp.arange(seg.shape[1])
+    see_old = (seg == 0)[:, :, None]
+    if window is None:
+        see_old = see_old & (jnp.arange(depth)[None, None] < pos0[:, None, None])
+    else:
+        # the position of the row in each slot (negative: none yet)
+        last = pos0[:, None] - 1
+        held = last - (last - jnp.arange(depth)[None]) % depth  # (B, depth)
+        see_old = see_old & (held >= 0)[:, None] & (
+            positions[:, :, None] - held[:, None] < window)
+    see = (steps[:, None] >= steps[None, :])[None] & (
+        seg[:, :, None] == seg[:, None, :])
+    if window is not None:
+        see = see & (steps[:, None] - steps[None, :] < window)[None]
+    return see_old, see
+
+
+def pairs_seen(see_old, see):
+    """(query, key) pairs under the masks, a stream (exact in float32)."""
+    return (jnp.sum(see_old, axis=(1, 2), dtype=jnp.float32)
+            + jnp.sum(see, axis=(1, 2), dtype=jnp.float32))
+
+
+def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope):
+    """Attention of a fragment's ``q`` ``(B, T, heads, D)`` over the
+    stored keys and values ``caches`` and the fragment's own ``k``, ``v``
+    ``(B, T, kv heads, D)``; ``rows`` holds the fragment's ``seg``,
+    ``positions`` ``(B, T)`` and ``pos0`` ``(B,)``. Returns ``(o (B, T,
+    heads, D) float32, (keys, values) after the fragment, stats)``, its
+    parts under ``scope``'s ``/scatter``, ``/scores`` and ``/out``.
+
+    Which form runs where, each chosen by what the call sees in its
+    input. A fragment (``T > 1``): ``flash_attention.fragment_attention``
+    where ``fragment_kernel_applies`` says so (a TPU, bfloat16, whole
+    blocks), window or none, else the XLA text a block of streams at a
+    time (:func:`env_block`). One token at full depth:
+    ``flash_attention.step_attention`` where ``step_kernel_applies`` says
+    so, which fetches a stream's key blocks below its depth only, else
+    ``step_attention_text``. One token over a ring: that text, always
+    (past its first turn a ring has no unwritten slot to skip).
+    ``ray_tpu_attention_{step,fragment}_lowerings_total{path}`` count the
+    choice.
+
+    ``stats`` is what the choice alone knows: with a ``window`` the
+    (query, key) ``pairs_seen``; for a fragment the key blocks its
+    kernel skipped and walked (``attn_key_blocks_*``: a stream's stored
+    blocks at or past its start position are skipped) and those the
+    one-token kernel would at each of the fragment's positions
+    (``attn_decode_key_blocks_*``), 0 of 0 where the text runs, which
+    multiplies every slot."""
+    k_cache, v_cache = caches
+    b, t, h, d = q.shape
+    hkv = k.shape[2]
+    depth = k_cache.shape[1]
+    seg, positions, pos0 = rows["seg"], rows["positions"], rows["pos0"]
+    k, v = k.astype(dtype), v.astype(dtype)
+    part = lambda name: jax.named_scope(f"{scope}/{name}")
+    ring = window is not None
+
+    with part("scatter"):
+        new_k = scatter_rows(k_cache, k.reshape(b, t, hkv * d), rows, ring)
+        new_v = scatter_rows(v_cache, v.reshape(b, t, hkv * d), rows, ring)
+
+    qh = (q * scale).astype(dtype).reshape(b, t, hkv, h // hkv, d)
+    step_kernel = not ring and flash_attention.step_kernel_applies(
+        h, hkv, d, depth, dtype)
+    stats = {}
+
+    def attend(qe, ke, ve, kc, vc, sege, pos0e, pose=None):
+        """One block of streams: the masked scores over the stored keys
+        and the fragment's own in one softmax."""
+        kc = kc.reshape(kc.shape[:2] + (hkv, d))
+        vc = vc.reshape(vc.shape[:2] + (hkv, d))
+        with part("scores"):
+            see_old, see = fragment_masks(sege, pos0e, pose, depth, window)
+            old = jnp.einsum(
+                "btngd,bsnd->bngts", qe, kc, preferred_element_type=jnp.float32)
+            own = jnp.einsum(
+                "btngd,bsnd->bngts", qe, ke, preferred_element_type=jnp.float32)
+            w = jax.nn.softmax(jnp.concatenate([
+                jnp.where(see_old[:, None, None], old, -jnp.inf),
+                jnp.where(see[:, None, None], own, -jnp.inf)], axis=-1), axis=-1)
+            w = w.astype(dtype)
+        with part("out"):
+            out = jnp.einsum(
+                "bngts,bsnd->btngd", w[..., :depth], vc,
+                preferred_element_type=jnp.float32,
+            ) + jnp.einsum(
+                "bngts,bsnd->btngd", w[..., depth:], ve,
+                preferred_element_type=jnp.float32,
+            )
+        return (out, pairs_seen(see_old, see)) if ring else out
+
+    if t == 1:
+        # decode reads the cache it has just written: the own key sits
+        # at slot pos0, so the stored range is one longer
+        see = fragment_masks(seg, pos0 + 1, positions, depth, window)[0][:, 0]
+        if ring:
+            stats["pairs_seen"] = jnp.sum(jnp.sum(see, axis=1, dtype=jnp.float32))
+        if step_kernel:
+            # a full-depth cache is half unwritten at the mean: the
+            # tiled step kernel fetches a stream's key blocks below its
+            # depth only
+            metrics.inc_attention_step_lowering("kernel")
+            with part("scores"):
+                o = flash_attention.step_attention(qh, new_k, new_v, pos0 + 1)
+        else:
+            metrics.inc_attention_step_lowering("xla")
+            with jax.named_scope(scope):
+                o = flash_attention.step_attention_text(qh, new_k, new_v, see)
+        return o.reshape(b, t, h, d), (new_k, new_v), stats
+
+    kernel = flash_attention.fragment_kernel_applies(t, h, hkv, d, depth, dtype)
+    none = (jnp.int32(0), 0)
+    for name, (skipped, walked) in (
+            ("attn_key_blocks",
+             flash_attention.fragment_key_blocks(pos0, depth) if kernel else none),
+            ("attn_decode_key_blocks",
+             flash_attention.step_key_blocks(positions + 1, depth)
+             if step_kernel else none)):
+        stats[name + "_skipped"] = skipped
+        stats[name + "_walked"] = jnp.int32(walked)
+    if kernel:
+        # one tiled kernel, forward and backward: no score matrix is
+        # written, and no block of streams is needed to hold one
+        metrics.inc_attention_fragment_lowering("kernel")
+        with part("scores"):
+            o = flash_attention.fragment_attention(
+                qh, k, v, k_cache, v_cache, pos0, seg, positions, window=window)
+            if ring:  # the masks' arithmetic, reduced where it is built
+                stats["pairs_seen"] = jnp.sum(pairs_seen(
+                    *fragment_masks(seg, pos0, positions, depth, window)))
+    else:
+        metrics.inc_attention_fragment_lowering("xla")
+        nb = max(1, b // env_block(h, t, depth + t))
+        if b % nb:
+            nb = 1
+        # a ring's masks need each query's position
+        args = (qh, k, v, k_cache, v_cache, seg, pos0) + ((positions,) if ring else ())
+        blocked = tuple(a.reshape((nb, b // nb) + a.shape[1:]) for a in args)
+        o = jax.lax.map(lambda xs: jax.checkpoint(attend)(*xs), blocked)
+        o = jax.tree_util.tree_map(lambda a: a.reshape((b,) + a.shape[2:]), o)
+        if ring:
+            o, seen = o
+            stats["pairs_seen"] = jnp.sum(seen)
+    return o.reshape(b, t, h, d), (new_k, new_v), stats

@@ -66,6 +66,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import backend
 from ray_tpu.telemetry import metrics as telemetry_metrics
 
 _HI = jax.lax.Precision.HIGHEST
@@ -100,13 +101,9 @@ def _kernel_applies(state) -> bool:
     """The kernel's lowering exists for a TPU, for ``(streams, heads,
     dk, dv)`` float32 with ``dk`` and ``dv`` whole 128-lane tiles and
     the heads whole 8-sublane tiles (``k`` and ``q`` are turned from
-    lanes to sublanes a block of heads at a time). "A TPU" is the
-    process's default backend, as ``ops/flash_attention.py`` has it,
-    not the platform a computation is lowered for: a compile for a
-    described TPU from a CPU host (the tests' ``v5e_mesh``, a memory
-    budget taken ahead of time) sees the ``jax.numpy`` body and has to
-    call :func:`gated_delta_step_kernel` itself to see the kernel."""
-    if jax.default_backend() != "tpu" or state.ndim != 4:
+    lanes to sublanes a block of heads at a time); "a TPU" as
+    ``ops/backend.is_tpu`` has it."""
+    if not backend.is_tpu() or state.ndim != 4:
         return False
     heads, dk, dv = state.shape[-3:]
     return (

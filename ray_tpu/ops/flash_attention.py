@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import backend
+
 _BLOCK_Q = 128
 _BLOCK_K = 128
 _NEG_INF = -1e30
@@ -281,7 +283,7 @@ def flash_attention(
     if use_pallas is None:
         # the kernel compiled and matched the XLA reference on a TPU v5e
         # at the shapes of tests/test_tpu_hardware.py (jax 0.9.0)
-        use_pallas = interpret or jax.default_backend() == "tpu"
+        use_pallas = interpret or backend.is_tpu()
     qf = q.reshape(B * H, T, D)
     kf = k.reshape(B * H, S, D)
     vf = v.reshape(B * H, S, D)
@@ -294,7 +296,7 @@ def flash_attention(
 
 # -- a fragment over a stored cache (the sequence models' learn form) -------
 #
-# ``models/sequence_lm._cached_attention``'s fragment form: T queries of
+# ``ops/cached_attention.cached_attention``'s fragment form: T queries of
 # every stream over the rows its cache holds and the fragment's own
 # keys. One grid step is one stream, one key head and one block of keys;
 # the ``group`` query heads that share the key head are rows of the one
@@ -355,9 +357,9 @@ def fragment_head_tile(tokens, heads, kv_heads, head_dim) -> int:
 
 def fragment_kernel_applies(
         tokens, heads, kv_heads, head_dim, depth, dtype) -> bool:
-    """The fragment kernel's lowering exists on a TPU (the process's
-    default backend, as in ``ops/deltanet.py``) for bfloat16 operands,
-    a fragment of whole 128-lane tiles of tokens (the own keys' episode
+    """The fragment kernel's lowering exists on a TPU
+    (``ops/backend.is_tpu``) for bfloat16 operands, a fragment of
+    whole 128-lane tiles of tokens (the own keys' episode
     numbers lie along the lanes, and a tile of weights is turned for the
     own keys' gradients), a cache of whole key blocks, a key that is
     whole lane tiles, packs into one (64: two key heads a block) or is
@@ -366,7 +368,7 @@ def fragment_kernel_applies(
     least one head (:func:`fragment_head_tile`)."""
     pack = _heads_packed(head_dim, kv_heads)
     return (
-        jax.default_backend() == "tpu"
+        backend.is_tpu()
         and dtype == jnp.bfloat16
         and tokens % _LANES == 0
         and fragment_block_k(depth) > 0
@@ -390,25 +392,6 @@ def fragment_key_blocks(pos0, depth: int, block_k: int | None = None):
     stored = depth // bk
     held = _blocks_held(pos0, bk, stored)
     return jnp.sum(stored - held), pos0.shape[0] * (stored + 1)
-
-
-def fragment_pairs_seen(pos0, seg, positions, depth: int, window: int):
-    """(query, key) pairs a window layer's fragment sees, summed over
-    the streams: the masks' arithmetic of ``_cached_attention``, reduced
-    where it is built (no mask is written)."""
-    t = seg.shape[1]
-    slots = jnp.arange(depth)
-    last = pos0[:, None] - 1
-    held = last - (last - slots[None]) % depth  # (B, S)
-    see_old = (seg == 0)[:, :, None] & (held >= 0)[:, None] & (
-        positions[:, :, None] - held[:, None] < window)
-    steps = jnp.arange(t)
-    behind = steps[:, None] - steps[None, :]
-    see = ((behind >= 0) & (behind < window))[None] & (
-        seg[:, :, None] == seg[:, None, :])
-    # a stream's count is exact in float32; so is the text's
-    return jnp.sum(jnp.sum(see_old, axis=(1, 2), dtype=jnp.float32)
-                   + jnp.sum(see, axis=(1, 2), dtype=jnp.float32))
 
 
 def _stored_mask(pos0, seg_q, pos_q, first, block_k, depth, window):
@@ -746,14 +729,8 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
     are float32; the weights enter the value product in ``v``'s type
     and are normalised after it.
 
-    A stored slot ``s`` is seen by the queries before the fragment's
-    first reset (``seg == 0``): without a ``window`` where ``s <
-    pos0``; in a ring of ``depth`` slots where the position it holds,
-    ``held = last - (last - s) mod depth`` with ``last = pos0 - 1``, is
-    not negative and less than ``window`` behind the query's. Own key
-    ``j`` is seen by query ``i`` where ``j <= i``, both of one episode
-    and ``i - j < window``. Stored blocks at or past ``pos0`` are
-    skipped whole.
+    The masks are ``ops/cached_attention.fragment_masks``'s, built in
+    the kernel; stored blocks at or past ``pos0`` are skipped whole.
 
     Differentiable in ``q``, ``k`` and ``v``. The caches get NO
     gradient (zeros): they are the rollout's rows, handed over as data,
@@ -803,7 +780,7 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
 
 # -- one token over a stored cache (the sequence models' rollout form) ------
 #
-# ``models/sequence_lm._cached_attention``'s one-token form over a
+# ``ops/cached_attention.cached_attention``'s one-token form over a
 # full-depth cache: a stream's query heads over the rows its cache holds,
 # its own (written by the step's scatter) the last of them. One grid step
 # is one stream, which walks the key blocks it holds in a loop, all key
@@ -822,14 +799,15 @@ _SUBLANES = 8
 
 
 def step_kernel_applies(heads, kv_heads, head_dim, depth, dtype) -> bool:
-    """The step kernel's lowering exists on a TPU (the process's default
-    backend) for bfloat16 operands, a cache of whole key blocks, a key
+    """The step kernel's lowering exists on a TPU
+    (``ops/backend.is_tpu``) for bfloat16 operands, a cache of whole
+    key blocks, a key
     that is whole lane tiles or packs into one (64: two key heads a
     block), and a block of all key heads that fits VMEM four times."""
     pack = _heads_packed(head_dim, kv_heads)
     block_k = fragment_block_k(depth)
     return (
-        jax.default_backend() == "tpu"
+        backend.is_tpu()
         and dtype == jnp.bfloat16
         and block_k > 0
         and heads % kv_heads == 0
@@ -962,18 +940,26 @@ def _step_fwd(q, k_cache, v_cache, rows_held, *, block_k, interpret):
     )(rows_held.astype(jnp.int32), q, k_cache, v_cache)
 
 
-def step_attention_text(q, k_cache, v_cache, rows_held):
-    """The same attention as XLA writes it, every slot under a mask
-    (``_cached_attention``'s one-token text): the kernel's backward pass
-    and its oracle."""
+def step_attention_text(q, k_cache, v_cache, see):
+    """One token's attention as XLA writes it, every slot under a mask:
+    THE one-token text, which ``ops/cached_attention`` runs where no
+    kernel's lowering exists (and over a ring, always) and which is the
+    step kernel's backward pass and oracle. ``q`` ``(B, 1, kv, group,
+    D)`` scaled, in the products' type; the caches ``(B, depth, kv *
+    D)`` after the step's scatter; ``see`` ``(B, depth)`` the slots each
+    stream's query sees (at full depth those below its rows held; in a
+    ring by the positions they hold). Returns ``(B, 1, kv, group, D)``
+    float32, its parts under the scopes ``scores`` and ``out``."""
     kv, d = q.shape[2], q.shape[-1]
     kc = k_cache.reshape(k_cache.shape[:2] + (kv, d))
     vc = v_cache.reshape(v_cache.shape[:2] + (kv, d))
-    s = jnp.einsum("btngd,bsnd->bngts", q, kc, preferred_element_type=jnp.float32)
-    see = jnp.arange(kc.shape[1])[None] < rows_held[:, None]
-    w = jax.nn.softmax(jnp.where(see[:, None, None, None], s, -jnp.inf), axis=-1)
-    return jnp.einsum("bngts,bsnd->btngd", w.astype(q.dtype), vc,
-                      preferred_element_type=jnp.float32)
+    with jax.named_scope("scores"):
+        s = jnp.einsum("btngd,bsnd->bngts", q, kc, preferred_element_type=jnp.float32)
+        w = jax.nn.softmax(
+            jnp.where(see[:, None, None, None], s, -jnp.inf), axis=-1).astype(q.dtype)
+    with jax.named_scope("out"):
+        return jnp.einsum("bngts,bsnd->btngd", w, vc,
+                          preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -1004,8 +990,8 @@ def _step_fwd_rule(q, k_cache, v_cache, rows_held, block_k, interpret):
 
 def _step_bwd_rule(block_k, interpret, residuals, do):
     *operands, rows_held = residuals
-    _, vjp = jax.vjp(
-        lambda *a: step_attention_text(*a, rows_held), *operands)
+    see = jnp.arange(operands[1].shape[1])[None] < rows_held[:, None]
+    _, vjp = jax.vjp(lambda *a: step_attention_text(*a, see), *operands)
     return vjp(do) + (None,)
 
 

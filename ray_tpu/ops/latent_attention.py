@@ -30,8 +30,11 @@ key part and a ``v_head_dim`` value: the query/key product is
   ``(T, S + T)`` matrix, with ``seg`` marking episodes that open inside
   the fragment.
 
-``ray_tpu_mla_decode_lowerings_total{form}`` counts which a traced layer
-took (``absorbed`` | ``absorbed_fragment`` | ``expanded``). All take
+:func:`latent_attention` is the layer's one entry: it writes the
+fragment's rows into the cache and picks among the three from what the
+call sees. ``ray_tpu_mla_decode_lowerings_total{form}`` counts which a
+traced layer took (``absorbed`` | ``absorbed_fragment`` | ``expanded``).
+All take
 ``dtype`` operands (the cache's) and accumulate in float32; masks and
 softmax are float32. :func:`yarn_inv_freq` and
 :func:`yarn_softmax_scale` are YaRN (arXiv:2309.00071) as DeepSeek-V3's
@@ -48,6 +51,14 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ray_tpu.ops import cached_attention, flash_attention
+from ray_tpu.telemetry import metrics
+
+# streams of a fragment whose expanded scores are alive at once: the
+# keys and values of 32 heads are rebuilt for the block as well (0.27 GB
+# for 8 streams, and as much again for their cotangents), so a constant
+_ENV_BLOCK = 4
 
 
 def yarn_inv_freq(dim: int, theta: float, scaling: Optional[Dict]) -> np.ndarray:
@@ -90,6 +101,46 @@ def rope(x, positions, inv_freq):
     cos, sin = jnp.cos(angle)[:, :, None], jnp.sin(angle)[:, :, None]
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def latent_attention(q_nope, q_pe, rows_new, cache, kv_b, rows, *, scale, dtype):
+    """A latent layer's attention over its cache of latent rows.
+    ``q_nope`` ``(B, T, H, dn)`` and ``q_pe`` ``(B, T, H, R)`` float32
+    (roped); ``rows_new`` ``(B, T, C + R)`` the fragment's own rows;
+    ``cache`` ``(B, positions, C + R)``; ``kv_b`` ``(C, H * (dn +
+    dv))``; ``rows`` the fragment's ``seg``, ``positions`` ``(B, T)``
+    and ``pos0`` ``(B,)``. Returns ``(o (B, T, H, dv) float32, the cache
+    after the fragment, stats)``: one token the absorbed product over
+    what the cache then holds, its own row included; a fragment the same
+    product on the tiled kernel where ``fragment_kernel_applies`` says so
+    (one key head, the latent rows as they lie), else the expanded text.
+    ``stats``: a fragment's key blocks skipped and walked, as
+    ``ops/cached_attention`` counts them."""
+    seg, positions, pos0 = rows["seg"], rows["positions"], rows["pos0"]
+    t, heads = q_nope.shape[1:3]
+    new_cache = cached_attention.scatter_rows(cache, rows_new, rows)
+    if t == 1:
+        metrics.inc_mla_decode_lowering("absorbed")
+        o = absorbed_step(
+            q_nope[:, 0], q_pe[:, 0], new_cache, kv_b, pos0, scale, dtype)[:, None]
+        return o, new_cache, {}
+    depth = cache.shape[1]
+    if flash_attention.fragment_kernel_applies(
+            t, heads, 1, cache.shape[2], depth, dtype):
+        metrics.inc_mla_decode_lowering("absorbed_fragment")
+        metrics.inc_attention_fragment_lowering("kernel")
+        skipped, walked = flash_attention.fragment_key_blocks(pos0, depth)
+        o = absorbed_fragment(
+            q_nope, q_pe, rows_new, cache, kv_b, seg, positions, pos0, scale, dtype)
+    else:
+        metrics.inc_mla_decode_lowering("expanded")
+        metrics.inc_attention_fragment_lowering("xla")
+        skipped, walked = jnp.int32(0), 0
+        o = expanded_fragment(
+            q_nope, q_pe, rows_new, cache, kv_b, seg, pos0, scale, dtype,
+            block=_ENV_BLOCK)
+    return o, new_cache, {"attn_key_blocks_skipped": skipped,
+                          "attn_key_blocks_walked": jnp.int32(walked)}
 
 
 def _kv_b_by_head(kv_b, heads: int, dtype):
@@ -142,8 +193,6 @@ def absorbed_fragment(q_nope, q_pe, rows_new, cache, kv_b, seg, positions,
     ``absorb``, ``scores`` and ``out`` under the caller's, as
     :func:`absorbed_step`'s do. ``kernel``: the tests' spellings of
     ``fragment_attention`` (``block_k``, ``head_tile``, ``interpret``)."""
-    from ray_tpu.ops import flash_attention
-
     heads, dn = q_nope.shape[2:]
     latent = kv_b.shape[0]
     w = _kv_b_by_head(kv_b, heads, dtype)
@@ -180,8 +229,6 @@ def expanded_fragment(q_nope, q_pe, rows_new, cache, kv_b, seg, pos0,
     b, t, heads, dn = q_nope.shape
     latent = kv_b.shape[0]
     w = _kv_b_by_head(kv_b, heads, dtype)
-    slots = jnp.arange(cache.shape[1])
-    steps = jnp.arange(t)
 
     def expand(rows):
         kv = jnp.einsum(
@@ -201,13 +248,8 @@ def expanded_fragment(q_nope, q_pe, rows_new, cache, kv_b, seg, pos0,
         qn, qp = (qn * scale).astype(dtype), (qp * scale).astype(dtype)
         k_old, v_old, pe_old = expand(old)
         k_new, v_new, pe_new = expand(new)
-        # a stored row is seen by the tokens before the first reset,
-        # below the start position; the fragment's own causally, within
-        # an episode
-        see_old = (sege == 0)[:, :, None] & (
-            slots[None, None] < pos0e[:, None, None])
-        see_new = (steps[:, None] >= steps[None, :])[None] & (
-            sege[:, :, None] == sege[:, None, :])
+        see_old, see_new = cached_attention.fragment_masks(
+            sege, pos0e, None, cache.shape[1], None)
         s = jnp.concatenate([
             jnp.where(see_old[:, None], scores(qn, qp, k_old, pe_old), -jnp.inf),
             jnp.where(see_new[:, None], scores(qn, qp, k_new, pe_new), -jnp.inf),

@@ -71,6 +71,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import backend
 from ray_tpu.telemetry import metrics as telemetry_metrics
 
 _HI = jax.lax.Precision.HIGHEST
@@ -118,9 +119,9 @@ def _kernel_applies(state) -> bool:
     """The kernel's lowering exists for a TPU, for a stacked float32
     leaf ``(streams, layers, H, P, N)`` with ``N`` whole 128-lane tiles
     and ``P`` and the heads whole 8-sublane tiles (a matrix's rows; ``x``
-    is turned from lanes to sublanes a block of heads at a time). "A
-    TPU" is the process's default backend, as in ``ops/deltanet.py``."""
-    if jax.default_backend() != "tpu" or state.ndim != 5:
+    is turned from lanes to sublanes a block of heads at a time); "a
+    TPU" as ``ops/backend.is_tpu`` has it."""
+    if not backend.is_tpu() or state.ndim != 5:
         return False
     heads, p, n = state.shape[-3:]
     return (

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_tpu.ops import backend
 from ray_tpu.ops.flash_attention import flash_block_attention_stats
 
 NEG_INF = -1e30
@@ -169,7 +170,7 @@ def ring_attention(
     f32 at T≈256 — both sit that far from a float64 reference); on CPU
     they agree to ~1e-4."""
     if use_pallas is None:
-        use_pallas = interpret or jax.default_backend() == "tpu"
+        use_pallas = interpret or backend.is_tpu()
 
     def run(q, k, v, pallas: bool):
         body = functools.partial(

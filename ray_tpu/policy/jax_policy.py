@@ -825,7 +825,7 @@ class JaxPolicy(Policy):
             )
             # a model whose saved activations of a whole minibatch do
             # not fit asks for groups of unrolls, each taken through
-            # forward, loss and backward on its own (models/sequence_lm.py)
+            # forward, loss and backward on its own (models/sequence_lm)
             ask = getattr(self.model, "loss_groups", None)
             loss_groups = ask(mb_loc // T_seq) if ask else None
 

@@ -100,7 +100,7 @@ H2D_BYTES_TOTAL = "ray_tpu_h2d_bytes_total"
 # inside fused K-updates-per-dispatch programs
 SUPERSTEP_UPDATES_TOTAL = "ray_tpu_superstep_updates_total"
 # routed-expert load of a model that holds a share of its experts
-# (models/sequence_lm.py): per update, the mean and the largest count
+# (models/sequence_lm): per update, the mean and the largest count
 # of tokens a held expert saw (summed over updates under "stat"), and
 # the (token, slot) pairs that fell on experts held elsewhere
 MOE_HELD_EXPERT_TOKENS_TOTAL = "ray_tpu_moe_held_expert_tokens_total"
@@ -116,13 +116,13 @@ MOE_DECODE_HELD_TOUCHED_TOTAL = "ray_tpu_moe_decode_held_experts_touched_total"
 # once per DeltaNet layer of a traced program
 DELTANET_STEP_LOWERINGS_TOTAL = "ray_tpu_deltanet_step_lowerings_total"
 # which form each traced routed-expert layer's product took
-# (models/sequence_lm.py, ops/moe.product_lowering): path = grouped
+# (models/sequence_lm, ops/moe.product_lowering): path = grouped
 # (only the (token, slot) pairs on held experts, sorted by expert) |
 # dense (every held expert over every token). Chosen from static shapes
 # and counted when the layer is traced
 MOE_PRODUCT_LOWERINGS_TOTAL = "ray_tpu_moe_product_lowerings_total"
 # which form each traced latent-attention layer took
-# (models/sequence_lm.py, ops/latent_attention.py): form = absorbed
+# (ops/latent_attention.latent_attention): form = absorbed
 # (one token against the latent rows: the rollout's step) |
 # absorbed_fragment (a fragment against the latent rows on the tiled
 # fragment kernel: the learn form where ops/flash_attention's rule
@@ -139,7 +139,7 @@ MLA_DECODE_LOWERINGS_TOTAL = "ray_tpu_mla_decode_lowerings_total"
 # share one trace)
 SSM_STEP_LOWERINGS_TOTAL = "ray_tpu_ssm_step_lowerings_total"
 # which form each traced sliding-window attention layer took over its
-# ring cache (models/sequence_lm.py): form = step (one token written to
+# ring cache (models/sequence_lm): form = step (one token written to
 # slot position mod window, then the ring read under the slots' own
 # positions: the rollout's step) | fragment (a fragment's queries over
 # the stored ring, each row's position recovered from its slot and the
@@ -148,8 +148,8 @@ SSM_STEP_LOWERINGS_TOTAL = "ray_tpu_ssm_step_lowerings_total"
 # body of a program
 WINDOW_CACHE_LOWERINGS_TOTAL = "ray_tpu_window_cache_lowerings_total"
 # which lowering each traced attention layer's fragment form took
-# (models/sequence_lm._cached_attention, every softmax attention kind
-# over a stored cache, and _latent_attn over its latent rows): path =
+# (ops/cached_attention.cached_attention, every softmax attention kind
+# over a stored cache, and ops/latent_attention over its latent rows): path =
 # kernel (ops/flash_attention's tiled fragment kernel, forward and
 # backward: a TPU backend, bfloat16, fragments and caches of whole
 # blocks) | xla (the score matrices a
@@ -158,14 +158,14 @@ WINDOW_CACHE_LOWERINGS_TOTAL = "ray_tpu_window_cache_lowerings_total"
 ATTENTION_FRAGMENT_LOWERINGS_TOTAL = (
     "ray_tpu_attention_fragment_lowerings_total")
 # which lowering each traced softmax-attention layer's ONE-TOKEN form
-# took (models/sequence_lm._cached_attention): path = kernel
+# took (ops/cached_attention.cached_attention): path = kernel
 # (ops/flash_attention.step_attention: a full-depth cache on a TPU
 # backend, bfloat16, whole key blocks; a stream's key blocks past its
 # depth are not fetched) | xla (every slot under a mask: every ring, and
 # everywhere else). Counted when the form is traced
 ATTENTION_STEP_LOWERINGS_TOTAL = "ray_tpu_attention_step_lowerings_total"
 # the geometry of each traced softmax-attention layer body
-# (models/sequence_lm.SequenceLM._attention, one body over a per-layer
+# (models/sequence_lm AttentionLayer.apply, one body over a per-layer
 # description): kind = the layer's name in ``layer_types``, heads = ITS
 # query heads, rope = none | default | yarn. Counted when the body is
 # traced (layers whose checkpointed block is the same trace once), in

@@ -98,7 +98,7 @@ def test_bf16_inputs():
     )
 
 
-# -- the fragment kernel against ``_cached_attention``'s XLA text ----------
+# -- the fragment kernel against ``cached_attention``'s XLA text -----------
 
 def _fragment(b=3, t=16, kv=2, group=4, d=128, depth=32, window=None,
               pos0=(0, 10, 32), resets=((), (5,), ()), dtype=jnp.float32,
@@ -128,17 +128,13 @@ def _text_and_kernel(rows, kv, window, dtype, block_k):
     """``(q, k, v, k_cache, v_cache) -> o (B, T, heads, D)`` twice: the
     model's XLA text (the rule's branch off a TPU) and the kernel in the
     interpreter."""
-    import types
-
-    from ray_tpu.models.sequence_lm import SequenceLM
+    from ray_tpu.ops.cached_attention import cached_attention
     from ray_tpu.ops.flash_attention import fragment_attention
 
-    stub = types.SimpleNamespace(kv_heads=kv, dtype=dtype)
-
     def text(q, k, v, kc, vc):
-        return SequenceLM._cached_attention(
-            stub, q, k, v, (kc, vc), rows, q.shape[-1] ** -0.5, window=window,
-            scope="swa" if window else "attn")[0]
+        return cached_attention(
+            q, k, v, (kc, vc), rows, scale=q.shape[-1] ** -0.5, window=window,
+            dtype=dtype, scope="swa" if window else "attn")[0]
 
     def kernel(q, k, v, kc, vc):
         b, t, h, d = q.shape
@@ -155,7 +151,7 @@ def _latent_fragment(b=3, t=16, heads=4, dn=16, rope=16, latent=128, dv=16,
                      depth=32, pos0=(0, 10, 32), resets=((), (5,), ()),
                      dtype=jnp.float32, seed=0, **_):
     """The latent layer's fragment: ``(q_nope, q_pe, rows_new, kv_b,
-    cache)`` as ``SequenceLM._latent_attn`` hands them over (a row of
+    cache)`` as the latent layer hands them over (a row of
     ``latent + rope`` lanes: not whole lane tiles, the value its leading
     ``latent``), the lane's rows, and a cotangent."""
     _, rows, _ = _fragment(b=b, t=t, depth=depth, pos0=pos0, resets=resets)
@@ -297,7 +293,7 @@ def test_fragment_kernel(name):
 
 
 def test_fragment_rule_blocks_and_pairs():
-    from ray_tpu.ops import flash_attention as fa
+    from ray_tpu.ops import cached_attention, flash_attention as fa
 
     # off a TPU the rule says XLA, whatever the shape
     assert not fa.fragment_kernel_applies(256, 28, 4, 128, 8192, jnp.bfloat16)
@@ -324,9 +320,9 @@ def test_fragment_rule_blocks_and_pairs():
                        & (pos[:, None] - held[None] < 24))
         want += np.sum((steps[:, None] >= steps[None]) & (seg[:, None] == seg[None])
                        & (steps[:, None] - steps[None] < 24))
-    got = fa.fragment_pairs_seen(
-        rows["pos0"], rows["seg"], rows["positions"], 24, 24)
-    assert float(got) == float(want)
+    got = cached_attention.pairs_seen(*cached_attention.fragment_masks(
+        rows["seg"], rows["pos0"], rows["positions"], 24, 24))
+    assert float(got.sum()) == float(want)
 
 
 @pytest.mark.parametrize("tokens,heads,kv,head,depth,tile", [
@@ -357,7 +353,7 @@ def test_fragment_rule_admits_the_cells_layers_on_a_tpu(
         tokens, heads, kv, head, depth + 24, jnp.bfloat16)
 
 
-# -- the step kernel against ``_cached_attention``'s one-token text --------
+# -- the step kernel against ``cached_attention``'s one-token text ---------
 
 def _step(b=5, kv=2, group=4, d=128, depth=2048, pos0=(0, 510, 511, 512, 2047),
           dtype=jnp.bfloat16, seed=0):
@@ -375,20 +371,17 @@ def _step(b=5, kv=2, group=4, d=128, depth=2048, pos0=(0, 510, 511, 512, 2047),
 
 def _step_text_and_kernel(rows, kv, dtype, block_k, monkeypatch, gate=False):
     """``(q, k, v, k_cache, v_cache) -> o (B, 1, heads, D)`` twice
-    through the model's ``_cached_attention``: its XLA text (the rule's
+    through ``ops/cached_attention``: its XLA text (the rule's
     branch off a TPU) and, the rule forced, the kernel in the
     interpreter; with ``gate`` a sigmoid gate of the query after it, as
     a gated layer applies one."""
-    import types
-
-    from ray_tpu.models.sequence_lm import SequenceLM
     from ray_tpu.ops import flash_attention as fa
-
-    stub = types.SimpleNamespace(kv_heads=kv, dtype=dtype)
+    from ray_tpu.ops.cached_attention import cached_attention
 
     def text(q, k, v, kc, vc):
-        o = SequenceLM._cached_attention(
-            stub, q, k, v, (kc, vc), rows, q.shape[-1] ** -0.5, scope="attn")[0]
+        o = cached_attention(
+            q, k, v, (kc, vc), rows, scale=q.shape[-1] ** -0.5, window=None,
+            dtype=dtype, scope="attn")[0]
         return o * jax.nn.sigmoid(q) if gate else o
 
     def kernel(*operands):

@@ -1,5 +1,5 @@
 """The sequence model's latent-attention, hyper-connection and
-sigmoid-router kinds (models/sequence_lm.py, ops/latent_attention.py,
+sigmoid-router kinds (models/sequence_lm, ops/latent_attention.py,
 ops/hyper_connection.py, ops/moe.py) held to the plain reference
 (perf/reference/xing4.py) on seeded weights at a small size with
 deliberately unequal dimensions: nope 16, rope 8, value 12, latent 24,
@@ -366,9 +366,11 @@ def test_expert_shares_add_up_to_the_uncut_layer():
             sl = slice(first, first + 2)
             share = {**p, **{k: p[k][sl] for k in
                              ("experts_gate", "experts_up", "experts_down")}}
-            part, (per_expert, absent, *_), _ = _model(lm)._moe(share, x, {"scope": ""})
+            part, _, stats = _model(lm).segments[-1].ffn.apply(
+                share, x, (), {"scope": "", "dtype": jnp.float32})
             total = total + (part - shared_only)
-            assert float(per_expert.sum() + absent) == 2 * T * 3
+            assert float(stats["moe_held_load"].sum()
+                         + stats["moe_slots_on_absent_experts"]) == 2 * T * 3
     np.testing.assert_allclose(total, whole, atol=2e-5)
 
 
@@ -418,6 +420,8 @@ def test_the_policys_grouped_learn_body_takes_the_reference_gradient(setup):
     def loss_fn(p, aux, part, rng, coeffs):
         stats = {}
         logits, value, _ = _model_forward(model, p, part, stats)
+        # the loss's own keys beside the model's, as the policy's hands them
+        stats.update(value_mean=jnp.mean(value), value_abs_max=jnp.max(jnp.abs(value)))
         return ref.ppo_loss(logits, value, part, config["algo_config"]), stats
 
     p = jax.tree_util.tree_map(jnp.asarray, params)
@@ -444,6 +448,49 @@ def test_the_policys_grouped_learn_body_takes_the_reference_gradient(setup):
     assert float(stats["moe_max_tokens_per_held_expert"]) >= float(
         stats["moe_tokens_per_held_expert"])
     assert float(stats["hc_res_row_sum_err_max"]) < 1e-5
+    # a key no kind declares: averaged over the groups, a ``_max`` kept
+    _, value, _ = _model_forward(model, p, dev)
+    value = np.asarray(value).reshape(2, -1)
+    np.testing.assert_allclose(stats["value_mean"], value.mean(1).mean(), rtol=1e-4)
+    np.testing.assert_allclose(stats["value_abs_max"], np.abs(value).max(), rtol=1e-4)
+
+
+def test_the_fused_lane_trains_the_lanes_in_groups_around_the_loss():
+    """PPO on the token env, ``env_backend: jax``: the learn program
+    takes a shard's streams through the whole stack and the loss in
+    groups (``loss_groups``) and hands ``reduce_group_stats`` the loss's
+    own statistics beside the model's; every one comes back one number
+    an update."""
+    from ray_tpu.algorithms.registry import get_algorithm_class
+
+    lm = dict(small_config()["algo_config"]["model"]["sequence_lm"],
+              max_position_embeddings=24)
+    algo = get_algorithm_class("PPO")(config={
+        "env": "TokenStreamJax-v0",
+        "env_config": {"vocab_size": VOCAB, "episode_length": 24, "phase_stride": 1},
+        "env_backend": "jax", "num_workers": 0, "num_envs_per_worker": 32,
+        "rollout_fragment_length": 8, "train_batch_size": 256,
+        "sgd_minibatch_size": 256, "num_sgd_iter": 1, "superstep": 1,
+        "gamma": 1.0, "lambda": 0.95, "lr": 1e-6, "grad_clip": 1.0,
+        "kl_coeff": 0.0, "entropy_coeff": 0.0, "seed": 3,
+        "model": {"use_sequence_lm": True, "sequence_lm": lm, "max_seq_len": 8,
+                  "dtype": "float32"},
+    })
+    try:
+        policy = algo.get_policy()
+        streams = 32 // policy.n_shards
+        policy.model.learn_streams = max(1, streams // 2)
+        assert policy.model.loss_groups(streams) == (2 if streams > 1 else 1)
+        for _ in range(2):
+            info = algo.train()["info"]["learner"]["default_policy"]
+            for key in ("total_loss", "entropy", "moe_tokens_per_held_expert",
+                        "moe_max_tokens_per_held_expert", "moe_rows_computed_share",
+                        "hc_res_row_sum_err_max", "attn_key_blocks_skipped_share"):
+                assert np.isfinite(info[key]) and np.ndim(info[key]) == 0, key
+            assert "moe_held_load" not in info
+            assert info["hc_res_row_sum_err_max"] < 1e-4
+    finally:
+        algo.cleanup()
 
 
 def _model_plain():

@@ -191,7 +191,7 @@ def test_every_layer_has_its_own_geometry_and_the_tree_matches(setup):
     config, params, model, _, _ = setup
     assert model.layer_types == tuple(KINDS)
     assert model.ffn_types == ("dense",) + ("experts",) * 4
-    got = model.attention
+    got = {seg.name: seg.mixer for seg in model.segments}
     assert [a.heads for a in got.values()] == [4, 6, 6, 6, 4]
     assert [a.rope for a in got.values()] == [
         "yarn", "default", "default", "default", "yarn"]
@@ -307,23 +307,26 @@ def test_loss_and_every_gradient_leaf_match_reference(setup):
 def _with_layers(model, only=None, **changed):
     """``model`` with the named fields of its attention layers'
     descriptions replaced (``only``: of that kind's)."""
-    model.attention = {
-        name: dataclasses.replace(a, **changed) if only in (None, a.kind) else a
-        for name, a in model.attention.items()}
+    model.segments = tuple(
+        seg._replace(mixer=dataclasses.replace(seg.mixer, **changed))
+        if only in (None, seg.mixer.kind) else seg for seg in model.segments)
     return model
 
 
 def _swapped_thetas(model):
     theta = {FULL: 1e4, SLIDING: 100.0}
-    model.attention = {
-        name: dataclasses.replace(a, theta=theta[a.kind])
-        for name, a in model.attention.items()}
+    model.segments = tuple(
+        seg._replace(mixer=dataclasses.replace(seg.mixer, theta=theta[seg.mixer.kind]))
+        for seg in model.segments)
     return model
 
 
-def _set(model, **attrs):
-    for k, v in attrs.items():
-        setattr(model, k, v)
+def _with_experts(model, **changed):
+    """``model`` with the named fields of its expert layers' description
+    replaced."""
+    model.segments = tuple(
+        seg._replace(ffn=dataclasses.replace(seg.ffn, **changed))
+        if seg.ffn.route_on else seg for seg in model.segments)
     return model
 
 
@@ -336,9 +339,9 @@ WRONG = {
     "thetas_swapped": _swapped_thetas,
     "gate_left_out": lambda m: _with_layers(m, gate=None),
     "q_k_norm_left_out": lambda m: _with_layers(m, qk_norm=False),
-    "scale_left_out": lambda m: _set(m, route_scale=1.0),
-    "softmax_router": lambda m: _set(m, scoring="softmax"),
-    "shared_expert_gated": lambda m: _set(m, shared_gated=True),
+    "scale_left_out": lambda m: _with_experts(m, scale=1.0),
+    "softmax_router": lambda m: _with_experts(m, scoring="softmax"),
+    "shared_expert_gated": lambda m: _with_experts(m, shared_gated=True),
 }
 
 
@@ -387,7 +390,7 @@ def test_one_head_count_for_both_kinds_fails_the_comparison(setup, form):
     del lm["num_attention_heads_per_layer"]
     one = SequenceLM(VOCAB, lm, dtype="float32")
     one.learn_streams = 2
-    assert [a.heads for a in one.attention.values()] == [4] * 5
+    assert [seg.mixer.heads for seg in one.segments] == [4] * 5
     assert one.param_shapes() != model.param_shapes()
     cut = {g: dict(l) for g, l in params.items()}
     for i in (1, 2, 3):
@@ -422,7 +425,7 @@ def test_a_gate_a_dimension_is_another_tree(setup):
     assert shapes["layer_0"]["q_proj"] == (32, 2 * 64)
     assert "g_proj" not in shapes["layer_0"] and "g_proj" not in shapes["layer_1"]
     assert "q_norm" not in shapes["layer_1"]
-    assert [a.gate for a in other.attention.values()] == [
+    assert [seg.mixer.gate for seg in other.segments] == [
         "element", None, None, None, "element"]
 
 
@@ -455,8 +458,10 @@ def test_the_four_shares_add_up_to_the_uncut_layer(tokens, top_k, lowering):
                 experts_held=[first, 2], num_experts_per_tok=top_k))
             mine = {k: v[first : first + 2] if k.startswith("experts_") else v
                     for k, v in params.items()}
-            out, load, _ = share._moe(mine, g, {"scope": ""})
-            assert float(load[0].sum() + load[1]) == tokens * top_k
+            out, _, load = share.segments[-1].ffn.apply(
+                mine, g, (), {"scope": "", "dtype": jnp.float32})
+            assert float(load["moe_held_load"].sum()
+                         + load["moe_slots_on_absent_experts"]) == tokens * top_k
             total = total + (out - shared[None])
     assert float(jnp.abs(want - shared[None]).max()) > 0.1
     np.testing.assert_allclose(total, want, atol=2e-5, rtol=1e-4)
@@ -499,20 +504,19 @@ def test_lagunas_keys_are_not_read_as_qwen3_nexts(setup):
     each, renormalised and scaled, and the shared expert has no gate.
     Without it the same keys read as before."""
     config, params, model, _, _ = setup
-    assert (model.scoring, model.route_scale, model.shared_gated) == (
-        "sigmoid", 2.5, False)
-    assert model.norm_topk and not model.select_bias and not model.route_on_input
-    assert model.shared_width == 16 and model.expert_act == "silu"
+    ffn = model.segments[2].ffn
+    assert (ffn.scoring, ffn.scale, ffn.shared_gated) == ("sigmoid", 2.5, False)
+    assert ffn.norm_topk and not ffn.select_bias and ffn.route_on == "stream"
+    assert ffn.shared_width == 16 and ffn.activation == "silu"
     lm = dict(config["algo_config"]["model"]["sequence_lm"])
     del lm["moe_routed_scaling_factor"]
-    qwen = SequenceLM(VOCAB, lm, dtype="float32")
-    assert (qwen.scoring, qwen.route_scale, qwen.shared_gated) == (
-        "softmax", 1.0, True)
+    qwen = SequenceLM(VOCAB, lm, dtype="float32").segments[2].ffn
+    assert (qwen.scoring, qwen.scale, qwen.shared_gated) == ("softmax", 1.0, True)
     # the route itself against the reference's
     x = jnp.asarray(np.random.default_rng(2).standard_normal((2, 5, 32)), jnp.float32)
     with jax.default_matmul_precision("highest"):
         idx, w = ref._route(params["layer_2"], x, ref.sizes(config, VOCAB))
-        got_idx, got_w = model._route(params["layer_2"], x.reshape(10, 32))
+        got_idx, got_w = ffn.route(params["layer_2"], x.reshape(10, 32))
     assert np.array_equal(np.asarray(idx), np.asarray(got_idx))
     np.testing.assert_allclose(got_w, w, atol=1e-6)
     np.testing.assert_allclose(np.asarray(got_w).sum(-1), 2.5, atol=1e-5)
@@ -655,9 +659,9 @@ def test_the_description_reads_the_other_families_as_before():
         "linear_conv_kernel_dim": 4, "num_experts": 2, "num_experts_per_tok": 2,
         "moe_intermediate_size": 16, "shared_expert_intermediate_size": 16,
         "max_position_embeddings": 32})
-    assert qwen.attention == {"layer_3": AttentionLayer(
+    assert qwen.segments[3].mixer == AttentionLayer(
         kind=FULL, heads=4, kv_heads=2, head_dim=8, scale=8 ** -0.5, rotary=2,
-        theta=1e6, gate="element", qk_norm=True)}
+        theta=1e6, gate="element", qk_norm=True)
     assert qwen.param_shapes()["layer_3"]["q_proj"] == (32, 64)
     window = SequenceLM(VOCAB, {
         "hidden_size": 32, "num_hidden_layers": 2,
@@ -665,15 +669,16 @@ def test_the_description_reads_the_other_families_as_before():
         "sliding_window_size": 8, "rope_theta": 1.5e6, "num_attention_heads": 4,
         "num_key_value_heads": 2, "head_dim": 8, "attention_multiplier": 0.5,
         "intermediate_size": 48, "max_position_embeddings": 32})
-    assert window.attention == {
+    mixers = {seg.name: seg.mixer for seg in window.segments}
+    assert mixers == {
         "layer_0": AttentionLayer(
             kind="attention", heads=4, kv_heads=2, head_dim=8, scale=0.5,
             theta=1.5e6),
         "layer_1": AttentionLayer(
             kind=SLIDING, heads=4, kv_heads=2, head_dim=8, scale=8 ** -0.5,
             window=8, rotary=8, theta=1.5e6)}
-    assert [a.scope for a in window.attention.values()] == ["attn", "swa"]
-    assert [a.rope for a in window.attention.values()] == ["none", "default"]
+    assert [a.scope for a in mixers.values()] == ["attn", "swa"]
+    assert [a.rope for a in mixers.values()] == ["none", "default"]
 
 
 @pytest.mark.parametrize("precision", ["int8", "fp8"])

@@ -1,4 +1,4 @@
-"""The sequence model (models/sequence_lm.py) held to the plain
+"""The sequence model (models/sequence_lm) held to the plain
 reference (perf/reference/qwen3_next.py) on seeded weights at a small
 size: logits, values, loss, every gradient leaf; the one-token
 recurrence against the chunked form; the shares of an expert layer
@@ -251,9 +251,11 @@ def test_expert_shares_add_up_to_the_uncut_layer():
             sl = slice(first, first + 2)
             share = {**p, **{k: p[k][sl] for k in
                              ("experts_gate", "experts_up", "experts_down")}}
-            part, (per_expert, absent, *_), _ = model._moe(share, x, {"scope": ""})
+            part, _, stats = model.segments[0].ffn.apply(
+                share, x, (), {"scope": "", "dtype": jnp.float32})
             total = total + (part - shared_only)
-            assert float(per_expert.sum() + absent) == 2 * T * 3
+            assert float(stats["moe_held_load"].sum()
+                         + stats["moe_slots_on_absent_experts"]) == 2 * T * 3
     np.testing.assert_allclose(total, whole, atol=2e-5)
 
 

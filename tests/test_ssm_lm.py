@@ -1,6 +1,6 @@
 """The sequence model's state-space and position-free attention kinds,
 a model with no expert layer, the tied head and the Granite multipliers
-(models/sequence_lm.py, ops/ssd.py) held to the plain reference
+(models/sequence_lm, ops/ssd.py) held to the plain reference
 (perf/reference/granite4h.py) on seeded weights at a small size: hidden
 32, six layers (mamba x 2, attention, mamba x 3: two stacked runs of
 unequal length), 8 state-space heads of 8 with a state of 16, chunks of

@@ -76,13 +76,11 @@ def _rows_with_a_reset(pos0, t):
 
 @pytest.mark.parametrize("cell", list(FRAGMENT_SHAPES))
 def test_fragment_attention_on_tpu(cell, monkeypatch):
-    """``_cached_attention``'s fragment form takes the kernel on the
+    """``cached_attention``'s fragment form takes the kernel on the
     chip by its own rule, and output and gradients agree with the XLA
     text (the rule's other branch) within bfloat16's rounding."""
-    import types
-
-    from ray_tpu.models.sequence_lm import SequenceLM
     from ray_tpu.ops import flash_attention
+    from ray_tpu.ops.cached_attention import cached_attention
     from ray_tpu.telemetry import metrics
 
     b, t, kv, group, d, depth, window = FRAGMENT_SHAPES[cell]
@@ -102,14 +100,13 @@ def test_fragment_attention_on_tpu(cell, monkeypatch):
         pos0 = jnp.minimum(pos0, depth - t)
     seg, positions = _rows_with_a_reset(pos0, t)
     rows = {"seg": seg, "positions": positions, "pos0": pos0}
-    stub = types.SimpleNamespace(kv_heads=kv, dtype=jnp.bfloat16)
 
     def run():
         # new functions a side: a jit of the same one would not trace again
         def attention(q, k, v):
-            return SequenceLM._cached_attention(
-                stub, q, k, v, caches, rows, d ** -0.5, window=window,
-                scope="swa" if window else None)[0]
+            return cached_attention(
+                q, k, v, caches, rows, scale=d ** -0.5, window=window,
+                dtype=jnp.bfloat16, scope="swa" if window else "attn")[0]
 
         grads = jax.jit(jax.grad(
             lambda q, k, v: jnp.sum(attention(q, k, v) * w), argnums=(0, 1, 2)))
@@ -143,15 +140,13 @@ STEP_SHAPES = {
 
 @pytest.mark.parametrize("cell", list(STEP_SHAPES))
 def test_step_attention_on_tpu(cell, monkeypatch):
-    """``_cached_attention``'s one-token form over a full-depth cache
+    """``cached_attention``'s one-token form over a full-depth cache
     takes the step kernel on the chip by its own rule and a ring's the
     text, and the kernel's output agrees with the text (the rule's other
     branch) within bfloat16's rounding: an empty stream, a block's edge
     from both sides and a full cache in one batch."""
-    import types
-
-    from ray_tpu.models.sequence_lm import SequenceLM
     from ray_tpu.ops import flash_attention
+    from ray_tpu.ops.cached_attention import cached_attention
     from ray_tpu.telemetry import metrics
 
     b, kv, group, d, depth = STEP_SHAPES[cell]
@@ -165,12 +160,11 @@ def test_step_attention_on_tpu(cell, monkeypatch):
     pos0 = jnp.asarray([0, 510, 511, 512, depth - 1], jnp.int32)
     rows = {"seg": jnp.zeros((b, 1), jnp.int32), "positions": pos0[:, None],
             "pos0": pos0}
-    stub = types.SimpleNamespace(kv_heads=kv, dtype=jnp.bfloat16)
 
     def run(window=None):
-        return jax.jit(lambda q, k, v: SequenceLM._cached_attention(
-            stub, q, k, v, caches, rows, d ** -0.5, window=window,
-            scope="swa" if window else "attn")[0])(q, k, v)
+        return jax.jit(lambda q, k, v: cached_attention(
+            q, k, v, caches, rows, scale=d ** -0.5, window=window,
+            dtype=jnp.bfloat16, scope="swa" if window else "attn")[0])(q, k, v)
 
     count = lambda path: metrics.attention_step_lowerings().get(path, 0)
     before = count("kernel"), count("xla")

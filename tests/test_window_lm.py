@@ -1,7 +1,7 @@
 """The sequence model's sliding-window kind on its ring cache beside
 position-free full attention, the router that reads the layer's input
 before the mixer, ReGLU experts and an expert layer with no shared
-expert (models/sequence_lm.py, ops/moe.py) held to the plain reference
+expert (models/sequence_lm, ops/moe.py) held to the plain reference
 (perf/reference/smallthinker.py) on seeded weights at a small size:
 hidden 32, four layers (full, window, window, window), 4 heads of 8 over
 2 KV heads, a window of 8 in episodes of 32, fragments of 16 (so a
@@ -29,9 +29,8 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 
-from ray_tpu.models import sequence_lm
 from ray_tpu.models.sequence_lm import SequenceLM
-from ray_tpu.ops import moe
+from ray_tpu.ops import cached_attention, moe
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 VOCAB = 64
@@ -272,9 +271,10 @@ def _with_layers(model, **changed):
     """``model`` with the named fields of its attention layers'
     descriptions replaced, where ``only`` (a kind) says which."""
     only = changed.pop("only")
-    model.attention = {
-        name: dataclasses.replace(a, **changed) if a.kind == only else a
-        for name, a in model.attention.items()}
+    model.segments = tuple(
+        seg._replace(mixer=dataclasses.replace(seg.mixer, **changed))
+        if getattr(seg.mixer, "kind", None) == only else seg
+        for seg in model.segments)
     return model
 
 
@@ -290,7 +290,10 @@ def _rope_on_the_full_layer(config):
 
 def _router_on_the_normed_stream(config):
     model = _model(config)
-    model.route_on_input = False  # routes where the other families do
+    # routes where the other families do
+    model.segments = tuple(
+        seg._replace(ffn=dataclasses.replace(seg.ffn, route_on="stream"))
+        for seg in model.segments)
     return model
 
 
@@ -386,9 +389,12 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(tokens, top_k, lowering):
                 moe_num_active_primary_experts=top_k))
             mine = {k: v[first : first + 1] if k.startswith("experts_") else v
                     for k, v in params.items()}
-            route = share._route(mine, x_in.reshape(tokens, 32))
-            out, load, _ = share._moe(mine, g, {"scope": "", "route": route})
-            assert float(load[0].sum() + load[1]) == tokens * top_k
+            ffn = share.segments[-1].ffn
+            route = ffn.route(mine, x_in.reshape(tokens, 32))
+            out, _, load = ffn.apply(
+                mine, g, (), {"scope": "", "dtype": jnp.float32, "route": route})
+            assert float(load["moe_held_load"].sum()
+                         + load["moe_slots_on_absent_experts"]) == tokens * top_k
             total = total + out
     assert float(jnp.abs(want).max()) > 0.1
     np.testing.assert_allclose(total, want, atol=1e-5, rtol=1e-4)
@@ -404,11 +410,12 @@ def test_the_route_is_the_renormalised_softmax_top_k_of_the_layers_input(setup):
     x = jnp.asarray(np.random.default_rng(2).standard_normal((2, 5, 32)), jnp.float32)
     with jax.default_matmul_precision("highest"):
         idx, w = ref._route(params["layer_2"], x, z)
-        got_idx, got_w = model._route(params["layer_2"], x.reshape(10, 32))
+        ffn = model.segments[2].ffn
+        got_idx, got_w = ffn.route(params["layer_2"], x.reshape(10, 32))
     assert np.array_equal(np.asarray(idx), np.asarray(got_idx))
     np.testing.assert_allclose(got_w, w, atol=1e-6)
-    assert model.route_on_input and model.expert_act == "relu"
-    assert model.shared_width == 0
+    assert ffn.route_on == "input" and ffn.activation == "relu"
+    assert ffn.shared_width == 0
 
 
 def test_gated_mlp_takes_its_activation_as_an_argument():
@@ -437,7 +444,7 @@ def test_gated_mlp_takes_its_activation_as_an_argument():
 ])
 def test_streams_of_a_score_block_follow_from_the_rows_it_sees(
         heads, tokens, rows, block):
-    assert sequence_lm._attn_env_block(heads, tokens, rows) == block
+    assert cached_attention.env_block(heads, tokens, rows) == block
 
 
 def test_window_statistic_and_lowering_counter(setup):
