@@ -40,6 +40,9 @@ ACTION_PROB = "action_prob"
 VF_PREDS = "vf_preds"
 ADVANTAGES = "advantages"
 VALUE_TARGETS = "value_targets"
+# of a policy that commits a block of tokens a step: the pass that
+# committed each token (models/sequence_lm/generation.py)
+UNMASK_STEP = "unmask_step"
 SEQ_LENS = "seq_lens"
 STATE_IN_PREFIX = "state_in_"
 STATE_OUT_PREFIX = "state_out_"
@@ -98,6 +101,7 @@ class SampleBatch(dict):
     VF_PREDS = VF_PREDS
     ADVANTAGES = ADVANTAGES
     VALUE_TARGETS = VALUE_TARGETS
+    UNMASK_STEP = UNMASK_STEP
     SEQ_LENS = SEQ_LENS
 
     def __init__(self, *args, **kwargs):

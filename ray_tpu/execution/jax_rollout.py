@@ -128,8 +128,10 @@ class JaxRolloutEngine:
         self._seed = seed
         self._metrics: List[RolloutMetrics] = []
         # ``env.report_actions``: the last dispatch's actions, host
-        # numpy ``([K,] T, N)``
+        # numpy ``([K,] T, N)``, and of a model that commits a block a
+        # step the pass that committed each (its trace)
         self.last_actions = None
+        self.last_trace = None
         self._rollout_fn = None
         self._body = None
         self.batch_size = self.N * self.T
@@ -145,6 +147,24 @@ class JaxRolloutEngine:
                 f"the model's max_seq_len {self.unroll}: the device lane "
                 "trains a model with state on whole unrolls"
             )
+
+        # a model that commits a block of tokens a lane step: every
+        # length the lane counts in env steps is whole blocks, so that a
+        # fragment, an unroll and an episode all start on a block's first
+        # token (``T`` stays the count of ENV steps)
+        self.tokens_per_step = int(getattr(policy.model, "tokens_per_step", 1))
+        if self.tokens_per_step > 1:
+            lengths = {
+                "rollout_fragment_length": self.T, "max_seq_len": self.unroll,
+                **{k: int(env.config[k]) for k in ("episode_length", "phase_stride")
+                   if k in env.config},
+            }
+            for name, n in lengths.items():
+                if n % self.tokens_per_step:
+                    raise ValueError(
+                        f"{name} {n} is not a multiple of the {self.tokens_per_step} "
+                        "tokens the model commits a step"
+                    )
 
         # initial env carry, resident and row-sharded from step zero
         keys = env_keys(seed, self.N)
@@ -203,7 +223,17 @@ class JaxRolloutEngine:
             getattr(policy.model, "supports_stored_train_state", False)
         )
 
+        block = self.tokens_per_step
+
         def body(params, carry, ro_rngs, coeffs):
+            if block > 1:
+                # V of the state a stream's next block starts from
+                next_value = lambda obs, state: policy.block_first_value(
+                    params, state)
+            else:
+                next_value = lambda obs, state: value_fwd(
+                    params, obs[:, None], state)[1]
+
             def step(c, key_t):
                 env_state, obs, ep_ret, ep_len, mstate = c
                 # pin each sub-program's fusion boundary so it
@@ -227,6 +257,44 @@ class JaxRolloutEngine:
                     actions, extra = jax.lax.optimization_barrier(
                         (actions, extra)
                     )
+                return env_steps(c[:4], actions, extra, mstate2, params_b)
+
+            def block_step(c, key_t):
+                """A lane step of a model that commits a block: ONE
+                block action of every stream (the model's denoise and
+                commit forwards), then the block's tokens through the
+                env one after another, in position order. The model
+                reads no observation: the last token is a row of its
+                cache."""
+                with jax.named_scope("rollout/act"):
+                    params_b, key_t = jax.lax.optimization_barrier(
+                        (params, key_t)
+                    )
+                    actions, mstate2, extra = policy.action_block_body(
+                        params_b, key_t, c[4]
+                    )
+                    actions, extra = jax.lax.optimization_barrier(
+                        (actions, extra)
+                    )
+                outs = []
+                for j in range(block):
+                    c, out = env_steps(
+                        c[:4], actions[:, j],
+                        {k: v[:, j] for k, v in extra.items()}, mstate2,
+                        params_b, last=j == block - 1,
+                    )
+                    outs.append(out)
+                return c, jax.tree_util.tree_map(
+                    lambda *xs: jnp.stack(xs), *outs
+                )
+
+            def env_steps(c, actions, extra, mstate2, params_b, last=True):
+                """What follows the act: the env's step and auto-reset,
+                the row, the metrics and the model state's reset.
+                ``last``: the token closes the lane step's action (of a
+                block, only its last token can end an episode or need
+                a bootstrap: the engine refused other lengths)."""
+                env_state, obs, ep_ret, ep_len = c
                 with jax.named_scope("rollout/env_step"):
                     env_state_b, actions_b = (
                         jax.lax.optimization_barrier(
@@ -258,7 +326,11 @@ class JaxRolloutEngine:
                     # the learn form opens a new episode where the
                     # rollout reset the state: at a row that starts one
                     row["resets"] = (ep_len == 0).astype(jnp.float32)
-                if mode == "gae" and stateful:
+                if mode == "gae" and stateful and not last:
+                    row["_v_next"] = sharding_lib.varying(
+                        jnp.zeros(rew.shape, jnp.float32), axis
+                    )
+                elif mode == "gae" and stateful:
                     # V(final obs) needs a forward from the advanced
                     # state, which the next step's act computes anyway
                     # unless the episode was cut: only a truncation
@@ -267,7 +339,7 @@ class JaxRolloutEngine:
                         row["_v_next"] = jax.lax.cond(
                             jnp.any(trunc & ~term),
                             lambda: sharding_lib.varying(
-                                value_fwd(params, obs2[:, None], mstate2)[1], axis
+                                next_value(obs2, mstate2), axis
                             ),
                             lambda: sharding_lib.varying(
                                 jnp.zeros(rew.shape, jnp.float32), axis
@@ -289,11 +361,15 @@ class JaxRolloutEngine:
                     }
                     if report_actions:
                         metrics["actions"] = actions
+                        if block > 1:  # with the pass that committed each
+                            metrics[SampleBatch.UNMASK_STEP] = extra[
+                                SampleBatch.UNMASK_STEP
+                            ]
                     env_state = tree_where(done, env_state3, env_state2)
                     obs_next = tree_where(done, obs3, obs2)
                     ep_ret = jnp.where(done, 0.0, ep_ret2)
                     ep_len = jnp.where(done, 0, ep_len2)
-                if stateful:
+                if stateful and last:
                     # only a step on which some stream ended pays for
                     # the pass over the state (a language model's is
                     # hundreds of MB; an episode ends once in thousands
@@ -310,6 +386,17 @@ class JaxRolloutEngine:
                     (row, metrics),
                 )
 
+            def scan_steps(c, keys):
+                """``keys``' env steps: one scan step each, or one a
+                block (the first of the block's keys), its tokens' rows
+                then laid out one a step like every other model's."""
+                if block == 1:
+                    return jax.lax.scan(step, c, keys)
+                c, ys = jax.lax.scan(block_step, c, keys[::block])
+                return c, jax.tree_util.tree_map(
+                    lambda x: x.reshape((-1,) + x.shape[2:]), ys
+                )
+
             c0 = (
                 carry["env"],
                 carry["obs"],
@@ -323,11 +410,11 @@ class JaxRolloutEngine:
                     starts = jax.tree_util.tree_map(
                         lambda x: x[None], c0[4]
                     )
-                c1, (rows, metrics) = jax.lax.scan(step, c0, ro_rngs)
+                c1, (rows, metrics) = scan_steps(c0, ro_rngs)
             else:
                 # the state at the start of every unroll-step chunk
                 def chunk(c, keys):
-                    c2, ys = jax.lax.scan(step, c, keys)
+                    c2, ys = scan_steps(c, keys)
                     return c2, (ys, c[4])
 
                 c1, ((rows, metrics), starts) = jax.lax.scan(
@@ -363,7 +450,7 @@ class JaxRolloutEngine:
                             # the tail's bootstrap: one forward from
                             # the final state (not committed)
                             with jax.named_scope("rollout/act"):
-                                tail = value_fwd(params, obs[:, None], mstate)[1][None]
+                                tail = next_value(obs, mstate)[None]
                         else:
                             tail = fresh[-1:]
                         # interior rows reuse the act-path values
@@ -450,9 +537,7 @@ class JaxRolloutEngine:
         drained (host numpy) metrics tree."""
         self._carry = carry
         self._record_metrics(metrics)
-        telemetry_metrics.inc_env_steps_on_device(
-            int(np.asarray(metrics["done"]).size)
-        )
+        self._count_env_steps(int(np.asarray(metrics["done"]).size))
 
     # -- standalone rollout (replay fill / per-update lane) --------------
 
@@ -547,8 +632,15 @@ class JaxRolloutEngine:
                     "bytes", sharding_lib.tree_nbytes(metrics)
                 )
             self._record_metrics(metrics)
-            telemetry_metrics.inc_env_steps_on_device(self.batch_size)
+            self._count_env_steps(self.batch_size)
         return dict(batch), self.batch_size
+
+    def _count_env_steps(self, steps: int) -> None:
+        telemetry_metrics.inc_env_steps_on_device(steps)
+        if self.tokens_per_step > 1:
+            telemetry_metrics.note_diffusion_rollout(
+                self.policy.model.generation.token_passes(steps), steps
+            )
 
     def learn_batch(self, batch: Dict[str, Any]) -> Dict[str, Any]:
         """The learn-column subset of a :meth:`rollout` batch (what
@@ -568,6 +660,7 @@ class JaxRolloutEngine:
 
     def _record_metrics(self, metrics) -> None:
         self.last_actions = metrics.get("actions")
+        self.last_trace = metrics.get(SampleBatch.UNMASK_STEP)
         done = np.asarray(metrics["done"]).reshape(-1)
         if not done.any():
             return

@@ -8,6 +8,7 @@ from typing import Dict, NamedTuple, Tuple
 
 import numpy as np
 
+from ray_tpu.models.sequence_lm.generation import Autoregressive, BlockDiffusion
 from ray_tpu.models.sequence_lm.kinds import (
     AttentionLayer, DeltaNetLayer, DenseLayer, ExpertLayer, HyperResidual,
     LatentLayer, MambaLayer, PlainResidual)
@@ -16,10 +17,34 @@ from ray_tpu.ops import latent_attention
 LINEAR, FULL, LATENT = "linear_attention", "full_attention", "latent_attention"
 MAMBA, ATTENTION, SLIDING = "mamba", "attention", "sliding_attention"
 DENSE, EXPERTS = "dense", "experts"
+# families whose every layer is ``qwen3_moe``'s: full attention with q/k
+# norms and no gate over an expert layer with no shared expert
+_QWEN3_MOE_STACKS = ("sdar_moe",)
+
+
+def _qwen3_moe_stack(config: Dict) -> bool:
+    return config.get("model_type") in _QWEN3_MOE_STACKS
+
+
+def generation_of(config: Dict):
+    """How the family generates. ``model_type: sdar_moe`` is a
+    block-diffusion model: ``block_length`` tokens a step in
+    ``denoising_steps`` passes, ``mask_token_id`` the row of the
+    embedding that stands for ``[MASK]`` (the published config gives
+    none of the three: the cell's file states them as assumed). Every
+    other family is autoregressive."""
+    c = config
+    if _qwen3_moe_stack(c):
+        return BlockDiffusion(
+            block_length=int(c["block_length"]),
+            denoising_steps=int(c["denoising_steps"]),
+            mask_token_id=int(c["mask_token_id"]))
+    return Autoregressive()
 
 
 def layer_types_of(config: Dict) -> Tuple[str, ...]:
-    """The pattern: ``layer_types`` if stated; all latent attention
+    """The pattern: ``layer_types`` if stated; all full attention for
+    a ``qwen3_moe`` stack; all latent attention
     where the config has a ``kv_lora_rank``; by
     ``sliding_window_layout`` where the config has one (1: a window
     layer, which is also where ``rope_layout`` turns; 0: full depth and
@@ -29,6 +54,8 @@ def layer_types_of(config: Dict) -> Tuple[str, ...]:
         # a published list: its first ``num_hidden_layers``
         return tuple(config["layer_types"])[:config.get("num_hidden_layers")]
     layers = int(config["num_hidden_layers"])
+    if _qwen3_moe_stack(config):
+        return (FULL,) * layers
     if "sliding_window_layout" in config:
         window = list(config["sliding_window_layout"])[:layers]
         if window != list(config.get("rope_layout", window))[:layers]:
@@ -67,11 +94,16 @@ def attention_layers_of(config: Dict, layer_types) -> Dict[int, AttentionLayer]:
       head (``g_proj``); without the key a ``"full_attention"`` layer is
       ``qwen3_next``'s, gated a dimension out of ``q_proj``, and the
       other kinds have none. A gated layer norms ``q`` and ``k`` over
-      the head;
+      the head, and so does a ``qwen3_moe`` stack's, which has no gate;
+    - mask: by blocks of the generation's ``block_length`` where the
+      family generates by block diffusion (``generation_of``), causal
+      otherwise;
     - scale: ``attention_multiplier`` on an ``"attention"`` layer that
       states one, else ``head^-1/2``."""
     c = config
     out = {}
+    plain = _qwen3_moe_stack(c)
+    block = generation_of(c).tokens_per_step
     per_layer = c.get("num_attention_heads_per_layer")
     for i, kind in enumerate(layer_types):
         if kind not in (FULL, ATTENTION, SLIDING):
@@ -99,7 +131,8 @@ def attention_layers_of(config: Dict, layer_types) -> Dict[int, AttentionLayer]:
             rotary = head_dim
         elif c.get("position_embedding_type", "nope") != "nope":
             raise ValueError('an "attention" layer takes no positions')
-        gate = "head" if c.get("gating") else "element" if kind == FULL else None
+        gate = "head" if c.get("gating") else (
+            "element" if kind == FULL and not plain else None)
         scale = head_dim ** -0.5
         if kind == ATTENTION:
             scale = float(c.get("attention_multiplier", scale))
@@ -107,7 +140,7 @@ def attention_layers_of(config: Dict, layer_types) -> Dict[int, AttentionLayer]:
             kind=kind, heads=heads, kv_heads=int(c["num_key_value_heads"]),
             head_dim=head_dim, scale=scale, window=window, rotary=rotary,
             theta=theta, yarn=yarn, rope_factor=factor, gate=gate,
-            qk_norm=gate is not None)
+            qk_norm=plain or gate is not None, block=block)
     return out
 
 
@@ -151,7 +184,7 @@ def _expert_layer(c: Dict, experts: int) -> ExpertLayer:
     ``qwen3_next`` does and routes as DeepSeek-V3 does without the
     selection bias. The shared expert: ``qwen3_next`` states its width
     and gates it; DeepSeek-V3 counts shared experts of the routed
-    width."""
+    width; a ``qwen3_moe`` stack has none."""
     primary = "moe_num_primary_experts" in c
     if primary and not c.get("moe_primary_router_apply_softmax", True):
         raise ValueError("a primary router without its softmax is not supported")
@@ -175,8 +208,13 @@ def _expert_layer(c: Dict, experts: int) -> ExpertLayer:
             "routed_scaling_factor", c.get("moe_routed_scaling_factor", 1.0))),
         shared_width=int(
             c["shared_expert_intermediate_size"] if stated
-            else int(c.get("n_shared_experts", 0 if primary else 1)) * width),
+            else int(c.get(
+                "n_shared_experts",
+                0 if primary or _qwen3_moe_stack(c) else 1)
+            ) * width),
         shared_gated=stated and not scaled,
+        # every masked position of a pass routes alike
+        alone=3 if generation_of(c).tokens_per_step > 1 else 0,
     )
 
 
@@ -200,8 +238,10 @@ def describe(config: Dict) -> Dict:
     residual by ``hc_mult``. ``segments``: a run of consecutive layers
     of one ``stacked`` description is one group ``"layers_<first>_<last>"``,
     every other layer its own ``"layer_<n>"``. The Granite multipliers,
-    each 1 where the config states none."""
+    each 1 where the config states none. ``generation``: how the family
+    generates (:func:`generation_of`)."""
     c = config
+    generation = generation_of(c)
     layer_types = layer_types_of(c)
     layers = len(layer_types)
     attention = attention_layers_of(c, layer_types)
@@ -236,6 +276,11 @@ def describe(config: Dict) -> Dict:
             eps=float(c.get("hc_eps", 1e-6)),
             clamp=(float(c.get("mhc_h_res_clamp_min", -30.0)),
                    float(c.get("mhc_h_res_clamp_max", 30.0))))
+    if generation.tokens_per_step > 1 and any(
+            i not in attention or attention[i].window is not None
+            for i in range(layers)):
+        raise ValueError(
+            "a block a step needs every mixer to be full-depth attention")
     runs = []
     for i, kind in enumerate(layer_types):
         mixer, ffn = attention.get(i) or made[kind], ffn_of[ffn_types[i]]
@@ -245,6 +290,7 @@ def describe(config: Dict) -> Dict:
             runs.append([i, mixer, ffn, 1])
     return dict(
         layer_types=layer_types, ffn_types=ffn_types, residual=residual,
+        generation=generation,
         segments=tuple(
             Segment(f"layers_{i}_{i + n - 1}" if mixer.stacked else f"layer_{i}",
                     mixer, ffn, n) for i, mixer, ffn, n in runs),

@@ -30,7 +30,12 @@ class Kind:
     D)``; ``ctx`` holds the fragment's rows (``seg``, ``fresh``,
     ``positions`` ``(B, T)``, ``pos0`` ``(B,)``), the ``scope`` prefix,
     the operands' ``dtype``, the norms' ``eps`` and the DeltaNet
-    ``chunk``."""
+    ``chunk``. Where the model's generation commits a block a step
+    (``generation.py``) the rows may say ``step`` (the ``T`` tokens are
+    one block of the lane's, not a fragment), ``keep`` (the mixer hands
+    the rows of its own that a later pass over the same fragment reads
+    back after its state leaves: an attention layer's keys and values)
+    or ``clean`` (those rows of the clean pass, handed to a noisy one)."""
 
     # a run of consecutive such layers is ONE group of the parameter
     # tree, its leaves on a leading layer axis
@@ -184,7 +189,12 @@ class AttentionLayer(Kind):
       on part of a full layer's head, ``cos`` and ``sin`` times its
       ``attention_factor``), and ``gating`` puts a gate a head
       (``g_proj``) and the q/k norms on EVERY layer, so a gated layer
-      stands on a ring.
+      stands on a ring;
+    - SDAR's layers (``model_type: sdar_moe``; arXiv:2510.06303): the
+      ``qwen3_moe`` layer (q/k norm, RoPE over the whole head, no gate)
+      under a BLOCK-causal mask: a key at position ``p_k`` is seen from
+      ``p_q`` iff ``p_k // block <= p_q // block`` (``block`` 1 is
+      causal, every other family's).
 
     State: keys and values, ``(rows, kv heads x head)`` in the operands'
     type, one row a position, flat, so that the device tiles (rows, row)
@@ -215,6 +225,8 @@ class AttentionLayer(Kind):
     # head and token) or None
     gate: Optional[str] = None
     qk_norm: bool = False
+    # the mask's rule: positions a block (1: causal)
+    block: int = 1
 
     stats = {
         # rows inside the window a query of a window layer saw
@@ -283,7 +295,9 @@ class AttentionLayer(Kind):
             q, k = normed_and_turned(q, "q_norm"), normed_and_turned(k, "k_norm")
         o, new, stats = cached_attention.cached_attention(
             q, k, v, state, ctx, scale=self.scale, window=self.window,
-            dtype=dtype, scope=scope)
+            dtype=dtype, scope=scope, block=self.block)
+        if ctx.get("keep"):
+            new = new + (k, v)
         if "pairs_seen" in stats:
             stats["window_rows_seen_mean"] = stats.pop("pairs_seen") / (b * t)
         if self.gate is not None:
@@ -607,7 +621,12 @@ class ExpertLayer(Kind):
       (ReGLU) where the others gate with SiLU, and there is NO shared
       expert;
     - Laguna: a sigmoid each, top-k with no selection bias, the chosen
-      scores over their sum times ``scale``, the shared expert UNGATED.
+      scores over their sum times ``scale``, the shared expert UNGATED;
+    - SDAR (``qwen3_moe``'s layer): softmax, top-k, renormalised, NO
+      shared expert; the model generates by masked diffusion, so every
+      ``[MASK]`` of a noisy pass meets the first layer's router as the
+      same vector and ``alone`` of the held experts may take a whole
+      pass's tokens.
 
     Scopes: ``moe/route`` (entered before the mixer where the router
     reads the input), ``moe/experts``, ``moe/shared``."""
@@ -625,6 +644,9 @@ class ExpertLayer(Kind):
     scale: float = 1.0
     shared_width: int = 0
     shared_gated: bool = False
+    # held experts of a grouped call that may outgrow their buffers and
+    # run over every token before the call goes dense (``ops/moe.py``)
+    alone: int = 0
 
     init_rules = {
         "select_bias": lambda key, shape: 0.01 * jax.random.normal(key, shape)}
@@ -688,7 +710,8 @@ class ExpertLayer(Kind):
                     axis=(0, 2), dtype=jnp.float32),
                 "moe_slots_on_absent_experts": absent,
                 "moe_rows_computed_share": moe.rows_computed(
-                    per_expert, b * t, self.top_k, self.router_outputs, lowering
+                    per_expert, b * t, self.top_k, self.router_outputs, lowering,
+                    self.alone,
                 ) / (b * t * held),
                 "moe_routes": indices,
             }
@@ -701,7 +724,7 @@ class ExpertLayer(Kind):
                 routed = moe.grouped_experts_product(
                     flat, *experts, indices, weights, per_expert,
                     self.first, self.router_outputs, dtype=dtype,
-                    activation=self.activation,
+                    activation=self.activation, alone=self.alone,
                 )
         if not self.shared_width:  # the routed sum alone
             return routed.reshape(b, t, d), (), stats

@@ -45,8 +45,8 @@ class SequenceLM:
     def __init__(self, num_outputs: int, config: Dict, dtype: str = "bfloat16"):
         self.config = dict(config)
         self.vocab = int(num_outputs)
-        # layer_types, ffn_types, segments, residual, hidden, positions,
-        # eps, embed_scale, logits_scale, tied_head
+        # layer_types, ffn_types, segments, residual, generation, hidden,
+        # positions, eps, embed_scale, logits_scale, tied_head
         vars(self).update(describe(self.config))
         # streams of a fragment batch the learn form runs at once (tests
         # shrink it)
@@ -61,7 +61,8 @@ class SequenceLM:
     @property
     def _reductions(self) -> Dict[str, str]:
         """Every statistic a kind of this model declares."""
-        kinds = [self.residual] + [k for s in self.segments for k in (s.mixer, s.ffn)]
+        kinds = [self.residual, self.generation] + [
+            k for s in self.segments for k in (s.mixer, s.ffn)]
         return {k: v for kind in kinds for k, v in kind.stats.items()}
 
     # -- state -----------------------------------------------------------
@@ -185,13 +186,27 @@ class SequenceLM:
 
     # -- forward ---------------------------------------------------------
 
+    @property
+    def tokens_per_step(self) -> int:
+        """Tokens a lane step commits (``generation.py``)."""
+        return self.generation.tokens_per_step
+
     def apply(self, params, obs, state, resets=None, scope: str = "",
-              stats_out: Optional[Dict] = None, **_):
+              stats_out: Optional[Dict] = None, commit: Optional[bool] = None,
+              trace=None, **_):
         """``obs`` ``(B, T[, 1])`` token ids; ``state`` as
         ``initial_state``; ``resets`` ``(B, T)`` (1.0 where a token
         opens an episode). Returns ``(logits (B*T, vocab), value
         (B*T,), state)``. ``scope`` prefixes the named scopes;
-        ``stats_out`` receives the kinds' statistics."""
+        ``stats_out`` receives the kinds' statistics.
+
+        A model that generates a block a step has two forms more.
+        ``commit`` (False or True): the ``T`` tokens are ONE BLOCK of
+        each stream at its position, every token of it seeing the whole
+        block and the cache below it; with ``commit`` the position moves
+        past the block and its rows stay, without it the position stays
+        and the next forward writes over them. ``trace`` ``(B, T)``: the
+        update's form, :meth:`_replay`."""
         tokens = obs.reshape(obs.shape[0], -1).astype(jnp.int32)
         b, t = tokens.shape
         # the one-token form opens an episode before the token it
@@ -200,6 +215,26 @@ class SequenceLM:
         # no pass over the state is made for it
         if t == 1 and resets is not None:
             state = self.reset_state(state, resets.reshape(b) > 0.5)
+        prefix = (scope + "/") if scope else ""
+        rows_ctx = self._rows(state, b, t, resets)
+        if trace is not None:
+            return self._replay(
+                params, tokens, trace.reshape(b, t).astype(jnp.int32), state,
+                rows_ctx, prefix, stats_out)
+        x, state_out, stats, _ = self._stack(
+            params, tokens, state, rows_ctx, prefix,
+            step=t == 1 or commit is not None)
+        state_out.append(
+            rows_ctx["pos0"] if commit is False else rows_ctx["positions"][:, -1] + 1)
+        logits, value = self._head(params, x, prefix)
+        if stats_out is not None:
+            self._report(stats_out, over_layers(stats, self._reductions), b * t)
+        return logits, value, tuple(state_out)
+
+    def _rows(self, state, b: int, t: int, resets):
+        """The fragment's rows: each token's episode number inside the
+        fragment, whether it opens one, its position, and the streams'
+        start positions."""
         if t == 1 or resets is None:
             resets = jnp.zeros((b, t), jnp.float32)
         fresh = resets.reshape(b, t) > 0.5
@@ -208,20 +243,27 @@ class SequenceLM:
         opened = jax.lax.cummax(jnp.where(fresh, steps, -1), axis=1)
         pos0 = state[-1]
         positions = jnp.where(seg == 0, pos0[:, None] + steps, steps - opened)
-        rows_ctx = {
-            "seg": seg, "fresh": fresh, "positions": positions, "pos0": pos0,
-        }
-        prefix = (scope + "/") if scope else ""
+        return {"seg": seg, "fresh": fresh, "positions": positions, "pos0": pos0}
+
+    def _stack(self, params, tokens, state, rows_ctx, prefix: str, *, step: bool,
+               keep: bool = False, clean=None):
+        """The embedding and the blocks. ``step``: the lane's form (one
+        token, or one block of a model that commits a block a step);
+        ``keep`` / ``clean``: the passes of :meth:`_replay`. Returns
+        ``(x, [state leaves], {key: [a segment's (layers, ...)]}, [a
+        segment's kept rows])``."""
+        b, t = tokens.shape
         residual, declared = self.residual, self._reductions
+        flags = (("step", step), ("keep", keep))
 
         x = jnp.take(params["embed"]["embedding"], tokens, axis=0)  # (B, T, D)
         if self.embed_scale != 1.0:
             x = x * self.embed_scale
         x = residual.enter(x)
 
-        def block(x, p, layer_state, rows, mixer, ffn):
+        def block(x, p, layer_state, rows, mixer, ffn, flags):
             ctx = dict(rows, scope=prefix, dtype=self.dtype, eps=self.eps,
-                       chunk=self.chunk)
+                       chunk=self.chunk, **dict(flags))
             if ffn.route_on == "input":
                 # the router reads the layer's input, before the mixer
                 with jax.named_scope(prefix + "moe/route"):
@@ -232,7 +274,8 @@ class SequenceLM:
                 x, p, "ffn", lambda h: ffn.apply(p, h, (), ctx), ctx)
             # what both sublayers report (the residual's own) as two layers'
             both = over_layers(
-                {k: [jnp.stack([stats[k], more[k]])] for k in set(stats) & set(more)},
+                {k: [jnp.stack([stats[k], more[k]])]
+                 for k in sorted(set(stats) & set(more))},
                 declared)
             return x, new, {**stats, **more, **both}
 
@@ -240,13 +283,13 @@ class SequenceLM:
         # block, here, or around the whole loss (``loss_groups``)
         groups = b // self.learn_streams if (
             not residual.groups_the_loss
-            and t > 1 and b > self.learn_streams and b % self.learn_streams == 0
+            and not step and b > self.learn_streams and b % self.learn_streams == 0
         ) else 1
         # the learn form keeps a block's input and recomputes the block
         # in the backward pass, ``learn_streams`` streams at a time: one
         # group's activations of one block are alive, not the batch's
         # of the stack
-        whole = jax.checkpoint(block, static_argnums=(4, 5)) if t > 1 else block
+        whole = block if step else jax.checkpoint(block, static_argnums=(4, 5, 6))
 
         def run_block(x, p, layer_state, rows, *kinds):
             if groups == 1:
@@ -260,22 +303,28 @@ class SequenceLM:
             return (merge(x), jax.tree_util.tree_map(merge, new),
                     over_streams(stats, declared))
 
-        state_out, stats = [], {}
-        for s, leaves in self._by_segment(state):
-            args = (params[s.name], leaves, rows_ctx, s.mixer, s.ffn)
+        state_out, stats, kept = [], {}, []
+        for i, (s, leaves) in enumerate(self._by_segment(state)):
+            rows = rows_ctx if clean is None else dict(rows_ctx, clean=clean[i])
+            args = (params[s.name], leaves, rows, s.mixer, s.ffn, flags)
             if s.mixer.stacked:
                 x, new, seen = self._run_of_layers(run_block, prefix, x, *args)
             else:
                 x, new, seen = run_block(x, *args)
                 seen = {k: v[None] for k, v in seen.items()}
-            state_out.extend(new)
+            state_out.extend(new[:len(leaves)])
+            kept.append(tuple(new[len(leaves):]))
             for k, v in seen.items():
                 stats.setdefault(k, []).append(v)
-        state_out.append(positions[:, -1] + 1)
+        return x, state_out, stats, kept
 
+    def _head(self, params, x, prefix: str):
+        """``(logits (rows, vocab), value (rows,))`` of the stack's
+        output ``x`` ``(B, T, lanes x D)``."""
         with jax.named_scope(prefix + "head"):
-            x = residual.leave(x)
-            feat = rms(x, params["final_norm"]["weight"], self.eps).reshape(b * t, -1)
+            x = self.residual.leave(x)
+            feat = rms(x, params["final_norm"]["weight"], self.eps)
+            feat = feat.reshape(-1, feat.shape[-1])
             if self.tied_head:  # the embedding, contracted over the hidden axis
                 logits = jax.lax.dot_general(
                     feat.astype(self.dtype),
@@ -290,9 +339,60 @@ class SequenceLM:
                 jnp.dot(feat, params["value"]["kernel"], precision=HI)
                 + params["value"]["bias"]
             )[:, 0]
+        return logits, value
+
+    def _replay(self, params, tokens, trace, state, rows_ctx, prefix: str,
+                stats_out: Optional[Dict]):
+        """The update's form of a model that commits a block a step: the
+        fragment's ``tokens`` ``(B, T)`` with their ``trace``, from the
+        stored start ``state``. A CLEAN pass over the tokens under the
+        block-causal mask, whose attention layers hand out their keys
+        and values; then, one after another, a NOISY pass for each
+        denoising step ``s`` whose input is ``generation.noisy_inputs``
+        and whose queries see the stored rows, the clean pass's rows of
+        strictly earlier blocks and their own pass's of their own block.
+        Token ``i``'s logits and value are the noisy pass ``trace[i]``'s
+        at ``i``: what the lane stored when it committed the token. The
+        gradient reaches the clean pass through its keys. The passes'
+        scopes: ``clean`` and ``noisy`` around the kinds' own."""
+        gen = self.generation
+        b, t = tokens.shape
+        declared = self._reductions
+        with jax.named_scope(prefix + "clean"):
+            _, _, stats, kept = self._stack(
+                params, tokens, state, rows_ctx, prefix, step=False, keep=True)
+        stats = over_layers(stats, declared)
+        routes = stats.pop("moe_routes", None)
+
+        def noisy(inputs):
+            with jax.named_scope(prefix + "noisy"):
+                x, _, seen, _ = self._stack(
+                    params, inputs, state, rows_ctx, prefix, step=False, clean=kept)
+            seen = over_layers(seen, declared)
+            seen.pop("moe_routes", None)
+            return x, seen
+
+        xs, seen = jax.lax.map(noisy, gen.noisy_inputs(tokens, trace))
+        # each token from the pass that committed it
+        x = jnp.take_along_axis(
+            xs, jnp.clip(trace, 0, xs.shape[0] - 1)[None, :, :, None], axis=0)[0]
+        logits, value = self._head(params, x, prefix)
         if stats_out is not None:
-            self._report(stats_out, over_layers(stats, declared), b * t)
-        return logits, value, tuple(state_out)
+            # the S + 1 passes' counts as one update's
+            stats = over_streams(
+                {k: jnp.concatenate([stats[k][None], seen[k]]) for k in stats},
+                declared)
+            if routes is not None:
+                stats["moe_routes"] = routes  # the clean pass's
+            self._report(stats_out, stats, b * t)
+            confidence = jnp.exp(jnp.take_along_axis(
+                jax.nn.log_softmax(logits), tokens.reshape(-1, 1), axis=1))
+            passes = gen.token_passes(b * t)
+            stats_out.update(
+                diffusion_commit_confidence_mean=jnp.mean(confidence),
+                diffusion_clean_token_passes=jnp.float32(passes["clean"]),
+                diffusion_noisy_token_passes=jnp.float32(passes["noisy"]))
+        return logits, value, state
 
     def _report(self, stats_out: Dict, stats: Dict, tokens: int):
         """The stack's statistics into ``stats_out``: the kinds' own

@@ -18,6 +18,10 @@ from those positions, never from slot numbers.
 
 from __future__ import annotations
 
+import functools
+import itertools
+import operator
+
 import jax
 import jax.numpy as jnp
 
@@ -57,13 +61,18 @@ def scatter_rows(cache, new, rows, ring: bool = False):
         new.astype(cache.dtype), mode="drop")
 
 
-def fragment_masks(seg, pos0, positions, depth: int, window):
+def fragment_masks(seg, pos0, positions, depth: int, window, block: int = 1):
     """``(see_old (B, T, depth), see (B, T, T))``: a stored key is seen
     by the tokens before the fragment's first reset, below the start
     position (in a ring: where the slot holds a row, less than
     ``window`` behind the query); the fragment's own causally, within an
-    episode and the window."""
+    episode and the window. ``block``: the fragment's own by BLOCKS of
+    that many tokens, a key of the query's block or an earlier one
+    (block-causal; a fragment and an episode start on a block's first
+    token, so a step's block is its position's)."""
     steps = jnp.arange(seg.shape[1])
+    if block > 1:
+        steps = steps // block
     see_old = (seg == 0)[:, :, None]
     if window is None:
         see_old = see_old & (jnp.arange(depth)[None, None] < pos0[:, None, None])
@@ -80,13 +89,25 @@ def fragment_masks(seg, pos0, positions, depth: int, window):
     return see_old, see
 
 
+def noisy_masks(seg, block: int):
+    """``(see_clean, see_own)`` ``(B, T, T)`` of a block-diffusion noisy
+    pass over a fragment: of the CLEAN pass's rows a query sees those of
+    strictly earlier blocks of its episode, of its own pass's rows those
+    of its own block (BD3-LM's block-causal and block-diagonal masks)."""
+    blocks = jnp.arange(seg.shape[1]) // block
+    same = seg[:, :, None] == seg[:, None, :]
+    return (same & (blocks[:, None] > blocks[None, :])[None],
+            same & (blocks[:, None] == blocks[None, :])[None])
+
+
 def pairs_seen(see_old, see):
     """(query, key) pairs under the masks, a stream (exact in float32)."""
     return (jnp.sum(see_old, axis=(1, 2), dtype=jnp.float32)
             + jnp.sum(see, axis=(1, 2), dtype=jnp.float32))
 
 
-def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope):
+def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
+                     block: int = 1):
     """Attention of a fragment's ``q`` ``(B, T, heads, D)`` over the
     stored keys and values ``caches`` and the fragment's own ``k``, ``v``
     ``(B, T, kv heads, D)``; ``rows`` holds the fragment's ``seg``,
@@ -94,15 +115,29 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope):
     heads, D) float32, (keys, values) after the fragment, stats)``, its
     parts under ``scope``'s ``/scatter``, ``/scores`` and ``/out``.
 
+    ``block`` is the mask's rule: a key is seen from its own block of
+    that many positions and from every later one (1: causal). With it
+    come two further things ``rows`` may hold. ``rows["step"]``: the
+    ``T`` tokens are ONE BLOCK of a stream's generation, at ``pos0 ..
+    pos0 + T - 1``: their rows are written first and every query reads
+    the slots below ``pos0 + T``, which IS the mask inside a block; the
+    one-token form is that with ``T == 1`` and needs no flag.
+    ``rows["clean"]``: ``(k, v)`` of another pass over the same
+    fragment (a block-diffusion update's clean pass); the queries then
+    see, of THOSE rows, the strictly earlier blocks and, of their own,
+    their own block (:func:`noisy_masks`).
+
     Which form runs where, each chosen by what the call sees in its
     input. A fragment (``T > 1``): ``flash_attention.fragment_attention``
     where ``fragment_kernel_applies`` says so (a TPU, bfloat16, whole
     blocks), window or none, else the XLA text a block of streams at a
-    time (:func:`env_block`). One token at full depth:
+    time (:func:`env_block`). One token, or one block, at full depth:
     ``flash_attention.step_attention`` where ``step_kernel_applies`` says
-    so, which fetches a stream's key blocks below its depth only, else
-    ``step_attention_text``. One token over a ring: that text, always
-    (past its first turn a ring has no unwritten slot to skip).
+    so, which fetches a stream's key blocks below its depth only (a
+    block's ``T x group`` queries of a key head are the rows of its one
+    query tile), else ``step_attention_text``. One token over a ring:
+    that text, always (past its first turn a ring has no unwritten slot
+    to skip).
     ``ray_tpu_attention_{step,fragment}_lowerings_total{path}`` count the
     choice.
 
@@ -121,6 +156,9 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope):
     k, v = k.astype(dtype), v.astype(dtype)
     part = lambda name: jax.named_scope(f"{scope}/{name}")
     ring = window is not None
+    step, clean = rows.get("step", t == 1), rows.get("clean")
+    if ring and (block > 1 or t > 1 and step):
+        raise ValueError("a ring cache has no block rule")
 
     with part("scatter"):
         new_k = scatter_rows(k_cache, k.reshape(b, t, hkv * d), rows, ring)
@@ -131,51 +169,62 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope):
         h, hkv, d, depth, dtype)
     stats = {}
 
-    def attend(qe, ke, ve, kc, vc, sege, pos0e, pose=None):
+    def attend(qe, ke, ve, kc, vc, sege, pos0e, pose=None, cleane=None):
         """One block of streams: the masked scores over the stored keys
         and the fragment's own in one softmax."""
         kc = kc.reshape(kc.shape[:2] + (hkv, d))
         vc = vc.reshape(vc.shape[:2] + (hkv, d))
+        keys, values = [kc, ke], [vc, ve]
         with part("scores"):
-            see_old, see = fragment_masks(sege, pos0e, pose, depth, window)
-            old = jnp.einsum(
-                "btngd,bsnd->bngts", qe, kc, preferred_element_type=jnp.float32)
-            own = jnp.einsum(
-                "btngd,bsnd->bngts", qe, ke, preferred_element_type=jnp.float32)
+            see_old, see = fragment_masks(sege, pos0e, pose, depth, window, block)
+            masks = [see_old, see]
+            if cleane is not None:
+                keys.insert(1, cleane[0]), values.insert(1, cleane[1])
+                masks[1:] = noisy_masks(sege, block)
+            scores = [jnp.einsum(
+                "btngd,bsnd->bngts", qe, x, preferred_element_type=jnp.float32)
+                for x in keys]
             w = jax.nn.softmax(jnp.concatenate([
-                jnp.where(see_old[:, None, None], old, -jnp.inf),
-                jnp.where(see[:, None, None], own, -jnp.inf)], axis=-1), axis=-1)
+                jnp.where(m[:, None, None], s, -jnp.inf)
+                for m, s in zip(masks, scores)], axis=-1), axis=-1)
             w = w.astype(dtype)
         with part("out"):
-            out = jnp.einsum(
-                "bngts,bsnd->btngd", w[..., :depth], vc,
-                preferred_element_type=jnp.float32,
-            ) + jnp.einsum(
-                "bngts,bsnd->btngd", w[..., depth:], ve,
-                preferred_element_type=jnp.float32,
-            )
+            ends = itertools.accumulate(x.shape[1] for x in values)
+            out = functools.reduce(operator.add, [jnp.einsum(
+                "bngts,bsnd->btngd", w[..., hi - x.shape[1]:hi], x,
+                preferred_element_type=jnp.float32)
+                for hi, x in zip(ends, values)])
         return (out, pairs_seen(see_old, see)) if ring else out
 
-    if t == 1:
-        # decode reads the cache it has just written: the own key sits
-        # at slot pos0, so the stored range is one longer
-        see = fragment_masks(seg, pos0 + 1, positions, depth, window)[0][:, 0]
+    if step:
+        # decode reads the cache it has just written: the own keys sit
+        # at slots pos0 .. pos0 + t - 1, so the stored range is t longer
+        see = fragment_masks(seg[:, :1] if t > 1 else seg, pos0 + t, positions,
+                             depth, window)[0][:, 0]
         if ring:
             stats["pairs_seen"] = jnp.sum(jnp.sum(see, axis=1, dtype=jnp.float32))
+        # a block's queries of a key head as the rows of one tile
+        qs = qh if t == 1 else qh.transpose(0, 2, 1, 3, 4).reshape(
+            b, 1, hkv, t * (h // hkv), d)
         if step_kernel:
             # a full-depth cache is half unwritten at the mean: the
             # tiled step kernel fetches a stream's key blocks below its
             # depth only
             metrics.inc_attention_step_lowering("kernel")
             with part("scores"):
-                o = flash_attention.step_attention(qh, new_k, new_v, pos0 + 1)
+                o = flash_attention.step_attention(qs, new_k, new_v, pos0 + t)
         else:
             metrics.inc_attention_step_lowering("xla")
             with jax.named_scope(scope):
-                o = flash_attention.step_attention_text(qh, new_k, new_v, see)
+                o = flash_attention.step_attention_text(qs, new_k, new_v, see)
+        if t > 1:
+            o = o.reshape(b, hkv, t, h // hkv, d).transpose(0, 2, 1, 3, 4)
         return o.reshape(b, t, h, d), (new_k, new_v), stats
 
-    kernel = flash_attention.fragment_kernel_applies(t, h, hkv, d, depth, dtype)
+    # the kernel's block rule is a bit mask: a power of two
+    own = t if clean is None else 2 * t
+    kernel = not block & (block - 1) and flash_attention.fragment_kernel_applies(
+        t, h, hkv, d, depth, dtype, own)
     none = (jnp.int32(0), 0)
     for name, (skipped, walked) in (
             ("attn_key_blocks",
@@ -191,18 +240,23 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope):
         metrics.inc_attention_fragment_lowering("kernel")
         with part("scores"):
             o = flash_attention.fragment_attention(
-                qh, k, v, k_cache, v_cache, pos0, seg, positions, window=window)
+                qh, k, v, k_cache, v_cache, pos0, seg, positions, window=window,
+                block=block, clean=clean)
             if ring:  # the masks' arithmetic, reduced where it is built
                 stats["pairs_seen"] = jnp.sum(pairs_seen(
                     *fragment_masks(seg, pos0, positions, depth, window)))
     else:
         metrics.inc_attention_fragment_lowering("xla")
-        nb = max(1, b // env_block(h, t, depth + t))
+        nb = max(1, b // env_block(h, t, depth + own))
         if b % nb:
             nb = 1
         # a ring's masks need each query's position
-        args = (qh, k, v, k_cache, v_cache, seg, pos0) + ((positions,) if ring else ())
-        blocked = tuple(a.reshape((nb, b // nb) + a.shape[1:]) for a in args)
+        args = (qh, k, v, k_cache, v_cache, seg, pos0) + (
+            (positions,) if ring or clean is not None else ())
+        if clean is not None:
+            args += (tuple(x.astype(dtype) for x in clean),)
+        blocked = jax.tree_util.tree_map(
+            lambda a: a.reshape((nb, b // nb) + a.shape[1:]), args)
         o = jax.lax.map(lambda xs: jax.checkpoint(attend)(*xs), blocked)
         o = jax.tree_util.tree_map(lambda a: a.reshape((b,) + a.shape[2:]), o)
         if ring:

@@ -338,7 +338,7 @@ def _heads_packed(head_dim: int, kv_heads: int) -> int:
     return pack if kv_heads % pack == 0 else 1
 
 
-def fragment_head_tile(tokens, heads, kv_heads, head_dim) -> int:
+def fragment_head_tile(tokens, heads, kv_heads, head_dim, own=None) -> int:
     """Query heads of one block of keys in a query tile: the most (a
     divisor of the group) that the backward pass can hold in VMEM, 0
     where not even one fits. Per row and lane ``q`` and ``dq`` in
@@ -346,17 +346,19 @@ def fragment_head_tile(tokens, heads, kv_heads, head_dim) -> int:
     pipeline, and the float32 ``dq`` accumulator (28 bytes), and three
     one-lane statistics that occupy whole 128-lane rows; beside the
     rows a head's float32 tiles of scores, weights and their gradient
-    over the widest key block (the stored 512, or the fragment's own)."""
+    over the widest key block (the stored 512, or the fragment's ``own``
+    keys: as many as its tokens, twice that in a noisy pass)."""
     pack = _heads_packed(head_dim, kv_heads)
     group = pack * (heads // kv_heads)
     row = 28 * _ceil_to(head_dim * pack, _LANES) + 12 * _LANES
-    room = _FRAGMENT_VMEM_BYTES // 2 - 12 * tokens * max(tokens, _FRAGMENT_BLOCK_K)
+    room = _FRAGMENT_VMEM_BYTES // 2 - 12 * tokens * max(
+        own or tokens, _FRAGMENT_BLOCK_K)
     return next((tile for tile in range(group, 0, -1)
                  if group % tile == 0 and tile * tokens * row <= room), 0)
 
 
 def fragment_kernel_applies(
-        tokens, heads, kv_heads, head_dim, depth, dtype) -> bool:
+        tokens, heads, kv_heads, head_dim, depth, dtype, own=None) -> bool:
     """The fragment kernel's lowering exists on a TPU
     (``ops/backend.is_tpu``) for bfloat16 operands, a fragment of
     whole 128-lane tiles of tokens (the own keys' episode
@@ -374,7 +376,7 @@ def fragment_kernel_applies(
         and fragment_block_k(depth) > 0
         and (head_dim * pack % _LANES == 0
              or kv_heads == 1 and head_dim % (_LANES // 2) == 0)
-        and fragment_head_tile(tokens, heads, kv_heads, head_dim) > 0
+        and fragment_head_tile(tokens, heads, kv_heads, head_dim, own) > 0
     )
 
 
@@ -414,11 +416,27 @@ def _stored_mask(pos0, seg_q, pos_q, first, block_k, depth, window):
     return held > jnp.where(seg_q == 0, pos_q - window, _FAR)
 
 
-def _own_mask(seg_q, seg_k, window):
-    """``(T, T)``: causal, same episode, inside the window."""
-    t = seg_q.shape[0]
-    behind = (jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
-              - jax.lax.broadcasted_iota(jnp.int32, (t, t), 1))
+def _own_mask(seg_q, seg_k, window, block=1):
+    """``(T, own keys)``: causal, same episode, inside the window.
+    ``block`` (a power of two): causal by blocks of that many tokens, a
+    key seen from its own block on
+    (``ops/cached_attention.fragment_masks``). ``2 T`` own keys are a
+    CLEAN pass's rows, then the queries' own pass's: of the first a
+    query sees the strictly earlier blocks, of the second its own block
+    (``ops/cached_attention.noisy_masks``)."""
+    t, keys = seg_q.shape[0], seg_k.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (t, keys), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (t, keys), 1)
+    if keys != t:
+        # the clean rows below the first step of the query's block (all
+        # of them among the first ``t`` columns), its own pass's from
+        # there to the block's last step
+        first, own = row & ~(block - 1), col - t
+        return (seg_q == seg_k) & (
+            (col < first) | ((own >= first) & (own <= (row | (block - 1)))))
+    if block > 1:  # the last step of the query's block
+        row = row | (block - 1)
+    behind = row - col
     mask = (behind >= 0) & (seg_q == seg_k)
     if window is not None:
         mask = mask & (behind < window)
@@ -428,7 +446,7 @@ def _own_mask(seg_q, seg_k, window):
 def _fragment_fwd_kernel(
     pos0_ref, q_ref, kc_ref, vc_ref, k_ref, v_ref, seg_q_ref, pos_q_ref,
     seg_k_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-    window, depth, block_k, tiles,
+    window, depth, block_k, tiles, block,
 ):
     """One stream, one key head, one tile of its query heads, one block
     of keys: the stored blocks in turn, then the fragment's own.
@@ -472,7 +490,8 @@ def _fragment_fwd_kernel(
 
     @pl.when(kb == stored)
     def _():
-        fold(k_ref[0], v_ref[0], _own_mask(seg_q_ref[0], seg_k_ref[0], window))
+        fold(k_ref[0], v_ref[0],
+             _own_mask(seg_q_ref[0], seg_k_ref[0], window, block))
         for g in range(group):
             l = l_ref[g]
             o_ref[0, 0, g] = (acc_ref[g] / l).astype(o_ref.dtype)
@@ -482,7 +501,7 @@ def _fragment_fwd_kernel(
 def _fragment_bwd_kernel(
     pos0_ref, q_ref, kc_ref, vc_ref, k_ref, v_ref, seg_q_ref, pos_q_ref,
     seg_k_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref, dq_acc,
-    delta_ref, *own_acc, window, depth, block_k, tiles,
+    delta_ref, *own_acc, window, depth, block_k, tiles, block,
 ):
     """The same walk; every score tile is computed again from the row
     statistics. ``dq`` gathers over all key blocks, the own keys' ``dk``
@@ -534,7 +553,7 @@ def _fragment_bwd_kernel(
     def _():
         dk, dv = fold(
             k_ref[0], v_ref[0],
-            _own_mask(seg_q_ref[0], seg_k_ref[0], window), True)
+            _own_mask(seg_q_ref[0], seg_k_ref[0], window, block), True)
         if tiles > 1:
             dk_acc, dv_acc = own_acc
 
@@ -561,7 +580,7 @@ def _fragment_bwd_kernel(
 
 
 def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
-                   tile, interpret, name):
+                   tile, interpret, block, name):
     """One pass over the grid ``(streams, key heads, stored blocks +
     1)``, with an axis of ``group / tile`` query tiles before the blocks
     where a tile holds fewer query heads than the group. ``operands``:
@@ -578,6 +597,9 @@ def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
     depth = k_cache.shape[1]
     stored = depth // block_k
     tiles = group // tile
+    # the own keys: the fragment's, or a clean pass's and then its own
+    own_keys = k.shape[1]
+    seg_k = seg if own_keys == t else jnp.concatenate([seg, seg], axis=1)
 
     def step(ids):  # (stream, key head, query tile, key block, pos0) of a step
         b, n, *rest, kb, pos0 = ids
@@ -602,7 +624,7 @@ def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
         def index(*ids):
             b, n, _, _, _ = step(ids)
             return b, 0, n
-        return pl.BlockSpec((1, t, width), index)
+        return pl.BlockSpec((1, own_keys, width), index)
 
     def per_stream(*shape):
         return pl.BlockSpec((1,) + shape, lambda *ids: (ids[0], 0, 0))
@@ -611,13 +633,14 @@ def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
     vma = sharding_lib.vma_of(operands)
     return pl.pallas_call(
         functools.partial(
-            kernel, window=window, depth=depth, block_k=block_k, tiles=tiles),
+            kernel, window=window, depth=depth, block_k=block_k, tiles=tiles,
+            block=block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bsz, kv) + (tiles,) * (tiles > 1) + (stored + 1,),
             in_specs=[
                 heads(q.shape), cached(d), cached(dv), own(d), own(dv),
-                per_stream(t, 1), per_stream(t, 1), per_stream(1, t),
+                per_stream(t, 1), per_stream(t, 1), per_stream(1, own_keys),
                 *(heads(r.shape) for r in rows),
             ],
             out_specs=[
@@ -638,18 +661,18 @@ def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
         ),
         name=name,
     )(pos0.astype(jnp.int32), q, k_cache, v_cache, k, v,
-      seg[:, :, None], positions[:, :, None], seg[:, None, :], *rows)
+      seg[:, :, None], positions[:, :, None], seg_k[:, None, :], *rows)
 
 
 # A ``jit`` of their own, so that a program with many call sites (five
 # layers, the forward pass, its recomputation and the backward pass, the
 # standalone learn program and the fused one) traces and lowers the
 # kernels once a shape.
-_STATIC = ("window", "block_k", "tile", "interpret")
+_STATIC = ("window", "block_k", "tile", "interpret", "block")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _fragment_fwd(operands, *, window, block_k, tile, interpret):
+def _fragment_fwd(operands, *, window, block_k, tile, interpret, block):
     q, _, v = operands[:3]
     bsz, kv, group, t, _ = q.shape
     dv = v.shape[-1] // kv
@@ -660,31 +683,34 @@ def _fragment_fwd(operands, *, window, block_k, tile, interpret):
          ((bsz, kv, group, t, 1), jnp.float32)],
         [stat(), stat(), pltpu.VMEM((tile, t, dv), jnp.float32)],
         window=window, block_k=block_k, tile=tile, interpret=interpret,
-        name="fragment_attention_fwd",
+        block=block, name="fragment_attention_fwd",
     )
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _fragment_bwd(operands, o, do, lse, *, window, block_k, tile, interpret):
+def _fragment_bwd(operands, o, do, lse, *, window, block_k, tile, interpret,
+                  block):
     q, k, v = operands[:3]
     kv, group, t, d = q.shape[1:]
-    own_acc = [pltpu.VMEM((t, a.shape[-1] // kv), jnp.float32) for a in (k, v)]
+    own_acc = [pltpu.VMEM((a.shape[1], a.shape[-1] // kv), jnp.float32)
+               for a in (k, v)]
     return _fragment_call(
         _fragment_bwd_kernel, operands, (o, do, lse),
         [(q.shape, q.dtype), (k.shape, k.dtype), (v.shape, v.dtype)],
         [pltpu.VMEM((tile, t, d), jnp.float32),
          pltpu.VMEM((tile, t, 1), jnp.float32)] + own_acc * (tile < group),
         window=window, block_k=block_k, tile=tile, interpret=interpret,
-        name="fragment_attention_bwd",
+        block=block, name="fragment_attention_bwd",
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11, 12))
 def _fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions,
-                        window, block_k, tile, interpret):
+                        window, block_k, tile, interpret, block):
     return _fragment_fwd(
         (q, k, v, k_cache, v_cache, pos0, seg, positions),
-        window=window, block_k=block_k, tile=tile, interpret=interpret)[0]
+        window=window, block_k=block_k, tile=tile, interpret=interpret,
+        block=block)[0]
 
 
 def _fragment_fwd_rule(*args):
@@ -693,11 +719,11 @@ def _fragment_fwd_rule(*args):
     return o, (operands, o, lse)
 
 
-def _fragment_bwd_rule(window, block_k, tile, interpret, residuals, do):
+def _fragment_bwd_rule(window, block_k, tile, interpret, block, residuals, do):
     operands, o, lse = residuals
     dq, dk, dv = _fragment_bwd(
-        operands, o, do, lse,
-        window=window, block_k=block_k, tile=tile, interpret=interpret)
+        operands, o, do, lse, window=window, block_k=block_k, tile=tile,
+        interpret=interpret, block=block)
     # the stored rows are the rollout's, handed over as data: no
     # gradient (``None`` is a zero cotangent), nor for the integers
     return (dq, dk, dv) + (None,) * 5
@@ -707,8 +733,8 @@ _fragment_attention.defvjp(_fragment_fwd_rule, _fragment_bwd_rule)
 
 
 def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
-                       window=None, block_k=None, head_tile=None,
-                       interpret=False):
+                       window=None, block=1, clean=None, block_k=None,
+                       head_tile=None, interpret=False):
     """A fragment's causal attention over its streams' stored keys and
     values and its own, as one tiled kernel with an online softmax in
     both directions: no ``(T, rows)`` matrix of scores or weights
@@ -731,6 +757,12 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
 
     The masks are ``ops/cached_attention.fragment_masks``'s, built in
     the kernel; stored blocks at or past ``pos0`` are skipped whole.
+    ``block`` (a power of two, no ``window``) is that function's: the
+    fragment's own keys are seen by blocks of that many tokens.
+    ``clean``: ``(k, v)`` of a clean pass over the same fragment; the
+    own keys are then those rows and the queries' own pass's, two blocks
+    of one key operand under ``ops/cached_attention.noisy_masks``, and
+    the gradient reaches the clean rows too.
 
     Differentiable in ``q``, ``k`` and ``v``. The caches get NO
     gradient (zeros): they are the rollout's rows, handed over as data,
@@ -750,8 +782,13 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
     block_k = fragment_block_k(depth, block_k)
     if not block_k:
         raise ValueError(f"a cache of {depth} rows is not whole key blocks")
+    if block & (block - 1) or block > 1 and window is not None:
+        raise ValueError(f"no block rule of {block} tokens (window {window})")
     pack = _heads_packed(d, kv) if d == dv else 1
-    tile = head_tile or fragment_head_tile(t, kv * group, kv, d)
+    if clean is not None:
+        k = jnp.concatenate([clean[0].astype(k.dtype), k], axis=1)
+        v = jnp.concatenate([clean[1].astype(v.dtype), v], axis=1)
+    tile = head_tile or fragment_head_tile(t, kv * group, kv, d, k.shape[1])
     if not tile or pack * group % tile:
         raise ValueError(
             f"no tile of the {pack * group} query heads of a key block fits")
@@ -773,9 +810,9 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
         return x.reshape(bsz, kv, group, t, -1).transpose(0, 3, 1, 2, 4)
 
     return gather(_fragment_attention(
-        spread(q), k.reshape(bsz, t, kv * d), v.reshape(bsz, t, kv * dv),
+        spread(q), k.reshape(bsz, -1, kv * d), v.reshape(bsz, -1, kv * dv),
         k_cache, v_cache, pos0, seg, positions, window, block_k, tile,
-        interpret))
+        interpret, block))
 
 
 # -- one token over a stored cache (the sequence models' rollout form) ------

@@ -26,10 +26,16 @@ apart), and :func:`product_lowering` picks one from the static shapes:
   buffer of its own, one batched product a projection over ``(held,
   buffer)`` rows, the rows added back onto their tokens. A buffer is
   static (:func:`expert_buffer_rows`: three times the tokens a uniform
-  router sends one expert, in whole tiles); a call in which ANY held
+  router sends one expert, in whole tiles); a call in which a held
   expert gets more takes the dense form under ``lax.cond``, so every
   routing is computed in full: all tokens on one expert cost what the
-  dense form costs and drop nothing.
+  dense form costs and drop nothing. A layer that is told so
+  (``alone``: a model that generates by masked diffusion routes every
+  ``[MASK]`` of a pass alike, so all of a pass's tokens on one expert is
+  its ordinary case) lets that many of its most loaded experts outgrow
+  their buffers: each keeps its buffer empty and runs over EVERY token
+  as one gated product under its column of the combine weights, beside
+  the others' buffers, and only a further one sends the call dense.
 """
 
 from __future__ import annotations
@@ -136,17 +142,25 @@ def product_lowering(tokens: int, k: int, num_experts: int) -> str:
     return "dense" if fits_a_buffer else "grouped"
 
 
-def rows_computed(per_expert, tokens: int, k: int, num_experts: int, lowering: str):
+def rows_computed(per_expert, tokens: int, k: int, num_experts: int, lowering: str,
+                  alone: int = 0):
     """Rows of the ``(rows, D) x (D, F)`` products that ``lowering``
     computes for these per-expert counts, float32: every token under
-    every held expert (dense, and a grouped call that outgrew a
-    buffer), or every held expert's buffer."""
+    every held expert (dense, and a grouped call in which more than
+    ``alone`` experts outgrew their buffers), or every held expert's
+    buffer, and every token once more for each expert that outgrew its
+    own."""
     held = per_expert.shape[0]
     dense_rows = jnp.float32(tokens * held)
     if lowering == "dense":
         return dense_rows
     buffer = expert_buffer_rows(tokens, k, num_experts)
-    return jnp.where(jnp.max(per_expert) <= buffer, held * buffer, dense_rows)
+    if not alone:
+        return jnp.where(jnp.max(per_expert) <= buffer, held * buffer, dense_rows)
+    outgrown = jnp.sum(per_expert > buffer)
+    return jnp.where(
+        outgrown > alone, dense_rows,
+        held * buffer + tokens * outgrown.astype(jnp.float32))
 
 
 def dense_experts_product(
@@ -186,6 +200,7 @@ def dense_experts_product(
 def grouped_experts_product(
     x, w_gate, w_up, w_down, indices, weights, per_expert, first: int,
     num_experts: int, dtype=jnp.bfloat16, activation: str = "silu",
+    alone: int = 0,
 ):
     """The same sum over the (token, slot) pairs on held experts only.
     ``indices``, ``weights`` ``(T, k)`` as :func:`route_top_k` gives
@@ -196,7 +211,11 @@ def grouped_experts_product(
     the ``c``-th of them; a slot past the count carries weight zero and
     no token. The products are plain batched ones: the backward pass is
     their transposes, the weights' gradient accumulated in float32 by
-    the product itself."""
+    the product itself. Up to ``alone`` of the most loaded experts,
+    where they have more pairs than a buffer holds, keep their buffers
+    empty and run over all ``T`` tokens instead (``one_alone``); more
+    such experts than that (any, at 0: every family that does not route
+    a pass's tokens alike): ``dense``."""
     t, d = x.shape
     held = w_gate.shape[0]
     k = indices.shape[-1]
@@ -206,13 +225,24 @@ def grouped_experts_product(
     # cast once for both ways: the cond hands back ``dtype`` gradients
     wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
 
-    def grouped():
+    buffered = counts
+    if alone:
+        # the most loaded experts, where they have more pairs than a
+        # buffer holds, keep their buffers empty and run over every
+        # token instead
+        most = jnp.argsort(-counts)[:alone]
+        outgrown = counts[most] > buffer
+        buffered = counts.at[most].set(jnp.where(outgrown, 0, counts[most]))
+
+    def grouped(buffered):
+        """``buffered``: the pairs of each held expert that go through
+        its buffer (all of them, or none of an outgrown one's)."""
         local = indices.reshape(-1) - first  # (T k,)
         here = (local >= 0) & (local < held)
         order = jnp.argsort(jnp.where(here, local, held), stable=True)
         slot = jnp.arange(buffer, dtype=jnp.int32)
         place = (jnp.cumsum(counts) - counts)[:, None] + slot  # (held, buffer)
-        filled = slot < counts[:, None]
+        filled = slot < buffered[:, None]
         pair = jnp.take(order, jnp.minimum(place, t * k - 1))
         token = jnp.where(filled, pair // k, t)  # t: no token
         weight = jnp.where(filled, jnp.take(weights.reshape(-1), pair), 0.0)
@@ -232,4 +262,22 @@ def grouped_experts_product(
         return dense_experts_product(
             x, wg, wu, wd, combine, dtype=dtype, activation=activation)
 
-    return jax.lax.cond(jnp.max(counts) <= buffer, grouped, dense)
+    fits = jnp.max(buffered) <= buffer
+    out = jax.lax.cond(fits, lambda: grouped(buffered), dense)
+    if alone:
+        from ray_tpu import sharding as sharding_lib
+
+        def one_alone(e):
+            column = jnp.sum(
+                jnp.where(indices - first == e, weights, 0.0), axis=-1, keepdims=True)
+            of = lambda w: jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False)
+            return column * gated_mlp(x, of(wg), of(wu), of(wd), dtype, activation)
+
+        # typed as the tokens are: inside a ``shard_map`` a cond's two
+        # results vary over the same axes
+        nothing = lambda: sharding_lib.varying(
+            jnp.zeros((t, d), jnp.float32), sharding_lib.vma_of((x, weights)))
+        for r in range(alone):
+            out = out + jax.lax.cond(
+                outgrown[r] & fits, lambda r=r: one_alone(most[r]), nothing)
+    return out

@@ -567,6 +567,39 @@ class JaxPolicy(Policy):
         )
         return actions, state_out, extra, expl_state
 
+    def _block_forward(self, params):
+        """The block form of a model that commits a block a step, as
+        its generation description calls it."""
+        return lambda tokens, state, commit: self.model.apply(
+            params, tokens, state, commit=commit
+        )
+
+    def action_block_body(self, params, rng, state):
+        """:meth:`_action_step_body` for a model whose
+        ``tokens_per_step`` is a block: ``(actions (N, B), state after
+        the block, extra)``, every ``extra`` column ``(N, B, ...)``. The
+        model's generation description denoises and commits (it samples
+        by confidence, not one categorical a stream: the exploration is
+        its own, at temperature 1); what is stored for a token is the
+        forward's that committed it, and ``unmask_step`` says which."""
+        rng_x, _ = jax.random.split(rng)
+        tokens, state_out, kept = self.model.generation.generate(
+            self._block_forward(params), state, rng_x
+        )
+        return tokens, state_out, {
+            SampleBatch.ACTION_DIST_INPUTS: kept["logits"],
+            SampleBatch.ACTION_LOGP: kept["logp"],
+            SampleBatch.VF_PREDS: kept["value"],
+            SampleBatch.UNMASK_STEP: kept["trace"],
+        }
+
+    def block_first_value(self, params, state):
+        """``(N,)``: the value the streams' NEXT blocks start from (the
+        lane's tail and truncation bootstraps)."""
+        return self.model.generation.first_value(
+            self._block_forward(params), state
+        )
+
     def reset_model_state(self, state, mask):
         """The model's per-stream state with the rows of ``mask`` (N,)
         bool set to the start of an episode: the model's own
@@ -1550,6 +1583,7 @@ class JaxPolicy(Policy):
             rollout=True,
         )
         telemetry_metrics.note_expert_load(infos)
+        telemetry_metrics.note_diffusion_passes(infos)
         return infos, carry, metrics, skipped
 
     def prepare_batch(self, samples) -> Tuple[Dict[str, np.ndarray], int]:
@@ -2202,6 +2236,12 @@ class JaxPolicy(Policy):
         obs = batch[SampleBatch.OBS]
         if not self.model.is_recurrent:
             return self.model.apply(params, obs)
+        kwargs = {}
+        if getattr(self.model, "tokens_per_step", 1) > 1:
+            # a block a step: the model read no observation; its update
+            # replays the committed tokens with the trace of their passes
+            obs = batch[SampleBatch.ACTIONS]
+            kwargs["trace"] = batch[SampleBatch.UNMASK_STEP]
         T = self._unroll_T
         N = obs.shape[0]
         if N % T:
@@ -2210,7 +2250,6 @@ class JaxPolicy(Policy):
                 f"of the unroll length max_seq_len={T}"
             )
         B = N // T
-        kwargs = {}
         resets = batch.get("resets")
         if resets is not None:
             kwargs["resets"] = resets.reshape(B, T)

@@ -171,6 +171,16 @@ ATTENTION_STEP_LOWERINGS_TOTAL = "ray_tpu_attention_step_lowerings_total"
 # traced (layers whose checkpointed block is the same trace once), in
 # either form, so a trace says that every layer got its own geometry
 ATTENTION_LAYER_LOWERINGS_TOTAL = "ray_tpu_attention_layer_lowerings_total"
+# tokens through the stack of a model that generates by block diffusion
+# (models/sequence_lm/generation.py), by the form of the forward: form =
+# denoise | commit (the rollout's block forwards: counted by the device
+# rollout lane a dispatch, env steps x the passes a token takes) | clean
+# | noisy (the update's passes: the learn program's own count of what it
+# traced, summed over the updates it ran); and the tokens the lane
+# committed. Forwards a committed token = (denoise + commit) /
+# block_length / committed
+DIFFUSION_TOKEN_PASSES_TOTAL = "ray_tpu_diffusion_token_passes_total"
+DIFFUSION_TOKENS_COMMITTED_TOTAL = "ray_tpu_diffusion_tokens_committed_total"
 # prioritized-replay segment-tree operations by op and by which tree
 # implementation performed them (docs/data_plane.md "device sum
 # tree"): host = the numpy SumSegmentTree walk, device = the
@@ -578,6 +588,46 @@ def note_expert_load(infos) -> None:
                 float(info["moe_decode_held_experts_touched_share"]),
                 {"stat": "share"})
             touched.inc(1.0, {"stat": "updates"})
+
+
+def add_diffusion_token_passes(form: str, n: float) -> None:
+    counter(
+        DIFFUSION_TOKEN_PASSES_TOTAL,
+        "tokens through the stack of a block-diffusion model, by forward",
+        ("form",),
+    ).inc(float(n), {"form": form})
+
+
+def note_diffusion_rollout(passes: Dict[str, float], committed: int) -> None:
+    """One dispatch of the device rollout lane with a model that commits
+    a block a step: its ``denoise`` and ``commit`` token-passes and the
+    tokens it committed."""
+    for form in ("denoise", "commit"):
+        add_diffusion_token_passes(form, passes[form])
+    counter(
+        DIFFUSION_TOKENS_COMMITTED_TOTAL,
+        "tokens committed by block-diffusion generation on the device lane",
+    ).inc(float(committed))
+
+
+def note_diffusion_passes(infos) -> None:
+    """Feed the update's ``clean`` and ``noisy`` token-passes from
+    drained per-update learner stats (a no-op for a model that reports
+    none)."""
+    for info in infos:
+        for form in ("clean", "noisy"):
+            if f"diffusion_{form}_token_passes" in info:
+                add_diffusion_token_passes(
+                    form, float(info[f"diffusion_{form}_token_passes"]))
+
+
+def diffusion_token_passes() -> Dict[str, float]:
+    """``{form: token-passes, "committed": tokens}`` since the process
+    began ({} for a model that generates a token a step)."""
+    out = _totals_by_tag(DIFFUSION_TOKEN_PASSES_TOTAL, "form")
+    if out:
+        out["committed"] = counter_total(DIFFUSION_TOKENS_COMMITTED_TOTAL)
+    return out
 
 
 def expert_load_totals() -> Dict[str, float]:

@@ -292,6 +292,75 @@ def test_fragment_kernel(name):
             np.asarray(a, np.float32), np.asarray(b, np.float32), **tol)
 
 
+_BLOCK_CASES = {
+    # a clean pass under the block-causal rule, and a noisy pass whose
+    # own keys are the clean pass's rows and then its own: streams empty,
+    # part full and full, an episode reset on a block's first token
+    "block_rule": dict(t=16, depth=32, pos0=(0, 8, 16), resets=((), (4,), ())),
+    "noisy_pass": dict(
+        t=16, depth=32, pos0=(0, 8, 16), resets=((), (4,), ()), noisy=True),
+    "noisy_pass_bfloat16": dict(
+        t=16, depth=32, pos0=(0, 8, 16), resets=((), (4,), ()), noisy=True,
+        dtype=jnp.bfloat16),
+    # the block-diffusion cell's layer at its fragment of 256 and the
+    # rule's own key blocks of 512: eight query heads a key head over a
+    # cache of 4,096, 512 own keys in the noisy pass
+    "block_rule_cache_4096": dict(
+        b=2, t=256, kv=1, group=8, depth=4096, pos0=(700, 3840),
+        resets=((), (100,)), block_k=None),
+    "noisy_pass_cache_4096": dict(
+        b=2, t=256, kv=1, group=8, depth=4096, pos0=(700, 3840),
+        resets=((), (100,)), block_k=None, noisy=True),
+}
+
+
+@pytest.mark.parametrize("name", list(_BLOCK_CASES))
+def test_fragment_kernel_block_rules(name):
+    """The kernel's block rule and its two own-key blocks against
+    ``cached_attention``'s XLA text (``fragment_masks`` with a block,
+    ``noisy_masks``), forward and every gradient, the clean rows'
+    included."""
+    from ray_tpu.ops.cached_attention import cached_attention
+    from ray_tpu.ops.flash_attention import fragment_attention
+
+    case = dict(_BLOCK_CASES[name])
+    block_k, noisy = case.pop("block_k", 16), case.pop("noisy", False)
+    dtype, kv, block = case.get("dtype", jnp.float32), case.get("kv", 2), 4
+    (q, k, v, kc, vc), rows, w = _fragment(**case)
+    rng = np.random.default_rng(9)
+    clean = tuple(
+        jnp.asarray(rng.standard_normal(k.shape), jnp.float32) for _ in range(2))
+
+    def text(q, k, v, ck, cv):
+        r = dict(rows, clean=(ck, cv)) if noisy else rows
+        return cached_attention(
+            q, k, v, (kc, vc), r, scale=q.shape[-1] ** -0.5, window=None,
+            dtype=dtype, scope="attn", block=block)[0]
+
+    def kernel(q, k, v, ck, cv):
+        b, t, h, d = q.shape
+        qh = (q * d ** -0.5).astype(dtype).reshape(b, t, kv, h // kv, d)
+        return fragment_attention(
+            qh, k.astype(dtype), v.astype(dtype), kc, vc, rows["pos0"],
+            rows["seg"], rows["positions"], block=block,
+            clean=(ck.astype(dtype), cv.astype(dtype)) if noisy else None,
+            block_k=block_k, interpret=True).reshape(b, t, h, d)
+
+    tol = dict(atol=1e-4, rtol=1e-4) if dtype == jnp.float32 else dict(
+        atol=0.15, rtol=5e-2)
+    operands = (q, k, v) + clean
+    np.testing.assert_allclose(
+        np.asarray(kernel(*operands)), np.asarray(text(*operands)), **tol)
+    loss = lambda f: lambda *a: jnp.sum(f(*a) * w)
+    learned = (0, 1, 2, 3, 4) if noisy else (0, 1, 2)
+    got = jax.grad(loss(kernel), argnums=learned)(*operands)
+    want = jax.grad(loss(text), argnums=learned)(*operands)
+    for a, b in zip(got, want):
+        assert float(jnp.max(jnp.abs(b))) > 0.1
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), **tol)
+
+
 def test_fragment_rule_blocks_and_pairs():
     from ray_tpu.ops import cached_attention, flash_attention as fa
 

@@ -383,7 +383,14 @@ FRAGMENT_LAYERS = {
     # dimension, no whole lane tiles) for 32 query heads in four tiles,
     # the value the row's leading 512 lanes, read out of the key cache
     "xing4_latent": (8, 128, 1, 32, 576, 2048, None, 512),
+    # the block-diffusion cell: eight query heads a key head under the
+    # block rule (its clean pass), and with a clean pass's rows as a
+    # second block of own keys (its noisy passes)
+    "sdar_clean": (16, 256, 4, 8, 128, 4096, None),
+    "sdar_noisy": (16, 256, 4, 8, 128, 4096, None),
 }
+# the mask's block and whether a clean pass's rows come beside the own
+FRAGMENT_BLOCKS = {"sdar_clean": (4, False), "sdar_noisy": (4, True)}
 
 
 @pytest.mark.parametrize("layer", list(FRAGMENT_LAYERS))
@@ -403,6 +410,7 @@ def test_v5e_fragment_attention_kernel_compiles(v5e_mesh, layer):
 
     b, t, kv, group, d, depth, window, *dv = FRAGMENT_LAYERS[layer]
     (dv,) = dv or (d,)
+    block, noisy = FRAGMENT_BLOCKS.get(layer, (1, False))
     axis = sharding_lib.data_axis(v5e_mesh)
     rows = sharding_lib.batch_sharded(v5e_mesh)
     on = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=rows)
@@ -414,7 +422,7 @@ def test_v5e_fragment_attention_kernel_compiles(v5e_mesh, layer):
             with jax.named_scope("learn/attn"):
                 return flash_attention.fragment_attention(
                     q, k, v, kc, kc if dv != d else vc, pos0, seg, positions,
-                    window=window)
+                    window=window, block=block, clean=(k, v) if noisy else None)
 
         return jax.grad(
             lambda *qkv: jnp.sum(jnp.square(attention(*qkv))),
@@ -444,6 +452,8 @@ STEP_LAYERS = {
     "laguna_full": (16, 8, 6, 128, 4096),
     "qwen3next": (64, 2, 8, 256, 2048),
     "granite4h": (16, 8, 4, 64, 2048),
+    # a block of 4 tokens: its 4 x 8 queries of a key head as one tile
+    "sdar_block": (16, 4, 32, 128, 4096),
 }
 
 

@@ -288,6 +288,10 @@ def _routing(name, indices, first, held):
         return indices
     if name == "all_on_one_expert":
         return absent.at[:, 0].set(first + 3)
+    if name == "all_on_two_experts":
+        return absent.at[:, 0].set(first + 3).at[:, 1].set(first + 1)
+    if name == "all_on_four_experts":  # one more than run alone: dense
+        return absent.at[:, :4].set(first + (3 + 2 * jnp.arange(4)) % held)
     if name == "none_held":
         return absent
     if name == "several_held_a_token":
@@ -300,8 +304,9 @@ def _routing(name, indices, first, held):
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("routing", [
-    "routed", "all_on_one_expert", "none_held", "several_held_a_token",
-    "tokens_not_a_tile", "empty_expert_between",
+    "routed", "all_on_one_expert", "all_on_one_expert_alone",
+    "all_on_two_experts_alone", "all_on_four_experts_alone", "none_held",
+    "several_held_a_token", "tokens_not_a_tile", "empty_expert_between",
 ])
 @pytest.mark.parametrize("router", sorted(ROUTERS))
 def test_grouped_product_equals_dense(router, routing, dtype):
@@ -318,7 +323,9 @@ def test_grouped_product_equals_dense(router, routing, dtype):
         options["select_bias"] = jax.random.normal(keys[6], (experts,)) * 0.02
     indices, weights = moe.route_top_k(
         x, jax.random.normal(keys[1], (d, experts)) * d ** -0.5, k, True, **options)
-    indices = _routing(routing, indices, first, held)
+    # "..._alone": a layer told that three experts may outgrow their buffers
+    alone = 3 if routing.endswith("_alone") else 0
+    indices = _routing(routing.removesuffix("_alone"), indices, first, held)
     assert bool(jnp.all(jnp.sort(indices, -1)[:, 1:] != jnp.sort(indices, -1)[:, :-1]))
     stacks = (
         jax.random.normal(keys[2], (held, d, f)) * d ** -0.5,
@@ -332,15 +339,25 @@ def test_grouped_product_equals_dense(router, routing, dtype):
         return moe.dense_experts_product(x, wg, wu, wd, combine, dtype=dtype)
 
     per_expert, _ = moe.expert_load(indices, first, held)
-    # which way the call goes: every token on one expert outgrows its
-    # buffer and takes the dense form under the cond; the two experts
-    # of "empty_expert_between" fill theirs to the last row
-    fits = int(per_expert.max()) <= moe.expert_buffer_rows(t, k, experts)
-    assert fits == (routing != "all_on_one_expert")
+    # which way the call goes: an expert with every token outgrows its
+    # buffer and the call takes the dense form under the cond, or, told
+    # so, that expert runs over every token beside the others' buffers
+    # (four such experts: dense again); the two experts of
+    # "empty_expert_between" fill theirs to the last row
+    outgrown = int(jnp.sum(per_expert > moe.expert_buffer_rows(t, k, experts)))
+    assert outgrown == {
+        "all_on_one_expert": 1, "all_on_one_expert_alone": 1,
+        "all_on_two_experts_alone": 2, "all_on_four_experts_alone": 4,
+    }.get(routing, 0)
+    want_rows = t * held if outgrown > alone else (
+        held * moe.expert_buffer_rows(t, k, experts) + t * outgrown)
+    assert float(moe.rows_computed(
+        per_expert, t, k, experts, "grouped", alone)) == want_rows
 
     def grouped(x, wg, wu, wd, w):
         return moe.grouped_experts_product(
-            x, wg, wu, wd, indices, w, per_expert, first, experts, dtype=dtype)
+            x, wg, wu, wd, indices, w, per_expert, first, experts, dtype=dtype,
+            alone=alone)
 
     want, want_vjp = jax.vjp(dense, x, *stacks, weights)
     got, got_vjp = jax.vjp(grouped, x, *stacks, weights)

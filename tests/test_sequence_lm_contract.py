@@ -51,7 +51,8 @@ def _offences(fn):
 
 
 @pytest.mark.parametrize("method", [
-    "__init__", "apply", "param_shapes", "init", "initial_state", "reset_state"])
+    "__init__", "apply", "param_shapes", "init", "initial_state", "reset_state",
+    "_rows", "_stack", "_head", "_replay"])
 def test_the_model_branches_on_no_kind_and_reads_no_key(method):
     assert list(_offences(getattr(SequenceLM, method))) == []
 

@@ -213,6 +213,80 @@ SURFACE = {
          'moe/experts', 'moe/route', 'moe/shared', 'swa', 'swa/gate', 'swa/out',
          'swa/scatter', 'swa/scores'],
     },
+    # a model that commits a block a step (PR 47): its fragment form is the
+    # update's replay of a trace (a clean and the noisy passes), its step form
+    # a block with the commit
+    "block_diffusion_lm":
+    {'params': {'embed': {'embedding': (64, 64)},
+                'final_norm': {'weight': (64,)},
+                'head': {'kernel': (64, 64)},
+                'value': {'kernel': (64, 1), 'bias': (1,)},
+                'layer_0': {'input_norm': (64,),
+                            'post_norm': (64,),
+                            'router': (64, 8),
+                            'experts_gate': (4, 64, 32),
+                            'experts_up': (4, 64, 32),
+                            'experts_down': (4, 32, 64),
+                            'q_proj': (64, 64),
+                            'k_proj': (64, 32),
+                            'v_proj': (64, 32),
+                            'o_proj': (64, 64),
+                            'q_norm': (16,),
+                            'k_norm': (16,)},
+                'layer_1': {'input_norm': (64,),
+                            'post_norm': (64,),
+                            'router': (64, 8),
+                            'experts_gate': (4, 64, 32),
+                            'experts_up': (4, 64, 32),
+                            'experts_down': (4, 32, 64),
+                            'q_proj': (64, 64),
+                            'k_proj': (64, 32),
+                            'v_proj': (64, 32),
+                            'o_proj': (64, 64),
+                            'q_norm': (16,),
+                            'k_norm': (16,)}},
+     'state': [((2, 32, 32), 'float32'),
+               ((2, 32, 32), 'float32'),
+               ((2, 32, 32), 'float32'),
+               ((2, 32, 32), 'float32'),
+               ((2,), 'int32')],
+     'fragment_stats': ['attn_decode_key_blocks_skipped_share',
+                        'attn_key_blocks_skipped_share',
+                        'diffusion_clean_token_passes',
+                        'diffusion_commit_confidence_mean',
+                        'diffusion_noisy_token_passes',
+                        'moe_decode_held_experts_touched_share',
+                        'moe_max_tokens_per_held_expert',
+                        'moe_rows_computed_share',
+                        'moe_slots_on_absent_experts',
+                        'moe_tokens_per_held_expert'],
+     'fragment_scopes': ['clean',
+                         'clean/p/attn',
+                         'clean/p/attn/out',
+                         'clean/p/attn/scatter',
+                         'clean/p/attn/scores',
+                         'clean/p/moe/experts',
+                         'clean/p/moe/route',
+                         'head',
+                         'noisy',
+                         'noisy/p/attn',
+                         'noisy/p/attn/out',
+                         'noisy/p/attn/scatter',
+                         'noisy/p/attn/scores',
+                         'noisy/p/moe/experts',
+                         'noisy/p/moe/route'],
+     'step_stats': ['moe_decode_held_experts_touched_share',
+                    'moe_max_tokens_per_held_expert',
+                    'moe_rows_computed_share',
+                    'moe_slots_on_absent_experts',
+                    'moe_tokens_per_held_expert'],
+     'step_scopes': ['attn',
+                     'attn/out',
+                     'attn/scatter',
+                     'attn/scores',
+                     'head',
+                     'moe/experts',
+                     'moe/route']},
 }
 
 
@@ -261,13 +335,19 @@ def test_the_surface_is_the_pinned_one(family):
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     assert jax.tree_util.tree_map(lambda x: x.shape, params) == want["params"]
     state = model.initial_state(4)
-    for form, t in (("fragment", 16), ("step", 1)):
+    for form, t in (("fragment", 16), ("step", model.tokens_per_step)):
         stats = {}
+        # a model that commits a block a step: its update replays a
+        # trace, its lane's form is a block with the commit
+        block_form = {} if model.tokens_per_step == 1 else (
+            {"commit": True} if form == "step"
+            else {"trace": jnp.zeros((4, t), jnp.int32)})
 
         def apply(p, tokens, state, fresh):
             stats.clear()
             return model.apply(
-                p, tokens, state, resets=fresh, scope="p", stats_out=stats)
+                p, tokens, state, resets=fresh, scope="p", stats_out=stats,
+                **block_form)
 
         jaxpr = jax.make_jaxpr(apply)(
             params, jnp.zeros((4, t), jnp.int32), state, jnp.zeros((4, t)))
