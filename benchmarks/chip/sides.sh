@@ -41,5 +41,5 @@ for path in sorted(glob.glob(f"{out}/sides_{cell}_*.jsonl")):
         print(path.rsplit("_", 1)[-1], r["result"]["seed"], r["result"].get("correct"), m,
               "missed:", missed, (r.get("lowerings") or {}).get("attention_fragment_lowerings"),
               "step:", (r.get("lowerings") or {}).get("attention_step_lowerings"),
-              r.get("learn_stats"))
+              r.get("learn_stats"), r.get("host_reads"), "iterations:", r["result"].get("attempted"))
 PY

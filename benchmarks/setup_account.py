@@ -10,7 +10,9 @@ as they stood when the measured window began, the harness's own
 ``[setup]`` line, the result line, the lowering counters at exit
 (``ray_tpu_*_lowerings_total``) and the newest iteration's
 ``LEARN_STATS`` (``attn_key_blocks_skipped_share``,
-``attn_decode_key_blocks_skipped_share``, ...). ``benchmarks/chip/setup_account.sh``
+``attn_decode_key_blocks_skipped_share``, ...), and ``host_reads``
+(``ray_tpu_rollout_drains_total{kind}``,
+``ray_tpu_weight_pulls_skipped_total``). ``benchmarks/chip/setup_account.sh``
 runs it cold and warm for each cell; ``--table`` prints PERF.md's
 "Where set-up goes" from such lines.
 """
@@ -104,6 +106,14 @@ def run(out_path: str, argv) -> int:
                      "mla_decode_lowerings")
         if hasattr(metrics, name)  # an older tree lacks the newest
     }
+    if hasattr(metrics, "rollout_drains"):
+        # the device lane's host half since the process began: reads of
+        # a rollout's metrics by kind, weight pulls nobody needed
+        record["host_reads"] = {
+            "rollout_drains": metrics.rollout_drains(),
+            "weight_pulls_skipped": metrics.counter_total(
+                metrics.WEIGHT_PULLS_SKIPPED_TOTAL),
+        }
     with open(out_path, "a") as f:
         f.write(json.dumps(record) + "\n")
     return rc

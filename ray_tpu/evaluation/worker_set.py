@@ -177,23 +177,24 @@ class WorkerSet:
         otherwise dominates the sync."""
         if self._local_worker is None:
             return
+        targets = self._remote_workers
+        if to_worker_indices is not None:
+            targets = [
+                w
+                for i, w in enumerate(targets)
+                if i + 1 in to_worker_indices
+            ]
         # the device->host pull of the acting weights is a blocking
-        # read whether or not a remote worker is there to take them
+        # read of megabytes: made only when somebody takes them
         with tracing.start_span(
-            "rollout:sync_weights", workers=len(self._remote_workers)
+            "rollout:sync_weights", workers=len(targets)
         ):
-            weights = self._local_worker.get_weights(
-                policies, inference_only=inference_only
-            )
-            if self._remote_workers:
-                ref = ray.put(weights)
-                targets = self._remote_workers
-                if to_worker_indices is not None:
-                    targets = [
-                        w
-                        for i, w in enumerate(self._remote_workers)
-                        if i + 1 in to_worker_indices
-                    ]
+            if targets:
+                ref = ray.put(
+                    self._local_worker.get_weights(
+                        policies, inference_only=inference_only
+                    )
+                )
                 for w in targets:
                     try:
                         w.set_weights.remote(ref, global_vars)
@@ -201,6 +202,8 @@ class WorkerSet:
                         # a corpse must not abort the broadcast to the
                         # rest of the fleet (recovery replaces it later)
                         continue
+            else:
+                telemetry_metrics.inc_weight_pulls_skipped()
         if global_vars:
             self._local_worker.set_global_vars(global_vars)
 

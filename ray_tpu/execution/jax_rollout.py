@@ -35,7 +35,11 @@ successor row's OBS the reset observation; GAE bootstraps 0 across
 
 Episode returns/lengths accumulate in the carry and drain with the
 stats readback as ``(T, N)`` masked arrays — the lane's RolloutMetrics
-come back without any per-step host work.
+come back without any per-step host work. The host reads nothing back
+that the round is not waiting for: :meth:`JaxRolloutEngine.rollout`
+starts the metrics' copy to the host and returns, and the read is
+finished when somebody asks for what it holds (``get_metrics``,
+``last_actions``) or behind the next rollout's dispatch.
 """
 
 from __future__ import annotations
@@ -130,8 +134,12 @@ class JaxRolloutEngine:
         # ``env.report_actions``: the last dispatch's actions, host
         # numpy ``([K,] T, N)``, and of a model that commits a block a
         # step the pass that committed each (its trace)
-        self.last_actions = None
-        self.last_trace = None
+        self._last_actions = None
+        self._last_trace = None
+        # the last ``rollout()``'s metrics, still on the device with
+        # their copy to the host started, and ``dispatch_count`` as
+        # that rollout left it: at most one read is ever outstanding
+        self._pending = None
         self._rollout_fn = None
         self._body = None
         self.batch_size = self.N * self.T
@@ -601,11 +609,20 @@ class JaxRolloutEngine:
 
     def rollout(self):
         """One dispatched rollout: returns ``(device batch tree,
-        batch_size)`` with the env carry advanced and episode metrics
-        absorbed. The policy's rng advances by T sequential splits,
-        the actor lane's per-step stream in its order, composed in one
-        program (``JaxPolicy._rollout_keys``), so a rollout is two
-        dispatches: the key schedule and the rollout program."""
+        batch_size)`` with the env carry advanced. The policy's rng
+        advances by T sequential splits, the actor lane's per-step
+        stream in its order, composed in one program
+        (``JaxPolicy._rollout_keys``), so a rollout is two dispatches:
+        the key schedule and the rollout program.
+
+        The host does NOT wait for the program: the episode metrics
+        (done flags, returns, lengths, the actions) stay behind as the
+        engine's one pending read, their copy to the host started, and
+        whatever the caller dispatches next queues behind the rollout
+        on a busy chip. The read is finished by the first of
+        :meth:`get_metrics`, ``last_actions`` / ``last_trace``, or the
+        next ``rollout()`` once that one has dispatched its own
+        program, so episodes are recorded in the order they ended."""
         import jax
 
         from ray_tpu import sharding as sharding_lib
@@ -625,15 +642,41 @@ class JaxRolloutEngine:
             self._carry, batch, metrics = self.rollout_from(
                 policy.params, self._carry, ro_rngs, coeffs
             )
-            # the one blocking read of the lane: the episode metrics
-            with tracing.start_span("rollout:drain") as drain:
-                metrics = jax.device_get(metrics)
-                drain.set_attribute(
-                    "bytes", sharding_lib.tree_nbytes(metrics)
-                )
-            self._record_metrics(metrics)
-            self._count_env_steps(self.batch_size)
+        # the previous rollout's read, behind this one's dispatch
+        self._finish_drain()
+        for leaf in jax.tree_util.tree_leaves(metrics):
+            leaf.copy_to_host_async()
+        self._pending = (metrics, sharding_lib.dispatch_count())
+        self._count_env_steps(self.batch_size)
         return dict(batch), self.batch_size
+
+    def _finish_drain(self) -> None:
+        """Finish the pending read, if there is one: the bytes are on
+        the host already wherever the chip has run the rollout since,
+        and the read costs a copy."""
+        if self._pending is None:
+            return
+        import jax
+
+        from ray_tpu import sharding as sharding_lib
+
+        (metrics, mark), self._pending = self._pending, None
+        deferred = sharding_lib.dispatch_count() != mark
+        with tracing.start_span("rollout:drain", deferred=deferred) as drain:
+            metrics = jax.device_get(metrics)
+            drain.set_attribute("bytes", sharding_lib.tree_nbytes(metrics))
+        telemetry_metrics.inc_rollout_drain(deferred)
+        self._record_metrics(metrics)
+
+    @property
+    def last_actions(self):
+        self._finish_drain()
+        return self._last_actions
+
+    @property
+    def last_trace(self):
+        self._finish_drain()
+        return self._last_trace
 
     def _count_env_steps(self, steps: int) -> None:
         telemetry_metrics.inc_env_steps_on_device(steps)
@@ -659,8 +702,8 @@ class JaxRolloutEngine:
     # -- episode metrics --------------------------------------------------
 
     def _record_metrics(self, metrics) -> None:
-        self.last_actions = metrics.get("actions")
-        self.last_trace = metrics.get(SampleBatch.UNMASK_STEP)
+        self._last_actions = metrics.get("actions")
+        self._last_trace = metrics.get(SampleBatch.UNMASK_STEP)
         done = np.asarray(metrics["done"]).reshape(-1)
         if not done.any():
             return
@@ -670,6 +713,7 @@ class JaxRolloutEngine:
             self._metrics.append(RolloutMetrics(int(l), float(r)))
 
     def get_metrics(self) -> List[RolloutMetrics]:
+        self._finish_drain()
         out = self._metrics
         self._metrics = []
         return out

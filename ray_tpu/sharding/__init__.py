@@ -17,6 +17,7 @@ shardings on the mesh :func:`resolve_mesh` builds from the config.
 from ray_tpu.sharding.compile import (
     ShardedFunction,
     compile_stats,
+    dispatch_count,
     f64_scope,
     sharded_jit,
 )
@@ -132,6 +133,7 @@ __all__ = [
     "clear_mesh_cache",
     "compile_stats",
     "data_axis",
+    "dispatch_count",
     "f64_scope",
     "get_mesh",
     "global_devices",

@@ -424,6 +424,14 @@ def f64_scope():
     return jax.enable_x64(True)
 
 
+def dispatch_count() -> int:
+    """Invocations of every live ShardedFunction so far. Two readings
+    that differ had a program dispatched between them (host code that
+    puts off a read asks whether it waited for anything)."""
+    with _LOCK:
+        return sum(f.calls for f in _REGISTRY)
+
+
 def compile_stats() -> Dict[str, Any]:
     """Process-wide compile-cache summary across every live
     ShardedFunction (benchmarks and the acceptance test read this)."""

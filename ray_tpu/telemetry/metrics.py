@@ -196,6 +196,13 @@ D2H_BYTES_TOTAL = "ray_tpu_d2h_bytes_total"
 # mesh-resident rollout programs (JaxVectorEnv lane) — compare against
 # ray_tpu_env_steps_sampled_total for the on-device fraction
 ENV_STEPS_ON_DEVICE_TOTAL = "ray_tpu_env_steps_on_device_total"
+# the lane's host half (execution/jax_rollout.py, WorkerSet.sync_weights):
+# reads of a rollout's episode metrics by kind (deferred = finished
+# after a later program was dispatched, so the host waited for no
+# rollout | blocking = forced before one), and acting-weight pulls
+# that were not made because no remote worker takes the weights
+ROLLOUT_DRAINS_TOTAL = "ray_tpu_rollout_drains_total"
+WEIGHT_PULLS_SKIPPED_TOTAL = "ray_tpu_weight_pulls_skipped_total"
 REPLAY_ROWS = "ray_tpu_replay_buffer_rows"
 REPLAY_CAPACITY = "ray_tpu_replay_buffer_capacity"
 REPLAY_BYTES = "ray_tpu_replay_buffer_bytes"
@@ -790,6 +797,28 @@ def inc_env_steps_on_device(n: int) -> None:
         ENV_STEPS_ON_DEVICE_TOTAL,
         "env steps taken inside mesh-resident rollout programs",
     ).inc(float(n))
+
+
+def inc_rollout_drain(deferred: bool) -> None:
+    """One read of a device rollout's episode metrics, by whether a
+    later dispatch stood between the rollout and the read."""
+    counter(
+        ROLLOUT_DRAINS_TOTAL,
+        "reads of a device rollout's episode metrics by kind",
+        ("kind",),
+    ).inc(1.0, {"kind": "deferred" if deferred else "blocking"})
+
+
+def rollout_drains() -> Dict[str, float]:
+    """``{kind: reads}`` since the process began."""
+    return _totals_by_tag(ROLLOUT_DRAINS_TOTAL, "kind")
+
+
+def inc_weight_pulls_skipped() -> None:
+    counter(
+        WEIGHT_PULLS_SKIPPED_TOTAL,
+        "sync_weights calls that pulled no weights: nobody takes them",
+    ).inc(1.0)
 
 
 def add_h2d_bytes(path: str, n: int) -> None:
