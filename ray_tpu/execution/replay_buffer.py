@@ -379,11 +379,16 @@ class SuperstepRingFeed:
     the mesh, and only ``idx`` (plus any ``extra`` stacked host
     columns, e.g. PER importance weights) cross host→device."""
 
-    def __init__(self, store, idx, extra, gather_fn, shardings, key):
+    def __init__(
+        self, store, idx, extra, gather_fn, unpack_fn, shardings, key
+    ):
         self.store = store
         self.idx = idx
         self.extra = extra
+        # (store, idx) -> (K, B, ...) rows as stored; one update's
+        # (B, ...) stored rows -> its logical columns
         self.gather_fn = gather_fn
+        self.unpack_fn = unpack_fn
         self.shardings = shardings
         self.key = key  # compile-cache key: the stored column set
 
@@ -420,21 +425,44 @@ def _unpack_rows(g, row_shape: tuple):
     return u8.reshape(g.shape[:-1] + tuple(row_shape))
 
 
-def _gather_columns(store, idx, meta):
-    """Rows ``idx`` (any int shape) of every ring in ``store`` as
-    logical columns — the one gather body of ``gather``/``sample``,
-    the fused tree sample and the superstep's ring feed."""
+def _gather_stored(store, idx):
+    """Rows ``idx`` (any int shape) of every ring in ``store`` AS
+    STORED: a packed pixel column comes out as its words, pad and all
+    (the gather's rows are whole lanes, and the pad's slice is free
+    only where the bytes are made)."""
     import jax
 
     from ray_tpu.ops import framestack as framestack_lib
 
-    out = {}
     with jax.named_scope("replay/gather"):
-        for k, ring in store.items():
-            row_shape, _, packed = meta[k]
-            g = framestack_lib.gather_rows(ring, idx)
-            out[k] = _unpack_rows(g, row_shape) if packed else g
-    return out
+        return {
+            k: framestack_lib.gather_rows(ring, idx)
+            for k, ring in store.items()
+        }
+
+
+def _unpack_columns(cols, meta):
+    """Gathered rows as stored -> logical columns: the ONE words ->
+    bytes conversion of a packed pixel column. Columns ``meta`` does
+    not know (a feed's extra columns) pass through."""
+    import jax
+
+    with jax.named_scope("replay/gather"):
+        return {
+            k: _unpack_rows(g, meta[k][0])
+            if k in meta and meta[k][2]
+            else g
+            for k, g in cols.items()
+        }
+
+
+def _gather_columns(store, idx, meta):
+    """Rows ``idx`` (any int shape) of every ring in ``store`` as
+    logical columns — the one gather body of ``gather``/``sample`` and
+    the fused tree sample. (The superstep's ring feed takes the two
+    halves apart: it gathers K updates' rows before its scan and
+    unpacks one update's inside it.)"""
+    return _unpack_columns(_gather_stored(store, idx), meta)
 
 
 class DeviceReplayBuffer:
@@ -849,9 +877,12 @@ class DeviceReplayBuffer:
     ) -> SuperstepRingFeed:
         """Package the device rings for an in-program superstep gather
         (``idx``: pre-drawn ``(k, B)`` positions; ``extra``: stacked
-        host columns merged after the gather). The gather body is the
-        sample path's — same uint32-lane unpack — so the scan consumes
-        rows bit-identical to ``gather()``'s output."""
+        host columns merged after the gather). The two halves of the
+        sample path's gather body: ``gather_fn`` takes the K updates'
+        rows out of the rings as stored (before the scan), ``unpack_fn``
+        makes one update's logical columns of them (inside it) — same
+        uint32-lane unpack, so each update consumes rows bit-identical
+        to ``gather()``'s output."""
         if self._host is not None:
             raise RuntimeError(
                 "superstep_feed on a spilled buffer — use the host "
@@ -866,7 +897,10 @@ class DeviceReplayBuffer:
             store=self._store,
             idx=idx,
             extra=dict(extra or {}),
-            gather_fn=self._gather_fn(),
+            gather_fn=_gather_stored,
+            unpack_fn=functools.partial(
+                _unpack_columns, meta=dict(self._meta)
+            ),
             shardings=shardings,
             key=tuple(sorted(self._store)),
         )

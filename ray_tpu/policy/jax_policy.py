@@ -836,26 +836,35 @@ class JaxPolicy(Policy):
             # Different shuffle stream per data shard.
             rng = jax.random.fold_in(rng, jax.lax.axis_index(axis))
 
-            # uint8 row columns (pixel obs) gather 3-4x faster viewed
-            # as uint32 lanes (measured: 127 -> 420 GB/s effective on
-            # v5e — narrow-element gathers are element-width-bound),
-            # so pack them once per nest and unpack per minibatch
+            # one minibatch of every row (decided from shapes, for any
+            # num_sgd_iter): the nest takes the batch as it lies. A
+            # mean over rows does not depend on their order, so a
+            # permutation has nothing to select; a gathered copy would
+            # move every pixel row three more times (pack, gather,
+            # unpack) and double a sequence model's stored states
+            whole_batch = num_mb == 1 and mb_loc == b_loc
+            telemetry_metrics.inc_learn_minibatch_lowering(
+                "whole" if whole_batch else "gathered"
+            )
+            # a strict subset of rows is gathered by index: uint8 row
+            # columns (pixel obs) gather 3-4x faster viewed as uint32
+            # lanes (measured: 127 -> 420 GB/s effective on v5e —
+            # narrow-element gathers are element-width-bound), so pack
+            # them once per nest and unpack per minibatch
             packed_shapes = {}
             batch = dict(batch)
-            for k, v in list(batch.items()):
-                if (
-                    v.dtype == jnp.uint8
-                    and v.ndim >= 2
-                    and int(np.prod(v.shape[1:])) % 4 == 0
-                ):
-                    packed_shapes[k] = v.shape
-                    batch[k] = jax.lax.bitcast_convert_type(
-                        v.reshape(v.shape[0], -1, 4), jnp.uint32
-                    )
+            if not whole_batch:
+                for k, v in list(batch.items()):
+                    if (
+                        v.dtype == jnp.uint8
+                        and v.ndim >= 2
+                        and int(np.prod(v.shape[1:])) % 4 == 0
+                    ):
+                        packed_shapes[k] = v.shape
+                        batch[k] = jax.lax.bitcast_convert_type(
+                            v.reshape(v.shape[0], -1, 4), jnp.uint32
+                        )
 
-            whole_batch = num_mb == 1 and mb_loc == b_loc and any(
-                k.startswith("__chunk__") for k in batch
-            )
             # a model whose saved activations of a whole minibatch do
             # not fit asks for groups of unrolls, each taken through
             # forward, loss and backward on its own (models/sequence_lm)
@@ -872,17 +881,13 @@ class JaxPolicy(Policy):
             def mb_step(carry, mb_rng_idx):
                 params, opt_state = carry
                 idx, mb_rng, is_last = mb_rng_idx
-                # __chunk__ columns hold one row per T-row unroll
-                # (chunk-start recurrent states); gather them by the
-                # unroll indices the row permutation selected
-                with jax.named_scope("learn/minibatch"):
-                    if whole_batch:
-                        # one minibatch of every row: a mean over rows
-                        # does not depend on their order, and a
-                        # gathered copy of the stored states would
-                        # double them
-                        mb = {k: _unpack(k, v) for k, v in batch.items()}
-                    else:
+                if whole_batch:
+                    mb = batch
+                else:
+                    # __chunk__ columns hold one row per T-row unroll
+                    # (chunk-start recurrent states); gather them by
+                    # the unroll indices the row permutation selected
+                    with jax.named_scope("learn/minibatch"):
                         mb = {
                             k: _unpack(
                                 k,
@@ -939,9 +944,10 @@ class JaxPolicy(Policy):
                 stats = dict(stats, total_loss=loss, grad_gnorm=gnorm)
                 return (params, opt_state), stats
 
-            def epoch(carry, rng_e_i):
-                rng_e, ep_i = rng_e_i
-                perm_rng, scan_rng = jax.random.split(rng_e)
+            def shuffled_rows(perm_rng):
+                """``(num_mb, mb_loc)`` row indices of an epoch's
+                minibatches (a recurrent policy's T-row sequences move
+                whole)."""
                 if T_seq > 1:
                     seq_perm = jax.random.permutation(
                         perm_rng, b_loc // T_seq
@@ -952,7 +958,15 @@ class JaxPolicy(Policy):
                     ).reshape(-1)
                 else:
                     perm = jax.random.permutation(perm_rng, b_loc)
-                idx = perm[: num_mb * mb_loc].reshape(num_mb, mb_loc)
+                return perm[: num_mb * mb_loc].reshape(num_mb, mb_loc)
+
+            def epoch(carry, rng_e_i):
+                rng_e, ep_i = rng_e_i
+                # one key stream for both forms: a loss that draws
+                # noise gets the same mb_rngs whether or not a
+                # permutation is drawn from perm_rng
+                perm_rng, scan_rng = jax.random.split(rng_e)
+                idx = None if whole_batch else shuffled_rows(perm_rng)
                 mb_rngs = jax.random.split(scan_rng, num_mb)
                 is_last = (ep_i == num_iters - 1) & (
                     jnp.arange(num_mb) == num_mb - 1
@@ -1439,8 +1453,7 @@ class JaxPolicy(Policy):
             def program():
                 return dict(
                     update_fn=self._device_update_fn(batch_size),
-                    gather_fn=rings.gather_fn,
-                    store_shardings=rings.shardings,
+                    rings=rings,
                     extra_cols=extra_cols,
                     priority_fn=pri_fn,
                 )

@@ -102,8 +102,7 @@ def build_superstep_fn(
     label: str,
     stacked_cols: Optional[Sequence[str]] = None,
     replicated_cols: Sequence[str] = (),
-    gather_fn: Optional[Callable] = None,
-    store_shardings: Optional[Dict] = None,
+    rings=None,
     extra_cols: Sequence[str] = (),
     rollout_fn: Optional[Callable] = None,
     priority_fn: Optional[Callable] = None,
@@ -123,11 +122,16 @@ def build_superstep_fn(
       - ``stacked_cols``: the program takes a ``(K, B, ...)`` column
         tree; columns named in ``replicated_cols`` (e.g. the
         deduplicated frame pool) replicate instead of row-sharding.
-      - ``gather_fn(store, idx) -> (K, B, ...) tree`` with
-        ``store_shardings``: the program takes the device replay rings
-        plus a host ``(K, B)`` index array and gathers the batches in
-        place; ``extra_cols`` names host-shipped stacked columns
-        merged after the gather (PER importance weights).
+      - ``rings`` (a ``SuperstepRingFeed``; only its two functions
+        and its shardings are kept, not its arrays): the program takes
+        the device replay rings plus a host ``(K, B)`` index array and
+        gathers the batches in place, ``rings.gather_fn(store, idx) ->
+        (K, B, ...) tree``; ``extra_cols`` names host-shipped stacked
+        columns merged after the gather (PER importance weights). The
+        scan carries the rows as the gather left them (a packed pixel
+        column as words); ``rings.unpack_fn(batch) -> batch`` makes
+        ONE update's logical columns of them inside the body, once,
+        for both of its readers (the update and ``priority_fn``).
       - ``rollout_fn(params, carry, rollout_rngs, coeffs) -> (carry,
         batch, metrics)``: each slot PRODUCES its own batch by rolling
         out a JAX-native vectorized env on the mesh
@@ -163,11 +167,11 @@ def build_superstep_fn(
     """
     if (
         int(stacked_cols is not None)
-        + int(gather_fn is not None)
+        + int(rings is not None)
         + int(rollout_fn is not None)
     ) != 1:
         raise ValueError(
-            "exactly one of stacked_cols / gather_fn / rollout_fn "
+            "exactly one of stacked_cols / rings / rollout_fn "
             "must be given"
         )
     if rollout_fn is not None and priority_fn is not None:
@@ -175,6 +179,12 @@ def build_superstep_fn(
             "priority_fn is a replay-feed feature; the rollout feed "
             "is on-policy"
         )
+    gather_fn = unpack_fn = None
+    if rings is not None:
+        # the feed's program half: the closures below keep these, not
+        # the feed and its arrays
+        gather_fn, unpack_fn = rings.gather_fn, rings.unpack_fn
+        store_shardings = dict(rings.shardings)
     axis = data_axis(mesh)
     replicated_cols = set(replicated_cols)
     with_pri = priority_fn is not None
@@ -223,6 +233,10 @@ def build_superstep_fn(
             params, opt_state, aux, batch, rng = vma_barrier(
                 (params, opt_state, aux, batch, rng)
             )
+            if unpack_fn is not None:
+                # after the barrier: the conversion's consumers are the
+                # update's and the priority pass's own first layers
+                batch = unpack_fn(batch)
             new_p, new_o, new_a, stats = update_fn(
                 params, opt_state, aux, batch, rng, coeffs
             )
@@ -271,7 +285,7 @@ def build_superstep_fn(
     if stacked_cols is not None:
         cols = tuple(stacked_cols)
     else:
-        cols = tuple(sorted(store_shardings or ())) + tuple(extra_cols)
+        cols = tuple(sorted(store_shardings)) + tuple(extra_cols)
     stacked_spec = {
         c: (P() if c in replicated_cols else P(None, axis))
         for c in cols
@@ -315,7 +329,7 @@ def build_superstep_fn(
 
     if gather_fn is not None:
         feed_spec = (
-            dict(store_shardings),
+            store_shardings,
             rep,
             {c: dat2 for c in extra_cols},
         )

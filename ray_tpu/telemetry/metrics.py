@@ -171,6 +171,14 @@ ATTENTION_STEP_LOWERINGS_TOTAL = "ray_tpu_attention_step_lowerings_total"
 # traced (layers whose checkpointed block is the same trace once), in
 # either form, so a trace says that every layer got its own geometry
 ATTENTION_LAYER_LOWERINGS_TOTAL = "ray_tpu_attention_layer_lowerings_total"
+# which form each traced SGD nest's minibatch took
+# (policy/jax_policy.py _nest_device_fn): form = whole (one minibatch
+# of every row of the per-shard batch: the nest takes the batch as it
+# lies, no permutation, no gather, no pack of a uint8 column) |
+# gathered (a strict subset of rows a step: pack uint8 columns once,
+# gather by a permutation's indices, unpack a minibatch). Decided from
+# shapes and counted when the nest is traced: once a traced nest body
+LEARN_MINIBATCH_LOWERINGS_TOTAL = "ray_tpu_learn_minibatch_lowering_total"
 # tokens through the stack of a model that generates by block diffusion
 # (models/sequence_lm/generation.py), by the form of the forward: form =
 # denoise | commit (the rollout's block forwards: counted by the device
@@ -748,6 +756,21 @@ def attention_layer_lowerings() -> Dict[str, float]:
         t = dict(tags)
         out["/".join(t.get(k, "") for k in ("kind", "heads", "rope"))] = v
     return out
+
+
+def inc_learn_minibatch_lowering(form: str) -> None:
+    """One traced SGD nest took ``form`` (``whole`` | ``gathered``) for
+    its minibatches."""
+    counter(
+        LEARN_MINIBATCH_LOWERINGS_TOTAL,
+        "SGD nests traced, by the form their minibatches took",
+        ("form",),
+    ).inc(1.0, {"form": form})
+
+
+def learn_minibatch_lowerings() -> Dict[str, float]:
+    """``{form: traced SGD nests}`` since the process began."""
+    return _totals_by_tag(LEARN_MINIBATCH_LOWERINGS_TOTAL, "form")
 
 
 def inc_attention_fragment_lowering(path: str) -> None:

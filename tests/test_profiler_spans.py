@@ -370,7 +370,8 @@ def lowered():
         (
             "superstep",
             [
-                "replay/gather", "sgd_nest", "learn/minibatch",
+                # one minibatch of all 32 rows: no learn/minibatch
+                "replay/gather", "sgd_nest",
                 "learn/loss_grad", "learn/allreduce", "learn/optimizer",
                 "learn/grad_norm", "learn/commit", "learn/td_error",
                 "/fc/", "/head/",
@@ -384,7 +385,8 @@ def lowered():
             "rollout_superstep",
             [
                 "rollout/act", "rollout/env_step",
-                "rollout/postprocess/gae", "sgd_nest", "learn/loss_grad",
+                "rollout/postprocess/gae", "sgd_nest", "learn/minibatch",
+                "learn/loss_grad",
                 "learn/allreduce", "learn/optimizer", "learn/commit",
             ],
         ),

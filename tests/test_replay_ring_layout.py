@@ -57,7 +57,9 @@ def test_rows_bit_identical_to_host_ring(row_shape, stored):
     """Insert from the host and from the device (past capacity, so
     the scatter wraps), then every read path — ``gather``, ``sample``
     and the superstep feed's ``gather_fn`` — returns the host ring's
-    rows bit for bit, whatever width the ring stores them at."""
+    rows bit for bit, whatever width the ring stores them at (the
+    feed gathers rows as stored and unpacks an update's: a packed
+    column is words, pad and all, between the two)."""
     assert _stored_words(row_shape) == stored
     rng = np.random.default_rng(0)
     host = ReplayBuffer(CAPACITY, seed=9)
@@ -85,11 +87,14 @@ def test_rows_bit_identical_to_host_ring(row_shape, stored):
             host._cols, dev.gather(np.arange(CAPACITY)).tree, what
         )
         feed = dev.superstep_feed(idx2)
-        _assert_rows_equal(
-            {k: col[idx2] for k, col in host._cols.items()},
-            jax.jit(feed.gather_fn)(feed.store, feed.idx),
-            what,
-        )
+        words = jax.jit(feed.gather_fn)(feed.store, feed.idx)
+        assert words["pix"].shape == idx2.shape + (stored,), what
+        for i, idx1 in enumerate(idx2):  # an update's rows at a time
+            _assert_rows_equal(
+                {k: col[idx1] for k, col in host._cols.items()},
+                jax.jit(feed.unpack_fn)({k: v[i] for k, v in words.items()}),
+                what,
+            )
     hs = host.sample(8)
     _assert_rows_equal(
         {k: np.asarray(v) for k, v in hs.items()},
@@ -270,6 +275,138 @@ def test_v5e_superstep_gather_reads_rows(
     )
     assert _ring_copies(compiled, capacity) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def _instructions(text):
+    """``(name, dtype, dims, opcode, operand names, called body)`` of every
+    instruction of an optimized HLO text that stands on its own (not
+    inside a fused computation); ``("tuple", ())`` for the dtype and
+    dims of one with several results."""
+    bodies = {}
+    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text):
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", comp)
+        if m:
+            bodies[m.group(1)] = comp
+    fused = set(re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", text))
+    out = []
+    for name, comp in bodies.items():
+        if name in fused:
+            continue
+        for line in comp.splitlines()[1:]:
+            m = re.match(
+                r"\s*(?:ROOT )?%([\w.\-]+) = "
+                r"(?:(\w+)\[([\d,]*)\]\S*|\(.*?\)) ([\w\-]+)\((.*)",
+                line,
+            )
+            if not m:
+                continue
+            inst, dtype, dims, opcode, rest = m.groups()
+            dtype, dims = dtype or "tuple", dims or ""
+            called = re.search(r"calls=%([\w.\-]+)", rest)
+            out.append((
+                inst, dtype, tuple(int(d) for d in dims.split(",") if d),
+                opcode,
+                re.findall(r"%([\w.\-]+)", rest.split("), ")[0]),
+                bodies.get(called.group(1), "") if called else "",
+            ))
+    return out
+
+
+def test_v5e_superstep_makes_a_pixel_rows_bytes_once(v5e_mesh):
+    """The benchmark cell's whole replay superstep (the DQN policy's
+    ``_device_update_fn(512)``, the ring feed of 131,072 x ``u32[7168]``
+    rows, the priority pass, K=8) as the chip's compiler leaves it.
+    What is held is the program's, not this compiler's count of
+    instructions: the rings are gathered once, as ``u32[4096,7168]``
+    words, before the scan; the scan carries words (no
+    ``u8[8,512,84,84,4]``); nothing packs bytes into words again; a
+    column's bytes are made ONCE an update, and the update's and the
+    priority pass's first convolutions all read those bytes; and since
+    they do, the target network's forward over ``new_obs`` stands once,
+    for the loss and the priorities (same bytes, same weights). The
+    parent fails four of these: it made the bytes of all 8 updates
+    before the scan, packed them into ``u32[512,84,84]`` in the body,
+    gathered every row by a permutation, unpacked a second time, and
+    ran a seventh convolution (the priority pass's own target forward,
+    over bytes that were another array). Between a ring and a
+    convolution libtpu 0.0.34 leaves six instructions a column (the
+    gather; the update's slice of it, a transposing ``copy``, the pad's
+    ``slice``, an 84 -> 88 ``reshape``, the words -> bytes fusion: the
+    first convolution wants the BATCH in the lanes), the parent ten;
+    docs/data_plane.md has them, no assertion counts them."""
+    import json
+    import os
+
+    import gymnasium as gym
+
+    from ray_tpu.algorithms.dqn.dqn import DQN, DQNJaxPolicy
+    from ray_tpu.sharding import superstep as superstep_lib
+
+    k, rows = 8, 512
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf/configs/nature_cnn_dqn_per.json")) as f:
+        cell = json.load(f)
+    cfg = DQN.get_default_config().to_dict()
+    cfg.update(cell["algo_config"])
+    cfg.update(seed=1, _mesh=sharding_lib.get_mesh(devices=jax.devices()[:1]))
+    policy = DQNJaxPolicy(
+        gym.spaces.Box(0, 255, (84, 84, 4), np.uint8), gym.spaces.Discrete(3), cfg
+    )
+    shapes = jax.tree_util.tree_map(
+        lambda x: _on(v5e_mesh, x.shape, x.dtype),
+        (policy.params, policy.opt_state, policy.aux_state, policy._coeff_array()),
+    )
+    policy.mesh = v5e_mesh
+    buf = _abstract_buffer(v5e_mesh, CELL_ROWS, {
+        "obs": ((84, 84, 4), np.uint8), "new_obs": ((84, 84, 4), np.uint8),
+        "actions": ((), np.int32), "rewards": ((), np.float32),
+        "dones": ((), np.float32),
+    })
+    feed = buf.superstep_feed(np.zeros((k, rows), np.int32))
+    fn = superstep_lib.build_superstep_fn(
+        policy._device_update_fn(rows), mesh=v5e_mesh, k=k, label="superstep[cell]",
+        rings=feed, extra_cols=("weights",),
+        priority_fn=policy._td_error_device_fn(),
+    )
+    params, opt_state, aux, coeffs = shapes
+    text = fn._jitted.lower(
+        params, opt_state, aux,
+        (dict(buf._store), _on(v5e_mesh, (k, rows), np.int32),
+         {"weights": _on(v5e_mesh, (k, rows), np.float32)}),
+        _on(v5e_mesh, (k,), np.float32), _on(v5e_mesh, (k, 2), np.uint32),
+        _on(v5e_mesh, (k, 2), np.uint32), coeffs,
+    ).compile().as_text()
+    assert not re.search(r"u8\[8,512,84,84,4\]", text)  # the scan carries words
+    # every instruction that writes an update's worth of pixels or more
+    by_name = {inst[0]: inst for inst in _instructions(text)}
+    views = ("parameter", "get-tuple-element", "tuple", "bitcast", "while")
+    pixels = [
+        inst for inst in by_name.values()
+        if inst[1] in ("u8", "u32") and inst[3] not in views
+        and int(np.prod(inst[2])) * (4 if inst[1] == "u32" else 1) >= rows * 84 * 84 * 4
+    ]
+
+    def reads(inst, dtype):
+        return any(by_name[o][1] == dtype for o in inst[4] if o in by_name)
+
+    gathers = [i for i in pixels if " gather(" in i[5]]
+    assert [(i[1], i[2]) for i in gathers] == [("u32", (k * rows, 7168))] * 2
+    packs = [i for i in pixels if i[1] == "u32" and reads(i, "u8")]
+    assert packs == []
+    unpacks = [i for i in pixels if i[1] == "u8"]
+    assert [i[2] for i in unpacks] == [(rows, 84, 84, 4)] * 2
+    assert all(reads(i, "u32") for i in unpacks)
+    # and the bytes are what the convolutions read: three forwards and
+    # a backward of the loss (online on obs; target and, for double-Q,
+    # online on new_obs), two forwards of the priority pass on the
+    # updated weights. A seventh would be its own target forward.
+    made = {u[0] for u in unpacks}
+    convs = [
+        i for i in by_name.values()
+        if " convolution(" in i[5] and made & set(i[4])
+    ]
+    assert 4 <= len(convs) <= 6, [(i[0], i[4]) for i in convs]
+    assert len({tuple(i[4]) for i in convs}) == len(convs)
 
 
 def test_v5e_delta_step_kernel_compiles_in_place(v5e_mesh):

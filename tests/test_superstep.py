@@ -467,8 +467,7 @@ def test_superstep_ring_gather_adds_no_collective():
     fn_rings = build_superstep_fn(
         p._device_update_fn(BS),
         label="rings",
-        gather_fn=feed.gather_fn,
-        store_shardings=feed.shardings,
+        rings=feed,
         **common,
     )
     cols = tuple(sorted(feed.store))
