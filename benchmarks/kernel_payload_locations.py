@@ -71,7 +71,7 @@ def fused_program_text(root: str) -> str:
     from ray_tpu.ops import ssd
     from ray_tpu.sharding import compile as compile_lib
 
-    ssd._kernel_applies = lambda state: state.ndim == 5  # as on a TPU
+    ssd._kernel_applies = lambda state, groups=1: state.ndim == 5  # as on a TPU
 
     class Dispatch:
         def __init__(self, jitted, label):

@@ -6,7 +6,11 @@ the key names of the published ``config.json`` each kind comes from;
 
 A block is two sublayers, a MIXER and a FEED-FORWARD, each with its own
 zero-centred RMSNorm ``rms(x) = x * rsqrt(mean(x^2) + eps) * (1 + w)``,
-each applied through the block's RESIDUAL kind. A kind is one frozen
+each applied through the block's RESIDUAL kind; or ONE of the two
+(``nemotron_h``: every character of ``hybrid_override_pattern`` is a
+block of one sublayer under one norm; the half it lacks is a
+``NoSublayer``, which has no norm, no leaf and no state, and the block
+runs the half it has). A kind is one frozen
 description with one protocol (``kinds.Kind``), its published source
 and arithmetic on its docstring; ``SequenceLM`` (``model.py``) asks the
 kinds in loops and knows none by name. What is the model's: the pattern
@@ -24,7 +28,10 @@ moments for it.
 A run of consecutive layers of a ``stacked`` kind (``"mamba"``) is ONE
 group of the parameter tree, ``"layers_<first>_<last>"``, its leaves
 stacked on a leading layer axis, and one ``lax.scan`` over them in
-either form. Every other layer is its own group ``"layer_<n>"``.
+either form (a state-space block between other kinds' blocks is a run of
+ONE layer, ``"layers_<n>_<n>"``: its state is still the stacked leaf the
+one-token kernel takes). Every other layer is its own group
+``"layer_<n>"``.
 
 State (``initial_state``; one row per stream, a flat tuple): for each
 group in order the leaves its mixer's ``state_shapes`` names (a stacked
@@ -53,11 +60,11 @@ from ray_tpu.models.sequence_lm.config import (
     Segment, attention_layers_of, describe, layer_types_of)
 from ray_tpu.models.sequence_lm.kinds import (
     AttentionLayer, DeltaNetLayer, DenseLayer, ExpertLayer, HyperResidual,
-    LatentLayer, MambaLayer, PlainResidual)
+    LatentLayer, MambaLayer, NoSublayer, PlainResidual)
 from ray_tpu.models.sequence_lm.model import SequenceLM
 
 __all__ = [
     "SequenceLM", "Segment", "describe", "layer_types_of", "attention_layers_of",
     "AttentionLayer", "LatentLayer", "DeltaNetLayer", "MambaLayer",
-    "DenseLayer", "ExpertLayer", "PlainResidual", "HyperResidual",
+    "DenseLayer", "ExpertLayer", "NoSublayer", "PlainResidual", "HyperResidual",
 ]

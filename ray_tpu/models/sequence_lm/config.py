@@ -11,12 +11,17 @@ import numpy as np
 from ray_tpu.models.sequence_lm.generation import Autoregressive, BlockDiffusion
 from ray_tpu.models.sequence_lm.kinds import (
     AttentionLayer, DeltaNetLayer, DenseLayer, ExpertLayer, HyperResidual,
-    LatentLayer, MambaLayer, PlainResidual)
+    LatentLayer, MambaLayer, NoSublayer, PlainResidual)
 from ray_tpu.ops import latent_attention
 
 LINEAR, FULL, LATENT = "linear_attention", "full_attention", "latent_attention"
 MAMBA, ATTENTION, SLIDING = "mamba", "attention", "sliding_attention"
 DENSE, EXPERTS = "dense", "experts"
+# the half a block of one sublayer lacks
+NONE = "none"
+# ``hybrid_override_pattern`` (``nemotron_h``): a character a block of
+# ONE sublayer, ``(mixer, feed-forward)``
+_PATTERN = {"M": (MAMBA, NONE), "E": (NONE, EXPERTS), "*": (ATTENTION, NONE)}
 # families whose every layer is ``qwen3_moe``'s: full attention with q/k
 # norms and no gate over an expert layer with no shared expert
 _QWEN3_MOE_STACKS = ("sdar_moe",)
@@ -42,14 +47,33 @@ def generation_of(config: Dict):
     return Autoregressive()
 
 
+def _pattern_of(config: Dict):
+    """``[(mixer, feed-forward)]`` of the first ``num_hidden_layers``
+    characters of a ``hybrid_override_pattern``. ``-``, the dense
+    feed-forward block of other Nemotron-H models, is refused by name."""
+    pattern = str(config["hybrid_override_pattern"])
+    pattern = pattern[:int(config.get("num_hidden_layers", len(pattern)))]
+    unknown = sorted(set(pattern) - set(_PATTERN))
+    if unknown:
+        raise ValueError(
+            f"hybrid_override_pattern has blocks {unknown} (of M, E, * and "
+            "the dense feed-forward block '-', only the first three are read)")
+    return [_PATTERN[ch] for ch in pattern]
+
+
 def layer_types_of(config: Dict) -> Tuple[str, ...]:
-    """The pattern: ``layer_types`` if stated; all full attention for
+    """The pattern of mixers: by ``hybrid_override_pattern`` where the
+    config has one (``M`` ``"mamba"``, ``*`` ``"attention"``, ``E``
+    ``"none"``: a block of experts alone); ``layer_types`` if stated;
+    all full attention for
     a ``qwen3_moe`` stack; all latent attention
     where the config has a ``kv_lora_rank``; by
     ``sliding_window_layout`` where the config has one (1: a window
     layer, which is also where ``rope_layout`` turns; 0: full depth and
     no positions); else every ``full_attention_interval``-th layer is
     full attention."""
+    if "hybrid_override_pattern" in config:
+        return tuple(mixer for mixer, _ in _pattern_of(config))
     if config.get("layer_types"):
         # a published list: its first ``num_hidden_layers``
         return tuple(config["layer_types"])[:config.get("num_hidden_layers")]
@@ -164,13 +188,28 @@ def _deltanet_layer(c: Dict) -> DeltaNetLayer:
 
 
 def _mamba_layer(c: Dict) -> MambaLayer:
-    if int(c.get("mamba_n_groups", 1)) != 1:
-        raise ValueError("B and C are shared by all heads: mamba_n_groups 1")
+    """The state-space layer under either family's names.
+    ``granitemoehybrid`` (Granite 4.0-H): ``mamba_n_heads``,
+    ``mamba_d_head``, ``mamba_d_state``, ``mamba_d_conv``,
+    ``mamba_conv_bias``, ``mamba_chunk_size``, ``mamba_n_groups``, and
+    an inner width that is also ``mamba_expand x hidden_size``.
+    ``nemotron_h`` (``mamba_num_heads``): ``mamba_head_dim``,
+    ``ssm_state_size``, ``conv_kernel``, ``use_conv_bias``,
+    ``chunk_size``, ``n_groups``; the inner width is ``mamba_num_heads x
+    mamba_head_dim`` whatever ``expand`` says (4,096 beside a hidden
+    size of 2,688)."""
+    if "mamba_num_heads" in c:
+        return MambaLayer(
+            heads=int(c["mamba_num_heads"]), head=int(c["mamba_head_dim"]),
+            state=int(c["ssm_state_size"]), conv=int(c["conv_kernel"]),
+            conv_bias=bool(c.get("use_conv_bias", True)),
+            chunk=int(c.get("chunk_size", 256)), groups=int(c.get("n_groups", 1)))
     layer = MambaLayer(
         heads=int(c["mamba_n_heads"]), head=int(c["mamba_d_head"]),
         state=int(c["mamba_d_state"]), conv=int(c["mamba_d_conv"]),
         conv_bias=bool(c.get("mamba_conv_bias", True)),
-        chunk=int(c.get("mamba_chunk_size", 256)))
+        chunk=int(c.get("mamba_chunk_size", 256)),
+        groups=int(c.get("mamba_n_groups", 1)))
     if layer.inner != int(c.get("mamba_expand", 2)) * int(c["hidden_size"]):
         raise ValueError("mamba_n_heads x mamba_d_head is not the inner width")
     return layer
@@ -184,7 +223,18 @@ def _expert_layer(c: Dict, experts: int) -> ExpertLayer:
     ``qwen3_next`` does and routes as DeepSeek-V3 does without the
     selection bias. The shared expert: ``qwen3_next`` states its width
     and gates it; DeepSeek-V3 counts shared experts of the routed
-    width; a ``qwen3_moe`` stack has none."""
+    width; a ``qwen3_moe`` stack has none. ``nemotron_h`` (a config with
+    ``moe_shared_expert_intermediate_size``): DeepSeek-V3's router
+    (sigmoid, the selection bias ``e_score_correction_bias``) though it
+    states neither ``scoring_func`` nor ``topk_method``; its ``n_group``
+    and ``topk_group`` are the ROUTER's groups and only 1 is read (the
+    group-limited choice is then the plain top-k; ``n_groups`` is the
+    state-space layer's); experts and shared expert ungated, the
+    activation ``mlp_hidden_act``, the shared expert's width stated and
+    its output not gated."""
+    nemotron = "moe_shared_expert_intermediate_size" in c
+    if nemotron and (int(c.get("n_group", 1)), int(c.get("topk_group", 1))) != (1, 1):
+        raise ValueError("a router that chooses among groups of experts is not supported")
     primary = "moe_num_primary_experts" in c
     if primary and not c.get("moe_primary_router_apply_softmax", True):
         raise ValueError("a primary router without its softmax is not supported")
@@ -201,18 +251,22 @@ def _expert_layer(c: Dict, experts: int) -> ExpertLayer:
         norm_topk=bool(c.get("norm_topk_prob", True)),
         width=width,
         route_on="input" if primary else "stream",
-        activation="relu" if primary else "silu",
-        scoring=str(c.get("scoring_func", "sigmoid" if scaled else "softmax")),
-        select_bias=c.get("topk_method") == "noaux_tc",
+        activation=str(c["mlp_hidden_act"]) if nemotron else (
+            "relu" if primary else "silu"),
+        scoring=str(c.get(
+            "scoring_func", "sigmoid" if scaled or nemotron else "softmax")),
+        select_bias=nemotron or c.get("topk_method") == "noaux_tc",
         scale=float(c.get(
             "routed_scaling_factor", c.get("moe_routed_scaling_factor", 1.0))),
         shared_width=int(
-            c["shared_expert_intermediate_size"] if stated
+            c["moe_shared_expert_intermediate_size"] if nemotron
+            else c["shared_expert_intermediate_size"] if stated
             else int(c.get(
                 "n_shared_experts",
                 0 if primary or _qwen3_moe_stack(c) else 1)
             ) * width),
         shared_gated=stated and not scaled,
+        gated=not nemotron,
         # every masked position of a pass routes alike
         alone=3 if generation_of(c).tokens_per_step > 1 else 0,
     )
@@ -227,10 +281,21 @@ class Segment(NamedTuple):
     ffn: object
     layers: int
 
+    @property
+    def sublayers(self) -> Tuple[str, ...]:
+        """The halves its blocks have (``"mixer"``, ``"ffn"``), in order:
+        both, or the one of a block of one sublayer."""
+        return tuple(sub for sub, kind in (("mixer", self.mixer), ("ffn", self.ffn))
+                     if not kind.absent)
+
 
 def describe(config: Dict) -> Dict:
     """What ``SequenceLM`` holds of a config, by attribute. Mixers by
-    :func:`layer_types_of`. Feed-forwards by ``mlp_layer_types``
+    :func:`layer_types_of`. A config with a ``hybrid_override_pattern``
+    (``nemotron_h``) has blocks of ONE sublayer: the other half is
+    ``"none"`` in ``layer_types`` / ``ffn_types`` and a
+    :class:`~ray_tpu.models.sequence_lm.kinds.NoSublayer` in the
+    segment. Otherwise feed-forwards by ``mlp_layer_types``
     (``"dense"`` or ``"sparse"`` a layer) where the config states them,
     else ``"dense"`` for the first ``first_k_dense_replace`` layers and
     ``"experts"`` after; a config that counts no experts
@@ -245,20 +310,23 @@ def describe(config: Dict) -> Dict:
     layer_types = layer_types_of(c)
     layers = len(layer_types)
     attention = attention_layers_of(c, layer_types)
-    others = {LINEAR: _deltanet_layer, LATENT: _latent_layer, MAMBA: _mamba_layer}
+    others = {LINEAR: _deltanet_layer, LATENT: _latent_layer, MAMBA: _mamba_layer,
+              NONE: lambda c: NoSublayer()}
     made = {kind: others[kind](c) for kind in set(layer_types) & set(others)}
     # experts the config counts, under whichever family's key
     experts = int(next(
         (c[k] for k in ("num_experts", "n_routed_experts", "num_local_experts",
                         "moe_num_primary_experts")
          if k in c), 0))
-    if experts and "mlp_layer_types" in c:  # stated a layer
+    if "hybrid_override_pattern" in c:  # a block's one sublayer
+        ffn_types = tuple(ffn for _, ffn in _pattern_of(c))
+    elif experts and "mlp_layer_types" in c:  # stated a layer
         ffn_types = tuple(
             DENSE if kind == DENSE else EXPERTS for kind in c["mlp_layer_types"][:layers])
     else:
         dense_first = int(c.get("first_k_dense_replace", 0)) if experts else layers
         ffn_types = tuple(DENSE if i < dense_first else EXPERTS for i in range(layers))
-    ffn_of = {}
+    ffn_of = {NONE: NoSublayer()}
     if DENSE in ffn_types:
         ffn_of[DENSE] = DenseLayer(
             int(c.get("shared_intermediate_size", c.get("intermediate_size"))))
@@ -269,6 +337,8 @@ def describe(config: Dict) -> Dict:
     if lanes > 1:
         if residual.scale != 1.0:
             raise ValueError("residual_multiplier with hc_mult lanes is not defined")
+        if NONE in layer_types + ffn_types:
+            raise ValueError("hc_mult lanes around a block of one sublayer")
         if EXPERTS in ffn_of and ffn_of[EXPERTS].route_on == "input":
             raise ValueError("a router on the layer's input with hc_mult lanes")
         residual = HyperResidual(
@@ -295,7 +365,9 @@ def describe(config: Dict) -> Dict:
             Segment(f"layers_{i}_{i + n - 1}" if mixer.stacked else f"layer_{i}",
                     mixer, ffn, n) for i, mixer, ffn, n in runs),
         hidden=int(c["hidden_size"]), positions=int(c["max_position_embeddings"]),
-        eps=float(c.get("rms_norm_eps", 1e-6)),
+        eps=float(next(
+            (c[k] for k in ("rms_norm_eps", "layer_norm_epsilon", "norm_eps") if k in c),
+            1e-6)),
         embed_scale=float(c.get("embedding_multiplier", 1.0)),
         logits_scale=float(c.get("logits_scaling", 1.0)),
         tied_head=bool(c.get("tie_word_embeddings", False)))

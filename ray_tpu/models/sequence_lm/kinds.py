@@ -48,6 +48,10 @@ class Kind:
     init_rules = {}
     # the keys of ``apply``'s statistics, each with its ``REDUCTIONS``
     stats = {}
+    # the half a block of ONE sublayer lacks (:class:`NoSublayer`)
+    absent = False
+    # where a feed-forward's router reads: None, no router
+    route_on = None
 
     def param_shapes(self, hidden: int):
         """The leaves of the layer's group that are this kind's."""
@@ -464,6 +468,18 @@ class DeltaNetLayer(Kind):
             return dot(o.reshape(b, t, vd), p["out_proj"], dtype), (s1, new_tail), {}
 
 
+@dataclasses.dataclass(frozen=True)
+class NoSublayer(Kind):
+    """The half a block of ONE sublayer does not have (``nemotron_h``:
+    every character of ``hybrid_override_pattern`` is ``h <- h +
+    f(rms(h))`` with ONE ``f``, a mixer or a feed-forward, under ONE
+    norm). No leaves, no state, no scope, and no norm and no residual
+    around it: ``SequenceLM`` gives a block the norms of the halves it
+    has and runs those."""
+
+    absent = True
+
+
 def _inverse_softplus_of_a_step(key, shape):
     dt = jnp.exp(jax.random.uniform(
         key, shape, minval=np.log(1e-3), maxval=np.log(1e-1)))
@@ -472,18 +488,23 @@ def _inverse_softplus_of_a_step(key, shape):
 
 @dataclasses.dataclass(frozen=True)
 class MambaLayer(Kind):
-    """``"mamba"`` (Mamba-2 as Hugging Face ``granitemoehybrid`` holds
-    it; Dao & Gu, arXiv:2405.21060): ``[z | x | B | C | dt] = h W_in``
-    (no bias); a causal depthwise convolution (width ``conv``) WITH a
-    bias and SiLU over the channels of ``(x, B, C)``; ``dt = softplus(dt
-    + dt_bias)``, ``A = -exp(A_log)`` a head; per head ``S <- exp(dt A) S
-    + dt x B^T``, ``y = S C + D x`` (``ops/ssd.py``; ``B`` and ``C`` are
-    shared by every head: ``mamba_n_groups`` 1); ``rms(y * silu(z)) * w``
-    over the whole inner width, then the output projection. Starts as
-    the family's does: ``A`` 1..heads, ``D`` one, ``dt_bias`` the inverse
+    """``"mamba"`` (Mamba-2 as Hugging Face ``granitemoehybrid`` and
+    ``nemotron_h`` hold it; Dao & Gu, arXiv:2405.21060): ``[z | x | B | C
+    | dt] = h W_in`` (no bias); a causal depthwise convolution (width
+    ``conv``) WITH a bias and SiLU over the channels of ``(x, B, C)``;
+    ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)`` a head; per head
+    ``S <- exp(dt A) S + dt x B^T``, ``y = S C + D x`` (``ops/ssd.py``);
+    ``rms(y * silu(z)) * w``, then the output projection. ``B`` and ``C``
+    are ``groups`` rows of ``state`` numbers, group-major, head ``h``
+    reading row ``h // (heads / groups)``, and the gated norm is an RMS
+    over each group's ``inner / groups`` numbers: Granite 4.0-H has ONE
+    group (rows every head shares, a norm over the whole inner width),
+    Nemotron-H 8 (of 8 heads and 512 numbers each). Starts as the
+    families' do: ``A`` 1..heads, ``D`` one, ``dt_bias`` the inverse
     softplus of a log-uniform step in (0.001, 0.1).
 
-    A run of consecutive layers is one stacked group. State, a RUN: two
+    A run of consecutive layers is one stacked group (a layer between
+    other kinds' blocks a run of one). State, a RUN: two
     leaves with the layer axis after the stream's, the ``(layers, heads,
     head, state)`` float32 matrices and the last ``conv - 1`` inputs of
     each convolution (the one-token form is handed the run's matrices
@@ -498,6 +519,8 @@ class MambaLayer(Kind):
     conv_bias: bool
     # how the published code computes a fragment, not a width
     chunk: int
+    # rows of ``B`` and of ``C``, and groups of the gated norm
+    groups: int = 1
 
     stacked = True
     cleared_on_reset = True
@@ -517,12 +540,12 @@ class MambaLayer(Kind):
 
     @property
     def conv_dim(self) -> int:
-        return self.inner + 2 * self.state
+        return self.inner + 2 * self.groups * self.state
 
     def param_shapes(self, d: int):
         shapes = dict(
             # columns [z | x | B | C | dt]
-            in_proj=(d, 2 * self.inner + 2 * self.state + self.heads),
+            in_proj=(d, self.inner + self.conv_dim + self.heads),
             conv=(self.conv_dim, self.conv),
             dt_bias=(self.heads,),
             A_log=(self.heads,),
@@ -543,7 +566,7 @@ class MambaLayer(Kind):
         dtype = ctx["dtype"]
         s0, tail = state
         b, t, _ = x.shape
-        inner, n, heads = self.inner, self.state, self.heads
+        inner, n, heads = self.inner, self.groups * self.state, self.heads
         with jax.named_scope(scope + "/in"):
             zxbcdt = dot(x, p["in_proj"], dtype)
             z = zxbcdt[..., :inner]
@@ -556,6 +579,8 @@ class MambaLayer(Kind):
         with jax.named_scope(scope + "/step"):
             xs = mixed[..., :inner].reshape(b, t, heads, self.head)
             bt, ct = mixed[..., inner : inner + n], mixed[..., inner + n :]
+            if self.groups > 1:  # a row a group (``ops/ssd.py``)
+                bt, ct = (v.reshape(b, t, self.groups, self.state) for v in (bt, ct))
             if t == 1:  # the run's stacked matrices and this layer's index
                 stacked, layer = s0
                 s1, y = ssd.ssd_step(
@@ -568,7 +593,12 @@ class MambaLayer(Kind):
                 )
             y = (y + p["D"][:, None] * xs).reshape(b, t, inner)
         with jax.named_scope(scope + "/out"):
-            y = rms(y * jax.nn.silu(z), p["ssm_norm"], ctx["eps"])
+            y, weight = y * jax.nn.silu(z), p["ssm_norm"]
+            if self.groups > 1:  # an RMS over each group's numbers
+                y = rms(y.reshape(b, t, self.groups, -1),
+                        weight.reshape(self.groups, -1), ctx["eps"]).reshape(b, t, inner)
+            else:
+                y = rms(y, weight, ctx["eps"])
             return (dot(y, p["out_proj"], dtype), (s1, new_tail),
                     {"ssm_dt_max": jnp.max(dt)})
 
@@ -582,8 +612,6 @@ class DenseLayer(Kind):
     ``mlp``."""
 
     width: int
-
-    route_on = None  # no router
 
     def param_shapes(self, d: int):
         w = self.width
@@ -644,6 +672,9 @@ class ExpertLayer(Kind):
     scale: float = 1.0
     shared_width: int = 0
     shared_gated: bool = False
+    # an expert, routed or shared, is ``(act(x Wg) * (x Wu)) Wd``; False:
+    # ``act(x Wu) Wd``, no gate matrix
+    gated: bool = True
     # held experts of a grouped call that may outgrow their buffers and
     # run over every token before the call goes dense (``ops/moe.py``)
     alone: int = 0
@@ -666,12 +697,15 @@ class ExpertLayer(Kind):
         e, f, fs = self.held, self.width, self.shared_width
         shapes = dict(
             router=(d, self.router_outputs),
-            experts_gate=(e, d, f),
             experts_up=(e, d, f),
             experts_down=(e, f, d),
         )
         if fs:
-            shapes.update(shared_gate=(d, fs), shared_up=(d, fs), shared_down=(fs, d))
+            shapes.update(shared_up=(d, fs), shared_down=(fs, d))
+        if self.gated:  # a gate matrix beside every up matrix
+            shapes["experts_gate"] = (e, d, f)
+            if fs:
+                shapes["shared_gate"] = (d, fs)
         if self.shared_gated:
             shapes["shared_expert_gate"] = (d, 1)
         if self.select_bias:
@@ -715,7 +749,9 @@ class ExpertLayer(Kind):
                 ) / (b * t * held),
                 "moe_routes": indices,
             }
-        experts = (p["experts_gate"], p["experts_up"], p["experts_down"])
+        # no gate matrix where the experts are ungated
+        experts = (p["experts_gate"] if self.gated else None,
+                   p["experts_up"], p["experts_down"])
         with jax.named_scope(scope + "moe/experts"):
             if lowering == "dense":
                 routed = moe.dense_experts_product(
@@ -730,7 +766,9 @@ class ExpertLayer(Kind):
             return routed.reshape(b, t, d), (), stats
         with jax.named_scope(scope + "moe/shared"):
             shared = moe.gated_mlp(
-                flat, p["shared_gate"], p["shared_up"], p["shared_down"], dtype=dtype)
+                flat, p["shared_gate"] if self.gated else None,
+                p["shared_up"], p["shared_down"],
+                dtype=dtype, activation=self.activation)
             if self.shared_gated:
                 shared = shared * jax.nn.sigmoid(
                     jnp.dot(flat, p["shared_expert_gate"], precision=HI))
@@ -739,7 +777,7 @@ class ExpertLayer(Kind):
 
 # -- residuals ------------------------------------------------------------
 
-_NORM = {"mixer": "input_norm", "ffn": "post_norm"}
+NORM_OF = {"mixer": "input_norm", "ffn": "post_norm"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -761,7 +799,7 @@ class PlainResidual(Kind):
         return x
 
     def around(self, x, p, sub, f, ctx):
-        y, new, stats = f(rms(x, p[_NORM[sub]], ctx["eps"]))
+        y, new, stats = f(rms(x, p[NORM_OF[sub]], ctx["eps"]))
         return x + (y if self.scale == 1.0 else y * self.scale), new, stats
 
 
@@ -795,13 +833,13 @@ class HyperResidual(Kind):
         small = lambda key, shape: jnp.full(shape, 0.01, jnp.float32)
         near_plain = lambda key, shape: jnp.concatenate(
             [jnp.zeros((2 * n,)), 2.0 * jnp.eye(n).ravel()]).astype(jnp.float32)
-        return {f"hc_{sub}_{leaf}": rule for sub in _NORM
+        return {f"hc_{sub}_{leaf}": rule for sub in NORM_OF
                 for leaf, rule in (("a", small), ("b", near_plain))}
 
     def param_shapes(self, d: int):
         n = self.lanes
         shapes = {}
-        for sub in _NORM:
+        for sub in NORM_OF:
             shapes[f"hc_{sub}_norm"] = (n * d,)
             shapes[f"hc_{sub}_phi"] = (n * d, 2 * n + n * n)
             shapes[f"hc_{sub}_a"] = (3,)
@@ -823,7 +861,7 @@ class HyperResidual(Kind):
                 *self.clamp, unroll=x.shape[1] == 1,
             )
             h = hyper_connection.mix_in(x, pre)
-        y, new, stats = f(rms(h, p[_NORM[sub]], ctx["eps"]))
+        y, new, stats = f(rms(h, p[NORM_OF[sub]], ctx["eps"]))
         with hc():
             stats = dict(
                 stats,

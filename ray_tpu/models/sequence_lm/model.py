@@ -10,7 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.sequence_lm.config import Segment, describe
-from ray_tpu.models.sequence_lm.kinds import HI, dot, over_layers, over_streams, rms
+from ray_tpu.models.sequence_lm.kinds import (
+    HI, NORM_OF, dot, over_layers, over_streams, rms)
 
 # a stacked run's leaves that enter a bfloat16 product
 _RUN_PRODUCT_LEAVES = ("in_proj", "out_proj", "mlp_gate", "mlp_up", "mlp_down")
@@ -121,7 +122,8 @@ class SequenceLM:
         if self.tied_head:
             del shapes["head"]
         for seg in self.segments:
-            layer = {"input_norm": (d,), "post_norm": (d,)}
+            # a norm for each half the block has
+            layer = {NORM_OF[sub]: (d,) for sub in seg.sublayers}
             for kind in (seg.ffn, self.residual, seg.mixer):
                 layer.update(kind.param_shapes(d))
             if seg.mixer.stacked:
@@ -268,10 +270,14 @@ class SequenceLM:
                 # the router reads the layer's input, before the mixer
                 with jax.named_scope(prefix + "moe/route"):
                     ctx["route"] = ffn.route(p, x.reshape(-1, x.shape[-1]))
-            x, new, stats = residual.around(
-                x, p, "mixer", lambda h: mixer.apply(p, h, layer_state, ctx), ctx)
-            x, _, more = residual.around(
-                x, p, "ffn", lambda h: ffn.apply(p, h, (), ctx), ctx)
+            # a block of one sublayer runs the half it has
+            new, stats, more = (), {}, {}
+            if not mixer.absent:
+                x, new, stats = residual.around(
+                    x, p, "mixer", lambda h: mixer.apply(p, h, layer_state, ctx), ctx)
+            if not ffn.absent:
+                x, _, more = residual.around(
+                    x, p, "ffn", lambda h: ffn.apply(p, h, (), ctx), ctx)
             # what both sublayers report (the residual's own) as two layers'
             both = over_layers(
                 {k: [jnp.stack([stats[k], more[k]])]

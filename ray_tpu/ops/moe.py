@@ -9,8 +9,10 @@ token. What the absent experts would add
 is left out (model-configs guide, section 4): no token is dropped, no
 capacity is set, and nothing stands in for other chips.
 
-An expert is gated: ``(act(x Wg) * (x Wu)) Wd`` with ``act`` named by
-``activation`` (``"silu"``: SwiGLU; ``"relu"``: ReGLU).
+An expert is gated, ``(act(x Wg) * (x Wu)) Wd``, or, handed no gate
+matrix, UNGATED, ``act(x Wu) Wd`` of two matrices, with ``act`` named by
+``activation`` (``"silu"``: SwiGLU; ``"relu"``: ReGLU; ``"relu2"``,
+``relu(.)^2``: Nemotron-H's ungated experts).
 
 The product has two forms with one result (float32 summation order
 apart), and :func:`product_lowering` picks one from the static shapes:
@@ -103,17 +105,30 @@ def expert_load(indices, first: int, held: int):
     return per_expert, jnp.sum(~here).astype(jnp.float32)
 
 
-_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+                "relu2": lambda v: jnp.square(jax.nn.relu(v))}
+
+
+def _hidden(act, gate, up):
+    """An expert's hidden activations from its float32 products; ``gate``
+    None: an ungated expert."""
+    return act(up) if gate is None else act(gate) * up
+
+
+def _cast(w, dtype):
+    return None if w is None else w.astype(dtype)
 
 
 def gated_mlp(x, w_gate, w_up, w_down, dtype=jnp.bfloat16, activation: str = "silu"):
-    """``(act(x Wg) * (x Wu)) Wd`` with ``dtype`` operands and float32
-    accumulation: the shared expert, and one routed expert."""
+    """``(act(x Wg) * (x Wu)) Wd``, or ``act(x Wu) Wd`` where ``w_gate``
+    is None, with ``dtype`` operands and float32 accumulation: the
+    shared expert, and one routed expert."""
     act = _ACTIVATIONS[activation]
     xb = x.astype(dtype)
-    gate = jnp.dot(xb, w_gate.astype(dtype), preferred_element_type=jnp.float32)
+    gate = None if w_gate is None else jnp.dot(
+        xb, w_gate.astype(dtype), preferred_element_type=jnp.float32)
     up = jnp.dot(xb, w_up.astype(dtype), preferred_element_type=jnp.float32)
-    hidden = (act(gate) * up).astype(dtype)
+    hidden = _hidden(act, gate, up).astype(dtype)
     return jnp.dot(hidden, w_down.astype(dtype), preferred_element_type=jnp.float32)
 
 
@@ -168,21 +183,23 @@ def dense_experts_product(
     dtype=jnp.bfloat16, activation: str = "silu",
 ):
     """``sum_e combine[t, e] * expert_e(x_t)``, every held expert over
-    every token. ``x`` ``(T, D)``; ``w_gate``, ``w_up`` ``(held, D,
-    F)``; ``w_down`` ``(held, F, D)``; ``combine`` ``(T, held)``. Tokens
+    every token. ``x`` ``(T, D)``; ``w_gate`` (None: ungated experts),
+    ``w_up`` ``(held, D, F)``; ``w_down`` ``(held, F, D)``; ``combine``
+    ``(T, held)``. Tokens
     go through in blocks of ``block_tokens`` (each recomputed in the
     backward pass), so the ``(block, held, F)`` hidden activations bound
     the memory, not ``(T, held, F)``."""
     t, d = x.shape
     act = _ACTIVATIONS[activation]
-    wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
+    wg, wu, wd = (_cast(w, dtype) for w in (w_gate, w_up, w_down))
 
     @jax.checkpoint
     def block(xb, cb):
         xb = xb.astype(dtype)
-        gate = jnp.einsum("td,edf->tef", xb, wg, preferred_element_type=jnp.float32)
+        gate = None if wg is None else jnp.einsum(
+            "td,edf->tef", xb, wg, preferred_element_type=jnp.float32)
         up = jnp.einsum("td,edf->tef", xb, wu, preferred_element_type=jnp.float32)
-        hidden = (act(gate) * up * cb[..., None]).astype(dtype)
+        hidden = (_hidden(act, gate, up) * cb[..., None]).astype(dtype)
         return jnp.einsum(
             "tef,efd->td", hidden, wd, preferred_element_type=jnp.float32
         )
@@ -202,8 +219,8 @@ def grouped_experts_product(
     num_experts: int, dtype=jnp.bfloat16, activation: str = "silu",
     alone: int = 0,
 ):
-    """The same sum over the (token, slot) pairs on held experts only.
-    ``indices``, ``weights`` ``(T, k)`` as :func:`route_top_k` gives
+    """The same sum over the (token, slot) pairs on held experts only
+    (``w_gate`` None: ungated experts). ``indices``, ``weights`` ``(T, k)`` as :func:`route_top_k` gives
     them, ``per_expert`` ``(held,)`` the pairs on each held expert
     (:func:`expert_load`). Pairs are sorted by expert (stable; pairs on
     absent experts last), so expert ``e``'s pairs are ``per_expert[e]``
@@ -217,13 +234,13 @@ def grouped_experts_product(
     such experts than that (any, at 0: every family that does not route
     a pass's tokens alike): ``dense``."""
     t, d = x.shape
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     k = indices.shape[-1]
     buffer = expert_buffer_rows(t, k, num_experts)
     counts = per_expert.astype(jnp.int32)
     act = _ACTIVATIONS[activation]
     # cast once for both ways: the cond hands back ``dtype`` gradients
-    wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
+    wg, wu, wd = (_cast(w, dtype) for w in (w_gate, w_up, w_down))
 
     buffered = counts
     if alone:
@@ -247,9 +264,10 @@ def grouped_experts_product(
         token = jnp.where(filled, pair // k, t)  # t: no token
         weight = jnp.where(filled, jnp.take(weights.reshape(-1), pair), 0.0)
         rows = jnp.take(x.astype(dtype), token, axis=0, mode="fill", fill_value=0)
-        gate = jnp.einsum("ecd,edf->ecf", rows, wg, preferred_element_type=jnp.float32)
+        gate = None if wg is None else jnp.einsum(
+            "ecd,edf->ecf", rows, wg, preferred_element_type=jnp.float32)
         up = jnp.einsum("ecd,edf->ecf", rows, wu, preferred_element_type=jnp.float32)
-        hidden = (act(gate) * up * weight[..., None]).astype(dtype)
+        hidden = (_hidden(act, gate, up) * weight[..., None]).astype(dtype)
         out = jnp.einsum(
             "ecf,efd->ecd", hidden, wd, preferred_element_type=jnp.float32
         )
@@ -270,7 +288,8 @@ def grouped_experts_product(
         def one_alone(e):
             column = jnp.sum(
                 jnp.where(indices - first == e, weights, 0.0), axis=-1, keepdims=True)
-            of = lambda w: jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False)
+            of = lambda w: None if w is None else jax.lax.dynamic_index_in_dim(
+                w, e, 0, keepdims=False)
             return column * gated_mlp(x, of(wg), of(wu), of(wd), dtype, activation)
 
         # typed as the tokens are: inside a ``shard_map`` a cond's two

@@ -3,12 +3,20 @@ two forms that are the same function of the same inputs.
 
 Per head, with a ``(P, N)`` state ``S`` (``P`` the head size, ``N`` the
 state size), a step size ``dt_t > 0``, a scalar ``A < 0`` a head, and
-``B_t``, ``C_t`` of ``N`` numbers shared by every head::
+``B_t``, ``C_t`` of ``N`` numbers::
 
     S   <- exp(dt_t A) S + dt_t x_t B_t^T
     y_t  = S C_t
 
 (the skip ``D x_t`` and the gate are the caller's).
+
+**The group axis.** ``B`` and ``C`` come in one of two shapes, told
+apart by their rank beside ``dt``'s: ``(..., N)``, rows every head
+shares (``mamba_n_groups`` 1: Granite 4.0-H), or ``(..., G, N)``, a row
+a GROUP of ``H / G`` consecutive heads (head ``h`` reads group ``h //
+(H / G)``; ``n_groups`` 8 of 64 heads: Nemotron-H). All three forms
+take both; the shared form lowers to the program it lowered to before
+there was a group axis.
 
 - :func:`ssd_step` is that recurrence for ONE token: the rollout
   lane's decode step (state in, state out).
@@ -81,15 +89,22 @@ _HI = jax.lax.Precision.HIGHEST
 _KERNEL_HEADS = 32
 
 
+def _groups(b, dt) -> int:
+    """``G`` where ``b`` has the group axis ``(..., G, N)``, one rank
+    above ``dt``'s ``(..., H)``; 0 where every head shares ``(..., N)``."""
+    return b.shape[-2] if b.ndim > dt.ndim else 0
+
+
 def ssd_step(state, x, dt, a, b, c, layer=None):
     """One token. ``state`` ``(..., H, P, N)``; ``x`` ``(..., H, P)``;
-    ``dt`` ``(..., H)``; ``a`` ``(H,)``; ``b``, ``c`` ``(..., N)``.
-    Returns ``(state, y)`` with ``y`` ``(..., H, P)``.
+    ``dt`` ``(..., H)``; ``a`` ``(H,)``; ``b``, ``c`` ``(..., N)`` or
+    ``(..., G, N)`` (the module's docstring). Returns ``(state, y)``
+    with ``y`` ``(..., H, P)``.
 
     With ``layer`` (an int32 scalar) ``state`` is a run's stacked leaf
     ``(B, layers, H, P, N)`` of which that layer's matrices take the
     step: the leaf comes back whole, the other layers as they were."""
-    if layer is not None and _kernel_applies(state):
+    if layer is not None and _kernel_applies(state, _groups(b, dt) or 1):
         telemetry_metrics.inc_ssm_step_lowering("kernel")
         return ssd_step_kernel(state, layer, x, dt, a, b, c)
     telemetry_metrics.inc_ssm_step_lowering("xla")
@@ -99,10 +114,15 @@ def ssd_step(state, x, dt, a, b, c, layer=None):
 
 
 def _step_body(state, x, dt, a, b, c):
+    groups = _groups(b, dt)
+    if groups:  # each head its group's row: (..., G, N) -> (..., H, 1, N)
+        a_head = lambda v: jnp.repeat(v, dt.shape[-1] // groups, axis=-2)[..., None, :]
+    else:
+        a_head = lambda v: v[..., None, None, :]
     decay = jnp.exp(dt * a)[..., None, None]
-    write = (dt[..., None] * x)[..., None] * b[..., None, None, :]
+    write = (dt[..., None] * x)[..., None] * a_head(b)
     state = decay * state + write
-    y = jnp.sum(state * c[..., None, None, :], axis=-1)
+    y = jnp.sum(state * a_head(c), axis=-1)
     return state, y
 
 
@@ -115,18 +135,20 @@ def _stacked_step_body(state, layer, x, dt, a, b, c):
         state, mine.astype(state.dtype), layer, 1), y
 
 
-def _kernel_applies(state) -> bool:
+def _kernel_applies(state, groups: int = 1) -> bool:
     """The kernel's lowering exists for a TPU, for a stacked float32
     leaf ``(streams, layers, H, P, N)`` with ``N`` whole 128-lane tiles
-    and ``P`` and the heads whole 8-sublane tiles (a matrix's rows; ``x``
-    is turned from lanes to sublanes a block of heads at a time); "a
-    TPU" as ``ops/backend.is_tpu`` has it."""
+    and ``P`` and the heads OF A GROUP whole 8-sublane tiles (a matrix's
+    rows; ``x`` is turned from lanes to sublanes a block of heads at a
+    time, and a block lies inside one group); "a TPU" as
+    ``ops/backend.is_tpu`` has it."""
     if not backend.is_tpu() or state.ndim != 5:
         return False
     heads, p, n = state.shape[-3:]
     return (
         state.dtype == jnp.float32
-        and n % 128 == 0 and p % 8 == 0 and heads % 8 == 0
+        and n % 128 == 0 and p % 8 == 0
+        and heads % groups == 0 and (heads // groups) % 8 == 0
     )
 
 
@@ -156,8 +178,9 @@ def _ssd_step_kernel(layer_ref, decay_ref, dtx_ref, b_ref, c_ref, s_ref,
     ``(1, H, N)``, a head's scalar repeated along the lanes; ``dtx``
     ``(1, H, P)`` arrives with ``P`` on the lanes and is turned once a
     block, so that a head's column broadcasts along the lanes of its
-    matrix; ``b``, ``c`` ``(1, 1, N)`` rows every head shares. The read
-    is taken from the block just written, while it is in VMEM."""
+    matrix; ``b``, ``c`` ``(1, 1, N)``, the rows of the block's group
+    (every head's, where there is one group). The read is taken from the
+    block just written, while it is in VMEM."""
     del layer_ref
     heads, _, n = s_ref.shape[2:]
     dtx_cols = dtx_ref[0].T  # (P, H)
@@ -186,7 +209,10 @@ def ssd_step_kernel(state, layer, x, dt, a, b, c, *, interpret=False):
     from ray_tpu import sharding as sharding_lib
 
     bsz, _, h, p, n = state.shape
-    heads = _KERNEL_HEADS if h % _KERNEL_HEADS == 0 else 8
+    groups = _groups(b, dt)
+    # a block of heads lies inside one group
+    per_group = h // (groups or 1)
+    heads = _KERNEL_HEADS if per_group % _KERNEL_HEADS == 0 else 8
     f32 = lambda v: v.astype(state.dtype)
     decay = jnp.broadcast_to(f32(jnp.exp(dt * a))[..., None], (bsz, h, n))
     dtx = f32(dt[..., None] * x)
@@ -195,7 +221,16 @@ def ssd_step_kernel(state, layer, x, dt, a, b, c, *, interpret=False):
     vma = sharding_lib.vma_of((state, x, dt, b, c))
     rows = lambda width: pl.BlockSpec(
         (1, heads, width), lambda i, j, layer: (i, j, 0))
-    shared = pl.BlockSpec((1, 1, n), lambda i, j, layer: (i, 0, 0))
+    if groups:
+        # ``(B, G, N)``: the row of block ``j``'s group, its axis squeezed
+        # out of the block (Mosaic wants a block's last two dimensions
+        # whole: ``(1, N)`` of ``(B, G, 1, N)``)
+        shared = pl.BlockSpec(
+            (1, None, 1, n), lambda i, j, layer: (i, j * heads // per_group, 0, 0))
+        row_of = lambda v: f32(v)[:, :, None]
+    else:  # ``(B, N)``: the one row, for every block
+        shared = pl.BlockSpec((1, 1, n), lambda i, j, layer: (i, 0, 0))
+        row_of = lambda v: f32(v)[:, None]
     matrices = pl.BlockSpec(
         (1, 1, heads, p, n), lambda i, j, layer: (i, layer[0], j, 0, 0))
     return pl.pallas_call(
@@ -218,7 +253,7 @@ def ssd_step_kernel(state, layer, x, dt, a, b, c, *, interpret=False):
         ),
         name="ssd_step",
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), decay, dtx,
-      f32(b)[:, None], f32(c)[:, None], state)
+      row_of(b), row_of(c), state)
 
 
 def ssd_chunked(
@@ -232,10 +267,10 @@ def ssd_chunked(
     chunk: int = 256,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``T`` tokens from ``state``. ``x`` ``(B, T, H, P)``; ``dt`` ``(B,
-    T, H)``; ``a`` ``(H,)``; ``b``, ``c`` ``(B, T, N)``; ``state`` ``(B,
-    H, P, N)``; ``resets`` ``(B, T)`` or None. ``T`` is a multiple of
-    ``chunk`` (or shorter than it). Returns ``(y (B, T, H, P), state)``.
-    """
+    T, H)``; ``a`` ``(H,)``; ``b``, ``c`` ``(B, T, N)`` or ``(B, T, G,
+    N)`` (the module's docstring); ``state`` ``(B, H, P, N)``; ``resets``
+    ``(B, T)`` or None. ``T`` is a multiple of ``chunk`` (or shorter
+    than it). Returns ``(y (B, T, H, P), state)``."""
     bsz, t, h, p = x.shape
     size = min(int(chunk), t)
     if t % size:
@@ -250,8 +285,28 @@ def ssd_chunked(
     row = jnp.arange(size)
     lower = row[:, None] >= row[None, :]
 
+    # the three places ``B`` and ``C`` enter: the ``C_i . B_j`` products
+    # weighing a head's decays, the read of the carried state and the
+    # write of the chunk's end state
+    groups = _groups(b, dt)
+    if groups:  # heads (B, H, ...) <-> their groups (B, G, H / G, ...)
+        grouped = lambda v: v.reshape((bsz, groups, h // groups) + v.shape[2:])
+        heads = lambda v: v.reshape((bsz, h) + v.shape[3:])
+        products = lambda cc, bc: jnp.einsum("bign,bjgn->bgij", cc, bc, precision=_HI)
+        weigh = lambda decay, cb: heads(grouped(decay) * cb[:, :, None])
+        read = lambda s, cc: heads(jnp.einsum(
+            "bgkpn,bcgn->bgkcp", grouped(s), cc, precision=_HI))
+        write = lambda xw, bc: heads(jnp.einsum(
+            "bgkcp,bcgn->bgkpn", grouped(xw), bc, precision=_HI))
+    else:
+        products = lambda cc, bc: jnp.einsum("bin,bjn->bij", cc, bc, precision=_HI)
+        weigh = lambda decay, cb: decay * cb[:, None]
+        read = lambda s, cc: jnp.einsum("bhpn,bcn->bhcp", s, cc, precision=_HI)
+        write = lambda xw, bc: jnp.einsum("bhcp,bcn->bhpn", xw, bc, precision=_HI)
+
     def one_chunk(s, xs):
-        xc, dtc, bc, cc, rc = xs  # (B, C, H, P), (B, C, H), (B, C, N) x 2, (B, C)
+        # (B, C, H, P), (B, C, H), (B, C[, G], N) x 2, (B, C)
+        xc, dtc, bc, cc, rc = xs
         g = jnp.moveaxis(dtc * a, 1, 2)  # (B, H, C) log-decays
         gcum = jnp.cumsum(g, axis=-1)
         seg = jnp.cumsum((rc > 0.5).astype(jnp.int32), axis=-1)[:, None]  # (B, 1, C)
@@ -260,17 +315,15 @@ def ssd_chunked(
         reach = jnp.exp(gcum) * (seg == 0)  # (B, H, C)
         diff = gcum[..., :, None] - gcum[..., None, :]
         decay = jnp.exp(jnp.where(lower & same, diff, -jnp.inf))  # (B, H, C, C)
-        cb = jnp.einsum("bin,bjn->bij", cc, bc, precision=_HI)  # (B, C, C)
+        cb = products(cc, bc)  # (B[, G], C, C)
         xdt = jnp.moveaxis(xc * dtc[..., None], 1, 2)  # (B, H, C, P)
-        y = jnp.matmul(decay * cb[:, None], xdt, precision=_HI) + reach[
+        y = jnp.matmul(weigh(decay, cb), xdt, precision=_HI) + reach[
             ..., None
-        ] * jnp.einsum("bhpn,bcn->bhcp", s, cc, precision=_HI)
+        ] * read(s, cc)
         # what is left at the chunk's end: the carried state if no
         # reset fell in the chunk, and the writes of the last segment
         tail = jnp.exp(gcum[..., -1:] - gcum) * (seg == seg[..., -1:])
-        s = s * reach[..., -1, None, None] + jnp.einsum(
-            "bhcp,bcn->bhpn", xdt * tail[..., None], bc, precision=_HI
-        )
+        s = s * reach[..., -1, None, None] + write(xdt * tail[..., None], bc)
         return s, jnp.moveaxis(y, 1, 2)  # (B, C, H, P)
 
     state, ys = jax.lax.scan(

@@ -7,6 +7,8 @@ program; these tests hold each schedule to its written-out host loop
 bit for bit, and the standalone rollout to two dispatches.
 """
 
+import gc
+
 import numpy as np
 import pytest
 
@@ -189,6 +191,9 @@ def test_standalone_rollout_is_two_dispatches():
     env, pol = _policy()
     eng = JaxRolloutEngine(pol, env, 8, 8, seed=5)
     eng.rollout()  # warm-up: both programs trace here
+    # earlier tests' dead programs leave the registry now, not between
+    # the two readings (their labels then read as negative growth)
+    gc.collect()
     calls, traces = _per_label("calls"), _per_label("traces")
     eng.rollout()
     assert _grown(calls, _per_label("calls")) == {

@@ -454,19 +454,30 @@ def test_v5e_delta_step_kernel_compiles_in_place(v5e_mesh):
     assert mem.temp_size_in_bytes < 16e6
 
 
-def test_v5e_ssd_step_kernel_compiles_in_place(v5e_mesh):
+# streams, the run's layers, and the shape of a stream's ``B`` / ``C``
+SSD_STEP_CELLS = [
+    pytest.param(16, 5, (128,), id="granite4h-rows-every-head-shares"),
+    pytest.param(32, 1, (8, 128), id="nemotron3nano-8-groups-a-run-of-one"),
+]
+
+
+@pytest.mark.parametrize("b,layers,rows_shape", SSD_STEP_CELLS)
+def test_v5e_ssd_step_kernel_compiles_in_place(v5e_mesh, b, layers, rows_shape):
     """The one-token state-space kernel (ops/ssd.py) at the granite
     cell's width, a run of 5 layers of 16 streams x 64 heads of 64 x 128,
-    as the lane runs it: the run's scan over layers chained in a scan
-    of 2 steps under ``shard_map``, the leaf donated. Mosaic takes it,
-    the 168 MB leaf is written where it lies (aliased through both
-    scans and the call: no second copy, no scratch), and the device op
-    carries the caller's scopes, under which the trace files its time."""
+    and at the Nemotron-H cell's, a run of ONE layer of 32 streams with
+    ``B`` and ``C`` in 8 groups (a group's 8 heads a grid step, the
+    group's row squeezed out of a ``(B, G, 1, N)`` block), as the lane
+    runs it: the run's scan over layers chained in a scan of 2 steps
+    under ``shard_map``, the leaf donated. Mosaic takes it, the leaf is
+    written where it lies (aliased through both scans and the call: no
+    second copy, no scratch), and the device op carries the caller's
+    scopes, under which the trace files its time."""
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.ops import ssd
 
-    b, layers, h, p, n = 16, 5, 64, 64, 128
+    h, p, n = 64, 64, 128
     axis = sharding_lib.data_axis(v5e_mesh)
     rows = sharding_lib.batch_sharded(v5e_mesh)
     on = lambda *shape: jax.ShapeDtypeStruct(shape, np.float32, sharding=rows)
@@ -491,7 +502,7 @@ def test_v5e_ssd_step_kernel_compiles_in_place(v5e_mesh):
     compiled = (
         jax.jit(sharded, donate_argnums=(0,))
         .lower(on(b, layers, h, p, n), on(b, h, p), on(b, h),
-               _on(v5e_mesh, (h,), np.float32), on(b, n), on(b, n))
+               _on(v5e_mesh, (h,), np.float32), on(b, *rows_shape), on(b, *rows_shape))
         .compile()
     )
     calls = [
