@@ -15,6 +15,14 @@ scope says what a fusion named by its output shape computes: PR 34 read
 ten largest operations of the whole program only. With ``scope`` the
 same table of another scope's operations (``learn/mla`` with ``steps``
 20: a latent layer's update of one group of streams, by operation).
+With ``scope`` ``all`` (and ``steps`` 1) every operation of the span,
+filed as the run's own reduction files it (``perf.program_trace.scope_of``: the innermost
+of the program's scopes on its path, so an ``add_any`` of the backward
+pass with no model scope lands under ``learn/loss_grad`` and a
+prefetch's ``async-done`` under none): the scopes' totals first, parent
+and change side by side say WHICH operations a scope's number is made
+of (PR 51: what left ``learn/loss_grad`` and what came into
+``learn/moe``).
 """
 
 from __future__ import annotations
@@ -37,14 +45,22 @@ def main(argv) -> int:
         path = tr.newest_xplane(path)
     bounds = tr.annotation_bounds(tr.load_xplane(path), tr.TRAIN_ANNOTATION)
     total, count = collections.Counter(), collections.Counter()
+    by_scope = collections.Counter()
     for op, duration_ns in program_trace._leaf_ops(
             program_trace.load_op_scopes(path), bounds):
         tf_op, _, _, name = op
-        if scope not in tf_op:
+        if scope == "all":
+            filed = program_trace.scope_of(tf_op) or "(unscoped)"
+            by_scope[filed] += duration_ns / 1e3
+            key = f"{filed}: {name.split(' ', 1)[-1]} | {tf_op[-60:]}"
+        elif scope in tf_op:
+            key = name.split(" ", 1)[-1] + " | " + tf_op.split(scope + "/", 1)[-1][-60:]
+        else:
             continue
-        key = name.split(" ", 1)[-1] + " | " + tf_op.split(scope + "/", 1)[-1][-60:]
         total[key] += duration_ns / 1e3
         count[key] += 1
+    for filed, us in by_scope.most_common():
+        print(f"{us / steps:9.2f} us  {filed}")
     print(f"{scope}: {sum(total.values()) / steps:.1f} us a step in "
           f"{sum(count.values()) / steps:.0f} operations")
     for key, us in total.most_common(top):

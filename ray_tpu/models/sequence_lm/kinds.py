@@ -785,7 +785,10 @@ class PlainResidual(Kind):
     """``"plain"``: ``x <- x + scale * F(rms(x))`` (``scale``: Granite's
     ``residual_multiplier``). A block's saved input is one hidden row a
     token and the batch's fit, so the learn form groups the streams
-    inside each block."""
+    inside each block, and there only for the MIXER'S half, whose
+    activations are what does not fit: the feed-forward's half is
+    token-wise and runs once over all the streams, for one more saved
+    row a token (``SequenceLM._stack``)."""
 
     scale: float = 1.0
 

@@ -11,7 +11,7 @@ import numpy as np
 
 from ray_tpu.models.sequence_lm.config import Segment, describe
 from ray_tpu.models.sequence_lm.kinds import (
-    HI, NORM_OF, dot, over_layers, over_streams, rms)
+    HI, NORM_OF, NoSublayer, dot, over_layers, over_streams, rms)
 
 # a stacked run's leaves that enter a bfloat16 product
 _RUN_PRODUCT_LEAVES = ("in_proj", "out_proj", "mlp_gate", "mlp_up", "mlp_down")
@@ -263,13 +263,24 @@ class SequenceLM:
             x = x * self.embed_scale
         x = residual.enter(x)
 
-        def block(x, p, layer_state, rows, mixer, ffn, flags):
+        def of_halves(stats, more):
+            """A block's statistics of its two halves': what both report
+            (the residual's own) as two layers'."""
+            both = over_layers(
+                {k: [jnp.stack([stats[k], more[k]])]
+                 for k in sorted(set(stats) & set(more))},
+                declared)
+            return {**stats, **more, **both}
+
+        def block(x, p, layer_state, rows, mixer, ffn, flags, before=None):
             ctx = dict(rows, scope=prefix, dtype=self.dtype, eps=self.eps,
                        chunk=self.chunk, **dict(flags))
             if ffn.route_on == "input":
                 # the router reads the layer's input, before the mixer
+                # (``before``: where the mixer's half ran apart, below)
+                source = x if before is None else before
                 with jax.named_scope(prefix + "moe/route"):
-                    ctx["route"] = ffn.route(p, x.reshape(-1, x.shape[-1]))
+                    ctx["route"] = ffn.route(p, source.reshape(-1, x.shape[-1]))
             # a block of one sublayer runs the half it has
             new, stats, more = (), {}, {}
             if not mixer.absent:
@@ -278,12 +289,7 @@ class SequenceLM:
             if not ffn.absent:
                 x, _, more = residual.around(
                     x, p, "ffn", lambda h: ffn.apply(p, h, (), ctx), ctx)
-            # what both sublayers report (the residual's own) as two layers'
-            both = over_layers(
-                {k: [jnp.stack([stats[k], more[k]])]
-                 for k in sorted(set(stats) & set(more))},
-                declared)
-            return x, new, {**stats, **more, **both}
+            return x, new, of_halves(stats, more)
 
         # the residual says whether the streams are grouped inside each
         # block, here, or around the whole loss (``loss_groups``)
@@ -292,22 +298,33 @@ class SequenceLM:
             and not step and b > self.learn_streams and b % self.learn_streams == 0
         ) else 1
         # the learn form keeps a block's input and recomputes the block
-        # in the backward pass, ``learn_streams`` streams at a time: one
-        # group's activations of one block are alive, not the batch's
-        # of the stack
+        # in the backward pass
         whole = block if step else jax.checkpoint(block, static_argnums=(4, 5, 6))
 
-        def run_block(x, p, layer_state, rows, *kinds):
-            if groups == 1:
-                return whole(x, p, layer_state, rows, *kinds)
+        def run_block(x, p, layer_state, rows, mixer, ffn, flags):
+            if groups == 1 or mixer.absent:
+                return whole(x, p, layer_state, rows, mixer, ffn, flags)
+            # more streams than the learn form runs at once: the MIXER'S
+            # half of the block ``learn_streams`` streams at a time (one
+            # group's activations of one mixer are alive, not the
+            # batch's), then the feed-forward's half, which is token-wise,
+            # ONCE over all of them under a checkpoint of its own: a held
+            # expert's weights are read and their gradient written once a
+            # layer, for one more saved hidden row a token
+            no_half = NoSublayer()
             split = lambda a: a.reshape((groups, b // groups) + a.shape[1:])
             merge = lambda a: a.reshape((b,) + a.shape[2:])
+            before = x
             x, new, stats = jax.lax.map(
-                lambda xs: whole(xs[0], p, xs[1], xs[2], *kinds),
+                lambda xs: whole(xs[0], p, xs[1], xs[2], mixer, no_half, flags),
                 jax.tree_util.tree_map(split, (x, layer_state, rows)),
             )
-            return (merge(x), jax.tree_util.tree_map(merge, new),
-                    over_streams(stats, declared))
+            x, new = merge(x), jax.tree_util.tree_map(merge, new)
+            stats = over_streams(stats, declared)
+            if ffn.absent:
+                return x, new, stats
+            x, _, more = whole(x, p, (), rows, no_half, ffn, flags, before)
+            return x, new, of_halves(stats, more)
 
         state_out, stats, kept = [], {}, []
         for i, (s, leaves) in enumerate(self._by_segment(state)):
