@@ -28,6 +28,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 
+from ray_tpu.sharding import async_pairs as async_pairs_lib
 from ray_tpu.telemetry import device as device_ledger
 from ray_tpu.telemetry import metrics as telemetry_metrics
 from ray_tpu.util import tracing
@@ -384,6 +385,23 @@ class ShardedFunction:
     def lower(self, *args, **kwargs):
         return self._jitted.lower(*args, **kwargs)
 
+    def compiled_text(self) -> Optional[str]:
+        """The program's scheduled, optimized HLO as the backend
+        compiled it, made on request: the signature the device ledger
+        analysed is lowered and compiled once more (through jax's
+        persistent cache where there is one: a retrieval, not a
+        compile) as the ledger's own analysis is, uncounted and with
+        its seconds under ``analysis_s``. ``None`` where the ledger
+        analysed no signature of this program."""
+        abstract = device_ledger.abstract_signature(self.label)
+        if abstract is None:
+            return None
+        args, kwargs, x64 = abstract
+        with self.uncounted_traces(analysis=True), jax.enable_x64(x64):
+            text = self._jitted.lower(*args, **kwargs).compile().as_text()
+        self.account.settle(self.label)
+        return text
+
 
 def sharded_jit(
     fn,
@@ -456,6 +474,25 @@ def compile_stats() -> Dict[str, Any]:
         # while the device ledger ran ({} with the ledger off)
         "recompile_causes": device_ledger.recompile_causes(),
     }
+
+
+def async_pairs(family: str) -> Dict[str, Any]:
+    """``{label: rows}`` for the live programs of ``family`` (a label
+    up to its first ``[``): the asynchronous pairs the compiler put
+    into each (``sharding/async_pairs.pairs``: a row a ``*-done`` with
+    the loop it sits in, what consumes it, what its start reads and
+    the room the scheduler gave it). Made when asked for, from
+    ``ShardedFunction.compiled_text``; a program the device ledger
+    analysed no signature of is left out. Nothing is kept: a second
+    request compiles (retrieves) again."""
+    with _LOCK:
+        fns = [f for f in _REGISTRY if f.account.family == family]
+    out: Dict[str, Any] = {}
+    for sf in fns:
+        text = sf.compiled_text()
+        if text is not None:
+            out[sf.label] = async_pairs_lib.pairs(text)
+    return out
 
 
 # -- the compile account -------------------------------------------------

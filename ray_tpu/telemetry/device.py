@@ -290,6 +290,7 @@ class _ProgramEntry:
         "n_devices",
         "tid",
         "source",
+        "abstract",
     )
 
     def __init__(self, label: str, donate_argnums=(), in_specs=None,
@@ -317,6 +318,11 @@ class _ProgramEntry:
         # and no trace/forensics ever fire, because no compile
         # happened in this process)
         self.source = "live"
+        # (abstract args, abstract kwargs, x64) of the analysed
+        # signature: shapes and shardings, no array. What a later
+        # request for the compiled text lowers again
+        # (sharding/compile.ShardedFunction.compiled_text)
+        self.abstract: Optional[Tuple[Any, Any, bool]] = None
         # stable synthetic chrome-trace lane for this program
         self.tid = _DEVICE_TID_BASE + (
             zlib.crc32(label.encode()) % 0x10000
@@ -453,6 +459,9 @@ def _analyze_program(entry: "_ProgramEntry", sf, args, kwargs) -> None:
             ).compile()
     except Exception:
         return
+    entry.abstract = (
+        sds_args, sds_kwargs, bool(jax.config.jax_enable_x64)
+    )
     n = None
     for leaf in jax.tree_util.tree_leaves(args):
         n = _sharding_devices(leaf)
@@ -659,6 +668,15 @@ def _flush_all_pending() -> None:
     for _tid, open_ in items:
         for e, t0, t1 in open_:
             _close(e, t0, t1)
+
+
+def abstract_signature(label: str) -> Optional[Tuple[Any, Any, bool]]:
+    """``(abstract args, abstract kwargs, x64)`` of the signature the
+    ledger analysed for the program ``label``; ``None`` where it
+    analysed none (the ledger off or light when the program traced)."""
+    with _LOCK:
+        entry = _entries.get(label)
+    return entry.abstract if entry is not None else None
 
 
 def recompile_causes() -> Dict[str, List[Dict[str, Any]]]:

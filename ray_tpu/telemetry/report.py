@@ -23,6 +23,15 @@ columns the trace alone doesn't carry. Sections:
 
 ``--json`` prints the same report as one JSON object (tests and
 dashboards); default is aligned text for humans.
+
+``--pairs FILE`` (repeatable, with or without a trace) prints what a
+compiled program waits for: its asynchronous ``*-start``/``*-done``
+pairs by the loop they sit in and the scope that consumes them, with
+their bytes and the room the scheduler gave them. ``FILE`` is a
+program's compiled text (``ShardedFunction.compiled_text()``, or an
+``--xla_dump_to`` ``*after_optimizations.txt``) or the JSON of
+``ray_tpu.sharding.compile.async_pairs(family)``; docs/observability.md,
+"What the compiled program waits for".
 """
 
 from __future__ import annotations
@@ -241,12 +250,44 @@ def render_text(report: Dict[str, Any]) -> str:
     return "\n".join(out)
 
 
+def render_pairs(path: str, top: int = 10) -> str:
+    """The pairs table of one ``--pairs`` file, a heading a program."""
+    from ray_tpu.sharding import async_pairs
+
+    with open(path) as f:
+        text = f.read()
+    if text.lstrip().startswith("{"):
+        programs = json.loads(text)
+    else:
+        first = text.split("\n", 1)[0].split()
+        name = first[1].rstrip(",") if len(first) > 1 else path
+        programs = {name: async_pairs.pairs(text)}
+    out: List[str] = []
+    for label, rows in programs.items():
+        out.append(
+            f"-- {label}: {len(rows)} asynchronous pairs, "
+            f"{_fmt_num(float(sum(r['bytes'] for r in rows)), 'B')} --"
+        )
+        out.append(async_pairs.format_table(rows, top=top))
+        out.append("")
+    return "\n".join(out)
+
+
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m ray_tpu.telemetry.report",
         description=__doc__.splitlines()[0],
     )
-    ap.add_argument("trace", help="chrome trace JSON (export_timeline)")
+    ap.add_argument(
+        "trace", nargs="?",
+        help="chrome trace JSON (export_timeline)",
+    )
+    ap.add_argument(
+        "--pairs", action="append", default=[], metavar="FILE",
+        help="a program's compiled HLO text, or the JSON of "
+        "sharding.compile.async_pairs(family): print its "
+        "asynchronous pairs by loop and consumer",
+    )
     ap.add_argument(
         "--ledger",
         help="device-ledger JSON (telemetry.device.dump)",
@@ -256,6 +297,12 @@ def main(argv: Optional[List[str]] = None) -> int:
         "--json", action="store_true", help="emit JSON, not text"
     )
     args = ap.parse_args(argv)
+    if args.trace is None and not args.pairs:
+        ap.error("a trace, or --pairs FILE")
+    for path in args.pairs:
+        print(render_pairs(path, top=args.top))
+    if args.trace is None:
+        return 0
     report = build_report(
         args.trace, ledger_path=args.ledger, top=args.top
     )
