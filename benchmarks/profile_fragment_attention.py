@@ -25,7 +25,15 @@ the caches donated so that the step's scatter is in place): the text
 (every slot under a mask) against ``ops/flash_attention.step_attention``,
 microseconds a step over 5 calls of 64 scanned steps, and the GB/s of
 each over the bytes it moves (the text all slots of both caches, the
-kernel the key blocks its streams hold).
+kernel the key blocks its streams hold). ``xing4_latent`` there is the
+latent layer's one-token form from the queries and the step's own row on
+(``ops/latent_attention.latent_attention``: the scatter, the absorbed
+query, scores and weighted sum, ``W_kvb``'s value half) at 32 streams 64
+positions apart over 2,048 slots of 576 lanes, five layers' caches in
+turn: XLA's text (every slot, twice) against ``step_attention`` over the
+one cache (the latent of the blocks held and every slot's 64 further
+lanes as a lane tile), the bytes those of rows as HBM holds them (576
+lanes padded to 640), microseconds a layer.
 """
 
 from __future__ import annotations
@@ -59,9 +67,14 @@ STEP_CASES = {
     "qwen3next": (64, 2, 8, 256, 2048),
     "granite4h": (16, 8, 4, 64, 2048),
 }
+# a cell's streams, heads, nope, rope, latent, value head, depth (= episode)
+LATENT_STEP_CASES = {
+    "xing4_latent": (32, 32, 128, 64, 512, 128, 2048),
+}
 CALLS = 10
 STEP_CALLS = 5
 STEPS = 64  # of one call
+LAYERS = 5  # latent layers stepped in turn
 
 
 def ms_per_call(fn, *args):
@@ -185,6 +198,33 @@ def run_latent(name, b, t, h, dn, rope, latent, dv, depth, blocks):
            ("o", "dq_nope", "dq_pe", "drows", "dkv_b"), pos0, depth, blocks)
 
 
+def us_a_step(applies, block_k, call, *state, layers=1):
+    """Microseconds a step (and layer) and the last call's output of
+    ``call(*state) -> (o, *state)``, ``STEPS`` steps a call under one
+    ``lax.scan`` with the caches in its carry, as the rollout runs them
+    (a call alone is the host's dispatch, 0.3 ms), the step kernel's
+    rule ``applies`` and its key block patched in while the call is
+    traced."""
+    step_attention = flash_attention.step_attention
+    rule = flash_attention.step_kernel_applies
+    flash_attention.step_kernel_applies = applies
+    flash_attention.step_attention = functools.partial(
+        step_attention, block_k=block_k)
+    try:
+        for _ in range(2):
+            o, *state = call(*state)
+        jax.block_until_ready(o)
+        start = time.perf_counter()
+        for _ in range(STEP_CALLS):
+            o, *state = call(*state)
+        jax.block_until_ready(o)
+        return (time.perf_counter() - start) * 1e6 / (
+            STEP_CALLS * STEPS * layers), o
+    finally:
+        flash_attention.step_attention = step_attention
+        flash_attention.step_kernel_applies = rule
+
+
 def run_step(name, b, kv, group, d, depth, blocks, pos0=None):
     bf = jnp.bfloat16
     h = kv * group
@@ -199,19 +239,8 @@ def run_step(name, b, kv, group, d, depth, blocks, pos0=None):
            "pos0": pos0}
 
     def measure(applies, block_k):
-        """Microseconds a step and the last step's output: ``STEPS``
-        steps a call under one ``lax.scan`` with the caches in its carry,
-        as the rollout runs them (a call alone is the host's dispatch,
-        0.3 ms), the rule and the kernel's key block patched in while
-        the call is traced."""
-        step_attention = flash_attention.step_attention
-        rule = flash_attention.step_kernel_applies
-        flash_attention.step_kernel_applies = applies
-        flash_attention.step_attention = functools.partial(
-            step_attention, block_k=block_k)
-
-        @functools.partial(jax.jit, donate_argnums=(3, 4))
-        def call(q, k, v, kc, vc):
+        @functools.partial(jax.jit, donate_argnums=(0, 1))
+        def call(kc, vc):
             def step(caches, i):
                 o, caches, _ = cached_attention.cached_attention(
                     q + i, k, v, caches, ctx, scale=d ** -0.5, window=None,
@@ -222,20 +251,10 @@ def run_step(name, b, kv, group, d, depth, blocks, pos0=None):
                 step, (kc, vc), jnp.arange(STEPS, dtype=jnp.float32) / STEPS)
             return o[0], kc, vc
 
-        kc = jax.random.normal(keys[3], (b, depth, kv * d), bf)
-        vc = jax.random.normal(keys[4], (b, depth, kv * d), bf)
-        try:
-            for _ in range(2):
-                o, kc, vc = call(q, k, v, kc, vc)
-            jax.block_until_ready(o)
-            start = time.perf_counter()
-            for _ in range(STEP_CALLS):
-                o, kc, vc = call(q, k, v, kc, vc)
-            jax.block_until_ready(o)
-            return (time.perf_counter() - start) * 1e6 / (STEP_CALLS * STEPS), o
-        finally:
-            flash_attention.step_attention = step_attention
-            flash_attention.step_kernel_applies = rule
+        return us_a_step(
+            applies, block_k, call,
+            jax.random.normal(keys[3], (b, depth, kv * d), bf),
+            jax.random.normal(keys[4], (b, depth, kv * d), bf))
 
     text_us, want = measure(lambda *a: False, None)
     row = 2 * 2 * kv * d  # bytes of a slot's key and value
@@ -254,13 +273,71 @@ def run_step(name, b, kv, group, d, depth, blocks, pos0=None):
         }), flush=True)
 
 
+def run_latent_step(name, b, h, dn, rope, latent, dv, depth, blocks):
+    bf = jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    q_nope = jax.random.normal(keys[0], (b, 1, h, dn), jnp.float32)
+    q_pe = jax.random.normal(keys[1], (b, 1, h, rope), jnp.float32)
+    row_new = jax.random.normal(keys[2], (b, 1, latent + rope), bf)
+    kv_b = jax.random.normal(keys[3], (latent, h * (dn + dv)), jnp.float32) * latent ** -0.5
+    pos0 = jnp.asarray(np.arange(b) * (depth // b), jnp.int32)  # 64 apart
+    ctx = {"seg": jnp.zeros((b, 1), jnp.int32), "positions": pos0[:, None],
+           "pos0": pos0}
+    scale = (dn + rope) ** -0.5
+
+    def measure(applies, block_k):
+        @functools.partial(jax.jit, donate_argnums=(0,))
+        def call(caches):
+            def step(caches, i):
+                outs = [latent_attention.latent_attention(
+                    q_nope + i, q_pe, row_new, cache, kv_b, ctx, scale=scale,
+                    dtype=bf)[:2] for cache in caches]
+                # every layer's output is used: none is dead code
+                return [cache for _, cache in outs], sum(o for o, _ in outs)
+
+            caches, o = jax.lax.scan(
+                step, caches, jnp.arange(STEPS, dtype=jnp.float32) / STEPS)
+            return o[0], caches
+
+        # the cell's five layers, each with a cache of its own: one alone
+        # stays in fast memory from step to step (the text then reads it
+        # at 2.4 TB/s, which no layer of the cell does)
+        caches = [jax.random.normal(key, (b, depth, latent + rope), bf)
+                  for key in jax.random.split(keys[4], LAYERS)]
+        return us_a_step(applies, block_k, call, caches, layers=LAYERS)
+
+    text_us, want = measure(lambda *a, **value: False, None)
+    lanes = flash_attention._LANES
+    row = 2 * flash_attention._ceil_to(latent + rope, lanes)  # a slot as HBM holds it
+    for block_k in blocks:
+        us, got = measure(lambda *a, **value: True, block_k)
+        bk = flash_attention.fragment_block_k(depth, block_k)
+        skipped, held = flash_attention.step_key_blocks(pos0 + 1, depth, block_k)
+        # the latent of the blocks held, and every slot's lanes after it
+        moved = (held - int(skipped)) * bk * 2 * latent + b * depth * 2 * (
+            flash_attention._ceil_to(rope, lanes))
+        print(json.dumps({
+            "case": name, "form": "latent_step", "block_k": bk,
+            "text_us": round(text_us, 1), "kernel_us": round(us, 1),
+            "text_gb_per_s": round(2 * b * depth * row / text_us / 1e3, 1),
+            "kernel_gb_per_s": round(moved / us / 1e3, 1),
+            "kernel_mb_fetched": round(moved / 1e6, 2),
+            "rel_o": round(rel(got, want), 5),
+            "key_blocks_skipped_share": round(float(skipped) / held, 4),
+        }), flush=True)
+
+
 def main(argv):
     if jax.default_backend() != "tpu":
         raise SystemExit("a TPU is needed: a time from another backend is no device time")
     if argv[:1] == ["step"]:
         blocks = [int(a) for a in argv if a.isdigit()] or [None]
-        for name in [a for a in argv[1:] if not a.isdigit()] or STEP_CASES:
-            run_step(name, *STEP_CASES[name], blocks)
+        for name in [a for a in argv[1:] if not a.isdigit()] or [
+                *STEP_CASES, *LATENT_STEP_CASES]:
+            if name in STEP_CASES:
+                run_step(name, *STEP_CASES[name], blocks)
+            else:
+                run_latent_step(name, *LATENT_STEP_CASES[name], blocks)
         return
     # the text is the rule's other branch
     flash_attention.fragment_kernel_applies = lambda *a: False

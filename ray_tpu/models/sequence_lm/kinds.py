@@ -323,7 +323,7 @@ class LatentLayer(Kind):
     k_nope + rope(q_pe) . k_pe) * s``, ``s = (nope + rope)^-1/2 * m^2``,
     causal softmax, ``o = P v``, output projection. The query/key product
     is ``nope + rope`` wide, the value product ``v_head``
-    (``ops/latent_attention.py`` holds its three forms and picks).
+    (``ops/latent_attention.py`` holds its forms and picks).
 
     State: ONE leaf of latent rows, ``(positions, kv_latent + rope_dim)``
     in the operands' type: the normed latent and the roped key part,
@@ -339,7 +339,11 @@ class LatentLayer(Kind):
     inv_freq: Tuple[float, ...]  # ``yarn_inv_freq`` of the rope part
     softmax_scale: float
 
-    stats = {"attn_key_blocks_skipped": "sum", "attn_key_blocks_walked": "sum"}
+    stats = {
+        "attn_key_blocks_skipped": "sum", "attn_key_blocks_walked": "sum",
+        "attn_decode_key_blocks_skipped": "sum",
+        "attn_decode_key_blocks_walked": "sum",
+    }
 
     def param_shapes(self, d: int):
         h, row = self.heads, self.kv_latent + self.rope_dim

@@ -829,26 +829,58 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
 # its 1 MB, 502 us for the 401 of this form at 32 streams of 8,192 rows;
 # one key head a grid step, 128 KB, was slower than the XLA text), and
 # the mask inside the last held block comes from the slot numbers.
+# ``ops/latent_attention``'s one-token form is the same walk over ONE
+# cache: one key head that is the latent row as it lies, its value the
+# row's leading lanes, read out of the key block's own buffer
+# (:func:`_step_one_cache_kernel`, which says why it is a body of its own).
 
 # the key and value blocks in flight and in use: two slots of each
 _STEP_VMEM_BYTES = 16 * 2 ** 20
 _SUBLANES = 8
 
 
-def step_kernel_applies(heads, kv_heads, head_dim, depth, dtype) -> bool:
+# key blocks the one-cache kernel keeps in flight beside the one in use
+# (on the chip, a latent layer of 32 streams 64 positions apart: 103 us
+# with one, 90 with two, 89 with three)
+_STEP_AHEAD = 2
+
+
+def _tail_lanes(head_dim: int, value_dim: int) -> int:
+    """Lanes of the block that holds a row's lanes past its value, whole
+    lane tiles: 128 for the latent row's 64 roped numbers."""
+    return _ceil_to(head_dim - value_dim, _LANES)
+
+
+def step_kernel_applies(heads, kv_heads, head_dim, depth, dtype,
+                        value_dim=None) -> bool:
     """The step kernel's lowering exists on a TPU
     (``ops/backend.is_tpu``) for bfloat16 operands, a cache of whole
     key blocks, a key
     that is whole lane tiles or packs into one (64: two key heads a
-    block), and a block of all key heads that fits VMEM four times."""
-    pack = _heads_packed(head_dim, kv_heads)
+    block), and a block of all key heads that fits VMEM four times.
+    With ``value_dim`` the rule is the ONE-CACHE form's (one key head
+    whose value is its row's leading ``value_dim`` lanes: the latent
+    row of 576 with its 512): a row of whole half lane tiles, a value of
+    whole lane tiles that the row's further lanes follow as one block of
+    whole tiles, and the blocks in flight with two streams' further
+    lanes in VMEM."""
     block_k = fragment_block_k(depth)
+    if not (backend.is_tpu() and dtype == jnp.bfloat16 and block_k > 0
+            and heads % kv_heads == 0):
+        return False
+    if value_dim is not None:
+        tail = _tail_lanes(head_dim, value_dim)
+        return (
+            kv_heads == 1
+            and head_dim % (_LANES // 2) == 0
+            and 0 < value_dim < head_dim
+            and value_dim % _LANES == 0 and value_dim % tail == 0
+            and 2 * ((_STEP_AHEAD + 1) * block_k * value_dim + 2 * depth * tail)
+            <= _STEP_VMEM_BYTES
+        )
+    pack = _heads_packed(head_dim, kv_heads)
     return (
-        backend.is_tpu()
-        and dtype == jnp.bfloat16
-        and block_k > 0
-        and heads % kv_heads == 0
-        and head_dim * pack % _LANES == 0
+        head_dim * pack % _LANES == 0
         and 4 * block_k * kv_heads * head_dim * 2 <= _STEP_VMEM_BYTES
     )
 
@@ -861,6 +893,25 @@ def step_key_blocks(rows_held, depth: int, block_k: int | None = None):
     stored = depth // bk
     return (jnp.sum(stored - _blocks_held(rows_held, bk, stored)),
             rows_held.size * stored)
+
+
+def _step_fold(s, mask, state, values_ref, values_at):
+    """A block's scores ``s`` into a query tile's running ``(max, sum,
+    accumulator)``: the online softmax's update, the weights into the
+    value product in the values' type. The values are read where the
+    product takes them: ``values_ref[values_at]``."""
+    m_prev, l_prev, acc = state
+    s = jnp.where(mask, s, _MASKED)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    return (
+        m_new,
+        alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True),
+        alpha * acc + jnp.dot(
+            p.astype(values_ref.dtype), values_ref[values_at],
+            preferred_element_type=jnp.float32),
+    )
 
 
 def _step_kernel(held_ref, q_ref, kc_ref, vc_ref, o_ref, k_buf, v_buf, sem,
@@ -913,22 +964,12 @@ def _step_kernel(held_ref, q_ref, kc_ref, vc_ref, o_ref, k_buf, v_buf, sem,
         mask = kb * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1) < held
         out = []
-        for n, (m_prev, l_prev, acc) in enumerate(carry):
+        for n, state in enumerate(carry):
             at = pl.ds(n * lanes, lanes)
             s = jax.lax.dot_general(
                 q_ref[0, n], k_buf[slot, :, at], _NT,
                 preferred_element_type=jnp.float32)
-            s = jnp.where(mask, s, _MASKED)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            out.append((
-                m_new,
-                alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True),
-                alpha * acc + jnp.dot(
-                    p.astype(v_buf.dtype), v_buf[slot, :, at],
-                    preferred_element_type=jnp.float32),
-            ))
+            out.append(_step_fold(s, mask, state, v_buf, (slot, slice(None), at)))
         return tuple(out)
 
     init = (jnp.full((rows, 1), _MASKED, jnp.float32),
@@ -977,19 +1018,147 @@ def _step_fwd(q, k_cache, v_cache, rows_held, *, block_k, interpret):
     )(rows_held.astype(jnp.int32), q, k_cache, v_cache)
 
 
-def step_attention_text(q, k_cache, v_cache, see):
+def _step_one_cache_kernel(first_ref, blocks_ref, stream_ref, block_ref,
+                           held_ref, q_ref, tail_ref, kc_ref, o_ref, buf, sem,
+                           *, block_k, tail):
+    """One stream a grid step, for ONE key head whose value is its
+    row's leading lanes (as many as ``o_ref``'s): :func:`_step_kernel`'s
+    walk and arithmetic with another fetch. Mosaic refuses that kernel's
+    copy of a block of a 576-lane cache (a slice of an HBM reference has
+    to be whole lane tiles wide, even one of the whole minor dimension),
+    so the value's lanes of a held block, whole tiles, come by the
+    kernel's own copies and are the operand of both products, and the
+    ``tail`` lanes after them come through the grid's pipeline, a whole
+    stream's at a time, as ONE block of whole lane tiles that reaches
+    past the row's end (``tail_ref`` ``(1, depth, tiles)``: lanes past
+    ``tail`` hold nothing and are zeroed; all slots, 11% of a full
+    cache's bytes). The copies run over the FLAT list of the (stream,
+    block) pairs held (``stream_ref``, ``block_ref``; a stream's first
+    entry ``first_ref[b]``, its ``blocks_ref[b]`` blocks), as many ahead
+    as ``buf`` has slots but one, whatever stream the next ones are."""
+    b, streams = pl.program_id(0), pl.num_programs(0)
+    slots = buf.shape[0]
+    width = o_ref.shape[-1]
+    total = first_ref[streams - 1] + blocks_ref[streams - 1]
+    held, first = held_ref[b], first_ref[b]
+
+    def copy(i):
+        at = pl.ds(pl.multiple_of(block_ref[i] * block_k, block_k), block_k)
+        slot = i % slots
+        return pltpu.make_async_copy(
+            kc_ref.at[stream_ref[i], at, pl.ds(0, width)], buf.at[slot],
+            sem.at[slot])
+
+    @pl.when(b == 0)
+    def _():
+        for i in range(slots - 1):
+            @pl.when(i < total)
+            def _():
+                copy(i).start()
+
+    q = q_ref[0, 0, :, pl.ds(0, width)]
+    q_tail = q_ref[0, 0, :, pl.ds(width, tail_ref.shape[-1])]
+    in_row = jax.lax.broadcasted_iota(
+        jnp.int32, (1, tail_ref.shape[-1]), 1) < tail
+
+    def block(kb, state):
+        i = first + kb
+
+        @pl.when(i + slots - 1 < total)
+        def _():
+            copy(i + slots - 1).start()
+
+        k_tail = tail_ref[0, pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)]
+        s = jax.lax.dot_general(
+            q_tail, jnp.where(in_row, k_tail, jnp.zeros_like(k_tail)), _NT,
+            preferred_element_type=jnp.float32)
+        copy(i).wait()
+        s = s + jax.lax.dot_general(
+            q, buf[i % slots], _NT, preferred_element_type=jnp.float32)
+        mask = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1) < held
+        return _step_fold(s, mask, state, buf, (i % slots,))
+
+    rows = q_ref.shape[2]
+    init = (jnp.full((rows, 1), _MASKED, jnp.float32),
+            jnp.zeros((rows, 1), jnp.float32),
+            jnp.zeros((rows, width), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, blocks_ref[b], block, init)
+    o_ref[0, 0] = acc / l
+
+
+@functools.partial(
+    jax.jit, static_argnames=("value_dim", "block_k", "interpret"))
+def _step_one_cache_fwd(q, cache, rows_held, *, value_dim, block_k, interpret):
+    """``q`` ``(B, 1, rows, D)`` over ``cache`` ``(B, depth, D)``, which
+    the kernel gets twice: in HBM for its own copies of the value's
+    lanes, and blocked for the lanes after them. The flat list of the
+    key blocks held is made here."""
+    from ray_tpu import sharding as sharding_lib
+
+    bsz, _, rows, d = q.shape
+    depth = cache.shape[1]
+    stored = depth // block_k
+    tail = _tail_lanes(d, value_dim)
+    # at least a stream's first block: its own row is in it
+    blocks = jnp.maximum(_blocks_held(rows_held, block_k, stored), 1).astype(jnp.int32)
+    first = jnp.cumsum(blocks) - blocks
+    # past the list's end the entries repeat its last, and are not fetched
+    stream = jnp.repeat(
+        jnp.arange(bsz, dtype=jnp.int32), blocks,
+        total_repeat_length=bsz * stored + _STEP_AHEAD)
+    block = jnp.minimum(
+        jnp.arange(stream.shape[0], dtype=jnp.int32) - first[stream],
+        blocks[stream] - 1)
+    of_stream = lambda *shape: pl.BlockSpec(
+        (1,) + shape, lambda b, *_: (b,) + (0,) * len(shape))
+    return pl.pallas_call(
+        functools.partial(
+            _step_one_cache_kernel, block_k=block_k, tail=d - value_dim),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(bsz,),
+            in_specs=[
+                of_stream(1, rows, value_dim + tail),
+                pl.BlockSpec((1, depth, tail),
+                             lambda b, *_: (b, 0, value_dim // tail)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=of_stream(1, rows, value_dim),
+            scratch_shapes=[
+                pltpu.VMEM((_STEP_AHEAD + 1, block_k, value_dim), cache.dtype),
+                pltpu.SemaphoreType.DMA((_STEP_AHEAD + 1,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (bsz, 1, rows, value_dim), jnp.float32,
+            vma=sharding_lib.vma_of((q, cache, rows_held))),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_FRAGMENT_VMEM_BYTES,
+        ),
+        name="step_attention_one_cache",
+    )(first, blocks, stream, block, rows_held.astype(jnp.int32),
+      _pad_to(q, 3, value_dim + tail), cache, cache)
+
+
+def step_attention_text(q, k_cache, v_cache, see, value_dim=None):
     """One token's attention as XLA writes it, every slot under a mask:
     THE one-token text, which ``ops/cached_attention`` runs where no
     kernel's lowering exists (and over a ring, always) and which is the
     step kernel's backward pass and oracle. ``q`` ``(B, 1, kv, group,
     D)`` scaled, in the products' type; the caches ``(B, depth, kv *
-    D)`` after the step's scatter; ``see`` ``(B, depth)`` the slots each
-    stream's query sees (at full depth those below its rows held; in a
-    ring by the positions they hold). Returns ``(B, 1, kv, group, D)``
-    float32, its parts under the scopes ``scores`` and ``out``."""
+    D)`` after the step's scatter (no ``v_cache``: the ONE key head's
+    first ``value_dim`` lanes are its value); ``see`` ``(B, depth)`` the
+    slots each stream's query sees (at full depth those below its rows
+    held; in a ring by the positions they hold). Returns ``(B, 1, kv,
+    group, D)`` float32, its parts under the scopes ``scores`` and
+    ``out``."""
     kv, d = q.shape[2], q.shape[-1]
     kc = k_cache.reshape(k_cache.shape[:2] + (kv, d))
-    vc = v_cache.reshape(v_cache.shape[:2] + (kv, d))
+    vc = kc[..., :value_dim] if v_cache is None else v_cache.reshape(
+        v_cache.shape[:2] + (kv, d))
     with jax.named_scope("scores"):
         s = jnp.einsum("btngd,bsnd->bngts", q, kc, preferred_element_type=jnp.float32)
         w = jax.nn.softmax(
@@ -999,11 +1168,16 @@ def step_attention_text(q, k_cache, v_cache, see):
                           preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _step_attention(q, k_cache, v_cache, rows_held, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _step_attention(q, k_cache, v_cache, rows_held, value_dim, block_k, interpret):
     bsz, _, kv, group, d = q.shape
     pack = _heads_packed(d, kv)
     rows = _ceil_to(pack * group, _SUBLANES)
+    if v_cache is None:  # one key head: its query heads the tile's rows
+        o = _step_one_cache_fwd(
+            _pad_to(q[:, 0], 2, rows), k_cache, rows_held,
+            value_dim=value_dim, block_k=block_k, interpret=interpret)
+        return o[:, :, :group][:, None]
     # a head narrower than the lanes: ``pack`` key heads a block, each
     # of their query heads zero outside its own head's lanes, so that
     # the products over the whole block are the head's own
@@ -1020,23 +1194,24 @@ def _step_attention(q, k_cache, v_cache, rows_held, block_k, interpret):
     return o.reshape(bsz, 1, kv, group, d)
 
 
-def _step_fwd_rule(q, k_cache, v_cache, rows_held, block_k, interpret):
-    return (_step_attention(q, k_cache, v_cache, rows_held, block_k, interpret),
+def _step_fwd_rule(q, k_cache, v_cache, rows_held, *static):
+    return (_step_attention(q, k_cache, v_cache, rows_held, *static),
             (q, k_cache, v_cache, rows_held))
 
 
-def _step_bwd_rule(block_k, interpret, residuals, do):
+def _step_bwd_rule(value_dim, block_k, interpret, residuals, do):
     *operands, rows_held = residuals
     see = jnp.arange(operands[1].shape[1])[None] < rows_held[:, None]
-    _, vjp = jax.vjp(lambda *a: step_attention_text(*a, see), *operands)
+    _, vjp = jax.vjp(
+        lambda *a: step_attention_text(*a, see, value_dim), *operands)
     return vjp(do) + (None,)
 
 
 _step_attention.defvjp(_step_fwd_rule, _step_bwd_rule)
 
 
-def step_attention(q, k_cache, v_cache, rows_held, *, block_k=None,
-                   interpret=False):
+def step_attention(q, k_cache, v_cache, rows_held, *, value_dim=None,
+                   block_k=None, interpret=False):
     """One token's attention over its stream's stored keys and values
     as one tiled kernel, forward only: of a full-depth cache only the key
     blocks with a slot below the stream's depth cross HBM, a key block
@@ -1050,6 +1225,14 @@ def step_attention(q, k_cache, v_cache, rows_held, *, block_k=None,
     and the accumulator are float32; the weights enter the value product
     in the cache's type and are normalised after it.
 
+    Where ONE key head's value is its key's leading ``value_dim`` lanes,
+    as in a latent row (``ops/latent_attention.absorbed_step``: 32 query
+    heads over rows of 576 lanes, the value their first 512), there is
+    no ``v_cache`` (``None``): a held key block crosses HBM once and is
+    the operand of both products, no second fetch and no sliced copy;
+    ``o`` is then ``value_dim`` wide. The same walk and arithmetic in
+    another kernel body (:func:`_step_one_cache_kernel`).
+
     Differentiable in ``q`` and the caches: the backward pass is
     :func:`step_attention_text`'s (rollout takes no gradient).
     ``block_k`` and ``interpret`` are the tests' spellings."""
@@ -1057,4 +1240,10 @@ def step_attention(q, k_cache, v_cache, rows_held, *, block_k=None,
     if not block_k:
         raise ValueError(
             f"a cache of {k_cache.shape[1]} rows is not whole key blocks")
-    return _step_attention(q, k_cache, v_cache, rows_held, block_k, interpret)
+    if (v_cache is None) != (value_dim is not None) or (
+            v_cache is None and q.shape[2] != 1):
+        raise ValueError(
+            "a key's leading lanes are its value for ONE key head, with no "
+            f"value cache and their number as value_dim ({value_dim})")
+    return _step_attention(
+        q, k_cache, v_cache, rows_held, value_dim, block_k, interpret)

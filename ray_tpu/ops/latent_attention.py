@@ -1,5 +1,6 @@
 """Multi-head latent attention (DeepSeek-V2/V3, arXiv:2405.04434 and
-2412.19437) over a cache of latent rows, in its three forms.
+2412.19437) over a cache of latent rows: a one-token form on two
+lowerings and two fragment forms.
 
 A position's cache row is ``[c_kv | k_pe]``: the RMS-normed key/value
 latent (``kv_lora_rank`` numbers) and the roped key part that all heads
@@ -12,7 +13,17 @@ key part and a ``v_head_dim`` value: the query/key product is
   is absorbed into the query (``q~_h = q_nope_h W_UK_h^T``), the scores
   and the weighted sum run against the latent rows as the cache holds
   them, and the value half is applied after (``o_h = (P_h C) W_UV_h``):
-  no key or value of an earlier position is ever rebuilt.
+  no key or value of an earlier position is ever rebuilt. Where the
+  step kernel's lowering exists
+  (``ops/flash_attention.step_kernel_applies``: bfloat16 on a TPU, a
+  cache of whole key blocks, a latent of whole lane tiles) the scores,
+  the softmax and the weighted sum are
+  ``ops/flash_attention.step_attention`` over ONE 576-wide key head
+  whose value is the row's leading ``kv_lora_rank`` lanes: the latent
+  of a stream's key blocks below its position crosses HBM once (and
+  every slot's roped lanes, a ninth as much); everywhere else (the CPU,
+  float32) they are XLA's text over every slot under a mask, which is
+  also the kernel's oracle and its backward pass.
 - :func:`absorbed_fragment` is the fragment form where the tiled
   fragment kernel's lowering exists
   (``ops/flash_attention.fragment_kernel_applies``: bfloat16 on a TPU):
@@ -31,9 +42,10 @@ key part and a ``v_head_dim`` value: the query/key product is
   the fragment.
 
 :func:`latent_attention` is the layer's one entry: it writes the
-fragment's rows into the cache and picks among the three from what the
+fragment's rows into the cache and picks among them from what the
 call sees. ``ray_tpu_mla_decode_lowerings_total{form}`` counts which a
-traced layer took (``absorbed`` | ``absorbed_fragment`` | ``expanded``).
+traced layer took (``absorbed`` | ``absorbed_kernel`` |
+``absorbed_fragment`` | ``expanded``).
 All take
 ``dtype`` operands (the cache's) and accumulate in float32; masks and
 softmax are float32. :func:`yarn_inv_freq` and
@@ -111,20 +123,28 @@ def latent_attention(q_nope, q_pe, rows_new, cache, kv_b, rows, *, scale, dtype)
     dv))``; ``rows`` the fragment's ``seg``, ``positions`` ``(B, T)``
     and ``pos0`` ``(B,)``. Returns ``(o (B, T, H, dv) float32, the cache
     after the fragment, stats)``: one token the absorbed product over
-    what the cache then holds, its own row included; a fragment the same
-    product on the tiled kernel where ``fragment_kernel_applies`` says so
-    (one key head, the latent rows as they lie), else the expanded text.
-    ``stats``: a fragment's key blocks skipped and walked, as
+    what the cache then holds, its own row included, on the step kernel
+    where ``step_kernel_applies`` says so (one key head, the latent rows
+    as they lie, a stream's held key blocks only), else as XLA's text
+    over every slot; a fragment the same product on the tiled kernel
+    where ``fragment_kernel_applies`` says so, else the expanded text.
+    ``stats``: a fragment's key blocks skipped and walked, and those
+    the one-token kernel would at each of the fragment's positions, as
     ``ops/cached_attention`` counts them."""
     seg, positions, pos0 = rows["seg"], rows["positions"], rows["pos0"]
     t, heads = q_nope.shape[1:3]
-    new_cache = cached_attention.scatter_rows(cache, rows_new, rows)
-    if t == 1:
-        metrics.inc_mla_decode_lowering("absorbed")
-        o = absorbed_step(
-            q_nope[:, 0], q_pe[:, 0], new_cache, kv_b, pos0, scale, dtype)[:, None]
-        return o, new_cache, {}
     depth = cache.shape[1]
+    new_cache = cached_attention.scatter_rows(cache, rows_new, rows)
+    step_kernel = flash_attention.step_kernel_applies(
+        heads, 1, cache.shape[2], depth, dtype, value_dim=kv_b.shape[0])
+    if t == 1:
+        metrics.inc_mla_decode_lowering(
+            "absorbed_kernel" if step_kernel else "absorbed")
+        metrics.inc_attention_step_lowering("kernel" if step_kernel else "xla")
+        o = absorbed_step(
+            q_nope[:, 0], q_pe[:, 0], new_cache, kv_b, pos0, scale, dtype,
+            kernel=step_kernel)[:, None]
+        return o, new_cache, {}
     if flash_attention.fragment_kernel_applies(
             t, heads, 1, cache.shape[2], depth, dtype):
         metrics.inc_mla_decode_lowering("absorbed_fragment")
@@ -139,8 +159,13 @@ def latent_attention(q_nope, q_pe, rows_new, cache, kv_b, rows, *, scale, dtype)
         o = expanded_fragment(
             q_nope, q_pe, rows_new, cache, kv_b, seg, pos0, scale, dtype,
             block=_ENV_BLOCK)
-    return o, new_cache, {"attn_key_blocks_skipped": skipped,
-                          "attn_key_blocks_walked": jnp.int32(walked)}
+    decode = flash_attention.step_key_blocks(
+        positions + 1, depth) if step_kernel else (jnp.int32(0), 0)
+    return o, new_cache, {
+        "attn_key_blocks_skipped": skipped,
+        "attn_key_blocks_walked": jnp.int32(walked),
+        "attn_decode_key_blocks_skipped": decode[0],
+        "attn_decode_key_blocks_walked": jnp.int32(decode[1])}
 
 
 def _kv_b_by_head(kv_b, heads: int, dtype):
@@ -148,13 +173,18 @@ def _kv_b_by_head(kv_b, heads: int, dtype):
     return kv_b.astype(dtype).reshape(kv_b.shape[0], heads, -1)
 
 
-def absorbed_step(q_nope, q_pe, cache, kv_b, pos0, scale: float, dtype):
+def absorbed_step(q_nope, q_pe, cache, kv_b, pos0, scale: float, dtype,
+                  kernel: bool = False, **spellings):
     """One token a stream against the latent rows. ``q_nope`` ``(B, H,
     dn)`` and ``q_pe`` ``(B, H, R)`` float32 (roped); ``cache`` ``(B,
     S, C + R)`` with the step's own row already at slot ``pos0``;
     ``kv_b`` ``(C, H * (dn + dv))``. Returns ``(B, H, dv)`` float32.
     Its three parts open the scopes ``absorb``, ``scores`` and ``out``
-    under the caller's."""
+    under the caller's. ``kernel``: scores, softmax and weighted sum on
+    ``flash_attention.step_attention`` (under ``scores``), the ``H``
+    absorbed queries the rows of one tile over one key head, the value
+    the key block's leading ``C`` lanes; ``spellings``: the tests' of
+    that function (``block_k``, ``interpret``)."""
     heads, dn = q_nope.shape[1], q_nope.shape[2]
     latent = kv_b.shape[0]
     w = _kv_b_by_head(kv_b, heads, dtype)
@@ -164,19 +194,26 @@ def absorbed_step(q_nope, q_pe, cache, kv_b, pos0, scale: float, dtype):
             preferred_element_type=jnp.float32,
         )
         q_row = (jnp.concatenate([q_lat, q_pe], axis=-1) * scale).astype(dtype)
-    with jax.named_scope("scores"):
-        scores = jnp.einsum(
-            "bhr,bsr->bhs", q_row, cache, preferred_element_type=jnp.float32
-        )
-        seen = jnp.arange(cache.shape[1])[None, None] <= pos0[:, None, None]
-        weights = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    if kernel:
+        with jax.named_scope("scores"):
+            mixed = flash_attention.step_attention(
+                q_row[:, None, None], cache, None, pos0 + 1, value_dim=latent,
+                **spellings)[:, 0, 0]
+    else:
+        with jax.named_scope("scores"):
+            scores = jnp.einsum(
+                "bhr,bsr->bhs", q_row, cache, preferred_element_type=jnp.float32
+            )
+            seen = jnp.arange(cache.shape[1])[None, None] <= pos0[:, None, None]
+            weights = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+        with jax.named_scope("out"):
+            # over the whole row: the 64 roped numbers cost an eighth more
+            # products and spare a sliced copy of the cache
+            mixed = jnp.einsum(
+                "bhs,bsr->bhr", weights.astype(dtype), cache,
+                preferred_element_type=jnp.float32,
+            )[..., :latent]
     with jax.named_scope("out"):
-        # over the whole row: the 64 roped numbers cost an eighth more
-        # products and spare a sliced copy of the cache
-        mixed = jnp.einsum(
-            "bhs,bsr->bhr", weights.astype(dtype), cache,
-            preferred_element_type=jnp.float32,
-        )[..., :latent]
         return jnp.einsum(
             "bhc,chv->bhv", mixed.astype(dtype), w[..., dn:],
             preferred_element_type=jnp.float32,

@@ -123,8 +123,11 @@ DELTANET_STEP_LOWERINGS_TOTAL = "ray_tpu_deltanet_step_lowerings_total"
 MOE_PRODUCT_LOWERINGS_TOTAL = "ray_tpu_moe_product_lowerings_total"
 # which form each traced latent-attention layer took
 # (ops/latent_attention.latent_attention): form = absorbed
-# (one token against the latent rows: the rollout's step) |
-# absorbed_fragment (a fragment against the latent rows on the tiled
+# (one token against the latent rows, every slot under a mask in XLA's
+# text: the rollout's step off a TPU or in float32) | absorbed_kernel
+# (the same product on ops/flash_attention.step_attention, a stream's
+# held key blocks only: the rollout's step where that kernel's rule
+# admits it, bfloat16 on a TPU) | absorbed_fragment (a fragment against the latent rows on the tiled
 # fragment kernel: the learn form where ops/flash_attention's rule
 # admits it, bfloat16 on a TPU) | expanded (a fragment, keys and values
 # rebuilt through W_kvb: the learn form everywhere else).
@@ -158,7 +161,8 @@ WINDOW_CACHE_LOWERINGS_TOTAL = "ray_tpu_window_cache_lowerings_total"
 ATTENTION_FRAGMENT_LOWERINGS_TOTAL = (
     "ray_tpu_attention_fragment_lowerings_total")
 # which lowering each traced softmax-attention layer's ONE-TOKEN form
-# took (ops/cached_attention.cached_attention): path = kernel
+# took (ops/cached_attention.cached_attention, and the latent layer's
+# in ops/latent_attention): path = kernel
 # (ops/flash_attention.step_attention: a full-depth cache on a TPU
 # backend, bfloat16, whole key blocks; a stream's key blocks past its
 # depth are not fetched) | xla (every slot under a mask: every ring, and
@@ -698,7 +702,7 @@ def moe_product_lowerings() -> Dict[str, float]:
 
 def inc_mla_decode_lowering(form: str) -> None:
     """One traced latent-attention layer took ``form`` (``absorbed`` |
-    ``absorbed_fragment`` | ``expanded``)."""
+    ``absorbed_kernel`` | ``absorbed_fragment`` | ``expanded``)."""
     counter(
         MLA_DECODE_LOWERINGS_TOTAL,
         "latent-attention layers traced, by the form they took",

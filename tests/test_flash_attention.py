@@ -517,27 +517,135 @@ def test_step_kernel(name, monkeypatch):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)
 
 
-@pytest.mark.parametrize("heads,kv,head,depth", [
-    (28, 4, 128, 8192),  # SmallThinker's full layer
-    (48, 8, 128, 4096),  # Laguna's full layers
-    (16, 2, 256, 2048),  # Qwen3-Next's gated layer
-    (32, 8, 64, 2048),   # Granite's: two key heads a block
+def _latent_step(b=5, heads=4, dn=16, rope=16, latent=128, dv=24, depth=64,
+                 pos0=(0, 15, 16, 17, 63), dtype=jnp.bfloat16, seed=0):
+    """One token of ``b`` streams at ``pos0`` as the latent layer hands
+    it to ``absorbed_step``: ``(q_nope, q_pe, cache, kv_b)`` (a row of
+    ``latent + rope`` lanes: one key head, no whole lane tiles, the
+    value its leading ``latent``; the step's own row already in the
+    cache) and a cotangent."""
+    rng = np.random.default_rng(seed)
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    operands = (
+        normal(b, heads, dn), normal(b, heads, rope),
+        normal(b, depth, latent + rope).astype(dtype),
+        normal(latent, heads * (dn + dv)) * latent ** -0.5)
+    return operands, jnp.asarray(pos0, jnp.int32), normal(b, heads, dv)
+
+
+def _latent_step_text_and_kernel(pos0, dtype, block_k):
+    """``(q_nope, q_pe, cache, kv_b) -> o (B, heads, dv)`` twice:
+    ``absorbed_step``'s text and the same on the step kernel in the
+    interpreter."""
+    from ray_tpu.ops import latent_attention
+
+    def text(q_nope, q_pe, cache, kv_b, **kernel):
+        return latent_attention.absorbed_step(
+            q_nope, q_pe, cache, kv_b, pos0, 0.1, dtype, **kernel)
+
+    return text, functools.partial(
+        text, kernel=True, block_k=block_k, interpret=True)
+
+
+_LATENT_STEP_CASES = {
+    # one key head of 144 lanes (no whole lane tiles), its leading 128
+    # the value, four query heads of 24-wide values; streams at depth 0,
+    # inside a block, on a block's edge from both sides and full
+    "blocks_of_128": dict(depth=512, pos0=(0, 100, 127, 128, 511), block_k=128),
+    "blocks_of_256": dict(depth=1024, pos0=(0, 255, 256, 700, 1023), block_k=256),
+    "blocks_of_512": dict(depth=2048, pos0=(0, 511, 512, 513, 2047), block_k=512),
+    # seven query heads: the tile's rows padded to the sublanes
+    "heads_7_one_block": dict(heads=7, depth=128, pos0=(0, 5, 126, 127, 64),
+                              block_k=128),
+    "float32_blocks_of_16": dict(block_k=16, dtype=jnp.float32),
+    "gradient_is_the_texts": dict(block_k=16, dtype=jnp.float32),
+    "a_skipped_block_changes_nothing": dict(
+        pos0=(0, 15, 20, 31, 7), block_k=16, dtype=jnp.float32),
+}
+
+
+@pytest.mark.parametrize("name", list(_LATENT_STEP_CASES))
+def test_latent_step_kernel(name):
+    """The latent row on the step kernel (one cache: a key block's
+    leading lanes are its values) against ``absorbed_step``'s text."""
+    case = dict(_LATENT_STEP_CASES[name])
+    block_k = case.pop("block_k")
+    dtype = case.get("dtype", jnp.bfloat16)
+    operands, pos0, w = _latent_step(**case)
+    text, kernel = _latent_step_text_and_kernel(pos0, dtype, block_k)
+    if name == "a_skipped_block_changes_nothing":
+        # no stream is deeper than 32 of 64 slots: the last two blocks
+        # of 16 are skipped for all, so other rows there give the same bits
+        q_nope, q_pe, cache, kv_b = operands
+        np.testing.assert_array_equal(
+            np.asarray(kernel(q_nope, q_pe, cache.at[:, 33:].set(7.0), kv_b)),
+            np.asarray(kernel(*operands)))
+        return
+    tol = dict(atol=1e-4, rtol=1e-4) if dtype == jnp.float32 else dict(
+        atol=2e-2, rtol=2e-2)
+    np.testing.assert_allclose(
+        np.asarray(kernel(*operands)), np.asarray(text(*operands)), **tol)
+    if name == "gradient_is_the_texts":
+        loss = lambda f: lambda *a: jnp.sum(f(*a) * w)
+        got = jax.grad(loss(kernel), argnums=range(4))(*operands)
+        want = jax.grad(loss(text), argnums=range(4))(*operands)
+        for a, b in zip(got, want):
+            assert float(jnp.max(jnp.abs(b))) > 1e-3
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)
+
+
+def test_step_attention_takes_one_cache_for_one_key_head_only():
+    """No value cache means ONE key head and a ``value_dim``: anything
+    else is refused before a kernel is built."""
+    from ray_tpu.ops import flash_attention as fa
+
+    q = jnp.zeros((2, 1, 2, 4, 128), jnp.bfloat16)
+    cache = jnp.zeros((2, 64, 256), jnp.bfloat16)
+    held = jnp.ones((2,), jnp.int32)
+    for operands, value_dim in (((q, cache, None), 128),      # two key heads
+                                ((q[:, :, :1], cache, None), None),
+                                ((q, cache, cache), 128)):
+        with pytest.raises(ValueError, match="ONE key head"):
+            fa.step_attention(*operands, held, value_dim=value_dim, block_k=16)
+
+
+@pytest.mark.parametrize("heads,kv,head,depth,value", [
+    (28, 4, 128, 8192, None),  # SmallThinker's full layer
+    (48, 8, 128, 4096, None),  # Laguna's full layers
+    (16, 2, 256, 2048, None),  # Qwen3-Next's gated layer
+    (32, 8, 64, 2048, None),   # Granite's: two key heads a block
+    # Xing4's latent rows: one key head of 576 lanes (whole half lane
+    # tiles) whose leading 512 are its value, in the one cache
+    (32, 1, 576, 2048, 512),
 ])
-def test_step_rule_by_shape(monkeypatch, heads, kv, head, depth):
+def test_step_rule_by_shape(monkeypatch, heads, kv, head, depth, value):
     """Off a TPU the rule says XLA whatever the shape; on one, from the
     shapes alone: every cell's full-depth layer in bfloat16, and neither
     float32 nor a cache of no whole key block (a ring never asks: its
-    window sends it to the text before the rule)."""
+    window sends it to the text before the rule) nor a row that is no
+    whole half lane tile."""
     from ray_tpu.ops import flash_attention as fa
 
-    assert not fa.step_kernel_applies(heads, kv, head, depth, jnp.bfloat16)
+    rule = functools.partial(fa.step_kernel_applies, value_dim=value)
+    assert not rule(heads, kv, head, depth, jnp.bfloat16)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert fa.step_kernel_applies(heads, kv, head, depth, jnp.bfloat16)
-    assert not fa.step_kernel_applies(heads, kv, head, depth, jnp.float32)
-    assert not fa.step_kernel_applies(heads, kv, head, depth + 24, jnp.bfloat16)
-    assert not fa.step_kernel_applies(heads, kv, 96, depth, jnp.bfloat16)
-    # two slots of a block of all key heads, keys and values, fit VMEM
-    assert not fa.step_kernel_applies(16 * heads, 16 * kv, head, depth, jnp.bfloat16)
+    assert rule(heads, kv, head, depth, jnp.bfloat16)
+    assert not rule(heads, kv, head, depth, jnp.float32)
+    assert not rule(heads, kv, head, depth + 24, jnp.bfloat16)
+    assert not rule(heads, kv, 96 + (value or 0), depth, jnp.bfloat16)
+    # two slots of a block of all key heads, keys and values, fit VMEM;
+    # one cache belongs to ONE key head
+    assert not rule(16 * heads, 16 * kv, head, depth, jnp.bfloat16)
+    if value:
+        # a value of no whole lane tiles; no lanes after it; a cache
+        # whose two streams' further lanes do not fit beside the blocks
+        assert not fa.step_kernel_applies(
+            heads, kv, head, depth, jnp.bfloat16, value_dim=value - 64)
+        assert not fa.step_kernel_applies(
+            heads, kv, head, depth, jnp.bfloat16, value_dim=head)
+        assert not rule(heads, kv, head, 16 * depth, jnp.bfloat16)
+        # and a latent row handed over as two caches is no softmax head
+        assert not fa.step_kernel_applies(heads, kv, head, depth, jnp.bfloat16)
     # 16 blocks of 512: rows held of 1, 512, 513 and past the cache skip
     # 15, 15, 14 and none
     skipped, held = fa.step_key_blocks(

@@ -6,6 +6,7 @@ deliberately unequal dimensions: nope 16, rope 8, value 12, latent 24,
 query latent 20, 3 lanes.
 """
 
+import functools
 import importlib.util
 import os
 
@@ -248,9 +249,135 @@ def test_fragment_form_on_the_kernel_equals_the_text_and_the_steps(
     assert float(got_stats["attn_key_blocks_skipped_share"]) == pytest.approx(
         skipped / (4.0 * n))
     assert float(want_stats["attn_key_blocks_skipped_share"]) == 0.0
-    # the one-token kernel is the softmax kinds': a latent model's learn
-    # program carries no statistic of it (its text is as it was)
-    assert "attn_decode_key_blocks_skipped_share" not in got_stats
+    # the one-token form's rule was not forced: its statistic reads 0 of 0
+    assert float(got_stats["attn_decode_key_blocks_skipped_share"]) == 0.0
+
+
+# the step kernel copies a row's latent as whole lane tiles: the forced
+# cases run this file's model with a latent of 128 (a row of 136)
+WIDE = {"kv_lora_rank": 128}
+
+
+def _step_kernel_in_the_interpreter(monkeypatch):
+    """What a TPU's rule would say, at this file's sizes: the latent
+    layers' one-token form on the step kernel in the Pallas interpreter,
+    the cache of 48 rows as three key blocks of 16."""
+    from ray_tpu.ops import flash_attention
+
+    monkeypatch.setattr(
+        flash_attention, "step_kernel_applies", lambda *a, **value: True)
+    monkeypatch.setattr(flash_attention, "fragment_block_k", lambda depth, _=None: 16)
+    monkeypatch.setattr(
+        flash_attention, "step_attention",
+        functools.partial(flash_attention.step_attention, interpret=True))
+
+
+@pytest.mark.parametrize("forced", [False, True])
+def test_one_token_form_takes_the_step_kernel_by_the_rule(monkeypatch, forced):
+    """(a') the three latent layers' one-token calls lower to the text
+    off a TPU (``absorbed``) and to the step kernel where its rule says
+    so (``absorbed_kernel``, here in the interpreter): the same logits,
+    values and state, both counters say which ran, and the learn form
+    reports the key blocks a step at each of its positions skips (0 of
+    0 on the text)."""
+    from ray_tpu.telemetry import metrics
+
+    config = small_config(**WIDE)
+    params = ref.init_params(jax.random.PRNGKey(7), config, VOCAB)
+    model = _model(config["algo_config"]["model"]["sequence_lm"])
+    batch = ref.make_batch(np.random.default_rng(3), config, 4 * T, VOCAB)
+    rows = batch["obs"].shape[0]
+    state = _f32_state(ref.batch_state(batch))
+    tokens = jnp.asarray(batch["obs"]).reshape(rows // T, T, 1)
+    with jax.default_matmul_precision("highest"):
+        want = model.apply(params, tokens[:, :1], state)
+        if forced:
+            _step_kernel_in_the_interpreter(monkeypatch)
+        forms = dict(metrics.mla_decode_lowerings())
+        paths = dict(metrics.attention_step_lowerings())
+        got = model.apply(params, tokens[:, :1], state)
+    now = metrics.mla_decode_lowerings()
+    grown = {k: now[k] - forms.get(k, 0) for k in now if now[k] != forms.get(k, 0)}
+    assert grown == {"absorbed_kernel" if forced else "absorbed": 3}
+    now = metrics.attention_step_lowerings()
+    grown = {k: now[k] - paths.get(k, 0) for k in now if now[k] != paths.get(k, 0)}
+    assert grown == {"kernel" if forced else "xla": 3}
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=3e-4, rtol=3e-4)
+    stats = {}
+    _model_forward(model, params, batch, stats)
+    # by hand: a step at position p holds the blocks of 16 up to p's own,
+    # of a layer's three
+    pos0 = np.asarray(ref.batch_state(batch)[-1])
+    fresh = batch["resets"].reshape(-1, T) > 0.5
+    skipped = []
+    for n in range(rows // T):
+        p = int(pos0[n])
+        for i in range(T):
+            p = 0 if fresh[n, i] else p
+            skipped.append(3 - min(p // 16 + 1, 3))
+            p += 1
+    assert 0.1 < np.mean(skipped) / 3 < 0.9
+    assert float(stats["attn_decode_key_blocks_skipped_share"]) == pytest.approx(
+        np.mean(skipped) / 3 if forced else 0.0)
+
+
+def test_a_rollout_through_the_lane_is_the_same_on_both_forms(monkeypatch):
+    """The device lane's rollout of the small latent model (8 streams 6
+    positions apart in episodes of 48, 8 steps: streams in every key
+    block of 16, one crossing a block's edge) with the one-token form on
+    the text and on the step kernel: the same tokens, and the same
+    log-probabilities, values and latent rows within tolerance."""
+    from ray_tpu import sharding as sharding_lib
+    from ray_tpu.algorithms.ppo.ppo import PPOConfig, PPOJaxPolicy
+    from ray_tpu.env.jax_tokens import TokenStreamJax
+    from ray_tpu.execution.jax_rollout import JaxRolloutEngine
+    from ray_tpu.telemetry import metrics
+
+    def rollout():
+        env = TokenStreamJax(
+            {"vocab_size": VOCAB, "episode_length": 48, "phase_stride": 6})
+        cfg = PPOConfig().to_dict()
+        cfg.update(
+            seed=5, num_workers=0, num_envs_per_worker=8,
+            rollout_fragment_length=8, train_batch_size=64,
+            sgd_minibatch_size=64, num_sgd_iter=1,
+            model={"use_sequence_lm": True, "max_seq_len": 8, "dtype": "float32",
+                   "sequence_lm": small_config(**WIDE)["algo_config"]["model"][
+                       "sequence_lm"]},
+            _mesh=sharding_lib.get_mesh(devices=jax.devices()[:1]))
+        cfg["lambda"] = 0.95
+        policy = PPOJaxPolicy(env.observation_space, env.action_space, cfg)
+        engine = JaxRolloutEngine(
+            policy, env, 8, 8, seed=5, standardize_advantages=False)
+        batch = jax.device_get(engine.rollout()[0])
+        return batch, jax.device_get(engine._carry["state"])
+
+    before = dict(metrics.mla_decode_lowerings())
+    want, want_state = rollout()
+    text = dict(metrics.mla_decode_lowerings())
+    assert text.get("absorbed", 0) > before.get("absorbed", 0)
+    assert text.get("absorbed_kernel", 0) == before.get("absorbed_kernel", 0)
+    _step_kernel_in_the_interpreter(monkeypatch)
+    # the Pallas interpreter slices a stream's block out of operands that
+    # vary over the mesh at a grid index that does not, and jax refuses the
+    # pair under ``shard_map``'s typing; on a TPU the grid is Mosaic's own
+    monkeypatch.setattr(
+        jax, "shard_map", functools.partial(jax.shard_map, check_vma=False))
+    got, got_state = rollout()
+    now = metrics.mla_decode_lowerings()
+    assert now.get("absorbed_kernel", 0) > text.get("absorbed_kernel", 0)
+    assert now.get("absorbed", 0) == text.get("absorbed", 0)
+    np.testing.assert_array_equal(got["actions"], want["actions"])
+    assert len(set(np.asarray(want["actions"]).tolist())) > 8
+    for name in ("action_logp", "vf_preds", "action_dist_inputs"):
+        np.testing.assert_allclose(
+            got[name], want[name], atol=1e-4, rtol=1e-4, err_msg=name)
+    depth = np.asarray(want_state[-1])
+    np.testing.assert_array_equal(np.asarray(got_state[-1]), depth)
+    for a, b in zip(got_state[:-1], want_state[:-1]):
+        for s in range(8):
+            np.testing.assert_allclose(a[s, : depth[s]], b[s, : depth[s]], atol=1e-4)
 
 
 def test_hyper_connection_block_equals_the_reference_and_its_gradient(setup):

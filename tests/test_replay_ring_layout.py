@@ -602,6 +602,9 @@ STEP_LAYERS = {
     "granite4h": (16, 8, 4, 64, 2048),
     # a block of 4 tokens: its 4 x 8 queries of a key head as one tile
     "sdar_block": (16, 4, 32, 128, 4096),
+    # Xing4's latent rows: one key head of 576 lanes for 32 query heads,
+    # the value its leading 512 lanes (a sixth number: no value cache)
+    "xing4_latent": (32, 1, 32, 576, 2048, 512),
 }
 
 
@@ -609,30 +612,33 @@ STEP_LAYERS = {
 def test_v5e_step_attention_kernel_compiles(v5e_mesh, layer):
     """The one-token form's kernel (ops/flash_attention.step_attention)
     at a sequence cell's streams and width under ``shard_map``: Mosaic
-    takes all four geometries (6 and 7 query heads padded to the
-    sublanes, 64 two key heads a block) as ONE custom call under the
-    caller's scope, and no float32 array over the cache's slots exists
-    in the compiled program: no score of a slot past a stream's depth
-    is computed."""
+    takes all the geometries (6 and 7 query heads padded to the
+    sublanes, 64 two key heads a block, the latent row's one key head
+    of 4.5 lane tiles with its leading lanes for a value) as ONE custom
+    call under the caller's scope, and no float32 array over the cache's
+    slots exists in the compiled program: no score of a slot past a
+    stream's depth is computed."""
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.ops import flash_attention
 
-    b, kv, group, d, depth = STEP_LAYERS[layer]
+    b, kv, group, d, depth, *value = STEP_LAYERS[layer]
     axis = sharding_lib.data_axis(v5e_mesh)
     rows = sharding_lib.batch_sharded(v5e_mesh)
     on = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=rows)
     bf = jnp.bfloat16
 
-    def step(q, kc, vc, held):
+    def step(q, held, kc, vc=None):
         with jax.named_scope("rollout/act/attn/scores"):
-            return flash_attention.step_attention(q, kc, vc, held)
+            return flash_attention.step_attention(
+                q, kc, vc, held, value_dim=value[0] if value else None)
 
+    caches = (on(bf, b, depth, kv * d),) * (1 if value else 2)
     sharded = jax.shard_map(
-        step, mesh=v5e_mesh, in_specs=(P(axis),) * 4, out_specs=P(axis))
+        step, mesh=v5e_mesh, in_specs=(P(axis),) * (2 + len(caches)),
+        out_specs=P(axis))
     text = jax.jit(sharded).lower(
-        on(bf, b, 1, kv, group, d), on(bf, b, depth, kv * d),
-        on(bf, b, depth, kv * d), on(jnp.int32, b),
+        on(bf, b, 1, kv, group, d), on(jnp.int32, b), *caches,
     ).compile().as_text()
     calls = [
         line for line in text.splitlines()

@@ -93,9 +93,9 @@ SURFACE = {
         },
         "state": [((2, 48, 32), 'float32'), ((2, 48, 32), 'float32'), ((2, 48, 32), 'float32'),
          ((2,), 'int32')],
-        "fragment_stats": ['attn_key_blocks_skipped_share', 'hc_res_col_sum_err_max',
-         'hc_res_row_sum_err_max', 'moe_held_load', 'moe_rows_computed_share',
-         'moe_slots_on_absent_experts'],
+        "fragment_stats": ['attn_decode_key_blocks_skipped_share', 'attn_key_blocks_skipped_share',
+         'hc_res_col_sum_err_max', 'hc_res_row_sum_err_max', 'moe_held_load',
+         'moe_rows_computed_share', 'moe_slots_on_absent_experts'],
         "step_stats": ['hc_res_col_sum_err_max', 'hc_res_row_sum_err_max', 'moe_held_load',
          'moe_rows_computed_share', 'moe_slots_on_absent_experts'],
         "fragment_scopes": ['hc', 'head', 'mla', 'mlp', 'moe/experts', 'moe/route', 'moe/shared'],

@@ -229,6 +229,49 @@ def test_latent_fragment_on_tpu():
             atol=2e-2 * scale)
 
 
+def test_latent_step_on_tpu(monkeypatch):
+    """The latent layer's one-token form at the Xing4 cell's width takes
+    the step kernel by the rule (one key head of 576 lanes, the 32 query
+    heads one tile, the value the block's leading 512 lanes) and agrees
+    with the text (the rule's other branch) within bfloat16's rounding:
+    an empty stream, a block's edge from both sides and a full cache in
+    one batch; both counters say which ran."""
+    from ray_tpu.ops import flash_attention, latent_attention
+    from ray_tpu.telemetry import metrics
+
+    b, h, dn, rope, latent, dv, depth = 5, 32, 128, 64, 512, 128, 2048
+    bf = jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    operands = (
+        jax.random.normal(keys[0], (b, 1, h, dn), jnp.float32),
+        jax.random.normal(keys[1], (b, 1, h, rope), jnp.float32),
+        jax.random.normal(keys[2], (b, 1, latent + rope), bf),
+        jax.random.normal(keys[3], (b, depth, latent + rope), bf),
+        jax.random.normal(keys[4], (latent, h * (dn + dv)), jnp.float32)
+        * latent ** -0.5,
+    )
+    pos0 = jnp.asarray([0, 510, 511, 512, depth - 1], jnp.int32)
+    rows = {"seg": jnp.zeros((b, 1), jnp.int32), "positions": pos0[:, None],
+            "pos0": pos0}
+
+    def run():
+        return jax.jit(lambda *a: latent_attention.latent_attention(
+            *a, rows, scale=(dn + rope) ** -0.5, dtype=bf)[:2])(*operands)
+
+    forms = lambda: (metrics.mla_decode_lowerings().get("absorbed_kernel", 0),
+                     metrics.mla_decode_lowerings().get("absorbed", 0),
+                     metrics.attention_step_lowerings().get("kernel", 0))
+    before = forms()
+    out, cache = run()
+    assert forms() == (before[0] + 1, before[1], before[2] + 1)
+    monkeypatch.setattr(
+        flash_attention, "step_kernel_applies", lambda *a, **value: False)
+    want, want_cache = run()
+    assert forms() == (before[0] + 1, before[1] + 1, before[2] + 1)
+    np.testing.assert_array_equal(np.asarray(cache), np.asarray(want_cache))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-2)
+
+
 def test_fragment_kernel_forced_on_a_refused_shape_raises():
     """A head of 96 is neither whole lane tiles nor a part of one: the
     rule keeps such a layer on the XLA text, and the kernel called for
