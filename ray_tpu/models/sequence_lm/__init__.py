@@ -59,12 +59,12 @@ two levels deep, ``{"layer_0": {"in_proj_qkvz": ...}, ...}``.
 from ray_tpu.models.sequence_lm.config import (
     Segment, attention_layers_of, describe, layer_types_of)
 from ray_tpu.models.sequence_lm.kinds import (
-    AttentionLayer, DeltaNetLayer, DenseLayer, ExpertLayer, HyperResidual,
+    AttentionLayer, DeltaNetLayer, DenseLayer, EvaLayer, ExpertLayer, HyperResidual,
     LatentLayer, MambaLayer, NoSublayer, PlainResidual)
 from ray_tpu.models.sequence_lm.model import SequenceLM
 
 __all__ = [
     "SequenceLM", "Segment", "describe", "layer_types_of", "attention_layers_of",
-    "AttentionLayer", "LatentLayer", "DeltaNetLayer", "MambaLayer",
+    "AttentionLayer", "LatentLayer", "DeltaNetLayer", "MambaLayer", "EvaLayer",
     "DenseLayer", "ExpertLayer", "NoSublayer", "PlainResidual", "HyperResidual",
 ]

@@ -10,12 +10,13 @@ import numpy as np
 
 from ray_tpu.models.sequence_lm.generation import Autoregressive, BlockDiffusion
 from ray_tpu.models.sequence_lm.kinds import (
-    AttentionLayer, DeltaNetLayer, DenseLayer, ExpertLayer, HyperResidual,
+    AttentionLayer, DeltaNetLayer, DenseLayer, EvaLayer, ExpertLayer, HyperResidual,
     LatentLayer, MambaLayer, NoSublayer, PlainResidual)
 from ray_tpu.ops import latent_attention
 
 LINEAR, FULL, LATENT = "linear_attention", "full_attention", "latent_attention"
 MAMBA, ATTENTION, SLIDING = "mamba", "attention", "sliding_attention"
+EVA = "eva_attention"
 DENSE, EXPERTS = "dense", "experts"
 # the half a block of one sublayer lacks
 NONE = "none"
@@ -66,7 +67,8 @@ def layer_types_of(config: Dict) -> Tuple[str, ...]:
     config has one (``M`` ``"mamba"``, ``*`` ``"attention"``, ``E``
     ``"none"``: a block of experts alone); ``layer_types`` if stated;
     all full attention for
-    a ``qwen3_moe`` stack; all latent attention
+    a ``qwen3_moe`` stack; all EVA attention where ``attention_class``
+    says ``"eva"`` (``evabyte``); all latent attention
     where the config has a ``kv_lora_rank``; by
     ``sliding_window_layout`` where the config has one (1: a window
     layer, which is also where ``rope_layout`` turns; 0: full depth and
@@ -80,6 +82,8 @@ def layer_types_of(config: Dict) -> Tuple[str, ...]:
     layers = int(config["num_hidden_layers"])
     if _qwen3_moe_stack(config):
         return (FULL,) * layers
+    if config.get("attention_class") == "eva":
+        return (EVA,) * layers
     if "sliding_window_layout" in config:
         window = list(config["sliding_window_layout"])[:layers]
         if window != list(config.get("rope_layout", window))[:layers]:
@@ -178,6 +182,29 @@ def _latent_layer(c: Dict) -> LatentLayer:
         kv_latent=int(c["kv_lora_rank"]), nope=nope, rope_dim=rope_dim,
         v_head=int(c["v_head_dim"]), inv_freq=tuple(float(f) for f in inv_freq),
         softmax_scale=latent_attention.yarn_softmax_scale(nope + rope_dim, scaling))
+
+
+def _eva_layer(c: Dict) -> EvaLayer:
+    """``model_type: evabyte`` with ``attention_class: "eva"``:
+    ``window_size`` and ``chunk_size``, RoPE by ``rope_theta`` with no
+    scaling, as many key heads as query heads. ``num_attention_heads``
+    is what this chip holds, ``heads_held`` ``[first, held]`` which of
+    the layer's they are (none: all of them, from 0), so the head's size
+    is ``head_dim`` where a share is held and ``hidden_size /
+    num_attention_heads`` of the whole layer."""
+    heads = int(c["num_attention_heads"])
+    first, held = c.get("heads_held") or (0, heads)
+    window, chunk = int(c["window_size"]), int(c["chunk_size"])
+    if int(c.get("num_key_value_heads", heads)) != heads or int(held) != heads:
+        raise ValueError(
+            "EVA attention pools a key head a query head: num_key_value_heads "
+            "and heads_held's count are num_attention_heads")
+    if c.get("rope_scaling") or window % chunk:
+        raise ValueError("EVA attention: no rope_scaling, whole chunks a window")
+    return EvaLayer(
+        heads=heads, head_dim=int(c.get("head_dim") or int(c["hidden_size"]) // heads),
+        window=window, chunk=chunk, theta=float(c.get("rope_theta", 10000.0)),
+        first=int(first))
 
 
 def _deltanet_layer(c: Dict) -> DeltaNetLayer:
@@ -311,7 +338,7 @@ def describe(config: Dict) -> Dict:
     layers = len(layer_types)
     attention = attention_layers_of(c, layer_types)
     others = {LINEAR: _deltanet_layer, LATENT: _latent_layer, MAMBA: _mamba_layer,
-              NONE: lambda c: NoSublayer()}
+              EVA: _eva_layer, NONE: lambda c: NoSublayer()}
     made = {kind: others[kind](c) for kind in set(layer_types) & set(others)}
     # experts the config counts, under whichever family's key
     experts = int(next(

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.ops import (
-    cached_attention, deltanet, hyper_connection, latent_attention, moe, ssd)
+    cached_attention, deltanet, eva_attention, hyper_connection, latent_attention, moe,
+    ssd)
 from ray_tpu.telemetry import metrics
 
 HI = jax.lax.Precision.HIGHEST
@@ -381,6 +382,102 @@ class LatentLayer(Kind):
                 scale=self.softmax_scale, dtype=dtype)
             return (dot(o.reshape(b, t, h * self.v_head), p["o_proj"], dtype),
                     (new_cache,), stats)
+
+
+@dataclasses.dataclass(frozen=True)
+class EvaLayer(Kind):
+    """``"eva_attention"`` (``model_type: evabyte``, ``attention_class:
+    "eva"``; Zheng, Yuan, Wang, Kong, "Efficient Attention via Control
+    Variates", ICLR 2023, arXiv:2302.04542, in the causal,
+    learned-proposal parameterisation of the EvaByte release: ``eva.py``,
+    ``eva_prep_kv_kernel.py``, ``eva_agg_kernel.py`` beside the published
+    ``config.json``). ``q, k, v = h W_q, h W_k, h W_v``, as many key
+    heads as query heads, no bias; RoPE (rotate-half, ``theta``, the
+    whole head) on ``q`` and ``k``; ``s = head^-1/2``. Per head, with
+    learned ``phi``, ``mu`` in ``R^head`` (``eva_phi``, ``eva_mu``),
+    window ``W`` and chunk ``c``:
+
+    - chunk ``j`` = positions ``c j .. c j + c - 1``, summarised when its
+      last token is written: ``kbar_j = sum_i softmax_i(phi . k_i) k_i``,
+      ``vbar_j = sum_i softmax_i(mu . k_i) v_i`` (``k_i`` after RoPE);
+    - ``S_t = {i : i // W == t // W, i <= t}`` (the query's own window,
+      exact); ``R_t = {j : c j + c - 1 < W (t // W)}`` (every chunk of
+      every earlier window);
+    - ``o_t = [sum_S e^{s q_t.k_i} v_i + sum_R e^{s q_t.kbar_j} vbar_j] /
+      [sum_S e^{s q_t.k_i} + sum_R e^{s q_t.kbar_j}]``: ONE softmax;
+    - ``y = W_o o``.
+
+    ``mu`` and ``phi`` are parameters of the CACHE WRITE: the learn form
+    differentiates through the summaries a fragment makes. The chip
+    holds ``heads`` of the layer's heads, from ``first`` on
+    (``heads_held``): its heads' part of ``W_o o``, the shares adding up
+    to the uncut layer's (:meth:`share_of`).
+
+    State: TWO pairs of leaves on TWO clocks (docs/policy_state.md, "Two
+    stores on two clocks"): keys and values of the window store, one row
+    a token, ``min(W, positions)`` rows, position ``p`` in slot ``p mod
+    W``; keys and values of the summary store, one row a chunk,
+    ``ceil(positions / c)`` rows. Not cleared on a reset: the masks
+    follow the position. Scopes: ``eva`` and its ``/scatter``,
+    ``/summarise``, ``/scores``, ``/out``."""
+
+    heads: int
+    head_dim: int
+    window: int
+    chunk: int
+    theta: float
+    # of the uncut layer's heads, those from ``first`` on
+    first: int = 0
+
+    init_rules = {
+        leaf: lambda key, shape: jnp.clip(
+            jax.random.normal(key, shape, jnp.float32), -1.0, 1.0) * shape[-1] ** -0.5
+        for leaf in ("eva_mu", "eva_phi")}
+    stats = {
+        # rows inside each mask a query saw
+        "eva_window_rows_seen_mean": "mean", "eva_summary_rows_seen_mean": "mean",
+        "eva_chunks_summarised": "sum", "eva_fragments_crossing_a_window": "sum",
+        # of a one-token step at each of the fragment's positions
+        "eva_window_key_blocks_skipped": "sum", "eva_window_key_blocks_walked": "sum",
+        "eva_summary_key_blocks_skipped": "sum", "eva_summary_key_blocks_walked": "sum",
+    }
+
+    def param_shapes(self, d: int):
+        wide = self.heads * self.head_dim
+        return dict(
+            q_proj=(d, wide), k_proj=(d, wide), v_proj=(d, wide), o_proj=(wide, d),
+            eva_mu=(self.heads, self.head_dim), eva_phi=(self.heads, self.head_dim))
+
+    def state_shapes(self, streams: int, positions: int, dtype):
+        row = self.heads * self.head_dim
+        exact = (streams, min(self.window, positions), row)
+        pooled = (streams, -(-positions // self.chunk), row)
+        return [(exact, dtype), (exact, dtype), (pooled, dtype), (pooled, dtype)]
+
+    def share_of(self, p):
+        """This share's leaves out of an uncut layer's ``p``: its heads'
+        columns of ``W_q``, ``W_k``, ``W_v``, rows of ``W_o`` and vectors."""
+        lo, hi = self.first * self.head_dim, (self.first + self.heads) * self.head_dim
+        cut = {"o_proj": p["o_proj"][lo:hi]}
+        cut.update({leaf: p[leaf][:, lo:hi] for leaf in ("q_proj", "k_proj", "v_proj")})
+        cut.update({leaf: p[leaf][self.first:self.first + self.heads]
+                    for leaf in ("eva_mu", "eva_phi")})
+        return {**p, **cut}
+
+    def apply(self, p, x, state, ctx):
+        scope = ctx["scope"] + "eva"
+        dtype = ctx["dtype"]
+        b, t, _ = x.shape
+        h, d = self.heads, self.head_dim
+        with jax.named_scope(scope):
+            q, k, v = (dot(x, p[leaf], dtype).reshape(b, t, h, d)
+                       for leaf in ("q_proj", "k_proj", "v_proj"))
+            q, k = (rope(z, ctx["positions"], d, self.theta) for z in (q, k))
+        o, new, stats = eva_attention.eva_attention(
+            q, k, v, p["eva_phi"], p["eva_mu"], state, ctx, scale=d ** -0.5,
+            window=self.window, chunk=self.chunk, dtype=dtype, scope=scope)
+        with jax.named_scope(scope + "/out"):
+            return dot(o.reshape(b, t, h * d), p["o_proj"], dtype), new, stats
 
 
 _ones = lambda key, shape: jnp.ones(shape, jnp.float32)

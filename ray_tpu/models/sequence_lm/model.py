@@ -426,10 +426,10 @@ class SequenceLM:
             # the one feed-forward there is, for every token
             stats_out["moe_routes"] = routes if routes is not None else jnp.zeros(
                 (1, tokens, 1), jnp.int32)
-        for name in ("attn_key_blocks", "attn_decode_key_blocks"):
-            if name + "_walked" in stats:
-                stats_out[name + "_skipped_share"] = stats.pop(
-                    name + "_skipped") / jnp.maximum(stats.pop(name + "_walked"), 1)
+        # key blocks a kind counted as ``<name>_skipped`` of ``<name>_walked``
+        for name in [k[:-len("_walked")] for k in stats if k.endswith("_key_blocks_walked")]:
+            stats_out[name + "_skipped_share"] = stats.pop(
+                name + "_skipped") / jnp.maximum(stats.pop(name + "_walked"), 1)
         place = stats.pop("moe_place_load", None)
         if place is not None and not self.residual.groups_the_loss:
             # a group of ``loss_groups`` hands out ``moe_held_load`` as it

@@ -150,6 +150,14 @@ SSM_STEP_LOWERINGS_TOTAL = "ray_tpu_ssm_step_lowerings_total"
 # learn form). Counted when the form is traced: once per window layer
 # body of a program
 WINDOW_CACHE_LOWERINGS_TOTAL = "ray_tpu_window_cache_lowerings_total"
+# which form each traced EVA attention layer took over its two stores
+# (ops/eva_attention.eva_attention): form = step (one token, XLA's text
+# over every slot of both stores under the two masks: the CPU's path) |
+# kernel (one token, the two-store step kernel: only the key blocks
+# inside the masks are fetched) | fragment (a fragment from stored start
+# states, the summaries it completes made inside it: the learn form).
+# Counted when the form is traced: once per EVA layer body of a program
+EVA_LOWERINGS_TOTAL = "ray_tpu_eva_lowerings_total"
 # which lowering each traced attention layer's fragment form took
 # (ops/cached_attention.cached_attention, every softmax attention kind
 # over a stored cache, and ops/latent_attention over its latent rows): path =
@@ -740,6 +748,16 @@ def inc_window_cache_lowering(form: str) -> None:
     ).inc(1.0, {"form": form})
 
 
+def inc_eva_lowering(form: str) -> None:
+    """One traced EVA attention layer took ``form`` (``step`` |
+    ``kernel`` | ``fragment``) over its two stores."""
+    counter(
+        EVA_LOWERINGS_TOTAL,
+        "EVA attention layers traced, by the form they took",
+        ("form",),
+    ).inc(1.0, {"form": form})
+
+
 def inc_attention_layer_lowering(kind: str, heads: int, rope: str) -> None:
     """One traced softmax-attention layer body of this geometry."""
     counter(
@@ -810,6 +828,11 @@ def attention_step_lowerings() -> Dict[str, float]:
 def window_cache_lowerings() -> Dict[str, float]:
     """``{form: traced sliding-window layers}`` since the process began."""
     return _totals_by_tag(WINDOW_CACHE_LOWERINGS_TOTAL, "form")
+
+
+def eva_lowerings() -> Dict[str, float]:
+    """``{form: traced EVA attention layers}`` since the process began."""
+    return _totals_by_tag(EVA_LOWERINGS_TOTAL, "form")
 
 
 def deltanet_step_lowerings() -> Dict[str, float]:

@@ -649,6 +649,43 @@ def test_v5e_step_attention_kernel_compiles(v5e_mesh, layer):
     assert not re.search(rf"f32\[[0-9,]*{depth}\]", text)
 
 
+def test_v5e_eva_step_kernel_compiles(v5e_mesh):
+    """The two-store one-token kernel (ops/eva_attention.step_attention)
+    at the EvaByte cell's streams and width (16 streams, 8 heads of 128,
+    a window store of 2,048 rows and a summary store of 640) under
+    ``shard_map``: Mosaic takes it as ONE custom call under the caller's
+    scope, and no float32 array over either store's slots exists in the
+    compiled program: no score of a slot outside the two masks is
+    computed by XLA beside it."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.ops import eva_attention
+
+    b, h, d, window, chunk, summaries = 16, 8, 128, 2048, 16, 640
+    axis = sharding_lib.data_axis(v5e_mesh)
+    rows = sharding_lib.batch_sharded(v5e_mesh)
+    on = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=rows)
+    bf = jnp.bfloat16
+
+    def step(q, positions, *stores):
+        with jax.named_scope("rollout/act/eva/scores"):
+            return eva_attention.step_attention(
+                q, stores, positions, window=window, chunk=chunk)
+
+    stores = (on(bf, b, window, h * d),) * 2 + (on(bf, b, summaries, h * d),) * 2
+    sharded = jax.shard_map(
+        step, mesh=v5e_mesh, in_specs=(P(axis),) * 6, out_specs=P(axis))
+    text = jax.jit(sharded).lower(
+        on(bf, b, h, d), on(jnp.int32, b), *stores).compile().as_text()
+    calls = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "custom-call(" in line
+    ]
+    assert len(calls) == 1 and "rollout/act/eva/scores" in calls[0]
+    assert "eva_step_attention" in calls[0]
+    assert not re.search(rf"f32\[[0-9,]*({window}|{summaries})\]", text)
+
+
 def test_v5e_tree_update_pays_for_its_levels(v5e_mesh):
     """The DQN cell's priority refresh (ops/segment_tree.py: an
     (8, 512) update of a 131,072-leaf f64 tree pair) as the chip's
