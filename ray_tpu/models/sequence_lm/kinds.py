@@ -440,6 +440,8 @@ class EvaLayer(Kind):
         # of a one-token step at each of the fragment's positions
         "eva_window_key_blocks_skipped": "sum", "eva_window_key_blocks_walked": "sum",
         "eva_summary_key_blocks_skipped": "sum", "eva_summary_key_blocks_walked": "sum",
+        # spans the step kernel fetches (a copy a leaf each), and their rows
+        "eva_step_copies": "sum", "eva_step_rows_fetched_mean": "mean",
     }
 
     def param_shapes(self, d: int):

@@ -34,7 +34,8 @@ written, and the scores run over both stores: where
 :func:`step_kernel_applies` says so as ONE tiled kernel
 (:func:`step_attention`) with one running max and sum that walks the
 window store's key blocks up to ``t mod W`` and the summary store's up to
-``(W / c) (t // W)`` and fetches no other block; elsewhere as XLA's text
+``(W / c) (t // W)``, up to eight consecutive blocks a copy and a trip,
+and fetches no other block; elsewhere as XLA's text
 over every slot under the two masks (:func:`step_text`: the CPU's path,
 the kernel's oracle and its backward pass). A fragment (``T > 1``) from
 stored start states: the text over the stored window rows, the stored
@@ -70,7 +71,13 @@ HI = jax.lax.Precision.HIGHEST
 # rows of one key block of either store: summaries become visible a
 # window's worth (W / c = 128 at the published sizes) at a time
 STEP_BLOCK = 128
-# key blocks in flight beside the one in use
+# key blocks of one span at most: consecutive blocks of one store and one
+# stream that one copy a leaf fetches and one trip of the kernel's walk
+# folds; and the spans in flight beside the one in use (on the chip, the
+# cell's 16 streams 640 apart, microseconds a call: a block a trip 155;
+# spans of 2, 4, 8, 16 with three in flight 166, 134, 122, -; 8 with two
+# and one 123, 141; 16 with two and one 137, 153)
+_SPAN_BLOCKS = 8
 _AHEAD = 3
 
 
@@ -139,40 +146,88 @@ def step_key_blocks(positions, window: int, chunk: int, window_rows: int,
     return out
 
 
-def _step_kernel(first_ref, count_ref, stream_ref, store_ref, block_ref,
-                 win_seen_ref, sum_seen_ref, q_ref, wk_ref, wv_ref, sk_ref, sv_ref,
-                 o_ref, k_buf, v_buf, sem, *, block):
+def step_spans(window_rows_seen, summary_rows_seen, block: int = STEP_BLOCK):
+    """``(window spans, summary spans)`` a step fetches: a store's key
+    blocks inside its mask (:func:`step_blocks`) in runs of up to
+    ``_SPAN_BLOCKS``, each run one copy a leaf and one trip of the
+    kernel's walk."""
+    return tuple(-(-held // _SPAN_BLOCKS)
+                 for held in step_blocks(window_rows_seen, summary_rows_seen, block))
+
+
+def step_fetches(positions, window: int, chunk: int, block: int = STEP_BLOCK):
+    """``(copies, rows fetched a step)`` of one-token steps at
+    ``positions`` (any shape): the spans of both stores over all steps
+    (a span's keys and values counted once) and the mean rows of whole
+    key blocks a step fetches, both stores."""
+    seen = rows_seen(positions, window, chunk)
+    copies = sum(jnp.sum(n) for n in step_spans(*seen, block))
+    rows = block * sum(jnp.mean(n.astype(jnp.float32)) for n in step_blocks(*seen, block))
+    return copies, rows
+
+
+def _by_halves(index, branches, *operands):
+    """``lax.switch`` as a tree of two-way branches: this lowering turns
+    a switch into a CHAIN of as many nested branches as it has cases,
+    and the chip's compiler falls over a chain of sixteen."""
+    if len(branches) == 1:
+        return branches[0](*operands)
+    half = len(branches) // 2
+    return jax.lax.cond(
+        index < half,
+        lambda *xs: _by_halves(index, branches[:half], *xs),
+        lambda *xs: _by_halves(index - half, branches[half:], *xs),
+        *operands)
+
+
+def _step_kernel(first_ref, count_ref, stream_ref, code_ref, row_ref, seen_ref,
+                 q_ref, wk_ref, wv_ref, sk_ref, sv_ref, o_ref, k_buf, v_buf, sem,
+                 *, block):
     """One stream a grid step: ``ops/flash_attention._step_kernel``'s
     arithmetic (one running max, sum and accumulator a head) over the
-    FLAT list of the (stream, store, block) triples inside the masks
-    (``stream_ref``, ``store_ref`` 0 the window store and 1 the summary
-    store, ``block_ref``; a stream's first entry ``first_ref[b]``, its
-    ``count_ref[b]`` entries, the window store's first), fetched by the
-    kernel's own copies, as many ahead as the buffers have slots but
-    one, whatever stream or store the next ones are of. The four stores
-    stay in HBM; a block outside a mask is in no list and is not
-    fetched."""
+    FLAT list of the SPANS inside the masks: a span is one to
+    ``_SPAN_BLOCKS`` consecutive key blocks of one store and one stream
+    (``stream_ref``; ``row_ref`` its first row; ``code_ref`` the store,
+    0 the window store and 1 the summary store, times ``_SPAN_BLOCKS``
+    plus its blocks less one; ``seen_ref`` the rows from its first that
+    are inside the mask; a stream's first entry ``first_ref[b]``, its
+    ``count_ref[b]`` entries, the window store's first), fetched by ONE
+    copy a leaf and folded in ONE trip. A copy's size is static, so a
+    trip takes the branch of its span's length: the copy, its wait and
+    the fold all over exactly the span's rows, and no row of a slot
+    that this trip's copy did not write is read. As many spans are in
+    flight as the buffers have slots but one, whatever stream or store
+    the next ones are of. The four stores stay in HBM; a block outside
+    a mask is in no span and is not fetched."""
     b, streams = pl.program_id(0), pl.num_programs(0)
     slots = k_buf.shape[0]
+    sizes = [n * block for n in range(1, _SPAN_BLOCKS + 1)]
+    # a slot is as deep as the longest span the stores can give
+    held_sizes = [size for size in sizes if size <= k_buf.shape[1]]
     heads, rows, lanes = q_ref.shape[1:]
     total = first_ref[streams - 1] + count_ref[streams - 1]
 
-    def copies(i, keys, values):
-        at = pl.ds(pl.multiple_of(block_ref[i] * block, block), block)
+    def copies(i, keys, values, size):
+        at = pl.ds(pl.multiple_of(row_ref[i], block), size)
         slot = i % slots
         return (
             pltpu.make_async_copy(
-                keys.at[stream_ref[i], at], k_buf.at[slot], sem.at[0, slot]),
+                keys.at[stream_ref[i], at], k_buf.at[slot, pl.ds(0, size)],
+                sem.at[0, slot]),
             pltpu.make_async_copy(
-                values.at[stream_ref[i], at], v_buf.at[slot], sem.at[1, slot]),
+                values.at[stream_ref[i], at], v_buf.at[slot, pl.ds(0, size)],
+                sem.at[1, slot]),
         )
 
     def start(i):
-        for store, (keys, values) in enumerate(((wk_ref, wv_ref), (sk_ref, sv_ref))):
-            @pl.when(store_ref[i] == store)
-            def _():
-                for copy in copies(i, keys, values):
+        def begin(keys, values, size):
+            if size <= keys.shape[1]:  # no span is longer than its store
+                for copy in copies(i, keys, values, size):
                     copy.start()
+
+        _by_halves(code_ref[i], [
+            functools.partial(begin, keys, values, size)
+            for keys, values in ((wk_ref, wv_ref), (sk_ref, sv_ref)) for size in sizes])
 
     @pl.when(b == 0)
     def _():
@@ -183,70 +238,91 @@ def _step_kernel(first_ref, count_ref, stream_ref, store_ref, block_ref,
 
     first = first_ref[b]
 
-    def fold(e, carry):
+    def fold(i, size, carry):
+        # a wait reads the semaphore and the span's size, which the two
+        # stores share
+        for copy in copies(i, wk_ref, wv_ref, size):
+            copy.wait()
+        slot, held = i % slots, pl.ds(0, size)
+        mask = jax.lax.broadcasted_iota(jnp.int32, (1, size), 1) < seen_ref[i]
+        out = []
+        for n, state in enumerate(carry):
+            at = pl.ds(n * lanes, lanes)
+            s = jax.lax.dot_general(
+                q_ref[0, n], k_buf[slot, held, at], _NT,
+                preferred_element_type=jnp.float32)
+            out.append(_step_fold(s, mask, state, v_buf, (slot, held, at)))
+        return tuple(out)
+
+    def trip(e, carry):
         i = first + e
 
         @pl.when(i + slots - 1 < total)
         def _():
             start(i + slots - 1)
 
-        # a wait reads the semaphore and the block's size, which the
-        # two stores share
-        for copy in copies(i, wk_ref, wv_ref):
-            copy.wait()
-        slot = i % slots
-        seen = jnp.where(store_ref[i] == 0, win_seen_ref[b], sum_seen_ref[b])
-        mask = block_ref[i] * block + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block), 1) < seen
-        out = []
-        for n, state in enumerate(carry):
-            at = pl.ds(n * lanes, lanes)
-            s = jax.lax.dot_general(
-                q_ref[0, n], k_buf[slot, :, at], _NT,
-                preferred_element_type=jnp.float32)
-            out.append(_step_fold(s, mask, state, v_buf, (slot, slice(None), at)))
-        return tuple(out)
+        return _by_halves(
+            code_ref[i] % _SPAN_BLOCKS,
+            [functools.partial(fold, i, size) for size in held_sizes], carry)
 
     init = (jnp.full((rows, 1), _MASKED, jnp.float32),
             jnp.zeros((rows, 1), jnp.float32),
             jnp.zeros((rows, lanes), jnp.float32))
-    done = jax.lax.fori_loop(0, count_ref[b], fold, (init,) * heads)
+    done = jax.lax.fori_loop(0, count_ref[b], trip, (init,) * heads)
     for n, (_, l, acc) in enumerate(done):
         o_ref[0, n] = acc / l
+
+
+def _span_list(win_seen, sum_seen, depths, block):
+    """The flat list :func:`_step_kernel` walks, ``_AHEAD`` entries
+    longer than the most spans ``depths`` (the window store's rows, the
+    summary store's) can give: ``(first, count)`` a stream and
+    ``(stream, code, row, seen)`` an entry."""
+    bsz = win_seen.shape[0]
+    span = _SPAN_BLOCKS * block
+    held = step_blocks(win_seen, sum_seen, block)
+    in_window, in_summary = step_spans(win_seen, sum_seen, block)
+    count = (in_window + in_summary).astype(jnp.int32)
+    first = jnp.cumsum(count) - count
+    # past the list's end the entries repeat its last, and are not fetched
+    stream = jnp.repeat(
+        jnp.arange(bsz, dtype=jnp.int32), count,
+        total_repeat_length=bsz * sum(-(-rows // span) for rows in depths) + _AHEAD)
+    entry = jnp.minimum(
+        jnp.arange(stream.shape[0], dtype=jnp.int32) - first[stream],
+        count[stream] - 1)
+    store = (entry >= in_window[stream]).astype(jnp.int32)
+    row = (entry - store * in_window[stream]) * span
+    of_store = lambda pair: jnp.where(store == 0, pair[0][stream], pair[1][stream])
+    blocks = jnp.minimum(of_store(held) - row // block, _SPAN_BLOCKS)
+    seen = of_store((win_seen, sum_seen)).astype(jnp.int32) - row
+    return (first, count), (stream, store * _SPAN_BLOCKS + blocks - 1, row, seen)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def _step_fwd(q, stores, win_seen, sum_seen, *, block, interpret):
     """``q`` ``(B, H, rows, D)`` over the four stores ``(B, slots, H *
-    D)``. The flat list of the key blocks inside the masks is made
-    here."""
+    D)``. The flat list of the spans inside the masks is made here."""
     from ray_tpu import sharding as sharding_lib
 
     bsz, heads, rows, lanes = q.shape
-    every = sum(-(-s.shape[1] // block) for s in stores[::2])
-    in_window, in_summary = step_blocks(win_seen, sum_seen, block)
-    count = in_window + in_summary
-    first = jnp.cumsum(count) - count
-    # past the list's end the entries repeat its last, and are not fetched
-    stream = jnp.repeat(
-        jnp.arange(bsz, dtype=jnp.int32), count,
-        total_repeat_length=bsz * every + _AHEAD)
-    entry = jnp.minimum(
-        jnp.arange(stream.shape[0], dtype=jnp.int32) - first[stream],
-        count[stream] - 1)
-    store = (entry >= in_window[stream]).astype(jnp.int32)
-    of_stream = pl.BlockSpec((1, heads, rows, lanes), lambda b, *_: (b, 0, 0, 0))
+    of_stream, entries = _span_list(
+        win_seen, sum_seen, [s.shape[1] for s in stores[::2]], block)
+    per_stream = pl.BlockSpec((1, heads, rows, lanes), lambda b, *_: (b, 0, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    # a slot holds the longest span the stores can give
+    depth = min(_SPAN_BLOCKS * block, max(s.shape[1] for s in stores))
+    slot = (_AHEAD + 1, depth, heads * lanes)
     return pl.pallas_call(
         functools.partial(_step_kernel, block=block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=7,
+            num_scalar_prefetch=6,
             grid=(bsz,),
-            in_specs=[of_stream] + [in_hbm] * 4,
-            out_specs=of_stream,
+            in_specs=[per_stream] + [in_hbm] * 4,
+            out_specs=per_stream,
             scratch_shapes=[
-                pltpu.VMEM((_AHEAD + 1, block, heads * lanes), stores[0].dtype),
-                pltpu.VMEM((_AHEAD + 1, block, heads * lanes), stores[1].dtype),
+                pltpu.VMEM(slot, stores[0].dtype),
+                pltpu.VMEM(slot, stores[1].dtype),
                 pltpu.SemaphoreType.DMA((2, _AHEAD + 1)),
             ],
         ),
@@ -259,8 +335,7 @@ def _step_fwd(q, stores, win_seen, sum_seen, *, block, interpret):
             vmem_limit_bytes=_FRAGMENT_VMEM_BYTES,
         ),
         name="eva_step_attention",
-    )(first.astype(jnp.int32), count, stream, store, entry - store * in_window[stream],
-      win_seen.astype(jnp.int32), sum_seen.astype(jnp.int32), q, *stores)
+    )(*of_stream, *entries, q, *stores)
 
 
 def step_text(q, stores, positions, window: int, chunk: int):
@@ -321,7 +396,8 @@ def step_attention(q, stores, positions, *, window: int, chunk: int,
     pass is the text's; rollout takes no gradient): of the window store
     only the key blocks with a slot at or below ``t mod W`` cross HBM,
     of the summary store only those below ``(W / c) (t // W)``, each
-    once. ``block`` and ``interpret`` are the tests' spellings."""
+    once, in spans of up to ``_SPAN_BLOCKS`` blocks.
+    ``block`` and ``interpret`` are the tests' spellings."""
     if any(s.shape[1] % block for s in stores) or q.shape[-1] != _LANES:
         raise ValueError("stores of whole key blocks and heads of one lane tile")
     return _step_attention(q, tuple(stores), positions, window, chunk, block, interpret)
@@ -378,6 +454,9 @@ def eva_attention(q, k, v, phi, mu, state, rows, *, scale, window: int, chunk: i
             positions, window, chunk, depth, summaries).items():
         stats[f"eva_{store}_key_blocks_skipped"] = skipped
         stats[f"eva_{store}_key_blocks_walked"] = jnp.int32(walked)
+    copies, fetched = step_fetches(positions, window, chunk)
+    stats["eva_step_copies"] = copies.astype(jnp.float32)
+    stats["eva_step_rows_fetched_mean"] = fetched
 
     if t == 1:
         p = positions[:, 0]

@@ -468,10 +468,26 @@ def test_statistics_and_lowering_counter(setup):
     assert float(stats["eva_fragments_crossing_a_window"]) == 2 * crossing == 2 * 4
     # one block a store at this size's 128-row blocks: nothing to skip
     assert float(stats["eva_window_key_blocks_skipped_share"]) == 0.0
+    # a store of this size is one 128-row block, so one span: a step at
+    # each of the update's positions copies a span of the window store,
+    # and one of the summary store once a window has closed (two layers)
+    spans = [1 + (n > 0) for n in pooled]
+    assert float(stats["eva_step_copies"]) == 2 * sum(spans)
+    assert abs(float(stats["eva_step_rows_fetched_mean"]) - 128 * np.mean(spans)) < 1e-3
     assert sorted(stats) == [
         "eva_chunks_summarised", "eva_fragments_crossing_a_window",
+        "eva_step_copies", "eva_step_rows_fetched_mean",
         "eva_summary_key_blocks_skipped_share", "eva_summary_rows_seen_mean",
         "eva_window_key_blocks_skipped_share", "eva_window_rows_seen_mean"]
+
+
+def _poisoned_outside(stores, held, block):
+    """The four stores with NaN in every row of every key block past the
+    ``held`` (window, summary) blocks a stream's masks reach into."""
+    return [
+        jnp.where(jnp.arange(x.shape[1])[None, :, None] >= block * n[:, None, None],
+                  jnp.nan, x)
+        for x, n in zip(stores, (held[0], held[0], held[1], held[1]))]
 
 
 def test_a_one_token_step_fetches_no_block_outside_the_two_masks():
@@ -501,10 +517,7 @@ def test_a_one_token_step_fetches_no_block_outside_the_two_masks():
     held = eva_attention.step_blocks(*seen, block)
     assert [int(x) for x in held[0]] == [1, 1, 2, 1, 1, 2]
     assert [int(x) for x in held[1]] == [0, 0, 0, 1, 2, 4]
-    poisoned = []
-    for store, blocks in zip(stores, (held[0], held[0], held[1], held[1])):
-        slots = jnp.arange(store.shape[1])[None, :, None]
-        poisoned.append(jnp.where(slots >= block * blocks[:, None, None], jnp.nan, store))
+    poisoned = _poisoned_outside(stores, held, block)
     assert bool(jnp.all(jnp.isnan(poisoned[2][0])))  # a stream with no summary yet
     np.testing.assert_array_equal(run(poisoned), got)
     assert bool(jnp.any(jnp.isnan(  # the text multiplies every slot
@@ -519,6 +532,117 @@ def test_a_one_token_step_fetches_no_block_outside_the_two_masks():
     at = jnp.asarray((starts[:, None] + np.arange(640)[None]).ravel())
     exact, pooled = eva_attention.rows_seen(at, 2048, 16)
     assert float(jnp.mean(exact)) == 1024.5 and float(jnp.mean(pooled)) == 256.0
+
+
+# the cell's geometry at a sixteenth: a window of 16 key blocks (of 8 rows
+# for 128), a block of summary rows a window, an episode of ten windows
+SPAN_WINDOW, SPAN_CHUNK, SPAN_BLOCK, SPAN_EPISODE = 128, 16, 8, 10 * 128
+SPAN = eva_attention._SPAN_BLOCKS  # 8: a window is two whole spans
+
+
+@pytest.mark.parametrize("in_window", [0, 7, 8, 23, 31, 32, 55, 63, 64, 127])
+def test_the_span_walk_is_the_text_at_every_span_length(in_window):
+    """Steps at ``t mod W = in_window`` (the cell's 0, 127, 128, 383, 511,
+    512, 895, 1,023, 1,024 and 2,047 at a sixteenth: both edges of a span
+    of one block, of three, four, five, seven and eight, a whole span
+    and a span of one, two whole spans) in the first, second, ninth and
+    tenth window of an episode (summary prefixes of 0, 1, 8 and 9 blocks:
+    no span, a span of one, a whole span, a whole span and a span of
+    one), in the interpreter: the text's, and UNMOVED by NaN in every
+    block outside the two masks, the block right after a mask's last
+    among them. The interpreter's scratch slots hold NaN before the first
+    copy, so a row of a slot that no copy wrote would show in a product
+    as well."""
+    from jax._src.pallas import primitives
+
+    assert bool(jnp.all(jnp.isnan(primitives.uninitialized_value((8, 128), jnp.bfloat16))))
+    rng = np.random.default_rng(41)
+    h, d, b = 2, 128, 4
+    sizes = (SPAN_WINDOW,) * 2 + (SPAN_EPISODE // SPAN_CHUNK,) * 2
+    stores = [jnp.asarray(rng.standard_normal((b, n, h * d)), jnp.bfloat16)
+              for n in sizes]
+    q = jnp.asarray(rng.standard_normal((b, h, d)) * d ** -0.5, jnp.bfloat16)
+    positions = jnp.asarray(
+        [SPAN_WINDOW * n + in_window for n in (0, 1, SPAN, SPAN + 1)], jnp.int32)
+    seen = eva_attention.rows_seen(positions, SPAN_WINDOW, SPAN_CHUNK)
+    held = eva_attention.step_blocks(*seen, SPAN_BLOCK)
+    assert [int(n) for n in held[0]] == [in_window // SPAN_BLOCK + 1] * 4
+    assert [int(n) for n in held[1]] == [0, 1, SPAN, SPAN + 1]
+    spans = eva_attention.step_spans(*seen, SPAN_BLOCK)
+    assert [int(n) for n in spans[0]] == [in_window // (SPAN * SPAN_BLOCK) + 1] * 4
+    assert [int(n) for n in spans[1]] == [0, 1, 1, 2]
+    want = eva_attention.step_text(q, stores, positions, SPAN_WINDOW, SPAN_CHUNK)
+    run = lambda s: eva_attention.step_attention(
+        q, s, positions, window=SPAN_WINDOW, chunk=SPAN_CHUNK, block=SPAN_BLOCK,
+        interpret=True)
+    got = run(stores)
+    np.testing.assert_allclose(got, want, atol=2e-2)
+    poisoned = _poisoned_outside(stores, held, SPAN_BLOCK)
+    # the first row after the summary mask's last block, a stream
+    assert all(bool(jnp.all(jnp.isnan(poisoned[2][n, SPAN_BLOCK * int(held[1][n])])))
+               for n in range(b))
+    np.testing.assert_array_equal(run(poisoned), got)
+    assert bool(jnp.any(jnp.isnan(
+        eva_attention.step_text(q, poisoned, positions, SPAN_WINDOW, SPAN_CHUNK))))
+
+
+@pytest.mark.parametrize("offset", [0, 127, 128, 383, 511, 512, 639])
+def test_the_flat_list_holds_spans_of_one_store_and_one_stream(offset):
+    """The list the kernel walks at the cell's sizes (16 streams 640
+    apart, ``offset`` into their fragments, blocks of 128 in stores of
+    2,048 and 640 rows): a span is one to ``_SPAN_BLOCKS`` blocks of ONE
+    store and ONE stream; a stream's spans tile its two prefixes in
+    order, the window's first, no block twice and none left out; none
+    reaches past its store; the rows it says are inside the mask are the
+    mask's."""
+    window, chunk, block, depths = 2048, 16, 128, (2048, 640)
+    positions = jnp.asarray(640 * np.arange(16) + offset, jnp.int32)
+    seen = eva_attention.rows_seen(positions, window, chunk)
+    held = [np.asarray(n) for n in eva_attention.step_blocks(*seen, block)]
+    (first, count), entries = eva_attention._span_list(*seen, depths, block)
+    first, count = np.asarray(first), np.asarray(count)
+    stream, code, row, rows_in = (np.asarray(x) for x in entries)
+    assert len(stream) == 16 * sum(
+        -(-rows // (SPAN * block)) for rows in depths) + eva_attention._AHEAD
+    assert list(first) == list(np.cumsum(count) - count)
+    for n in range(16):
+        mine = slice(first[n], first[n] + count[n])
+        assert set(stream[mine]) == {n}
+        store, blocks = code[mine] // SPAN, code[mine] % SPAN + 1
+        assert list(store) == sorted(store)  # the window store's spans first
+        for which in (0, 1):
+            of = store == which
+            # whole spans, then one that ends at the mask's last block
+            assert list(row[mine][of]) == [SPAN * block * i for i in range(of.sum())]
+            assert list(blocks[of]) == [SPAN] * (held[which][n] // SPAN) + (
+                [held[which][n] % SPAN] if held[which][n] % SPAN else [])
+            assert np.all(row[mine][of] + block * blocks[of] <= depths[which])
+            assert list(rows_in[mine][of]) == list(
+                int(seen[which][n]) - row[mine][of])
+    # past the list's end: the last entry again
+    total = first[-1] + count[-1]
+    assert set(stream[total:]) == {15} and set(code[total:]) == {code[total - 1]}
+
+
+def test_the_two_fetch_statistics_are_the_masks_arithmetic_at_the_cells_sizes():
+    """``eva_step_copies`` and ``eva_step_rows_fetched_mean`` over every
+    position of the cell's 16 fragments of 640 in an episode of 10,240:
+    2.3 spans a stream and step (1.5 of the window store, 0.8 of the
+    summary store) where a block a trip was 10.5, and 1,344 rows (1,088 +
+    256) of whole key blocks either way."""
+    at = jnp.asarray((640 * np.arange(16)[:, None] + np.arange(640)[None]).ravel())
+    copies, fetched = eva_attention.step_fetches(at, 2048, 16)
+    assert abs(float(copies) / at.size - 2.3) < 1e-6
+    assert float(fetched) == 1344.0
+    seen = eva_attention.rows_seen(at, 2048, 16)
+    spans = eva_attention.step_spans(*seen)
+    assert int(jnp.sum(spans[0])) == 1.5 * at.size
+    assert int(jnp.sum(spans[1])) == 0.8 * at.size
+    blocks = eva_attention.step_blocks(*seen)
+    assert float(jnp.mean(blocks[0] + blocks[1])) == 10.5
+    counted = eva_attention.step_key_blocks(at, 2048, 16, 2048, 640)
+    walked = sum(every - int(skipped) for skipped, every in counted.values())
+    assert walked == 10.5 * at.size == float(fetched) / 128 * at.size
 
 
 def test_the_kernel_is_the_steps_form_where_the_rule_says_so(setup, monkeypatch):

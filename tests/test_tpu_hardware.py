@@ -272,6 +272,34 @@ def test_latent_step_on_tpu(monkeypatch):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-2)
 
 
+def test_eva_step_on_tpu():
+    """The two-store one-token kernel at the EvaByte cell's sizes (16
+    streams at depths ``640 i + s``, 8 heads of 128, stores of 2,048 and
+    640 rows) against the text within bfloat16's rounding, at fragment
+    offsets that give every span length in both stores and both edges of
+    a window; the stores' blocks outside the masks hold NaN, so a block
+    fetched that the masks do not name would show."""
+    from ray_tpu.ops import eva_attention
+
+    b, h, d, window, chunk, summaries = 16, 8, 128, 2048, 16, 640
+    bf = jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    q = (jax.random.normal(keys[0], (b, h, d), jnp.float32) * d ** -0.5).astype(bf)
+    stores = [jax.random.normal(key, (b, rows, h * d), bf)
+              for key, rows in zip(keys[1:], (window, window, summaries, summaries))]
+    for s in (0, 127, 128, 511, 639):
+        positions = 640 * jnp.arange(b, dtype=jnp.int32) + s
+        want = eva_attention.step_text(q, stores, positions, window, chunk)
+        held = eva_attention.step_blocks(*eva_attention.rows_seen(positions, window, chunk))
+        poisoned = [
+            jnp.where(jnp.arange(x.shape[1])[None, :, None]
+                      >= eva_attention.STEP_BLOCK * n[:, None, None], jnp.nan, x)
+            for x, n in zip(stores, (held[0], held[0], held[1], held[1]))]
+        got = jax.jit(lambda q, *x: eva_attention.step_attention(
+            q, x, positions, window=window, chunk=chunk))(q, *poisoned)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-2)
+
+
 def test_fragment_kernel_forced_on_a_refused_shape_raises():
     """A head of 96 is neither whole lane tiles nor a part of one: the
     rule keeps such a layer on the XLA text, and the kernel called for
