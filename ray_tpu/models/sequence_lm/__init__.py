@@ -5,8 +5,9 @@ the key names of the published ``config.json`` each kind comes from;
 ``config.describe`` is the one reader of them.
 
 A block is two sublayers, a MIXER and a FEED-FORWARD, each with its own
-zero-centred RMSNorm ``rms(x) = x * rsqrt(mean(x^2) + eps) * (1 + w)``,
-each applied through the block's RESIDUAL kind; or ONE of the two
+norm (the model's, ``kinds.Norm``: the zero-centred RMSNorm ``rms(x) = x
+* rsqrt(mean(x^2) + eps) * (1 + w)``, or ``phi4flash``'s LayerNorm with a
+bias), each applied through the block's RESIDUAL kind; or ONE of the two
 (``nemotron_h``: every character of ``hybrid_override_pattern`` is a
 block of one sublayer under one norm; the half it lacks is a
 ``NoSublayer``, which has no norm, no leaf and no state, and the block
@@ -14,7 +15,9 @@ runs the half it has). A kind is one frozen
 description with one protocol (``kinds.Kind``), its published source
 and arithmetic on its docstring; ``SequenceLM`` (``model.py``) asks the
 kinds in loops and knows none by name. What is the model's: the pattern
-and its groups, the embedding and the head, the residual's wiring around
+and its groups, the ONE channel between layers besides the stream (what a
+mixer ``exports`` under a name, a later mixer ``imports``:
+docs/policy_state.md, "State that one layer makes and others read"), the embedding and the head, the residual's wiring around
 the two sublayers, the learn form's grouping, and the state tuple.
 
 The Granite multipliers, each applied only where the config states it:
@@ -59,12 +62,14 @@ two levels deep, ``{"layer_0": {"in_proj_qkvz": ...}, ...}``.
 from ray_tpu.models.sequence_lm.config import (
     Segment, attention_layers_of, describe, layer_types_of)
 from ray_tpu.models.sequence_lm.kinds import (
-    AttentionLayer, DeltaNetLayer, DenseLayer, EvaLayer, ExpertLayer, HyperResidual,
-    LatentLayer, MambaLayer, NoSublayer, PlainResidual)
+    AttentionLayer, DeltaNetLayer, DenseLayer, EvaLayer, ExpertLayer, GatedMemoryLayer,
+    HyperResidual, LatentLayer, MambaLayer, Norm, NoSublayer, PlainResidual,
+    SelectiveScanLayer)
 from ray_tpu.models.sequence_lm.model import SequenceLM
 
 __all__ = [
     "SequenceLM", "Segment", "describe", "layer_types_of", "attention_layers_of",
     "AttentionLayer", "LatentLayer", "DeltaNetLayer", "MambaLayer", "EvaLayer",
-    "DenseLayer", "ExpertLayer", "NoSublayer", "PlainResidual", "HyperResidual",
+    "SelectiveScanLayer", "GatedMemoryLayer", "DenseLayer", "ExpertLayer",
+    "NoSublayer", "PlainResidual", "HyperResidual", "Norm",
 ]

@@ -4,19 +4,25 @@ branches on a description's fields, never on a key's presence."""
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, NamedTuple, Tuple
 
 import numpy as np
 
 from ray_tpu.models.sequence_lm.generation import Autoregressive, BlockDiffusion
 from ray_tpu.models.sequence_lm.kinds import (
-    AttentionLayer, DeltaNetLayer, DenseLayer, EvaLayer, ExpertLayer, HyperResidual,
-    LatentLayer, MambaLayer, NoSublayer, PlainResidual)
+    AttentionLayer, DeltaNetLayer, DenseLayer, EvaLayer, ExpertLayer, GatedMemoryLayer,
+    HyperResidual, LatentLayer, MambaLayer, Norm, NoSublayer, PlainResidual,
+    SelectiveScanLayer)
 from ray_tpu.ops import latent_attention
 
 LINEAR, FULL, LATENT = "linear_attention", "full_attention", "latent_attention"
 MAMBA, ATTENTION, SLIDING = "mamba", "attention", "sliding_attention"
 EVA = "eva_attention"
+SCAN, MEMORY, CROSS = "selective_scan", "gated_memory", "cross_attention"
+# what the SambaY exporters hand on, by name: layer L/2's scan output and
+# layer L/2 + 1's key/value cache
+_MEMORY, _KV = "memory", "kv"
 DENSE, EXPERTS = "dense", "experts"
 # the half a block of one sublayer lacks
 NONE = "none"
@@ -62,6 +68,45 @@ def _pattern_of(config: Dict):
     return [_PATTERN[ch] for ch in pattern]
 
 
+def _sambay(config: Dict) -> bool:
+    return config.get("model_type") == "phi4flash"
+
+
+def _sambay_indices(config: Dict) -> Tuple[Tuple[int, ...], int]:
+    """``(the published index of every layer held, the published
+    depth)`` of a ``phi4flash`` config: all ``num_hidden_layers`` of
+    them, or where the depth is cut the ``layer_indices`` list (as many
+    as ``num_hidden_layers``) out of ``published_num_hidden_layers``."""
+    held = int(config["num_hidden_layers"])
+    if "layer_indices" not in config:
+        return tuple(range(held)), held
+    indices = tuple(int(i) for i in config["layer_indices"])
+    depth = int(config["published_num_hidden_layers"])
+    if len(indices) != held or list(indices) != sorted(set(indices)) or not (
+            0 <= indices[0] and indices[-1] < depth):
+        raise ValueError(
+            f"layer_indices {indices}: num_hidden_layers ({held}) rising indices "
+            f"below published_num_hidden_layers ({depth})")
+    return indices, depth
+
+
+def _sambay_kind(i: int, depth: int, period: int) -> str:
+    """The mixer of PUBLISHED layer ``i`` of a decoder-hybrid-decoder of
+    ``depth`` layers (SambaY, arXiv:2507.06607, figure 1; ``period`` is
+    ``mb_per_layer``): the self-decoder, layers up to ``depth / 2``,
+    alternates a selective scan (every ``period``-th layer, ``depth / 2``
+    among them: the one whose output is the memory) with window
+    attention; layer ``depth / 2 + 1`` is the ONE full attention, whose
+    cache the cross-decoder reads; after it gated-memory units (where
+    the self-decoder has its scans) alternate with cross-attention."""
+    scan_place = i % period == 0
+    if i <= depth // 2:
+        return SCAN if scan_place else SLIDING
+    if i == depth // 2 + 1:
+        return ATTENTION
+    return MEMORY if scan_place else CROSS
+
+
 def layer_types_of(config: Dict) -> Tuple[str, ...]:
     """The pattern of mixers: by ``hybrid_override_pattern`` where the
     config has one (``M`` ``"mamba"``, ``*`` ``"attention"``, ``E``
@@ -73,9 +118,14 @@ def layer_types_of(config: Dict) -> Tuple[str, ...]:
     ``sliding_window_layout`` where the config has one (1: a window
     layer, which is also where ``rope_layout`` turns; 0: full depth and
     no positions); else every ``full_attention_interval``-th layer is
-    full attention."""
+    full attention. ``model_type: phi4flash``: :func:`_sambay_kind` of
+    each held layer's published index."""
     if "hybrid_override_pattern" in config:
         return tuple(mixer for mixer, _ in _pattern_of(config))
+    if _sambay(config):  # by each held layer's PUBLISHED index
+        indices, depth = _sambay_indices(config)
+        return tuple(_sambay_kind(i, depth, int(config.get("mb_per_layer", 2)))
+                     for i in indices)
     if config.get("layer_types"):
         # a published list: its first ``num_hidden_layers``
         return tuple(config["layer_types"])[:config.get("num_hidden_layers")]
@@ -127,14 +177,20 @@ def attention_layers_of(config: Dict, layer_types) -> Dict[int, AttentionLayer]:
       family generates by block diffusion (``generation_of``), causal
       otherwise;
     - scale: ``attention_multiplier`` on an ``"attention"`` layer that
-      states one, else ``head^-1/2``."""
+      states one, else ``head^-1/2``;
+    - ``model_type: phi4flash``: NO positions on any layer, the
+      differential form and biases on every one, ``index`` the layer's
+      published index; the ``"attention"`` layer exports its cache and a
+      ``"cross_attention"`` layer reads it."""
     c = config
     out = {}
     plain = _qwen3_moe_stack(c)
+    sambay = _sambay(c)
+    indices = _sambay_indices(c)[0] if sambay else ()
     block = generation_of(c).tokens_per_step
     per_layer = c.get("num_attention_heads_per_layer")
     for i, kind in enumerate(layer_types):
-        if kind not in (FULL, ATTENTION, SLIDING):
+        if kind not in (FULL, ATTENTION, SLIDING, CROSS):
             continue
         heads = int(per_layer[i] if per_layer else c["num_attention_heads"])
         head_dim = int(c.get("head_dim") or int(c["hidden_size"]) // heads)
@@ -153,6 +209,8 @@ def attention_layers_of(config: Dict, layer_types) -> Dict[int, AttentionLayer]:
                                or 0.1 * np.log(float(rope["factor"])) + 1.0)
             elif kind_of != "default":
                 raise ValueError(f"rope_type {kind_of!r} is not supported")
+        elif sambay:
+            pass  # the state-space layers carry the order
         elif kind == FULL:
             rotary = int(head_dim * float(c.get("partial_rotary_factor", 1.0)))
         elif kind == SLIDING:
@@ -161,6 +219,8 @@ def attention_layers_of(config: Dict, layer_types) -> Dict[int, AttentionLayer]:
             raise ValueError('an "attention" layer takes no positions')
         gate = "head" if c.get("gating") else (
             "element" if kind == FULL and not plain else None)
+        if sambay and (heads % 2 or int(c["num_key_value_heads"]) % 2):
+            raise ValueError("differential attention pairs adjacent heads")
         scale = head_dim ** -0.5
         if kind == ATTENTION:
             scale = float(c.get("attention_multiplier", scale))
@@ -168,7 +228,10 @@ def attention_layers_of(config: Dict, layer_types) -> Dict[int, AttentionLayer]:
             kind=kind, heads=heads, kv_heads=int(c["num_key_value_heads"]),
             head_dim=head_dim, scale=scale, window=window, rotary=rotary,
             theta=theta, yarn=yarn, rope_factor=factor, gate=gate,
-            qk_norm=plain or gate is not None, block=block)
+            qk_norm=plain or gate is not None, block=block,
+            diff=sambay, bias=sambay, index=indices[i] if sambay else 0,
+            exports_as=_KV if sambay and kind == ATTENTION else None,
+            source=_KV if kind == CROSS else None)
     return out
 
 
@@ -240,6 +303,18 @@ def _mamba_layer(c: Dict) -> MambaLayer:
     if layer.inner != int(c.get("mamba_expand", 2)) * int(c["hidden_size"]):
         raise ValueError("mamba_n_heads x mamba_d_head is not the inner width")
     return layer
+
+
+def _selective_scan_layer(c: Dict) -> SelectiveScanLayer:
+    """``model_type: phi4flash`` states none of the state-space sizes:
+    Mamba's defaults (``mamba_d_state`` 16, ``mamba_d_conv`` 4,
+    ``mamba_expand`` 2, ``mamba_dt_rank`` ``ceil(hidden / 16)``) unless
+    the config has the key."""
+    d = int(c["hidden_size"])
+    return SelectiveScanLayer(
+        inner=int(c.get("mamba_expand", 2)) * d, state=int(c.get("mamba_d_state", 16)),
+        dt_rank=int(c.get("mamba_dt_rank", -(-d // 16))),
+        conv=int(c.get("mamba_d_conv", 4)))
 
 
 def _expert_layer(c: Dict, experts: int) -> ExpertLayer:
@@ -331,14 +406,19 @@ def describe(config: Dict) -> Dict:
     of one ``stacked`` description is one group ``"layers_<first>_<last>"``,
     every other layer its own ``"layer_<n>"``. The Granite multipliers,
     each 1 where the config states none. ``generation``: how the family
-    generates (:func:`generation_of`)."""
+    generates (:func:`generation_of`). ``norm``: the zero-centred
+    RMSNorm, or ``phi4flash``'s LayerNorm with a bias. A mixer that
+    ``imports`` what no earlier layer ``exports`` is refused by name."""
     c = config
     generation = generation_of(c)
     layer_types = layer_types_of(c)
     layers = len(layer_types)
     attention = attention_layers_of(c, layer_types)
     others = {LINEAR: _deltanet_layer, LATENT: _latent_layer, MAMBA: _mamba_layer,
-              EVA: _eva_layer, NONE: lambda c: NoSublayer()}
+              EVA: _eva_layer, NONE: lambda c: NoSublayer(),
+              SCAN: _selective_scan_layer,
+              MEMORY: lambda c: GatedMemoryLayer(
+                  inner=_selective_scan_layer(c).inner, source=_MEMORY)}
     made = {kind: others[kind](c) for kind in set(layer_types) & set(others)}
     # experts the config counts, under whichever family's key
     experts = int(next(
@@ -378,9 +458,26 @@ def describe(config: Dict) -> Dict:
             for i in range(layers)):
         raise ValueError(
             "a block a step needs every mixer to be full-depth attention")
+    mixers = [attention.get(i) or made[kind] for i, kind in enumerate(layer_types)]
+    if _sambay(c):
+        # the LAST scan of the self-decoder is the one whose output is
+        # the memory (published layer depth / 2)
+        indices, depth = _sambay_indices(c)
+        mixers = [
+            dataclasses.replace(m, exports_as=_MEMORY)
+            if kind == SCAN and i == depth // 2 else m
+            for m, kind, i in zip(mixers, layer_types, indices)]
+    exported = set()
+    for i, mixer in enumerate(mixers):
+        missing = [name for name in mixer.imports if name not in exported]
+        if missing:
+            raise ValueError(
+                f"layer {i} ({layer_types[i]}) reads {missing}, which no layer "
+                "before it exports: the cut leaves an importer without its exporter")
+        exported.update(mixer.exports)
     runs = []
     for i, kind in enumerate(layer_types):
-        mixer, ffn = attention.get(i) or made[kind], ffn_of[ffn_types[i]]
+        mixer, ffn = mixers[i], ffn_of[ffn_types[i]]
         if mixer.stacked and runs and runs[-1][1:3] == [mixer, ffn]:
             runs[-1][3] += 1
         else:
@@ -393,8 +490,10 @@ def describe(config: Dict) -> Dict:
                     mixer, ffn, n) for i, mixer, ffn, n in runs),
         hidden=int(c["hidden_size"]), positions=int(c["max_position_embeddings"]),
         eps=float(next(
-            (c[k] for k in ("rms_norm_eps", "layer_norm_epsilon", "norm_eps") if k in c),
+            (c[k] for k in ("rms_norm_eps", "layer_norm_epsilon", "norm_eps",
+                            "layer_norm_eps") if k in c),
             1e-6)),
+        norm=Norm(bias=_sambay(c)),
         embed_scale=float(c.get("embedding_multiplier", 1.0)),
         logits_scale=float(c.get("logits_scaling", 1.0)),
         tied_head=bool(c.get("tie_word_embeddings", False)))

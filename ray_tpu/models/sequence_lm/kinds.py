@@ -15,7 +15,7 @@ import numpy as np
 
 from ray_tpu.ops import (
     cached_attention, deltanet, eva_attention, hyper_connection, latent_attention, moe,
-    ssd)
+    selective_scan, ssd)
 from ray_tpu.telemetry import metrics
 
 HI = jax.lax.Precision.HIGHEST
@@ -53,6 +53,23 @@ class Kind:
     absent = False
     # where a feed-forward's router reads: None, no router
     route_on = None
+    # the name under which a mixer hands something it made to LATER
+    # layers, and the name of an earlier layer's export it reads
+    # (``SequenceLM._stack`` carries them: docs/policy_state.md, "State
+    # that one layer makes and others read"). A mixer with ``exports``
+    # hands them as the LAST element of its new state, ``{name: value}``,
+    # every leaf a row a stream; one with ``imports`` finds them under
+    # ``ctx["imports"]``
+    exports_as = None
+    source = None
+
+    @property
+    def exports(self):
+        return (self.exports_as,) if self.exports_as else ()
+
+    @property
+    def imports(self):
+        return (self.source,) if self.source else ()
 
     def param_shapes(self, hidden: int):
         """The leaves of the layer's group that are this kind's."""
@@ -72,6 +89,37 @@ def rms(x, weight, eps, centred=True):
     x = x.astype(jnp.float32)
     y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
     return y * ((1.0 + weight) if centred else weight)
+
+
+@dataclasses.dataclass(frozen=True)
+class Norm:
+    """The model's norm, of every sublayer's input and of the stack's
+    output: the zero-centred RMSNorm :func:`rms`, or with ``bias`` a
+    LayerNorm ``(x - mean) * rsqrt(var + eps) * (1 + w) + b`` (the weight
+    stored zero-centred like every other norm's: with seeded weights a
+    reparametrisation of the published ``w``). Float32."""
+
+    bias: bool = False
+
+    def leaves(self, name: str, d: int):
+        """``{leaf: shape}`` of the norm called ``name`` in its group:
+        the weight under the name itself, the bias beside it."""
+        shapes = {name: (d,)}
+        if self.bias:
+            shapes[_bias_of(name)] = (d,)
+        return shapes
+
+    def __call__(self, x, p, name: str, eps: float):
+        if not self.bias:
+            return rms(x, p[name], eps)
+        x = x.astype(jnp.float32)
+        x = x - jnp.mean(x, axis=-1, keepdims=True)
+        y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+        return y * (1.0 + p[name]) + p[_bias_of(name)]
+
+
+def _bias_of(name: str) -> str:
+    return "bias" if name == "weight" else name + "_bias"
 
 
 def l2norm(x, eps=1e-6):
@@ -199,7 +247,31 @@ class AttentionLayer(Kind):
       ``qwen3_moe`` layer (q/k norm, RoPE over the whole head, no gate)
       under a BLOCK-causal mask: a key at position ``p_k`` is seen from
       ``p_q`` iff ``p_k // block <= p_q // block`` (``block`` 1 is
-      causal, every other family's).
+      causal, every other family's);
+    - Phi-4-mini-flash's layers (``model_type: phi4flash``; SambaY, Ren
+      et al., arXiv:2507.06607, with the differential attention of Ye
+      et al., arXiv:2410.05258, as the release's ``modeling_phi4flash.py``
+      pairs it): NO positions, biases on ``W_qkv`` and ``W_o`` (``bias``),
+      and with ``diff`` ADJACENT heads pair: query pair ``j`` is ``(q_2j,
+      q_2j+1)``, key pair ``g = j // (group)`` is ``(k_2g, k_2g+1)`` with
+      the value ``V_g = [v_2g | v_2g+1]``, twice a head wide;
+      ``o_j = softmax(s q_2j k_2g^T) V_g - lambda softmax(s q_2j+1
+      k_2g+1^T) V_g`` under the layer's mask, ``lambda = exp(lq1 . lk1) -
+      exp(lq2 . lk2) + lambda0``, four learned vectors a LAYER,
+      ``lambda0 = 0.8 - 0.6 exp(-0.3 index)`` with ``index`` the layer's
+      PUBLISHED index; ``o_j <- rms(o_j) * w * (1 - lambda0)`` over its
+      ``2 x head`` numbers (a plain weight). Both maps and both value
+      halves come from ONE pass over the cache: a key pair is one key
+      head of ``2 x head`` lanes as it lies in the row, ``q_2j`` enters
+      as ``[q_2j | 0]`` and ``q_2j+1`` as ``[0 | q_2j+1]`` (the zeros
+      select the pair's half in the score product), and the value
+      product is over the pair's whole row: the geometry
+      ``ops/cached_attention`` and its kernels already serve, at twice
+      the score product's operations. With ``exports_as`` the layer's
+      cache is handed to later layers; with ``source`` the layer is a
+      CROSS layer: it has ``W_q`` and ``W_o`` only, reads the cache
+      exported under that name (the token's own row among its rows) and
+      writes nothing.
 
     State: keys and values, ``(rows, kv heads x head)`` in the operands'
     type, one row a position, flat, so that the device tiles (rows, row)
@@ -207,10 +279,13 @@ class AttentionLayer(Kind):
     factor included); a window layer a RING of ``min(window, positions)``
     rows whatever the episode's depth (docs/policy_state.md, "The ring").
 
+    A cross layer has none.
+
     Scopes: projections, norms and RoPE under ``attn`` (``swa`` for a
-    window layer), the cached attention's parts under its ``/scatter``,
-    ``/scores`` and ``/out``, the gate under ``/gate``, the output
-    projection under ``/out``."""
+    window layer, ``xattn`` for a cross layer), the cached attention's
+    parts under its ``/scatter``, ``/scores`` and ``/out``, the gate
+    under ``/gate``, the subtraction, its norm and factor under
+    ``/diff``, the output projection under ``/out``."""
 
     kind: str
     heads: int
@@ -232,18 +307,44 @@ class AttentionLayer(Kind):
     qk_norm: bool = False
     # the mask's rule: positions a block (1: causal)
     block: int = 1
+    # the differential form over pairs of adjacent heads, and the
+    # layer's published index (its ``lambda0``)
+    diff: bool = False
+    index: int = 0
+    # biases on the q/k/v and output projections
+    bias: bool = False
+    # the name its cache is exported under, and the name of the export
+    # a cross layer reads instead of a cache of its own
+    exports_as: Optional[str] = None
+    source: Optional[str] = None
 
+    init_rules = {
+        **{leaf: lambda key, shape: 0.1 * jax.random.normal(key, shape, jnp.float32)
+           for leaf in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")},
+        "diff_norm": lambda key, shape: jnp.ones(shape, jnp.float32),
+    }
     stats = {
         # rows inside the window a query of a window layer saw
         "window_rows_seen_mean": "mean",
         "attn_key_blocks_skipped": "sum", "attn_key_blocks_walked": "sum",
         "attn_decode_key_blocks_skipped": "sum",
         "attn_decode_key_blocks_walked": "sum",
+        "diff_lambda_mean": "mean",
+        # layer-passes that read a cache they do not own, the rows a
+        # query of such a layer saw, and its key blocks in both forms
+        "shared_cache_reads": "sum", "xattn_rows_seen_mean": "mean",
+        "xattn_key_blocks_skipped": "sum", "xattn_key_blocks_walked": "sum",
+        "xattn_decode_key_blocks_skipped": "sum",
+        "xattn_decode_key_blocks_walked": "sum",
     }
 
     @property
     def scope(self) -> str:
-        return "swa" if self.window else "attn"
+        return "xattn" if self.source else "swa" if self.window else "attn"
+
+    @property
+    def lambda0(self) -> float:
+        return 0.8 - 0.6 * float(np.exp(-0.3 * self.index))
 
     @property
     def rope(self) -> str:
@@ -265,9 +366,21 @@ class AttentionLayer(Kind):
             shapes.update(q_norm=(self.head_dim,), k_norm=(self.head_dim,))
         if self.gate == "head":
             shapes["g_proj"] = (d, self.heads)
+        if self.bias:
+            shapes.update({leaf[0] + "_bias": (shapes[leaf][1],)
+                           for leaf in ("q_proj", "k_proj", "v_proj", "o_proj")})
+        if self.diff:
+            shapes.update({f"lambda_{v}": (self.head_dim,)
+                           for v in ("q1", "k1", "q2", "k2")})
+            shapes["diff_norm"] = (2 * self.head_dim,)
+        if self.source:  # a cross layer makes no keys and no values
+            for leaf in ("k_proj", "v_proj", "k_bias", "v_bias"):
+                shapes.pop(leaf, None)
         return shapes
 
     def state_shapes(self, streams: int, positions: int, dtype):
+        if self.source:
+            return []
         shape = (streams, self.cache_rows(positions), self.kv_heads * self.head_dim)
         return [(shape, dtype), (shape, dtype)]
 
@@ -279,15 +392,23 @@ class AttentionLayer(Kind):
         metrics.inc_attention_layer_lowering(self.kind, h, self.rope)
         if self.window is not None:
             metrics.inc_window_cache_lowering("step" if t == 1 else "fragment")
+
+        def projected(leaf, heads):
+            z = dot(x, p[leaf + "_proj"], dtype)
+            if self.bias:
+                z = z + p[leaf + "_bias"]
+            return z.reshape(b, t, heads, -1)
+
         with jax.named_scope(scope):
-            q = dot(x, p["q_proj"], dtype)
+            q = projected("q", h)
             if self.gate == "element":
-                q = q.reshape(b, t, h, 2 * d)
                 q, gate = q[..., :d], q[..., d:]
+            if self.source:
+                # the owner's caches and its keys and values of these tokens
+                state, (k, v) = ctx["imports"][self.source]
             else:
-                q = q.reshape(b, t, h, d)
-            k = dot(x, p["k_proj"], dtype).reshape(b, t, hkv, d)
-            v = dot(x, p["v_proj"], dtype).reshape(b, t, hkv, d)
+                k, v = projected("k", hkv), projected("v", hkv)
+            own = (k, v)
 
             def normed_and_turned(z, norm):
                 if self.qk_norm:
@@ -297,21 +418,55 @@ class AttentionLayer(Kind):
                              self.yarn, self.rope_factor)
                 return z
 
-            q, k = normed_and_turned(q, "q_norm"), normed_and_turned(k, "k_norm")
+            q = normed_and_turned(q, "q_norm")
+            if not self.source:
+                k = normed_and_turned(k, "k_norm")
+            if self.diff:
+                # a pair of heads as ONE key head twice as wide, the pair's
+                # two queries as its heads, each zero outside its own half
+                q = q.reshape(b, t, h // 2, 2, d)
+                none = jnp.zeros_like(q[..., 0, :])
+                q = jnp.stack(
+                    [jnp.concatenate([q[..., 0, :], none], axis=-1),
+                     jnp.concatenate([none, q[..., 1, :]], axis=-1)],
+                    axis=3).reshape(b, t, h, 2 * d)
+                k, v = (z.reshape(b, t, hkv // 2, 2 * d) for z in (k, v))
         o, new, stats = cached_attention.cached_attention(
             q, k, v, state, ctx, scale=self.scale, window=self.window,
-            dtype=dtype, scope=scope, block=self.block)
+            dtype=dtype, scope=scope, block=self.block, scatter=not self.source)
         if ctx.get("keep"):
             new = new + (k, v)
         if "pairs_seen" in stats:
             stats["window_rows_seen_mean"] = stats.pop("pairs_seen") / (b * t)
+        if self.source:
+            new = ()
+            stats = {"x" + k: v for k, v in stats.items()}
+            stats["shared_cache_reads"] = jnp.float32(1.0)
+            stats["xattn_rows_seen_mean"] = jnp.mean(
+                ctx["positions"].astype(jnp.float32)) + 1.0
         if self.gate is not None:
             with jax.named_scope(scope + "/gate"):
                 if self.gate == "head":
                     gate = dot(x, p["g_proj"], dtype)[..., None]  # (B, T, H, 1)
                 o = o * jax.nn.sigmoid(gate)
+        if self.diff:
+            with jax.named_scope(scope + "/diff"):
+                lam = (jnp.exp(jnp.sum(p["lambda_q1"] * p["lambda_k1"]))
+                       - jnp.exp(jnp.sum(p["lambda_q2"] * p["lambda_k2"]))
+                       + self.lambda0)
+                o = o.reshape(b, t, h // 2, 2, 2 * d)
+                o = o[..., 0, :] - lam * o[..., 1, :]
+                o = rms(o, p["diff_norm"], eps, centred=False) * (1.0 - self.lambda0)
+                stats["diff_lambda_mean"] = lam
+        if self.exports_as:
+            # the one-token form: the cache AFTER this token's row was
+            # written; a fragment: the stored rows, and the fragment's own
+            # keys and values beside them
+            step = ctx.get("step", t == 1)
+            new = new + ({self.exports_as: (new[:2] if step else tuple(state), own)},)
         with jax.named_scope(scope + "/out"):
-            return dot(o.reshape(b, t, h * d), p["o_proj"], dtype), new, stats
+            out = dot(o.reshape(b, t, h * d), p["o_proj"], dtype)
+            return (out + p["o_bias"] if self.bias else out), new, stats
 
 
 @dataclasses.dataclass(frozen=True)
@@ -706,6 +861,125 @@ class MambaLayer(Kind):
                     {"ssm_dt_max": jnp.max(dt)})
 
 
+@dataclasses.dataclass(frozen=True)
+class SelectiveScanLayer(Kind):
+    """``"selective_scan"`` (Mamba-1: Gu & Dao, arXiv:2312.00752, as the
+    state-space layers of Samba, arXiv:2406.07522, and of SambaY /
+    ``model_type: phi4flash``, arXiv:2507.06607, hold it): ``[u | z] = h
+    W_in`` (no bias); ``u <- silu(conv(u) + b_conv)``, causal, depthwise,
+    width ``conv``, per episode; ``[r | B | C] = u W_x`` (``dt_rank + 2
+    state`` columns, no bias); ``dt = softplus(r W_dt + b_dt)``, a number
+    a CHANNEL; ``A = -exp(A_log)``, a number a (state, channel); per
+    channel ``c`` and state ``n`` ``S_t[n, c] = exp(dt_t[c] A[n, c])
+    S_{t-1}[n, c] + dt_t[c] B_t[n] u_t[c]``, ``y_t[c] = sum_n S_t[n, c]
+    C_t[n] + D[c] u_t[c]`` (``ops/selective_scan.py``); ``F = (y *
+    silu(z)) W_out``. What Mamba-2 (:class:`MambaLayer`) has and this
+    has not: heads, ONE decay a head, ``B`` / ``C`` groups, a gated norm;
+    what this has and Mamba-2 has not: a low-rank ``dt`` and a decay a
+    (state, channel). Starts as the family's does: ``A`` 1..state a
+    channel, ``D`` one, ``b_dt`` the inverse softplus of a log-uniform
+    step in (0.001, 0.1), ``W_dt`` uniform in ``+-dt_rank^-1/2``.
+
+    With ``exports_as`` the layer hands ``m_t = y_t`` (after the ``D``
+    skip, BEFORE the gate ``silu(z)``, float32) to later layers: SambaY's
+    memory, an ACTIVATION of the same pass, not state.
+
+    State: the ``(state, inner)`` float32 matrix (the channels on the
+    lanes) and the last ``conv - 1`` inputs of the convolution; zeroed at
+    an episode's start. Scopes: ``scan/in`` (``W_in``, and ``W_x``,
+    ``W_dt`` and the softplus after the convolution), ``scan/conv``,
+    ``scan/step`` (the recurrence and the skip), ``scan/out``."""
+
+    inner: int
+    state: int
+    dt_rank: int
+    conv: int
+    exports_as: Optional[str] = None
+
+    cleared_on_reset = True
+    init_rules = {
+        # (state, inner): 1..state down every channel
+        "A_log": lambda key, shape: jnp.broadcast_to(
+            jnp.log(jnp.arange(1, shape[-2] + 1, dtype=jnp.float32))[:, None], shape),
+        "D": _ones, "dt_bias": _inverse_softplus_of_a_step,
+        "dt_proj": lambda key, shape: jax.random.uniform(
+            key, shape, jnp.float32, -1.0, 1.0) * shape[-2] ** -0.5,
+        "conv": lambda key, shape: (
+            jax.random.normal(key, shape, jnp.float32) / np.sqrt(shape[-1])),
+    }
+    # the largest step size a stream saw
+    stats = {"scan_dt_max": "max"}
+
+    def param_shapes(self, d: int):
+        i, n, r = self.inner, self.state, self.dt_rank
+        return dict(
+            in_proj=(d, 2 * i),  # columns [u | z]
+            conv=(i, self.conv), conv_bias=(i,),
+            x_proj=(i, r + 2 * n),  # columns [r | B | C]
+            dt_proj=(r, i), dt_bias=(i,),
+            A_log=(n, i), D=(i,),
+            out_proj=(i, d),
+        )
+
+    def state_shapes(self, streams: int, positions: int, dtype):
+        return [((streams, self.state, self.inner), jnp.float32),
+                ((streams, self.conv - 1, self.inner), jnp.float32)]
+
+    def apply(self, p, x, state, ctx):
+        scope = ctx["scope"] + "scan"
+        dtype = ctx["dtype"]
+        s0, tail = state
+        t = x.shape[1]
+        i, n, r = self.inner, self.state, self.dt_rank
+        with jax.named_scope(scope + "/in"):
+            uz = dot(x, p["in_proj"], dtype)
+            u, z = uz[..., :i], uz[..., i:]
+        with jax.named_scope(scope + "/conv"):
+            u, new_tail = causal_conv(tail, u, ctx["seg"], p["conv"], p["conv_bias"])
+        with jax.named_scope(scope + "/in"):
+            rbc = dot(u, p["x_proj"], dtype)
+            bt, ct = rbc[..., r : r + n], rbc[..., r + n :]
+            dt = jax.nn.softplus(dot(rbc[..., :r], p["dt_proj"], dtype) + p["dt_bias"])
+            a = -jnp.exp(p["A_log"])
+        with jax.named_scope(scope + "/step"):
+            if t == 1:
+                s1, y = selective_scan.selective_step(
+                    s0, u[:, 0], dt[:, 0], a, bt[:, 0], ct[:, 0])
+                y = y[:, None]
+            else:
+                y, s1 = selective_scan.selective_scan(
+                    s0, u, dt, a, bt, ct, ctx["fresh"].astype(jnp.float32))
+            y = y + p["D"] * u
+        new = (s1, new_tail)
+        if self.exports_as:
+            new = new + ({self.exports_as: y},)
+        with jax.named_scope(scope + "/out"):
+            return (dot(y * jax.nn.silu(z), p["out_proj"], dtype), new,
+                    {"scan_dt_max": jnp.max(dt)})
+
+
+@dataclasses.dataclass(frozen=True)
+class GatedMemoryLayer(Kind):
+    """``"gated_memory"`` (SambaY's Gated Memory Unit, Ren et al.,
+    arXiv:2507.06607, section 2): ``F = (m * silu(h W_1)) W_2`` with
+    ``m`` the memory of the SAME token that the layer named ``source``
+    exported (a :class:`SelectiveScanLayer`'s scan output, float32):
+    token-wise, two matrices, no bias, no scan and NO state. Scope:
+    ``gmu``."""
+
+    inner: int
+    source: str
+
+    def param_shapes(self, d: int):
+        return dict(gmu_in=(d, self.inner), gmu_out=(self.inner, d))
+
+    def apply(self, p, x, state, ctx):
+        with jax.named_scope(ctx["scope"] + "gmu"):
+            gate = jax.nn.silu(dot(x, p["gmu_in"], ctx["dtype"]))
+            return dot(ctx["imports"][self.source] * gate, p["gmu_out"],
+                       ctx["dtype"]), (), {}
+
+
 # -- feed-forwards --------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -885,8 +1159,8 @@ NORM_OF = {"mixer": "input_norm", "ffn": "post_norm"}
 
 @dataclasses.dataclass(frozen=True)
 class PlainResidual(Kind):
-    """``"plain"``: ``x <- x + scale * F(rms(x))`` (``scale``: Granite's
-    ``residual_multiplier``). A block's saved input is one hidden row a
+    """``"plain"``: ``x <- x + scale * F(norm(x))`` (``scale``: Granite's
+    ``residual_multiplier``; ``norm`` the model's, :class:`Norm`). A block's saved input is one hidden row a
     token and the batch's fit, so the learn form groups the streams
     inside each block, and there only for the MIXER'S half, whose
     activations are what does not fit: the feed-forward's half is
@@ -905,7 +1179,7 @@ class PlainResidual(Kind):
         return x
 
     def around(self, x, p, sub, f, ctx):
-        y, new, stats = f(rms(x, p[NORM_OF[sub]], ctx["eps"]))
+        y, new, stats = f(ctx["norm"](x, p, NORM_OF[sub], ctx["eps"]))
         return x + (y if self.scale == 1.0 else y * self.scale), new, stats
 
 

@@ -11,7 +11,8 @@ import numpy as np
 
 from ray_tpu.models.sequence_lm.config import Segment, describe
 from ray_tpu.models.sequence_lm.kinds import (
-    HI, NORM_OF, NoSublayer, dot, over_layers, over_streams, rms)
+    HI, NORM_OF, NoSublayer, dot, over_layers, over_streams)
+from ray_tpu.telemetry import metrics
 
 # a stacked run's leaves that enter a bfloat16 product
 _RUN_PRODUCT_LEAVES = ("in_proj", "out_proj", "mlp_gate", "mlp_up", "mlp_down")
@@ -47,7 +48,7 @@ class SequenceLM:
         self.config = dict(config)
         self.vocab = int(num_outputs)
         # layer_types, ffn_types, segments, residual, generation, hidden,
-        # positions, eps, embed_scale, logits_scale, tied_head
+        # positions, eps, norm, embed_scale, logits_scale, tied_head
         vars(self).update(describe(self.config))
         # streams of a fragment batch the learn form runs at once (tests
         # shrink it)
@@ -115,7 +116,7 @@ class SequenceLM:
         d, v = self.hidden, self.vocab
         shapes = {
             "embed": {"embedding": (v, d)},
-            "final_norm": {"weight": (d,)},
+            "final_norm": self.norm.leaves("weight", d),
             "head": {"kernel": (d, v)},
             "value": {"kernel": (d, 1), "bias": (1,)},
         }
@@ -123,7 +124,9 @@ class SequenceLM:
             del shapes["head"]
         for seg in self.segments:
             # a norm for each half the block has
-            layer = {NORM_OF[sub]: (d,) for sub in seg.sublayers}
+            layer = {}
+            for sub in seg.sublayers:
+                layer.update(self.norm.leaves(NORM_OF[sub], d))
             for kind in (seg.ffn, self.residual, seg.mixer):
                 layer.update(kind.param_shapes(d))
             if seg.mixer.stacked:
@@ -272,9 +275,14 @@ class SequenceLM:
                 declared)
             return {**stats, **more, **both}
 
-        def block(x, p, layer_state, rows, mixer, ffn, flags, before=None):
+        def block(x, p, layer_state, rows, imports, mixer, ffn, flags, before=None):
+            # ``imports``: what earlier layers exported, of the names this
+            # mixer reads; an ARGUMENT of the block, so that a
+            # checkpointed block saves it and its gradient flows back to
+            # the exporter
             ctx = dict(rows, scope=prefix, dtype=self.dtype, eps=self.eps,
-                       chunk=self.chunk, **dict(flags))
+                       norm=self.norm, chunk=self.chunk, imports=imports,
+                       **dict(flags))
             if ffn.route_on == "input":
                 # the router reads the layer's input, before the mixer
                 # (``before``: where the mixer's half ran apart, below)
@@ -282,14 +290,16 @@ class SequenceLM:
                 with jax.named_scope(prefix + "moe/route"):
                     ctx["route"] = ffn.route(p, source.reshape(-1, x.shape[-1]))
             # a block of one sublayer runs the half it has
-            new, stats, more = (), {}, {}
+            new, stats, more, exported = (), {}, {}, {}
             if not mixer.absent:
                 x, new, stats = residual.around(
                     x, p, "mixer", lambda h: mixer.apply(p, h, layer_state, ctx), ctx)
+                if mixer.exports:  # the last element of its new state
+                    new, exported = tuple(new[:-1]), new[-1]
             if not ffn.absent:
                 x, _, more = residual.around(
                     x, p, "ffn", lambda h: ffn.apply(p, h, (), ctx), ctx)
-            return x, new, of_halves(stats, more)
+            return x, new, of_halves(stats, more), exported
 
         # the residual says whether the streams are grouped inside each
         # block, here, or around the whole loss (``loss_groups``)
@@ -299,11 +309,11 @@ class SequenceLM:
         ) else 1
         # the learn form keeps a block's input and recomputes the block
         # in the backward pass
-        whole = block if step else jax.checkpoint(block, static_argnums=(4, 5, 6))
+        whole = block if step else jax.checkpoint(block, static_argnums=(5, 6, 7))
 
-        def run_block(x, p, layer_state, rows, mixer, ffn, flags):
+        def run_block(x, p, layer_state, rows, imports, mixer, ffn, flags):
             if groups == 1 or mixer.absent:
-                return whole(x, p, layer_state, rows, mixer, ffn, flags)
+                return whole(x, p, layer_state, rows, imports, mixer, ffn, flags)
             # more streams than the learn form runs at once: the MIXER'S
             # half of the block ``learn_streams`` streams at a time (one
             # group's activations of one mixer are alive, not the
@@ -315,26 +325,35 @@ class SequenceLM:
             split = lambda a: a.reshape((groups, b // groups) + a.shape[1:])
             merge = lambda a: a.reshape((b,) + a.shape[2:])
             before = x
-            x, new, stats = jax.lax.map(
-                lambda xs: whole(xs[0], p, xs[1], xs[2], mixer, no_half, flags),
-                jax.tree_util.tree_map(split, (x, layer_state, rows)),
+            x, new, stats, exported = jax.lax.map(
+                lambda xs: whole(xs[0], p, *xs[1:], mixer, no_half, flags),
+                jax.tree_util.tree_map(split, (x, layer_state, rows, imports)),
             )
-            x, new = merge(x), jax.tree_util.tree_map(merge, new)
+            x, new, exported = merge(x), *jax.tree_util.tree_map(merge, (new, exported))
             stats = over_streams(stats, declared)
             if ffn.absent:
-                return x, new, stats
-            x, _, more = whole(x, p, (), rows, no_half, ffn, flags, before)
-            return x, new, of_halves(stats, more)
+                return x, new, stats, exported
+            x, _, more, _ = whole(x, p, (), rows, {}, no_half, ffn, flags, before)
+            return x, new, of_halves(stats, more), exported
 
+        # what a layer made for later layers to read, by name: the ONE
+        # channel between layers besides the stream (a kind declares
+        # ``exports`` / ``imports``; a stacked run has neither)
+        shared = {}
         state_out, stats, kept = [], {}, []
         for i, (s, leaves) in enumerate(self._by_segment(state)):
             rows = rows_ctx if clean is None else dict(rows_ctx, clean=clean[i])
-            args = (params[s.name], leaves, rows, s.mixer, s.ffn, flags)
+            imports = {name: shared[name] for name in s.mixer.imports}
+            args = (params[s.name], leaves, rows, imports, s.mixer, s.ffn, flags)
             if s.mixer.stacked:
                 x, new, seen = self._run_of_layers(run_block, prefix, x, *args)
             else:
-                x, new, seen = run_block(x, *args)
+                x, new, seen, exported = run_block(x, *args)
                 seen = {k: v[None] for k, v in seen.items()}
+                shared.update(exported)
+                for name in exported:
+                    metrics.inc_shared_state_lowering(name, sum(
+                        name in other.mixer.imports for other in self.segments))
             state_out.extend(new[:len(leaves)])
             kept.append(tuple(new[len(leaves):]))
             for k, v in seen.items():
@@ -346,7 +365,7 @@ class SequenceLM:
         output ``x`` ``(B, T, lanes x D)``."""
         with jax.named_scope(prefix + "head"):
             x = self.residual.leave(x)
-            feat = rms(x, params["final_norm"]["weight"], self.eps)
+            feat = self.norm(x, params["final_norm"], "weight", self.eps)
             feat = feat.reshape(-1, feat.shape[-1])
             if self.tied_head:  # the embedding, contracted over the hidden axis
                 logits = jax.lax.dot_general(
@@ -445,7 +464,8 @@ class SequenceLM:
 
     # -- a run of stacked layers -----------------------------------------
 
-    def _run_of_layers(self, run_block, scope, x, p, run_state, rows, *kinds):
+    def _run_of_layers(self, run_block, scope, x, p, run_state, rows, imports,
+                       *kinds):
         """A run of identical layers whose leaves are stacked on a
         leading layer axis, as ONE ``lax.scan``: the layer is traced
         once (ten layers unrolled compiled for longer than a run of the
@@ -470,8 +490,8 @@ class SequenceLM:
                 # lies); the convolution tail, 4 MB a run, as a slice
                 with jax.named_scope(scope + "ssm/carry"):
                     tail = jax.lax.dynamic_index_in_dim(tails, layer, 1, keepdims=False)
-                x, (matrices, tail), stats = run_block(
-                    x, p_l, ((matrices, layer), tail), rows, *kinds)
+                x, (matrices, tail), stats, _ = run_block(
+                    x, p_l, ((matrices, layer), tail), rows, imports, *kinds)
                 with jax.named_scope(scope + "ssm/carry"):
                     tails = jax.lax.dynamic_update_index_in_dim(
                         tails, tail.astype(tails.dtype), layer, 1)
@@ -482,7 +502,7 @@ class SequenceLM:
 
         def fragment(x, xs):
             p_l, mine = xs
-            x, new, stats = run_block(x, p_l, mine, rows, *kinds)
+            x, new, stats, _ = run_block(x, p_l, mine, rows, imports, *kinds)
             return x, (new, stats)
 
         x, (new, stats) = jax.lax.scan(

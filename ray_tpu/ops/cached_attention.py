@@ -107,13 +107,21 @@ def pairs_seen(see_old, see):
 
 
 def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
-                     block: int = 1):
+                     block: int = 1, scatter: bool = True):
     """Attention of a fragment's ``q`` ``(B, T, heads, D)`` over the
     stored keys and values ``caches`` and the fragment's own ``k``, ``v``
     ``(B, T, kv heads, D)``; ``rows`` holds the fragment's ``seg``,
     ``positions`` ``(B, T)`` and ``pos0`` ``(B,)``. Returns ``(o (B, T,
     heads, D) float32, (keys, values) after the fragment, stats)``, its
     parts under ``scope``'s ``/scatter``, ``/scores`` and ``/out``.
+
+    ``scatter=False``: ``caches`` are ANOTHER layer's, which wrote them
+    (a layer that reads a cache it does not own): nothing is written and
+    the caches come back as they are. In the one-token form they hold the
+    step's own row already (the owner's caches AFTER its scatter) and
+    ``k``, ``v`` are not read; in the fragment form they are the owner's
+    stored rows and ``k``, ``v`` the owner's keys and values of the
+    fragment, whose gradient is then summed over every reader.
 
     ``block`` is the mask's rule: a key is seen from its own block of
     that many positions and from every later one (1: causal). With it
@@ -160,9 +168,12 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
     if ring and (block > 1 or t > 1 and step):
         raise ValueError("a ring cache has no block rule")
 
-    with part("scatter"):
-        new_k = scatter_rows(k_cache, k.reshape(b, t, hkv * d), rows, ring)
-        new_v = scatter_rows(v_cache, v.reshape(b, t, hkv * d), rows, ring)
+    if scatter:
+        with part("scatter"):
+            new_k = scatter_rows(k_cache, k.reshape(b, t, hkv * d), rows, ring)
+            new_v = scatter_rows(v_cache, v.reshape(b, t, hkv * d), rows, ring)
+    else:
+        new_k, new_v = k_cache, v_cache
 
     qh = (q * scale).astype(dtype).reshape(b, t, hkv, h // hkv, d)
     step_kernel = not ring and flash_attention.step_kernel_applies(

@@ -158,6 +158,19 @@ WINDOW_CACHE_LOWERINGS_TOTAL = "ray_tpu_window_cache_lowerings_total"
 # states, the summaries it completes made inside it: the learn form).
 # Counted when the form is traced: once per EVA layer body of a program
 EVA_LOWERINGS_TOTAL = "ray_tpu_eva_lowerings_total"
+# which form each traced selective scan took (ops/selective_scan.py, a
+# Mamba-1 layer's recurrence): form = step (one token, state in and
+# out) | fragment (a fragment from a stored state, the state on a
+# scan's carry, a chunk of tokens under one checkpoint) | kernel (none
+# yet: the name a kernel's lowering would count under). Counted when the
+# form is traced: once per scan body of a program
+SELECTIVE_SCAN_LOWERINGS_TOTAL = "ray_tpu_selective_scan_lowerings_total"
+# state or an activation that ONE layer makes and later layers read
+# (models/sequence_lm/model.py, the layer loop's export / import
+# channel): name = what was exported ("kv": a key/value cache, "memory":
+# a scan's output), readers = the layers of the traced stack that import
+# it. Counted when a stack is traced: once per export of a program
+SHARED_STATE_LOWERINGS_TOTAL = "ray_tpu_shared_state_lowerings_total"
 # which lowering each traced attention layer's fragment form took
 # (ops/cached_attention.cached_attention, every softmax attention kind
 # over a stored cache, and ops/latent_attention over its latent rows): path =
@@ -758,6 +771,25 @@ def inc_eva_lowering(form: str) -> None:
     ).inc(1.0, {"form": form})
 
 
+def inc_selective_scan_lowering(form: str) -> None:
+    """One traced selective scan took ``form`` (``step`` | ``fragment``
+    | ``kernel``)."""
+    counter(
+        SELECTIVE_SCAN_LOWERINGS_TOTAL,
+        "selective scans traced, by the form they took",
+        ("form",),
+    ).inc(1.0, {"form": form})
+
+
+def inc_shared_state_lowering(name: str, readers: int) -> None:
+    """One traced stack exported ``name`` to ``readers`` later layers."""
+    counter(
+        SHARED_STATE_LOWERINGS_TOTAL,
+        "exports of one layer's state or activation to later layers, traced",
+        ("name", "readers"),
+    ).inc(1.0, {"name": name, "readers": str(int(readers))})
+
+
 def inc_attention_layer_lowering(kind: str, heads: int, rope: str) -> None:
     """One traced softmax-attention layer body of this geometry."""
     counter(
@@ -833,6 +865,18 @@ def window_cache_lowerings() -> Dict[str, float]:
 def eva_lowerings() -> Dict[str, float]:
     """``{form: traced EVA attention layers}`` since the process began."""
     return _totals_by_tag(EVA_LOWERINGS_TOTAL, "form")
+
+
+def selective_scan_lowerings() -> Dict[str, float]:
+    """``{form: traced selective scans}`` since the process began."""
+    return _totals_by_tag(SELECTIVE_SCAN_LOWERINGS_TOTAL, "form")
+
+
+def shared_state_lowerings() -> Dict[str, float]:
+    """``{"<name>/<readers>": traced exports}`` since the process began."""
+    m = get_metric(SHARED_STATE_LOWERINGS_TOTAL)
+    return {} if m is None else {
+        "{name}/{readers}".format(**dict(tags)): v for tags, v in m.series()}
 
 
 def deltanet_step_lowerings() -> Dict[str, float]:
