@@ -161,9 +161,10 @@ EVA_LOWERINGS_TOTAL = "ray_tpu_eva_lowerings_total"
 # which form each traced selective scan took (ops/selective_scan.py, a
 # Mamba-1 layer's recurrence): form = step (one token, state in and
 # out) | fragment (a fragment from a stored state, the state on a
-# scan's carry, a chunk of tokens under one checkpoint) | kernel (none
-# yet: the name a kernel's lowering would count under). Counted when the
-# form is traced: once per scan body of a program
+# scan's carry, a chunk of tokens under one checkpoint: XLA's text, the
+# CPU's and odd sizes') | kernel (a fragment on the Pallas kernels, a
+# tile of channels in VMEM over the fragment's tokens: a TPU's). Counted
+# when the form is traced: once per scan body of a program
 SELECTIVE_SCAN_LOWERINGS_TOTAL = "ray_tpu_selective_scan_lowerings_total"
 # state or an activation that ONE layer makes and later layers read
 # (models/sequence_lm/model.py, the layer loop's export / import

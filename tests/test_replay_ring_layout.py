@@ -686,6 +686,59 @@ def test_v5e_eva_step_kernel_compiles(v5e_mesh):
     assert not re.search(rf"f32\[[0-9,]*({window}|{summaries})\]", text)
 
 
+def test_v5e_selective_scan_kernels_compile(v5e_mesh):
+    """The fragment-form selective scan (ops/selective_scan.py) at the
+    Phi-4-mini-flash cell's size (16 streams x 256 tokens, 16 states x
+    5,120 channels) under ``shard_map`` and a block's ``jax.checkpoint``,
+    value and gradient of every operand: Mosaic takes the forward kernel
+    (the pass and its recomputation) and the backward kernel, all under
+    the caller's ``learn/scan/step`` scope, where the trace files their
+    time (perf/layer_metrics/scan.fragment_hbm_roofline_pct.py), and no
+    buffer of a fragment's states ``(16, 256, 16, 5120)`` nor of its
+    chunks' starts exists."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.ops import selective_scan
+
+    b, t, n, c = 16, 256, 16, 5120
+    axis = sharding_lib.data_axis(v5e_mesh)
+    rows = sharding_lib.batch_sharded(v5e_mesh)
+    on = lambda *shape: jax.ShapeDtypeStruct(shape, np.float32, sharding=rows)
+
+    def loss(state, u, dt, a, bb, cc, resets):
+        @jax.checkpoint
+        def block(state, u, dt, a, bb, cc):
+            with jax.named_scope("learn/scan/step"):
+                y, after = selective_scan.selective_scan_kernel(
+                    state, u, dt, a, bb, cc, resets)
+            return jnp.tanh(y) * u, after
+
+        y, after = block(state, u, dt, sharding_lib.varying(a, axis), bb, cc)
+        return jnp.sum(y) + jnp.sum(after)
+
+    def value_and_grad(*operands):
+        value, grads = jax.value_and_grad(loss, argnums=tuple(range(6)))(*operands)
+        return value[None], grads
+
+    sharded = jax.shard_map(
+        value_and_grad, mesh=v5e_mesh,
+        in_specs=(P(axis), P(axis), P(axis), P(), P(axis), P(axis), P(axis)),
+        out_specs=(P(axis), (P(axis),) * 6))
+    compiled = jax.jit(sharded).lower(
+        on(b, n, c), on(b, t, c), on(b, t, c), _on(v5e_mesh, (n, c), np.float32),
+        on(b, t, n), on(b, t, n), on(b, t)).compile()
+    text = compiled.as_text()
+    calls = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "custom-call(" in line
+    ]
+    assert len(calls) == 3 and all("learn/scan/step" in line for line in calls)
+    assert sum("selective_scan_fwd" in line for line in calls) == 2
+    assert sum("selective_scan_bwd" in line for line in calls) == 1
+    assert not re.search(rf"f32\[{b},({t}|{t // 16}),{n},{c}\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+
+
 def test_v5e_tree_update_pays_for_its_levels(v5e_mesh):
     """The DQN cell's priority refresh (ops/segment_tree.py: an
     (8, 512) update of a 131,072-leaf f64 tree pair) as the chip's

@@ -300,6 +300,38 @@ def test_eva_step_on_tpu():
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-2)
 
 
+def test_selective_scan_on_tpu():
+    """The fragment-form selective scan at the Phi-4-mini-flash cell's
+    sizes (16 streams x 256 tokens, 16 states x 5,120 channels, a reset
+    a stream but the first) as the dispatch runs it on the chip: the
+    call takes the kernels, ``y`` and the state after are the text's to
+    float32 rounding, and so is every cotangent."""
+    from ray_tpu.ops import selective_scan
+    from ray_tpu.telemetry import metrics
+
+    b, t, n, c = 16, 256, 16, 5120
+    keys = jax.random.split(jax.random.PRNGKey(0), 7)
+    normal = lambda k, *shape: jax.random.normal(keys[k], shape, jnp.float32)
+    at = jax.random.randint(keys[6], (b,), 0, t)
+    resets = jnp.zeros((b, t), jnp.float32).at[jnp.arange(1, b), at[1:]].set(1.0)
+    ops = (normal(0, b, n, c), normal(1, b, t, c),
+           jax.nn.softplus(normal(2, b, t, c) - 4.0), -jnp.exp(normal(3, n, c) * 0.5),
+           normal(4, b, t, n), normal(5, b, t, n), resets)
+
+    def scalar(scan):
+        def of(*ops):
+            y, after = scan(*ops)
+            return jnp.sum(y * y) + jnp.sum(after * after)
+        return jax.jit(jax.value_and_grad(of, argnums=tuple(range(6))))
+
+    before = metrics.selective_scan_lowerings().get("kernel", 0)
+    got = scalar(selective_scan.selective_scan)(*ops)
+    assert metrics.selective_scan_lowerings()["kernel"] == before + 1
+    want = scalar(selective_scan._scan_text)(*ops)
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w)) < 1e-5
+
+
 def test_fragment_kernel_forced_on_a_refused_shape_raises():
     """A head of 96 is neither whole lane tiles nor a part of one: the
     rule keeps such a layer on the XLA text, and the kernel called for
