@@ -76,11 +76,7 @@ Flags:  --profile       run ONE telemetry-instrumented PPO iteration
                         over REAL sockets, sweeping client counts —
                         throughput + p50/p99 per path, bitwise
                         response parity, zero recompiles in the timed
-                        window — plus the AOT cold-start A/B: fresh-
-                        replica warmup wall + time-to-first-response
-                        with an empty vs warm compile cache (warm =
-                        ZERO fresh compiles, test-asserted); writes
-                        benchmarks/e2e/ingress_ab.json
+                        window; writes benchmarks/e2e/ingress_ab.json
         --flood         OPEN-loop flood harness for the horizontal
                         front door (docs/serving.md "Scaling the
                         front door"): Poisson + recorded-burst
@@ -108,9 +104,9 @@ Flags:  --profile       run ONE telemetry-instrumented PPO iteration
                         full rendezvous → epoch → lockstep-learn
                         protocol — steps/s by fleet size, drain
                         (noticed) vs kill (heartbeat) recovery wall,
-                        and the resize wall with a pre-seeded AOT
-                        cache vs cold (warm resize = zero fresh
-                        compiles); writes benchmarks/e2e/fleet.json
+                        and the resize wall (reshard + the twin's
+                        first learn step, compile included); writes
+                        benchmarks/e2e/fleet.json
         --fleet-chaos   control-plane failover lane (docs/fleet.md
                         "failure model & leadership"): coordinator
                         kill → fenced standby takeover → failover
@@ -1772,7 +1768,6 @@ def bench_fleet_worker():
     rank = int(os.environ["RAY_TPU_PROCESS_ID"])
     world = int(os.environ["RAY_TPU_NUM_PROCESSES"])
     mode = os.environ.get("RAY_TPU_FLEET_BENCH_MODE", "drain")
-    aot_root = os.environ.get("RAY_TPU_FLEET_BENCH_AOT", "")
     if world > 1:
         dist.initialize()
 
@@ -1798,10 +1793,6 @@ def bench_fleet_worker():
         "lr": 3e-4,
         "seed": 0,
     }
-    if aot_root:
-        config["aot_cache_dir"] = os.path.join(
-            aot_root, f"rank{rank}"
-        )
     obs_space = gym.spaces.Box(-1.0, 1.0, (16,), np.float32)
     act_space = gym.spaces.Discrete(4)
     policy = PPOJaxPolicy(obs_space, act_space, config)
@@ -1878,7 +1869,6 @@ def bench_fleet_worker():
                     "mode": "kill",
                     "steps_per_s": round(steps_per_s, 1),
                     "recovery_wall_s": round(recovery_wall, 3),
-                    "resize_aot_source": fn.aot_source,
                     "resize_traces": fn.traces,
                 }
             )
@@ -1922,7 +1912,6 @@ def bench_fleet_worker():
                 "steps_per_s": round(steps_per_s, 1),
                 "recovery_wall_s": round(recovery_wall, 3),
                 "resize_wall_s": round(resize_wall, 3),
-                "resize_aot_source": fn.aot_source,
                 "resize_traces": fn.traces,
             }
         )
@@ -1940,24 +1929,21 @@ def bench_fleet(out_path=None):
       - steps/s at hosts ∈ {1, 2} and the DCN scaling efficiency;
       - drain (provider-noticed) vs kill (heartbeat-detected)
         recovery wall: notice/death → first post-resize step done;
-      - the resize wall with a pre-seeded AOT cache vs cold — the
-        warm-cache-restart headline (warm resize performs zero fresh
-        compiles; `resize_traces` in the JSON asserts it).
+      - the resize wall: the reshard onto the survivor mesh plus the
+        twin's first learn step, its compile included
+        (`resize_traces` in the JSON counts it).
 
     Writes benchmarks/e2e/fleet.json."""
     import os
-    import shutil
     import socket
     import subprocess
-    import tempfile
 
     from ray_tpu.fleet import KVServer
 
     os.makedirs("benchmarks/e2e", exist_ok=True)
     out_path = out_path or "benchmarks/e2e/fleet.json"
-    aot_root = tempfile.mkdtemp(prefix="ray_tpu_fleet_bench_aot_")
 
-    def run(world, mode="drain", preseed=True):
+    def run(world, mode="drain"):
         kv = KVServer(host="127.0.0.1")
         with socket.socket() as s:
             s.bind(("127.0.0.1", 0))
@@ -1969,8 +1955,6 @@ def bench_fleet(out_path=None):
             "RAY_TPU_NUM_PROCESSES": str(world),
             "RAY_TPU_KV_ADDRESS": f"127.0.0.1:{kv.port}",
             "RAY_TPU_FLEET_BENCH_MODE": mode,
-            "RAY_TPU_FLEET_BENCH_AOT": aot_root if preseed else "",
-            "RAY_TPU_FLEET_PRESEED": "1" if preseed else "0",
         }
         if world > 1:
             env_base["RAY_TPU_COORDINATOR"] = (
@@ -2009,35 +1993,25 @@ def bench_fleet(out_path=None):
         raise RuntimeError(f"no FLEETBENCH line:\n{outs[0]}")
 
     one = run(world=1)
-    warm = run(world=2, mode="drain", preseed=True)
-    cold = run(world=2, mode="drain", preseed=False)
-    kill = run(world=2, mode="kill", preseed=True)
-    shutil.rmtree(aot_root, ignore_errors=True)
+    drain = run(world=2, mode="drain")
+    kill = run(world=2, mode="kill")
 
     report = {
         "metric": "fleet_elastic_learner_mesh",
         "steps_per_s_by_hosts": {
             "1": one["steps_per_s"],
-            "2": warm["steps_per_s"],
+            "2": drain["steps_per_s"],
         },
         # 2 hosts double the devices over a CPU "DCN": efficiency is
         # steps/s parity at the SAME global batch (weak scaling of
         # the collective, not more throughput)
         "dcn_scaling_efficiency": round(
-            warm["steps_per_s"] / one["steps_per_s"], 3
+            drain["steps_per_s"] / one["steps_per_s"], 3
         ),
-        "drain_recovery_wall_s": warm["recovery_wall_s"],
+        "drain_recovery_wall_s": drain["recovery_wall_s"],
         "kill_recovery_wall_s": kill["recovery_wall_s"],
-        "resize_wall_s": {
-            "preseeded_aot": warm["resize_wall_s"],
-            "cold": cold["resize_wall_s"],
-        },
-        "resize_speedup_from_preseed": round(
-            cold["resize_wall_s"] / max(warm["resize_wall_s"], 1e-9),
-            2,
-        ),
-        "warm_resize_fresh_compiles": warm["resize_traces"],
-        "warm_resize_aot_source": warm["resize_aot_source"],
+        "resize_wall_s": drain["resize_wall_s"],
+        "resize_traces": drain["resize_traces"],
         "config": {
             "world": 2,
             "devices_per_host": 2,
@@ -2050,8 +2024,7 @@ def bench_fleet(out_path=None):
             "a socket round trip, so 2-host steps/s measures the "
             "protocol's lockstep correctness, not DCN bandwidth — "
             "the scaling headline belongs to the TPU round; the "
-            "portable numbers here are the recovery walls and the "
-            "preseed speedup"
+            "portable numbers here are the recovery walls"
         ),
     }
     with open(out_path, "w") as f:
@@ -2789,14 +2762,9 @@ def bench_ingress(
         SAME checkpoint — requests coalesce across connections into
         power-of-two buckets before dispatch.
 
-    Plus the AOT cold-start A/B: a fresh replica's warmup wall and
-    time-to-first-response with an empty compile cache (live XLA
-    compiles, which also SEED the cache) vs a warm one (every bucket
-    restored from disk — zero fresh compiles, trace-count-asserted).
-
     Acceptance (ISSUE 14): ingress throughput >= 4x per-request at
     32 clients, bitwise response parity, 0 recompiles in the timed
-    window, AOT cold start with 0 fresh compiles of cached buckets.
+    window.
     Writes benchmarks/e2e/ingress_ab.json."""
     import os
     import shutil
@@ -2817,7 +2785,6 @@ def bench_ingress(
         policy_deployment,
         restore_policy,
     )
-    from ray_tpu.sharding.aot import AOTCompileCache
     from ray_tpu.sharding.compile import compile_stats
 
     out_path = out_path or "benchmarks/e2e/ingress_ab.json"
@@ -2991,57 +2958,6 @@ def bench_ingress(
         for a, b in zip(ingress_results, naive_results)
     )
 
-    # -- AOT cold-start A/B ------------------------------------------
-    def cold_start(cache, name):
-        p, pr, fl, _ = restore_policy(ckpt_root)
-        srv = BatchedPolicyServer(
-            p,
-            name=name,
-            max_batch_size=max_batch_size,
-            explore=False,
-            obs_filter=fl,
-            preprocessor=pr,
-            aot_cache=cache,
-            start=False,
-        )
-        t0 = time.perf_counter()
-        srv.warmup()
-        warmup_s = time.perf_counter() - t0
-        srv.start()
-        t0 = time.perf_counter()
-        srv.submit(obs_stream[0]).result(120.0)
-        first_response_s = time.perf_counter() - t0
-        fresh_compiles = sum(
-            fn.traces for fn in srv._fns.values()
-        )
-        sources = sorted(
-            {fn.aot_source for fn in srv._fns.values()}
-        )
-        srv.stop()
-        return {
-            "warmup_s": round(warmup_s, 4),
-            "first_response_s": round(first_response_s, 5),
-            "fresh_compiles": fresh_compiles,
-            "sources": sources,
-        }
-
-    cache = AOTCompileCache(os.path.join(workdir, "aot_cache"))
-    # cold replica, empty cache: live AOT compiles seed the cache
-    cold_live = cold_start(cache, "bench_cold")
-    cache.flush()
-    # fresh replica, warm cache: every bucket restores from disk
-    cold_aot = cold_start(cache, "bench_cold")
-    cache.stop()
-    aot_ab = {
-        "live": cold_live,
-        "aot_cache": cold_aot,
-        "warmup_speedup": round(
-            cold_live["warmup_s"]
-            / max(cold_aot["warmup_s"], 1e-9),
-            2,
-        ),
-    }
-
     curve = [
         {
             "clients": c,
@@ -3071,17 +2987,12 @@ def bench_ingress(
         },
         "recompiles_in_timed_window": recompiles,
         "parity_bitwise": parity,
-        "aot_cold_start": aot_ab,
         "criteria": {
             "speedup_ge_4x_at_32_clients": all(
                 e["speedup"] >= 4.0 for e in wide
             ),
             "zero_recompiles": recompiles == 0,
             "parity_bitwise": parity,
-            "aot_cold_start_zero_fresh_compiles": (
-                cold_aot["fresh_compiles"] == 0
-                and cold_aot["sources"] == ["aot_cache"]
-            ),
         },
     }
     shutil.rmtree(workdir, ignore_errors=True)
@@ -3110,9 +3021,9 @@ def bench_flood(out_path=None, smoke=False):
       - at 2x the knee EVERY response must be a 200-inside-deadline,
         429 (inflight/quota), 503 (queue-wait shed) or 504 (deadline)
         — never a hang, never a 200 past its deadline;
-      - both configs serve the SAME checkpoint from a pre-seeded AOT
-        cache (fixed-seed obs stream, bitwise parity across configs,
-        zero fresh compiles per worker, heartbeat-asserted).
+      - both configs serve the SAME checkpoint (fixed-seed obs
+        stream, bitwise parity across configs, zero recompiles per
+        worker after its warmup, heartbeat-asserted).
 
     Each config is a real ``IngressSupervisor`` bank on one shared
     port (SO_REUSEPORT where available). Writes
@@ -3137,7 +3048,6 @@ def bench_flood(out_path=None, smoke=False):
     out_path = out_path or "benchmarks/e2e/flood.json"
     workdir = tempfile.mkdtemp(prefix="flood_bench_")
     ckpt_root = os.path.join(workdir, "ckpts")
-    cache_dir = os.path.join(workdir, "aot_cache")
     repo = os.path.dirname(os.path.abspath(__file__))
     max_batch_size = 16
 
@@ -3162,15 +3072,13 @@ def bench_flood(out_path=None, smoke=False):
         run_recorded = True
         parity_n = 64
 
-    # the checkpoint + AOT cache are built in a SUBPROCESS so this
-    # process never initializes the XLA client before forking worker
-    # banks (fork-after-jax-init is the classic deadlock); workers
-    # restore every bucket from the warm cache — zero fresh compiles
+    # the checkpoint is built in a SUBPROCESS so this process never
+    # initializes the XLA client before forking worker banks
+    # (fork-after-jax-init is the classic deadlock)
     seed_code = (
-        "import json, sys\n"
+        "import sys\n"
         "from ray_tpu.algorithms.ppo.ppo import PPO\n"
-        "ckpt, cache_dir, mbs = (\n"
-        "    sys.argv[1], sys.argv[2], int(sys.argv[3]))\n"
+        "ckpt = sys.argv[1]\n"
         "cfg = {'env': 'CartPole-v1', 'seed': 0, 'num_workers': 0,\n"
         "       'train_batch_size': 64, 'sgd_minibatch_size': 64,\n"
         "       'num_sgd_iter': 1, 'lr': 3e-4,\n"
@@ -3178,18 +3086,6 @@ def bench_flood(out_path=None, smoke=False):
         "algo = PPO(config=cfg)\n"
         "algo.save(ckpt)\n"
         "algo.cleanup()\n"
-        "from ray_tpu.serve.policy_server import (\n"
-        "    BatchedPolicyServer, restore_policy)\n"
-        "from ray_tpu.sharding.aot import AOTCompileCache\n"
-        "p, prep, filt, _ = restore_policy(ckpt)\n"
-        "cache = AOTCompileCache(cache_dir)\n"
-        "srv = BatchedPolicyServer(\n"
-        "    p, name='flood', max_batch_size=mbs, explore=False,\n"
-        "    obs_filter=filt, preprocessor=prep, aot_cache=cache,\n"
-        "    start=False)\n"
-        "srv.warmup()\n"
-        "cache.flush()\n"
-        "srv.stop()\n"
     )
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
@@ -3198,7 +3094,6 @@ def bench_flood(out_path=None, smoke=False):
         [
             sys.executable, "-c", seed_code,
             os.path.join(ckpt_root, "checkpoint_000001"),
-            cache_dir, str(max_batch_size),
         ],
         check=True, env=env, cwd=repo,
     )
@@ -3214,17 +3109,15 @@ def bench_flood(out_path=None, smoke=False):
 
     def worker_init(ctx):
         # runs INSIDE each forked ingress worker: full replica stack
-        # per process, restored from the shared checkpoint + cache
+        # per process, restored from the shared checkpoint
         from ray_tpu.ingress import CoalescingRouter, LocalReplica
         from ray_tpu.serve.policy_server import (
             BatchedPolicyServer,
             restore_policy,
         )
-        from ray_tpu.sharding.aot import AOTCompileCache
         from ray_tpu.sharding.compile import compile_stats
 
         policy, prep, obs_filter, _ = restore_policy(ckpt_root)
-        cache = AOTCompileCache(cache_dir)
         server = BatchedPolicyServer(
             policy,
             name="flood",
@@ -3233,7 +3126,6 @@ def bench_flood(out_path=None, smoke=False):
             explore=False,
             obs_filter=obs_filter,
             preprocessor=prep,
-            aot_cache=cache,
             start=False,
         )
         server.warmup()
@@ -3246,16 +3138,10 @@ def bench_flood(out_path=None, smoke=False):
         )
         ctx.ingress.add_policy("flood", router)
         traces0 = compile_stats()["traces"]
-        fresh0 = sum(fn.traces for fn in server._fns.values())
-        sources = sorted(
-            {fn.aot_source for fn in server._fns.values()}
-        )
 
         def extra_stats():
             return {
                 "recompiles": compile_stats()["traces"] - traces0,
-                "warmup_fresh_compiles": fresh0,
-                "aot_sources": sources,
             }
 
         ctx.ingress.extra_stats = extra_stats
@@ -3580,11 +3466,6 @@ def bench_flood(out_path=None, smoke=False):
     zero_recompiles = bool(all_extras) and all(
         e["recompiles"] == 0 for e in all_extras
     )
-    aot_warm = bool(all_extras) and all(
-        e["warmup_fresh_compiles"] == 0
-        and e["aot_sources"] == ["aot_cache"]
-        for e in all_extras
-    )
     report = {
         "metric": "ingress_flood",
         "smoke": smoke,
@@ -3616,7 +3497,6 @@ def bench_flood(out_path=None, smoke=False):
             ),
             "parity_bitwise": parity,
             "zero_recompiles": zero_recompiles,
-            "aot_warm_start_all_workers": aot_warm,
             "scaleout_knee_ge_2p5x": scale_ratio >= 2.5,
         },
         "caveats": [
@@ -4066,7 +3946,7 @@ def bench_observability(
             "steady-state ledger hooks are timestamps + dict "
             "bumps per dispatch/drain; the cost/memory analysis "
             "pays ONE extra AOT compile per traced signature "
-            "(jit execution cache and AOT cache are disjoint), "
+            "(jit's execution cache does not serve lower().compile()), "
             "visible in analysis_compile_s, never per step"
         ),
     }

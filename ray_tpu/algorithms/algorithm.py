@@ -241,16 +241,12 @@ class Algorithm(Trainable):
                 ),
             )
         # the compiled-program registry (sharding/registry.py): every
-        # executable this config lowers, predicted up-front — AOT
-        # pre-seeding, warmup and dispatch-diet coverage all walk this
-        # one list (tests/test_dispatch_diet.py asserts completeness).
-        # With an AOT cache configured, sweep the warmable specs now so
-        # a restarted driver seeds its executables before train().
+        # executable this config lowers, predicted up-front — warmup
+        # and dispatch-diet coverage walk this one list
+        # (tests/test_dispatch_diet.py asserts completeness).
         from ray_tpu.sharding import registry as registry_lib
 
         self.program_registry = registry_lib.for_algorithm(self)
-        if config.get("aot_cache_dir"):
-            self.program_registry.sweep()
 
     # -- training iteration ---------------------------------------------
 

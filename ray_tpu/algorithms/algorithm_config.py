@@ -208,13 +208,6 @@ class AlgorithmConfig:
         # Requires dist.initialize() to have joined N processes
         # (RAY_TPU_COORDINATOR et al.; Algorithm.setup validates).
         self.hosts = None
-        # AOT compiled-program cache directory (sharding/aot.py,
-        # docs/serving.md "the front door"): when set, the policy's
-        # learn program warms through the fleet-shared executable
-        # cache at its first build — an elastic joiner (or a restarted
-        # driver) whose predecessor populated the cache compiles
-        # NOTHING on the learn path. None = live jit (the default).
-        self.aot_cache_dir = None
 
         # exploration
         self.explore = True
@@ -427,7 +420,6 @@ class AlgorithmConfig:
         *,
         model_parallel=None,
         hosts=None,
-        aot_cache_dir: Optional[str] = None,
         **kwargs,
     ) -> "AlgorithmConfig":
         """Learner-plane placement (docs/sharding.md).
@@ -436,14 +428,10 @@ class AlgorithmConfig:
         model's rules; see the attribute comment in ``__init__``.
         ``hosts``: "auto" | int N — span the learner mesh over the N
         processes of the jax.distributed runtime (the multi-host
-        fleet, docs/fleet.md). ``aot_cache_dir``: fleet-shared AOT
-        executable cache the learn program warms through (zero fresh
-        compiles for elastic joiners on a warm cache)."""
+        fleet, docs/fleet.md)."""
         from ray_tpu import sharding as sharding_lib
 
         sharding_lib.refuse_removed_options(kwargs)
-        if aot_cache_dir is not None:
-            self.aot_cache_dir = str(aot_cache_dir)
         if hosts is not None:
             if hosts != "auto":
                 h = int(hosts)

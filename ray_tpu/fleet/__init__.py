@@ -5,9 +5,11 @@ one (GCS node table, heartbeats, resource-change pubsub): hosts
 rendezvous through a KV control plane, membership lives with a
 single-writer coordinator, every mesh (re)construction is a
 generation-numbered epoch, and a preemption-driven resize is a
-warm-cache restart — the PR-10 reshard contract moves the state, the
-geometry-keyed PR-14 AOT cache supplies the executables, so the
-survivor's first post-resize step performs zero fresh compiles.
+restart at the new geometry — the PR-10 reshard contract moves the
+state, and the survivor's programs compile through jax's persistent
+compilation cache where ``utils/platform.ensure_compile_cache()``
+placed one (a retrieval instead of a backend compile; the trace and
+the lowering are paid either way).
 
 Modules (docs/fleet.md):
 
@@ -15,8 +17,8 @@ Modules (docs/fleet.md):
   from ``parallel.distributed``; blocking gets, pubsub, heartbeats);
 - :mod:`~ray_tpu.fleet.coordinator` membership, mesh epochs, drain
   protocol, epoch-scoped barriers;
-- :mod:`~ray_tpu.fleet.elastic`     resize/pre-seed primitives over
-  the reshard contract and the AOT cache.
+- :mod:`~ray_tpu.fleet.elastic`     resize primitives over the
+  reshard contract.
 
 Crash tolerance (PR 19): the coordinator's authority is a fenced KV
 lease (``LEASE_NAME``) — standbys acquire it on expiry and rebuild
@@ -47,12 +49,8 @@ from ray_tpu.fleet.coordinator import (
     epoch_key,
 )
 from ray_tpu.fleet.elastic import (
-    PRESEED_ENV,
     epoch_mesh,
-    preseed_enabled,
-    preseed_resize,
     resize_policy,
-    resize_target_meshes,
     resync_epoch,
     shadow_policy,
 )
@@ -85,17 +83,13 @@ __all__ = [
     "LEASE_NAME",
     "LEASE_TTL_ENV",
     "MeshEpoch",
-    "PRESEED_ENV",
     "StaleTermError",
     "Subscriber",
     "barrier_key",
     "drain_key",
     "epoch_key",
     "epoch_mesh",
-    "preseed_enabled",
-    "preseed_resize",
     "resize_policy",
-    "resize_target_meshes",
     "resync_epoch",
     "shadow_policy",
 ]

@@ -1,48 +1,21 @@
-"""ray_tpu.fleet.elastic — live mesh resize as a warm-cache restart.
+"""ray_tpu.fleet.elastic — live mesh resize as a restart at the new
+geometry.
 
-Three pieces the rest of the repo already proved, composed into one
-primitive:
-
-- the **PR-10 reshard contract**: ``Policy.set_state`` re-places any
-  host state tree per the ACTIVE sharding rules, bitwise across mesh
-  geometries — so moving a learner to a new mesh is "build a twin on
-  the new mesh, hand it the state";
-- the **PR-14 AOT executable cache**, geometry-keyed since this PR
-  (``compile._mesh_geometry_token``): entries for several mesh
-  geometries coexist in one cache dir, so the fleet can hold compiled
-  programs for geometries it is not currently running;
-- the **PR-16 program registry** sweep: the learn-program shapes of a
-  config are predictable, so the resize-target geometry's programs can
-  be compiled BEFORE any preemption notice exists.
-
-``preseed_resize`` runs at fleet bring-up (or idle time): it builds a
-shadow policy on each resize-target mesh and AOT-compiles its learn
-program into the shared cache. When a preemption later shrinks the
-fleet, ``resize_policy`` builds the survivor's twin on the new mesh —
-its warmup hits the pre-seeded entry, so the resize performs ZERO
-fresh compiles (asserted via the compile ledger in the tests). That is
-the tentpole contract: resize is a warm-cache restart.
-
-Env knob: ``RAY_TPU_FLEET_PRESEED=0`` disables the bring-up pre-seed
-sweep (docs/fleet.md).
+``resize_policy`` is the **PR-10 reshard contract** applied to a mesh
+change: ``Policy.set_state`` re-places any host state tree per the
+ACTIVE sharding rules, bitwise across mesh geometries — so moving a
+learner to a new mesh is "build a twin on the new mesh, hand it the
+state". The twin's learn program compiles at its first step like any
+other program: where ``utils/platform.ensure_compile_cache()`` placed
+jax's persistent compilation cache (``JAX_COMPILATION_CACHE_DIR`` to
+share one across the fleet) and an earlier process ran the same
+program on the same geometry, the backend compile is a retrieval; the
+trace and the lowering are paid either way (docs/fleet.md).
 """
 
 from __future__ import annotations
 
-import os
-from typing import Any, Dict, List, Optional
-
 from ray_tpu.fleet.coordinator import MeshEpoch
-
-PRESEED_ENV = "RAY_TPU_FLEET_PRESEED"
-
-
-def preseed_enabled() -> bool:
-    return os.environ.get(PRESEED_ENV, "1").lower() not in (
-        "0",
-        "false",
-        "off",
-    )
 
 
 def shadow_policy(policy, mesh):
@@ -63,9 +36,7 @@ def resize_policy(policy, new_mesh):
     coefficient schedule, step counters) — ``set_state``'s
     ``_tree_to_device`` re-places every leaf per the twin's sharding
     rules, so the transfer is bitwise and training continues exactly
-    where the old geometry stopped. With an AOT cache configured and
-    pre-seeded (``preseed_resize``), the twin's first learn step
-    installs a cached executable: zero fresh compiles."""
+    where the old geometry stopped."""
     import time
 
     from ray_tpu.telemetry import fleetview
@@ -89,81 +60,6 @@ def resize_policy(policy, new_mesh):
         ),
     )
     return twin
-
-
-def preseed_resize(
-    policy,
-    mesh,
-    dev_batch: Dict[str, Any],
-    batch_size: int,
-) -> str:
-    """AOT-compile the learn program ``policy`` would run after a
-    resize to ``mesh``, into the policy's configured AOT cache.
-
-    ``dev_batch`` is a HOST tree with the post-resize global batch
-    shapes (the registry's predictive specs; in the common shrink case
-    the global batch is unchanged — same shapes, different mesh, which
-    is exactly why the cache keys on geometry). Returns the
-    ``aot_warmup`` status: ``"hit"`` (already seeded), ``"compiled"``
-    (seeded now — the one ahead-of-time compile this geometry will
-    ever cost), or ``"disabled"`` (no cache configured / jax build
-    without executable serialization)."""
-    import jax
-    import numpy as np
-
-    shadow = shadow_policy(policy, mesh)
-    cache = shadow._learn_aot_cache()
-    if cache is None:
-        return "disabled"
-    fn = shadow.learn_fn(batch_size)
-    # place exactly as the learn path would (per-column sharding
-    # tree), so the lowered signature matches the real post-resize
-    # program's — executable values don't matter, placement does
-    sh = shadow.batch_shardings(dev_batch)
-    dev = {
-        k: jax.device_put(
-            np.asarray(v),
-            sh[k] if isinstance(sh, dict) else sh,
-        )
-        for k, v in dev_batch.items()
-    }
-    status = fn.aot_warmup(
-        cache,
-        shadow.params,
-        shadow.opt_state,
-        shadow.aux_state,
-        dev,
-        shadow._rng,
-        shadow._coeff_array(),
-    )
-    # the seed must be durable before a preemption can arrive
-    cache.flush()
-    from ray_tpu.telemetry import metrics
-
-    metrics.inc_fleet_preseed(status)
-    return status
-
-
-def resize_target_meshes(mesh) -> List:
-    """The ±1-host resize geometries worth pre-seeding from ``mesh``:
-    today the shrink-by-one-host survivor mesh (this process's local
-    devices) — the geometry a preemption forces. Growth geometries
-    join when a process can host more devices than it runs (the
-    restarted-fleet case pre-seeds through the same cache dir by
-    construction: the new process compiles against the same keys)."""
-    import jax
-    import numpy as np
-
-    local = list(jax.local_devices())
-    try:
-        n_mesh = int(np.asarray(mesh.devices).size)
-    except Exception:
-        n_mesh = len(local)
-    if len(local) >= n_mesh:
-        return []  # already single-host: no shrink geometry below it
-    from ray_tpu import sharding as sharding_lib
-
-    return [sharding_lib.get_mesh(devices=local)]
 
 
 def resync_epoch(kv, current_gen: int, timeout: float = 30.0) -> MeshEpoch:
@@ -193,8 +89,8 @@ def epoch_mesh(epoch: MeshEpoch):
     multi-host epoch builds over the global device view, which
     requires the jax.distributed runtime to already span exactly the
     epoch's hosts: growing or re-pairing live processes is a process
-    restart (cheap by design — the AOT cache makes the restart
-    warm), not an in-process rewire."""
+    restart (the persistent compilation cache serves its backend
+    compiles), not an in-process rewire."""
     import jax
 
     from ray_tpu import sharding as sharding_lib
@@ -206,6 +102,6 @@ def epoch_mesh(epoch: MeshEpoch):
             f"epoch gen={epoch.gen} names {epoch.num_processes} "
             f"hosts but this jax runtime spans "
             f"{jax.process_count()} processes — restart the fleet "
-            "at the new geometry (the AOT cache keeps it warm)"
+            "at the new geometry"
         )
     return sharding_lib.get_mesh(devices=jax.devices())

@@ -16,9 +16,11 @@ Three layers between a TCP socket and a mesh forward
   inherited listener), with crash respawn, forwarded membership,
   whole-bank drain, and one merged ``/metrics`` exposition.
 
-Cold starts skip the compile storm via the AOT executable cache
-(:mod:`ray_tpu.sharding.aot`), loaded by
-``BatchedPolicyServer.warmup(aot_cache=...)``.
+A replica's cold start (``BatchedPolicyServer.warmup``) compiles each
+bucket through jax's persistent compilation cache, placed by
+``utils/platform.ensure_compile_cache()`` (``JAX_COMPILATION_CACHE_DIR``
+to share one across replicas): a later replica still traces and
+lowers, and retrieves the executable instead of compiling it.
 """
 
 from ray_tpu.ingress.admission import (  # noqa: F401

@@ -241,15 +241,6 @@ class JaxPolicy(Policy):
 
         # (batch_size, with_frames) -> compiled SGD-nest program
         self._learn_fns: Dict[Tuple[int, bool], Any] = {}
-        # AOT executable cache for the learn program (sharding/aot.py;
-        # ROADMAP item 2 leftover): resolved lazily from
-        # config["aot_cache_dir"] so importing the policy never touches
-        # the cache machinery. The elastic joiner's warmup rides this —
-        # a freshly built policy whose fleet already populated the
-        # cache installs the serialized executable instead of paying
-        # the XLA compile (aot_warmup in learn_on_device_batch).
-        self._aot_cache = None
-        self._aot_cache_resolved = False
         self._action_fn = None
         self._value_fn = None
         self.num_grad_updates = 0
@@ -1711,88 +1702,6 @@ class JaxPolicy(Policy):
             self._learn_fns[key] = fn
         return fn
 
-    def _learn_aot_cache(self):
-        """The AOT executable cache for learn programs, resolved once
-        from ``config["aot_cache_dir"]`` (None when unconfigured).
-        getattr-guarded: bespoke-net policies (SlateQ) run their own
-        init chain past ``JaxPolicy.__init__``, so the lazy attrs may
-        not exist on first touch."""
-        if not getattr(self, "_aot_cache_resolved", False):
-            self._aot_cache_resolved = True
-            self._aot_cache = getattr(self, "_aot_cache", None)
-            root = self.config.get("aot_cache_dir")
-            if root:
-                from ray_tpu.sharding import aot as aot_lib
-
-                self._aot_cache = aot_lib.resolve_cache(root)
-        return self._aot_cache
-
-    # the warmup belongs to the driver thread: it installs the
-    # program's dispatch path, which must not race a learn in flight
-    # ray-tpu: thread=driver
-    def _maybe_aot_warm(self, fn, args) -> None:
-        """Elastic-joiner cold start (``ShardedFunction.aot_warmup``):
-        before a freshly built learn program's FIRST dispatch, try to
-        install the fleet-shared serialized executable for this exact
-        signature. A hit means a joiner (or restarted driver) runs its
-        first learn step with ZERO fresh compiles; a miss compiles
-        ahead of time once and seeds the cache for the next joiner.
-        ``aot_warmup`` only LOWERS — nothing dispatches, so the
-        donated opt_state buffers in ``args`` are untouched (no
-        RTA001 hazard) and the caller reuses them for the real call."""
-        if getattr(fn, "_aot_warm_attempted", False):
-            return  # one attempt per program (a "disabled" jax build
-            # must not pay a lower() per learn call)
-        fn._aot_warm_attempted = True
-        if getattr(fn, "aot_source", None) is not None:
-            return  # already warmed (hit, live-compiled, or fallback)
-        if getattr(fn, "traces", 0) > 0 or getattr(fn, "calls", 0) > 0:
-            return  # program already compiled live: nothing to save
-        cache = self._learn_aot_cache()
-        if cache is None:
-            return
-        fn.aot_warmup(cache, *args)
-
-    # ray-tpu: thread=driver
-    def _maybe_fleet_preseed(self, dev_batch, batch_size) -> None:
-        """Resize-geometry AOT pre-seed (docs/fleet.md): ONCE, at the
-        first learn on a mesh that spans processes with an AOT cache
-        configured, compile the learn program of each ±1-host resize
-        geometry into the shared cache — a later preemption-driven
-        resize then restores its executable instead of compiling
-        (fleet.elastic.resize_policy: zero fresh compiles). The seed
-        batch is zeros at the live batch's global shapes: executables
-        depend on placement and shape, never on values."""
-        if getattr(self, "_fleet_preseeded", False):
-            return
-        self._fleet_preseeded = True
-        if self._learn_aot_cache() is None:
-            return
-        mesh = getattr(self, "mesh", None)
-        if mesh is None or not sharding_lib.mesh_spans_processes(
-            mesh
-        ):
-            return
-        from ray_tpu.fleet import elastic as elastic_lib
-
-        if not elastic_lib.preseed_enabled():
-            return
-        try:
-            import numpy as np
-
-            host = {
-                k: np.zeros(v.shape, v.dtype)
-                for k, v in dev_batch.items()
-                if hasattr(v, "shape")
-            }
-            for target in elastic_lib.resize_target_meshes(mesh):
-                elastic_lib.preseed_resize(
-                    self, target, host, batch_size
-                )
-        except Exception:
-            pass  # the pre-seed is an optimization: a failed sweep
-            # must never break the live learn path
-
     def learn_on_device_batch(
         self, dev_batch: Dict[str, Any], batch_size: int,
         *, defer_stats: bool = False,
@@ -1831,14 +1740,6 @@ class JaxPolicy(Policy):
         self._update_scheduled_coeffs()
         self._rng, rng = jax.random.split(self._rng)
         coeffs = self._coeff_array()
-        # elastic-joiner AOT warmup at the _build_learn_fn call site:
-        # install the fleet-shared executable for this signature
-        # before the first dispatch (no-op without aot_cache_dir)
-        self._maybe_aot_warm(
-            fn,
-            (self.params, self.opt_state, aux, dev_batch, rng, coeffs),
-        )
-        self._maybe_fleet_preseed(dev_batch, batch_size)
         compiles_before = getattr(fn, "traces", 0)
         compile_s_before = getattr(fn, "compile_time_s", 0.0)
         t0 = _time.perf_counter()

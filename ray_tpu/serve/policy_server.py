@@ -259,7 +259,6 @@ class BatchedPolicyServer:
         obs_filter=None,
         preprocessor=None,
         stats_window_s: float = 30.0,
-        aot_cache=None,
         start: bool = True,
     ):
         self.policy = policy
@@ -318,7 +317,7 @@ class BatchedPolicyServer:
         self._fns: Dict[Tuple[int, bool], Any] = {}
         # per-bucket program specs (sharding/registry.py): warmup()
         # walks this registry, and an algorithm-owned registry can
-        # absorb the same rows so the driver's AOT/coverage sweep sees
+        # absorb the same rows so the driver's coverage sweep sees
         # serve programs alongside the learn-side ones
         self.program_registry = self._build_program_registry()
 
@@ -337,19 +336,6 @@ class BatchedPolicyServer:
         self._stop = threading.Event()
         self._flush_hints = 0
         self.error: Optional[BaseException] = None
-        # AOT compiled-program cache (sharding/aot.py): warmup loads
-        # serialized serve executables instead of compiling — the
-        # cold-start path of docs/serving.md "the front door"
-        from ray_tpu.sharding import aot as aot_lib
-
-        self.aot_cache = aot_lib.resolve_cache(aot_cache)
-        # a cache built HERE from a path is ours to stop; a passed-in
-        # instance is fleet-shared and outlives any one server
-        self._owns_aot_cache = (
-            self.aot_cache is not None
-            and not isinstance(aot_cache, aot_lib.AOTCompileCache)
-        )
-
         self.requests_total = 0
         self.batches_total = 0
         self.batch_rows_total = 0
@@ -698,15 +684,6 @@ class BatchedPolicyServer:
             fn = self._fns[key] = self._build_serve_fn(
                 bucket, explore
             )
-        if self.aot_cache is not None:
-            # AOT cold start (sharding/aot.py): a cache hit installs
-            # the serialized executable — the warm call below then
-            # executes WITHOUT any XLA compile; a miss compiles ahead
-            # of time once and seeds the cache for the next replica
-            fn.aot_warmup(
-                self.aot_cache,
-                params, self._carry, padded, np.int32(0), coeffs,
-            )
         _, _, self._carry = fn(
             params, self._carry, padded, np.int32(0), coeffs
         )
@@ -911,11 +888,6 @@ class BatchedPolicyServer:
             # host can report neither MFU nor HBM headroom)
             "device": device_ledger_summary(),
             "buckets": list(self.buckets),
-            "aot": (
-                self.aot_cache.stats()
-                if self.aot_cache is not None
-                else None
-            ),
         }
 
     def stop(self, join_timeout: float = 30.0) -> None:
@@ -924,8 +896,6 @@ class BatchedPolicyServer:
             self._cv.notify_all()
         if self._thread is not None and self._thread.is_alive():
             self._thread.join(timeout=join_timeout)
-        if self._owns_aot_cache:
-            self.aot_cache.stop()
 
 
 # -- checkpoint restore / hot-reload sources ----------------------------
@@ -1165,7 +1135,6 @@ class PolicyDeployment:
         watch: bool = True,
         poll_interval_s: float = 0.5,
         warmup: bool = True,
-        aot_cache=None,
         config_overrides: Optional[Dict[str, Any]] = None,
     ):
         policy, prep, obs_filter, info = restore_policy(
@@ -1183,11 +1152,6 @@ class PolicyDeployment:
             explore=explore,
             obs_filter=obs_filter,
             preprocessor=prep,
-            # a directory path shared across the fleet: every replica
-            # process resolves its own cache client over the same
-            # entries, so the first replica's compiles become every
-            # later replica's cold-start hits
-            aot_cache=aot_cache,
             start=False,
         )
         if warmup:

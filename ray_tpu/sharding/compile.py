@@ -24,7 +24,7 @@ import re
 import threading
 import time
 import weakref
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, Optional, Sequence
 
 import jax
 
@@ -47,34 +47,6 @@ def label_family(label: str) -> str:
     with its sizes, stays the key of ``compile_stats()`` and of the
     device ledger."""
     return re.sub(r"[^A-Za-z0-9_]", "_", label.split("[", 1)[0]) or "sharded_fn"
-
-
-def _mesh_geometry_token(tree) -> Tuple:
-    """Stable token naming every mesh geometry the tree's shardings
-    reference: ((axis, size) pairs, participating device ids) per
-    distinct mesh. The AOT cache keys on it (aot.FORMAT 2) — a program
-    lowered on a 2-host (dcn=2, batch=k) mesh and its 1-host resize
-    twin share label AND abstract shapes but not executables, so the
-    geometry must be part of the entry identity for the fleet's
-    pre-seeded ±1-host entries to coexist."""
-    toks = set()
-    for leaf in jax.tree_util.tree_leaves(tree):
-        for holder in (leaf, getattr(leaf, "sharding", None)):
-            mesh = getattr(holder, "mesh", None)
-            if mesh is None:
-                continue
-            try:
-                axes = tuple(
-                    (str(a), int(s))
-                    for a, s in dict(mesh.shape).items()
-                )
-                ids = tuple(
-                    int(d.id) for d in mesh.devices.flat
-                )
-            except Exception:
-                continue
-            toks.add((axes, ids))
-    return tuple(sorted(toks))
 
 
 class ShardedFunction:
@@ -106,12 +78,6 @@ class ShardedFunction:
         self.calls = 0
         self.account = CompileAccount(label_family(self.label))
         self.retrace_causes: list = []  # what moved, the last few
-        # AOT-installed dispatch path (sharding/aot.py): a compiled
-        # executable restored from the persistent cache ("aot_cache")
-        # or compiled ahead of time here ("aot_live"); None = plain jit
-        self._aot = None
-        self.aot_source: Optional[str] = None
-        self.aot_fallbacks = 0
         # ledger-visible program identity (telemetry/device.py)
         self.in_specs = in_specs
         self.out_specs = out_specs
@@ -168,124 +134,7 @@ class ShardedFunction:
         finally:
             self._uncounted.on = _TLS.claim = False
 
-    def aot_warmup(self, cache, *args, **kwargs) -> str:
-        """Install an ahead-of-time compiled executable for the ONE
-        abstract signature ``(*args, **kwargs)`` describes (the serve
-        bucket contract: one ShardedFunction = one static shape).
-
-        Tries the persistent cache first — a hit installs the
-        deserialized executable with ZERO fresh compiles and registers
-        it in the device ledger with ``compile_s=0`` /
-        ``source="aot_cache"``. A miss compiles ahead of time (counted
-        as this function's one trace), installs the result, and queues
-        the serialized executable for the cache writer so the NEXT
-        replica hits. Returns ``"hit"`` / ``"compiled"`` /
-        ``"disabled"`` (no cache — the caller falls back to plain jit
-        warmup).
-
-        The cache signature carries the MESH GEOMETRY of the program's
-        shardings on top of the ledger's shape/dtype signature: the
-        same label at the same shapes lowers to different collectives
-        on different meshes (a 2-host fleet pre-seeding its 1-host
-        resize geometry is the motivating case — without the token the
-        two entries would collide on one key).
-        """
-        from ray_tpu.sharding import aot as aot_lib
-
-        cache = aot_lib.resolve_cache(cache)
-        if cache is None:
-            return "disabled"
-        try:
-            sig = device_ledger.signature_of(
-                args, kwargs, self.static_argnames
-            )
-            geo = _mesh_geometry_token(
-                (args, kwargs, self.in_specs, self.out_specs)
-            )
-            if geo:
-                sig = (sig, ("mesh", geo))
-        except Exception:
-            return "disabled"
-        loaded = cache.load(self.label, sig)
-        if loaded is not None:
-            self._aot = loaded
-            self.aot_source = "aot_cache"
-            device_ledger.on_aot(self, 0.0, "aot_cache")
-            return "hit"
-        try:
-            with self.uncounted_traces():
-                compiled = self._jitted.lower(
-                    *args, **kwargs
-                ).compile()
-        except Exception:
-            return "disabled"
-        with self._lock:
-            # a real XLA compile: count it exactly like a jit trace so
-            # compile_stats stays honest about cold-start cost
-            self.traces += 1
-        self._aot = compiled
-        self.aot_source = "aot_live"
-        dt = self.account.settle(self.label)
-        device_ledger.on_aot(self, dt, "aot_live")
-        cache.save(self.label, sig, compiled)
-        return "compiled"
-
-    def _call_aot(self, args, kwargs):
-        """Dispatch through the installed AOT executable; any failure
-        (signature drift, an executable a stale cache slipped past the
-        keying) drops the AOT path and falls back to plain jit — the
-        graceful-fallback contract. Shape/dtype mismatches raise
-        BEFORE execution, so donated buffers are still intact for the
-        fallback call."""
-        ledger_on = device_ledger.enabled()
-        trace_on = tracing.is_enabled()
-        if not (ledger_on or trace_on):
-            # steady-path diet: nobody consumes the wall/perf stamps,
-            # so don't take them (the ledger hook below early-returns)
-            try:
-                out = self._aot(*args, **kwargs)
-            except Exception:
-                self._aot = None
-                with self._lock:
-                    self.aot_fallbacks += 1
-                tracing.event("aot:fallback", label=self.label)
-                try:
-                    telemetry_metrics.inc_aot_cache_event("fallback")
-                except Exception:
-                    pass
-                return None
-            self.calls += 1
-            return (out,)
-        t_wall0 = time.time()
-        t0 = time.perf_counter()
-        try:
-            if trace_on:
-                with tracing.start_span("jit:" + self.label) as sp:
-                    out = self._aot(*args, **kwargs)
-                    sp.set_attribute("aot", self.aot_source)
-            else:
-                out = self._aot(*args, **kwargs)
-        except Exception:
-            self._aot = None
-            with self._lock:
-                self.aot_fallbacks += 1
-            tracing.event("aot:fallback", label=self.label)
-            try:
-                telemetry_metrics.inc_aot_cache_event("fallback")
-            except Exception:
-                pass
-            return None
-        dt = time.perf_counter() - t0
-        with self._lock:
-            self.calls += 1
-        device_ledger.on_call(self, t_wall0, dt, traced=False)
-        return (out,)
-
     def __call__(self, *args, **kwargs):
-        if self._aot is not None:
-            boxed = self._call_aot(args, kwargs)
-            if boxed is not None:
-                return boxed[0]
         before = self.traces
         # fast path: after warmup, with neither tracing nor the device
         # ledger consuming the per-call stamps, dispatch costs one
@@ -377,9 +226,6 @@ class ShardedFunction:
         }
         if self.retrace_causes:
             out["retrace_causes"] = list(self.retrace_causes)
-        if self.aot_source is not None or self.aot_fallbacks:
-            out["aot_source"] = self.aot_source
-            out["aot_fallbacks"] = self.aot_fallbacks
         return out
 
     def lower(self, *args, **kwargs):

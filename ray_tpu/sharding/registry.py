@@ -6,22 +6,20 @@ prioritized-tree programs, serve buckets — carries a ``sharded_jit``
 label (the same label the compile-cache stats and the PR-13 device
 ledger report). This module makes that inventory a first-class object:
 a :class:`ProgramRegistry` of :class:`ProgramSpec` rows, predicted
-up-front from the config rather than discovered after the fact, so AOT
-pre-seeding, warmup sweeps and dispatch-diet coverage checks are all
-ONE walk over the same list.
+up-front from the config rather than discovered after the fact, so
+warmup sweeps and dispatch-diet coverage checks are ONE walk over the
+same list.
 
 Three consumers (docs/API.md "program registry"):
 
 - ``Algorithm.setup`` builds ``algo.program_registry`` via
-  :func:`for_algorithm` and, when ``config["aot_cache_dir"]`` is set,
-  sweeps the warmable specs so a restarted driver pre-seeds its
-  executables before the first train call;
+  :func:`for_algorithm`;
 - ``serve.BatchedPolicyServer.warmup`` walks its per-bucket specs
   (registered by the server itself) instead of an ad-hoc loop;
 - ``tests/test_dispatch_diet.py`` asserts completeness: every label
   ``compile_stats()`` observed after a run matches some spec — a new
   program that forgets to register here fails CI, which is what keeps
-  the warmup/AOT sweep exhaustive.
+  the warmup sweep exhaustive.
 
 Labels with data-dependent components (batch sizes resolved at the
 first learn call, draw widths, bucket sizes) register as anchored
@@ -161,7 +159,7 @@ class ProgramRegistry:
         self, *, kind: Optional[str] = None, warm: bool = True
     ) -> Dict[str, Any]:
         """Walk the specs (optionally one ``kind``), running each
-        ``warm`` callable — the one-pass AOT pre-seed / bucket warmup.
+        ``warm`` callable — the one-pass bucket warmup.
         Errors are collected, not raised: a spec whose program can't
         build yet (batch size unknown until the first train call) must
         not abort the specs after it."""

@@ -33,7 +33,7 @@ The ledger is off by default (one flag check per dispatch). The
 telemetry runtime enables it (``AlgorithmConfig.telemetry(...)``), or
 ``RAY_TPU_DEVICE_LEDGER=1`` does with no config at all. The cost /
 memory analysis pays one extra ahead-of-time compile per traced
-signature (the jit execution cache and the AOT cache are disjoint);
+signature (jit's execution cache does not serve ``lower().compile()``);
 ``device_ledger="light"`` keeps the counters and forensics without it.
 """
 
@@ -289,7 +289,6 @@ class _ProgramEntry:
         "memory",
         "n_devices",
         "tid",
-        "source",
         "abstract",
     )
 
@@ -311,13 +310,6 @@ class _ProgramEntry:
         self.bytes_accessed: Optional[float] = None
         self.memory: Optional[Dict[str, float]] = None
         self.n_devices = 1
-        # how this program's executable came to exist: "live" (jit
-        # traced+compiled in this process), "aot_live" (compiled ahead
-        # of time here, seeding the AOT cache), or "aot_cache"
-        # (deserialized from the persistent cache — compile_s stays 0
-        # and no trace/forensics ever fire, because no compile
-        # happened in this process)
-        self.source = "live"
         # (abstract args, abstract kwargs, x64) of the analysed
         # signature: shapes and shardings, no array. What a later
         # request for the compiled text lowers again
@@ -341,7 +333,6 @@ class _ProgramEntry:
             "in_shardings": self.in_shardings,
             "out_shardings": self.out_shardings,
             "n_devices": self.n_devices,
-            "source": self.source,
             "flops": self.flops,
             "bytes_accessed": self.bytes_accessed,
             "memory": self.memory,
@@ -443,8 +434,8 @@ def _abstractify(args, kwargs, static_argnames=()):
 
 def _analyze_program(entry: "_ProgramEntry", sf, args, kwargs) -> None:
     """Capture ``cost_analysis``/``memory_analysis`` for the signature
-    just traced. Pays ONE ahead-of-time compile (the jit execution
-    cache and the AOT cache are disjoint caches); the guard in
+    just traced. Pays ONE ahead-of-time compile (jit's execution
+    cache does not serve ``lower().compile()``); the guard in
     ``ShardedFunction`` keeps that abstract retrace out of the
     recompile counters."""
     import jax
@@ -555,23 +546,6 @@ def on_traced(sf, args, kwargs, compile_s: float) -> Optional[str]:
     if _analyze and entry.flops is None:
         _analyze_program(entry, sf, args, kwargs)
     return cause
-
-
-def on_aot(sf, compile_s: float, source: str) -> None:
-    """``sf`` just installed an AOT executable (sharding/aot.py).
-    ``source="aot_cache"`` registers the row with ``compile_s=0`` and
-    NO trace — a cache hit is not a compile, and must not feed the
-    ``jit:recompile`` forensics. ``source="aot_live"`` is the one
-    ahead-of-time compile that seeded the cache: counted exactly like
-    a trace so cold-start cost stays visible."""
-    if not _enabled:
-        return
-    with _LOCK:
-        entry = _entry_for(sf)
-        entry.source = source
-        if source == "aot_live":
-            entry.traces += 1
-            entry.compile_time_s += compile_s
 
 
 def on_call(sf, t_wall0: float, dt: float, traced: bool = False) -> None:

@@ -55,13 +55,11 @@ FLEET_SIZE = "ray_tpu_fleet_size"
 PREEMPTIONS_TOTAL = "ray_tpu_preemptions_total"
 # learner fleet (docs/fleet.md): hosts in the current mesh epoch and
 # the epoch generation itself (a resize shows as the host gauge
-# stepping and the generation bumping together), resizes by reason
-# (drain vs heartbeat-expired), and AOT pre-seed sweep outcomes by
-# aot_warmup status (hit / compiled / disabled)
+# stepping and the generation bumping together) and resizes by reason
+# (drain vs heartbeat-expired)
 LEARNER_FLEET_HOSTS = "ray_tpu_learner_fleet_hosts"
 MESH_EPOCH = "ray_tpu_mesh_epoch"
 MESH_RESIZES_TOTAL = "ray_tpu_mesh_resizes_total"
-FLEET_PRESEEDS_TOTAL = "ray_tpu_fleet_aot_preseeds_total"
 # fleet-wide observability plane (docs/observability.md "Fleet view",
 # telemetry/fleetview.py): per-host barrier wall at each epoch-scoped
 # barrier (seconds a host's arrival led the LAST arriver's,
@@ -294,10 +292,6 @@ ROUTER_BATCHES_TOTAL = "ray_tpu_router_batches_total"
 ROUTER_MERGED_ROWS_TOTAL = "ray_tpu_router_merged_rows_total"
 ROUTER_EXPIRED_TOTAL = "ray_tpu_router_expired_total"
 ROUTER_REROUTED_TOTAL = "ray_tpu_router_rerouted_total"
-# AOT compiled-program cache (sharding/aot.py): hit/miss/save plus
-# the failure lanes (load_error/save_error → misses; fallback = an
-# installed executable rejected at dispatch, reverted to live jit)
-AOT_CACHE_EVENTS_TOTAL = "ray_tpu_aot_cache_events_total"
 # the compile account (sharding/compile.py): jax's own seconds of every
 # compile by program family and phase (trace | lower | backend |
 # analysis, the device ledger's second compile), and the persistent
@@ -497,15 +491,6 @@ def set_kv_rtt(host: str, seconds: float) -> None:
         "KV heartbeat round-trip seconds measured per host",
         ("host",),
     ).set(float(seconds), {"host": host})
-
-
-def inc_fleet_preseed(status: str, n: int = 1) -> None:
-    """Resize-geometry AOT pre-seed attempts by aot_warmup outcome."""
-    counter(
-        FLEET_PRESEEDS_TOTAL,
-        "resize-geometry AOT pre-seed attempts",
-        ("status",),
-    ).inc(float(n), {"status": status})
 
 
 def inc_kv_retries(host: str, op: str, n: int = 1) -> None:
@@ -1240,16 +1225,6 @@ def inc_router_rerouted(deployment: str, n: int = 1) -> None:
         "requests rerouted off dead replicas",
         ("deployment",),
     ).inc(float(n), {"deployment": deployment})
-
-
-def inc_aot_cache_event(event: str, n: int = 1) -> None:
-    """AOT compile-cache traffic (sharding/aot.py): hit / miss / save
-    / load_error / save_error / fallback."""
-    counter(
-        AOT_CACHE_EVENTS_TOTAL,
-        "AOT compiled-program cache events",
-        ("event",),
-    ).inc(float(n), {"event": event})
 
 
 def add_compile_phase_seconds(

@@ -5,8 +5,8 @@ chain — temp file → flush → ``os.fsync`` → ``os.replace`` →
 directory fsync — and several skipped the fsyncs: a host crash could
 publish a rename pointing at unwritten data blocks, or a directory
 entry that never made it to disk, on the exact files the recovery
-layer trusts (checkpoints, stream snapshots, experiment state, AOT
-cache entries). This module centralizes the chain; the static
+layer trusts (checkpoints, stream snapshots, experiment state). This
+module centralizes the chain; the static
 analyzer's RTA009 rule flags any ``os.replace`` outside it, so the
 discipline can no longer regress one call site at a time.
 

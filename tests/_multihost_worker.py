@@ -7,8 +7,7 @@ rank runs a HostAgent (join/heartbeat/epoch observation/barriers), and
 the elastic half is the real drain choreography — provider notice →
 coordinator cuts epoch gen+1 → lockstep drain step → barrier → the
 survivor rebuilds via fleet.resize_policy on fleet.epoch_mesh, with
-bitwise post-reshard params and (AOT cache pre-seeded in-process by
-the first learn step) zero fresh compiles.
+bitwise post-reshard params and a learn step on the new mesh.
 
 Since PR 19 a chaos stage runs between the observability rung and the
 drain: rank 0's coordinator "crashes" without releasing its lease, a
@@ -21,7 +20,7 @@ control plane that has already failed over twice.
 Exercises: jax.distributed bring-up, a global mesh psum across hosts,
 cross-host weight broadcast, put_global batch placement, fleet
 rendezvous + epochs + drain + barrier, fenced coordinator failover,
-live resize as a warm-cache restart.
+live resize as a restart at the new geometry.
 """
 
 import os
@@ -112,14 +111,6 @@ def main() -> None:
         "lr": 1e-3,
         "seed": 0,  # identical init on every process
     }
-    # per-rank AOT cache dir: the first learn step pre-seeds this
-    # rank's shrink geometry (fleet auto pre-seed), which the survivor
-    # later hits at resize — zero fresh compiles
-    aot_root = os.environ.get("RAY_TPU_TEST_AOT_DIR")
-    if aot_root:
-        config["aot_cache_dir"] = os.path.join(
-            aot_root, f"rank{rank}"
-        )
     policy = PPOJaxPolicy(obs_space, act_space, config)
     data_rng = np.random.default_rng(42)  # same stream on all hosts
     host_batch = {
@@ -379,7 +370,7 @@ def main() -> None:
         return
 
     # ---- host0 survives the shrink: epoch 3 names it alone; the
-    # resize is a warm-cache restart (PR-10 reshard + pre-seeded AOT) --
+    # resize is a restart at the new geometry (PR-10 reshard) --
     epoch3 = agent.wait_for_epoch(3)
     assert epoch3.gen == 3 and epoch3.hosts == ("host0",), epoch3
     new_mesh = fleet.epoch_mesh(epoch3)  # local devices, no DCN
@@ -398,26 +389,6 @@ def main() -> None:
     print("RESHARD_BITWISE_OK")
     solo_stats = survivor.learn_on_batch(SampleBatch(host_batch))
     assert np.isfinite(solo_stats["total_loss"]), solo_stats
-    if aot_root:
-        fn = survivor.learn_fn(bsize)
-        assert fn.aot_source == "aot_cache" and fn.traces == 0, (
-            fn.aot_source,
-            fn.traces,
-        )
-        # the PR-13 ledger agrees: the resized learn program
-        # registered as a cache restore (compile_s=0, no traces),
-        # not a live compile
-        from ray_tpu.telemetry import device as device_ledger
-
-        if device_ledger.enabled():
-            cached = [
-                p
-                for p in device_ledger.snapshot()["programs"]
-                if p["source"] == "aot_cache"
-                and p["executions"] > 0
-            ]
-            assert cached, "no aot_cache ledger row for the resize"
-        print("AOT_RESIZE_HIT zero fresh compiles")
     print("ELASTIC_OK survivor continued on local mesh")
     kv.put("fleet_test/solo_done", True)
     coord.stop()

@@ -5,6 +5,7 @@ persistent cache's verdicts; the steps of building an Algorithm as
 ``setup:`` phases that are kept with tracing off; and none of it on
 the path of a steady dispatch or of an iteration."""
 
+import functools
 import inspect
 import os
 import subprocess
@@ -257,42 +258,249 @@ def test_the_two_counter_families_carry_the_account():
         assert series[key] == pytest.approx(fn.stats()[phase + "_s"])
 
 
-def test_the_persistent_cache_says_miss_then_hit_for_the_same_program(
-    tmp_path,
-):
-    code = f"""
+# What is compiled twice against ONE persistent cache directory, in a
+# child process whose ``JAX_COMPILATION_CACHE_DIR`` names it (the one
+# answer to "where does a compiled program come from":
+# ``utils/platform.ensure_compile_cache()``). ``jax.clear_caches()``
+# between the two stands for the second process: nothing compiled is
+# left in memory, so the second build traces and lowers again and asks
+# the directory for the executable.
+_CACHE_PRELUDE = """
+import os
 import jax, jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir", {str(tmp_path)!r})
+import numpy as np
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 from ray_tpu.sharding.compile import sharded_jit, compile_stats
+from ray_tpu.utils.platform import ensure_compile_cache
+
+# the variable is set: the code places no directory of its own
+assert ensure_compile_cache() == os.environ["JAX_COMPILATION_CACHE_DIR"]
+assert (
+    jax.config.jax_compilation_cache_dir
+    == os.environ["JAX_COMPILATION_CACHE_DIR"]
+)
+
+def verdicts(fn):
+    row = fn.stats()
+    return row["cache_hits"], row["cache_misses"]
 
 def body(x):
     return jnp.tanh(x @ x.T).sum()
 
 x = jnp.ones((16, 16))
+"""
+
+_POLICY = """
+import gymnasium as gym
+from ray_tpu import sharding as sharding_lib
+from ray_tpu.algorithms.ppo.ppo import PPOJaxPolicy
+
+def policy(**over):
+    return PPOJaxPolicy(
+        gym.spaces.Box(-1.0, 1.0, (4,), np.float32),
+        gym.spaces.Discrete(2),
+        {
+            "seed": 7, "num_workers": 0, "train_batch_size": 16,
+            "sgd_minibatch_size": 16, "num_sgd_iter": 1, "lr": 3e-4,
+            "model": {"fcnet_hiddens": [16, 16]},
+            "_mesh": sharding_lib.get_mesh(devices=jax.devices()[:1]),
+            **over,
+        },
+    )
+"""
+
+_CACHE_CASES = {
+    # the bare program
+    "program": """
 first = sharded_jit(body, label="acct_cache[p]")
 first(x)
-a = first.stats()
-assert (a["cache_hits"], a["cache_misses"]) == (0, 1), a
+assert verdicts(first) == (0, 1), first.stats()
 jax.clear_caches()
 again = sharded_jit(body, label="acct_cache[p]")
 again(x)
 b = again.stats()
-assert (b["cache_hits"], b["cache_misses"]) == (1, 0), b
+assert verdicts(again) == (1, 0), b
 assert b["backend_s"] > 0.0  # the retrieval
+assert b["traces"] == 1 and b["trace_s"] > 0.0  # a hit still traces
 fam = compile_stats()["families"]["acct_cache"]
 assert (fam["cache_hits"], fam["cache_misses"]) == (1, 1), fam
-print("miss then hit")
-"""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+""",
+    # a replica's cold start: every bucket of a second server's warmup
+    # is a hit, and it serves what the first served
+    "serve_warmup": _POLICY + """
+from ray_tpu.serve.policy_server import BatchedPolicyServer
+
+obs = np.random.default_rng(0).uniform(-1, 1, (5, 4)).astype(np.float32)
+
+def replica():
+    srv = BatchedPolicyServer(
+        policy(), name="policy", max_batch_size=8, explore=True,
+        start=False,
+    )
+    assert srv.warmup() == len(srv.buckets) > 1
+    srv.start()
+    served = [srv.submit(o).result(60.0) for o in obs]
+    srv.stop()
+    return srv, served
+
+seeder, want = replica()
+assert [verdicts(f) for f in seeder._fns.values()] == [(0, 1)] * len(
+    seeder.buckets
+)
+jax.clear_caches()
+joiner, got = replica()
+for fn in joiner._fns.values():
+    row = fn.stats()
+    # it traced and lowered, and retrieved instead of compiling
+    assert (row["traces"], row["recompiles"]) == (1, 0), row
+    assert verdicts(fn) == (1, 0), row
+for (a, ea), (b, eb) in zip(want, got):
+    assert np.array_equal(a, b)
+    assert np.array_equal(ea["action_logp"], eb["action_logp"])
+""",
+    # the joiner's case: a second policy's learn program is a hit and
+    # its update is the first's, bit for bit
+    "learn_program": _POLICY + """
+from ray_tpu.data.sample_batch import SampleBatch
+
+rng = np.random.default_rng(3)
+B = 16
+batch = {
+    SampleBatch.OBS: rng.standard_normal((B, 4)).astype(np.float32),
+    SampleBatch.ACTIONS: rng.integers(0, 2, B).astype(np.int64),
+    SampleBatch.ACTION_LOGP: np.full(B, -0.7, np.float32),
+    SampleBatch.ACTION_DIST_INPUTS: rng.standard_normal((B, 2)).astype(
+        np.float32
+    ),
+    SampleBatch.ADVANTAGES: rng.standard_normal(B).astype(np.float32),
+    SampleBatch.VALUE_TARGETS: rng.standard_normal(B).astype(np.float32),
+}
+
+def learner(**over):
+    pol = policy(**over)
+    pol.learn_on_batch(SampleBatch(batch))
+    leaves = jax.tree_util.tree_leaves(pol.get_weights())
+    return pol.learn_fn(B), [np.asarray(v) for v in leaves]
+
+seeder, want = learner()
+assert verdicts(seeder) == (0, 1), seeder.stats()
+jax.clear_caches()
+joiner, got = learner()
+assert joiner.label == seeder.label
+assert verdicts(joiner) == (1, 0), joiner.stats()
+assert joiner.stats()["traces"] == 1
+assert all(a.tobytes() == b.tobytes() for a, b in zip(want, got))
+# the same label and shapes under other loss coefficients is ANOTHER
+# program: a miss, and an update of its own
+jax.clear_caches()
+other, moved = learner(vf_loss_coeff=0.0, entropy_coeff=0.5)
+assert other.label == seeder.label
+assert verdicts(other) == (0, 1), other.stats()
+assert any(a.tobytes() != b.tobytes() for a, b in zip(want, moved))
+""",
+    # a directory that holds nothing of this program: a miss that
+    # compiles live and answers the same
+    "second_directory": """
+from jax.experimental.compilation_cache import compilation_cache
+
+first = sharded_jit(body, label="acct_cache[d]")
+want = float(first(x))
+assert verdicts(first) == (0, 1), first.stats()
+other = os.environ["JAX_COMPILATION_CACHE_DIR"] + "_other"
+os.makedirs(other)
+jax.clear_caches()
+compilation_cache.reset_cache()
+jax.config.update("jax_compilation_cache_dir", other)
+again = sharded_jit(body, label="acct_cache[d]")
+assert float(again(x)) == want
+assert verdicts(again) == (0, 1), again.stats()
+assert len(os.listdir(other)) == 1  # and seeds the new directory
+""",
+    # what the key is made from: the same label and shapes around
+    # another constant is a miss, and answers for itself
+    "other_constant": """
+def scaled(c):
+    return sharded_jit(lambda x: body(x) * c, label="acct_cache[c]")
+
+one = scaled(2.0)
+assert float(one(x)) == 2.0 * float(body(x))
+assert verdicts(one) == (0, 1)
+jax.clear_caches()
+other = scaled(3.0)
+assert float(other(x)) == 3.0 * float(body(x))
+assert verdicts(other) == (0, 1), other.stats()
+jax.clear_caches()
+same = scaled(2.0)
+assert float(same(x)) == 2.0 * float(body(x))
+assert verdicts(same) == (1, 0), same.stats()
+""",
+}
+
+
+@pytest.mark.parametrize("what", sorted(_CACHE_CASES))
+def test_the_persistent_cache_says_miss_then_hit_for_the_same_program(
+    tmp_path, what
+):
+    cache = tmp_path / "cache"
+    cache.mkdir()
+    env = dict(
+        os.environ, JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(cache)
+    )
+    code = _CACHE_PRELUDE + _CACHE_CASES[what] + '\nprint("miss then hit")\n'
     out = subprocess.run(
         [sys.executable, "-c", code], env=env, capture_output=True,
         text=True, timeout=300, cwd=ROOT,
     )
     assert out.returncode == 0, out.stderr[-3000:]
     assert out.stdout.strip().endswith("miss then hit")
+
+
+# The serialized-executable cache (PR 14 to PR 58) is gone with every
+# name it went by: no module of the package keeps a second answer to
+# "where does a compiled program come from". (Spelled in halves so that
+# a grep for them over the tree finds this file no more than the rest.)
+_GONE = ("aot_" + "cache", "aot_" + "warmup", "aot_" + "source", "PRE" + "SEED")
+
+
+@functools.cache
+def _package_lines():
+    """Every line of the package's Python, read once for all cases."""
+    lines = []
+    for folder, _, names in os.walk(os.path.join(ROOT, "ray_tpu")):
+        for name in names:
+            if name.endswith(".py"):
+                path = os.path.join(folder, name)
+                with open(path, encoding="utf-8") as f:
+                    lines += [
+                        (f"{os.path.relpath(path, ROOT)}:{n}", line)
+                        for n, line in enumerate(f, 1)
+                    ]
+    return lines
+
+
+def _naming(name, lines):
+    return [where for where, line in lines if name.lower() in line.lower()]
+
+
+@pytest.mark.parametrize("name", _GONE)
+def test_the_package_names_no_second_compile_cache(name):
+    assert _naming(name, _package_lines()) == []
+
+
+def test_the_walk_over_the_package_finds_a_planted_name():
+    planted = [
+        ("a.py:1", "        self." + _GONE[0] + " = resolve_cache(root)\n"),
+        ("a.py:2", "    fn." + _GONE[1] + "(cache, *args)\n"),
+        ("b.py:7", 'os.environ.get("RAY_TPU_FLEET_' + _GONE[3] + '", "1")\n'),
+        ("b.py:8", "placed by ensure_compile_cache()\n"),
+    ]
+    assert [_naming(name, planted) for name in _GONE] == [
+        ["a.py:1"], ["a.py:2"], [], ["b.py:7"],
+    ]
+    # and it walks the package: the one placement it must find
+    (placed,) = _naming("def ensure_compile_cache", _package_lines())
+    assert placed.startswith("ray_tpu/utils/platform.py:")
 
 
 # -- building an Algorithm, by phase ----------------------------------------

@@ -5,7 +5,7 @@ per-host provider-notice source.
 
 Tier-1 keeps the coordinator protocol units and the fake-policy resize
 sibling; the full PPO resize rungs live in the slow tier
-(test_resize_warm_cache_single_process here, and the 2-process
+(test_resize_single_process here, and the 2-process
 test_two_process_dcn_cluster in test_multihost.py) per the PR-1 test
 budget rule.
 """
@@ -211,53 +211,13 @@ def test_resize_policy_carries_state_bitwise():
 def test_epoch_mesh_single_host_is_local():
     import jax
 
-    from ray_tpu import sharding as sharding_lib
-
     epoch = fleet.MeshEpoch(gen=2, hosts=("host0",))
     mesh = fleet.epoch_mesh(epoch)
     assert len(mesh.devices.flat) == len(jax.local_devices())
-    # single-process: no shrink geometry below the local mesh
-    assert fleet.resize_target_meshes(mesh) == []
     # an epoch naming more hosts than the runtime spans is a restart
     wide = fleet.MeshEpoch(gen=3, hosts=("host0", "host1"))
     with pytest.raises(RuntimeError, match="restart"):
         fleet.epoch_mesh(wide)
-    # a sub-mesh of the virtual host DOES have a shrink target
-    sub = sharding_lib.get_mesh(devices=jax.devices()[:4])
-    targets = fleet.resize_target_meshes(sub)
-    assert len(targets) == 0 or all(
-        len(t.devices.flat) == len(jax.local_devices())
-        for t in targets
-    )
-
-
-def test_preseed_enabled_knob(monkeypatch):
-    monkeypatch.delenv(fleet.PRESEED_ENV, raising=False)
-    assert fleet.preseed_enabled()
-    monkeypatch.setenv(fleet.PRESEED_ENV, "0")
-    assert not fleet.preseed_enabled()
-
-
-def test_mesh_geometry_token_distinguishes_device_sets():
-    import jax
-
-    from ray_tpu import sharding as sharding_lib
-    from ray_tpu.sharding.compile import _mesh_geometry_token
-
-    mesh8 = sharding_lib.get_mesh(devices=jax.devices())
-    mesh4 = sharding_lib.get_mesh(devices=jax.devices()[:4])
-    x8 = jax.device_put(
-        np.ones((8,), np.float32),
-        sharding_lib.leaf_sharding(np.ones((8,), np.float32), mesh8),
-    )
-    x4 = jax.device_put(
-        np.ones((8,), np.float32),
-        sharding_lib.leaf_sharding(np.ones((8,), np.float32), mesh4),
-    )
-    t8, t4 = _mesh_geometry_token(x8), _mesh_geometry_token(x4)
-    assert t8 and t4 and t8 != t4
-    # host trees carry no geometry: token is empty, signature unchanged
-    assert _mesh_geometry_token({"a": np.ones(2)}) == ()
 
 
 def test_provider_notice_dir_scopes_per_host(tmp_path, monkeypatch):
@@ -280,17 +240,16 @@ def test_provider_notice_dir_scopes_per_host(tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Slow rung: the full warm-cache resize on one process (tier-1 sibling
-# of test_two_process_dcn_cluster's survivor path)
+# Slow rung: the full resize on one process (tier-1 sibling of
+# test_two_process_dcn_cluster's survivor path)
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.slow  # ~30 s: two PPO policy builds + AOT compile; the
+@pytest.mark.slow  # ~30 s: two PPO policy builds + their compiles; the
 # protocol/primitive units above are the tier-1 siblings (PR-1 rule)
-def test_resize_warm_cache_single_process(tmp_path):
-    """preseed_resize then resize_policy: params bitwise across the
-    reshard, and the resized learn program loads from the AOT cache
-    with zero fresh compiles."""
+def test_resize_single_process():
+    """resize_policy: params bitwise across the reshard, and the twin
+    learns on the new mesh."""
     import gymnasium as gym
     import jax
 
@@ -314,7 +273,6 @@ def test_resize_warm_cache_single_process(tmp_path):
             "num_sgd_iter": 1,
             "lr": 1e-3,
             "seed": 0,
-            "aot_cache_dir": str(tmp_path),
         },
     )
     rng = np.random.default_rng(42)
@@ -334,16 +292,6 @@ def test_resize_warm_cache_single_process(tmp_path):
             np.float32
         ),
     }
-    tree, bsize = policy.prepare_batch(SampleBatch(host))
-    # pre-seed the shrink geometry BEFORE any notice exists
-    assert fleet.preseed_resize(policy, mesh4, tree, bsize) in (
-        "compiled",
-        "hit",
-    )
-    # a second pre-seed is a cache hit: the seed is durable
-    assert (
-        fleet.preseed_resize(policy, mesh4, tree, bsize) == "hit"
-    )
     policy.learn_on_batch(SampleBatch(host))
     reference = policy.get_weights()
     survivor = fleet.resize_policy(policy, mesh4)
@@ -355,6 +303,3 @@ def test_resize_warm_cache_single_process(tmp_path):
             assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
     stats = survivor.learn_on_batch(SampleBatch(host))
     assert np.isfinite(stats["total_loss"])
-    fn = survivor.learn_fn(bsize)
-    assert fn.aot_source == "aot_cache"
-    assert fn.traces == 0  # zero fresh compiles: warm-cache restart

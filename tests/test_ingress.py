@@ -17,11 +17,10 @@ Covers the ingress-plane contracts:
   autoscaler's input) and the ingress shedding signal read the SAME
   numbers (the satellite regression pin);
 - HTTP/ASGI protocol: real-socket POST/healthz/metrics, keep-alive,
-  and the ASGI app driving the identical dispatch;
-- AOT cold starts: a fresh server restores serialized executables
-  with ZERO fresh compiles of cached buckets, ledger rows carry
-  ``source="aot_cache"`` / ``compile_s=0``, and every cache/version
-  mismatch falls back to live compilation.
+  and the ASGI app driving the identical dispatch.
+
+A replica's cold start on jax's persistent compilation cache is a case
+of ``tests/test_compile_account.py``.
 """
 
 import json
@@ -51,9 +50,7 @@ from ray_tpu.serve.policy_server import (
     BatchedPolicyServer,
     TrailingWindow,
 )
-from ray_tpu.sharding.aot import AOTCompileCache
 from ray_tpu.sharding.compile import compile_stats
-from ray_tpu.telemetry import device as device_ledger
 
 _OBS = gym.spaces.Box(-1.0, 1.0, (4,), np.float32)
 _ACT = gym.spaces.Discrete(2)
@@ -736,165 +733,6 @@ def test_asgi_app_contract(rng):
     finally:
         router.stop()
         server.stop()
-
-
-# -- AOT cold starts ---------------------------------------------------
-
-
-def test_aot_cold_start_zero_compiles(tmp_path, rng):
-    """A fresh replica with a warm AOT cache reaches its first
-    response with ZERO fresh compiles of cached buckets: every serve
-    program restores from disk (source='aot_cache'), the ledger rows
-    carry compile_s=0, and served results stay bitwise-equal to a
-    live-compiled reference."""
-    cache = AOTCompileCache(str(tmp_path / "aot"))
-    device_ledger.clear()
-    device_ledger.enable(analyze=False)
-    try:
-        # replica 1: empty cache — compiles ahead of time and seeds.
-        # Cache entries key on the program label, so fleet replicas
-        # share entries by sharing their deployment name.
-        s1 = _server(name="policy", aot_cache=cache)
-        cache.flush()
-        assert cache.stats()["saves"] == len(s1.buckets)
-        for fn in s1._fns.values():
-            assert fn.aot_source == "aot_live"
-            assert fn.traces == 1
-        seeder_rows = [
-            p
-            for p in device_ledger.snapshot()["programs"]
-            if p["label"].startswith("serve[policy")
-        ]
-        assert all(
-            r["source"] == "aot_live" and r["compile_time_s"] > 0
-            for r in seeder_rows
-        )
-        # model the fresh replica PROCESS: its ledger starts empty
-        device_ledger.clear()
-
-        # replica 2 (fresh functions, same fleet cache): pure hits
-        s2 = _server(name="policy", aot_cache=cache)
-        for fn in s2._fns.values():
-            assert fn.aot_source == "aot_cache"
-            assert fn.traces == 0  # NO fresh compile of any bucket
-        assert (
-            cache.stats()["hits"] >= len(s2.buckets)
-        )
-
-        obs_stream = rng.uniform(-1, 1, (5, 4)).astype(np.float32)
-        ref = _policy()
-        for o in obs_stream:
-            a2, ex2 = s2.submit(o).result(30.0)
-            a_ref, _, ex_ref = ref.compute_actions(
-                o[None], explore=True
-            )
-            assert np.array_equal(a2, a_ref[0])
-            assert np.array_equal(
-                ex2["action_logp"], ex_ref["action_logp"][0]
-            )
-        # the ledger satellite: restored programs register with
-        # compile_s=0 / source="aot_cache" (honest MFU accounting;
-        # no jit:recompile forensics fired for a cache hit)
-        snap = device_ledger.snapshot()
-        joiner_rows = [
-            p
-            for p in snap["programs"]
-            if p["label"].startswith("serve[policy")
-        ]
-        assert len(joiner_rows) == len(s2.buckets)
-        for row in joiner_rows:
-            assert row["source"] == "aot_cache"
-            assert row["compile_time_s"] == 0.0
-            assert row["traces"] == 0
-            assert row["recompile_causes"] == []
-            assert row["executions"] >= 1  # warm forward ran
-        s1.stop()
-        s2.stop()
-    finally:
-        device_ledger.disable()
-        device_ledger.clear()
-        cache.stop()
-
-
-def test_aot_cache_mismatch_falls_back_live(tmp_path, rng):
-    """Every cache failure mode is a MISS that falls back to live
-    compilation: corrupt entries, fingerprint mismatches, and a stale
-    executable that slips through keying but fails at dispatch."""
-    from ray_tpu.sharding import aot as aot_lib
-
-    root = str(tmp_path / "aot")
-    cache = AOTCompileCache(root, writer=False)
-    s1 = _server(name="cachemiss", aot_cache=cache)
-    cache.flush()
-    n_entries = cache.stats()["entries"]
-    assert n_entries == len(s1.buckets)
-    s1.stop()
-
-    # corrupt EVERY entry: loads fail, warmup compiles live, serving
-    # still works — the graceful-fallback acceptance contract
-    import os
-
-    for name in os.listdir(root):
-        with open(os.path.join(root, name), "wb") as f:
-            f.write(b"torn garbage")
-    cache2 = AOTCompileCache(root, writer=False)
-    s2 = _server(name="cachemiss", aot_cache=cache2)
-    assert cache2.stats()["hits"] == 0
-    assert cache2.stats()["load_errors"] == len(s2.buckets)
-    for fn in s2._fns.values():
-        assert fn.aot_source == "aot_live"  # compiled live
-    out, _ = s2.submit(
-        rng.uniform(-1, 1, 4).astype(np.float32)
-    ).result(30.0)
-    assert out in (0, 1)
-    s2.stop()
-
-    # a different fingerprint keys to a DIFFERENT path: entries from
-    # another topology/version are never even opened
-    fp2 = dict(cache.fingerprint_dict)
-    fp2["jax"] = "0.0.0-other"
-    key_here = aot_lib.entry_key("L", ("sig",), cache.fingerprint_dict)
-    key_other = aot_lib.entry_key("L", ("sig",), fp2)
-    assert key_here != key_other
-
-    # a stale executable that somehow installs anyway fails at
-    # dispatch and reverts to live jit (aot_fallbacks counted)
-    s3 = _server(name="c3", warm=True)
-
-    class _Boom:
-        def __call__(self, *a, **k):
-            raise TypeError("argument shapes changed")
-
-    fn = next(iter(s3._fns.values()))
-    fn._aot = _Boom()
-    fn.aot_source = "aot_cache"
-    obs = rng.uniform(-1, 1, 4).astype(np.float32)
-    a, _ = s3.submit(obs).result(30.0)
-    assert fn._aot is None and fn.aot_fallbacks == 1
-    assert a in (0, 1)
-    s3.stop()
-
-
-def test_aot_cache_shared_across_policy_deployment(tmp_path):
-    """PolicyDeployment plumbs a fleet-shared cache DIRECTORY through
-    to its server (replicas in other processes resolve their own
-    client over the same entries)."""
-    from ray_tpu.serve.policy_server import BatchedPolicyServer
-
-    server = BatchedPolicyServer(
-        _policy(),
-        name="plumb",
-        max_batch_size=2,
-        aot_cache=str(tmp_path / "fleet_cache"),
-        start=False,
-    )
-    assert server.aot_cache is not None
-    assert server.aot_cache.root == str(tmp_path / "fleet_cache")
-    server.warmup()
-    server.aot_cache.flush()
-    assert server.aot_cache.stats()["saves"] == len(server.buckets)
-    assert server.stats()["aot"]["saves"] == len(server.buckets)
-    server.stop()
 
 
 @pytest.mark.slow

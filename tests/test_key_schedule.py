@@ -7,8 +7,6 @@ program; these tests hold each schedule to its written-out host loop
 bit for bit, and the standalone rollout to two dispatches.
 """
 
-import gc
-
 import numpy as np
 import pytest
 
@@ -18,7 +16,7 @@ import jax.numpy as jnp
 from ray_tpu.algorithms.ppo.ppo import PPOConfig, PPOJaxPolicy
 from ray_tpu.env.jax_control import CartPoleJax
 from ray_tpu.execution.jax_rollout import JaxRolloutEngine
-from ray_tpu.sharding.compile import compile_stats
+from ray_tpu.sharding import compile as compile_lib
 
 
 def _policy(seed=5):
@@ -169,10 +167,15 @@ def test_lanes_share_one_stream():
 # -- dispatch count --------------------------------------------------------
 
 
-def _per_label(key):
+def _live_programs():
+    with compile_lib._LOCK:
+        return list(compile_lib._REGISTRY)
+
+
+def _per_label(programs, key):
     out = {}
-    for f in compile_stats()["per_function"]:
-        out[f["label"]] = out.get(f["label"], 0) + f[key]
+    for f in programs:
+        out[f.label] = out.get(f.label, 0) + getattr(f, key)
     return out
 
 
@@ -188,26 +191,34 @@ def test_standalone_rollout_is_two_dispatches():
     """After warm-up one ``rollout()`` executes exactly two programs,
     the key schedule and the rollout program, and traces nothing; an
     engine of another T builds a second chain beside the first."""
+    # The counts are read from the programs THIS test makes: whatever
+    # was alive before it is held to the end (so none of it is
+    # collected, and no id of it reused, between two readings) and
+    # never counted.
+    theirs = _live_programs()
+    not_ours = {id(f) for f in theirs}
+
+    def ours(key):
+        made = [f for f in _live_programs() if id(f) not in not_ours]
+        return _per_label(made, key)
+
     env, pol = _policy()
     eng = JaxRolloutEngine(pol, env, 8, 8, seed=5)
     eng.rollout()  # warm-up: both programs trace here
-    # earlier tests' dead programs leave the registry now, not between
-    # the two readings (their labels then read as negative growth)
-    gc.collect()
-    calls, traces = _per_label("calls"), _per_label("traces")
+    calls, traces = ours("calls"), ours("traces")
     eng.rollout()
-    assert _grown(calls, _per_label("calls")) == {
+    assert _grown(calls, ours("calls")) == {
         "rollout_keys[8]": 1,
         "jax_rollout[CartPoleJax:8x8]": 1,
     }
-    assert _grown(traces, _per_label("traces")) == {}
+    assert _grown(traces, ours("traces")) == {}
 
     chains = pol._split_chain_fns
     first = chains[("rollout_keys", (8,), None, None)]
     assert (first.traces, first.calls) == (1, 2)
     other = JaxRolloutEngine(pol, env, 8, 16, seed=5)
     other.rollout()
-    assert _grown(traces, _per_label("traces")) == {
+    assert _grown(traces, ours("traces")) == {
         "rollout_keys[16]": 1,
         "jax_rollout[CartPoleJax:8x16]": 1,
     }

@@ -68,15 +68,12 @@ def test_two_process_dcn_cluster(tmp_path):
     global-mesh psum, cross-host weight broadcast, fleet rendezvous +
     epochs, a coordinator-kill chaos stage (fenced standby failover
     mid-training), and a live resize (drain host1, survivor reshards
-    onto its local mesh with a pre-seeded AOT cache — zero fresh
-    compiles)."""
+    onto its local mesh and learns there)."""
     coord_port = _free_port()
     kv = KVServer(host="127.0.0.1")
     repo_root = os.path.dirname(os.path.dirname(__file__))
     notice_dir = tmp_path / "notices"
     notice_dir.mkdir()
-    aot_dir = tmp_path / "aot"
-    aot_dir.mkdir()
     env_base = {
         **os.environ,
         "PYTHONPATH": repo_root
@@ -88,10 +85,6 @@ def test_two_process_dcn_cluster(tmp_path):
         "RAY_TPU_NUM_PROCESSES": "2",
         "RAY_TPU_KV_ADDRESS": f"127.0.0.1:{kv.port}",
         "RAY_TPU_PREEMPTION_NOTICE_DIR": str(notice_dir),
-        "RAY_TPU_TEST_AOT_DIR": str(aot_dir),
-        # PR-13 ledger on: the worker asserts the survivor's learn
-        # program row registered with source="aot_cache"
-        "RAY_TPU_DEVICE_LEDGER": "1",
         # short lease so the chaos stage's coordinator-kill failover
         # (standby waits out the dead incumbent's TTL) stays fast
         "RAY_TPU_FLEET_LEASE_TTL_S": "2.0",
@@ -139,7 +132,5 @@ def test_two_process_dcn_cluster(tmp_path):
     # elastic learner-fleet case: host1 drained on notice, host0
     # finished the lockstep drain step and continued on its local mesh
     assert "ELASTIC_OK" in outs[0]
-    # the resize contract: params bitwise across the reshard, and the
-    # resized learn program came out of the pre-seeded AOT cache
+    # the resize contract: params bitwise across the reshard
     assert "RESHARD_BITWISE_OK" in outs[0]
-    assert "AOT_RESIZE_HIT" in outs[0]

@@ -23,16 +23,26 @@ from ray_tpu.execution.parallel_requests import (
 
 @ray.remote
 class _Sampler:
-    """Stand-in rollout worker: sample() returns (wid, call#)."""
+    """Stand-in rollout worker: sample() returns (wid, call#). A
+    ``gate`` path holds every sample() until that file exists: "slow"
+    as an event the test releases, not as a number of seconds."""
 
-    def __init__(self, wid, delay=0.0):
+    def __init__(self, wid, delay=0.0, gate=None):
         self.wid = wid
         self.delay = float(delay)
+        self.gate = gate
         self.n = 0
 
     def sample(self):
         if self.delay:
             time.sleep(self.delay)
+        if self.gate:
+            import os
+
+            deadline = time.monotonic() + 120.0
+            while not os.path.exists(self.gate):
+                assert time.monotonic() < deadline, "gate never opened"
+                time.sleep(0.005)
         self.n += 1
         return (self.wid, self.n)
 
@@ -45,7 +55,7 @@ class _Sampler:
 def _make_workers(specs):
     if not ray.is_initialized():
         ray.init()
-    return [_Sampler.remote(wid, d) for wid, d in specs]
+    return [_Sampler.remote(*spec) for spec in specs]
 
 
 def test_in_flight_cap_respected():
@@ -69,9 +79,10 @@ def test_in_flight_cap_respected():
     assert mgr.in_flight(w) == 2
 
 
-def test_ray_wait_harvest_completion_order():
+def test_ray_wait_harvest_completion_order(tmp_path):
     """A slow worker must not gate the fast worker's results."""
-    slow, fast = _make_workers([("slow", 1.5), ("fast", 0.0)])
+    gate = tmp_path / "release_the_slow_worker"
+    slow, fast = _make_workers([("slow", 0.0, str(gate)), ("fast", 0.0)])
     mgr = AsyncRequestsManager(
         [slow, fast], max_remote_requests_in_flight_per_worker=1
     )
@@ -82,6 +93,7 @@ def test_ray_wait_harvest_completion_order():
     assert slow not in got
     assert mgr.in_flight(slow) == 1
     # the straggler still arrives on a later harvest
+    gate.touch()
     got2 = mgr.get_ready(timeout=30.0)
     assert got2 == {slow: [("slow", 1)]}
     assert mgr.num_completed == 2

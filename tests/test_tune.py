@@ -83,7 +83,17 @@ def test_tune_run_stop_on_reward():
     assert t.last_result["training_iteration"] < 50
 
 
-def test_asha_stops_bad_trials():
+# ASHA judges a report against what its rung has recorded SO FAR, so
+# who is cut follows the order results arrive in. Both executors fix
+# that order (every trial one iteration a step; one trial actor at a
+# time), and either way the two far trials reach rung 2 after the two
+# near ones and fall under its median.
+@pytest.mark.parametrize(
+    "executor",
+    [{"parallel": False}, {"max_concurrent_trials": 1}],
+    ids=["in_step", "one_actor_at_a_time"],
+)
+def test_asha_stops_bad_trials(executor):
     scheduler = AsyncHyperBandScheduler(
         max_t=20, grace_period=2, reduction_factor=2
     )
@@ -93,13 +103,18 @@ def test_asha_stops_bad_trials():
         stop={"training_iteration": 20},
         scheduler=scheduler,
         verbose=0,
+        **executor,
     )
-    iters = [
+    ids = [t.trial_id for t in analysis.trials]
+    assert [
         t.last_result["training_iteration"] for t in analysis.trials
-    ]
-    # at least one trial early-stopped before max_t
-    assert min(iters) < 20
-    assert max(iters) == 20
+    ] == [20, 20, 2, 2]
+    # the scheduler's own record: all four at rung 2, the two near
+    # trials alone at every rung above it
+    assert {
+        r["milestone"]: sorted(r["recorded"])
+        for r in scheduler._bracket.rungs
+    } == {2: ids, 4: ids[:2], 8: ids[:2], 16: ids[:2]}
 
 
 def test_pbt_perturbs():

@@ -2,6 +2,8 @@
 ``tune/tests/test_trial_scheduler.py`` HyperBand / median-stopping
 cases)."""
 
+import pytest
+
 from ray_tpu.tune import (
     HyperBandScheduler,
     MedianStoppingRule,
@@ -93,7 +95,26 @@ def test_hyperband_synchronous_cut():
     ) == STOP
 
 
-def test_hyperband_end_to_end():
+# A rung is decided when the last of its population reports, so what
+# each trial reaches follows the ORDER results arrive in. Both
+# executors below fix that order (every trial one iteration a step;
+# one trial actor at a time), so the iterations are the scheduler's
+# decisions and nothing else. With all four actors at once the order
+# is the processes' start-up race, and "someone was cut" a coin.
+@pytest.mark.parametrize(
+    "executor, iterations",
+    [
+        # in step: rung 1 cuts the two far trials as its fourth report
+        # lands (the third has its STOP at its next), rung 2 the
+        # worse of the two left
+        ({"parallel": False}, [3, 8, 2, 1]),
+        # one after another: three brackets' worth run out alone, the
+        # fourth report fills rung 1 and the last trial is cut there
+        ({"max_concurrent_trials": 1}, [8, 8, 8, 1]),
+    ],
+    ids=["in_step", "one_actor_at_a_time"],
+)
+def test_hyperband_end_to_end(executor, iterations):
     from tests.test_tune import _Quadratic as Quad
 
     sched = HyperBandScheduler(max_t=8, reduction_factor=2)
@@ -103,9 +124,15 @@ def test_hyperband_end_to_end():
         stop={"training_iteration": 8},
         scheduler=sched,
         verbose=0,
+        **executor,
     )
-    iters = [
-        t.last_result["training_iteration"] for t in analysis.trials
-    ]
-    assert min(iters) < 8  # someone was cut at a rung
-    assert max(iters) == 8  # the best survived to the end
+    trials = analysis.trials
+    assert [
+        t.last_result["training_iteration"] for t in trials
+    ] == iterations
+    # the decisions: which rung cut whom (x=1.0, the nearest, never)
+    assert sched._stopped_at == {
+        trials[2].trial_id: 1,
+        trials[3].trial_id: 1,
+        trials[0].trial_id: 2,
+    }
