@@ -19,13 +19,16 @@ backend is not a device time.
 
     ... benchmarks/profile_fragment_attention.py step [<case> ...] [<block_k> ...]
 
-The ONE-TOKEN form of a full-depth softmax layer alone, as the rollout
-runs it (all of a cell's streams, depths spread evenly over the episode,
-the caches donated so that the step's scatter is in place): the text
-(every slot under a mask) against ``ops/flash_attention.step_attention``,
+The ONE-TOKEN form of a softmax layer alone, as the rollout runs it (all
+of a cell's streams, depths spread evenly over the episode, the caches
+donated so that the step's scatter is in place): the text (every slot
+under a mask) against ``ops/flash_attention.step_attention``,
 microseconds a step over 5 calls of 64 scanned steps, and the GB/s of
 each over the bytes it moves (the text all slots of both caches, the
-kernel the key blocks its streams hold). ``xing4_latent`` there is the
+kernel the key blocks its streams hold). The ``*_ring`` cases are the
+three ring cells' window layers (SmallThinker's 4,096 slots with half
+its streams in their first turn; Phi-4's and Laguna's 512, six rings in
+turn, microseconds a ring). ``xing4_latent`` there is the
 latent layer's one-token form from the queries and the step's own row on
 (``ops/latent_attention.latent_attention``: the scatter, the absorbed
 query, scores and weighted sum, ``W_kvb``'s value half) at 32 streams 64
@@ -60,12 +63,19 @@ CASES = {
 LATENT_CASES = {
     "xing4_latent": (8, 128, 32, 128, 64, 512, 128, 2048),
 }
-# a cell's streams, key heads, group, head, depth (= episode)
+# a cell's streams, key heads, group, head, depth (= episode); a RING's
+# further three: its window, the episode its streams are spread over and
+# the rings stepped in turn (each with caches of its own: a 512-row ring
+# alone stays in fast memory from step to step, which none does in its
+# cell; the Phi-4 cell's differential pairs are key heads of 128 lanes)
 STEP_CASES = {
     "smallthinker_full": (32, 4, 7, 128, 8192),
     "laguna_full": (16, 8, 6, 128, 4096),
     "qwen3next": (64, 2, 8, 256, 2048),
     "granite4h": (16, 8, 4, 64, 2048),
+    "smallthinker_ring": (32, 4, 7, 128, 4096, 4096, 8192, 1),
+    "phi4flash_ring": (16, 10, 4, 128, 512, 512, 8192, 6),
+    "laguna_ring": (16, 8, 8, 128, 512, 512, 4096, 6),
 }
 # a cell's streams, heads, nope, rope, latent, value head, depth (= episode)
 LATENT_STEP_CASES = {
@@ -225,36 +235,41 @@ def us_a_step(applies, block_k, call, *state, layers=1):
         flash_attention.step_kernel_applies = rule
 
 
-def run_step(name, b, kv, group, d, depth, blocks, pos0=None):
+def run_step(name, b, kv, group, d, depth, window=None, episode=None, layers=1,
+             *, blocks):
     bf = jnp.bfloat16
     h = kv * group
     keys = jax.random.split(jax.random.PRNGKey(0), 5)
     q = jax.random.normal(keys[0], (b, 1, h, d), jnp.float32)
     k = jax.random.normal(keys[1], (b, 1, kv, d), jnp.float32)
     v = jax.random.normal(keys[2], (b, 1, kv, d), jnp.float32)
-    if pos0 is None:  # every stream half a fragment past its place in the episode
-        pos0 = np.arange(b) * (depth // b) + depth // (2 * b)
-    pos0 = jnp.asarray(pos0, jnp.int32)
+    # every stream half a fragment past its place in the episode (a ring
+    # a window deep in an episode of two: half the streams have not
+    # turned it yet)
+    episode = episode or depth
+    pos0 = jnp.asarray(
+        np.arange(b) * (episode // b) + episode // (2 * b), jnp.int32)
     ctx = {"seg": jnp.zeros((b, 1), jnp.int32), "positions": pos0[:, None],
            "pos0": pos0}
 
     def measure(applies, block_k):
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def call(kc, vc):
+        @functools.partial(jax.jit, donate_argnums=(0,))
+        def call(caches):
             def step(caches, i):
-                o, caches, _ = cached_attention.cached_attention(
-                    q + i, k, v, caches, ctx, scale=d ** -0.5, window=None,
-                    dtype=bf, scope="attn")
-                return caches, o
+                outs = [cached_attention.cached_attention(
+                    q + i, k, v, pair, ctx, scale=d ** -0.5, window=window,
+                    dtype=bf, scope="attn")[:2] for pair in caches]
+                # every layer's output is used: none is dead code
+                return [pair for _, pair in outs], sum(o for o, _ in outs)
 
-            (kc, vc), o = jax.lax.scan(
-                step, (kc, vc), jnp.arange(STEPS, dtype=jnp.float32) / STEPS)
-            return o[0], kc, vc
+            caches, o = jax.lax.scan(
+                step, caches, jnp.arange(STEPS, dtype=jnp.float32) / STEPS)
+            return o[0], caches
 
-        return us_a_step(
-            applies, block_k, call,
-            jax.random.normal(keys[3], (b, depth, kv * d), bf),
-            jax.random.normal(keys[4], (b, depth, kv * d), bf))
+        caches = [tuple(jax.random.normal(key, (b, depth, kv * d), bf)
+                        for key in jax.random.split(pair))
+                  for pair in jax.random.split(keys[3], layers)]
+        return us_a_step(applies, block_k, call, caches, layers=layers)
 
     text_us, want = measure(lambda *a: False, None)
     row = 2 * 2 * kv * d  # bytes of a slot's key and value
@@ -264,7 +279,7 @@ def run_step(name, b, kv, group, d, depth, blocks, pos0=None):
         skipped, held = flash_attention.step_key_blocks(pos0 + 1, depth, block_k)
         moved = (held - int(skipped)) * bk * row
         print(json.dumps({
-            "case": name, "form": "step", "block_k": bk,
+            "case": name, "form": "ring_step" if window else "step", "block_k": bk,
             "text_us": round(text_us, 1), "kernel_us": round(us, 1),
             "text_gb_per_s": round(b * depth * row / text_us / 1e3, 1),
             "kernel_gb_per_s": round(moved / us / 1e3, 1),
@@ -335,7 +350,7 @@ def main(argv):
         for name in [a for a in argv[1:] if not a.isdigit()] or [
                 *STEP_CASES, *LATENT_STEP_CASES]:
             if name in STEP_CASES:
-                run_step(name, *STEP_CASES[name], blocks)
+                run_step(name, *STEP_CASES[name], blocks=blocks)
             else:
                 run_latent_step(name, *LATENT_STEP_CASES[name], blocks)
         return

@@ -139,13 +139,15 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
     input. A fragment (``T > 1``): ``flash_attention.fragment_attention``
     where ``fragment_kernel_applies`` says so (a TPU, bfloat16, whole
     blocks), window or none, else the XLA text a block of streams at a
-    time (:func:`env_block`). One token, or one block, at full depth:
+    time (:func:`env_block`). One token, or one block:
     ``flash_attention.step_attention`` where ``step_kernel_applies`` says
     so, which fetches a stream's key blocks below its depth only (a
     block's ``T x group`` queries of a key head are the rows of its one
-    query tile), else ``step_attention_text``. One token over a ring:
-    that text, always (past its first turn a ring has no unwritten slot
-    to skip).
+    query tile), else ``step_attention_text``. A ring's one token takes
+    the same rule: the slots its query sees ARE the ``min(pos0 + 1,
+    depth)`` leading ones (before the first turn slot ``s`` holds
+    position ``s``, from it on every slot holds a row less than
+    ``window`` behind), in whatever order the turns left them.
     ``ray_tpu_attention_{step,fragment}_lowerings_total{path}`` count the
     choice.
 
@@ -176,8 +178,7 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
         new_k, new_v = k_cache, v_cache
 
     qh = (q * scale).astype(dtype).reshape(b, t, hkv, h // hkv, d)
-    step_kernel = not ring and flash_attention.step_kernel_applies(
-        h, hkv, d, depth, dtype)
+    step_kernel = flash_attention.step_kernel_applies(h, hkv, d, depth, dtype)
     stats = {}
 
     def attend(qe, ke, ve, kc, vc, sege, pos0e, pose=None, cleane=None):
@@ -218,12 +219,13 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
         qs = qh if t == 1 else qh.transpose(0, 2, 1, 3, 4).reshape(
             b, 1, hkv, t * (h // hkv), d)
         if step_kernel:
-            # a full-depth cache is half unwritten at the mean: the
-            # tiled step kernel fetches a stream's key blocks below its
-            # depth only
+            # a full-depth cache is half unwritten at the mean, a ring
+            # before its first turn: the tiled step kernel fetches a
+            # stream's key blocks below its depth only
             metrics.inc_attention_step_lowering("kernel")
+            held = jnp.minimum(pos0 + t, depth) if ring else pos0 + t
             with part("scores"):
-                o = flash_attention.step_attention(qs, new_k, new_v, pos0 + t)
+                o = flash_attention.step_attention(qs, new_k, new_v, held)
         else:
             metrics.inc_attention_step_lowering("xla")
             with jax.named_scope(scope):

@@ -817,9 +817,10 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
 
 # -- one token over a stored cache (the sequence models' rollout form) ------
 #
-# ``ops/cached_attention.cached_attention``'s one-token form over a
-# full-depth cache: a stream's query heads over the rows its cache holds,
-# its own (written by the step's scatter) the last of them. One grid step
+# ``ops/cached_attention.cached_attention``'s one-token form: a stream's
+# query heads over the rows its cache holds, its own (written by the
+# step's scatter) among them; a ring's are its leading slots as well,
+# whatever positions they hold, and a softmax has no order. One grid step
 # is one stream, which walks the key blocks it holds in a loop, all key
 # heads of a block together; the query heads of a key head are the rows
 # of one small tile. A stream's depth is a scalar-prefetch operand: the
@@ -1146,11 +1147,11 @@ def _step_one_cache_fwd(q, cache, rows_held, *, value_dim, block_k, interpret):
 def step_attention_text(q, k_cache, v_cache, see, value_dim=None):
     """One token's attention as XLA writes it, every slot under a mask:
     THE one-token text, which ``ops/cached_attention`` runs where no
-    kernel's lowering exists (and over a ring, always) and which is the
-    step kernel's backward pass and oracle. ``q`` ``(B, 1, kv, group,
-    D)`` scaled, in the products' type; the caches ``(B, depth, kv *
-    D)`` after the step's scatter (no ``v_cache``: the ONE key head's
-    first ``value_dim`` lanes are its value); ``see`` ``(B, depth)`` the
+    kernel's lowering exists and which is the step kernel's backward
+    pass and oracle. ``q`` ``(B, 1, kv, group, D)`` scaled, in the
+    products' type; the caches ``(B, depth, kv * D)`` after the step's
+    scatter (no ``v_cache``: the ONE key head's first ``value_dim``
+    lanes are its value); ``see`` ``(B, depth)`` the
     slots each stream's query sees (at full depth those below its rows
     held; in a ring by the positions they hold). Returns ``(B, 1, kv,
     group, D)`` float32, its parts under the scopes ``scores`` and
@@ -1213,9 +1214,10 @@ _step_attention.defvjp(_step_fwd_rule, _step_bwd_rule)
 def step_attention(q, k_cache, v_cache, rows_held, *, value_dim=None,
                    block_k=None, interpret=False):
     """One token's attention over its stream's stored keys and values
-    as one tiled kernel, forward only: of a full-depth cache only the key
-    blocks with a slot below the stream's depth cross HBM, a key block
-    once a key head and the value block once.
+    as one tiled kernel, forward only: only the key blocks with a slot
+    below the stream's depth cross HBM (of a ring: below the rows it has
+    written, all of them from its first turn on), a key block once a key
+    head and the value block once.
 
     ``q`` ``(B, 1, kv, group, D)``, scaled already, in the products'
     type; ``k_cache``, ``v_cache`` ``(B, depth, kv * D)`` AFTER the

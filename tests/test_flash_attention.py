@@ -438,18 +438,20 @@ def _step(b=5, kv=2, group=4, d=128, depth=2048, pos0=(0, 510, 511, 512, 2047),
     return (q, k, v) + caches, rows, normal(b, 1, kv * group, d)
 
 
-def _step_text_and_kernel(rows, kv, dtype, block_k, monkeypatch, gate=False):
+def _step_text_and_kernel(rows, kv, dtype, block_k, monkeypatch, gate=False,
+                          window=None):
     """``(q, k, v, k_cache, v_cache) -> o (B, 1, heads, D)`` twice
     through ``ops/cached_attention``: its XLA text (the rule's
     branch off a TPU) and, the rule forced, the kernel in the
     interpreter; with ``gate`` a sigmoid gate of the query after it, as
-    a gated layer applies one."""
+    a gated layer applies one; with a ``window`` the caches are rings
+    and the text's mask comes from the positions their slots hold."""
     from ray_tpu.ops import flash_attention as fa
     from ray_tpu.ops.cached_attention import cached_attention
 
     def text(q, k, v, kc, vc):
         o = cached_attention(
-            q, k, v, (kc, vc), rows, scale=q.shape[-1] ** -0.5, window=None,
+            q, k, v, (kc, vc), rows, scale=q.shape[-1] ** -0.5, window=window,
             dtype=dtype, scope="attn")[0]
         return o * jax.nn.sigmoid(q) if gate else o
 
@@ -478,24 +480,57 @@ _STEP_CASES = {
     # past the cache's end the scatter drops the key and every slot is seen
     "a_stream_past_the_depth": dict(depth=1024, pos0=(0, 1023, 1024, 2000, 512)),
 }
+# a ring of ``depth`` slots (every slot filled with noise, as a finished
+# episode leaves them): the kernel reads the ``min(pos0 + 1, depth)``
+# leading slots, the text the slots whose POSITION is inside the window
+_STEP_RING_CASES = {
+    # key blocks of 512: one held, two by a row, all; a stream in its
+    # first turn's last step beside one a step into its second
+    "ring_before_the_first_turn": dict(
+        kv=4, group=7, depth=2048, window=2048, pos0=(0, 5, 511, 512, 1500)),
+    "ring_at_the_first_turn": dict(
+        depth=1024, window=1024, pos0=(1021, 1022, 1023, 1024, 1025)),
+    "ring_wrapped_several_times": dict(
+        depth=512, window=512, pos0=(512, 1023, 1024, 3 * 512 + 17, 8191)),
+    # position 0 again over the rows of the episode before, and a
+    # neighbour deep in its own
+    "ring_just_reset_over_old_rows": dict(
+        depth=64, window=64, pos0=(0, 1, 0, 200, 15), block_k=16,
+        dtype=jnp.float32),
+    # an episode shorter than the window: the ring is the episode
+    "ring_depth_below_the_window": dict(
+        depth=64, window=100, pos0=(0, 15, 16, 62, 63), block_k=16,
+        dtype=jnp.float32),
+    "ring_heads_40_kv_20_d_64_packed": dict(
+        kv=20, group=2, d=64, depth=512, window=512, pos0=(0, 63, 511, 512, 4000)),
+}
 _STEP_CONTRACT_CASES = {
     "gradient_is_the_texts": dict(
         depth=64, pos0=(0, 15, 16, 17, 63), block_k=16, dtype=jnp.float32),
     "a_skipped_block_changes_nothing": dict(
         depth=64, pos0=(0, 15, 20, 31, 7), block_k=16, dtype=jnp.float32),
+    "ring_gradient_is_the_texts": dict(
+        depth=64, window=64, pos0=(0, 15, 63, 64, 150), block_k=16,
+        dtype=jnp.float32),
+    # no ring has written past slot 31 yet
+    "ring_a_skipped_block_changes_nothing": dict(
+        depth=64, window=64, pos0=(0, 15, 20, 31, 7), block_k=16,
+        dtype=jnp.float32),
 }
 
 
-@pytest.mark.parametrize("name", list(_STEP_CASES) + list(_STEP_CONTRACT_CASES))
+@pytest.mark.parametrize(
+    "name", list(_STEP_CASES) + list(_STEP_RING_CASES) + list(_STEP_CONTRACT_CASES))
 def test_step_kernel(name, monkeypatch):
-    case = dict({**_STEP_CASES, **_STEP_CONTRACT_CASES}[name])
+    case = dict({**_STEP_CASES, **_STEP_RING_CASES, **_STEP_CONTRACT_CASES}[name])
     block_k, gate = case.pop("block_k", None), case.pop("gate", False)
+    window = case.pop("window", None)
     dtype = case.get("dtype", jnp.bfloat16)
     operands, rows, w = _step(**case)
     text, kernel = _step_text_and_kernel(
-        rows, case.get("kv", 2), dtype, block_k, monkeypatch, gate)
+        rows, case.get("kv", 2), dtype, block_k, monkeypatch, gate, window)
     loss = lambda f: lambda *a: jnp.sum(f(*a) * w)
-    if name == "a_skipped_block_changes_nothing":
+    if name.endswith("a_skipped_block_changes_nothing"):
         # no stream is deeper than 32 of 64 slots: the last two blocks of
         # 16 are skipped for all, so other rows there give the same bits
         q, k, v, *caches = operands
@@ -507,7 +542,7 @@ def test_step_kernel(name, monkeypatch):
         atol=2e-2, rtol=2e-2)
     np.testing.assert_allclose(
         np.asarray(kernel(*operands)), np.asarray(text(*operands)), **tol)
-    if name == "gradient_is_the_texts":
+    if name.endswith("gradient_is_the_texts"):
         # rollout takes none; a differentiated call gets the text's, in
         # the query, the own key and value and the stored rows
         got = jax.grad(loss(kernel), argnums=range(5))(*operands)

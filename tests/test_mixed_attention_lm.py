@@ -111,6 +111,22 @@ def _apply_fn(model):
         p, tok, state, resets=fresh))
 
 
+def _step_kernel_in_the_interpreter(monkeypatch):
+    """What a TPU's rule would say, at this file's sizes: every layer's
+    one-token form on the step kernel in the Pallas interpreter."""
+    import functools
+
+    from ray_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "step_kernel_applies", lambda *a: True)
+    monkeypatch.setattr(
+        flash_attention, "fragment_block_k",
+        lambda depth, _=None: 8 if depth > WINDOW else 4)
+    monkeypatch.setattr(
+        flash_attention, "step_attention",
+        functools.partial(flash_attention.step_attention, interpret=True))
+
+
 @pytest.fixture(scope="module")
 def setup():
     config = small_config()
@@ -217,13 +233,20 @@ def test_every_layer_has_its_own_geometry_and_the_tree_matches(setup):
                       + [(5, EPISODE, 32)] * 2 + [(5,)])
 
 
-@pytest.mark.parametrize("start", [0, 21])
-def test_one_token_steps_from_a_wrapped_ring_equal_the_reference(setup, start):
+@pytest.mark.parametrize("start,forced", [(0, False), (21, False), (21, True)],
+                         ids=["0", "21", "21_on_the_step_kernel"])
+def test_one_token_steps_from_a_wrapped_ring_equal_the_reference(
+        setup, monkeypatch, start, forced):
     """Token by token through the carried caches for an episode's length
     and on into the next (the ring of 8 wraps five times; a reset leaves
     the last episode's rows in it) against the reference's full masked
-    forward."""
+    forward; once more with every layer's step on the kernel (the rule
+    forced, the interpreter), which reads a ring's leading slots by
+    their number."""
     config, params, model, _, fns = setup
+    if forced:
+        _step_kernel_in_the_interpreter(monkeypatch)
+        fns = {"apply": _apply_fn(model)}
     rng = np.random.default_rng(11 + start)
     n, steps = 3, EPISODE + 8
     tokens = rng.integers(0, VOCAB, (n, steps)).astype(np.int32)
@@ -610,15 +633,12 @@ def test_counters_and_statistics_say_every_layer_got_its_geometry(setup):
 @pytest.mark.parametrize("forced", [False, True])
 def test_one_token_form_the_rings_on_the_text_the_full_layers_by_the_rule(
         setup, monkeypatch, forced):
-    """The three gated rings' one-token calls lower to the text whatever
-    the rule says, the two gated full layers' (four query heads over two
-    key heads, YaRN) to the step kernel where it says so, here in the
-    interpreter with the cache of 32 rows as four key blocks of 8: the
-    same logits, values and state; the learn form's statistic counts the
-    blocks a step at each position skips."""
-    import functools
-
-    from ray_tpu.ops import flash_attention
+    """The three gated rings' one-token calls (six query heads over two
+    key heads) and the two gated full layers' (four, YaRN) lower to the
+    step kernel where the rule says so, here in the interpreter with the
+    cache of 32 rows as four key blocks of 8 and a ring's 8 as two of 4:
+    the same logits, values and state; the learn form's statistic counts
+    the blocks a step at each position skips."""
     from ray_tpu.telemetry import metrics
 
     config, params, model, batch, _ = setup
@@ -627,17 +647,12 @@ def test_one_token_form_the_rings_on_the_text_the_full_layers_by_the_rule(
     tokens = jnp.asarray(batch["obs"]).reshape(rows // T, T, 1)
     want = model.apply(params, tokens[:, :1], state)
     if forced:
-        monkeypatch.setattr(flash_attention, "step_kernel_applies", lambda *a: True)
-        monkeypatch.setattr(
-            flash_attention, "fragment_block_k", lambda depth, _=None: 8)
-        monkeypatch.setattr(
-            flash_attention, "step_attention",
-            functools.partial(flash_attention.step_attention, interpret=True))
+        _step_kernel_in_the_interpreter(monkeypatch)
     before = dict(metrics.attention_step_lowerings())
     got = model.apply(params, tokens[:, :1], state)
     now = metrics.attention_step_lowerings()
-    assert now.get("kernel", 0) - before.get("kernel", 0) == (2 if forced else 0)
-    assert now["xla"] - before.get("xla", 0) == (3 if forced else 5)
+    assert now.get("kernel", 0) - before.get("kernel", 0) == (5 if forced else 0)
+    assert now.get("xla", 0) - before.get("xla", 0) == (0 if forced else 5)
     for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(a, b, atol=3e-4, rtol=3e-4)
     stats = {}

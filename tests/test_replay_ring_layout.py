@@ -605,6 +605,11 @@ STEP_LAYERS = {
     # Xing4's latent rows: one key head of 576 lanes for 32 query heads,
     # the value its leading 512 lanes (a sixth number: no value cache)
     "xing4_latent": (32, 1, 32, 576, 2048, 512),
+    # the three ring cells' window layers (Phi-4's differential pairs
+    # are ten key heads of 128 lanes): a ring of 512 rows is ONE key block
+    "smallthinker_ring": (32, 4, 7, 128, 4096),
+    "laguna_ring": (16, 8, 8, 128, 512),
+    "phi4flash_ring": (16, 10, 4, 128, 512),
 }
 
 

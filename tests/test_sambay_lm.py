@@ -19,6 +19,7 @@ reads 30 to 1,000 times the logits' tolerance, the int8 and fp8 controls
 and a bfloat16 scan or memory 10 to 100 times.
 """
 
+import functools
 import importlib.util
 import os
 
@@ -214,12 +215,26 @@ def test_the_uncut_layer_list_counts_the_published_parameters():
 # -- (b) both forms against the reference ---------------------------------------------
 
 
-def test_one_token_steps_through_two_episodes_equal_the_reference(setup):
+@pytest.mark.parametrize("forced", [False, True], ids=["text", "step_kernel"])
+def test_one_token_steps_through_two_episodes_equal_the_reference(
+        setup, monkeypatch, forced):
     """80 steps from an empty state, an episode's end after 40: the scan
     state, the convolution's inputs, the ring (five turns) and the shared
     cache through the carried state against the reference's full forward
-    of both episodes."""
+    of both episodes; once more with the ring's, the full layer's and the
+    cross layer's steps on the kernel (the rule forced, the interpreter;
+    the second episode's first steps read a ring that still holds the
+    first's rows)."""
     config, params, model, fns = setup
+    if forced:
+        monkeypatch.setattr(flash_attention, "step_kernel_applies", lambda *a, **k: True)
+        monkeypatch.setattr(
+            flash_attention, "fragment_block_k",
+            lambda depth, _=None: 8 if depth > WINDOW else 4)
+        monkeypatch.setattr(flash_attention, "step_attention", functools.partial(
+            flash_attention.step_attention, interpret=True))
+        before = dict(metrics.attention_step_lowerings())
+        fns = {"step": jax.jit(lambda p, tok, st, fr: model.apply(p, tok, st, resets=fr))}
     rng = np.random.default_rng(11)
     tokens = rng.integers(0, VOCAB, (2, 2 * EPISODE))
     fresh = np.zeros((2, 2 * EPISODE), bool)
@@ -237,6 +252,10 @@ def test_one_token_steps_through_two_episodes_equal_the_reference(setup):
     for a, b in zip(state[:-1], want[1]["state"][:-1]):
         np.testing.assert_allclose(a, np.asarray(b, np.float32), atol=LOGIT_TOL)
     assert list(np.asarray(state[-1])) == [EPISODE, EPISODE]
+    if forced:  # one trace: the ring, the full layer and its reader
+        now = metrics.attention_step_lowerings()
+        assert now["kernel"] - before.get("kernel", 0) == 3
+        assert now.get("xla", 0) == before.get("xla", 0)
 
 
 @pytest.mark.parametrize("depths,reset_at", [
@@ -514,7 +533,6 @@ def test_a_cross_layers_step_fetches_the_rows_below_the_position_once_and_writes
     position's block is fetched, and a block is fetched once for both
     maps and both value halves: the kernel walks a stream's held blocks
     once); and it hands back no state."""
-    import functools
 
     layer = kinds.AttentionLayer(
         kind="cross_attention", heads=4, kv_heads=2, head_dim=64, scale=0.125,

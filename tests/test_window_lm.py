@@ -495,15 +495,18 @@ def test_window_statistic_and_lowering_counter(setup):
 
 
 def _step_kernel_in_the_interpreter(monkeypatch):
-    """What a TPU's rule would say, at this file's sizes: the full
-    layer's one-token form on the step kernel in the Pallas interpreter,
-    its cache of 32 rows as four key blocks of 8."""
+    """What a TPU's rule would say, at this file's sizes: every layer's
+    one-token form on the step kernel in the Pallas interpreter, the
+    full layer's cache of 32 rows as four key blocks of 8, a ring's 8
+    rows as two of 4."""
     import functools
 
     from ray_tpu.ops import flash_attention
 
     monkeypatch.setattr(flash_attention, "step_kernel_applies", lambda *a: True)
-    monkeypatch.setattr(flash_attention, "fragment_block_k", lambda depth, _=None: 8)
+    monkeypatch.setattr(
+        flash_attention, "fragment_block_k",
+        lambda depth, _=None: 8 if depth > WINDOW else 4)
     monkeypatch.setattr(
         flash_attention, "step_attention",
         functools.partial(flash_attention.step_attention, interpret=True))
@@ -512,11 +515,12 @@ def _step_kernel_in_the_interpreter(monkeypatch):
 @pytest.mark.parametrize("forced", [False, True])
 def test_one_token_form_a_ring_on_the_text_the_full_layer_by_the_rule(
         setup, monkeypatch, forced):
-    """A ring's one-token call lowers to the text whatever the rule
-    says (``ray_tpu_attention_step_lowerings_total{path="xla"}`` counts
-    it), the full layer's to the kernel where the rule says so: the same
-    logits, values and state, and the learn form reports the key blocks
-    a step at each of its positions skips (0 on the text)."""
+    """A ring's one-token call lowers by the full layer's rule
+    (``ray_tpu_attention_step_lowerings_total{path}`` counts all four
+    under ``kernel`` where it says so, under ``xla`` where not): the
+    same logits, values and state, and the learn form reports the key
+    blocks a step at each of its positions skips, the rings' among them
+    (0 on the text)."""
     from ray_tpu.telemetry import metrics
 
     config, params, model, batch, _ = setup
@@ -529,8 +533,8 @@ def test_one_token_form_a_ring_on_the_text_the_full_layer_by_the_rule(
     before = dict(metrics.attention_step_lowerings())
     got = model.apply(params, tokens[:, :1], state)
     now = metrics.attention_step_lowerings()
-    assert now.get("kernel", 0) - before.get("kernel", 0) == (1 if forced else 0)
-    assert now["xla"] - before.get("xla", 0) == (3 if forced else 4)
+    assert now.get("kernel", 0) - before.get("kernel", 0) == (4 if forced else 0)
+    assert now.get("xla", 0) - before.get("xla", 0) == (0 if forced else 4)
     for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(a, b, atol=LOGIT_TOL, rtol=LOGIT_TOL)
     stats = {}
@@ -538,19 +542,21 @@ def test_one_token_form_a_ring_on_the_text_the_full_layer_by_the_rule(
         params, tokens, state,
         resets=jnp.asarray(batch["resets"]).reshape(rows // T, T), stats_out=stats)
     # by hand: a step at position p holds the blocks of 8 up to p's own,
-    # of the full layer's four
+    # of the full layer's four, and of each of the three rings' two
+    # blocks of 4 the second from position 4 on
     pos0 = np.asarray(batch["__chunk__state_in_8"])
     fresh = batch["resets"].reshape(-1, T) > 0.5
-    skipped = []
+    skipped, in_a_ring = [], []
     for n in range(rows // T):
         p = int(pos0[n])
         for i in range(T):
             p = 0 if fresh[n, i] else p
             skipped.append(4 - min(p // 8 + 1, 4))
+            in_a_ring.append(2 - min(p // 4 + 1, 2))
             p += 1
-    assert 0.2 < np.mean(skipped) / 4 < 0.8
+    assert 0.2 < np.mean(skipped) / 4 < 0.8 and 0 < np.mean(in_a_ring) < 1
     assert float(stats["attn_decode_key_blocks_skipped_share"]) == pytest.approx(
-        np.mean(skipped) / 4 if forced else 0.0)
+        (np.mean(skipped) + 3 * np.mean(in_a_ring)) / (4 + 3 * 2) if forced else 0.0)
 
 
 def test_reset_state_leaves_the_rings_and_zeroes_the_position(setup):
