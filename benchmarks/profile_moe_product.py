@@ -100,7 +100,7 @@ def run(name, t, d, f, e, k, held, scoring):
     wd = jax.random.normal(keys[4], (held, f, d), jnp.float32) * f ** -0.5
     ct = jax.random.normal(keys[5], x.shape, jnp.float32)
     indices, weights = jax.jit(jax.vmap(
-        lambda x: moe.route_top_k(x, router, k, True, scoring=scoring)))(x)
+        lambda x: moe.route(x, router, k, True, scoring=scoring)[:2]))(x)
     one_expert = jnp.broadcast_to(
         jnp.arange(held - 1, held - 1 + k, dtype=jnp.int32), indices.shape)
 

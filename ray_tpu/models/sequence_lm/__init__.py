@@ -42,14 +42,14 @@ run's with the layer axis after the stream's); last, the stream's
 position. ``apply`` has two forms that are the same function of the
 same weights: ``T == 1`` is the recurrence (one token, state in and
 out: the rollout lane's step), ``T > 1`` runs a fragment from a stored
-start state (DeltaNet and the state-space layers in chunks, attention
+start state (the delta rules and the state-space layers in chunks, attention
 over the stored keys plus the fragment's own, ``resets`` opening a new
 episode inside it: the learn program's form).
 
 Precision: float32 parameters; the projections, expert products, the
 head and the attention products take bfloat16 operands and accumulate
-in float32; the router, softmax, top-k, ``g``, ``beta``, the DeltaNet
-state, the hyper-connection maps and mixes and every norm are float32
+in float32; the router, softmax, top-k, ``g``, ``beta``, the delta
+rules' state, the hyper-connection maps and mixes and every norm are float32
 (the router, the maps' projection and the delta rule at precision
 "highest"); the state-space recurrence in both forms, ``dt``, ``A``,
 its convolution and the multipliers float32 (the recurrence at
@@ -63,13 +63,14 @@ from ray_tpu.models.sequence_lm.config import (
     Segment, attention_layers_of, describe, layer_types_of)
 from ray_tpu.models.sequence_lm.kinds import (
     AttentionLayer, DeltaNetLayer, DenseLayer, EvaLayer, ExpertLayer, GatedMemoryLayer,
-    HyperResidual, LatentLayer, MambaLayer, Norm, NoSublayer, PlainResidual,
+    HyperResidual, KDALayer, LatentLayer, MambaLayer, Norm, NoSublayer, PlainResidual,
     SelectiveScanLayer)
 from ray_tpu.models.sequence_lm.model import SequenceLM
 
 __all__ = [
     "SequenceLM", "Segment", "describe", "layer_types_of", "attention_layers_of",
-    "AttentionLayer", "LatentLayer", "DeltaNetLayer", "MambaLayer", "EvaLayer",
+    "AttentionLayer", "LatentLayer", "DeltaNetLayer", "KDALayer", "MambaLayer",
+    "EvaLayer",
     "SelectiveScanLayer", "GatedMemoryLayer", "DenseLayer", "ExpertLayer",
     "NoSublayer", "PlainResidual", "HyperResidual", "Norm",
 ]

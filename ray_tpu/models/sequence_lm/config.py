@@ -12,13 +12,14 @@ import numpy as np
 from ray_tpu.models.sequence_lm.generation import Autoregressive, BlockDiffusion
 from ray_tpu.models.sequence_lm.kinds import (
     AttentionLayer, DeltaNetLayer, DenseLayer, EvaLayer, ExpertLayer, GatedMemoryLayer,
-    HyperResidual, LatentLayer, MambaLayer, Norm, NoSublayer, PlainResidual,
+    HyperResidual, KDALayer, LatentLayer, MambaLayer, Norm, NoSublayer, PlainResidual,
     SelectiveScanLayer)
-from ray_tpu.ops import latent_attention
+from ray_tpu.ops import deltanet, latent_attention
 
 LINEAR, FULL, LATENT = "linear_attention", "full_attention", "latent_attention"
 MAMBA, ATTENTION, SLIDING = "mamba", "attention", "sliding_attention"
 EVA = "eva_attention"
+KDA = "kimi_delta_attention"
 SCAN, MEMORY, CROSS = "selective_scan", "gated_memory", "cross_attention"
 # what the SambaY exporters hand on, by name: layer L/2's scan output and
 # layer L/2 + 1's key/value cache
@@ -72,9 +73,14 @@ def _sambay(config: Dict) -> bool:
     return config.get("model_type") == "phi4flash"
 
 
-def _sambay_indices(config: Dict) -> Tuple[Tuple[int, ...], int]:
+def _bailing(config: Dict) -> bool:
+    return config.get("model_type") == "bailing_hybrid"
+
+
+def _held_indices(config: Dict) -> Tuple[Tuple[int, ...], int]:
     """``(the published index of every layer held, the published
-    depth)`` of a ``phi4flash`` config: all ``num_hidden_layers`` of
+    depth)`` of a config whose mixers go by PUBLISHED index
+    (``phi4flash``, ``bailing_hybrid``): all ``num_hidden_layers`` of
     them, or where the depth is cut the ``layer_indices`` list (as many
     as ``num_hidden_layers``) out of ``published_num_hidden_layers``."""
     held = int(config["num_hidden_layers"])
@@ -119,11 +125,18 @@ def layer_types_of(config: Dict) -> Tuple[str, ...]:
     layer, which is also where ``rope_layout`` turns; 0: full depth and
     no positions); else every ``full_attention_interval``-th layer is
     full attention. ``model_type: phi4flash``: :func:`_sambay_kind` of
-    each held layer's published index."""
+    each held layer's published index. ``model_type: bailing_hybrid``
+    (Ling 3.0): by each held layer's published index too, the LAST layer
+    of every ``layer_group_size`` latent attention and the others Kimi
+    Delta Attention."""
     if "hybrid_override_pattern" in config:
         return tuple(mixer for mixer, _ in _pattern_of(config))
+    if _bailing(config):
+        every = int(config["layer_group_size"])
+        return tuple(LATENT if (i + 1) % every == 0 else KDA
+                     for i in _held_indices(config)[0])
     if _sambay(config):  # by each held layer's PUBLISHED index
-        indices, depth = _sambay_indices(config)
+        indices, depth = _held_indices(config)
         return tuple(_sambay_kind(i, depth, int(config.get("mb_per_layer", 2)))
                      for i in indices)
     if config.get("layer_types"):
@@ -186,7 +199,7 @@ def attention_layers_of(config: Dict, layer_types) -> Dict[int, AttentionLayer]:
     out = {}
     plain = _qwen3_moe_stack(c)
     sambay = _sambay(c)
-    indices = _sambay_indices(c)[0] if sambay else ()
+    indices = _held_indices(c)[0] if sambay else ()
     block = generation_of(c).tokens_per_step
     per_layer = c.get("num_attention_heads_per_layer")
     for i, kind in enumerate(layer_types):
@@ -235,16 +248,60 @@ def attention_layers_of(config: Dict, layer_types) -> Dict[int, AttentionLayer]:
     return out
 
 
+def _head_gate(c: Dict) -> bool:
+    """``gated_attention_proj_granularity_type``: only ``head_wise`` (a
+    number a head and token on a mixer's output) is read."""
+    kind = c.get("gated_attention_proj_granularity_type")
+    if kind not in (None, "head_wise"):
+        raise ValueError(f"an output gate of granularity {kind!r} is not supported")
+    return kind == "head_wise"
+
+
 def _latent_layer(c: Dict) -> LatentLayer:
+    """``q_lora_rank`` null (or no such key): NO query latent."""
     rope_dim, nope = int(c["qk_rope_head_dim"]), int(c["qk_nope_head_dim"])
     scaling = c.get("rope_scaling")
     inv_freq = latent_attention.yarn_inv_freq(
         rope_dim, float(c.get("rope_theta", 10000.0)), scaling)
+    q_latent = c.get("q_lora_rank")
     return LatentLayer(
-        heads=int(c["num_attention_heads"]), q_latent=int(c["q_lora_rank"]),
+        heads=int(c["num_attention_heads"]),
+        q_latent=None if q_latent is None else int(q_latent),
         kv_latent=int(c["kv_lora_rank"]), nope=nope, rope_dim=rope_dim,
         v_head=int(c["v_head_dim"]), inv_freq=tuple(float(f) for f in inv_freq),
-        softmax_scale=latent_attention.yarn_softmax_scale(nope + rope_dim, scaling))
+        softmax_scale=latent_attention.yarn_softmax_scale(nope + rope_dim, scaling),
+        interleave=bool(c.get("rope_interleave", False)), gate=_head_gate(c))
+
+
+def _kda_layer(c: Dict) -> KDALayer:
+    """``model_type: bailing_hybrid``: heads of ``head_dim`` for keys and
+    values alike (``num_kv_heads_for_linear_attn`` 0: as many key heads
+    as heads), ``short_conv_kernel_size``, the bounded gate
+    (``kda_safe_gate`` with ``kda_lower_bound``) from ONE matrix
+    (``no_kda_lora``), L2-normed ``q`` and ``k`` (``use_qk_norm``), no
+    value norm, the head-wise output gate. What the kind has no field
+    for is refused by name, and so is a ``kda_lower_bound`` under which
+    the chunked rule's factors leave float32
+    (``ops/deltanet.CHANNEL_LOG_DECAY_FLOOR``)."""
+    heads, head = int(c["num_attention_heads"]), int(c["head_dim"])
+    lower = float(c["kda_lower_bound"])
+    if not deltanet.CHANNEL_LOG_DECAY_FLOOR < lower < 0.0:
+        raise ValueError(
+            f"Kimi Delta Attention: kda_lower_bound {lower} is not inside "
+            f"({deltanet.CHANNEL_LOG_DECAY_FLOOR:.3f}, 0): the chunked rule's "
+            "sub-blocks would overflow float32")
+    unread = [k for k, want in (
+        ("kda_safe_gate", True), ("no_kda_lora", True), ("use_kda_lora", False),
+        ("linear_silu", True), ("use_qk_norm", True), ("value_norm", False),
+        ("group_norm_size", 1), ("num_kv_heads_for_linear_attn", 0),
+    ) if c.get(k, want) != want]
+    if unread or not _head_gate(c):
+        raise ValueError(
+            f"Kimi Delta Attention: {unread or 'no head-wise output gate'} is not "
+            "what the layer computes")
+    return KDALayer(
+        heads=heads, dk=head, dv=head, conv=int(c["short_conv_kernel_size"]),
+        lower=lower)
 
 
 def _eva_layer(c: Dict) -> EvaLayer:
@@ -328,15 +385,16 @@ def _expert_layer(c: Dict, experts: int) -> ExpertLayer:
     width; a ``qwen3_moe`` stack has none. ``nemotron_h`` (a config with
     ``moe_shared_expert_intermediate_size``): DeepSeek-V3's router
     (sigmoid, the selection bias ``e_score_correction_bias``) though it
-    states neither ``scoring_func`` nor ``topk_method``; its ``n_group``
-    and ``topk_group`` are the ROUTER's groups and only 1 is read (the
-    group-limited choice is then the plain top-k; ``n_groups`` is the
-    state-space layer's); experts and shared expert ungated, the
-    activation ``mlp_hidden_act``, the shared expert's width stated and
-    its output not gated."""
-    nemotron = "moe_shared_expert_intermediate_size" in c
-    if nemotron and (int(c.get("n_group", 1)), int(c.get("topk_group", 1))) != (1, 1):
-        raise ValueError("a router that chooses among groups of experts is not supported")
+    states neither ``scoring_func`` nor ``topk_method``; experts and
+    shared expert ungated, the activation ``mlp_hidden_act``, the shared
+    expert's width stated and its output not gated (``n_groups`` is its
+    state-space layer's). ``bailing_hybrid`` (Ling 3.0): DeepSeek-V3's
+    names, ``num_shared_experts`` shared experts of
+    ``moe_shared_expert_intermediate_size``, gated SwiGLU. ``n_group``
+    and ``topk_group`` are the ROUTER's groups under every family that
+    states them (1 and 1, or neither key: the plain top-k)."""
+    bailing = _bailing(c)
+    nemotron = "moe_shared_expert_intermediate_size" in c and not bailing
     primary = "moe_num_primary_experts" in c
     if primary and not c.get("moe_primary_router_apply_softmax", True):
         raise ValueError("a primary router without its softmax is not supported")
@@ -362,6 +420,8 @@ def _expert_layer(c: Dict, experts: int) -> ExpertLayer:
             "routed_scaling_factor", c.get("moe_routed_scaling_factor", 1.0))),
         shared_width=int(
             c["moe_shared_expert_intermediate_size"] if nemotron
+            else int(c.get("num_shared_experts", 1))
+            * int(c["moe_shared_expert_intermediate_size"]) if bailing
             else c["shared_expert_intermediate_size"] if stated
             else int(c.get(
                 "n_shared_experts",
@@ -371,6 +431,7 @@ def _expert_layer(c: Dict, experts: int) -> ExpertLayer:
         gated=not nemotron,
         # every masked position of a pass routes alike
         alone=3 if generation_of(c).tokens_per_step > 1 else 0,
+        n_group=int(c.get("n_group", 1)), topk_group=int(c.get("topk_group", 1)),
     )
 
 
@@ -414,7 +475,8 @@ def describe(config: Dict) -> Dict:
     layer_types = layer_types_of(c)
     layers = len(layer_types)
     attention = attention_layers_of(c, layer_types)
-    others = {LINEAR: _deltanet_layer, LATENT: _latent_layer, MAMBA: _mamba_layer,
+    others = {LINEAR: _deltanet_layer, LATENT: _latent_layer, KDA: _kda_layer,
+              MAMBA: _mamba_layer,
               EVA: _eva_layer, NONE: lambda c: NoSublayer(),
               SCAN: _selective_scan_layer,
               MEMORY: lambda c: GatedMemoryLayer(
@@ -462,7 +524,7 @@ def describe(config: Dict) -> Dict:
     if _sambay(c):
         # the LAST scan of the self-decoder is the one whose output is
         # the memory (published layer depth / 2)
-        indices, depth = _sambay_indices(c)
+        indices, depth = _held_indices(c)
         mixers = [
             dataclasses.replace(m, exports_as=_MEMORY)
             if kind == SCAN and i == depth // 2 else m

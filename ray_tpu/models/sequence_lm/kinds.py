@@ -479,7 +479,12 @@ class LatentLayer(Kind):
     k_nope + rope(q_pe) . k_pe) * s``, ``s = (nope + rope)^-1/2 * m^2``,
     causal softmax, ``o = P v``, output projection. The query/key product
     is ``nope + rope`` wide, the value product ``v_head``
-    (``ops/latent_attention.py`` holds its forms and picks).
+    (``ops/latent_attention.py`` holds its forms and picks). A config
+    whose ``q_lora_rank`` is null has NO query latent (``q_latent``
+    None): ``q = x W_q``, one matrix and no query norm
+    (``bailing_hybrid``, which also turns ADJACENT pairs in RoPE,
+    ``interleave``, and multiplies each head's output by ``sigmoid(x
+    W_g)``, a number a head and token, ``gate``).
 
     State: ONE leaf of latent rows, ``(positions, kv_latent + rope_dim)``
     in the operands' type: the normed latent and the roped key part,
@@ -487,13 +492,17 @@ class LatentLayer(Kind):
     ``/scores``, ``/out``."""
 
     heads: int
-    q_latent: int
+    q_latent: Optional[int]  # None: no query latent, ``q = x W_q``
     kv_latent: int
     nope: int
     rope_dim: int
     v_head: int
     inv_freq: Tuple[float, ...]  # ``yarn_inv_freq`` of the rope part
     softmax_scale: float
+    # RoPE turns adjacent pairs (``rope_interleave``), not the halves
+    interleave: bool = False
+    # the output times ``sigmoid(x W_g)``, a number a head (``g_proj``)
+    gate: bool = False
 
     stats = {
         "attn_key_blocks_skipped": "sum", "attn_key_blocks_walked": "sum",
@@ -503,15 +512,21 @@ class LatentLayer(Kind):
 
     def param_shapes(self, d: int):
         h, row = self.heads, self.kv_latent + self.rope_dim
-        return dict(
-            q_a=(d, self.q_latent),
-            q_a_norm=(self.q_latent,),
-            q_b=(self.q_latent, h * (self.nope + self.rope_dim)),
+        q_width = h * (self.nope + self.rope_dim)
+        shapes = dict(
             kv_a=(d, row),
             kv_a_norm=(self.kv_latent,),
             kv_b=(self.kv_latent, h * (self.nope + self.v_head)),
             o_proj=(h * self.v_head, d),
         )
+        if self.q_latent is None:
+            shapes["q_proj"] = (d, q_width)
+        else:
+            shapes.update(q_a=(d, self.q_latent), q_a_norm=(self.q_latent,),
+                          q_b=(self.q_latent, q_width))
+        if self.gate:
+            shapes["g_proj"] = (d, h)
+        return shapes
 
     def state_shapes(self, streams: int, positions: int, dtype):
         return [((streams, positions, self.kv_latent + self.rope_dim), dtype)]
@@ -523,18 +538,25 @@ class LatentLayer(Kind):
             b, t, _ = x.shape
             h, dn, c = self.heads, self.nope, self.kv_latent
             inv_freq = np.asarray(self.inv_freq, np.float32)
-            c_q = rms(dot(x, p["q_a"], dtype), p["q_a_norm"], eps)
-            q = dot(c_q, p["q_b"], dtype).reshape(b, t, h, dn + self.rope_dim)
-            q_pe = latent_attention.rope(q[..., dn:], positions, inv_freq)
+            rope = lambda r: latent_attention.rope(
+                r, positions, inv_freq, self.interleave)
+            if self.q_latent is None:
+                q = dot(x, p["q_proj"], dtype)
+            else:
+                q = dot(rms(dot(x, p["q_a"], dtype), p["q_a_norm"], eps), p["q_b"], dtype)
+            q = q.reshape(b, t, h, dn + self.rope_dim)
+            q_pe = rope(q[..., dn:])
             kv = dot(x, p["kv_a"], dtype)
-            k_pe = latent_attention.rope(
-                kv[:, :, None, c:], positions, inv_freq)[:, :, 0]
+            k_pe = rope(kv[:, :, None, c:])[:, :, 0]
             rows_new = jnp.concatenate(
                 [rms(kv[..., :c], p["kv_a_norm"], eps), k_pe], axis=-1
             ).astype(cache.dtype)
             o, new_cache, stats = latent_attention.latent_attention(
                 q[..., :dn], q_pe, rows_new, cache, p["kv_b"], ctx,
                 scale=self.softmax_scale, dtype=dtype)
+            if self.gate:
+                with jax.named_scope("gate"):
+                    o = o * jax.nn.sigmoid(dot(x, p["g_proj"], dtype))[..., None]
             return (dot(o.reshape(b, t, h * self.v_head), p["o_proj"], dtype),
                     (new_cache,), stats)
 
@@ -724,6 +746,109 @@ class DeltaNetLayer(Kind):
             o = rms(o, p["gdn_norm"], ctx["eps"], centred=False)
             o = o * jax.nn.silu(z.reshape(b, t, hv, self.dv))
             return dot(o.reshape(b, t, vd), p["out_proj"], dtype), (s1, new_tail), {}
+
+
+@dataclasses.dataclass(frozen=True)
+class KDALayer(Kind):
+    """``"kimi_delta_attention"`` (Kimi Delta Attention: Kimi Linear,
+    arXiv:2510.26692, over the gated delta rule of arXiv:2412.06464; as
+    ``model_type: bailing_hybrid`` configures it): ``q~ = x W_q``, ``k~ =
+    x W_k``, ``v~ = x W_v``, each through its OWN causal depthwise
+    convolution (width ``conv``, no bias) and SiLU; per head ``q =
+    l2norm(q~) dk^-1/2``, ``k = l2norm(k~)``, ``v = v~``. The decay is a
+    number a head AND KEY CHANNEL from one matrix (``no_kda_lora``): ``a
+    = x W_f``, ``g = lower * sigmoid(exp(A_log_h) (a + dt_bias))``, so
+    every log-decay lies in ``(lower, 0)`` (``kda_safe_gate``,
+    ``kda_lower_bound`` -5: what lets ``ops/deltanet.py``'s chunked form
+    keep sub-blocks of 16 in float32; the layer adds no clip). ``beta =
+    sigmoid(x W_b)``, one a head. The rule, per head with a ``(dk, dv)``
+    float32 state: ``S <- diag(exp(g_t)) S; d = beta_t (v_t - S^T k_t);
+    S <- S + k_t d^T; o_t = S^T q_t`` (one token the step, a fragment in
+    chunks). ``o <- rms(o) * w`` per head, times ``sigmoid(x W_g)``, a
+    number a head and token (``head_wise``), then ``W_o``. No positions.
+    As many key heads as heads. Starts with ``A`` uniform in (1, 16) and
+    ``dt_bias`` zero.
+
+    State: the ``(heads, dk, dv)`` float32 matrix and the last ``conv -
+    1`` inputs of each of the three convolutions. Scopes: ``kda`` and
+    its ``/gate`` (the decay, ``beta``), ``/conv``, ``/rule``, ``/out``
+    (norm, output gate, ``W_o``); the three projections under ``kda``
+    itself."""
+
+    heads: int
+    dk: int
+    dv: int
+    conv: int
+    lower: float  # the log-decay's bound, below 0
+
+    cleared_on_reset = True
+    init_rules = {
+        "A_log": lambda key, shape: jnp.log(
+            jax.random.uniform(key, shape, minval=1.0, maxval=16.0)),
+        "kda_norm": _ones}
+
+    def param_shapes(self, d: int):
+        kd, vd, h = self.heads * self.dk, self.heads * self.dv, self.heads
+        return dict(
+            q_proj=(d, kd), k_proj=(d, kd), v_proj=(d, vd),
+            q_conv=(kd, self.conv), k_conv=(kd, self.conv), v_conv=(vd, self.conv),
+            f_proj=(d, kd), A_log=(h,), dt_bias=(kd,),
+            b_proj=(d, h), g_proj=(d, h),
+            kda_norm=(self.dv,), out_proj=(vd, d),
+        )
+
+    def state_shapes(self, streams: int, positions: int, dtype):
+        kd, vd = self.heads * self.dk, self.heads * self.dv
+        return [((streams, self.heads, self.dk, self.dv), jnp.float32)] + [
+            ((streams, self.conv - 1, width), jnp.float32) for width in (kd, kd, vd)]
+
+    def operands(self, p, x, tails, ctx):
+        """What the rule reads of ``x`` ``(B, T, D)``: ``((q, k, v, g,
+        beta), the convolutions' new tails)``, ``g`` a number a head and
+        key channel inside ``(lower, 0)``. Under the caller's ``kda``
+        scope; a comparison of the rule alone calls it too
+        (``perf/checks/kda_rule.py``)."""
+        dtype = ctx["dtype"]
+        b, t, _ = x.shape
+        h, dk, dv = self.heads, self.dk, self.dv
+        with jax.named_scope("gate"):
+            a = dot(x, p["f_proj"], dtype) + p["dt_bias"]
+            g = self.lower * jax.nn.sigmoid(
+                jnp.exp(p["A_log"])[:, None] * a.reshape(b, t, h, dk))
+            beta = jax.nn.sigmoid(jnp.dot(x, p["b_proj"], precision=HI))
+        mixed, new_tails = [], []
+        for name, tail in zip("qkv", tails):
+            y = dot(x, p[name + "_proj"], dtype)
+            with jax.named_scope("conv"):
+                y, tail = causal_conv(tail, y, ctx["seg"], p[name + "_conv"])
+            mixed.append(y)
+            new_tails.append(tail)
+        with jax.named_scope("rule"):
+            q = l2norm(mixed[0].reshape(b, t, h, dk)) * (dk ** -0.5)
+            k = l2norm(mixed[1].reshape(b, t, h, dk))
+            v = mixed[2].reshape(b, t, h, dv)
+        return (q, k, v, g, beta), new_tails
+
+    def apply(self, p, x, state, ctx):
+        with jax.named_scope(ctx["scope"] + "kda"):
+            s0, *tails = state
+            dtype = ctx["dtype"]
+            b, t, _ = x.shape
+            (q, k, v, g, beta), new_tails = self.operands(p, x, tails, ctx)
+            with jax.named_scope("rule"):
+                if t == 1:
+                    s1, o = deltanet.gated_delta_step(
+                        s0, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+                    o = o[:, None]
+                else:
+                    o, s1 = deltanet.gated_delta_chunked(
+                        s0, q, k, v, g, beta,
+                        resets=ctx["fresh"].astype(jnp.float32), chunk=ctx["chunk"])
+            with jax.named_scope("out"):
+                o = rms(o, p["kda_norm"], ctx["eps"], centred=False)
+                o = o * jax.nn.sigmoid(dot(x, p["g_proj"], dtype))[..., None]
+                out = dot(o.reshape(b, t, self.heads * self.dv), p["out_proj"], dtype)
+            return out, (s1, *new_tails), {}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1031,7 +1156,15 @@ class ExpertLayer(Kind):
       shared expert; the model generates by masked diffusion, so every
       ``[MASK]`` of a noisy pass meets the first layer's router as the
       same vector and ``alone`` of the held experts may take a whole
-      pass's tokens.
+      pass's tokens;
+    - ``bailing_hybrid`` (Ling 3.0): DeepSeek-V3's router WITH its
+      groups: the ``router_outputs`` are ``n_group`` groups of
+      consecutive experts, a token keeps the ``topk_group`` groups whose
+      two best ``score + select_bias`` add up highest and takes its
+      top-k among their experts only (``n_group`` 1, every other
+      family's: the plain top-k). Where the held experts lie in one
+      group, the tokens that did not choose it send nothing here;
+      ``moe_held_group_chosen_share`` is the share that did.
 
     Scopes: ``moe/route`` (entered before the mixer where the router
     reads the input), ``moe/experts``, ``moe/shared``."""
@@ -1055,6 +1188,10 @@ class ExpertLayer(Kind):
     # held experts of a grouped call that may outgrow their buffers and
     # run over every token before the call goes dense (``ops/moe.py``)
     alone: int = 0
+    # the router's groups of consecutive experts, and those a token
+    # keeps before its top-k (1 and 1: no groups)
+    n_group: int = 1
+    topk_group: int = 1
 
     init_rules = {
         "select_bias": lambda key, shape: 0.01 * jax.random.normal(key, shape)}
@@ -1068,6 +1205,9 @@ class ExpertLayer(Kind):
         "moe_rows_computed_share": "mean",
         # every token's expert set
         "moe_routes": "tokens",
+        # of the tokens, those whose chosen groups hold a held expert (a
+        # group-limited router's layers only)
+        "moe_held_group_chosen_share": "mean",
     }
 
     def param_shapes(self, d: int):
@@ -1090,13 +1230,13 @@ class ExpertLayer(Kind):
         return shapes
 
     def route(self, p, flat):
-        """Every token's ``(indices, weights)`` from ``flat`` ``(tokens,
-        D)``: the expert layer's input, or the block's where the router
-        stands before the mixer."""
-        return moe.route_top_k(
+        """Every token's ``(indices, weights, the groups it chose or
+        None)`` from ``flat`` ``(tokens, D)``: the expert layer's input,
+        or the block's where the router stands before the mixer."""
+        return moe.route(
             flat, p["router"], self.top_k, self.norm_topk,
             scoring=self.scoring, select_bias=p.get("select_bias"),
-            scale=self.scale,
+            scale=self.scale, n_group=self.n_group, topk_group=self.topk_group,
         )
 
     def apply(self, p, x, state, ctx):
@@ -1109,7 +1249,7 @@ class ExpertLayer(Kind):
         metrics.inc_moe_product_lowering(lowering)
         with jax.named_scope(scope + "moe/route"):
             # routed already where the router reads the block's input
-            indices, weights = ctx.get("route") or self.route(p, flat)
+            indices, weights, groups = ctx.get("route") or self.route(p, flat)
             if lowering == "dense":
                 combine = moe.held_combine_weights(indices, weights, self.first, held)
             per_expert, absent = moe.expert_load(indices, self.first, held)
@@ -1126,6 +1266,12 @@ class ExpertLayer(Kind):
                 ) / (b * t * held),
                 "moe_routes": indices,
             }
+            if groups is not None:
+                # the groups this chip's experts lie in (static)
+                size = self.router_outputs // self.n_group
+                mine = sorted({e // size for e in range(self.first, self.first + held)})
+                stats["moe_held_group_chosen_share"] = jnp.mean(
+                    jnp.any(groups[:, mine], axis=-1), dtype=jnp.float32)
         # no gate matrix where the experts are ungated
         experts = (p["experts_gate"] if self.gated else None,
                    p["experts_up"], p["experts_down"])

@@ -9,6 +9,14 @@ and a write strength ``beta_t`` in (0, 1)::
     S   <- S + k_t d_t^T
     o_t  = S^T q_t
 
+**The decay is a number a head, or a number a KEY CHANNEL** (Kimi Delta
+Attention, Kimi Linear, arXiv:2510.26692): ``g`` with a trailing ``dk``
+axis makes the first line ``S <- diag(exp(g_t)) S``, a row of ``S`` a
+channel. Both forms take either, told apart by ``g``'s rank when they
+are traced; the scalar decay keeps the text it had (PERF.md, PR 61: the
+shared path is split, not adapted), and 128 equal channels give what
+the scalar gives, to rounding.
+
 - :func:`gated_delta_step` is that recurrence for ONE token: the
   rollout lane's decode step (state in, state out).
 - :func:`gated_delta_chunked` computes a fragment of ``T`` tokens from
@@ -17,7 +25,15 @@ and a write strength ``beta_t`` in (0, 1)::
   by the nilpotent product ``(I - A)(I + A^2)(I + A^4)...``, all matrix
   products), and only the chunk-end state is carried: the learn
   program's form, whose backward pass keeps ``T / C`` states instead
-  of ``T``.
+  of ``T``. A decay a channel has no ``(C, C)`` factor: each product
+  over ``dk`` carries ``exp(G_i - G_j)`` INSIDE the sum
+  (:func:`_channel_decayed_products`), so the chunk is cut into
+  sub-blocks of ``_SUB`` rows and both operands are scaled against a
+  sub-block's FIRST row: every factor is at most 1 except inside a
+  diagonal sub-block, where it is at most ``exp(-(_SUB - 1) min g)``.
+  That is finite in float32 for the bounded gate the layer computes
+  (``g > -5``: ``exp(75)``) and for nothing much below it: the bound is
+  the model's (``kda_safe_gate``), and no clip is added here.
 
 **The one-token form has two lowerings of one algorithm**, picked by
 what the code can see when it is traced, never by an option:
@@ -34,8 +50,9 @@ what the code can see when it is traced, never by an option:
   elementwise op that consumes its result into one fusion, so on a TPU
   this body reads every matrix three times and writes it once.
 
-``ray_tpu_deltanet_step_lowerings_total{path="kernel"|"xla"}`` counts,
-at trace time, which one each traced one-token form took.
+``ray_tpu_deltanet_step_lowerings_total{path="kernel"|"xla",
+decay="head"|"channel"}`` counts, at trace time, which one each traced
+one-token form took and for which decay.
 
 **Resets.** ``resets`` (1.0 where a token begins a new episode) zero
 the state before that token. In the chunked form a reset splits its
@@ -59,6 +76,7 @@ the recurrence said.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -75,21 +93,32 @@ _HI = jax.lax.Precision.HIGHEST
 # matrices in and out at 128 x 128 (on the v5e 8 heads a step were 4%
 # slower, 32 no faster)
 _KERNEL_HEADS = 16
+# rows of a chunk's sub-block under a decay a channel (Kimi Linear's 16)
+_SUB = 16
+# what a log-decay a channel must stay above for the chunked form's
+# largest factor, ``exp(-(_SUB - 1) g)``, to be finite in float32
+# (``exp(88)`` is the last that is): a layer whose gate can go below it
+# is refused where it is described, not clipped here
+CHANNEL_LOG_DECAY_FLOOR = -88.0 / (_SUB - 1)
 
 
 def gated_delta_step(state, q, k, v, g, beta):
     """One token of the recurrence. ``state`` ``(..., dk, dv)``; ``q``,
-    ``k`` ``(..., dk)``; ``v`` ``(..., dv)``; ``g``, ``beta`` ``(...)``.
-    Returns ``(state, o)`` with ``o`` ``(..., dv)``."""
+    ``k`` ``(..., dk)``; ``v`` ``(..., dv)``; ``beta`` ``(...)``; ``g``
+    ``(...)``, or ``(..., dk)``: a decay a key channel. Returns
+    ``(state, o)`` with ``o`` ``(..., dv)``."""
+    decay = "channel" if g.ndim == k.ndim else "head"
     if _kernel_applies(state):
-        telemetry_metrics.inc_deltanet_step_lowering("kernel")
+        telemetry_metrics.inc_deltanet_step_lowering("kernel", decay)
         return gated_delta_step_kernel(state, q, k, v, g, beta)
-    telemetry_metrics.inc_deltanet_step_lowering("xla")
+    telemetry_metrics.inc_deltanet_step_lowering("xla", decay)
     return _delta_step_body(state, q, k, v, g, beta)
 
 
 def _delta_step_body(state, q, k, v, g, beta):
-    state = state * jnp.exp(g)[..., None, None]
+    # a decay a channel scales the rows, a decay a head the matrix
+    decay = jnp.exp(g)
+    state = state * (decay[..., None] if g.ndim == k.ndim else decay[..., None, None])
     read = jnp.einsum("...kv,...k->...v", state, k, precision=_HI)
     delta = beta[..., None] * (v - read)
     state = state + k[..., :, None] * delta[..., None, :]
@@ -113,19 +142,23 @@ def _kernel_applies(state) -> bool:
 
 
 def _delta_step_kernel(decay_ref, beta_ref, k_ref, q_ref, v_ref, s_ref,
-                       s_out_ref, o_ref):
+                       s_out_ref, o_ref, *, channel: bool):
     """One stream, a block of heads. ``s_ref`` ``(1, H, dk, dv)``; the
-    other inputs ``(1, H, width)`` rows, ``decay`` and ``beta`` repeated
-    along the lanes. ``k`` and ``q`` arrive with ``dk`` on the lanes and
-    are turned once a block, so that a head's column broadcasts along
-    the lanes of its matrix."""
+    other inputs ``(1, H, width)`` rows, ``beta`` repeated along the
+    lanes, and ``decay`` too where it is a number a head; with
+    ``channel`` it is a row of ``dk``, a number a key channel. ``k`` and
+    ``q`` (and a decay a channel) arrive with ``dk`` on the lanes and are
+    turned once a block, so that a head's column broadcasts along the
+    lanes of its matrix."""
     heads = s_ref.shape[1]
     k_cols, q_cols = k_ref[0].T, q_ref[0].T  # (dk, H)
     decay, beta, v = decay_ref[0], beta_ref[0], v_ref[0]
+    if channel:
+        decay = decay.T  # (dk, H): a head's column scales its rows
     outs = []
     for h in range(heads):
         k_col, q_col = k_cols[:, h : h + 1], q_cols[:, h : h + 1]
-        s = s_ref[0, h] * decay[h : h + 1]
+        s = s_ref[0, h] * (decay[:, h : h + 1] if channel else decay[h : h + 1])
         read = jnp.sum(s * k_col, axis=0, keepdims=True)
         delta = beta[h : h + 1] * (v[h : h + 1] - read)
         s = s + k_col * delta
@@ -148,7 +181,9 @@ def gated_delta_step_kernel(state, q, k, v, g, beta, *, interpret=False):
 
     b, h, dk, dv = state.shape
     heads = _KERNEL_HEADS if h % _KERNEL_HEADS == 0 else 8
-    decay = jnp.broadcast_to(jnp.exp(g)[..., None], (b, h, dv))
+    channel = g.ndim == 3  # a row of ``dk`` a head, as ``k`` is
+    decay = jnp.exp(g) if channel else jnp.broadcast_to(
+        jnp.exp(g)[..., None], (b, h, dv))
     beta = jnp.broadcast_to(beta[..., None], (b, h, dv))
     # inside a ``shard_map`` the outputs vary over the mesh axes the
     # inputs do
@@ -156,9 +191,10 @@ def gated_delta_step_kernel(state, q, k, v, g, beta, *, interpret=False):
     rows = lambda width: pl.BlockSpec((1, heads, width), lambda i, j: (i, j, 0))
     matrices = pl.BlockSpec((1, heads, dk, dv), lambda i, j: (i, j, 0, 0))
     return pl.pallas_call(
-        _delta_step_kernel,
+        functools.partial(_delta_step_kernel, channel=channel),
         grid=(b, h // heads),
-        in_specs=[rows(dv), rows(dv), rows(dk), rows(dk), rows(dv), matrices],
+        in_specs=[rows(dk if channel else dv), rows(dv), rows(dk), rows(dk),
+                  rows(dv), matrices],
         out_specs=[matrices, rows(dv)],
         out_shape=[
             jax.ShapeDtypeStruct(state.shape, state.dtype, vma=vma),
@@ -190,6 +226,31 @@ def _unit_lower_inverse(a):
     return inv
 
 
+def _channel_decayed_products(rows, k, gcum):
+    """``out[.., i, j] = sum_c rows[.., i, c] k[.., j, c] exp(G[.., i, c]
+    - G[.., j, c])`` for ``i >= j`` (entries above the diagonal are
+    finite and meaningless: the caller masks them). ``rows`` ``(R, B, H,
+    C, dk)``: the ``R`` left operands that share ``k`` and ``gcum``
+    ``(B, H, C, dk)``, the chunk's keys and inclusive running sums of
+    the log-decays. A block of ``sub`` rows takes its FIRST row's sums
+    as the reference: its own rows are scaled by ``exp(G_i - G_ref) <=
+    1``, the keys by ``exp(G_ref - G_j)``, which is at most 1 for a key
+    of an earlier block and at most ``exp(-(sub - 1) min g)`` for a key
+    of the block itself; a key of a later block gets 0."""
+    c, dk = k.shape[-2:]
+    sub = math.gcd(c, _SUB)
+    n = c // sub
+    blocked = gcum.reshape(gcum.shape[:-2] + (n, sub, dk))
+    ref = blocked[..., :1, :]  # (B, H, n, 1, dk)
+    left = rows.reshape(rows.shape[:-2] + (n, sub, dk)) * jnp.exp(blocked - ref)
+    # keys up to the block's last row, against the block's reference
+    seen = (jnp.arange(c) // sub)[None, :] <= jnp.arange(n)[:, None]  # (n, C)
+    right = k[..., None, :, :] * jnp.exp(jnp.where(
+        seen[..., None], ref - gcum[..., None, :, :], -jnp.inf))  # (B, H, n, C, dk)
+    out = jnp.einsum("rbhnik,bhnjk->rbhnij", left, right, precision=_HI)
+    return out.reshape(rows.shape[:-2] + (c, c))
+
+
 def gated_delta_chunked(
     state,
     q,
@@ -201,8 +262,10 @@ def gated_delta_chunked(
     chunk: int = 64,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``T`` tokens from ``state``. ``q``, ``k`` ``(B, T, H, dk)``; ``v``
-    ``(B, T, H, dv)``; ``g``, ``beta`` ``(B, T, H)``; ``state`` ``(B, H,
-    dk, dv)``; ``resets`` ``(B, T)`` or None. ``T`` is a multiple of
+    ``(B, T, H, dv)``; ``beta`` ``(B, T, H)``; ``g`` ``(B, T, H)``, or
+    ``(B, T, H, dk)``: a decay a key channel, which must stay above
+    ``CHANNEL_LOG_DECAY_FLOOR`` (module docstring); ``state`` ``(B, H, dk,
+    dv)``; ``resets`` ``(B, T)`` or None. ``T`` is a multiple of
     ``chunk`` (or shorter than it). Returns ``(o (B, T, H, dv), state)``.
     """
     b, t, h, dk = q.shape
@@ -211,6 +274,7 @@ def gated_delta_chunked(
     if t % c:
         raise ValueError(f"fragment of {t} tokens is not a multiple of {c}")
     n = t // c
+    channel = g.ndim == 4
     if resets is None:
         resets = jnp.zeros((b, t), jnp.float32)
 
@@ -227,33 +291,52 @@ def gated_delta_chunked(
 
     def one_chunk(s, x):
         qc, kc, vc, gc, bc, rc = x
-        gcum = jnp.cumsum(gc, axis=-1)  # (B, H, C)
         seg = jnp.cumsum((rc > 0.5).astype(jnp.int32), axis=-1)[:, None]
         same = seg[..., :, None] == seg[..., None, :]  # (B, 1, C, C)
-        # the start state reaches the tokens before the first reset
-        reach = jnp.exp(gcum) * (seg == 0)
-        diff = gcum[..., :, None] - gcum[..., None, :]
-        decay = jnp.exp(jnp.where(lower & same, diff, -jnp.inf))
         kb = kc * bc[..., None]
-        a = jnp.einsum("bhik,bhjk->bhij", kb, kc, precision=_HI)
-        solve = _unit_lower_inverse(jnp.where(strictly_lower, a * decay, 0.0))
+        # ``a``: the writes' products under their decays, strictly below
+        # the diagonal; ``qk``: the reads', on and below it; ``reach``
+        # and ``tail``: the decay from the chunk's start to each row and
+        # from each row to the chunk's end, a column (or a number a
+        # channel) of each row
+        if channel:
+            gcum = jnp.cumsum(gc, axis=-2)  # (B, H, C, dk)
+            a, qk = _channel_decayed_products(jnp.stack([kb, qc]), kc, gcum)
+            a = jnp.where(strictly_lower & same, a, 0.0)
+            qk = jnp.where(lower & same, qk, 0.0)
+            # the start state reaches the tokens before the first reset
+            reach = jnp.exp(gcum) * (seg == 0)[..., None]
+            tail = jnp.exp(gcum[..., -1:, :] - gcum) * (seg == seg[..., -1:])[..., None]
+        else:
+            gcum = jnp.cumsum(gc, axis=-1)  # (B, H, C)
+            diff = gcum[..., :, None] - gcum[..., None, :]
+            decay = jnp.exp(jnp.where(lower & same, diff, -jnp.inf))
+            a = jnp.einsum("bhik,bhjk->bhij", kb, kc, precision=_HI)
+            a = jnp.where(strictly_lower, a * decay, 0.0)
+            qk = jnp.einsum("bhik,bhjk->bhij", qc, kc, precision=_HI) * decay
+            reach = (jnp.exp(gcum) * (seg == 0))[..., None]
+            tail = (jnp.exp(gcum[..., -1:] - gcum) * (seg == seg[..., -1:]))[..., None]
+        solve = _unit_lower_inverse(a)
         u = jnp.matmul(solve, vc * bc[..., None], precision=_HI)
-        w = jnp.matmul(solve, kb * reach[..., None], precision=_HI)
+        w = jnp.matmul(solve, kb * reach, precision=_HI)
         v_new = u - jnp.matmul(w, s, precision=_HI)  # (B, H, C, dv)
-        qk = jnp.einsum("bhik,bhjk->bhij", qc, kc, precision=_HI) * decay
-        out = jnp.matmul(qc * reach[..., None], s, precision=_HI) + jnp.matmul(
+        out = jnp.matmul(qc * reach, s, precision=_HI) + jnp.matmul(
             qk, v_new, precision=_HI
         )
         # what is left at the chunk's end: the carried state if no
         # reset fell in the chunk, and the writes of the last segment
-        tail = jnp.exp(gcum[..., -1:] - gcum) * (seg == seg[..., -1:])
-        s = s * reach[..., -1, None, None] + jnp.einsum(
-            "bhjk,bhjv->bhkv", kc * tail[..., None], v_new, precision=_HI
+        s = s * jnp.swapaxes(reach[..., -1:, :], -1, -2) + jnp.einsum(
+            "bhjk,bhjv->bhkv", kc * tail, v_new, precision=_HI
         )
         return s, out
 
+    # a decay a channel: a chunk's scaled operands and products (a key's
+    # row once a sub-block, 0.27 GB a layer of 32 heads at 16 streams of
+    # 256 tokens) are made again in the backward pass, a chunk at a time,
+    # and the scan keeps the chunks' start states and inputs alone
     state, outs = jax.lax.scan(
-        one_chunk, state, (qs, ks, vs, gs, betas, rs)
+        jax.checkpoint(one_chunk) if channel else one_chunk, state,
+        (qs, ks, vs, gs, betas, rs)
     )
     # (n, B, H, C, dv) -> (B, T, H, dv)
     outs = jnp.moveaxis(jnp.moveaxis(outs, 0, 1), 2, 3)

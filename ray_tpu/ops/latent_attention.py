@@ -105,12 +105,19 @@ def yarn_softmax_scale(qk_head_dim: int, scaling: Optional[Dict]) -> float:
     return scale
 
 
-def rope(x, positions, inv_freq):
-    """Rotate every dimension of ``x`` ``(B, T, H, R)`` on the ``[first
-    half | second half]`` layout; ``positions`` ``(B, T)``."""
+def rope(x, positions, inv_freq, interleave: bool = False):
+    """Rotate every dimension of ``x`` ``(B, T, H, R)``; ``positions``
+    ``(B, T)``. Frequency ``i`` turns the pair ``(x[i], x[i + R / 2])``
+    (the ``[first half | second half]`` layout), or with ``interleave``
+    the adjacent pair ``(x[2 i], x[2 i + 1])`` (a config's
+    ``rope_interleave``)."""
     half = x.shape[-1] // 2
     angle = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
     cos, sin = jnp.cos(angle)[:, :, None], jnp.sin(angle)[:, :, None]
+    if interleave:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        return jnp.stack(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).reshape(x.shape)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 

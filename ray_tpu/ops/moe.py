@@ -50,19 +50,35 @@ import jax.numpy as jnp
 _HI = jax.lax.Precision.HIGHEST
 
 
-def route_top_k(
+def chosen_groups(pick, n_group: int, topk_group: int):
+    """``(T, n_group)`` bool: the ``topk_group`` groups of consecutive
+    experts a token may choose among (DeepSeek-V3's group-limited
+    routing, arXiv:2412.19437 section 2.1.2, as ``noaux_tc`` has it): a
+    group's score is the sum of its TWO largest ``pick`` (the scores
+    that choose: the selection bias included)."""
+    grouped = pick.reshape(pick.shape[:-1] + (n_group, -1))
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, best = jax.lax.top_k(group_score, topk_group)  # (T, topk_group)
+    return jnp.any(best[..., None] == jnp.arange(n_group), axis=-2)
+
+
+def route(
     x, router_kernel, k: int, renormalise: bool, scoring: str = "softmax",
-    select_bias=None, scale: float = 1.0,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """``(indices (T, k) int32, weights (T, k) float32)``: scores over
-    all router outputs in float32 at precision "highest" (a rounding
+    select_bias=None, scale: float = 1.0, n_group: int = 1, topk_group: int = 1,
+):
+    """``(indices (T, k) int32, weights (T, k) float32, groups)``: scores
+    over all router outputs in float32 at precision "highest" (a rounding
     step here changes WHICH experts a token gets), then the top ``k``.
     ``scoring`` is ``"softmax"`` or ``"sigmoid"`` (each expert scored on
     its own: DeepSeek-V3). ``select_bias`` ``(E,)`` is added to the
     scores that PICK the experts and never to a weight, and takes no
     gradient (the load-balancing bias of ``noaux_tc``); ``scale``
     multiplies the weights after the renormalisation
-    (``routed_scaling_factor``)."""
+    (``routed_scaling_factor``). With ``n_group`` above 1 the choice is
+    group-limited: only the experts of a token's :func:`chosen_groups`
+    stand for its top ``k`` (the others are struck out), and ``groups``
+    is that ``(T, n_group)`` choice; ``n_group`` 1 is the plain top-k
+    (the operations it always was) and ``groups`` is None."""
     logits = jnp.dot(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32), precision=_HI
     )
@@ -72,18 +88,24 @@ def route_top_k(
         scores = jax.nn.sigmoid(logits)
     else:
         raise ValueError(f"unknown router scoring {scoring!r}")
-    if select_bias is None:
+    pick = scores if select_bias is None else (
+        scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)))
+    groups = None
+    if n_group > 1:
+        with jax.named_scope("groups"):
+            groups = chosen_groups(pick, n_group, topk_group)
+            pick = jnp.where(
+                jnp.repeat(groups, pick.shape[-1] // n_group, axis=-1), pick, -jnp.inf)
+    if pick is scores:
         weights, indices = jax.lax.top_k(scores, k)
     else:
-        _, indices = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), k
-        )
+        _, indices = jax.lax.top_k(pick, k)
         weights = jnp.take_along_axis(scores, indices, axis=-1)
     if renormalise:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     if scale != 1.0:
         weights = weights * scale
-    return indices.astype(jnp.int32), weights
+    return indices.astype(jnp.int32), weights, groups
 
 
 def held_combine_weights(indices, weights, first: int, held: int):
@@ -220,7 +242,7 @@ def grouped_experts_product(
     alone: int = 0,
 ):
     """The same sum over the (token, slot) pairs on held experts only
-    (``w_gate`` None: ungated experts). ``indices``, ``weights`` ``(T, k)`` as :func:`route_top_k` gives
+    (``w_gate`` None: ungated experts). ``indices``, ``weights`` ``(T, k)`` as :func:`route` gives
     them, ``per_expert`` ``(held,)`` the pairs on each held expert
     (:func:`expert_load`). Pairs are sorted by expert (stable; pairs on
     absent experts last), so expert ``e``'s pairs are ``per_expert[e]``

@@ -108,10 +108,17 @@ MOE_ABSENT_SLOTS_TOTAL = "ray_tpu_moe_absent_slots_total"
 # fused lane, where a fragment is one stream's rollout): summed over
 # updates under stat = share, the updates counted under stat = updates
 MOE_DECODE_HELD_TOUCHED_TOTAL = "ray_tpu_moe_decode_held_experts_touched_total"
+# a group-limited router (ops/moe.chosen_groups): of an update's tokens,
+# the share whose chosen groups hold one of this chip's experts, a mean
+# over the expert layers, summed over updates under stat = share, the
+# updates counted under stat = updates
+MOE_HELD_GROUP_CHOSEN_TOTAL = "ray_tpu_moe_held_group_chosen_total"
 # which lowering each traced one-token gated-delta step took
 # (ops/deltanet.py): path = kernel (the Pallas kernel: a TPU, whole
-# tiles) | xla (the jax.numpy body). Counted when the form is traced,
-# once per DeltaNet layer of a traced program
+# tiles) | xla (the jax.numpy body); decay = head (a number a head:
+# Gated DeltaNet) | channel (a number a key channel: Kimi Delta
+# Attention). Counted when the form is traced, once per layer of a
+# traced program
 DELTANET_STEP_LOWERINGS_TOTAL = "ray_tpu_deltanet_step_lowerings_total"
 # which form each traced routed-expert layer's product took
 # (models/sequence_lm, ops/moe.product_lowering): path = grouped
@@ -614,6 +621,15 @@ def note_expert_load(infos) -> None:
                 float(info["moe_decode_held_experts_touched_share"]),
                 {"stat": "share"})
             touched.inc(1.0, {"stat": "updates"})
+        if "moe_held_group_chosen_share" in info:
+            chosen = counter(
+                MOE_HELD_GROUP_CHOSEN_TOTAL,
+                "share of the tokens whose chosen groups of experts hold a "
+                "held expert, summed over updates; and the updates counted",
+                ("stat",),
+            )
+            chosen.inc(float(info["moe_held_group_chosen_share"]), {"stat": "share"})
+            chosen.inc(1.0, {"stat": "updates"})
 
 
 def add_diffusion_token_passes(form: str, n: float) -> None:
@@ -673,23 +689,34 @@ def decode_held_experts_touched() -> Dict[str, float]:
     return _totals_by_tag(MOE_DECODE_HELD_TOUCHED_TOTAL, "stat")
 
 
+def held_group_chosen() -> Dict[str, float]:
+    """``{"share", "updates"}`` sums since the process began ({} for a
+    model whose router chooses no groups)."""
+    return _totals_by_tag(MOE_HELD_GROUP_CHOSEN_TOTAL, "stat")
+
+
 def _totals_by_tag(name: str, tag: str) -> Dict[str, float]:
     """``{value of tag: total}`` of a counter ({} before its first
     increment)."""
     m = get_metric(name)
     if m is None:
         return {}
-    return {dict(tags).get(tag, ""): v for tags, v in m.series()}
+    out: Dict[str, float] = {}
+    for tags, v in m.series():  # summed over the counter's other tags
+        key = dict(tags).get(tag, "")
+        out[key] = out.get(key, 0.0) + v
+    return out
 
 
-def inc_deltanet_step_lowering(path: str) -> None:
+def inc_deltanet_step_lowering(path: str, decay: str) -> None:
     """One traced one-token gated-delta step took ``path`` (``kernel``
-    | ``xla``): ops/deltanet.py picks from platform and shape."""
+    | ``xla``): ops/deltanet.py picks from platform and shape.
+    ``decay``: a number a ``head``, or a key ``channel``."""
     counter(
         DELTANET_STEP_LOWERINGS_TOTAL,
         "one-token gated-delta steps traced, by the lowering they took",
-        ("path",),
-    ).inc(1.0, {"path": path})
+        ("path", "decay"),
+    ).inc(1.0, {"path": path, "decay": decay})
 
 
 def inc_moe_product_lowering(path: str) -> None:

@@ -15,12 +15,14 @@ from ray_tpu.ops import deltanet
 from ray_tpu.telemetry import metrics as telemetry_metrics
 
 
-def _inputs(b, h, dk, dv, seed=0):
+def _inputs(b, h, dk, dv, seed=0, channel=False):
+    """``channel``: a decay a key channel (Kimi Delta Attention), ``g``
+    ``(b, h, dk)``; else a decay a head."""
     rng = np.random.default_rng(seed)
     f32 = lambda *s: rng.standard_normal(s).astype(np.float32)
     s, q, k, v = f32(b, h, dk, dv), f32(b, h, dk) / np.sqrt(dk), f32(b, h, dk), f32(b, h, dv)
     k /= np.linalg.norm(k, axis=-1, keepdims=True)
-    g = -rng.uniform(0.01, 1.0, (b, h)).astype(np.float32)
+    g = -rng.uniform(0.01, 1.0, (b, h, dk) if channel else (b, h)).astype(np.float32)
     beta = rng.uniform(0.1, 0.9, (b, h)).astype(np.float32)
     return s, q, k, v, g, beta
 
@@ -33,21 +35,26 @@ def _lowerings():
     return dict(telemetry_metrics.deltanet_step_lowerings())
 
 
-# (streams, heads, dk, dv), whether the kernel's lowering exists for it
+# (streams, heads, dk, dv), whether the kernel's lowering exists for it,
+# whether the decay is a number a key channel
 SHAPES = [
-    pytest.param((2, 32, 128, 128), True, id="cell-32x128x128"),
-    pytest.param((3, 8, 128, 256), True, id="one-block-of-8-heads"),
-    pytest.param((2, 4, 64, 128), False, id="half-tile-dk-falls-back"),
-    pytest.param((2, 6, 128, 128), False, id="odd-heads-fall-back"),
+    pytest.param((2, 32, 128, 128), True, False, id="cell-32x128x128"),
+    pytest.param((3, 8, 128, 256), True, False, id="one-block-of-8-heads"),
+    pytest.param((2, 4, 64, 128), False, False, id="half-tile-dk-falls-back"),
+    pytest.param((2, 6, 128, 128), False, False, id="odd-heads-fall-back"),
+    pytest.param((2, 32, 128, 128), True, True, id="kda-cell-32x128x128-a-decay-a-channel"),
+    pytest.param((3, 8, 128, 256), True, True, id="kda-one-block-of-8-heads"),
+    pytest.param((2, 4, 64, 128), False, True, id="kda-half-tile-dk-falls-back"),
 ]
 
 
-@pytest.mark.parametrize("shape,kernel", SHAPES)
-def test_step_lowerings_agree(shape, kernel, monkeypatch):
+@pytest.mark.parametrize("shape,kernel,channel", SHAPES)
+def test_step_lowerings_agree(shape, kernel, channel, monkeypatch):
     """State and output of the lowering a TPU would take against the
     body, to float32 rounding: a reset on some rows (``g = -inf``), ``g``
-    at 0 and very negative, ``beta`` at both ends."""
-    s, q, k, v, g, beta = _inputs(*shape)
+    at 0 and very negative, ``beta`` at both ends; with a decay a head
+    and with a decay a key channel (a head's row of them on those rows)."""
+    s, q, k, v, g, beta = _inputs(*shape, channel=channel)
     g[0, 0], g[0, 1], g[1, :2] = 0.0, -80.0, -np.inf
     beta[0, 2], beta[0, 3] = 0.0, 1.0
     want_s, want_o = deltanet._delta_step_body(s, q, k, v, g, beta)

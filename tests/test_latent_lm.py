@@ -428,9 +428,9 @@ def test_selection_bias_picks_and_never_weighs():
     kernel = jnp.asarray(rng.standard_normal((16, 8)), jnp.float32)
     bias = jnp.asarray(0.3 * rng.standard_normal(8), jnp.float32)
     scores = jax.nn.sigmoid(jnp.dot(x, kernel, precision=jax.lax.Precision.HIGHEST))
-    i0, w0 = moe.route_top_k(x, kernel, 3, True, scoring="sigmoid", scale=2.0)
-    i1, w1 = moe.route_top_k(x, kernel, 3, True, scoring="sigmoid", select_bias=bias,
-                             scale=2.0)
+    i0, w0 = moe.route(x, kernel, 3, True, scoring="sigmoid", scale=2.0)[:2]
+    i1, w1 = moe.route(x, kernel, 3, True, scoring="sigmoid", select_bias=bias,
+                             scale=2.0)[:2]
     assert np.any(np.sort(i0, -1) != np.sort(i1, -1))  # some token's set moved
     np.testing.assert_allclose(w0.sum(-1), 2.0, rtol=1e-6)
     np.testing.assert_allclose(w1.sum(-1), 2.0, rtol=1e-6)
@@ -439,11 +439,11 @@ def test_selection_bias_picks_and_never_weighs():
                                rtol=1e-6)
     assert np.array_equal(
         np.sort(i1, -1), np.sort(np.asarray(jax.lax.top_k(scores + bias, 3)[1]), -1))
-    grad = jax.grad(lambda b: jnp.sum(jnp.square(moe.route_top_k(
+    grad = jax.grad(lambda b: jnp.sum(jnp.square(moe.route(
         x, kernel, 3, True, scoring="sigmoid", select_bias=b, scale=2.0)[1])))(bias)
     assert float(jnp.abs(grad).max()) == 0.0
     # the defaults are the softmax router as it was
-    i2, w2 = moe.route_top_k(x, kernel, 3, True)
+    i2, w2 = moe.route(x, kernel, 3, True)[:2]
     top_w, top_i = jax.lax.top_k(jax.nn.softmax(jnp.dot(
         x, kernel, precision=jax.lax.Precision.HIGHEST)), 3)
     assert np.array_equal(i2, top_i)

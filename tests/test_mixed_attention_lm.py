@@ -539,7 +539,7 @@ def test_lagunas_keys_are_not_read_as_qwen3_nexts(setup):
     x = jnp.asarray(np.random.default_rng(2).standard_normal((2, 5, 32)), jnp.float32)
     with jax.default_matmul_precision("highest"):
         idx, w = ref._route(params["layer_2"], x, ref.sizes(config, VOCAB))
-        got_idx, got_w = ffn.route(params["layer_2"], x.reshape(10, 32))
+        got_idx, got_w, _ = ffn.route(params["layer_2"], x.reshape(10, 32))
     assert np.array_equal(np.asarray(idx), np.asarray(got_idx))
     np.testing.assert_allclose(got_w, w, atol=1e-6)
     np.testing.assert_allclose(np.asarray(got_w).sum(-1), 2.5, atol=1e-5)

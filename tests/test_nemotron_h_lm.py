@@ -169,12 +169,17 @@ def test_the_published_pattern_is_52_blocks_of_one_sublayer_each():
     assert attention.scale == 16 ** -0.5 and d["eps"] == 1e-5 and not d["tied_head"]
 
 
-def test_a_dense_block_and_router_groups_are_refused_by_name():
+def test_a_dense_block_is_refused_by_name_and_router_groups_are_read():
+    """``-`` has no kind; ``n_group`` / ``topk_group`` are the ROUTER's
+    groups and are read since PR 61 (1 and 1, what the published config
+    states, is the plain top-k)."""
     lm = small_config()["algo_config"]["model"]["sequence_lm"]
     with pytest.raises(ValueError, match="'-'"):
         describe(dict(lm, hybrid_override_pattern="ME-M*", num_hidden_layers=5))
-    with pytest.raises(ValueError, match="groups of experts"):
-        describe(dict(lm, n_group=2, topk_group=1))
+    routers = lambda c: {(s.ffn.n_group, s.ffn.topk_group)
+                         for s in describe(c)["segments"] if s.ffn.route_on}
+    assert routers(lm) == {(1, 1)}
+    assert routers(dict(lm, n_group=2, topk_group=1)) == {(2, 1)}
 
 
 @pytest.mark.parametrize("family", ["test_ssm_lm", "test_sequence_lm", "test_latent_lm"])
@@ -486,10 +491,10 @@ def test_selection_bias_picks_and_never_weighs(setup):
     layer = model.segments[1].ffn
     p = dict(params["layer_1"])
     x = jnp.asarray(np.random.default_rng(5).standard_normal((8, 48)), jnp.float32)
-    idx, w = layer.route(p, x)
+    idx, w, _ = layer.route(p, x)
     np.testing.assert_allclose(jnp.sum(w, -1), 2.5, rtol=1e-5)
     p["select_bias"] = p["select_bias"] + 10.0 * (jnp.arange(16) == 13)
-    idx2, w2 = layer.route(p, x)
+    idx2, w2, _ = layer.route(p, x)
     assert bool(jnp.all(jnp.any(idx2 == 13, -1)))
     scores = jax.nn.sigmoid(x @ p["router"])
     chosen = jnp.take_along_axis(scores, idx2, -1)

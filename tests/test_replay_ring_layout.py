@@ -409,18 +409,24 @@ def test_v5e_superstep_makes_a_pixel_rows_bytes_once(v5e_mesh):
     assert len({tuple(i[4]) for i in convs}) == len(convs)
 
 
-def test_v5e_delta_step_kernel_compiles_in_place(v5e_mesh):
-    """The one-token gated-delta kernel (ops/deltanet.py) at the
-    sequence cell's width, 64 streams x 32 heads of 128 x 128, chained
-    in a scan under ``shard_map`` like the lane's decode steps (the
-    call has to say how its outputs vary over the mesh): Mosaic takes
-    it, and the 0.13 GB of matrices are written where they lie (no
+@pytest.mark.parametrize("b, decay", [
+    pytest.param(64, "head", id="qwen3next-a-decay-a-head"),
+    pytest.param(16, "channel", id="ling3flash-a-decay-a-key-channel"),
+])
+def test_v5e_delta_step_kernel_compiles_in_place(v5e_mesh, b, decay):
+    """The one-token gated-delta kernel (ops/deltanet.py) at a sequence
+    cell's width, its streams x 32 heads of 128 x 128, chained in a scan
+    under ``shard_map`` like the lane's decode steps (the call has to
+    say how its outputs vary over the mesh), with a decay a head (Gated
+    DeltaNet) and a decay a key channel (Kimi Delta Attention, whose row
+    is turned in the kernel as ``k`` is): Mosaic takes both, and the
+    matrices (0.13 GB at 64 streams) are written where they lie (no
     second copy, no scratch)."""
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.ops import deltanet
 
-    b, h, dk, dv = 64, 32, 128, 128
+    h, dk, dv = 32, 128, 128
     axis = sharding_lib.data_axis(v5e_mesh)
     rows = sharding_lib.batch_sharded(v5e_mesh)
     on = lambda *shape: jax.ShapeDtypeStruct(shape, np.float32, sharding=rows)
@@ -439,7 +445,7 @@ def test_v5e_delta_step_kernel_compiles_in_place(v5e_mesh):
     compiled = (
         jax.jit(sharded, donate_argnums=(0,))
         .lower(on(b, h, dk, dv), on(b, h, dk), on(b, h, dk), on(b, h, dv),
-               on(b, h), on(b, h))
+               on(b, h, dk) if decay == "channel" else on(b, h), on(b, h))
         .compile()
     )
     calls = [
@@ -531,6 +537,7 @@ FRAGMENT_LAYERS = {
     # dimension, no whole lane tiles) for 32 query heads in four tiles,
     # the value the row's leading 512 lanes, read out of the key cache
     "xing4_latent": (8, 128, 1, 32, 576, 2048, None, 512),
+    "ling3flash_latent": (16, 256, 1, 32, 576, 4096, None, 512),
     # the block-diffusion cell: eight query heads a key head under the
     # block rule (its clean pass), and with a clean pass's rows as a
     # second block of own keys (its noisy passes)
@@ -605,6 +612,7 @@ STEP_LAYERS = {
     # Xing4's latent rows: one key head of 576 lanes for 32 query heads,
     # the value its leading 512 lanes (a sixth number: no value cache)
     "xing4_latent": (32, 1, 32, 576, 2048, 512),
+    "ling3flash_latent": (16, 1, 32, 576, 4096, 512),
     # the three ring cells' window layers (Phi-4's differential pairs
     # are ten key heads of 128 lanes): a ring of 512 rows is ONE key block
     "smallthinker_ring": (32, 4, 7, 128, 4096),

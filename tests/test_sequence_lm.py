@@ -269,7 +269,7 @@ def test_held_combine_weights_and_load():
     assert float(absent) == 3
 
 
-# the two routers of the cells, small: (E, k, held, first, route_top_k's options)
+# the two routers of the cells, small: (E, k, held, first, route's options)
 ROUTERS = {
     "softmax_10_of_512_held_32_at_64": (512, 10, 32, 64, {}),
     "sigmoid_bias_scale_4_of_64_held_8": (
@@ -321,8 +321,8 @@ def test_grouped_product_equals_dense(router, routing, dtype):
     options = dict(options)
     if options.pop("select_bias", False):
         options["select_bias"] = jax.random.normal(keys[6], (experts,)) * 0.02
-    indices, weights = moe.route_top_k(
-        x, jax.random.normal(keys[1], (d, experts)) * d ** -0.5, k, True, **options)
+    indices, weights = moe.route(
+        x, jax.random.normal(keys[1], (d, experts)) * d ** -0.5, k, True, **options)[:2]
     # "..._alone": a layer told that three experts may outgrow their buffers
     alone = 3 if routing.endswith("_alone") else 0
     indices = _routing(routing.removesuffix("_alone"), indices, first, held)

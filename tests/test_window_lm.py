@@ -403,7 +403,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(tokens, top_k, lowering):
 def test_the_route_is_the_renormalised_softmax_top_k_of_the_layers_input(setup):
     """Softmax over the chosen logits (the reference, as the family
     writes it) is the softmax over all, top-k, renormalised
-    (``ops/moe.route_top_k`` as the Qwen3-Next layer calls it); the
+    (``ops/moe.route`` as the Qwen3-Next layer calls it); the
     model takes it from the block's input."""
     config, params, model, _, _ = setup
     z = ref.sizes(config, VOCAB)
@@ -411,7 +411,7 @@ def test_the_route_is_the_renormalised_softmax_top_k_of_the_layers_input(setup):
     with jax.default_matmul_precision("highest"):
         idx, w = ref._route(params["layer_2"], x, z)
         ffn = model.segments[2].ffn
-        got_idx, got_w = ffn.route(params["layer_2"], x.reshape(10, 32))
+        got_idx, got_w, _ = ffn.route(params["layer_2"], x.reshape(10, 32))
     assert np.array_equal(np.asarray(idx), np.asarray(got_idx))
     np.testing.assert_allclose(got_w, w, atol=1e-6)
     assert ffn.route_on == "input" and ffn.activation == "relu"
