@@ -675,7 +675,8 @@ class DeltaNetLayer(Kind):
     16) and ``dt_bias`` one.
 
     State: the ``(value heads, dk, dv)`` float32 matrix and the last
-    ``conv - 1`` inputs of the convolution. Scope: ``linear_attn``."""
+    ``conv - 1`` inputs of the convolution. Scopes: ``linear_attn`` and,
+    around the rule alone in both forms, its ``/rule``."""
 
     k_heads: int
     v_heads: int
@@ -732,17 +733,18 @@ class DeltaNetLayer(Kind):
             k = l2norm(k)
             q = jnp.repeat(q, hv // hk, axis=2)
             k = jnp.repeat(k, hv // hk, axis=2)
-            if t == 1:
-                s1, o = deltanet.gated_delta_step(
-                    s0, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0]
-                )
-                o = o[:, None]
-            else:
-                o, s1 = deltanet.gated_delta_chunked(
-                    s0, q, k, v, g, beta,
-                    resets=ctx["fresh"].astype(jnp.float32),
-                    chunk=ctx["chunk"],
-                )
+            with jax.named_scope("rule"):
+                if t == 1:
+                    s1, o = deltanet.gated_delta_step(
+                        s0, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0]
+                    )
+                    o = o[:, None]
+                else:
+                    o, s1 = deltanet.gated_delta_chunked(
+                        s0, q, k, v, g, beta,
+                        resets=ctx["fresh"].astype(jnp.float32),
+                        chunk=ctx["chunk"],
+                    )
             o = rms(o, p["gdn_norm"], ctx["eps"], centred=False)
             o = o * jax.nn.silu(z.reshape(b, t, hv, self.dv))
             return dot(o.reshape(b, t, vd), p["out_proj"], dtype), (s1, new_tail), {}

@@ -35,8 +35,8 @@ the scalar gives, to rounding.
   (``g > -5``: ``exp(75)``) and for nothing much below it: the bound is
   the model's (``kda_safe_gate``), and no clip is added here.
 
-**The one-token form has two lowerings of one algorithm**, picked by
-what the code can see when it is traced, never by an option:
+**Each form has two lowerings of one algorithm**, picked by what the
+code can see when it is traced, never by an option. The one-token form:
 
 - :func:`gated_delta_step_kernel`, a Pallas (Mosaic) kernel, where the
   default backend is a TPU and ``dk`` and ``dv`` are whole 128-lane
@@ -50,9 +50,30 @@ what the code can see when it is traced, never by an option:
   elementwise op that consumes its result into one fusion, so on a TPU
   this body reads every matrix three times and writes it once.
 
+The fragment form with a decay a HEAD:
+
+- :func:`gated_delta_chunked_kernel`, two Pallas kernels under one
+  ``custom_vjp``, where the default backend is a TPU, the operands are
+  float32, ``dk`` and ``dv`` whole 128-lane tiles and the chunks fill
+  whole 128-row tiles, alone or side by side
+  (:func:`_chunked_kernel_applies`). A grid step is a stream and a
+  block of heads; a head's state, its chunks and their ``(C, C)``
+  products stay in VMEM over the fragment, every product on the MXU at
+  precision highest, and ``exp(G_i - G_j)`` is at most 1 wherever the
+  masks keep it, whatever the decay. What bounds it is the MXU's six
+  bfloat16 passes a float32 product, not HBM (PERF.md section 6).
+- :func:`_chunked_text`, the ``lax.scan`` over chunks in ``jax.numpy``,
+  everywhere else (the CPU, odd sizes, another precision): a fusion a
+  product, whose operands and ``(C, C)`` results cross HBM. It is the
+  statement of the function and the kernels' reference.
+
+A decay a CHANNEL keeps the text on every backend: another algebra
+(sub-blocks under a bounded gate), separated on purpose (PERF.md, PR 64).
+
 ``ray_tpu_deltanet_step_lowerings_total{path="kernel"|"xla",
-decay="head"|"channel"}`` counts, at trace time, which one each traced
-one-token form took and for which decay.
+decay="head"|"channel"}`` and ``ray_tpu_deltanet_chunked_lowerings_total``
+(the same labels) count, at trace time, which one each traced one-token
+and fragment form took and for which decay.
 
 **Resets.** ``resets`` (1.0 where a token begins a new episode) zero
 the state before that token. In the chunked form a reset splits its
@@ -268,6 +289,18 @@ def gated_delta_chunked(
     dv)``; ``resets`` ``(B, T)`` or None. ``T`` is a multiple of
     ``chunk`` (or shorter than it). Returns ``(o (B, T, H, dv), state)``.
     """
+    decay = "channel" if g.ndim == 4 else "head"
+    if _chunked_kernel_applies(state, q, k, v, g, beta, chunk):
+        telemetry_metrics.inc_deltanet_chunked_lowering("kernel", decay)
+        return gated_delta_chunked_kernel(state, q, k, v, g, beta, resets, chunk)
+    telemetry_metrics.inc_deltanet_chunked_lowering("xla", decay)
+    return _chunked_text(state, q, k, v, g, beta, resets, chunk)
+
+
+def _chunked_text(state, q, k, v, g, beta, resets=None, chunk: int = 64):
+    """:func:`gated_delta_chunked` in ``jax.numpy``: the statement of the
+    function, what the CPU, odd sizes and a decay a channel run, and the
+    kernels' reference."""
     b, t, h, dk = q.shape
     dv = v.shape[-1]
     c = min(int(chunk), t)
@@ -341,3 +374,440 @@ def gated_delta_chunked(
     # (n, B, H, C, dv) -> (B, T, H, dv)
     outs = jnp.moveaxis(jnp.moveaxis(outs, 0, 1), 2, 3)
     return outs.reshape(b, t, h, dv), state
+
+
+# -- the fragment form with a decay a head, on a kernel pair ----------------
+
+# rows of a fragment a step of the kernels' walk takes at once: whole
+# chunks, and where a chunk is shorter than a 128-row tile as many of
+# them side by side as fill one (two chunks of 64 are ONE block-diagonal
+# (128, 128) problem for everything a state does not enter: the MXU pays
+# by the tile, so the solve of two chunks costs what the solve of one does)
+_STEP_ROWS = 128
+# rows of the block of per-token numbers a grid step reads, tokens on the
+# lanes: the chunk's running log-decay, beta, the segment number, the
+# decay from the chunk's start to the token (0 after a reset), the decay
+# from the token to the chunk's end (0 before the last reset); three spare
+_TOKEN_ROWS = 8
+# heads a grid step of the chunked kernels holds, a leading axis of every
+# array in them (on the v5e at the cell's size 1 a step ran the forward
+# kernel in 2.10 ms a call and the backward in 1.38, 2 in 1.44 / 1.11, 4 in
+# 1.32 / 1.04, 8 in 1.30 / 1.00, each with the solve's two products a power
+# stacked on one weight tile, 2% of the forward, which the loop kept gives
+# up: 1.35 / 1.04 at 4; benchmarks/profile_delta_rule.py)
+_CHUNKED_HEADS = 4
+_VMEM_BYTES = 64 << 20
+
+
+def _chunks_a_step(c: int, n: int) -> int:
+    """Chunks of ``c`` rows, of a fragment's ``n``, that one step of the
+    kernels' walk takes side by side."""
+    return max(p for p in range(1, max(1, _STEP_ROWS // c) + 1) if n % p == 0)
+
+
+def _chunked_kernel_applies(state, q, k, v, g, beta, chunk) -> bool:
+    """The kernel pair's lowering exists for a TPU (``ops/backend.is_tpu``),
+    a decay a HEAD (``g`` of rank 3; a decay a channel is another
+    algebra and keeps the text), float32 operands, ``dk`` and ``dv``
+    whole 128-lane tiles, and chunks that fill whole 128-row tiles,
+    alone or side by side."""
+    if not backend.is_tpu() or g.ndim != 3:
+        return False
+    t, dk, dv = q.shape[1], q.shape[-1], v.shape[-1]
+    c = min(int(chunk), t)
+    if t % c or c % 8:
+        return False
+    return (
+        all(x.dtype == jnp.float32 for x in (state, q, k, v, g, beta))
+        and dk % 128 == 0 and dv % 128 == 0
+        and (c * _chunks_a_step(c, t // c)) % _STEP_ROWS == 0
+    )
+
+
+def _mxu(a, b, contract):
+    """A float32 product a head on the MXU at precision highest (six
+    bfloat16 passes), float32 accumulation. ``a``, ``b`` ``(heads, .,
+    .)``."""
+    return jax.lax.dot_general(
+        a, b, (contract, ((0,), (0,))), precision=_HI,
+        preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):  # a @ b, a head
+    return _mxu(a, b, ((2,), (1,)))
+
+
+def _nt(a, b):  # a @ b.T
+    return _mxu(a, b, ((2,), (2,)))
+
+
+def _tn(a, b):  # a.T @ b
+    return _mxu(a, b, ((1,), (1,)))
+
+
+def _turned(x):
+    return jnp.swapaxes(x, 1, 2)
+
+
+def _rows_above(x, at, of):
+    """``x`` ``(heads, c, w)`` as chunk ``at``'s rows of ``of`` chunks
+    side by side, zeros elsewhere: a block-diagonal matrix's rows of one
+    chunk meet it over all ``of * c`` columns, whole tiles."""
+    if of == 1:
+        return x
+    zeros = jnp.zeros_like(x)
+    return jnp.concatenate([x if i == at else zeros for i in range(of)], axis=1)
+
+
+def _lanes(col, width):
+    """Columns ``(heads, p, 1)`` along ``width`` lanes."""
+    return jnp.broadcast_to(col, col.shape[:2] + (width,))
+
+
+def _row_at(col, at, width):
+    """Row ``at`` of columns ``(heads, p, 1)`` along ``width`` lanes,
+    ``(heads, 1, width)``: it meets a matrix by a broadcast down the
+    sublanes. A sum under a mask, because Mosaic has no broadcast of one
+    number both ways at once."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, col.shape[:2] + (width,), 1)
+    return jnp.sum(jnp.where(rows == at, _lanes(col, width), 0.0), axis=1,
+                   keepdims=True)
+
+
+def _step_operands(rows, q, k, v, c):
+    """What a step's ``p`` rows (``p / c`` chunks side by side) give on
+    the vector unit before any product, for every head of the grid step
+    at once (a leading axis: the heads share nothing, and an operation
+    written once for all of them is one to trace and, on the chip, a
+    link of each head's chain in turn): the per-token columns, the decay
+    matrix under its masks and the scaled operands. ``rows`` ``(heads,
+    8, p)`` (``_TOKEN_ROWS``); ``q``, ``k`` ``(heads, p, dk)``; ``v``
+    ``(heads, p, dv)``."""
+    heads, p = q.shape[:2]
+    # the per-token rows as columns: one transpose of a square tile a head
+    turned = _turned(jnp.concatenate(
+        [rows, jnp.zeros((heads, p - _TOKEN_ROWS, p), jnp.float32)], axis=1))
+    gcum, beta, seg, reach, tail = (turned[:, :, r : r + 1] for r in range(5))
+    i = jax.lax.broadcasted_iota(jnp.int32, (p, p), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (p, p), 1)
+    within = i >= j
+    for edge in range(c, p, c):
+        # of one chunk: on one side of every chunk's edge (Mosaic compares
+        # no two masks)
+        after_i, after_j = i >= edge, j >= edge
+        within = within & ((after_i & after_j) | ~(after_i | after_j))
+    lower = (_lanes(seg, p) == rows[:, 2:3]) & within
+    # exp(G_i - G_j), at most 1 wherever the mask keeps it
+    diff = _lanes(gcum, p) - rows[:, 0:1]
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
+    kb = k * beta
+    return dict(
+        i=i, j=j, decay=decay, kb=kb, vb=v * beta, qr=q * reach,
+        kbr=kb * reach, kt=k * tail, beta=beta, reach=reach, tail=tail)
+
+
+def _step_products(m, q, k, c):
+    """The text's ``a``, ``qk`` and ``solve`` of a step as block-diagonal
+    ``(heads, p, p)`` matrices, from :func:`_step_operands`' ``m``. ``k
+    beta`` and ``q`` meet ``k^T`` as ONE left operand of ``2 p`` rows."""
+    p = q.shape[1]
+    i, j = m["i"], m["j"]
+    products = _nt(jnp.concatenate([m["kb"], q], axis=1), k)  # (heads, 2p, p)
+    a = jnp.where(i > j, products[:, :p] * m["decay"], 0.0)
+    qk = products[:, p:] * m["decay"]
+    # (I + a)^-1 = (I - a)(I + a^2)(I + a^4)...: ``a^c = 0`` a chunk
+    solve, power, span = (i == j).astype(jnp.float32) - a, a, 2
+    while span < c:
+        power = _nn(power, power)
+        solve = solve + _nn(solve, power)
+        span *= 2
+    return a, qk, solve
+
+
+def _walk(steps, body):
+    """``body(s)`` for each step: a loop on the chip, and no loop at all
+    for a fragment of one step (the cell's: two chunks of 64)."""
+    if steps == 1:
+        body(0)
+    else:
+        jax.lax.fori_loop(0, steps, lambda s, _: body(s), None)
+
+
+def _step_rows(s, p):
+    return pl.ds(s * p, p) if isinstance(s, int) else pl.ds(pl.multiple_of(s * p, p), p)
+
+
+def _by_head(ref, at, heads):
+    """A step's rows of a ``(1, T, heads * width)`` block, a head a
+    leading row: ``(heads, p, width)``."""
+    width = ref.shape[2] // heads
+    return jnp.stack(
+        [ref[0, at, hh * width : (hh + 1) * width] for hh in range(heads)])
+
+
+def _to_heads(ref, at, x):
+    """``x`` ``(heads, p, width)`` into a step's rows of a ``(1, T, heads
+    * width)`` block."""
+    width = x.shape[2]
+    for hh in range(x.shape[0]):
+        ref[0, at, hh * width : (hh + 1) * width] = x[hh]
+
+
+def _chunked_fwd_kernel(rows_ref, q_ref, k_ref, v_ref, s0_ref,
+                        o_ref, s1_ref, starts_ref, kept_ref, news_ref, *, c):
+    """One stream, a block of heads, the fragment's chunks in turn.
+    ``rows`` ``(1, heads, steps, 8, p)``; ``q``, ``k``, ``v``, ``o`` ``(1,
+    T, heads * width)``, the heads' lanes of the stream's tokens; the
+    states ``(1, heads, dk, dv)``. For the backward kernel: ``starts``
+    ``(1, heads, n, dk, dv)``, the state each chunk began from; ``kept``
+    ``(1, heads, steps, 3, p, p)``, a step's ``a``, ``qk`` and ``solve``
+    (ten of a step's eighteen products are the solve: the backward pass
+    reads it, and makes no product of the forward pass again); ``news``
+    ``(1, T, heads * dv)``, the tokens' writes. The states sit in their
+    output block between steps (a loop's carry read from a block is
+    typed apart from one computed inside the loop under ``shard_map``)."""
+    heads, steps, p = rows_ref.shape[1], rows_ref.shape[2], rows_ref.shape[4]
+    dv = s0_ref.shape[3]
+    per = p // c
+    s1_ref[...] = s0_ref[...]
+
+    def some_chunks(s):
+        at = _step_rows(s, p)
+        q, k = _by_head(q_ref, at, heads), _by_head(k_ref, at, heads)
+        m = _step_operands(rows_ref[0, :, s], q, k, _by_head(v_ref, at, heads), c)
+        a, qk, solve = _step_products(m, q, k, c)
+        kept_ref[0, :, s, 0], kept_ref[0, :, s, 1], kept_ref[0, :, s, 2] = a, qk, solve
+        state, outs, news = s1_ref[0], [], []
+        for ci in range(per):
+            own = slice(ci * c, (ci + 1) * c)
+            starts_ref[0, :, s * per + ci] = state
+            # what the start state gives the reads and takes from the writes
+            from_state = _nn(
+                jnp.concatenate([m["qr"][:, own], m["kbr"][:, own]], axis=1), state)
+            v_new = _nn(solve[:, own],
+                        _rows_above(m["vb"][:, own] - from_state[:, c:], ci, per))
+            news.append(v_new)
+            outs.append(from_state[:, :c]
+                        + _nn(qk[:, own], _rows_above(v_new, ci, per)))
+            # the carried state if no reset fell in the chunk, and the
+            # writes of its last segment
+            state = state * _row_at(m["reach"], (ci + 1) * c - 1, dv) + _tn(
+                m["kt"][:, own], v_new)
+        _to_heads(o_ref, at, jnp.concatenate(outs, axis=1))
+        _to_heads(news_ref, at, jnp.concatenate(news, axis=1))
+        s1_ref[0] = state
+
+    _walk(steps, some_chunks)
+
+
+def _chunked_bwd_kernel(rows_ref, q_ref, k_ref, v_ref, starts_ref, kept_ref,
+                        news_ref, do_ref, ds1_ref,
+                        dq_ref, dk_ref, dv_ref, drows_ref, ds0_ref, *, c):
+    """The same grid step backwards, the steps from the last to the
+    first, on what the forward kernel kept: a step walks its chunks
+    backwards with the states' cotangent in ITS output block, and the
+    cotangents of the two ``(p, p)`` products are taken once a step, all
+    chunks side by side. ``drows`` is ``rows``' cotangent (the running
+    log-decay through ``exp(G_i - G_j)`` alone: ``reach`` and ``tail``
+    are operands of their own, and XLA's text outside differentiates
+    what made them)."""
+    heads, steps, p = rows_ref.shape[1], rows_ref.shape[2], rows_ref.shape[4]
+    dv = ds1_ref.shape[3]
+    per = p // c
+    ds0_ref[...] = ds1_ref[...]
+
+    def some_chunks(back):
+        s = steps - 1 - back
+        at = _step_rows(s, p)
+        q, k, v, do, v_new = (
+            _by_head(ref, at, heads) for ref in (q_ref, k_ref, v_ref, do_ref, news_ref))
+        m = _step_operands(rows_ref[0, :, s], q, k, v, c)
+        a, qk, solve = kept_ref[0, :, s, 0], kept_ref[0, :, s, 1], kept_ref[0, :, s, 2]
+        turned_solve = _turned(solve)
+        through_reads = _tn(qk, do)  # (heads, p, dv)
+        d_rhs, d_qr, d_kbr, d_kt = ([None] * per for _ in range(4))
+        d_reach = jnp.zeros((heads, p, 1), jnp.float32)
+        for ci in reversed(range(per)):
+            own = slice(ci * c, (ci + 1) * c)
+            start, ds = starts_ref[0, :, s * per + ci], ds0_ref[0]
+            d_new = through_reads[:, own] + _nn(m["kt"][:, own], ds)
+            d_rhs[ci] = _nn(turned_solve[:, own], _rows_above(d_new, ci, per))
+            d_kt[ci] = _nt(v_new[:, own], ds)
+            cots = jnp.concatenate([do[:, own], d_rhs[ci]], axis=1)
+            to_operands = _nt(cots, start)  # (heads, 2c, dk)
+            d_qr[ci], d_kbr[ci] = to_operands[:, :c], -to_operands[:, c:]
+            last = (ci + 1) * c - 1
+            through_carry = jnp.sum(
+                jnp.sum(start * ds, axis=2, keepdims=True), axis=1, keepdims=True)
+            d_reach = d_reach + jnp.where(m["i"][:, :1] == last, through_carry, 0.0)
+            ds0_ref[0] = ds * _row_at(m["reach"], last, dv) + _tn(
+                jnp.concatenate([m["qr"][:, own], -m["kbr"][:, own]], axis=1), cots)
+        d_rhs, d_qr, d_kbr, d_kt = (
+            jnp.concatenate(x, axis=1) for x in (d_rhs, d_qr, d_kbr, d_kt))
+        # cotangents of ``a`` (minus d_rhs v_new^T) and of ``qk``
+        over = _nt(jnp.concatenate([d_rhs, do], axis=1), v_new)  # (heads, 2p, p)
+        d_a, d_qk = -over[:, :p], over[:, p:]
+        to_products = jnp.concatenate(
+            [jnp.where(m["i"] > m["j"], d_a * m["decay"], 0.0), d_qk * m["decay"]],
+            axis=1)
+        times_k = _nn(to_products, k)  # (heads, 2p, dk)
+        d_kb = times_k[:, :p] + d_kbr * m["reach"]
+        _to_heads(dq_ref, at, times_k[:, p:] + d_qr * m["reach"])
+        _to_heads(dk_ref, at, (
+            _tn(to_products, jnp.concatenate([m["kb"], q], axis=1))
+            + d_kb * m["beta"] + d_kt * m["tail"]))
+        _to_heads(dv_ref, at, d_rhs * m["beta"])
+        over_lanes = lambda x: jnp.sum(x, axis=2, keepdims=True)
+        through_decay = d_a * a + d_qk * qk
+        columns = (
+            over_lanes(through_decay),
+            over_lanes(d_rhs * v) + over_lanes(d_kb * k),
+            None,
+            d_reach + over_lanes(d_qr * q) + over_lanes(d_kbr * m["kb"]),
+            over_lanes(d_kt * k),
+        )
+        # the columns as rows, by one transpose of a square tile a head
+        tile = sum(jnp.where(m["j"] == r, _lanes(col, p), 0.0)
+                   for r, col in enumerate(columns) if col is not None)
+        against = jnp.sum(through_decay, axis=1, keepdims=True)  # (heads, 1, p)
+        row = jax.lax.broadcasted_iota(jnp.int32, (_TOKEN_ROWS, p), 0)
+        drows_ref[0, :, s] = _turned(tile)[:, :_TOKEN_ROWS] - jnp.where(
+            row == 0, against, 0.0)
+
+    _walk(steps, some_chunks)
+
+
+def _chunked_call(kernel, operands, outs, *, c, heads, interpret, name):
+    """One of the two kernels over ``(streams, heads / heads a step)``.
+    ``operands`` and ``outs`` as ``(how it is blocked, array or
+    shape)``: ``tokens`` ``(B, T, H * width)``, a stream's rows of the
+    step's heads' lanes; ``head`` ``(B, H, ...)`` (a state, ``rows``,
+    what the forward kernel keeps), the step's heads' whole."""
+    from ray_tpu import sharding as sharding_lib
+
+    of = next(v.shape[1] for how, v in operands if how == "head")
+    blocked = {
+        "tokens": lambda shape: pl.BlockSpec(
+            (1, shape[1], shape[2] // of * heads), lambda i, j: (i, 0, j)),
+        "head": lambda shape: pl.BlockSpec(
+            (1, heads) + tuple(shape[2:]),
+            lambda i, j: (i, j) + (0,) * (len(shape) - 2)),
+    }
+    arrays = [v for _, v in operands]
+    return pl.pallas_call(
+        functools.partial(kernel, c=c),
+        grid=(arrays[0].shape[0], of // heads),
+        in_specs=[blocked[how](v.shape) for how, v in operands],
+        out_specs=[blocked[how](shape) for how, shape in outs],
+        # inside a ``shard_map`` the results vary over the axes the
+        # operands do
+        out_shape=[
+            jax.ShapeDtypeStruct(shape, jnp.float32,
+                                 vma=sharding_lib.vma_of(arrays))
+            for _, shape in outs
+        ],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_BYTES,
+        ),
+        name=name,
+    )(*arrays)
+
+
+# A ``jit`` of their own, as the step kernel has: a program with many call
+# sites (three layers; the forward pass, its recomputation and the backward
+# pass; the standalone learn program and the fused one) traces and lowers
+# each kernel once a shape.
+@functools.partial(jax.jit, static_argnames=("c", "heads", "interpret"))
+def _chunked_fwd(state, q, k, v, rows, *, c, heads, interpret):
+    n, (steps, p) = q.shape[1] // c, (rows.shape[2], rows.shape[4])
+    return _chunked_call(
+        _chunked_fwd_kernel,
+        [("head", rows), ("tokens", q), ("tokens", k), ("tokens", v),
+         ("head", state)],
+        [("tokens", v.shape), ("head", state.shape),
+         ("head", state.shape[:2] + (n,) + state.shape[2:]),
+         ("head", state.shape[:2] + (steps, 3, p, p)), ("tokens", v.shape)],
+        c=c, heads=heads, interpret=interpret, name="gated_delta_chunked_fwd")
+
+
+@functools.partial(jax.jit, static_argnames=("c", "heads", "interpret"))
+def _chunked_bwd(q, k, v, rows, starts, kept, news, do, ds1, *, c, heads, interpret):
+    return _chunked_call(
+        _chunked_bwd_kernel,
+        [("head", rows), ("tokens", q), ("tokens", k), ("tokens", v),
+         ("head", starts), ("head", kept), ("tokens", news), ("tokens", do),
+         ("head", ds1)],
+        [("tokens", q.shape), ("tokens", k.shape), ("tokens", v.shape),
+         ("head", rows.shape), ("head", ds1.shape)],
+        c=c, heads=heads, interpret=interpret, name="gated_delta_chunked_bwd")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _chunked_rule(c, heads, interpret, state, q, k, v, rows):
+    return _chunked_fwd(state, q, k, v, rows, c=c, heads=heads, interpret=interpret)[:2]
+
+
+def _chunked_rule_fwd(c, heads, interpret, state, q, k, v, rows):
+    o, after, *kept = _chunked_fwd(
+        state, q, k, v, rows, c=c, heads=heads, interpret=interpret)
+    return (o, after), (q, k, v, rows, *kept)
+
+
+def _chunked_rule_bwd(c, heads, interpret, kept, cotangents):
+    dq, dk, dv, drows, dstate = _chunked_bwd(
+        *kept, *cotangents, c=c, heads=heads, interpret=interpret)
+    return dstate, dq, dk, dv, drows
+
+
+_chunked_rule.defvjp(_chunked_rule_fwd, _chunked_rule_bwd)
+
+
+def gated_delta_chunked_kernel(state, q, k, v, g, beta, resets=None,
+                               chunk: int = 64, *, heads=None, interpret=False):
+    """:func:`gated_delta_chunked` with a decay a head as two Pallas
+    calls under one ``custom_vjp``, for operands
+    :func:`_chunked_kernel_applies` admits. A grid step is one stream and
+    a block of heads (``_CHUNKED_HEADS``, a leading axis of every array
+    in the kernels: they share nothing, and the solve is a chain of
+    products each of which waits for the one before, so the MXU takes
+    one head's product while the vector unit cuts another's operands
+    into bfloat16 pieces): a head's ``(dk, dv)`` state stays in VMEM
+    over the fragment's chunks beside the chunks' ``(C, C)`` products,
+    so a fragment moves its operands in and its outputs out, and for
+    the backward pass its chunks' start states, the ``(C, C)`` products
+    and the tokens' writes, and nothing else. The same algebra as the
+    text, every product float32 at precision highest; what XLA's text
+    keeps here is what is a number a token (the running log-decay, the
+    segment numbers, the two decays against the chunk's ends), which
+    the kernels read as one ``(8, rows)`` block a step. ``heads`` caps
+    the heads a grid step holds and ``interpret`` runs the kernels in
+    the Pallas interpreter (the CPU tests): nothing upstream passes
+    either."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    c = min(int(chunk), t)
+    if t % c:
+        raise ValueError(f"fragment of {t} tokens is not a multiple of {c}")
+    n = t // c
+    p = c * _chunks_a_step(c, n)
+    if resets is None:
+        resets = jnp.zeros((b, t), jnp.float32)
+    by_chunk = lambda x: x.reshape((b, n, c) + x.shape[2:])
+    gcum = jnp.cumsum(by_chunk(g), axis=2)  # (B, n, C, H)
+    seg = jnp.cumsum((by_chunk(resets) > 0.5).astype(jnp.float32), axis=2)[..., None]
+    # the start state reaches the tokens before the first reset, and the
+    # tokens of the last segment reach the chunk's end
+    reach = jnp.exp(gcum) * (seg == 0)
+    tail = jnp.exp(gcum[:, :, -1:] - gcum) * (seg == seg[:, :, -1:])
+    rows = jnp.stack(
+        [gcum, by_chunk(beta), jnp.broadcast_to(seg, gcum.shape), reach, tail]
+        + [jnp.zeros_like(gcum)] * (_TOKEN_ROWS - 5))  # (8, B, n, C, H)
+    # (B, H, steps, 8, rows a step)
+    rows = rows.reshape(_TOKEN_ROWS, b, t // p, p, h).transpose(1, 4, 2, 0, 3)
+    o, state = _chunked_rule(
+        c, math.gcd(h, heads or _CHUNKED_HEADS), interpret, state,
+        q.reshape(b, t, h * dk), k.reshape(b, t, h * dk), v.reshape(b, t, h * dv),
+        rows)
+    return o.reshape(b, t, h, dv), state

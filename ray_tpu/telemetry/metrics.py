@@ -120,6 +120,13 @@ MOE_HELD_GROUP_CHOSEN_TOTAL = "ray_tpu_moe_held_group_chosen_total"
 # Attention). Counted when the form is traced, once per layer of a
 # traced program
 DELTANET_STEP_LOWERINGS_TOTAL = "ray_tpu_deltanet_step_lowerings_total"
+# which lowering each traced FRAGMENT form of the gated delta rule took
+# (ops/deltanet.gated_delta_chunked): path = kernel (the Pallas kernel
+# pair under one custom_vjp: a TPU, a decay a head, whole tiles) | xla
+# (the chunked jax.numpy text: the CPU, odd sizes, every decay a
+# channel); decay as above. Counted where the path is chosen, once per
+# traced call (a run of like layers under one scan is one)
+DELTANET_CHUNKED_LOWERINGS_TOTAL = "ray_tpu_deltanet_chunked_lowerings_total"
 # which form each traced routed-expert layer's product took
 # (models/sequence_lm, ops/moe.product_lowering): path = grouped
 # (only the (token, slot) pairs on held experts, sorted by expert) |
@@ -719,6 +726,18 @@ def inc_deltanet_step_lowering(path: str, decay: str) -> None:
     ).inc(1.0, {"path": path, "decay": decay})
 
 
+def inc_deltanet_chunked_lowering(path: str, decay: str) -> None:
+    """One traced fragment form of the gated delta rule took ``path``
+    (``kernel`` | ``xla``): ops/deltanet.py picks from platform, the
+    decay's rank and the shapes. ``decay``: a number a ``head``, or a
+    key ``channel``."""
+    counter(
+        DELTANET_CHUNKED_LOWERINGS_TOTAL,
+        "fragment forms of the gated delta rule traced, by the lowering they took",
+        ("path", "decay"),
+    ).inc(1.0, {"path": path, "decay": decay})
+
+
 def inc_moe_product_lowering(path: str) -> None:
     """One traced routed-expert layer took ``path`` (``grouped`` |
     ``dense``) for the held experts' product."""
@@ -895,6 +914,14 @@ def shared_state_lowerings() -> Dict[str, float]:
 def deltanet_step_lowerings() -> Dict[str, float]:
     """``{path: traced one-token steps}`` since the process began."""
     return _totals_by_tag(DELTANET_STEP_LOWERINGS_TOTAL, "path")
+
+
+def deltanet_chunked_lowerings() -> Dict[str, float]:
+    """``{"<path>/<decay>": traced fragment forms of the gated delta
+    rule}`` since the process began."""
+    m = get_metric(DELTANET_CHUNKED_LOWERINGS_TOTAL)
+    return {} if m is None else {
+        "{path}/{decay}".format(**dict(tags)): v for tags, v in m.series()}
 
 
 def inc_env_steps_on_device(n: int) -> None:

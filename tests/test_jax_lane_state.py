@@ -148,6 +148,7 @@ def test_sequence_model_trains_on_the_token_env_through_the_fused_lane():
     from ray_tpu.algorithms.registry import get_algorithm_class
 
     before = telemetry_metrics.expert_load_totals().get("updates", 0.0)
+    chunked_before = dict(telemetry_metrics.deltanet_chunked_lowerings())
     algo = get_algorithm_class("PPO")(config={
         "env": "TokenStreamJax-v0",
         "env_config": {"vocab_size": 32, "episode_length": 24, "phase_stride": 5},
@@ -180,6 +181,14 @@ def test_sequence_model_trains_on_the_token_env_through_the_fused_lane():
         assert algo._counters["num_env_steps_trained"] == 3 * 128
         totals = telemetry_metrics.expert_load_totals()
         assert totals["updates"] - before == 3 and totals["max"] >= totals["mean"]
+        # the learn form's DeltaNet layers (a run of three, traced once), a
+        # decay a head, on the path this platform chose (here the CPU:
+        # XLA's text)
+        chunked = telemetry_metrics.deltanet_chunked_lowerings()
+        took = {k: v - chunked_before.get(k, 0) for k, v in chunked.items()
+                if v != chunked_before.get(k, 0)}
+        path = "kernel" if jax.default_backend() == "tpu" else "xla"
+        assert set(took) == {path + "/head"}
     finally:
         algo.cleanup()
 

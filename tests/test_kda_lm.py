@@ -499,6 +499,7 @@ def test_the_fused_lane_generates_and_trains_the_policy():
     lm = dict(small_config()["algo_config"]["model"]["sequence_lm"],
               max_position_embeddings=24)
     before = metrics.held_group_chosen().get("updates", 0)
+    chunked_before = dict(metrics.deltanet_chunked_lowerings())
     algo = get_algorithm_class("PPO")(config={
         "env": "TokenStreamJax-v0",
         "env_config": {"vocab_size": VOCAB, "episode_length": 24, "phase_stride": 3},
@@ -524,6 +525,11 @@ def test_the_fused_lane_generates_and_trains_the_policy():
     assert metrics.held_group_chosen()["updates"] - before == 1
     decays = metrics._totals_by_tag(metrics.DELTANET_STEP_LOWERINGS_TOTAL, "decay")
     assert decays.get("channel", 0) > 0
+    # the learn form's KDA layers: a decay a channel keeps XLA's text on
+    # every platform
+    chunked = metrics.deltanet_chunked_lowerings()
+    took = {k for k, v in chunked.items() if v != chunked_before.get(k, 0)}
+    assert took == {"xla/channel"}
 
 
 # -- the configuration -----------------------------------------------------------------

@@ -752,6 +752,61 @@ def test_v5e_selective_scan_kernels_compile(v5e_mesh):
     assert compiled.memory_analysis().temp_size_in_bytes < 400e6
 
 
+def test_v5e_delta_rule_fragment_kernels_compile(v5e_mesh):
+    """The fragment form of the gated delta rule with a decay a head
+    (ops/deltanet.gated_delta_chunked_kernel) at the Qwen3-Next cell's
+    size (16 streams a call x 128 tokens, 32 heads of 128 x 128, two
+    chunks of 64 side by side) under ``shard_map`` and a block's
+    ``jax.checkpoint``, value and gradient of every operand: Mosaic
+    takes the forward kernel (the pass and its recomputation) and the
+    backward kernel, all under the caller's ``learn/linear_attn`` and its
+    ``rule`` (jax wraps ``jvp(...)`` around the outer scope alone in the
+    first pass), where the trace files their time
+    (perf/layer_metrics/linear_attn.rule_device_ms_per_update.py), and no
+    ``(C, C)`` product of the text's, ``f32[16,32,64,64]``, exists."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.ops import deltanet
+
+    b, t, h, dk, dv = 16, 128, 32, 128, 128
+    axis = sharding_lib.data_axis(v5e_mesh)
+    rows = sharding_lib.batch_sharded(v5e_mesh)
+    on = lambda *shape: jax.ShapeDtypeStruct(shape, np.float32, sharding=rows)
+
+    def loss(state, q, k, v, g, beta, resets):
+        @jax.checkpoint
+        def block(state, q, k, v, g, beta):
+            with jax.named_scope("learn/linear_attn"), jax.named_scope("rule"):
+                o, after = deltanet.gated_delta_chunked_kernel(
+                    state, q, k, v, g, beta, resets, 64)
+            return jnp.tanh(o) * v, after
+
+        o, after = block(state, q, k, v, g, beta)
+        return jnp.sum(o) + jnp.sum(after)
+
+    def value_and_grad(*operands):
+        value, grads = jax.value_and_grad(loss, argnums=tuple(range(6)))(*operands)
+        return value[None], grads
+
+    sharded = jax.shard_map(
+        value_and_grad, mesh=v5e_mesh, in_specs=(P(axis),) * 7,
+        out_specs=(P(axis), (P(axis),) * 6))
+    compiled = jax.jit(sharded).lower(
+        on(b, h, dk, dv), on(b, t, h, dk), on(b, t, h, dk), on(b, t, h, dv),
+        on(b, t, h), on(b, t, h), on(b, t)).compile()
+    text = compiled.as_text()
+    calls = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "custom-call(" in line
+    ]
+    assert len(calls) == 3 and all(
+        "learn/linear_attn/rule/" in line or "learn/linear_attn)/rule/" in line
+        for line in calls)
+    assert sum("gated_delta_chunked_fwd/" in line for line in calls) == 2
+    assert sum("gated_delta_chunked_bwd/" in line for line in calls) == 1
+    assert not re.search(rf"f32\[{b},{h},64,64\]", text)
+
+
 def test_v5e_tree_update_pays_for_its_levels(v5e_mesh):
     """The DQN cell's priority refresh (ops/segment_tree.py: an
     (8, 512) update of a 131,072-leaf f64 tree pair) as the chip's

@@ -5,6 +5,7 @@ recurrence against the chunked form; the shares of an expert layer
 against the uncut layer; the token env's rows.
 """
 
+import functools
 import importlib.util
 import os
 
@@ -16,6 +17,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.sequence_lm import SequenceLM
 from ray_tpu.ops import deltanet, moe
+from ray_tpu.telemetry import metrics
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 VOCAB = 64
@@ -132,9 +134,19 @@ def test_forward_matches_reference(setup):
             np.testing.assert_allclose(a, b, atol=2e-2 if kind == ref.FULL else 2e-4)
 
 
-def test_loss_and_every_gradient_leaf_match_reference(setup):
+@pytest.mark.parametrize("rule", ["text", "kernel"])
+def test_loss_and_every_gradient_leaf_match_reference(setup, rule, monkeypatch):
+    """``rule``: the DeltaNet layers' fragment form as the CPU lowers it
+    (XLA's text), and on the kernel pair a TPU takes (in the Pallas
+    interpreter, at this size's small tiles)."""
     config, params, model, batch = setup
     dev = {k: jnp.asarray(v) for k, v in batch.items()}
+    if rule == "kernel":
+        monkeypatch.setattr(deltanet, "_chunked_kernel_applies", lambda *a: True)
+        monkeypatch.setattr(
+            deltanet, "gated_delta_chunked_kernel",
+            functools.partial(deltanet.gated_delta_chunked_kernel, interpret=True))
+    before = dict(metrics.deltanet_chunked_lowerings())
 
     def system_loss(p):
         logits, value, _ = _model_forward(model, p, batch)
@@ -145,6 +157,9 @@ def test_loss_and_every_gradient_leaf_match_reference(setup):
             lambda p: ref.loss(p, dev, config)
         )(params)
         got_loss, got = jax.value_and_grad(system_loss)(params)
+    took = {k for k, v in metrics.deltanet_chunked_lowerings().items()
+            if v != before.get(k, 0)}
+    assert took == {("kernel" if rule == "kernel" else "xla") + "/head"}
     assert abs(float(got_loss) - float(want_loss)) < 1e-4 * abs(float(want_loss))
     whole = np.sqrt(sum(float(jnp.sum(g * g)) for g in jax.tree_util.tree_leaves(want)))
     for group in want:

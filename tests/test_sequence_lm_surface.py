@@ -6,7 +6,10 @@ readers sum under (the named scopes of ``jax.make_jaxpr(model.apply)``'s name
 stacks), each at the five families' small test configs and recorded from the
 commit before the model became a package of kinds (PR 45, parent 1a90899).
 A change that moves any of these moves the benchmark's references or its
-per-layer metrics with it, and has to say so here.
+per-layer metrics with it, and has to say so here. Since PR 64 the gated
+delta rule alone sits under ``linear_attn/rule`` in both forms (what
+``linear_attn.rule_device_ms_per_update`` reads); ``linear_attn`` still
+holds everything it held.
 """
 import importlib.util
 import os
@@ -60,9 +63,9 @@ SURFACE = {
          'moe_rows_computed_share', 'moe_slots_on_absent_experts',
          'moe_tokens_per_held_expert'],
         "fragment_scopes": ['attn', 'attn/gate', 'attn/out', 'attn/scatter', 'attn/scores', 'head',
-         'linear_attn', 'moe/experts', 'moe/route', 'moe/shared'],
+         'linear_attn', 'linear_attn/rule', 'moe/experts', 'moe/route', 'moe/shared'],
         "step_scopes": ['attn', 'attn/gate', 'attn/out', 'attn/scatter', 'attn/scores', 'head',
-         'linear_attn', 'moe/experts', 'moe/route', 'moe/shared'],
+         'linear_attn', 'linear_attn/rule', 'moe/experts', 'moe/route', 'moe/shared'],
     },
     "latent_lm": {
         "params": {
