@@ -47,8 +47,9 @@ over the stored keys plus the fragment's own, ``resets`` opening a new
 episode inside it: the learn program's form).
 
 Precision: float32 parameters; the projections, expert products, the
-head and the attention products take bfloat16 operands and accumulate
-in float32; the router, softmax, top-k, ``g``, ``beta``, the delta
+head, the attention products and a learned index's two projections and
+score product take bfloat16 operands and accumulate in float32; the
+index's weights, relu, sum over heads and top-k, the router, softmax, top-k, ``g``, ``beta``, the delta
 rules' state, the hyper-connection maps and mixes and every norm are float32
 (the router, the maps' projection and the delta rule at precision
 "highest"); the state-space recurrence in both forms, ``dt``, ``A``,
@@ -63,14 +64,14 @@ from ray_tpu.models.sequence_lm.config import (
     Segment, attention_layers_of, describe, layer_types_of)
 from ray_tpu.models.sequence_lm.kinds import (
     AttentionLayer, DeltaNetLayer, DenseLayer, EvaLayer, ExpertLayer, GatedMemoryLayer,
-    HyperResidual, KDALayer, LatentLayer, MambaLayer, Norm, NoSublayer, PlainResidual,
-    SelectiveScanLayer)
+    HyperResidual, Indexer, KDALayer, LatentLayer, MambaLayer, Norm, NoSublayer,
+    PlainResidual, SelectiveScanLayer)
 from ray_tpu.models.sequence_lm.model import SequenceLM
 
 __all__ = [
     "SequenceLM", "Segment", "describe", "layer_types_of", "attention_layers_of",
-    "AttentionLayer", "LatentLayer", "DeltaNetLayer", "KDALayer", "MambaLayer",
-    "EvaLayer",
+    "AttentionLayer", "Indexer", "LatentLayer", "DeltaNetLayer", "KDALayer",
+    "MambaLayer", "EvaLayer",
     "SelectiveScanLayer", "GatedMemoryLayer", "DenseLayer", "ExpertLayer",
     "NoSublayer", "PlainResidual", "HyperResidual", "Norm",
 ]

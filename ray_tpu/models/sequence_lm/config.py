@@ -12,8 +12,8 @@ import numpy as np
 from ray_tpu.models.sequence_lm.generation import Autoregressive, BlockDiffusion
 from ray_tpu.models.sequence_lm.kinds import (
     AttentionLayer, DeltaNetLayer, DenseLayer, EvaLayer, ExpertLayer, GatedMemoryLayer,
-    HyperResidual, KDALayer, LatentLayer, MambaLayer, Norm, NoSublayer, PlainResidual,
-    SelectiveScanLayer)
+    HyperResidual, Indexer, KDALayer, LatentLayer, MambaLayer, Norm, NoSublayer,
+    PlainResidual, SelectiveScanLayer)
 from ray_tpu.ops import deltanet, latent_attention
 
 LINEAR, FULL, LATENT = "linear_attention", "full_attention", "latent_attention"
@@ -32,7 +32,10 @@ NONE = "none"
 _PATTERN = {"M": (MAMBA, NONE), "E": (NONE, EXPERTS), "*": (ATTENTION, NONE)}
 # families whose every layer is ``qwen3_moe``'s: full attention with q/k
 # norms and no gate over an expert layer with no shared expert
-_QWEN3_MOE_STACKS = ("sdar_moe",)
+_QWEN3_MOE_STACKS = ("sdar_moe", "KeyeVL2")
+# families that generate a block a step by masked diffusion: how a
+# family generates is not what its layers are
+_BLOCK_DIFFUSION = ("sdar_moe",)
 
 
 def _qwen3_moe_stack(config: Dict) -> bool:
@@ -45,9 +48,10 @@ def generation_of(config: Dict):
     ``denoising_steps`` passes, ``mask_token_id`` the row of the
     embedding that stands for ``[MASK]`` (the published config gives
     none of the three: the cell's file states them as assumed). Every
-    other family is autoregressive."""
+    other family is autoregressive, ``KeyeVL2``, which shares SDAR's
+    stack of layers, among them."""
     c = config
-    if _qwen3_moe_stack(c):
+    if c.get("model_type") in _BLOCK_DIFFUSION:
         return BlockDiffusion(
             block_length=int(c["block_length"]),
             denoising_steps=int(c["denoising_steps"]),
@@ -162,6 +166,32 @@ def layer_types_of(config: Dict) -> Tuple[str, ...]:
 _YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast", "beta_slow")
 
 
+def _indexer_of(c: Dict, head_dim: int):
+    """``sa_config`` (``model_type: KeyeVL2``): ``indexer_num_heads``
+    heads of ``indexer_head_dim`` over ``indexer_num_kv_heads`` 1 index
+    key a row, ``topk`` rows a query. ``q_chunk_size`` / ``kv_chunk_size``
+    are how the release TILES the score product, no width and no pooling
+    of rows: not read. ``rope_scaling`` may state ``mrope_section``: the
+    three components' shares of the head's ``head_dim / 2`` pairs, which
+    over text tokens (equal components) is rotate-half RoPE over the
+    whole head; any other scaling is refused. None without the key."""
+    sa = c.get("sa_config")
+    if not sa:
+        return None
+    if int(sa.get("indexer_num_kv_heads", 1)) != 1:
+        raise ValueError("sa_config: the index has ONE key a row for all its heads")
+    scaling = c.get("rope_scaling") or {}
+    section = scaling.get("mrope_section")
+    if scaling.get("rope_type", scaling.get("type", "default")) != "default" or (
+            section is not None and 2 * sum(int(n) for n in section) != head_dim):
+        raise ValueError(
+            f"rope_scaling {scaling}: only M-RoPE sections that fill the head's "
+            f"{head_dim // 2} pairs, unscaled, are read")
+    return Indexer(
+        heads=int(sa["indexer_num_heads"]), head_dim=int(sa["indexer_head_dim"]),
+        top_k=int(sa["topk"]))
+
+
 def attention_layers_of(config: Dict, layer_types) -> Dict[int, AttentionLayer]:
     """``{layer: AttentionLayer}`` for the softmax-attention layers of
     ``layer_types``, each key read for what it states and under
@@ -194,7 +224,8 @@ def attention_layers_of(config: Dict, layer_types) -> Dict[int, AttentionLayer]:
     - ``model_type: phi4flash``: NO positions on any layer, the
       differential form and biases on every one, ``index`` the layer's
       published index; the ``"attention"`` layer exports its cache and a
-      ``"cross_attention"`` layer reads it."""
+      ``"cross_attention"`` layer reads it;
+    - ``sa_config``: a learned index on every layer (:func:`_indexer_of`)."""
     c = config
     out = {}
     plain = _qwen3_moe_stack(c)
@@ -244,7 +275,10 @@ def attention_layers_of(config: Dict, layer_types) -> Dict[int, AttentionLayer]:
             qk_norm=plain or gate is not None, block=block,
             diff=sambay, bias=sambay, index=indices[i] if sambay else 0,
             exports_as=_KV if sambay and kind == ATTENTION else None,
-            source=_KV if kind == CROSS else None)
+            source=_KV if kind == CROSS else None,
+            indexer=_indexer_of(c, head_dim))
+        if out[i].indexer and (sambay or window is not None or block > 1):
+            raise ValueError("sa_config: an index over a full-depth causal cache only")
     return out
 
 

@@ -215,6 +215,21 @@ def over_layers(stats, declared):
 # -- mixers ---------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
+class Indexer:
+    """A learned index over an attention layer's cache (``sa_config``):
+    ``heads`` index heads of ``head_dim`` over ONE index key a row, and
+    the ``top_k`` rows a query attends to (``ops/sparse_index``)."""
+
+    heads: int
+    head_dim: int
+    top_k: int
+
+
+# the index key's norm: a LayerNorm with a bias, whatever the model's is
+_INDEX_KEY_NORM = Norm(bias=True)
+
+
+@dataclasses.dataclass(frozen=True)
 class AttentionLayer(Kind):
     """One softmax-attention layer: what the ``"full_attention"``,
     ``"attention"`` and ``"sliding_attention"`` kinds of every family
@@ -271,13 +286,37 @@ class AttentionLayer(Kind):
       cache is handed to later layers; with ``source`` the layer is a
       CROSS layer: it has ``W_q`` and ``W_o`` only, reads the cache
       exported under that name (the token's own row among its rows) and
-      writes nothing.
+      writes nothing;
+    - Keye-VL-2.0's decoder layers (``model_type: KeyeVL2``): the
+      ``qwen3_moe`` layer under the causal mask with an ``indexer``, the
+      lightning indexer of DeepSeek Sparse Attention. With ``x`` the
+      layer's normed input: ``qI_j = RoPE(x W_qI)[j]`` for the index's
+      ``heads`` heads, ONE index key ``kI = RoPE(LN(x W_kI))`` a row
+      (a LayerNorm with weight and bias, the weight stored zero-centred
+      like every norm's; RoPE over the whole index head at the layer's
+      theta), ``w = x W_w`` a number a head; ``I[t, s] = sum_j w[t, j]
+      relu(qI[t, j] . kI[s])`` over the rows ``s <= t`` of the episode,
+      and the query attends to the ``min(t + 1, top_k)`` rows of the
+      largest ``I``, ties to the lower position, by an EXACT top-k. The
+      index is read through ``stop_gradient``: its five leaves lie in
+      the parameter tree and take a gradient of exactly zero (no
+      alignment loss is stated for it), and the PPO gradient reaches
+      ``W_q``, ``W_k``, ``W_v``, ``W_o`` through the chosen rows only.
+      bfloat16 operands with float32 accumulation in ``x W_qI``, ``x
+      W_kI`` and ``qI . kI``; float32 for ``x W_w`` (precision highest),
+      LN, RoPE, the relu, the sum over heads and the top-k. M-RoPE with
+      ``mrope_section`` over text tokens, whose three components are
+      equal, IS this RoPE.
 
     State: keys and values, ``(rows, kv heads x head)`` in the operands'
     type, one row a position, flat, so that the device tiles (rows, row)
     without padding 2 heads to 8; keys stored after norm and RoPE (YaRN's
     factor included); a window layer a RING of ``min(window, positions)``
     rows whatever the episode's depth (docs/policy_state.md, "The ring").
+    With an ``indexer`` a THIRD leaf, the index keys ``(rows, index
+    head)`` in the operands' type, stored after LN and RoPE at the slot
+    of the row's position, written by the same scatter and, like the
+    other two, left by a reset (docs/policy_state.md, "The index").
 
     A cross layer has none.
 
@@ -285,7 +324,19 @@ class AttentionLayer(Kind):
     window layer, ``xattn`` for a cross layer), the cached attention's
     parts under its ``/scatter``, ``/scores`` and ``/out``, the gate
     under ``/gate``, the subtraction, its norm and factor under
-    ``/diff``, the output projection under ``/out``."""
+    ``/diff``, the output projection under ``/out``; an index's three
+    projections, LN and RoPE under ``/index/proj``, its scores under
+    ``/index/scores``, the top-k under ``/index/topk`` (``/select`` is
+    kept for a lowering that fetches the chosen rows alone: none does).
+
+    Statistics of an index (means over queries and layers):
+    ``index_rows_scored_mean`` (rows a query saw),
+    ``index_rows_selected_mean`` (rows it attended to, counted from the
+    choice itself), ``index_selected_share_mean`` (the second over the
+    first, a query), ``index_dense_query_share`` (queries that saw no
+    more than ``top_k`` rows), and as ``index_decode_*`` what the
+    one-token form scores and keeps at each of the fragment's
+    positions."""
 
     kind: str
     heads: int
@@ -317,6 +368,9 @@ class AttentionLayer(Kind):
     # a cross layer reads instead of a cache of its own
     exports_as: Optional[str] = None
     source: Optional[str] = None
+    # a learned index that chooses each query's rows (None: every row
+    # the mask allows)
+    indexer: Optional[Indexer] = None
 
     init_rules = {
         **{leaf: lambda key, shape: 0.1 * jax.random.normal(key, shape, jnp.float32)
@@ -336,6 +390,13 @@ class AttentionLayer(Kind):
         "xattn_key_blocks_skipped": "sum", "xattn_key_blocks_walked": "sum",
         "xattn_decode_key_blocks_skipped": "sum",
         "xattn_decode_key_blocks_walked": "sum",
+        # a learned index's choice, in the fragment form and as the
+        # one-token form makes it at the same positions; on request
+        # (``ctx["choices"]``) the choice itself, a row a stream
+        "index_choices": "tokens",
+        **{f"index_{form}{stat}": "mean" for form in ("", "decode_") for stat in (
+            "rows_scored_mean", "rows_selected_mean", "selected_share_mean",
+            "dense_query_share")},
     }
 
     @property
@@ -376,13 +437,59 @@ class AttentionLayer(Kind):
         if self.source:  # a cross layer makes no keys and no values
             for leaf in ("k_proj", "v_proj", "k_bias", "v_bias"):
                 shapes.pop(leaf, None)
+        if self.indexer:
+            ix = self.indexer
+            shapes.update(
+                index_q_proj=(d, ix.heads * ix.head_dim), index_k_proj=(d, ix.head_dim),
+                index_w_proj=(d, ix.heads),
+                **_INDEX_KEY_NORM.leaves("index_k_norm", ix.head_dim))
         return shapes
 
     def state_shapes(self, streams: int, positions: int, dtype):
         if self.source:
             return []
-        shape = (streams, self.cache_rows(positions), self.kv_heads * self.head_dim)
-        return [(shape, dtype), (shape, dtype)]
+        rows = self.cache_rows(positions)
+        shape = (streams, rows, self.kv_heads * self.head_dim)
+        index = [((streams, rows, self.indexer.head_dim), dtype)] if self.indexer else []
+        return [(shape, dtype), (shape, dtype)] + index
+
+    def selection(self, p, x, index_cache, ctx, scope: str):
+        """The index's operands of this call
+        (``ops/cached_attention.Selection``), nothing of them
+        differentiated."""
+        ix, dtype = self.indexer, ctx["dtype"]
+        b, t, _ = x.shape
+        x = jax.lax.stop_gradient(x)
+        p = {leaf: jax.lax.stop_gradient(p[leaf])
+             for leaf in p if leaf.startswith("index_")}
+        turned = lambda z: rope(z, ctx["positions"], ix.head_dim, self.theta)
+        with jax.named_scope(scope + "/index/proj"):
+            q = turned(dot(x, p["index_q_proj"], dtype).reshape(
+                b, t, ix.heads, ix.head_dim))
+            k = _INDEX_KEY_NORM(
+                dot(x, p["index_k_proj"], dtype), p, "index_k_norm", ctx["eps"])
+            k = turned(k[:, :, None])[:, :, 0]
+            w = jnp.dot(x.astype(jnp.float32), p["index_w_proj"], precision=HI)
+        return cached_attention.Selection(
+            q.astype(dtype), w, k.astype(dtype), index_cache, ix.top_k)
+
+    def _index_stats(self, ctx, selected=None):
+        """The index's statistics of a fragment at ``ctx``'s positions:
+        a query at position ``p`` saw ``p + 1`` rows; ``selected`` ``(B,
+        T)`` the rows each attended to as the choice itself counted them
+        (None: every row seen was chosen, ``top_k`` out of reach)."""
+        seen = ctx["positions"].astype(jnp.float32) + 1.0
+        kept = jnp.minimum(seen, float(self.indexer.top_k))
+        dense = jnp.mean(seen <= self.indexer.top_k, dtype=jnp.float32)
+        out = {}
+        for form, got in (("", kept if selected is None else selected),
+                          ("decode_", kept)):
+            out.update({
+                f"index_{form}rows_scored_mean": jnp.mean(seen),
+                f"index_{form}rows_selected_mean": jnp.mean(got),
+                f"index_{form}selected_share_mean": jnp.mean(got / seen),
+                f"index_{form}dense_query_share": dense})
+        return out
 
     def apply(self, p, x, state, ctx):
         scope = ctx["scope"] + self.scope
@@ -431,11 +538,18 @@ class AttentionLayer(Kind):
                      jnp.concatenate([none, q[..., 1, :]], axis=-1)],
                     axis=3).reshape(b, t, h, 2 * d)
                 k, v = (z.reshape(b, t, hkv // 2, 2 * d) for z in (k, v))
+        select = None
+        if self.indexer:
+            select = self.selection(p, x, state[2], ctx, scope)
         o, new, stats = cached_attention.cached_attention(
-            q, k, v, state, ctx, scale=self.scale, window=self.window,
-            dtype=dtype, scope=scope, block=self.block, scatter=not self.source)
+            q, k, v, state[:2], ctx, scale=self.scale, window=self.window,
+            dtype=dtype, scope=scope, block=self.block, scatter=not self.source,
+            select=select)
         if ctx.get("keep"):
             new = new + (k, v)
+        selected = stats.pop("index_rows_selected", None)
+        if self.indexer and t > 1:  # the learn form's: the lane reads no statistics
+            stats.update(self._index_stats(ctx, selected))
         if "pairs_seen" in stats:
             stats["window_rows_seen_mean"] = stats.pop("pairs_seen") / (b * t)
         if self.source:

@@ -228,7 +228,8 @@ class SequenceLM:
                 rows_ctx, prefix, stats_out)
         x, state_out, stats, _ = self._stack(
             params, tokens, state, rows_ctx, prefix,
-            step=t == 1 or commit is not None)
+            step=t == 1 or commit is not None,
+            choices=stats_out is not None and "index_choices" in stats_out)
         state_out.append(
             rows_ctx["pos0"] if commit is False else rows_ctx["positions"][:, -1] + 1)
         logits, value = self._head(params, x, prefix)
@@ -251,15 +252,19 @@ class SequenceLM:
         return {"seg": seg, "fresh": fresh, "positions": positions, "pos0": pos0}
 
     def _stack(self, params, tokens, state, rows_ctx, prefix: str, *, step: bool,
-               keep: bool = False, clean=None):
+               keep: bool = False, clean=None, choices: bool = False):
         """The embedding and the blocks. ``step``: the lane's form (one
         token, or one block of a model that commits a block a step);
-        ``keep`` / ``clean``: the passes of :meth:`_replay`. Returns
+        ``keep`` / ``clean``: the passes of :meth:`_replay`; ``choices``:
+        a caller's ``stats_out`` asks for ``index_choices``, every query's
+        chosen rows of the layers with a learned index. Returns
         ``(x, [state leaves], {key: [a segment's (layers, ...)]}, [a
         segment's kept rows])``."""
         b, t = tokens.shape
         residual, declared = self.residual, self._reductions
         flags = (("step", step), ("keep", keep))
+        if choices:  # only where asked: every other program's flags are as they were
+            flags += (("choices", True),)
 
         x = jnp.take(params["embed"]["embedding"], tokens, axis=0)  # (B, T, D)
         if self.embed_scale != 1.0:

@@ -21,11 +21,12 @@ from __future__ import annotations
 import functools
 import itertools
 import operator
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import flash_attention
+from ray_tpu.ops import flash_attention, sparse_index
 from ray_tpu.telemetry import metrics
 
 # streams of a fragment whose attention scores are alive at once: at
@@ -41,6 +42,33 @@ def env_block(heads: int, tokens: int, rows: int) -> int:
     token against ``rows`` keys) are alive at once."""
     fit = _ATTN_SCORE_BYTES // (4 * heads * tokens * rows)
     return min(8, 1 << max(0, int(fit).bit_length() - 1))
+
+
+def query_tile(heads: int, tokens: int, rows: int) -> int:
+    """Queries of ONE stream whose float32 scores (every head against
+    ``rows`` keys) are alive at once: the fragment's, halved while they
+    pass ``_ATTN_SCORE_BYTES`` (128 of 256 at 48 heads over 16,640
+    rows)."""
+    tile = tokens
+    while tile % 2 == 0 and tile > 8 and 4 * heads * tile * rows > _ATTN_SCORE_BYTES:
+        tile //= 2
+    return tile
+
+
+class Selection(NamedTuple):
+    """A learned index's operands of one call (``ops/sparse_index``):
+    the fragment's index queries ``q`` ``(B, T, heads, D)`` and keys
+    ``k`` ``(B, T, D)`` in the products' type, the heads' weights ``w``
+    ``(B, T, heads)`` float32, the stored index keys ``cache`` ``(B,
+    depth, D)`` (a third cache leaf, one row a position, written by the
+    same scatter as keys and values) and ``top_k``, the rows a query
+    attends to."""
+
+    q: jax.Array
+    w: jax.Array
+    k: jax.Array
+    cache: jax.Array
+    top_k: int
 
 
 def scatter_rows(cache, new, rows, ring: bool = False):
@@ -107,7 +135,7 @@ def pairs_seen(see_old, see):
 
 
 def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
-                     block: int = 1, scatter: bool = True):
+                     block: int = 1, scatter: bool = True, select=None):
     """Attention of a fragment's ``q`` ``(B, T, heads, D)`` over the
     stored keys and values ``caches`` and the fragment's own ``k``, ``v``
     ``(B, T, kv heads, D)``; ``rows`` holds the fragment's ``seg``,
@@ -151,6 +179,31 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
     ``ray_tpu_attention_{step,fragment}_lowerings_total{path}`` count the
     choice.
 
+    ``select`` (a :class:`Selection`; a full-depth causal cache only):
+    every query attends to the ``top_k`` rows its index scores highest
+    among those the mask lets it see. The index keys are a THIRD cache,
+    scattered with the other two and returned after them. Where ``top_k``
+    reaches the cache's depth every row seen is chosen and the call IS
+    the one without ``select`` (the same forms, the same kernels).
+    Otherwise no kernel serves it (``step_kernel_applies`` /
+    ``fragment_kernel_applies`` answer no for a selection) and the path
+    label is ``selected_xla`` in both counters. One token: the index
+    scores of the stream's slots (``/index/scores``), their exact top-k
+    as a mask over the slots (``/index/topk``) and the one-token text
+    over every slot under it; nothing is gathered (``/select`` is the
+    scope of a lowering that fetches the chosen rows alone, and holds
+    nothing today). A fragment: a stream and :func:`query_tile` queries
+    at a time, the index scores
+    over the stored rows and the fragment's own, the choice as a mask
+    (``sparse_index.select``) and the text's masked softmax under it; no
+    row is gathered (2,048 rows a query of a fragment would be a
+    gigabyte a stream and layer). ``stats["index_rows_selected"]``: the
+    rows each query attended to, ``(B, T)``; where ``rows["choices"]``
+    asks for it, ``stats["index_choices"]``: the choice itself as a mask
+    ``(B, T, slots)`` over the cache's slots (one token, whose own row is
+    in its slot already) or over the slots and then the fragment's own
+    rows.
+
     ``stats`` is what the choice alone knows: with a ``window`` the
     (query, key) ``pairs_seen``; for a fragment the key blocks its
     kernel skipped and walked (``attn_key_blocks_*``: a stream's stored
@@ -169,6 +222,9 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
     step, clean = rows.get("step", t == 1), rows.get("clean")
     if ring and (block > 1 or t > 1 and step):
         raise ValueError("a ring cache has no block rule")
+    if select is not None and (
+            ring or block > 1 or clean is not None or not scatter or t > 1 and step):
+        raise ValueError("a learned index selects among a causal cache's own rows")
 
     if scatter:
         with part("scatter"):
@@ -176,10 +232,22 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
             new_v = scatter_rows(v_cache, v.reshape(b, t, hkv * d), rows, ring)
     else:
         new_k, new_v = k_cache, v_cache
+    after = (new_k, new_v)
+    stats = {}
+    if select is not None:
+        with part("scatter"):
+            after += (scatter_rows(select.cache, select.k, rows),)
+        if select.top_k >= depth:
+            select = None  # every row seen is chosen: the forms below, as they are
+            if rows.get("choices"):
+                stats["index_choices"] = fragment_masks(
+                    seg, pos0 + 1, None, depth, None)[0] if step else jnp.concatenate(
+                        fragment_masks(seg, pos0, None, depth, None), axis=-1)
 
     qh = (q * scale).astype(dtype).reshape(b, t, hkv, h // hkv, d)
-    step_kernel = flash_attention.step_kernel_applies(h, hkv, d, depth, dtype)
-    stats = {}
+    # the kernels' rules are asked about a selection only where there is one
+    asked = {} if select is None else {"selected": True}
+    step_kernel = flash_attention.step_kernel_applies(h, hkv, d, depth, dtype, **asked)
 
     def attend(qe, ke, ve, kc, vc, sege, pos0e, pose=None, cleane=None):
         """One block of streams: the masked scores over the stored keys
@@ -218,7 +286,22 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
         # a block's queries of a key head as the rows of one tile
         qs = qh if t == 1 else qh.transpose(0, 2, 1, 3, 4).reshape(
             b, 1, hkv, t * (h // hkv), d)
-        if step_kernel:
+        if select is not None:
+            # every slot of the cache under the choice's mask: on the
+            # v5e a gather of the chosen rows by slot number cost more
+            # than the rows it saved (ops/sparse_index's docstring)
+            metrics.inc_attention_step_lowering("selected_xla")
+            with part("index/scores"):
+                index = sparse_index.scores(select.q, select.w, after[2])[:, 0]
+            with part("index/topk"):
+                chosen = sparse_index.select(index, see, select.top_k)
+            with jax.named_scope(scope):
+                o = flash_attention.step_attention_text(qs, new_k, new_v, chosen)
+            stats["index_rows_selected"] = jnp.sum(
+                chosen, axis=1, dtype=jnp.float32)[:, None]
+            if rows.get("choices"):
+                stats["index_choices"] = chosen[:, None]
+        elif step_kernel:
             # a full-depth cache is half unwritten at the mean, a ring
             # before its first turn: the tiled step kernel fetches a
             # stream's key blocks below its depth only
@@ -232,12 +315,12 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
                 o = flash_attention.step_attention_text(qs, new_k, new_v, see)
         if t > 1:
             o = o.reshape(b, hkv, t, h // hkv, d).transpose(0, 2, 1, 3, 4)
-        return o.reshape(b, t, h, d), (new_k, new_v), stats
+        return o.reshape(b, t, h, d), after, stats
 
     # the kernel's block rule is a bit mask: a power of two
     own = t if clean is None else 2 * t
     kernel = not block & (block - 1) and flash_attention.fragment_kernel_applies(
-        t, h, hkv, d, depth, dtype, own)
+        t, h, hkv, d, depth, dtype, own, **asked)
     none = (jnp.int32(0), 0)
     for name, (skipped, walked) in (
             ("attn_key_blocks",
@@ -258,6 +341,13 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
             if ring:  # the masks' arithmetic, reduced where it is built
                 stats["pairs_seen"] = jnp.sum(pairs_seen(
                     *fragment_masks(seg, pos0, positions, depth, window)))
+    elif select is not None:
+        metrics.inc_attention_fragment_lowering("selected_xla")
+        o, chosen = _selected_fragment(
+            qh, k, v, k_cache, v_cache, seg, pos0, select, part)
+        stats["index_rows_selected"] = jnp.sum(chosen, axis=-1, dtype=jnp.float32)
+        if rows.get("choices"):
+            stats["index_choices"] = chosen
     else:
         metrics.inc_attention_fragment_lowering("xla")
         nb = max(1, b // env_block(h, t, depth + own))
@@ -275,4 +365,68 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
         if ring:
             o, seen = o
             stats["pairs_seen"] = jnp.sum(seen)
-    return o.reshape(b, t, h, d), (new_k, new_v), stats
+    return o.reshape(b, t, h, d), after, stats
+
+
+def _selected_fragment(qh, k, v, k_cache, v_cache, seg, pos0, select, part):
+    """The fragment form under a learned index: ``(o (B, T, kv, group,
+    D) float32, the choice (B, T, depth + T) bool)``. A block of streams
+    (:func:`env_block`) and a tile of queries (:func:`query_tile`) at a
+    time, twice. First the index scores over the stored rows and the
+    fragment's own and the choice as a mask: it has no derivative and is
+    kept for the backward pass, a byte a (query, row) pair. Then the
+    masked softmax of the attention's own scores under it, each block
+    and tile recomputed there as the text without an index is."""
+    b, t, hkv, group, d = qh.shape
+    depth, dtype = k_cache.shape[1], qh.dtype
+    heads = hkv * group + select.q.shape[2]
+    tile = query_tile(heads, t, depth + t)
+    tiles = lambda a: jnp.moveaxis(
+        a.reshape((a.shape[0], t // tile, tile) + a.shape[2:]), 1, 0)
+    join = lambda a: jnp.moveaxis(a, 0, 1).reshape((a.shape[1], t) + a.shape[3:])
+
+    def choose(sege, pos0e, qi, wi, ki, ic):
+        seen = jnp.concatenate(fragment_masks(sege, pos0e, None, depth, None), axis=-1)
+        index_keys = jnp.concatenate([ic, ki], axis=1)
+
+        def some_queries(xs):
+            qit, wit, seent = xs
+            with part("index/scores"):
+                index = sparse_index.scores(qit, wit, index_keys)
+            with part("index/topk"):
+                return sparse_index.select(index, seent, select.top_k)
+
+        return join(jax.lax.map(some_queries, tuple(tiles(a) for a in (qi, wi, seen))))
+
+    def attend(qe, ke, ve, kc, vc, chosen):
+        kc = kc.reshape(kc.shape[:2] + (hkv, d))
+        vc = vc.reshape(vc.shape[:2] + (hkv, d))
+
+        def some_queries(qt, chosent):
+            with part("scores"):
+                s = jnp.concatenate([jnp.einsum(
+                    "btngd,bsnd->bngts", qt, x, preferred_element_type=jnp.float32)
+                    for x in (kc, ke)], axis=-1)
+                w = jax.nn.softmax(
+                    jnp.where(chosent[:, None, None], s, -jnp.inf), axis=-1).astype(dtype)
+            with part("out"):
+                return sum(jnp.einsum(
+                    "bngts,bsnd->btngd", wx, x, preferred_element_type=jnp.float32)
+                    for wx, x in ((w[..., :depth], vc), (w[..., depth:], ve)))
+
+        return join(jax.lax.map(
+            lambda xs: jax.checkpoint(some_queries)(*xs), (tiles(qe), tiles(chosen))))
+
+    nb = max(1, b // env_block(heads, tile, depth + t))
+    if b % nb:
+        nb = 1
+    blocked = lambda *args: jax.tree_util.tree_map(
+        lambda a: a.reshape((nb, b // nb) + a.shape[1:]), args)
+    chosen = jax.lax.map(
+        lambda xs: choose(*xs),
+        blocked(seg, pos0, select.q, select.w, select.k, select.cache))
+    out = jax.lax.map(
+        lambda xs: jax.checkpoint(attend)(*xs),
+        blocked(qh, k, v, k_cache, v_cache) + (chosen,))
+    whole = lambda a: a.reshape((b,) + a.shape[2:])
+    return whole(out), whole(chosen)

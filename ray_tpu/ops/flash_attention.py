@@ -358,8 +358,14 @@ def fragment_head_tile(tokens, heads, kv_heads, head_dim, own=None) -> int:
 
 
 def fragment_kernel_applies(
-        tokens, heads, kv_heads, head_dim, depth, dtype, own=None) -> bool:
-    """The fragment kernel's lowering exists on a TPU
+        tokens, heads, kv_heads, head_dim, depth, dtype, own=None,
+        selected: bool = False) -> bool:
+    """Never for a call with a selection (``selected``: a learned index
+    chose each query's rows, ``ops/cached_attention.Selection``): the
+    kernel's masks are arithmetic on a tile's positions, and no kernel
+    takes a mask a (query, row) pair yet; such a call lowers to the text
+    under a selection and counts under ``path="selected_xla"``.
+    Otherwise the fragment kernel's lowering exists on a TPU
     (``ops/backend.is_tpu``) for bfloat16 operands, a fragment of
     whole 128-lane tiles of tokens (the own keys' episode
     numbers lie along the lanes, and a tile of weights is turned for the
@@ -377,6 +383,7 @@ def fragment_kernel_applies(
         and (head_dim * pack % _LANES == 0
              or kv_heads == 1 and head_dim % (_LANES // 2) == 0)
         and fragment_head_tile(tokens, heads, kv_heads, head_dim, own) > 0
+        and not selected
     )
 
 
@@ -853,8 +860,14 @@ def _tail_lanes(head_dim: int, value_dim: int) -> int:
 
 
 def step_kernel_applies(heads, kv_heads, head_dim, depth, dtype,
-                        value_dim=None) -> bool:
-    """The step kernel's lowering exists on a TPU
+                        value_dim=None, selected: bool = False) -> bool:
+    """Never for a call with a selection (``selected``: a learned index
+    chose the query's rows): the kernel walks the CONTIGUOUS key blocks
+    below a stream's depth, masks by position alone and fetches no row
+    by number; such a call runs the one-token text over every slot under
+    the selection's mask, counted under ``path="selected_xla"``.
+    Otherwise the step kernel's lowering
+    exists on a TPU
     (``ops/backend.is_tpu``) for bfloat16 operands, a cache of whole
     key blocks, a key
     that is whole lane tiles or packs into one (64: two key heads a
@@ -866,8 +879,8 @@ def step_kernel_applies(heads, kv_heads, head_dim, depth, dtype,
     whole tiles, and the blocks in flight with two streams' further
     lanes in VMEM."""
     block_k = fragment_block_k(depth)
-    if not (backend.is_tpu() and dtype == jnp.bfloat16 and block_k > 0
-            and heads % kv_heads == 0):
+    if selected or not (backend.is_tpu() and dtype == jnp.bfloat16 and block_k > 0
+                        and heads % kv_heads == 0):
         return False
     if value_dim is not None:
         tail = _tail_lanes(head_dim, value_dim)

@@ -1587,6 +1587,7 @@ class JaxPolicy(Policy):
             rollout=True,
         )
         telemetry_metrics.note_expert_load(infos)
+        telemetry_metrics.note_index_selection(infos)
         telemetry_metrics.note_diffusion_passes(infos)
         return infos, carry, metrics, skipped
 

@@ -113,6 +113,12 @@ MOE_DECODE_HELD_TOUCHED_TOTAL = "ray_tpu_moe_decode_held_experts_touched_total"
 # over the expert layers, summed over updates under stat = share, the
 # updates counted under stat = updates
 MOE_HELD_GROUP_CHOSEN_TOTAL = "ray_tpu_moe_held_group_chosen_total"
+# a learned index over an attention cache's rows (ops/sparse_index): an
+# update's statistics of its choice, each summed over updates under its
+# own name less ``index_`` (stat = rows_scored_mean | rows_selected_mean
+# | selected_share_mean | dense_query_share), the updates counted under
+# stat = updates
+ATTENTION_INDEX_SELECTION_TOTAL = "ray_tpu_attention_index_selection_total"
 # which lowering each traced one-token gated-delta step took
 # (ops/deltanet.py): path = kernel (the Pallas kernel: a TPU, whole
 # tiles) | xla (the jax.numpy body); decay = head (a number a head:
@@ -639,6 +645,33 @@ def note_expert_load(infos) -> None:
             chosen.inc(1.0, {"stat": "updates"})
 
 
+_INDEX_STATS = ("rows_scored_mean", "rows_selected_mean", "selected_share_mean",
+                "dense_query_share")
+
+
+def note_index_selection(infos) -> None:
+    """Feed the learned index's counter from drained per-update learner
+    stats (a no-op for a model whose attention has no index)."""
+    for info in infos:
+        if "index_selected_share_mean" not in info:
+            continue
+        chosen = counter(
+            ATTENTION_INDEX_SELECTION_TOTAL,
+            "a learned index's choice of cache rows, an update's means over "
+            "queries and layers summed over updates; and the updates counted",
+            ("stat",),
+        )
+        for stat in _INDEX_STATS:
+            chosen.inc(float(info["index_" + stat]), {"stat": stat})
+        chosen.inc(1.0, {"stat": "updates"})
+
+
+def index_selection() -> Dict[str, float]:
+    """``{stat: sum over updates, "updates": n}`` since the process
+    began ({} for a model whose attention has no index)."""
+    return _totals_by_tag(ATTENTION_INDEX_SELECTION_TOTAL, "stat")
+
+
 def add_diffusion_token_passes(form: str, n: float) -> None:
     counter(
         DIFFUSION_TOKEN_PASSES_TOTAL,
@@ -861,7 +894,8 @@ def learn_minibatch_lowerings() -> Dict[str, float]:
 
 def inc_attention_fragment_lowering(path: str) -> None:
     """One traced attention layer's fragment form took ``path``
-    (``kernel`` | ``xla``)."""
+    (``kernel`` | ``xla`` | ``selected_xla``: the text under a learned
+    index's choice of rows)."""
     counter(
         ATTENTION_FRAGMENT_LOWERINGS_TOTAL,
         "attention layers' fragment forms traced, by the lowering they took",
@@ -876,7 +910,8 @@ def attention_fragment_lowerings() -> Dict[str, float]:
 
 def inc_attention_step_lowering(path: str) -> None:
     """One traced attention layer's one-token form took ``path``
-    (``kernel`` | ``xla``)."""
+    (``kernel`` | ``xla`` | ``selected_xla``: the text over every slot
+    under a learned index's choice of rows)."""
     counter(
         ATTENTION_STEP_LOWERINGS_TOTAL,
         "attention layers' one-token forms traced, by the lowering they took",
