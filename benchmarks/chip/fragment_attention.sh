@@ -2,6 +2,10 @@
 # The learn form's attention alone on the chip: the kernel against the
 # XLA text at the three sequence cells' sizes (a line a case, appended to
 # chiprun_out/fragment_attention_alone.jsonl), then the on-chip tests of it.
+# Cases by name: smallthinker_full, smallthinker_ring, qwen3next, granite4h,
+# xing4_latent, and keye2_selected (the learned-index cell's layer: the text
+# under a choice against the kernel with the choice as its operand, and
+# the kernel without one beside them).
 #   chiprun --timeout 1500 -- bash benchmarks/chip/fragment_attention.sh [<block_k> ...]
 # A cell's runs with the counter and the statistic: benchmarks/chip/sides.sh.
 set -u

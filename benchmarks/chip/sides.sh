@@ -22,7 +22,7 @@ for run in "$@"; do
   ( cd ".chip_check/$side" && PYTHONPATH=. python3 "$OLDPWD/benchmarks/setup_account.py" \
       "$out/sides_${cell}_$side.jsonl" --workload "$cell" --seed "$seed" \
       --seconds 30 --trace "$trace" > "$log" 2>&1 )
-  echo "$n $side seed $seed rc=$? in $(( $(date +%s) - t0 )) s; cache $(du -sm "$JAX_COMPILATION_CACHE_DIR" | cut -f1) MiB"
+  echo "$n $side seed $seed rc=$? in $(( $(date +%s) - t0 )) s; cache $(find "$JAX_COMPILATION_CACHE_DIR" -type f -printf '%s\n' | awk '{s += $1} END {print s, "B in", NR, "files"}')"
   [ "$trace" = 1 ] && ( cd ".chip_check/$side" && python3 -m perf.program_trace .perf_trace \
       > "$out/program_trace_${cell}_${side}_$seed.json" 2> /dev/null )
   grep -E "^\[setup\]" "$log" | cut -c1-330

@@ -14,8 +14,15 @@ latent layer's attention from the queries and the latent rows on
 (``ops/latent_attention``): ``expanded_fragment``'s text, every stored
 key rebuilt through ``W_kvb``, against ``absorbed_fragment`` on the
 kernel, with the gradients of ``q_nope``, ``q_pe``, the own rows and
-``W_kvb``. Prints one JSON line a case. TPU only: a time from another
-backend is not a device time.
+``W_kvb``. ``keye2_selected`` is the learned-index cell's layer (32
+query heads over 4 key heads of 128, 16 streams over episodes of 8,192)
+under a choice of 2,048 rows a query, made once by
+``cached_attention._choose_rows`` from random index operands (scattered,
+as seeded index weights choose) and handed to both sides as data: the
+text under the choice (``_selected_text``) against the kernel with the
+choice as its operand, and beside them the kernel WITHOUT a choice
+(``kernel_no_choice_*``: what the operand costs). Prints one JSON line
+a case. TPU only: a time from another backend is not a device time.
 
     ... benchmarks/profile_fragment_attention.py step [<case> ...] [<block_k> ...]
 
@@ -37,12 +44,25 @@ turn: XLA's text (every slot, twice) against ``step_attention`` over the
 one cache (the latent of the blocks held and every slot's 64 further
 lanes as a lane tile), the bytes those of rows as HBM holds them (576
 lanes padded to 640), microseconds a layer.
+
+    JAX_PLATFORMS=cpu PYTHONPATH=. python benchmarks/profile_fragment_attention.py jaxpr
+
+Needs no TPU: the sha256 of the fragment kernel pair's jaxpr (forward,
+the checkpoint's recomputation and backward: bodies, index maps,
+operands) WITHOUT a choice at every case above, and with a clean pass's
+rows under a block rule of 4 (the block-diffusion cell's). Run it from two
+checkouts (this file laid over the older one): equal hashes say that a
+change of ``ops/flash_attention.py`` left the kernels of the cells
+without a learned index as they were, where a program lowered on the CPU
+holds the text and says nothing of them.
 """
 
 from __future__ import annotations
 
 import functools
+import hashlib
 import json
+import re
 import sys
 import time
 
@@ -58,6 +78,10 @@ CASES = {
     "smallthinker_ring": (16, 256, 4, 7, 128, 4096, 4096, 8192),
     "qwen3next": (16, 128, 2, 8, 256, 2048, None, 2048),
     "granite4h": (16, 256, 8, 4, 64, 2048, None, 2048),
+}
+# the same, then the index's heads, head and rows a query
+SELECTED_CASES = {
+    "keye2_selected": (16, 256, 4, 8, 128, 8192, None, 8192, 16, 64, 2048),
 }
 # a group's streams, tokens, heads, nope, rope, latent, value head, depth
 LATENT_CASES = {
@@ -117,9 +141,10 @@ def fragment_rows(b, t, episode):
     return pos0, seg, positions
 
 
-def report(name, text, kernel, args, w, parts, pos0, depth, blocks):
+def report(name, text, kernel, args, w, parts, pos0, depth, blocks, beside=None):
     """``text(*args)`` against ``kernel(block_k)(*args)``: a JSON line a
-    key block size."""
+    key block size. ``beside``: ``{name: block_k -> fn}`` timed as the
+    kernel is and not compared."""
 
     def measure(fn):
         """Milliseconds of the forward pass and of forward, recomputation
@@ -136,18 +161,22 @@ def report(name, text, kernel, args, w, parts, pos0, depth, blocks):
     for block_k in blocks:
         (fwd, in_all), got = measure(kernel(block_k))
         skipped, walked = flash_attention.fragment_key_blocks(pos0, depth, block_k)
+        others = {}
+        for label, fn in (beside or {}).items():
+            (others[f"{label}_fwd_ms"], others[f"{label}_fwd_remat_bwd_ms"]), _ = (
+                measure(fn(block_k)))
         print(json.dumps({
             "case": name,
             "block_k": flash_attention.fragment_block_k(depth, block_k),
             "xla_fwd_ms": xla_fwd, "xla_fwd_remat_bwd_ms": xla_all,
-            "kernel_fwd_ms": fwd, "kernel_fwd_remat_bwd_ms": in_all,
+            "kernel_fwd_ms": fwd, "kernel_fwd_remat_bwd_ms": in_all, **others,
             **{f"rel_{part}": round(rel(a, b), 5)
                for part, a, b in zip(parts, got, want)},
             "key_blocks_skipped_share": round(float(skipped) / walked, 4),
         }), flush=True)
 
 
-def run(name, b, t, kv, group, d, depth, window, episode, blocks):
+def run(name, b, t, kv, group, d, depth, window, episode, *index, blocks):
     bf = jnp.bfloat16
     h = kv * group
     keys = jax.random.split(jax.random.PRNGKey(0), 6)
@@ -160,23 +189,44 @@ def run(name, b, t, kv, group, d, depth, window, episode, blocks):
     pos0, seg, positions = fragment_rows(b, t, episode)
     ctx = {"seg": seg, "positions": positions, "pos0": pos0}
     scale = d ** -0.5
+    heads_of = lambda q: (q * scale).astype(bf).reshape(b, t, kv, group, d)
 
     def text(q, k, v):
         return cached_attention.cached_attention(
             q, k, v, (kc, vc), ctx, scale=scale, window=window, dtype=bf,
             scope="swa" if window else "attn")[0]
 
-    def kernel(block_k):
+    def kernel(block_k, **choice):
         def attention(q, k, v):
-            qh = (q * scale).astype(bf).reshape(b, t, kv, group, d)
             return flash_attention.fragment_attention(
-                qh, k.astype(bf), v.astype(bf), kc, vc, pos0, seg, positions,
-                window=window, block_k=block_k,
+                heads_of(q), k.astype(bf), v.astype(bf), kc, vc, pos0, seg,
+                positions, window=window, block_k=block_k, **choice,
             ).reshape(b, t, h, d)
         return attention
 
+    beside = None
+    if index:
+        heads, width, top_k = index
+        ik = jax.random.split(keys[5], 4)
+        select = cached_attention.Selection(
+            jax.random.normal(ik[0], (b, t, heads, width), bf),
+            jax.random.uniform(ik[1], (b, t, heads), jnp.float32),
+            jax.random.normal(ik[2], (b, t, width), bf),
+            jax.random.normal(ik[3], (b, depth, width), bf), top_k)
+        chosen = jax.jit(lambda: cached_attention._choose_rows(
+            h + heads, seg, pos0, select, jax.named_scope))()
+
+        def text(q, k, v):
+            return cached_attention._selected_text(
+                h + heads, heads_of(q), k.astype(bf), v.astype(bf), kc, vc,
+                chosen, jax.named_scope).reshape(b, t, h, d)
+
+        kernel, beside = functools.partial(
+            kernel, chosen=(chosen[..., :depth], chosen[..., depth:])), {
+                "kernel_no_choice": kernel}
+
     report(name, text, kernel, (q, k, v), w, ("o", "dq", "dk", "dv"),
-           pos0, depth, blocks)
+           pos0, depth, blocks, beside)
 
 
 def run_latent(name, b, t, h, dn, rope, latent, dv, depth, blocks):
@@ -342,7 +392,53 @@ def run_latent_step(name, b, h, dn, rope, latent, dv, depth, blocks):
         }), flush=True)
 
 
+def jaxpr_hashes():
+    bf, i32 = jnp.bfloat16, jnp.int32
+    on = jax.ShapeDtypeStruct
+
+    def line(name, attention, differentiated, fixed):
+        """``attention(*differentiated, *fixed)``'s gradients under a
+        checkpoint, traced for shapes alone."""
+        n = len(differentiated)
+        traced = jax.make_jaxpr(lambda *a: jax.grad(
+            lambda *d: jnp.sum(jax.checkpoint(
+                lambda *d: attention(*d, *a[n:]))(*d).astype(jnp.float32)),
+            argnums=tuple(range(n)))(*a[:n]))(*differentiated, *fixed)
+        # without addresses and the line numbers of the kernels' file
+        text = re.sub(r"0x[0-9a-f]+|(?<=\.py):\d+", "", str(traced))
+        print(name, hashlib.sha256(text.encode()).hexdigest()[:16], len(text))
+
+    def rows(b, t):
+        return on((b,), i32), on((b, t), i32), on((b, t), i32)
+
+    for name, (b, t, kv, group, d, depth, window, _) in CASES.items():
+        # a block rule has no window
+        for label, block in ((name, 1), (name + "_clean_block4", 4))[
+                :1 if window else 2]:
+            def attention(q, k, v, kc, vc, pos0, seg, positions, block=block):
+                return flash_attention.fragment_attention(
+                    q, k, v, kc, vc, pos0, seg, positions, window=window,
+                    block=block, clean=(k, v) if block > 1 else None)
+
+            line(label, attention,
+                 (on((b, t, kv, group, d), bf),) + (on((b, t, kv, d), bf),) * 2,
+                 (on((b, depth, kv * d), bf),) * 2 + rows(b, t))
+    for name, (b, t, h, dn, rope, latent, dv, depth) in LATENT_CASES.items():
+        def attention(q_nope, q_pe, rows_new, kv_b, cache, pos0, seg, positions):
+            return latent_attention.absorbed_fragment(
+                q_nope, q_pe, rows_new, cache, kv_b, seg, positions, pos0,
+                (dn + rope) ** -0.5, bf)
+
+        line(name, attention,
+             (on((b, t, h, dn), jnp.float32), on((b, t, h, rope), jnp.float32),
+              on((b, t, latent + rope), bf),
+              on((latent, h * (dn + dv)), jnp.float32)),
+             (on((b, depth, latent + rope), bf),) + rows(b, t))
+
+
 def main(argv):
+    if argv[:1] == ["jaxpr"]:
+        return jaxpr_hashes()
     if jax.default_backend() != "tpu":
         raise SystemExit("a TPU is needed: a time from another backend is no device time")
     if argv[:1] == ["step"]:
@@ -355,12 +451,13 @@ def main(argv):
                 run_latent_step(name, *LATENT_STEP_CASES[name], blocks)
         return
     # the text is the rule's other branch
-    flash_attention.fragment_kernel_applies = lambda *a: False
+    flash_attention.fragment_kernel_applies = lambda *a, **selected: False
     blocks = [int(a) for a in argv if a.isdigit()] or [None]
-    names = [a for a in argv if not a.isdigit()] or [*CASES, *LATENT_CASES]
+    names = [a for a in argv if not a.isdigit()] or [
+        *CASES, *SELECTED_CASES, *LATENT_CASES]
     for name in names:
-        if name in CASES:
-            run(name, *CASES[name], blocks)
+        if name in CASES or name in SELECTED_CASES:
+            run(name, *{**CASES, **SELECTED_CASES}[name], blocks=blocks)
         else:
             run_latent(name, *LATENT_CASES[name], blocks)
 

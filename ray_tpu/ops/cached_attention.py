@@ -185,17 +185,22 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
     scattered with the other two and returned after them. Where ``top_k``
     reaches the cache's depth every row seen is chosen and the call IS
     the one without ``select`` (the same forms, the same kernels).
-    Otherwise no kernel serves it (``step_kernel_applies`` /
-    ``fragment_kernel_applies`` answer no for a selection) and the path
-    label is ``selected_xla`` in both counters. One token: the index
+    Otherwise the choice is a MASK in both forms. One token: no kernel
+    serves it (``step_kernel_applies`` answers no for a selection; the
+    step counter's label is ``selected_xla``): the index
     scores of the stream's slots (``/index/scores``), their exact top-k
     as a mask over the slots (``/index/topk``) and the one-token text
     over every slot under it; nothing is gathered (``/select`` is the
     scope of a lowering that fetches the chosen rows alone, and holds
-    nothing today). A fragment: a stream and :func:`query_tile` queries
-    at a time, the index scores
-    over the stored rows and the fragment's own, the choice as a mask
-    (``sparse_index.select``) and the text's masked softmax under it; no
+    nothing today). A fragment: first the choice, a stream and
+    :func:`query_tile` queries at a time: the index scores
+    over the stored rows and the fragment's own and the mask
+    (``sparse_index.select``); then, where ``fragment_kernel_applies``
+    says so (it counts the choice's blocks in the tile's room),
+    ``flash_attention.fragment_attention`` with the choice as one more
+    operand of both kernels (``path="selected_kernel"``), else the
+    text's masked softmax under it a tile at a time
+    (``path="selected_xla"``); no
     row is gathered (2,048 rows a query of a fragment would be a
     gigabyte a stream and layer). ``stats["index_rows_selected"]``: the
     rows each query attended to, ``(B, T)``; where ``rows["choices"]``
@@ -330,24 +335,34 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
              if step_kernel else none)):
         stats[name + "_skipped"] = skipped
         stats[name + "_walked"] = jnp.int32(walked)
+    chosen = None
+    if select is not None:
+        # the attention's heads and the index's size the text's tiles; the
+        # kernels take the choice in two parts, a byte a pair
+        sized = h + select.q.shape[2]
+        chosen = _choose_rows(sized, seg, pos0, select, part, split=kernel)
+        stats["index_rows_selected"] = functools.reduce(jnp.add, [
+            jnp.sum(c, axis=-1, dtype=jnp.float32)
+            for c in (chosen if kernel else (chosen,))])
+        if rows.get("choices"):
+            stats["index_choices"] = (
+                jnp.concatenate(chosen, axis=-1) != 0 if kernel else chosen)
     if kernel:
         # one tiled kernel, forward and backward: no score matrix is
-        # written, and no block of streams is needed to hold one
-        metrics.inc_attention_fragment_lowering("kernel")
+        # written, and no block of streams is needed to hold one; a
+        # choice is one more operand of both
+        metrics.inc_attention_fragment_lowering(
+            "kernel" if chosen is None else "selected_kernel")
         with part("scores"):
             o = flash_attention.fragment_attention(
                 qh, k, v, k_cache, v_cache, pos0, seg, positions, window=window,
-                block=block, clean=clean)
+                block=block, clean=clean, chosen=chosen)
             if ring:  # the masks' arithmetic, reduced where it is built
                 stats["pairs_seen"] = jnp.sum(pairs_seen(
                     *fragment_masks(seg, pos0, positions, depth, window)))
-    elif select is not None:
+    elif chosen is not None:
         metrics.inc_attention_fragment_lowering("selected_xla")
-        o, chosen = _selected_fragment(
-            qh, k, v, k_cache, v_cache, seg, pos0, select, part)
-        stats["index_rows_selected"] = jnp.sum(chosen, axis=-1, dtype=jnp.float32)
-        if rows.get("choices"):
-            stats["index_choices"] = chosen
+        o = _selected_text(sized, qh, k, v, k_cache, v_cache, chosen, part)
     else:
         metrics.inc_attention_fragment_lowering("xla")
         nb = max(1, b // env_block(h, t, depth + own))
@@ -368,22 +383,41 @@ def cached_attention(q, k, v, caches, rows, *, scale, window, dtype, scope,
     return o.reshape(b, t, h, d), after, stats
 
 
-def _selected_fragment(qh, k, v, k_cache, v_cache, seg, pos0, select, part):
-    """The fragment form under a learned index: ``(o (B, T, kv, group,
-    D) float32, the choice (B, T, depth + T) bool)``. A block of streams
-    (:func:`env_block`) and a tile of queries (:func:`query_tile`) at a
-    time, twice. First the index scores over the stored rows and the
-    fragment's own and the choice as a mask: it has no derivative and is
-    kept for the backward pass, a byte a (query, row) pair. Then the
-    masked softmax of the attention's own scores under it, each block
-    and tile recomputed there as the text without an index is."""
-    b, t, hkv, group, d = qh.shape
-    depth, dtype = k_cache.shape[1], qh.dtype
-    heads = hkv * group + select.q.shape[2]
-    tile = query_tile(heads, t, depth + t)
+def _blocks_and_tiles(b, t, heads, rows):
+    """How a fragment under a learned index is cut, so that ``heads``
+    heads' float32 scores over ``rows`` keys fit :func:`env_block` and
+    :func:`query_tile`: ``(blocked, whole, tiles, join)``. ``blocked``:
+    arrays ``(B, ...)`` with the streams in blocks on a leading axis,
+    ``whole`` its inverse; ``tiles``: a block's array ``(b, T, ...)``
+    with the queries in tiles on a leading axis, ``join`` its inverse."""
+    tile = query_tile(heads, t, rows)
+    nb = max(1, b // env_block(heads, tile, rows))
+    if b % nb:
+        nb = 1
+    blocked = lambda *args: jax.tree_util.tree_map(
+        lambda a: a.reshape((nb, b // nb) + a.shape[1:]), args)
+    whole = lambda a: a.reshape((b,) + a.shape[2:])
     tiles = lambda a: jnp.moveaxis(
         a.reshape((a.shape[0], t // tile, tile) + a.shape[2:]), 1, 0)
     join = lambda a: jnp.moveaxis(a, 0, 1).reshape((a.shape[1], t) + a.shape[3:])
+    return blocked, whole, tiles, join
+
+
+def _choose_rows(heads, seg, pos0, select, part, split=False):
+    """A learned index's choice over a fragment, ``(B, T, depth + T)``
+    bool: which of the stored slots and then of the fragment's own rows
+    each query attends to. A block of streams and a tile of queries at
+    a time (:func:`_blocks_and_tiles` for ``heads`` heads, the
+    attention's and the index's), the index scores over the stored rows
+    and the fragment's own and their exact top-k among the rows the
+    masks let the query see. It has no derivative and is kept for the
+    backward pass, a byte a (query, row) pair. ``split``: the stored
+    slots' part and the own rows' apart and int8, as the fragment
+    kernels take them (``flash_attention.fragment_attention``'s
+    ``chosen``), written so by the fusion that makes the choice."""
+    depth = select.cache.shape[1]
+    blocked, whole, tiles, join = _blocks_and_tiles(
+        *seg.shape, heads, depth + seg.shape[1])
 
     def choose(sege, pos0e, qi, wi, ki, ic):
         seen = jnp.concatenate(fragment_masks(sege, pos0e, None, depth, None), axis=-1)
@@ -394,11 +428,33 @@ def _selected_fragment(qh, k, v, k_cache, v_cache, seg, pos0, select, part):
             with part("index/scores"):
                 index = sparse_index.scores(qit, wit, index_keys)
             with part("index/topk"):
-                return sparse_index.select(index, seent, select.top_k)
+                choice = sparse_index.select(index, seent, select.top_k)
+                if not split:
+                    return choice
+                return tuple(c.astype(jnp.int8)
+                             for c in jnp.split(choice, [depth], axis=-1))
 
-        return join(jax.lax.map(some_queries, tuple(tiles(a) for a in (qi, wi, seen))))
+        return jax.tree_util.tree_map(join, jax.lax.map(
+            some_queries, tuple(tiles(a) for a in (qi, wi, seen))))
 
-    def attend(qe, ke, ve, kc, vc, chosen):
+    return jax.tree_util.tree_map(whole, jax.lax.map(
+        lambda xs: choose(*xs),
+        blocked(seg, pos0, select.q, select.w, select.k, select.cache)))
+
+
+def _selected_text(heads, qh, k, v, k_cache, v_cache, chosen, part):
+    """The fragment form under a choice (:func:`_choose_rows`) as XLA
+    writes it, ``o (B, T, kv, group, D)`` float32: where no kernel's
+    lowering exists, and the kernels' oracle. A block of streams and a
+    tile of queries at a time as the choice was made, the masked softmax
+    of the attention's scores over every stored row and the fragment's
+    own under the choice, each block and tile recomputed in the backward
+    pass as the text without an index is."""
+    b, t, hkv, _, d = qh.shape
+    depth, dtype = k_cache.shape[1], qh.dtype
+    blocked, whole, tiles, join = _blocks_and_tiles(b, t, heads, depth + t)
+
+    def attend(qe, ke, ve, kc, vc, chosene):
         kc = kc.reshape(kc.shape[:2] + (hkv, d))
         vc = vc.reshape(vc.shape[:2] + (hkv, d))
 
@@ -415,18 +471,8 @@ def _selected_fragment(qh, k, v, k_cache, v_cache, seg, pos0, select, part):
                     for wx, x in ((w[..., :depth], vc), (w[..., depth:], ve)))
 
         return join(jax.lax.map(
-            lambda xs: jax.checkpoint(some_queries)(*xs), (tiles(qe), tiles(chosen))))
+            lambda xs: jax.checkpoint(some_queries)(*xs), (tiles(qe), tiles(chosene))))
 
-    nb = max(1, b // env_block(heads, tile, depth + t))
-    if b % nb:
-        nb = 1
-    blocked = lambda *args: jax.tree_util.tree_map(
-        lambda a: a.reshape((nb, b // nb) + a.shape[1:]), args)
-    chosen = jax.lax.map(
-        lambda xs: choose(*xs),
-        blocked(seg, pos0, select.q, select.w, select.k, select.cache))
-    out = jax.lax.map(
+    return whole(jax.lax.map(
         lambda xs: jax.checkpoint(attend)(*xs),
-        blocked(qh, k, v, k_cache, v_cache) + (chosen,))
-    whole = lambda a: a.reshape((b,) + a.shape[2:])
-    return whole(out), whole(chosen)
+        blocked(qh, k, v, k_cache, v_cache, chosen)))

@@ -338,7 +338,8 @@ def _heads_packed(head_dim: int, kv_heads: int) -> int:
     return pack if kv_heads % pack == 0 else 1
 
 
-def fragment_head_tile(tokens, heads, kv_heads, head_dim, own=None) -> int:
+def fragment_head_tile(tokens, heads, kv_heads, head_dim, own=None,
+                       selected: bool = False) -> int:
     """Query heads of one block of keys in a query tile: the most (a
     divisor of the group) that the backward pass can hold in VMEM, 0
     where not even one fits. Per row and lane ``q`` and ``dq`` in
@@ -347,12 +348,18 @@ def fragment_head_tile(tokens, heads, kv_heads, head_dim, own=None) -> int:
     one-lane statistics that occupy whole 128-lane rows; beside the
     rows a head's float32 tiles of scores, weights and their gradient
     over the widest key block (the stored 512, or the fragment's ``own``
-    keys: as many as its tokens, twice that in a noisy pass)."""
+    keys: as many as its tokens, twice that in a noisy pass); under a
+    selection (``selected``) the choice's stored and own blocks, a byte
+    a (query, key) pair, each twice for the pipeline, and the wider one
+    widened to 32 bits."""
     pack = _heads_packed(head_dim, kv_heads)
     group = pack * (heads // kv_heads)
     row = 28 * _ceil_to(head_dim * pack, _LANES) + 12 * _LANES
-    room = _FRAGMENT_VMEM_BYTES // 2 - 12 * tokens * max(
-        own or tokens, _FRAGMENT_BLOCK_K)
+    own = own or tokens
+    room = _FRAGMENT_VMEM_BYTES // 2 - 12 * tokens * max(own, _FRAGMENT_BLOCK_K)
+    if selected:
+        room -= tokens * (2 * (_FRAGMENT_BLOCK_K + own)
+                          + 4 * max(own, _FRAGMENT_BLOCK_K))
     return next((tile for tile in range(group, 0, -1)
                  if group % tile == 0 and tile * tokens * row <= room), 0)
 
@@ -360,12 +367,7 @@ def fragment_head_tile(tokens, heads, kv_heads, head_dim, own=None) -> int:
 def fragment_kernel_applies(
         tokens, heads, kv_heads, head_dim, depth, dtype, own=None,
         selected: bool = False) -> bool:
-    """Never for a call with a selection (``selected``: a learned index
-    chose each query's rows, ``ops/cached_attention.Selection``): the
-    kernel's masks are arithmetic on a tile's positions, and no kernel
-    takes a mask a (query, row) pair yet; such a call lowers to the text
-    under a selection and counts under ``path="selected_xla"``.
-    Otherwise the fragment kernel's lowering exists on a TPU
+    """The fragment kernel's lowering exists on a TPU
     (``ops/backend.is_tpu``) for bfloat16 operands, a fragment of
     whole 128-lane tiles of tokens (the own keys' episode
     numbers lie along the lanes, and a tile of weights is turned for the
@@ -373,7 +375,14 @@ def fragment_kernel_applies(
     whole lane tiles, packs into one (64: two key heads a block) or is
     the one key head's (its block is the cache's whole minor dimension:
     the latent row of 576, whole half tiles), and a query tile of at
-    least one head (:func:`fragment_head_tile`)."""
+    least one head (:func:`fragment_head_tile`). A call with a selection
+    (``selected``: a learned index chose each query's rows,
+    ``ops/cached_attention.Selection``) takes the same rule with the
+    choice's blocks in the tile's room: the kernels take the choice as
+    one more operand, a byte a (query, row) pair
+    (:func:`fragment_attention`'s ``chosen``), and it counts under
+    ``path="selected_kernel"``, where the rule says no under
+    ``path="selected_xla"``."""
     pack = _heads_packed(head_dim, kv_heads)
     return (
         backend.is_tpu()
@@ -382,8 +391,8 @@ def fragment_kernel_applies(
         and fragment_block_k(depth) > 0
         and (head_dim * pack % _LANES == 0
              or kv_heads == 1 and head_dim % (_LANES // 2) == 0)
-        and fragment_head_tile(tokens, heads, kv_heads, head_dim, own) > 0
-        and not selected
+        and fragment_head_tile(
+            tokens, heads, kv_heads, head_dim, own, selected) > 0
     )
 
 
@@ -450,16 +459,55 @@ def _own_mask(seg_q, seg_k, window, block=1):
     return mask
 
 
+def _split_choice(refs, selected):
+    """``(the choice's stored and own blocks, the further references)``:
+    under a selection the two lead the kernel's references after the
+    masks' operands; without one there is none."""
+    return (refs[:2], refs[2:]) if selected else ((None, None), refs)
+
+
+def _chosen(mask, chosen_ref):
+    """``mask`` ``(T, keys)`` and the choice's block of the same pairs
+    (``(1, T, keys)`` int8, a byte a pair: Mosaic loads it packed four
+    rows a sublane and widens it to the 32-bit layout of the scores'
+    tiles, where a comparison makes it a mask); ``mask`` as it is
+    without a selection."""
+    if chosen_ref is None:
+        return mask
+    return mask & (chosen_ref[0].astype(jnp.int32) != 0)
+
+
+def _each_head(heads, body, rolled, carry=None):
+    """``carry = body(g, carry)`` for each query head ``g`` of a tile:
+    unrolled, a copy of the body a head, or (``rolled``) one body under
+    a loop. The kernels under a selection roll it: a program keeps a
+    copy of both kernels a call site (three a layer), and eight heads
+    unrolled were 2.1 MB of the learned-index cell's compiled program
+    (61.9 MB for 59.8, compressed) for 16% of a layer's 13.0 ms on the
+    chip, 1% of the cell's rate (PR 66). Without a selection the bodies
+    are unrolled as they were: nine cells' programs hold them so."""
+    if rolled:
+        return jax.lax.fori_loop(0, heads, body, carry)
+    for g in range(heads):
+        carry = body(g, carry)
+    return carry
+
+
 def _fragment_fwd_kernel(
     pos0_ref, q_ref, kc_ref, vc_ref, k_ref, v_ref, seg_q_ref, pos_q_ref,
-    seg_k_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-    window, depth, block_k, tiles, block,
+    seg_k_ref, *refs, window, depth, block_k, tiles, block, selected,
 ):
     """One stream, one key head, one tile of its query heads, one block
     of keys: the stored blocks in turn, then the fragment's own.
     ``q_ref`` ``(1, 1, heads of the tile, T, D)``; the running max, sum
     and accumulator of every query row live in scratch across the key
-    blocks."""
+    blocks. ``selected``: a learned index's choice of each query's rows
+    is ``&``-ed onto both masks (:func:`_split_choice`); every query has
+    a chosen row (it sees its own key and the choice takes at least one
+    of those seen), so the blocks a choice masks whole fold as the
+    blocks the positions mask whole do."""
+    (chosen_stored, chosen_own), (o_ref, lse_ref, m_ref, l_ref, acc_ref) = (
+        _split_choice(refs, selected))
     b, kb = pl.program_id(0), pl.program_id(2 + (tiles > 1))
     stored = depth // block_k
     pos0 = pos0_ref[b]
@@ -472,7 +520,7 @@ def _fragment_fwd_kernel(
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     def fold(keys, values, mask):
-        for g in range(group):
+        def head(g, _):
             s = jax.lax.dot_general(
                 q_ref[0, 0, g], keys, _NT, preferred_element_type=jnp.float32)
             s = jnp.where(mask, s, _MASKED)
@@ -486,35 +534,42 @@ def _fragment_fwd_kernel(
                 preferred_element_type=jnp.float32)
             m_ref[g] = m_new
 
+        _each_head(group, head, selected)
+
     # a stored block at or past the start position holds nothing any
     # query may see (in a ring too: the slots from ``pos0`` on are empty
     # until the ring has turned once)
     @pl.when((kb < stored) & (kb * block_k < pos0))
     def _():
-        fold(kc_ref[0], vc_ref[0], _stored_mask(
+        fold(kc_ref[0], vc_ref[0], _chosen(_stored_mask(
             pos0, seg_q_ref[0], pos_q_ref[0], kb * block_k, block_k, depth,
-            window))
+            window), chosen_stored))
 
     @pl.when(kb == stored)
     def _():
-        fold(k_ref[0], v_ref[0],
-             _own_mask(seg_q_ref[0], seg_k_ref[0], window, block))
-        for g in range(group):
+        fold(k_ref[0], v_ref[0], _chosen(
+            _own_mask(seg_q_ref[0], seg_k_ref[0], window, block), chosen_own))
+
+        def result(g, _):
             l = l_ref[g]
             o_ref[0, 0, g] = (acc_ref[g] / l).astype(o_ref.dtype)
             lse_ref[0, 0, g] = m_ref[g] + jnp.log(l)
 
+        _each_head(group, result, selected)
+
 
 def _fragment_bwd_kernel(
     pos0_ref, q_ref, kc_ref, vc_ref, k_ref, v_ref, seg_q_ref, pos_q_ref,
-    seg_k_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref, dq_acc,
-    delta_ref, *own_acc, window, depth, block_k, tiles, block,
+    seg_k_ref, *refs, window, depth, block_k, tiles, block, selected,
 ):
-    """The same walk; every score tile is computed again from the row
-    statistics. ``dq`` gathers over all key blocks, the own keys' ``dk``
-    and ``dv`` are made in the last step (summed in the float32
-    ``own_acc`` where the key head's query heads come in several
-    tiles); a stored row gets nothing."""
+    """The same walk under the same masks; every score tile is computed
+    again from the row statistics. ``dq`` gathers over all key blocks,
+    the own keys' ``dk`` and ``dv`` are made in the last step (summed in
+    the float32 ``own_acc`` where the key head's query heads come in
+    several tiles); a stored row gets nothing."""
+    (chosen_stored, chosen_own), refs = _split_choice(refs, selected)
+    (o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref, dq_acc, delta_ref,
+     *own_acc) = refs
     b, kb = pl.program_id(0), pl.program_id(2 + (tiles > 1))
     tile = pl.program_id(2) if tiles > 1 else 0
     stored = depth // block_k
@@ -524,13 +579,16 @@ def _fragment_bwd_kernel(
     @pl.when(kb == 0)
     def _():
         dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
-        for g in range(group):
+
+        def delta(g, _):
             delta_ref[g] = jnp.sum(
                 o_ref[0, 0, g] * do_ref[0, 0, g], axis=-1, keepdims=True)
 
+        _each_head(group, delta, selected)
+
     def fold(keys, values, mask, own):
-        dk = dv = None
-        for g in range(group):
+        def head(g, grads):
+            dk, dv = grads
             q = q_ref[0, 0, g]
             do = do_ref[0, 0, g].astype(values.dtype)
             s = jax.lax.dot_general(
@@ -548,19 +606,25 @@ def _fragment_bwd_kernel(
                     ds.T.astype(q.dtype), q, preferred_element_type=jnp.float32)
                 dv = dv_g if dv is None else dv + dv_g
                 dk = dk_g if dk is None else dk + dk_g
-        return dk, dv
+            return dk, dv
+
+        if selected and own:  # a loop's carry has a value from the start
+            return _each_head(group, head, True, tuple(
+                jnp.zeros(a.shape, jnp.float32) for a in (keys, values)))
+        return _each_head(group, head, selected, (None, None))
 
     @pl.when((kb < stored) & (kb * block_k < pos0))
     def _():
-        fold(kc_ref[0], vc_ref[0], _stored_mask(
+        fold(kc_ref[0], vc_ref[0], _chosen(_stored_mask(
             pos0, seg_q_ref[0], pos_q_ref[0], kb * block_k, block_k, depth,
-            window), False)
+            window), chosen_stored), False)
 
     @pl.when(kb == stored)
     def _():
         dk, dv = fold(
-            k_ref[0], v_ref[0],
-            _own_mask(seg_q_ref[0], seg_k_ref[0], window, block), True)
+            k_ref[0], v_ref[0], _chosen(
+                _own_mask(seg_q_ref[0], seg_k_ref[0], window, block),
+                chosen_own), True)
         if tiles > 1:
             dk_acc, dv_acc = own_acc
 
@@ -582,8 +646,11 @@ def _fragment_bwd_kernel(
         else:
             dk_ref[0] = dk.astype(dk_ref.dtype)
             dv_ref[0] = dv.astype(dv_ref.dtype)
-        for g in range(group):
+
+        def result(g, _):
             dq_ref[0, 0, g] = dq_acc[g].astype(dq_ref.dtype)
+
+        _each_head(group, result, selected)
 
 
 def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
@@ -593,12 +660,16 @@ def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
     where a tile holds fewer query heads than the group. ``operands``:
     ``q`` ``(B, kv, group, T, D)``, the own ``k``, ``v`` ``(B, T, kv *
     D)``, the caches ``(B, depth, kv * D)``, ``pos0`` ``(B,)``, ``seg``,
-    ``positions`` ``(B, T)``; ``rows``: further operands blocked like
-    ``q``; ``outs``: ``(shape, dtype)`` of each result, blocked like
-    ``q`` at five axes and like the own keys at three."""
+    ``positions`` ``(B, T)``, ``chosen`` (``None``, or a choice's stored
+    ``(B, T, depth)`` and own ``(B, T, own keys)`` parts, int8: the
+    stored one blocked by the caches' index map, so that a skipped
+    step fetches none of it, the own one whole; a block serves every
+    key head and query tile of its stream); ``rows``: further operands
+    blocked like ``q``; ``outs``: ``(shape, dtype)`` of each result,
+    blocked like ``q`` at five axes and like the own keys at three."""
     from ray_tpu import sharding as sharding_lib
 
-    q, k, v, k_cache, v_cache, pos0, seg, positions = operands
+    q, k, v, k_cache, v_cache, pos0, seg, positions, chosen = operands
     bsz, kv, group, t, d = q.shape
     dv = v.shape[-1] // kv
     depth = k_cache.shape[1]
@@ -612,14 +683,15 @@ def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
         b, n, *rest, kb, pos0 = ids
         return b, n, rest[0] if rest else 0, kb, pos0
 
-    def cached(width):
+    def held(ids):
         # past the last block a stream holds the index stays where it
         # is, so nothing is fetched for the steps that are skipped
-        def index(*ids):
-            b, n, _, kb, pos0 = step(ids)
-            last = jnp.maximum(_blocks_held(pos0[b], block_k, stored) - 1, 0)
-            return b, jnp.minimum(kb, last), n
-        return pl.BlockSpec((1, block_k, width), index)
+        b, n, _, kb, pos0 = step(ids)
+        last = jnp.maximum(_blocks_held(pos0[b], block_k, stored) - 1, 0)
+        return b, jnp.minimum(kb, last), n
+
+    def cached(width):
+        return pl.BlockSpec((1, block_k, width), lambda *ids: held(ids))
 
     def heads(shape):
         def index(*ids):
@@ -636,19 +708,22 @@ def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
     def per_stream(*shape):
         return pl.BlockSpec((1,) + shape, lambda *ids: (ids[0], 0, 0))
 
+    choice = () if chosen is None else (
+        pl.BlockSpec((1, t, block_k), lambda *ids: (ids[0], 0, held(ids)[1])),
+        per_stream(t, own_keys))
     # inside a ``shard_map`` the results vary over the axes the operands do
     vma = sharding_lib.vma_of(operands)
     return pl.pallas_call(
         functools.partial(
             kernel, window=window, depth=depth, block_k=block_k, tiles=tiles,
-            block=block),
+            block=block, selected=chosen is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bsz, kv) + (tiles,) * (tiles > 1) + (stored + 1,),
             in_specs=[
                 heads(q.shape), cached(d), cached(dv), own(d), own(dv),
                 per_stream(t, 1), per_stream(t, 1), per_stream(1, own_keys),
-                *(heads(r.shape) for r in rows),
+                *choice, *(heads(r.shape) for r in rows),
             ],
             out_specs=[
                 heads(shape) if len(shape) == 5 else own(shape[-1] // kv)
@@ -668,7 +743,8 @@ def _fragment_call(kernel, operands, rows, outs, scratch, *, window, block_k,
         ),
         name=name,
     )(pos0.astype(jnp.int32), q, k_cache, v_cache, k, v,
-      seg[:, :, None], positions[:, :, None], seg_k[:, None, :], *rows)
+      seg[:, :, None], positions[:, :, None], seg_k[:, None, :],
+      *(chosen or ()), *rows)
 
 
 # A ``jit`` of their own, so that a program with many call sites (five
@@ -711,17 +787,17 @@ def _fragment_bwd(operands, o, do, lse, *, window, block_k, tile, interpret,
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11, 12))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11, 12, 13))
 def _fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions,
-                        window, block_k, tile, interpret, block):
+                        chosen, window, block_k, tile, interpret, block):
     return _fragment_fwd(
-        (q, k, v, k_cache, v_cache, pos0, seg, positions),
+        (q, k, v, k_cache, v_cache, pos0, seg, positions, chosen),
         window=window, block_k=block_k, tile=tile, interpret=interpret,
         block=block)[0]
 
 
 def _fragment_fwd_rule(*args):
-    operands, static = args[:8], dict(zip(_STATIC, args[8:]))
+    operands, static = args[:9], dict(zip(_STATIC, args[9:]))
     o, lse = _fragment_fwd(operands, **static)
     return o, (operands, o, lse)
 
@@ -732,16 +808,17 @@ def _fragment_bwd_rule(window, block_k, tile, interpret, block, residuals, do):
         operands, o, do, lse, window=window, block_k=block_k, tile=tile,
         interpret=interpret, block=block)
     # the stored rows are the rollout's, handed over as data: no
-    # gradient (``None`` is a zero cotangent), nor for the integers
-    return (dq, dk, dv) + (None,) * 5
+    # gradient (``None`` is a zero cotangent), nor for the integers and
+    # the choice
+    return (dq, dk, dv) + (None,) * 6
 
 
 _fragment_attention.defvjp(_fragment_fwd_rule, _fragment_bwd_rule)
 
 
 def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
-                       window=None, block=1, clean=None, block_k=None,
-                       head_tile=None, interpret=False):
+                       window=None, block=1, clean=None, chosen=None,
+                       block_k=None, head_tile=None, interpret=False):
     """A fragment's causal attention over its streams' stored keys and
     values and its own, as one tiled kernel with an online softmax in
     both directions: no ``(T, rows)`` matrix of scores or weights
@@ -770,6 +847,17 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
     own keys are then those rows and the queries' own pass's, two blocks
     of one key operand under ``ops/cached_attention.noisy_masks``, and
     the gradient reaches the clean rows too.
+    ``chosen``: a learned index's choice (``ops/cached_attention``'s
+    ``select``) in two parts, ``(B, T, depth)`` and ``(B, T, own
+    keys)``: which of the stored slots and which of the own keys each
+    query attends to, of those the masks let it see (a query with no
+    chosen row at all has no softmax). A byte a pair (int8: the
+    narrowest type Mosaic loads on a v5e, widened in the kernel), not
+    zero where chosen. It reaches both kernels as one more operand (a
+    stream's stored part is blocked with its key blocks and skipped with
+    them), is ``&``-ed onto the masks, and has no derivative. Without
+    it the operands, the blocks and the kernels' bodies are the ones of
+    a call that has none.
 
     Differentiable in ``q``, ``k`` and ``v``. The caches get NO
     gradient (zeros): they are the rollout's rows, handed over as data,
@@ -795,7 +883,14 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
     if clean is not None:
         k = jnp.concatenate([clean[0].astype(k.dtype), k], axis=1)
         v = jnp.concatenate([clean[1].astype(v.dtype), v], axis=1)
-    tile = head_tile or fragment_head_tile(t, kv * group, kv, d, k.shape[1])
+    if chosen is not None:
+        if [c.shape for c in chosen] != [(bsz, t, depth), (bsz, t, k.shape[1])]:
+            raise ValueError(
+                f"a choice of {[c.shape for c in chosen]} for {(bsz, t)} "
+                f"queries over {depth} and {k.shape[1]} rows")
+        chosen = tuple(c.astype(jnp.int8) for c in chosen)
+    tile = head_tile or fragment_head_tile(
+        t, kv * group, kv, d, k.shape[1], chosen is not None)
     if not tile or pack * group % tile:
         raise ValueError(
             f"no tile of the {pack * group} query heads of a key block fits")
@@ -818,7 +913,7 @@ def fragment_attention(q, k, v, k_cache, v_cache, pos0, seg, positions, *,
 
     return gather(_fragment_attention(
         spread(q), k.reshape(bsz, -1, kv * d), v.reshape(bsz, -1, kv * dv),
-        k_cache, v_cache, pos0, seg, positions, window, block_k, tile,
+        k_cache, v_cache, pos0, seg, positions, chosen, window, block_k, tile,
         interpret, block))
 
 

@@ -894,8 +894,9 @@ def learn_minibatch_lowerings() -> Dict[str, float]:
 
 def inc_attention_fragment_lowering(path: str) -> None:
     """One traced attention layer's fragment form took ``path``
-    (``kernel`` | ``xla`` | ``selected_xla``: the text under a learned
-    index's choice of rows)."""
+    (``kernel`` | ``xla`` | ``selected_kernel``: the kernel pair with a
+    learned index's choice of rows as one more operand |
+    ``selected_xla``: the text under the choice)."""
     counter(
         ATTENTION_FRAGMENT_LOWERINGS_TOTAL,
         "attention layers' fragment forms traced, by the lowering they took",

@@ -14,6 +14,22 @@ from ray_tpu.ops.flash_attention import (
 )
 
 
+@pytest.fixture(autouse=True)
+def _release_compiled_programs():
+    """A kernel in the Pallas interpreter is some 600 memory mappings of
+    XLA:CPU code a case, which jax's caches keep, and a tier-1 worker
+    that passes the kernel's ``vm.max_map_count`` of 65,530 dies of a
+    segmentation fault in a LATER file's compile (PR 66:
+    ``tests/test_latent_lm.py``, in three whole runs, once the ten cases
+    under a choice were here). ``jax.clear_caches`` after EVERY case of
+    this file, not the ten alone: ``--dist load`` deals the file's 81
+    cases to all six workers, and each gives back whatever its worker
+    held (21,922 mappings to 747 after 36 cases). With the fixture on
+    the ten cases alone the whole run lost a worker again."""
+    yield
+    jax.clear_caches()
+
+
 def _qkv(rng, B=2, H=2, T=24, S=40, D=16, dtype=jnp.float32):
     q = jnp.asarray(rng.standard_normal((B, H, T, D)), dtype)
     k = jnp.asarray(rng.standard_normal((B, H, S, D)), dtype)
@@ -355,6 +371,130 @@ def test_fragment_kernel_block_rules(name):
     learned = (0, 1, 2, 3, 4) if noisy else (0, 1, 2)
     got = jax.grad(loss(kernel), argnums=learned)(*operands)
     want = jax.grad(loss(text), argnums=learned)(*operands)
+    for a, b in zip(got, want):
+        assert float(jnp.max(jnp.abs(b))) > 0.1
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), **tol)
+
+
+_CHOSEN_CASES = {
+    # streams empty, part full and full (blocks of 16 keys: two, one and
+    # no stored block skipped), an episode reset inside the fragment
+    "depths_and_a_reset": dict(),
+    "reset_at_the_first_token": dict(resets=((0,), (5, 9), ())),
+    # the cell's group of eight query heads a key head, and a packed one
+    "group_8": dict(kv=1, group=8),
+    # its eight query heads in two tiles, as a tighter VMEM would cut
+    # them: the own keys' gradients summed over the tiles
+    "group_8_in_two_head_tiles": dict(kv=1, group=8, head_tile=4),
+    "head_64_group_4": dict(d=64, kv=4, group=4),
+    "bfloat16": dict(dtype=jnp.bfloat16),
+    # the rule's own key blocks of 512 at the cell's fragment of 256
+    "group_8_cache_2048": dict(
+        b=2, t=256, kv=1, group=8, depth=2048, pos0=(700, 1800),
+        resets=((), (100,)), block_k=None),
+    # what the operand must leave alone
+    "cache_cotangents_are_zeros": dict(),
+    "a_skipped_block_changes_nothing": dict(pos0=(0, 10, 16)),
+    "all_seen_is_the_call_without_a_choice": dict(),
+}
+
+
+def _choice(rows, depth, seed=1):
+    """A choice ``(B, T, depth + T)`` over a fragment's rows: half the
+    pairs at random (seen or not: the kernel's masks stay on), stream
+    1's fourth query with NO chosen stored row, stream 2's eighth with no
+    chosen own row, and every query with at least one row it sees."""
+    from ray_tpu.ops.cached_attention import fragment_masks
+
+    b, t = rows["seg"].shape
+    seen = np.concatenate([np.asarray(m) for m in fragment_masks(
+        rows["seg"], rows["pos0"], None, depth, None)], axis=-1)
+    chosen = np.random.default_rng(seed).random((b, t, depth + t)) < 0.5
+    chosen[1 % b, 3, :depth] = False
+    chosen[2 % b, 7, depth:] = False
+    none = ~(chosen & seen).any(-1)
+    own = depth + np.arange(t)
+    chosen[:, np.arange(t), own] |= none  # a query sees its own key
+    return jnp.asarray(chosen), jnp.asarray(seen)
+
+
+@pytest.mark.parametrize("name", list(_CHOSEN_CASES))
+def test_fragment_kernel_under_a_choice(name):
+    """The kernel pair with a learned index's choice as one more operand
+    (``fragment_attention(chosen=)``) against the text under the same
+    choice (``cached_attention._selected_text``): ``o`` and the
+    gradients of ``q``, ``k``, ``v``; the stored rows get none, a stored
+    block at or past the start position is neither read nor is its part
+    of the choice, and a choice of every row seen gives the bits of the
+    call without one."""
+    from ray_tpu.ops import cached_attention
+    from ray_tpu.ops.flash_attention import fragment_attention
+
+    case = dict(_CHOSEN_CASES[name])
+    block_k, head_tile = case.pop("block_k", 16), case.pop("head_tile", None)
+    dtype, kv = case.get("dtype", jnp.float32), case.get("kv", 2)
+    (q, k, v, kc, vc), rows, w = _fragment(**case)
+    depth = kc.shape[1]
+    chosen, seen = _choice(rows, depth)
+
+    def heads_of(q):
+        b, t, h, d = q.shape
+        return (q * d ** -0.5).astype(dtype).reshape(b, t, kv, h // kv, d)
+
+    def kernel(q, k, v, kc, vc, chosen=chosen):
+        stored = kc.shape[1]  # the kernels take the choice in two parts
+        return fragment_attention(
+            heads_of(q), k.astype(dtype), v.astype(dtype), kc, vc, rows["pos0"],
+            rows["seg"], rows["positions"], block_k=block_k, head_tile=head_tile,
+            chosen=None if chosen is None else (
+                chosen[..., :stored], chosen[..., stored:]),
+            interpret=True).reshape(q.shape)
+
+    def text(q, k, v, kc, vc):
+        return cached_attention._selected_text(
+            q.shape[2], heads_of(q), k.astype(dtype), v.astype(dtype), kc, vc,
+            chosen & seen, jax.named_scope).reshape(q.shape)
+
+    operands = (q, k, v, kc, vc)
+    loss = lambda f: lambda *a: jnp.sum(f(*a) * w)
+    if name == "cache_cotangents_are_zeros":
+        got = jax.grad(loss(kernel), argnums=(3, 4))(*operands)
+        want = jax.grad(loss(text), argnums=(3, 4))(*operands)
+        assert all(float(jnp.max(jnp.abs(g))) == 0.0 for g in got)
+        assert all(float(jnp.max(jnp.abs(g))) > 0.1 for g in want)
+        return
+    under = lambda mask: jax.value_and_grad(
+        lambda *a: jnp.sum(kernel(*a, chosen=mask) * w), argnums=(0, 1, 2))
+    if name == "a_skipped_block_changes_nothing":
+        # streams at 0, 10 and 16 of 32 slots: the second block of 16 is
+        # skipped for all three, so caches and a choice cut to the first
+        # block, or with other rows and another choice in the second,
+        # give the same bits
+        want = under(chosen)(*operands)
+        cut = under(jnp.concatenate(
+            [chosen[..., :16], chosen[..., depth:]], axis=-1))(
+                q, k, v, kc[:, :16], vc[:, :16])
+        other = under(chosen.at[..., 16:depth].set(~chosen[..., 16:depth]))(
+            q, k, v, kc.at[:, 16:].set(7.0), vc.at[:, 16:].set(-7.0))
+        for got in (cut, other):
+            for a, b in zip(jax.tree_util.tree_leaves(got),
+                            jax.tree_util.tree_leaves(want)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        return
+    if name == "all_seen_is_the_call_without_a_choice":
+        none = under(None)(*operands)
+        for mask in (seen, jnp.ones_like(seen)):
+            for a, b in zip(jax.tree_util.tree_leaves(under(mask)(*operands)),
+                            jax.tree_util.tree_leaves(none)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        return
+    tol = dict(atol=1e-4, rtol=1e-4) if dtype == jnp.float32 else dict(
+        atol=0.15, rtol=5e-2)
+    np.testing.assert_allclose(
+        np.asarray(kernel(*operands)), np.asarray(text(*operands)), **tol)
+    got = under(chosen)(*operands)[1]
+    want = jax.grad(loss(text), argnums=(0, 1, 2))(*operands)
     for a, b in zip(got, want):
         assert float(jnp.max(jnp.abs(b))) > 0.1
         np.testing.assert_allclose(

@@ -600,6 +600,76 @@ def test_v5e_fragment_attention_kernel_compiles(v5e_mesh, layer):
     assert not re.search(rf"f32\[[0-9,]*{t},({depth}|{depth + t})\]", text)
 
 
+def test_v5e_fragment_attention_kernel_takes_a_choice(v5e_mesh, monkeypatch):
+    """The learned-index cell's layer (a group of 16 streams, T 256, 32
+    query heads over 4 key heads of 128, depth 8,192, 16 index heads of
+    64, 2,048 rows a query) through ``cached_attention(select=)`` where
+    the backend is a TPU, under ``shard_map`` and the block's checkpoint:
+    the rule admits it, the counter says ``selected_kernel``, Mosaic
+    takes the choice's int8 blocks in both kernels, the three custom
+    calls (forward, recomputation, backward) stand under
+    ``learn/attn/scores``, and the compiled program holds no float32
+    array of (tokens, rows) of the ATTENTION's 32 heads: the only score
+    tiles left are the index's, a stream at a time."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.ops import cached_attention
+    from ray_tpu.telemetry import metrics
+
+    b, t, kv, group, d, depth, index_heads, index_dim, top_k = (
+        16, 256, 4, 8, 128, 8192, 16, 64, 2048)
+    h = kv * group
+    axis = sharding_lib.data_axis(v5e_mesh)
+    rows = sharding_lib.batch_sharded(v5e_mesh)
+    on = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=rows)
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = metrics.attention_fragment_lowerings()
+
+    def grads(q, k, v, kc, vc, qi, wi, ki, ic, pos0, seg, positions):
+        @jax.checkpoint
+        def attention(q, k, v):
+            return cached_attention.cached_attention(
+                q, k, v, (kc, vc), {"seg": seg, "positions": positions, "pos0": pos0},
+                scale=d ** -0.5, window=None, dtype=bf, scope="learn/attn",
+                select=cached_attention.Selection(qi, wi, ki, ic, top_k))[0]
+
+        return jax.grad(
+            lambda *qkv: jnp.sum(jnp.square(attention(*qkv))),
+            argnums=(0, 1, 2))(q, k, v)
+
+    sharded = jax.shard_map(
+        grads, mesh=v5e_mesh, in_specs=(P(axis),) * 12, out_specs=P(axis))
+    compiled = jax.jit(sharded).lower(
+        on(f32, b, t, h, d), on(bf, b, t, kv, d), on(bf, b, t, kv, d),
+        on(bf, b, depth, kv * d), on(bf, b, depth, kv * d),
+        on(bf, b, t, index_heads, index_dim), on(f32, b, t, index_heads),
+        on(bf, b, t, index_dim), on(bf, b, depth, index_dim),
+        on(i32, b), on(i32, b, t), on(i32, b, t),
+    ).compile()
+    after = metrics.attention_fragment_lowerings()
+    moved = {path: after[path] - before.get(path, 0) for path in after}
+    assert moved.get("selected_kernel") and not moved.get("selected_xla")
+    text = compiled.as_text()
+    calls = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "custom-call(" in line
+    ]
+    assert len(calls) == 3 and all("learn/attn/scores" in line for line in calls)
+    # the forward pass, its recomputation, the backward pass
+    assert ["transpose(jvp" in line for line in calls] == [False, True, True]
+    assert ["rematted_computation" in line for line in calls] == [False, True, False]
+    assert ["fragment_attention_bwd" in line for line in calls] == [False, False, True]
+    assert not re.search(rf"f32\[[0-9,]*{h},{t},{depth + t}\]", text)
+    assert not re.search(rf"f32\[[0-9,]*{group},{t},({depth}|{depth + t})\]", text)
+    # the choice crosses HBM a byte a pair, never widened there
+    assert not re.search(rf"(s32|f32)\[{b},{t},({depth}|{depth + t})\]", text)
+    # and leaves the fusion that makes it as the kernels' two int8 parts:
+    # no whole choice is written beside them
+    assert re.search(rf"s8\[{b},{t},{depth}\]", text)
+    assert not re.search(rf"(pred|s8)\[{b},{t},{depth + t}\]", text)
+
+
 # all of a cell's streams, key heads, query heads a key head, head, depth:
 # the four cells' full-depth softmax layers as the rollout steps them
 STEP_LAYERS = {

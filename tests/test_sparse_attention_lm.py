@@ -524,8 +524,10 @@ def test_index_statistics_and_the_counters_label_of_the_path_taken(setup):
     """The learn form's eight statistics by hand from the positions, and
     ``ray_tpu_attention_{fragment,step}_lowerings_total`` under
     ``selected_xla`` for a call with a selection, under the old labels
-    for the same stack whose index keeps every row. The kernels' rules
-    answer no for a selection on any backend."""
+    for the same stack whose index keeps every row. Off a TPU both
+    kernels' rules answer no, so the path and every label here stay
+    ``selected_xla`` (what a TPU changes:
+    ``test_the_fragment_rule_admits_a_selection_on_a_tpu``)."""
     from ray_tpu.telemetry import metrics
 
     config, params, model, batch, _ = setup
@@ -598,6 +600,119 @@ def _no_rope_on_the_index(config):
             "indexer": seg.mixer.indexer}))
         for seg in model.segments)
     return model
+
+
+@pytest.mark.parametrize("tokens,heads,kv,head,depth,tile", [
+    (256, 32, 4, 128, 8192, 8),    # the cell's layer: its group of 8 in one tile
+    (256, 32, 4, 128, 16384, 8),   # at the episodes ISSUE 65 named
+    (128, 64, 8, 64, 2048, 16),    # a packed head: two key heads a block
+    (256, 6, 2, 128, 2048, 3),
+])
+def test_the_fragment_rule_admits_a_selection_on_a_tpu(
+        monkeypatch, tokens, heads, kv, head, depth, tile):
+    """Where the backend is a TPU the fragment rule answers for a call
+    with a selection as for one without, the choice's blocks counted in
+    the tile's room (VMEM alone decides a tile: here the one without a
+    selection); the step rule keeps answering no (the one-token form under a
+    selection is the text: ROADMAP B32 (a)); off a TPU both say no."""
+    bf = jnp.bfloat16
+    assert not flash_attention.fragment_kernel_applies(
+        tokens, heads, kv, head, depth, bf, selected=True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert flash_attention.fragment_kernel_applies(
+        tokens, heads, kv, head, depth, bf, selected=True)
+    assert flash_attention.fragment_kernel_applies(tokens, heads, kv, head, depth, bf)
+    assert flash_attention.fragment_head_tile(
+        tokens, heads, kv, head, selected=True) == tile
+    assert flash_attention.fragment_head_tile(tokens, heads, kv, head) == tile
+    assert not flash_attention.fragment_kernel_applies(
+        tokens, heads, kv, head, depth, jnp.float32, selected=True)
+    assert not flash_attention.fragment_kernel_applies(
+        tokens, heads, kv, head, depth + 24, bf, selected=True)
+    assert not flash_attention.step_kernel_applies(
+        heads, kv, head, depth, bf, selected=True)
+    assert flash_attention.step_kernel_applies(heads, kv, head, depth, bf)
+    # the choice's blocks take room: a fragment whose tile just fits
+    # without one does not fit with one
+    assert flash_attention.fragment_head_tile(1408, 8, 8, 128) == 1
+    assert flash_attention.fragment_head_tile(1408, 8, 8, 128, selected=True) == 0
+
+
+def test_a_selection_takes_the_kernel_where_the_rule_says_so(monkeypatch):
+    """``cached_attention(select=)`` where the fragment rule admits the
+    call (patched: the kernel pair runs in the interpreter here): the
+    choice is made as on the text's path (the same rows, query for
+    query), handed to ``fragment_attention`` as ``chosen``, counted
+    under ``selected_kernel`` and not ``selected_xla``; ``o`` and the
+    gradients are the text's, and the statistics report the kernel's
+    walk of the key blocks as they do without an index."""
+    import functools
+
+    from ray_tpu.telemetry import metrics
+
+    b, t, kv, group, d, depth, index_heads, width, top_k = 3, 16, 2, 2, 128, 256, 4, 16, 24
+    h = kv * group
+    rng = np.random.default_rng(5)
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    q, k, v = normal(b, t, h, d), normal(b, t, kv, d), normal(b, t, kv, d)
+    caches = (normal(b, depth, kv * d), normal(b, depth, kv * d))
+    pos0 = jnp.asarray([0, 100, 256], jnp.int32)
+    fresh = np.zeros((b, t), bool)
+    fresh[1, 5] = True
+    seg = jnp.asarray(np.cumsum(fresh, 1), jnp.int32)
+    steps = np.arange(t)[None]
+    opened = np.maximum.accumulate(np.where(fresh, steps, -1), axis=1)
+    positions = jnp.where(seg == 0, pos0[:, None] + steps, steps - opened).astype(jnp.int32)
+    rows = {"seg": seg, "positions": positions, "pos0": pos0, "choices": True}
+    select = cached_attention.Selection(
+        normal(b, t, index_heads, width), jnp.abs(normal(b, t, index_heads)),
+        normal(b, t, width), normal(b, depth, width), top_k)
+    w = normal(b, t, h, d)
+
+    def call(q, k, v):
+        o, _, stats = cached_attention.cached_attention(
+            q, k, v, caches, rows, scale=d ** -0.5, window=None, dtype=jnp.float32,
+            scope="attn", select=select)
+        return jnp.sum(o * w), (o, stats)
+
+    count = lambda: dict(metrics.attention_fragment_lowerings())
+    before = count()
+    (_, (want, want_stats)), want_grads = jax.value_and_grad(
+        call, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    text = count()
+    assert text.get("selected_xla", 0) - before.get("selected_xla", 0) == 1
+    assert text.get("selected_kernel", 0) == before.get("selected_kernel", 0)
+    assert int(want_stats["attn_key_blocks_walked"]) == 0
+
+    asked = []
+    monkeypatch.setattr(
+        flash_attention, "fragment_kernel_applies",
+        lambda *a, selected=False: asked.append(selected) or True)
+    monkeypatch.setattr(
+        flash_attention, "fragment_attention",
+        functools.partial(flash_attention.fragment_attention, interpret=True))
+    (_, (got, stats)), grads = jax.value_and_grad(
+        call, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    now = count()
+    assert asked == [True]
+    assert now.get("selected_kernel", 0) - text.get("selected_kernel", 0) == 1
+    assert now.get("selected_xla", 0) == text.get("selected_xla", 0)
+    assert now.get("kernel", 0) == before.get("kernel", 0)
+    assert metrics.attention_fragment_lowerings()["selected_kernel"] >= 1
+    # the same choice, the rows a query attended to, and the text's numbers
+    assert np.array_equal(
+        np.asarray(stats["index_choices"]), np.asarray(want_stats["index_choices"]))
+    assert np.array_equal(np.asarray(stats["index_rows_selected"]),
+                          np.asarray(want_stats["index_rows_selected"]))
+    assert float(jnp.min(stats["index_rows_selected"])) >= 1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4, rtol=1e-4)
+    for a, c in zip(grads, want_grads):
+        assert float(jnp.max(jnp.abs(c))) > 0.1
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=1e-4, rtol=1e-4)
+    # one stored block of 256 and the own, a key head: the stream at 0
+    # skips the stored one
+    assert (int(stats["attn_key_blocks_skipped"]),
+            int(stats["attn_key_blocks_walked"])) == (1, 6)
 
 
 @pytest.mark.parametrize("wrong", [_top_k_plus_one, _no_rope_on_the_index])
